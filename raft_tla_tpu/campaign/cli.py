@@ -56,8 +56,8 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--deadlock", action="store_true")
     p.add_argument("--mesh-plan", default=None, metavar="N,M,...",
                    help="mesh size per resume attempt, last entry "
-                        "repeats (default: probe jax.devices() at "
-                        "every spawn)")
+                        "repeats (default: count the devices in a "
+                        "probe child before every spawn)")
     p.add_argument("--checkpoint-every", type=float, default=120.0,
                    metavar="S", help="child snapshot period; 0 = every "
                                      "window boundary (default 120)")

@@ -61,6 +61,7 @@ from raft_tla_tpu.campaign.integrity import (CheckpointCorrupt,
 from raft_tla_tpu.obs import append_event
 from raft_tla_tpu.obs.collect import LogTail as _LogTail
 from raft_tla_tpu.obs.history import _DRIFT_EXEMPT, fiducial_drift
+from raft_tla_tpu.utils.device import DeviceError, probe_devices
 
 # check.py's exit contract (mirrored, not imported: the supervisor must
 # not pay the check-CLI import just to read four integers)
@@ -273,10 +274,10 @@ class Supervisor:
     """Drive one campaign to a verdict across any number of child
     lifetimes.  See the module docstring for the loop contract.
 
-    ``mesh_plan``: None (probe ``jax.devices()`` each spawn), a list of
-    mesh sizes indexed by attempt (last entry repeats — the test
-    harness's deterministic reshard schedule), or a callable
-    ``attempt -> ndev``.
+    ``mesh_plan``: None (count the devices in a probe child before each
+    spawn — this process never opens a backend), a list of mesh sizes
+    indexed by attempt (last entry repeats — the test harness's
+    deterministic reshard schedule), or a callable ``attempt -> ndev``.
 
     ``spawn_hook(sup, proc, attempt)`` / ``pre_verify_hook(sup,
     attempt)`` are the chaos seams: fault injection attaches here, the
@@ -382,9 +383,11 @@ class Supervisor:
     def _mesh_for(self, attempt: int) -> int:
         plan = self.mesh_plan
         if plan is None:
-            import jax
-            nd = fit_mesh(len(jax.devices()), self.spec.window,
-                          self.spec.chunk)
+            # One process per chip: a probe child counts the devices and
+            # has exited before the check child needs them — opening the
+            # backend here would hold the chip against our own child.
+            nd = fit_mesh(probe_devices(cpu=self.spec.cpu)["count"],
+                          self.spec.window, self.spec.chunk)
         elif callable(plan):
             nd = int(plan(attempt))
         else:
@@ -691,7 +694,12 @@ class Supervisor:
                     # fresh start: no partial family may shadow it
                     for p in snapshot_family(self.ckpt):
                         os.remove(p)
-            ndev = self._mesh_for(attempt)
+            try:
+                ndev = self._mesh_for(attempt)
+            except DeviceError as e:
+                self._say(str(e))
+                return self._result("error", 1, last_end, spawns,
+                                    preempts, reshards, detail=str(e))
             ndev_have = self._state.get("ndev")
             if resume and ndev_have is not None and ndev != ndev_have:
                 try:

@@ -151,8 +151,9 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--cp-lanes", action="store_true",
                    help="--engine ddd-shard only: CP mode — shard the "
                         "bag-scan ACTION lanes across the mesh instead "
-                        "of the frontier rows (window replicated; see "
-                        "RESULTS.md 'CP measured' before choosing it)")
+                        "of the frontier rows (window replicated; "
+                        "measured 1.51x slower than row sharding on the "
+                        "8-virtual-device CPU mesh, never on chips)")
     from raft_tla_tpu.models.views import REGISTRY as _view_registry
     p.add_argument("--view", default=None,
                    choices=tuple(sorted(_view_registry)),
@@ -223,7 +224,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "argument). Sets RAFT_TLA_SIGPRUNE process-wide so "
                         "every engine inherits one decision; default: "
                         "leave the env/auto policy alone (auto is "
-                        "currently OFF — RESULTS.md 'sig-prune A/B')")
+                        "currently OFF — 0.94x in-engine on the CPU, "
+                        "not measured on the chip)")
     p.add_argument("--megakernel", default=None,
                    choices=("auto", "on", "off"),
                    help="Pallas megakernel build of the fused step: the "
@@ -233,8 +235,10 @@ def build_argparser() -> argparse.ArgumentParser:
                         "lane for lane). Sets RAFT_TLA_MEGAKERNEL "
                         "process-wide so every engine inherits one "
                         "decision; default: leave the env/auto policy "
-                        "alone (auto is currently OFF — RESULTS.md "
-                        "'Megakernel A/B')")
+                        "alone (auto is currently OFF — 0.82x in-engine "
+                        "on the CPU under the Pallas interpreter; on the "
+                        "TPU Mosaic refuses the kernel's gathers, so 'on' "
+                        "exits with the compiler's message)")
     p.add_argument("--host-dedup", default=None,
                    choices=("auto", "on", "off"),
                    help="partitioned + background host dedup for the ddd "
@@ -246,8 +250,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "byte-identical (utils/keyset.py has the ordering "
                         "argument). Sets RAFT_TLA_HOSTDEDUP process-wide; "
                         "default: leave the env/auto policy alone (auto "
-                        "= on iff nproc >= 2 — RESULTS.md 'Host dedup "
-                        "A/B')")
+                        "= on iff nproc >= 2 — 0.72x in-engine at "
+                        "nproc=1 on the CPU, not measured on the chip)")
     p.add_argument("--prefetch", default=None,
                    choices=("auto", "on", "off"),
                    help="double-buffered upload prefetch for the ddd "
@@ -261,7 +265,9 @@ def build_argparser() -> argparse.ArgumentParser:
                         "discovery stays byte-identical, hit or miss. "
                         "Sets RAFT_TLA_PREFETCH process-wide; default: "
                         "leave the env/auto policy alone (auto = on iff "
-                        "nproc >= 2 — RESULTS.md 'Upload prefetch A/B')")
+                        "nproc >= 2 — 1.29x full / 0.91x frontier "
+                        "retention at nproc=1 on the CPU, not measured "
+                        "on the chip)")
     p.add_argument("--device-dedup", default=None,
                    choices=("auto", "on", "off", "hash", "sort"),
                    help="device-resident exact within-level fingerprint "
@@ -276,7 +282,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "'sort' the portable sorted-set arm. Sets "
                         "RAFT_TLA_DEVDEDUP process-wide; default: leave "
                         "the env/auto policy alone (auto is currently "
-                        "OFF — RESULTS.md 'Device dedup A/B')")
+                        "OFF — 0.44x warm rate on the CPU, not measured "
+                        "on the chip)")
     p.add_argument("--lint", default="warn", choices=("warn", "strict"),
                    help="static width-safety pass (analysis/widthcheck) "
                         "before any step build: prove no transition can "
@@ -464,37 +471,16 @@ def _make_cli_mesh(args):
     return make_slice_mesh(args.slices, nd // args.slices)
 
 
-def _force_cpu(args):
-    """Honor ``--cpu`` (one definition for every CLI path): switch the
-    backend, or warn when backends are already initialized — never
-    silently run on the accelerator."""
-    if not args.cpu:
-        return
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        if args.devices:
-            try:
-                jax.config.update("jax_num_cpu_devices", args.devices)
-            except AttributeError:
-                # older jax: no jax_num_cpu_devices knob — the XLA flag
-                # does the same job as long as no backend is live yet
-                # (same caveat the RuntimeError arm below covers)
-                flags = [f for f in os.environ.get("XLA_FLAGS",
-                                                   "").split()
-                         if "host_platform_device_count" not in f]
-                flags.append("--xla_force_host_platform_device_count="
-                             f"{args.devices}")
-                os.environ["XLA_FLAGS"] = " ".join(flags)
-    except RuntimeError:
-        if jax.default_backend() != "cpu":
-            print("Warning: --cpu requested but JAX backends are "
-                  f"already initialized on {jax.default_backend()!r}; "
-                  "proceeding there", file=sys.stderr)
+def _needs_device(args) -> bool:
+    """False for the paths that never compute on a device: the pure-
+    Python oracle, and the DDD-family checkpoint rewrite (host arrays
+    only — the campaign supervisor runs it beside a live child)."""
+    if args.reshard_to is not None:
+        return args.engine == "shard"
+    return args.engine != "ref" or args.simulate is not None
 
 
 def _run(args, config):
-    _force_cpu(args)
     if args.engine == "ref":
         from raft_tla_tpu.models import refbfs
         return refbfs.check(config)
@@ -535,7 +521,8 @@ def _run(args, config):
         from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
         # the filter table is a traffic optimization, not a capacity
         # bound — size it to the expected state count, capped at the
-        # 2 GiB-buffer limit the exact tables live under
+        # 2 GiB single-buffer limit the exact tables live under
+        # (inherited, not re-measured on this machine)
         table = 1 << max(10, min(28, (2 * args.cap - 1).bit_length()))
         # segment output buffers must hold at least one chunk's worst-case
         # candidate stream (chunk * action fan-out)
@@ -687,8 +674,6 @@ def main(argv=None) -> int:
         # (ops/devdedup.devdedup_backend) by the ddd engine families.
         import os
         os.environ["RAFT_TLA_DEVDEDUP"] = args.device_dedup
-    from raft_tla_tpu.serve.sched import enable_compile_cache
-    enable_compile_cache(args.compile_cache)
     _DEVICE_ENGINES = ("device", "paged", "streamed", "ddd", "shard",
                        "pagedshard", "ddd-shard")
     if args.view and args.simulate:
@@ -787,12 +772,29 @@ def main(argv=None) -> int:
             if args.lint == "strict":
                 return EXIT_ERROR
 
+    dev_line = None
+    if _needs_device(args):
+        # One decision, before the first device op: --cpu, an explicit
+        # JAX_PLATFORMS, or a TPU — never a silent fall-back to the CPU
+        # (utils/device.py).  The header states where the run executes.
+        from raft_tla_tpu.serve.sched import enable_compile_cache
+        from raft_tla_tpu.utils import device
+        try:
+            dev = device.select_device(args.cpu, args.devices)
+        except device.DeviceError as e:
+            print(f"Error: {e}", file=sys.stderr)
+            return EXIT_ERROR
+        dev_line = "Device: " + device.describe(dev)
+        enable_compile_cache(args.compile_cache, platform=dev["platform"])
+
     b = config.bounds
     if not model.is_raft:
         print(f"raft_tla_tpu {__import__('raft_tla_tpu').__version__} — "
               f"exhaustive check of spec {args.spec} (frontend-compiled)")
         print(f"Universe: {b.n_servers} resource managers "
               f"(from {args.cfg})")
+        if dev_line:
+            print(dev_line)
         print(f"Invariants: {', '.join(config.invariants) or '(none)'}")
         if args.emit_tlc:
             try:
@@ -808,7 +810,6 @@ def main(argv=None) -> int:
                       "in --simulate mode (liveness needs exhaustive "
                       "search)", file=sys.stderr)
                 return EXIT_ERROR
-            _force_cpu(args)
             try:
                 return _simulate(args, config)
             except Exception as e:
@@ -821,6 +822,8 @@ def main(argv=None) -> int:
           f"(from {args.cfg})")
     print(f"Constraint: MaxTerm={b.max_term} MaxLogLen={b.max_log} "
           f"MaxMsgs={b.max_msgs} MaxDup={b.max_dup}")
+    if dev_line:
+        print(dev_line)
     if b.history:
         print("Faithful mode: history variables (elections/allLogs/"
               f"voterLog/mlog) carried; elections capacity {b.max_elections}")
@@ -859,7 +862,6 @@ def main(argv=None) -> int:
                   "--simulate mode (liveness needs exhaustive search)",
                   file=sys.stderr)
             return EXIT_ERROR
-        _force_cpu(args)
         try:
             return _simulate(args, config)
         except Exception as e:
@@ -875,7 +877,6 @@ def main(argv=None) -> int:
             print("Error: --reshard-to needs --resume SRC and "
                   "--checkpoint DST", file=sys.stderr)
             return EXIT_ERROR
-        _force_cpu(args)
         if args.engine == "shard":
             from raft_tla_tpu.parallel.shard_engine import (
                 ShardCapacities, reshard_checkpoint)

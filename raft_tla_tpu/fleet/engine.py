@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from raft_tla_tpu.config import CheckConfig
 from raft_tla_tpu.engine import DEADLOCK, Violation
-from raft_tla_tpu.parallel.shard_engine import _AXIS, _shard_map, make_mesh
+from raft_tla_tpu.parallel.shard_engine import _AXIS, make_mesh
 from raft_tla_tpu.simulate import resolve_sim_model
 
 I32 = jnp.int32
@@ -235,13 +235,17 @@ def _build_fleet_segment(config: CheckConfig, model, mesh, walkers: int,
     shard = P(_AXIS)
     shard2 = P(_AXIS, None)
     repl = P()
-    seg = _shard_map(
+    seg = jax.shard_map(
         device_seg, mesh=mesh,
         in_specs=(repl, repl, repl, repl, repl,
                   shard2, shard2, shard, shard, shard, shard),
         out_specs=(shard2, shard2, shard, shard, shard, shard,
                    shard, shard, shard, shard2, shard,
-                   shard, shard, shard, shard, shard))
+                   shard, shard, shard, shard, shard),
+        # like every sharded engine here: the walk loop's carry mixes
+        # replicated initial values with per-device updates, which
+        # jax.shard_map's varying-axes check (JAX 0.9) refuses to infer
+        check_vma=False)
     # Donate the walker shards (args 5-10): off-CPU each dispatch then
     # reuses the buffers in place.  (CPU has no donation; gate it off
     # there to keep virtual-mesh runs warning-free.)

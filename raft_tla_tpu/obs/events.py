@@ -102,7 +102,8 @@ event types invalid on a ``"v" < 7`` line:
 ``worker_lost``    worker, kind [+ pid, exit_code, jobs, detail]
                    (the pool reaped a dead/preempted worker; ``kind`` is
                     the death classification: killed / segfault / oom /
-                    signal / crashed / heartbeat-stale / session-wall)
+                    signal / crashed / backend / heartbeat-stale /
+                    session-wall)
 ``job_retry``      job_id, attempt [+ worker, backoff_s, reason]
                    (a surviving job was requeued to a fresh worker)
 ``quarantine``     job_id, reason [+ deaths, worker, detail]
@@ -124,8 +125,10 @@ Version 8 adds the cross-process tracing layer (obs/trace.py — gated by
                    (obs/collect.py) can place monotonic span timestamps
                    from many processes on one wall axis with a recorded
                    error bound
-``run_start.host`` host context for cross-session comparison (nproc,
-                   jax version, backend)
+``run_start.host`` host context (nproc, and once the process has
+                   opened a JAX backend: jax version, device platform /
+                   kind / count) — on every engine run_start, so a log
+                   always says where its run executed
 
 Version 9 adds ddd device-dedup attribution (ops/devdedup — gated by
 ``--device-dedup`` / ``RAFT_TLA_DEVDEDUP``): segment ``export_rows``
@@ -695,13 +698,13 @@ class RunTelemetry:
         if fiducials:
             fields["fiducials"] = fiducials
         fields["pid"] = os.getpid()
-        # The v8 clock anchor: always stamped (cheap, three clock reads)
-        # so any log joins a merged trace timeline; host context rides
-        # along only when tracing, where cross-host comparison matters.
+        # The v8 clock anchor and host context: always stamped (three
+        # clock reads and a dict) so any log joins a merged trace
+        # timeline AND says which device the run executed on — a run
+        # that landed on the wrong platform must be visible in its log.
         from raft_tla_tpu.obs.trace import clock_anchor, host_context
         fields["anchor"] = clock_anchor()
-        if self.trace.enabled:
-            fields["host"] = host_context()
+        fields["host"] = host_context()
         self.log.emit("run_start", **fields)
 
     def segment(self, n_states: int, level: int, n_transitions: int,

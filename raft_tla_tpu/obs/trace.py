@@ -69,18 +69,18 @@ def clock_anchor() -> dict:
 
 
 def host_context() -> dict:
-    """Best-effort host identity for cross-session trace comparison:
-    nproc always; jax version/backend only if jax is already imported
-    (never force the import — obs stays light)."""
-    import sys
+    """Where a log's process ran: nproc always; jax version and the
+    device (platform / kind / count, as utils.device reports them) only
+    when this process has ALREADY opened a backend.  Never opens one:
+    the supervising parents (campaign, serve pool, bench) stamp their
+    logs through here too, and a parent that touched the device would
+    hold the chip against its own children."""
+    from raft_tla_tpu.utils import device
     ctx: dict = {"nproc": os.cpu_count() or 1}
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            ctx["jax"] = str(jax.__version__)
-            ctx["backend"] = str(jax.default_backend())
-        except Exception:
-            pass
+    if device.backends_initialized():
+        import jax
+        ctx["jax"] = str(jax.__version__)
+        ctx.update(device.device_info())
     return ctx
 
 

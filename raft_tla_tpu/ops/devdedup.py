@@ -3,7 +3,7 @@ engines (``RAFT_TLA_DEVDEDUP`` / ``--device-dedup``).
 
 The DDD loop's remaining structural host dependency (ROADMAP item 5):
 every candidate fingerprint — including within-level duplicates the
-lossy filter evicted and re-sighted — crosses the d2h tunnel to the
+lossy filter evicted and re-sighted — crosses the d2h link to the
 master keyset.  This module is the hot tier of a two-tier dedup: an
 HBM-resident **exact** set of the fingerprints already streamed *this
 level*, applied to each segment's output buffers before export, so only

@@ -785,16 +785,17 @@ def _megakernel_enabled(bounds, symmetry):
     grouping sees one 128-row block instead of the whole chunk), so the
     blocking that helps the pinned program costs the production one
     (RESULTS.md "Megakernel A/B" attributes the loss entirely to the
-    expand phase).  On-chip
-    the bet is HBM-round-trip elimination between the stage fusions vs
-    Mosaic's appetite for the gather/sort-heavy canonicalize+prescan
-    stages (the round-2 hand orbit kernel died there — RESULTS.md
-    "Pallas orbit kernel"); the on-chip A/B is queued, and the gate
-    stays available for it via the override."""
+    expand phase).  On the chip there is nothing to A/B yet: Mosaic
+    refuses the staged step (TPU v5 lite, JAX 0.9.0, chip_smoke.py
+    kernels phase, PR 21) — ``ValueError: Shape mismatch in input,
+    indices and output`` from its gather lowering rule, which takes only
+    take_along_axis-shaped 2-D gathers (ops/pallas_step.py "Mosaic
+    status").  ``on`` there fails loudly at step construction with that
+    message; it never falls back to the XLA step."""
     import os
     force = os.environ.get("RAFT_TLA_MEGAKERNEL", "auto")
-    if force == "on":            # measurement override (runs/megakernel_ab)
-        return True              # and the on-chip re-A/B — not the default
+    if force == "on":            # measurement override (runs/megakernel_ab,
+        return True              # interpreter only) — not the default
     if force == "off":
         return False
     return False

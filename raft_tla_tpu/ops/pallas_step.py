@@ -45,13 +45,20 @@ of named slabs against the ~16 MB/core VMEM budget, leaving Mosaic
 headroom for the scan carries; rows pad up to the block multiple with
 zero rows (sliced off the outputs, so padding never changes a lane).
 
-Mosaic status: off-TPU this module runs under the Pallas interpreter
-(ops/pallas_compat; that is also the CPU A/B + parity-test path).  A
-real Mosaic build of the staged step must contend with the gather/sort
-heavy canonicalize + prescan stages — the round-2 hand-scheduled orbit
-kernel failed Mosaic past P=6 on scoped-vmem (RESULTS.md "Pallas orbit
-kernel") — so the gate ships auto=OFF until an on-chip session measures
-a win (RESULTS.md "Megakernel A/B"; ops/kernels._megakernel_enabled).
+Mosaic status: REFUSED on the chip (chip_smoke.py kernels phase, TPU v5
+lite, JAX 0.9.0 / libtpu 0.0.34, PR 21).  Building the staged flagship step
+with ``interpret=False`` fails in Mosaic's gather lowering
+(``jax/_src/pallas/mosaic/lowering.py:_gather_lowering_rule``):
+``ValueError: Shape mismatch in input, indices and output`` — that rule
+takes only a 2-D ``take_along_axis``-shaped gather whose input, indices and
+output share one shape, and the staged step's permutation-LUT gathers are
+not that.  So this module runs only under the Pallas interpreter
+(ops/pallas_compat; the CPU parity-test path), ``--megakernel on`` on the
+chip exits non-zero with that message (check.py surfaces it, nothing falls
+back), and the gate stays auto=OFF (ops/kernels._megakernel_enabled).
+Whether the kernel is rewritten to Mosaic's gather shape or deleted is a
+later PR's call; ops/pallas_fp.py DOES compile under Mosaic and is
+bit-equal to the jnp fingerprint on the same chip.
 """
 
 from __future__ import annotations

@@ -87,7 +87,7 @@ from raft_tla_tpu.ops import kernels
 from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym_mod
 from raft_tla_tpu.parallel.shard_engine import (
-    _AXIS, _DCN, _mesh_axes, _shard_map, exchange, make_mesh)
+    _AXIS, _DCN, _mesh_axes, exchange, make_mesh)
 from raft_tla_tpu.utils import ckpt
 from raft_tla_tpu.utils import keyset
 from raft_tla_tpu.utils import native
@@ -513,7 +513,7 @@ class DDDShardEngine:
         fn = _build_segment(config, self.caps, self.A, self.lay.width,
                             self.schema, self.ndev, nici, axes)
         self._segment = jax.jit(
-            _shard_map(fn, mesh=self.mesh,
+            jax.shard_map(fn, mesh=self.mesh,
                           in_specs=(fc_specs, buf_specs, dp, dp, dp, dp,
                                     P(), P()),
                           out_specs=(fc_specs, buf_specs, st_specs),
@@ -523,11 +523,11 @@ class DDDShardEngine:
         if self._devdedup:
             dd_specs = devdedup.DevSet(dp, dp, dp)
             self._dd_apply = jax.jit(
-                _shard_map(_dd_filter_shard(self._devdedup),
-                           mesh=self.mesh,
-                           in_specs=(dd_specs, buf_specs, dp, dp),
-                           out_specs=(dd_specs, buf_specs, dp, dp, dp),
-                           check_vma=False),
+                jax.shard_map(_dd_filter_shard(self._devdedup),
+                              mesh=self.mesh,
+                              in_specs=(dd_specs, buf_specs, dp, dp),
+                              out_specs=(dd_specs, buf_specs, dp, dp, dp),
+                              check_vma=False),
                 donate_argnums=(0, 1))
         self._in_shardings = [
             NamedSharding(self.mesh, dp) for _ in range(4)]
@@ -827,6 +827,13 @@ class DDDShardEngine:
             blocks_done = 0
 
         fc = self._init_filter()
+        # run_start.n_devices is a statement about where the carry lives,
+        # so hold it to the placement JAX reports, not to the request.
+        placed = {s.device for s in fc.tbl_hi.addressable_shards}
+        if len(placed) != self.ndev:
+            raise RuntimeError(
+                f"filter carry landed on {len(placed)} distinct device(s) "
+                f"of a {self.ndev}-device mesh: {sorted(map(str, placed))}")
         dst = self._init_devset() if self._dd_apply else None
         export_rows = 0      # rows actually exported d2h (post-filter)
         dd_hits = 0          # rows the per-shard device sets dropped

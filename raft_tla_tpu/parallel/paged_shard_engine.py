@@ -63,7 +63,7 @@ from raft_tla_tpu.ops import kernels
 from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym_mod
 from raft_tla_tpu.parallel.shard_engine import (FAIL_ROUTE, _DCN,
-    _mesh_axes, _shard_map, exchange, make_mesh)
+    _mesh_axes, exchange, make_mesh)
 from raft_tla_tpu.utils import ckpt, native, pacing
 
 I32 = jnp.int32
@@ -374,7 +374,7 @@ class PagedShardEngine:
         fn = _build_segment(config, self.caps, self.A, self.lay.width,
                             self.ndev, self.schema, nici=nici, axes=axes)
         paged_spec = P(axes if len(axes) > 1 else axes[0])
-        self._segment = jax.jit(_shard_map(
+        self._segment = jax.jit(jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(specs, P(), paged_spec),
             out_specs=(P(), specs),

@@ -30,6 +30,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
 import sys
 import threading
 import time
@@ -203,25 +204,43 @@ def main(argv=None) -> int:
     p.add_argument("--quiet", action="store_true")
     args = p.parse_args(argv)
 
-    if args.cpu:
-        import jax
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError:
-            pass
     from raft_tla_tpu.serve.pool import run_pool
-    from raft_tla_tpu.serve.service import run_service
+    from raft_tla_tpu.serve.service import read_results
     from raft_tla_tpu.serve.supervise import PoolPolicy
+    from raft_tla_tpu.utils import device
 
     ref_dir = os.path.join(args.workdir, "ref")
     pool_dir = os.path.join(args.workdir, "pool-out")
     jobs = _toy_jobs(args.cfg, args.jobs, args.max_msgs)
 
-    ref_recs = run_service(jobs, ref_dir, chunk=args.chunk,
-                           quiet=args.quiet)
+    # One process per chip: the solo reference runs through the ordinary
+    # serve CLI in a child that has exited before the pool's workers
+    # start, and this process never opens a backend.
+    os.makedirs(ref_dir, exist_ok=True)
+    manifest = os.path.join(ref_dir, "jobs.jsonl")
+    with open(manifest, "w", encoding="utf-8") as f:
+        for job in jobs:
+            f.write(json.dumps(job.to_dict(), sort_keys=True) + "\n")
+    argv = [sys.executable, "-m", "raft_tla_tpu.serve", manifest,
+            "--out", ref_dir, "--chunk", str(args.chunk)]
+    argv += ["--cpu"] if args.cpu else []
+    argv += ["--quiet"] if args.quiet else []
+    rc = subprocess.run(argv).returncode
+    if rc != 0:
+        print(f"serve-chaos: FAIL — solo reference exited {rc}",
+              file=sys.stderr)
+        return 1
+    ref_recs = read_results(ref_dir)
+    try:
+        dev = device.probe_devices(cpu=args.cpu)
+    except device.DeviceError as e:
+        print(f"Error: {e}", file=sys.stderr)
+        return 1
     chaos = PoolChaos(kill_after_events=args.kill_after_segments)
+    # run_pool refuses more workers than chips (one process per chip)
     run_pool(jobs, pool_dir, workers=args.workers, chunk=args.chunk,
              quiet=args.quiet, cpu=args.cpu,
+             chips=dev["count"] if dev["platform"] == "tpu" else None,
              policy=PoolPolicy(backoff_base_s=0.05, backoff_cap_s=0.2,
                                backoff_jitter_seed=1),
              spawn_hook=chaos.spawn_hook)

@@ -57,6 +57,7 @@ from raft_tla_tpu.serve.service import (_append_records, _events_path,
                                         _reject_events, read_results,
                                         record_is_terminal)
 from raft_tla_tpu.serve.supervise import PoolPolicy, WorkerHealth
+from raft_tla_tpu.utils import device
 
 
 class _PoolJob:
@@ -92,10 +93,11 @@ class _Worker:
     """One live child process + its health view."""
 
     def __init__(self, wid: str, group: _Group, proc, out_path: str,
-                 health: WorkerHealth):
+                 health: WorkerHealth, chip: int | None = None):
         self.wid = wid
         self.group = group
         self.proc = proc
+        self.chip = chip                 # TPU chip it is bound to, if any
         self.out_path = out_path
         self.health = health
         self.preempt: tuple | None = None   # (reason, detail) once signaled
@@ -183,7 +185,7 @@ def _partition(admitted: list, workers: int) -> list:
 
 def run_pool(jobs, out_dir: str, *, workers: int = 2, chunk: int = 1024,
              max_states: int | None = None, quiet: bool = False,
-             depth: int = 2, cpu: bool = False,
+             depth: int = 2, cpu: bool = False, chips: int | None = None,
              policy: PoolPolicy | None = None, spawn_hook=None,
              stop=None, clock=time.time, sleep=time.sleep) -> list:
     """Serve ``jobs`` through the supervised worker pool; returns the
@@ -196,9 +198,20 @@ def run_pool(jobs, out_dir: str, *, workers: int = 2, chunk: int = 1024,
     workers are SIGINTed (they drain losslessly) and undispatched jobs
     get attributed ``stopped`` records.  ``clock``/``sleep`` are
     injectable for tests.
+
+    ``chips`` is the host's TPU chip count (None off-TPU).  A chip
+    serves one process at a time, so at most ``chips`` workers run and
+    each is bound to its own (:func:`~raft_tla_tpu.utils.device.chip_env`);
+    asking for more is refused here rather than left to kill workers at
+    backend init.  This process itself must stay off the device.
     """
     from raft_tla_tpu.serve.jobs import admit
 
+    if chips is not None and workers > chips:
+        raise ValueError(f"{workers} workers need {workers} TPU chips, "
+                         f"this host has {chips}")
+    # With one chip there is nothing to choose between, so no binding.
+    free_chips = list(range(chips)) if chips and chips > 1 else None
     policy = policy or PoolPolicy()
     os.makedirs(out_dir, exist_ok=True)
     pool_dir = os.path.join(out_dir, "pool")
@@ -317,7 +330,8 @@ def run_pool(jobs, out_dir: str, *, workers: int = 2, chunk: int = 1024,
         # pool's supervising process owns the one endpoint over out_dir
         # (it already sees every tenant log the workers write), and a
         # child re-binding the same port would die at startup.
-        child_env = dict(os.environ)
+        chip = free_chips.pop(0) if free_chips is not None else None
+        child_env = device.chip_env(chip)
         child_env.pop(ENV_METRICS, None)
         try:
             proc = subprocess.Popen(argv, stdout=out_f,
@@ -330,7 +344,7 @@ def run_pool(jobs, out_dir: str, *, workers: int = 2, chunk: int = 1024,
             policy, [_events_path(out_dir, pj.job_id) for pj in todo],
             clock=clock)
         health.start(clock())
-        w = _Worker(wid, group, proc, out_path, health)
+        w = _Worker(wid, group, proc, out_path, health, chip)
         active.append(w)
         append_event(pool_events, "worker_spawn", worker=wid,
                      pid=proc.pid, jobs=[pj.job_id for pj in todo],
@@ -382,11 +396,11 @@ def run_pool(jobs, out_dir: str, *, workers: int = 2, chunk: int = 1024,
         """Blame-and-bisect: each suspect takes a death; a lone suspect
         at K deaths is quarantined; survivors one short of K go solo
         (so their K-th death, if it comes, is unambiguous); the rest
-        bisect.  OOM and session-wall arrive here via their own
-        no-blame paths."""
+        bisect.  OOM, session-wall and backend-open deaths arrive here
+        via their own no-blame paths (the respawn budget bounds them)."""
         nonlocal respawns
         K = policy.max_job_deaths
-        blame = kind not in ("session-wall", "oom", "drain")
+        blame = kind not in ("session-wall", "oom", "drain", "backend")
         if blame:
             for pj in suspects:
                 pj.deaths += 1
@@ -430,6 +444,8 @@ def run_pool(jobs, out_dir: str, *, workers: int = 2, chunk: int = 1024,
 
     def reap(w: _Worker, rc: int) -> None:
         active.remove(w)
+        if w.chip is not None:
+            free_chips.append(w.chip)
         if tracer.enabled:
             now_mono = time.monotonic()
             tracer.emit_span("worker", w.t0_mono, now_mono - w.t0_mono,

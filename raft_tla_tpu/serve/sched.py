@@ -34,9 +34,10 @@ into the serving layer:
   lowered+compiled AOT on a background thread, so already-compiled bins
   keep the device fed while a new signature compiles.  The scheduler
   only blocks on a compile when nothing else has work (the device would
-  idle anyway).  ``enable_compile_cache`` wires JAX's persistent
-  compilation cache (``--compile-cache DIR`` / ``RAFT_TLA_COMPILE_CACHE``)
-  so daemon restarts are warm.
+  idle anyway).  ``enable_compile_cache`` places JAX's persistent
+  compilation cache (``JAX_COMPILATION_CACHE_DIR`` from outside, else
+  ``--compile-cache DIR`` / ``RAFT_TLA_COMPILE_CACHE``, else — off the
+  CPU — ``<checkout>/.jax_cache``) so daemon restarts are warm.
 - **Fair-share packing** (deficit round robin): when a bin's live lanes
   oversubscribe the chunk, each dispatch grants every pending lane a
   quantum of ``max(1, B // n_live)`` rows plus any deficit carried from
@@ -64,26 +65,48 @@ from raft_tla_tpu.ops import fingerprint as fpr
 ENV_COMPILE_CACHE = "RAFT_TLA_COMPILE_CACHE"
 
 
-def enable_compile_cache(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``path`` (or the
-    ``RAFT_TLA_COMPILE_CACHE`` env var), so a daemon restart re-serving
-    the same step signatures skips recompilation.  Returns the resolved
-    directory, or None when neither source names one.  Best-effort: the
-    knobs exist on the baked-in jax, but each update is guarded so an
-    older/newer jax degrades to cold compiles instead of failing."""
-    path = path or os.environ.get(ENV_COMPILE_CACHE) or None
+# <checkout>/.jax_cache — fixed, because the directory is part of the
+# cache key's lookup: a temp name, pid or timestamp would never hit.
+_DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache(path: str | None = None, *,
+                         platform: str) -> str | None:
+    """The one place JAX's persistent compilation cache is placed, for
+    every process that compiles (check, serve, pool and campaign
+    children).  Returns the directory in effect, or None for no cache.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: the machine placed the cache from
+    outside — JAX reads the variable itself and this function sets no
+    directory at all, whatever ``path`` or ``RAFT_TLA_COMPILE_CACHE``
+    say.  Unset: ``path`` (``--compile-cache``), else the gate, else
+    ``<checkout>/.jax_cache``.  Either way the min-time / min-size knobs
+    drop to zero so small step programs are cached too (a served toy job
+    is otherwise all compile).
+
+    One exception to the default directory: on the ``cpu`` platform
+    (``platform`` = what utils.device.select_device reported) the cache
+    is on only where somebody placed it.  jaxlib 0.9.0's XLA:CPU loader
+    prints a ~3 KB ``cpu_aot_loader.cc`` line per cached program to
+    stderr on every warm load; always-on, that flood filled the stderr
+    pipe of a piped child and wedged it (tests/test_serve_sched's
+    daemon test), and CPU runs here are correctness runs that have no
+    use for a warm start."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        return outside
+    path = path or os.environ.get(ENV_COMPILE_CACHE) \
+        or (None if platform == "cpu" else _DEFAULT_COMPILE_CACHE)
     if not path:
         return None
-    import jax
     path = os.path.abspath(path)
     os.makedirs(path, exist_ok=True)
-    for knob, val in (("jax_compilation_cache_dir", path),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):
-            pass
+    jax.config.update("jax_compilation_cache_dir", path)
     return path
 
 
@@ -110,7 +133,8 @@ class _BinState:
     and the background compile."""
 
     __slots__ = ("bn", "bufs", "free", "rr", "deficit", "compiled",
-                 "thread", "compile_wall_s", "compiled_async")
+                 "thread", "compile_wall_s", "compiled_async",
+                 "compile_error")
 
     def __init__(self, bn, depth: int, chunk: int):
         self.bn = bn
@@ -123,6 +147,7 @@ class _BinState:
         self.thread: threading.Thread | None = None
         self.compile_wall_s: float | None = None
         self.compiled_async = False
+        self.compile_error: Exception | None = None
 
 
 class DispatchScheduler:
@@ -161,22 +186,32 @@ class DispatchScheduler:
     # -- compile ------------------------------------------------------------
 
     def _compile(self, st: _BinState) -> None:
-        """Lower+compile a bin's fused step AOT (worker thread).  On any
-        lowering/AOT failure, fall back to lazy jit — the compile lands
-        back on the dispatch path but correctness is unchanged."""
+        """Lower+compile a bin's fused step AOT (worker thread).  A
+        failure is carried to the dispatch thread, which stops the bin's
+        lanes with the compiler's message (:meth:`_fail_uncompilable`) —
+        never a quiet retry as a lazy jit on the dispatch path."""
         import jax
         import jax.numpy as jnp
         with self.tracer.span("compile",
                               bin=getattr(st.bn, "tag", "bin")):
             t0 = time.monotonic()
-            fn = jax.jit(st.bn.step_fn)
+            spec = jax.ShapeDtypeStruct((self.chunk, st.bn.lay.width),
+                                        jnp.int32)
             try:
-                spec = jax.ShapeDtypeStruct((self.chunk, st.bn.lay.width),
-                                            jnp.int32)
-                st.compiled = fn.lower(spec).compile()
-            except Exception:
-                st.compiled = fn
+                st.compiled = jax.jit(st.bn.step_fn).lower(spec).compile()
+            except Exception as e:      # thread boundary: reported by
+                st.compile_error = e    # _fail_uncompilable, not lost
             st.compile_wall_s = time.monotonic() - t0
+
+    def _fail_uncompilable(self, order: list, outcomes: dict) -> None:
+        """Stop every live lane of a bin whose step failed to compile,
+        attributed with the compiler's own message; other bins serve on."""
+        for st in order:
+            if st.compile_error is None:
+                continue
+            for lane in st.bn.live_lanes():
+                lane.fail(f"step compile failed: {st.compile_error}")
+                outcomes[lane.job_id] = lane.outcome
 
     def _start_compile(self, st: _BinState) -> None:
         if not self.compile_async:
@@ -375,6 +410,7 @@ class DispatchScheduler:
         order = list(states.values())
         rr = 0
         while True:
+            self._fail_uncompilable(order, outcomes)
             stopping = self._stopping()
             if not stopping:
                 # fill the pipeline, round-robin across bins
@@ -403,6 +439,8 @@ class DispatchScheduler:
             if not waiting:
                 break
             waiting[0].thread.join()
+        self._fail_uncompilable(order, outcomes)   # a compile that failed
+        #                                            after the last sweep
         for st in order:
             if st.compile_wall_s is not None:
                 tag = getattr(st.bn, "tag", str(st.bn.key))

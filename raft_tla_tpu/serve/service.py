@@ -349,7 +349,7 @@ def run_daemon(source: str, out_dir: str, chunk: int = 1024,
                max_states: int | None = None, quiet: bool = False,
                depth: int = 2, poll_s: float = 2.0,
                max_idle_polls: int | None = None, workers: int = 0,
-               cpu: bool = False) -> int:
+               cpu: bool = False, chips: int | None = None) -> int:
     """The long-running front: ``raft-tla-serve QUEUE_DIR --watch``.
 
     Continuous intake atop the one-pass queue-dir code path: every poll
@@ -367,8 +367,9 @@ def run_daemon(source: str, out_dir: str, chunk: int = 1024,
     whose content digest already has a *terminal* record is skipped, not
     re-run — a restarted daemon never re-bills device time for work it
     already finished.  ``workers > 0`` routes every batch through the
-    fault-isolated worker pool (:func:`raft_tla_tpu.serve.pool.run_pool`)
-    instead of executing in-process.
+    fault-isolated worker pool (:func:`raft_tla_tpu.serve.pool.run_pool`,
+    which binds each worker to one of ``chips`` TPU chips) instead of
+    executing in-process.
 
     Stop contract (the campaign supervisor's, reused): the FIRST SIGINT
     stops intake and drains — the executor finishes in-flight dispatches
@@ -482,7 +483,7 @@ def run_daemon(source: str, out_dir: str, chunk: int = 1024,
                     recs = run_pool(batch, out_dir, workers=workers,
                                     chunk=chunk, max_states=max_states,
                                     quiet=quiet, depth=depth, cpu=cpu,
-                                    stop=stop.is_set)
+                                    chips=chips, stop=stop.is_set)
                 else:
                     recs = run_service(batch, out_dir, chunk=chunk,
                                        max_states=max_states, quiet=quiet,
@@ -586,26 +587,44 @@ def build_argparser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    args = build_argparser().parse_args(argv)
+    parser = build_argparser()
+    args = parser.parse_args(argv)
     if args.trace:
         # Process-wide so pool worker children (plain serve CLIs spawned
         # with the inherited environment) trace too — the gate pattern
         # every RAFT_TLA_* knob follows.
         from raft_tla_tpu.obs.trace import ENV_TRACE
         os.environ[ENV_TRACE] = "1"
-    if args.cpu:
-        import jax
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError:
-            if jax.default_backend() != "cpu":
-                print("Warning: --cpu requested but JAX backends are "
-                      f"already initialized on {jax.default_backend()!r}; "
-                      "proceeding there", file=sys.stderr)
-    from raft_tla_tpu.serve.sched import enable_compile_cache
-    cache_dir = enable_compile_cache(args.compile_cache)
-    if cache_dir and not args.quiet:
-        print(f"compile cache: {cache_dir}")
+    from raft_tla_tpu.serve.sched import ENV_COMPILE_CACHE, \
+        enable_compile_cache
+    from raft_tla_tpu.utils import device
+    if args.compile_cache:
+        os.environ[ENV_COMPILE_CACHE] = args.compile_cache   # workers too
+    chips = None   # TPU chips to bind pool workers to (None: CPU)
+    try:
+        if args.workers:
+            # One process per chip: the supervising front never opens
+            # the device its workers need — a child that has exited
+            # counts the chips, and each worker is bound to its own.
+            dev = device.probe_devices(cpu=args.cpu)
+            if dev["platform"] == "tpu":
+                chips = dev["count"]
+        else:
+            dev = device.select_device(args.cpu)
+    except device.DeviceError as e:
+        print(f"Error: {e}", file=sys.stderr)
+        return 1
+    if chips is not None and args.workers > chips:
+        parser.error(f"--workers {args.workers} exceeds the "
+                     f"{chips} TPU chip(s) of this host: a chip "
+                     "serves one process at a time, so the extra workers "
+                     "could only die opening it")
+    if not args.quiet:
+        print(f"device: {device.describe(dev)}")
+    if not args.workers:
+        cache_dir = enable_compile_cache(platform=dev["platform"])
+        if cache_dir and not args.quiet:
+            print(f"compile cache: {cache_dir}")
     from raft_tla_tpu.obs.metrics import metrics_port
     mport = metrics_port(args.metrics_port)
     mserver = None
@@ -621,19 +640,20 @@ def main(argv=None) -> int:
             snapshot_path=os.path.join(args.out, "metrics.events"))
         print(f"metrics endpoint: {mserver.url}", flush=True)
     try:
-        return _run_front(args)
+        return _run_front(args, chips)
     finally:
         if mserver is not None:
             mserver.close()
 
 
-def _run_front(args) -> int:
+def _run_front(args, chips: int | None) -> int:
     if args.watch:
         return run_daemon(args.source, args.out, chunk=args.chunk,
                           max_states=args.max_states, quiet=args.quiet,
                           depth=args.depth, poll_s=args.poll,
                           max_idle_polls=args.max_idle_polls,
-                          workers=args.workers, cpu=args.cpu)
+                          workers=args.workers, cpu=args.cpu,
+                          chips=chips)
     skipped: list = []
     try:
         jobs = load_jobs(args.source, skipped=skipped)
@@ -668,7 +688,7 @@ def _run_front(args) -> int:
         records = run_pool(jobs, args.out, workers=args.workers,
                            chunk=args.chunk, max_states=args.max_states,
                            quiet=args.quiet, depth=args.depth,
-                           cpu=args.cpu, stop=stop)
+                           cpu=args.cpu, chips=chips, stop=stop)
     else:
         records = run_service(jobs, args.out, chunk=args.chunk,
                               max_states=args.max_states, quiet=args.quiet,

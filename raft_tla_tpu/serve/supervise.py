@@ -10,9 +10,10 @@ free of process trees so every rule is unit-testable:
   halving.
 - :func:`classify_death` — map a worker's exit (returncode + captured
   stderr/stdout tail) to a death kind: ``oom`` / ``killed`` /
-  ``segfault`` / ``signal`` / ``crashed``.  The kind picks the recovery
-  path: OOM degrades (respawn at half dispatch width), everything else
-  blames the worker's unfinished jobs and bisects toward the poison.
+  ``segfault`` / ``signal`` / ``crashed`` / ``backend``.  The kind picks
+  the recovery path: OOM degrades (respawn at half dispatch width), a
+  backend-open failure respawns with no blame, everything else blames
+  the worker's unfinished jobs and bisects toward the poison.
 - :class:`WorkerHealth` — one worker's liveness view, built from the
   campaign supervisor's pieces verbatim: a
   :class:`~raft_tla_tpu.campaign.supervisor._LogTail` per assigned
@@ -32,6 +33,7 @@ import time
 
 from raft_tla_tpu.campaign.supervisor import (CampaignPolicy,
                                               HealthMonitor, _LogTail)
+from raft_tla_tpu.utils.device import UNAVAILABLE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,19 +74,31 @@ _OOM_MARKERS = ("MemoryError", "RESOURCE_EXHAUSTED", "Out of memory",
                 "out of memory", "std::bad_alloc")
 
 
+# A worker that could not open its JAX backend (chip held by another
+# process, no accelerator) died of its environment before touching a
+# job: the serve CLI prints utils.device's DeviceError line, and a raw
+# JAX start-up failure carries the second marker.
+_BACKEND_MARKERS = (UNAVAILABLE, "Unable to initialize backend")
+
+
 def classify_death(returncode: int, out_text: str = "") -> tuple:
     """``(kind, detail)`` for a worker that exited abnormally.
 
-    ``kind`` is one of ``oom`` (degrade: respawn at half width),
-    ``killed`` (SIGKILL — external killer or the host OOM reaper),
-    ``segfault``, ``signal`` (any other fatal signal), or ``crashed``
-    (nonzero exit with no better evidence).  The output scan wins over
-    the returncode: an uncaught MemoryError exits 1, a TPU
-    RESOURCE_EXHAUSTED aborts on a signal — both are OOM for recovery
-    purposes (blaming a job for the pool's own memory pressure would
-    quarantine innocents).
+    ``kind`` is one of ``backend`` (the worker never opened its device —
+    an environment death: respawn, blame no job), ``oom`` (degrade:
+    respawn at half width), ``killed`` (SIGKILL — external killer or the
+    host OOM reaper), ``segfault``, ``signal`` (any other fatal signal),
+    or ``crashed`` (nonzero exit with no better evidence).  The output
+    scan wins over the returncode: an uncaught MemoryError exits 1, a
+    TPU RESOURCE_EXHAUSTED aborts on a signal — both are OOM for
+    recovery purposes (blaming a job for the pool's own memory pressure
+    would quarantine innocents), and a backend-open failure exits 1 like
+    any crash would.
     """
     text = out_text or ""
+    if any(m in text for m in _BACKEND_MARKERS):
+        return ("backend", "worker output shows it could not open its "
+                           "JAX backend")
     if any(m in text for m in _OOM_MARKERS):
         return ("oom", "worker output shows an out-of-memory failure")
     if returncode < 0:

@@ -2,9 +2,12 @@
 
 Builds the shared library on first use with the baked-in g++ (no pybind11 in
 the image — SURVEY §2.8 note; plain C ABI + ctypes instead).  Every entry
-point has a NumPy fallback twin so the checker runs — more slowly and
-host-RAM-hungry — even where a toolchain is missing; ``HAS_NATIVE`` reports
-which implementation is live, and tests assert the two agree.
+point has a NumPy twin that tests assert agrees with it, but the twins are
+references, not a fallback: a failed build is an error wherever the native
+path was expected (``make_store``, ``scc_csr``, ``fingerprint_rows``), with
+the compiler's own message — a campaign must not quietly run on the slower,
+host-RAM-hungry store.  A caller that wants the NumPy store asks for it by
+name (``PyHostStore``).  ``HAS_NATIVE`` reports which implementation is live.
 """
 
 from __future__ import annotations
@@ -13,7 +16,6 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import sys
 import tempfile
 
 import numpy as np
@@ -29,19 +31,12 @@ _u32p = ctypes.POINTER(ctypes.c_uint32)
 _i64p = ctypes.POINTER(ctypes.c_int64)
 
 
-def _build() -> str | None:
+def _build() -> str:
     # The library file is named by the source hash: freshness is content-
     # based (mtimes lie after a fresh clone), and concurrent builders race
     # benignly — both produce identical bytes and the os.replace is atomic.
-    try:
-        with open(_SRC, "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    except OSError as e:
-        # wheel installs ship the package without the sibling native/
-        # tree — the NumPy fallback serves them (same results, slower)
-        print(f"native source unavailable ({e}); using NumPy fallback",
-              file=sys.stderr)
-        return None
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     lib = os.path.join(_LIB_DIR, f"libraft_host-{digest}.so")
     if os.path.exists(lib):
         return lib
@@ -50,24 +45,19 @@ def _build() -> str | None:
     os.close(fd)
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        subprocess.run(cmd, check=True, capture_output=True, text=True,
+                       timeout=120)
         os.replace(tmp, lib)
-    except (OSError, subprocess.SubprocessError) as e:
-        print(f"native build failed ({e}); using NumPy fallback",
-              file=sys.stderr)
-        try:
+    except subprocess.CalledProcessError as e:
+        raise OSError(f"{' '.join(cmd)} failed:\n{e.stderr}") from e
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return None
     return lib
 
 
 def _load():
-    path = _build()
-    if path is None:
-        return None
-    lib = ctypes.CDLL(path)
+    lib = ctypes.CDLL(_build())
     lib.store_create.restype = ctypes.c_void_p
     lib.store_create.argtypes = [ctypes.c_int32]
     lib.store_destroy.argtypes = [ctypes.c_void_p]
@@ -93,8 +83,27 @@ def _load():
     return lib
 
 
-_lib = _load()
+# Importing this module never fails on a missing toolchain (PyHostStore
+# and FileStore need none); the build error is kept and raised where the
+# native path is actually called for.
+try:
+    _lib, _BUILD_ERROR = _load(), None
+except (OSError, subprocess.SubprocessError) as _e:
+    _lib, _BUILD_ERROR = None, _e
 HAS_NATIVE = _lib is not None
+
+
+def _want_native() -> bool:
+    """True: take the C++ path.  False: the caller cleared ``HAS_NATIVE``
+    to get the NumPy twin on purpose.  A build that FAILED is neither —
+    it raises, so nothing gives way quietly."""
+    if HAS_NATIVE:
+        return True
+    if _BUILD_ERROR is not None:
+        raise RuntimeError(
+            "native host runtime unavailable (native/host_store.cc did "
+            f"not build): {_BUILD_ERROR}") from _BUILD_ERROR
+    return False
 
 
 def _as_i32(a: np.ndarray) -> np.ndarray:
@@ -109,7 +118,7 @@ class HostStore:
     """Append-only host store of packed state rows + trace links.
 
     The TLC ``states/`` analog (SURVEY §2.8): discovery-indexed, append-only,
-    host-RAM resident.  C++-backed when the toolchain is available.
+    host-RAM resident.  C++-backed.
 
     Safe for ONE appender thread plus concurrent readers of disjoint,
     already-published ranges: the C++ side publishes new rows through an
@@ -120,6 +129,7 @@ class HostStore:
     """
 
     def __init__(self, width: int):
+        _want_native()
         self.width = int(width)
         self._h = _lib.store_create(self.width)
         self._n_links = 0
@@ -284,8 +294,9 @@ class PyHostStore:
 
 
 def make_store(width: int):
-    """The C++ store when available, the NumPy twin otherwise."""
-    return HostStore(width) if HAS_NATIVE else PyHostStore(width)
+    """The C++ store; raises if its build failed (ask for
+    ``PyHostStore`` by name to run on the NumPy twin)."""
+    return HostStore(width) if _want_native() else PyHostStore(width)
 
 
 class FileStore:
@@ -502,14 +513,14 @@ class LevelStore:
 
 def scc_csr(indptr: np.ndarray, dst: np.ndarray) -> tuple:
     """Strongly connected components of a CSR digraph: returns
-    ``(comp_id[int64 n], n_comps)``.  C++ iterative Tarjan when the
-    native library is available; NumPy-assisted iterative Tarjan in
-    Python otherwise (same ids-in-completion-order contract)."""
+    ``(comp_id[int64 n], n_comps)``.  C++ iterative Tarjan; the NumPy-
+    assisted iterative Tarjan below is its reference twin (same ids-in-
+    completion-order contract), reached by clearing ``HAS_NATIVE``."""
     indptr = _as_i64(indptr)
     dst = _as_i64(dst)
     n = indptr.shape[0] - 1
     comp = np.empty(n, np.int64)
-    if HAS_NATIVE:
+    if _want_native():
         ncomp = _lib.scc_tarjan(n, indptr.ctypes.data_as(_i64p),
                                 dst.ctypes.data_as(_i64p),
                                 comp.ctypes.data_as(_i64p))
@@ -560,14 +571,12 @@ def scc_csr(indptr: np.ndarray, dst: np.ndarray) -> tuple:
 
 
 def fingerprint_rows(rows: np.ndarray) -> tuple:
-    """Bit-identical host fingerprint of packed rows via the C++ path.
-
-    Falls back to the NumPy reference implementation (the definition site,
-    ops/fingerprint.py) when no toolchain is available.
-    """
+    """Bit-identical host fingerprint of packed rows via the C++ path
+    (the NumPy reference, ops/fingerprint.py, when a caller cleared
+    ``HAS_NATIVE``; a failed build raises)."""
     rows = _as_i32(rows)
     rows2d = rows.reshape(-1, rows.shape[-1])
-    if not HAS_NATIVE:
+    if not _want_native():
         return fpr.fingerprint(rows2d, fpr.lane_constants(rows2d.shape[-1]),
                                np)
     consts = np.ascontiguousarray(fpr.lane_constants(rows2d.shape[-1]))

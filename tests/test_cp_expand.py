@@ -20,7 +20,7 @@ from raft_tla_tpu.models import interp, spec as SP
 from raft_tla_tpu.ops import kernels
 from raft_tla_tpu.parallel.cp_expand import (
     build_cp_step, cp_lane_count, cp_lane_map)
-from raft_tla_tpu.parallel.shard_engine import make_mesh, _AXIS, _shard_map
+from raft_tla_tpu.parallel.shard_engine import make_mesh, _AXIS
 
 from test_state import random_pystate
 
@@ -47,7 +47,7 @@ def _run_cp(bounds, spec, invs, sym, vecs, ndev):
     def shard_fn(v):
         return step(v, jax.lax.axis_index(_AXIS))
 
-    out = jax.jit(_shard_map(
+    out = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh, in_specs=P(), out_specs=P(_AXIS)))(vecs)
     return {k: np.asarray(v) for k, v in out.items()}
 

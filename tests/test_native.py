@@ -223,3 +223,30 @@ def test_scc_csr_native_matches_python_fallback():
         assert len(pairs) == nc_n
         assert len({a for a, _ in pairs}) == nc_n
         assert len({b for _, b in pairs}) == nc_n
+
+
+def test_failed_native_build_is_an_error_not_a_quiet_numpy_fallback(
+        monkeypatch):
+    """A build that failed raises where the native path is called for,
+    with the compiler's message; the NumPy store still serves a caller
+    who asks for it by name, and clearing HAS_NATIVE (no build error)
+    still selects the reference twins on purpose."""
+    import numpy as np
+
+    from raft_tla_tpu.utils import native
+
+    monkeypatch.setattr(native, "HAS_NATIVE", False)
+    monkeypatch.setattr(native, "_BUILD_ERROR",
+                        OSError("g++ ... failed:\nhost_store.cc:1: boom"))
+    for call in (lambda: native.make_store(3),
+                 lambda: native.HostStore(3),
+                 lambda: native.fingerprint_rows(np.zeros((2, 3), np.int32)),
+                 lambda: native.scc_csr(np.zeros(2, np.int64),
+                                        np.zeros(0, np.int64))):
+        with pytest.raises(RuntimeError, match=r"(?s)did not build.*boom"):
+            call()
+    store = native.PyHostStore(3)            # asked for by name: fine
+    store.append(np.ones((2, 3), np.int32))
+    assert len(store) == 2
+    monkeypatch.setattr(native, "_BUILD_ERROR", None)
+    assert isinstance(native.make_store(3), native.PyHostStore)

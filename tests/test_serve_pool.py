@@ -64,6 +64,12 @@ def test_classify_death_kinds():
     assert classify_death(1, "MemoryError: ...")[0] == "oom"
     assert classify_death(-6, "RESOURCE_EXHAUSTED: hbm")[0] == "oom"
     assert classify_death(134, "std::bad_alloc")[0] == "oom"
+    # a worker that never opened its device died of its environment
+    assert classify_death(
+        1, "Error: device unavailable: no TPU found")[0] == "backend"
+    assert classify_death(
+        1, "RuntimeError: Unable to initialize backend 'tpu': "
+           "UNAVAILABLE: TPU is already in use")[0] == "backend"
 
 
 def test_partition_keeps_bins_together_and_splits_when_needed(tmp_path):
@@ -293,6 +299,53 @@ def test_pool_oom_respawns_with_halved_chunk(tmp_path, monkeypatch):
     assert not [e for e in pool_events if e["event"] == "quarantine"]
     retries = [e for e in pool_events if e["event"] == "job_retry"]
     assert retries and all(e["reason"] == "oom" for e in retries)
+
+
+def test_pool_backend_death_blames_no_job(tmp_path, monkeypatch):
+    """Workers that die opening their backend (here for real: the
+    children are told to use a TPU this sandbox does not have) are an
+    environment failure.  max_job_deaths=1 proves no job was blamed —
+    one blamed death would write a terminal quarantined/poison-job
+    record for a healthy job, which is a wrong answer."""
+    cfg = write_cfg(tmp_path / "toy.cfg")
+    jobs = _jobs(cfg, ["healthy"], alternate=False)
+    out = str(tmp_path / "out")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")       # inherited by workers
+    recs = run_pool(jobs, out, workers=1, chunk=256, quiet=True,
+                    policy=PoolPolicy(poll_s=0.02, backoff_base_s=0.02,
+                                      backoff_cap_s=0.05,
+                                      backoff_jitter_seed=7,
+                                      max_job_deaths=1, max_respawns=1))
+    assert recs[0]["status"] == "stopped"
+    assert "pool gave up" in recs[0]["error"]
+    assert "backend" in recs[0]["error"]
+    assert not record_is_terminal(recs[0])           # a restart may retry
+    pool_events = [json.loads(l) for l in open(os.path.join(
+        out, "pool.events"))]
+    lost = [e for e in pool_events if e["event"] == "worker_lost"]
+    assert lost and all(e["kind"] == "backend" for e in lost)
+    assert not [e for e in pool_events if e["event"] == "quarantine"]
+
+
+def test_more_workers_than_chips_is_refused(tmp_path, monkeypatch, capsys):
+    """A chip serves one process: asking for more workers than chips is
+    a loud error, from the library call and from the CLI (whose parent
+    learns the count from a probe child, never from its own backend)."""
+    cfg = write_cfg(tmp_path / "toy.cfg")
+    with pytest.raises(ValueError, match="3 workers need 3 TPU chips"):
+        run_pool(_jobs(cfg, ["a"]), str(tmp_path / "o"), workers=3,
+                 chips=2)
+    from raft_tla_tpu.serve import service
+    from raft_tla_tpu.utils import device
+    monkeypatch.setattr(device, "probe_devices", lambda cpu=False: {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    manifest = tmp_path / "m.jsonl"
+    manifest.write_text(json.dumps({"id": "a", "cfg": str(cfg)}) + "\n")
+    with pytest.raises(SystemExit) as e:
+        service.main([str(manifest), "--out", str(tmp_path / "o2"),
+                      "--workers", "2"])
+    assert e.value.code == 2
+    assert "exceeds the 1 TPU chip(s)" in capsys.readouterr().err
 
 
 def test_pool_drain_attributes_undispatched_jobs(tmp_path):

@@ -144,6 +144,88 @@ def test_executor_parity_vs_solo_all_depths():
             assert dict(got.coverage) == dict(ref.coverage), (depth, jid)
 
 
+def test_aot_compile_failure_stops_the_bin_with_the_compilers_message(
+        monkeypatch):
+    """An AOT compile failure used to degrade to a lazy jit on the
+    dispatch path; now it surfaces: the bin's lanes stop, attributed
+    with the compiler's own words, and the other bins serve on."""
+    from raft_tla_tpu.serve import batch
+    orig = batch._Bin.__init__
+
+    def init(self, key, config, tag="bin"):
+        orig(self, key, config, tag)
+        if config.symmetry:
+            def refuse(vecs):
+                raise ValueError("mosaic says no")
+            self.step_fn = refuse
+    monkeypatch.setattr(batch._Bin, "__init__", init)
+    out = BatchExecutor(chunk=128).run([("a", TOY_M1), ("s", TOY_M1S)])
+    assert out["a"].status == "completed" and out["a"].result.n_states == 524
+    assert out["s"].status == "stopped"
+    assert "step compile failed" in out["s"].error
+    assert "mosaic says no" in out["s"].error
+
+
+_CACHE_PROBE = """
+import json, os, sys
+import jax
+touched = []
+_update = jax.config.update
+def spy(name, value):
+    touched.append(name)
+    return _update(name, value)
+jax.config.update = spy
+from raft_tla_tpu.serve.sched import enable_compile_cache
+got = enable_compile_cache(sys.argv[1] or None, platform=sys.argv[2])
+print(json.dumps({"returned": got, "config": jax.config.jax_compilation_cache_dir,
+                  "touched": touched,
+                  "min_s": jax.config.jax_persistent_cache_min_compile_time_secs}))
+"""
+
+
+def _cache_probe(flag, platform="tpu", **env):
+    full = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR",
+                         "RAFT_TLA_COMPILE_CACHE")}
+    full.update(env)
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE, flag, platform],
+                       cwd=REPO, env=full, capture_output=True, text=True,
+                       timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself; the flag and
+    the gate are ignored and NO code path updates the directory."""
+    outside, flag, gate = (str(tmp_path / n) for n in ("x", "flag", "gate"))
+    got = _cache_probe(flag, JAX_COMPILATION_CACHE_DIR=outside,
+                       RAFT_TLA_COMPILE_CACHE=gate)
+    assert got["returned"] == got["config"] == outside
+    assert "jax_compilation_cache_dir" not in got["touched"]
+    assert got["min_s"] == 0.0           # small programs still cached
+    assert not os.path.exists(flag) and not os.path.exists(gate)
+
+
+def test_compile_cache_default_is_the_fixed_checkout_directory(tmp_path):
+    """Unset: the flag if given, else the gate, else <checkout>/.jax_cache
+    — fixed, never a temp name — on an accelerator.  (That a second
+    process then reuses the entries is asserted by
+    tests/test_zz_chip_smoke.py's warm phase.)"""
+    fixed = os.path.join(REPO, ".jax_cache")
+    got = _cache_probe("")
+    assert got["returned"] == got["config"] == fixed
+    flag, gate = str(tmp_path / "flag"), str(tmp_path / "gate")
+    assert _cache_probe("", RAFT_TLA_COMPILE_CACHE=gate)["config"] == gate
+    assert _cache_probe(flag, RAFT_TLA_COMPILE_CACHE=gate)["config"] == flag
+    # the one exception: on the CPU the default directory is not used
+    # (jaxlib's XLA:CPU loader floods stderr on warm loads), only a
+    # placed one
+    got = _cache_probe("", platform="cpu")
+    assert got["returned"] is None and got["config"] is None
+    assert _cache_probe(flag, platform="cpu")["config"] == flag
+
+
 def test_depth_validation():
     with pytest.raises(ValueError, match="depth"):
         DispatchScheduler(chunk=64, depth=0)
