@@ -1,0 +1,100 @@
+"""The controls: one stated guarantee broken underneath the timed path, from
+outside the program (nothing of raft_tla_tpu is edited; these patch it in the
+benchmark's own process).  ``correct`` has to come out false under each:
+benchmark/control.py shows it on the chip at a cell's own size,
+benchmark/selftest.py at toy size.
+"""
+
+from __future__ import annotations
+
+import contextlib
+
+
+@contextlib.contextmanager
+def short_keys(bits: int = 32):
+    """Guarantee broken: exact dedup on the 64-bit key.  Every step program
+    built inside emits keys cut to their low ``bits`` bits."""
+    import jax.numpy as jnp
+    from raft_tla_tpu.ops import kernels
+    real = kernels.build_step
+
+    def build_step(*args, **kw):
+        step = real(*args, **kw)
+        lo_mask = jnp.uint32((1 << min(bits, 32)) - 1)
+        hi_mask = jnp.uint32((1 << max(bits - 32, 0)) - 1)
+
+        def cut(vecs):
+            out = dict(step(vecs))
+            out["fp_hi"] = out["fp_hi"] & hi_mask
+            out["fp_lo"] = out["fp_lo"] & lo_mask
+            return out
+        return cut
+
+    kernels.build_step = build_step
+    try:
+        yield
+    finally:
+        kernels.build_step = real
+
+
+class _AdmitAll:
+    """A host key set that forgets: every streamed candidate is 'new', so
+    the only dedup left is the device's lossy filter."""
+
+    def __init__(self):
+        self.n = 0
+
+    def __len__(self):
+        return self.n
+
+    def seed(self, key):
+        self.n += 1
+
+    def dedup(self, keys):
+        import numpy as np
+        self.n += int(keys.size)
+        return np.arange(keys.size, dtype=np.int64)
+
+
+@contextlib.contextmanager
+def filter_only_dedup():
+    """Guarantee broken: the device filter only advises.  The exact host
+    key set is replaced by one that admits everything it is shown."""
+    from raft_tla_tpu.utils import keyset
+    real = keyset.new_master
+    keyset.new_master = lambda *a, **kw: _AdmitAll()
+    try:
+        yield
+    finally:
+        keyset.new_master = real
+
+
+@contextlib.contextmanager
+def invariants_off():
+    """Guarantee broken: every invariant evaluated on every admitted orbit.
+    Every step program built inside says that every invariant holds (the
+    change that would tempt a later PR: the invariant pass is work that no
+    sound run's counts depend on)."""
+    import jax.numpy as jnp
+    from raft_tla_tpu.ops import kernels
+    real = kernels.build_step
+
+    def build_step(*args, **kw):
+        step = real(*args, **kw)
+
+        def blind(vecs):
+            out = dict(step(vecs))
+            out["inv_ok"] = jnp.ones_like(out["inv_ok"])
+            return out
+        return blind
+
+    kernels.build_step = build_step
+    try:
+        yield
+    finally:
+        kernels.build_step = real
+
+
+CONTROLS = {"key32": lambda: short_keys(32),
+            "filter_only": filter_only_dedup,
+            "invariants_off": invariants_off}

@@ -1,0 +1,163 @@
+"""What decides ``correct``.  Every number compared is printed beside its
+limit; all limits are 0, the comparisons are exact (counts, and states
+compared as states — the reference never sees a fingerprint)."""
+
+from __future__ import annotations
+
+import random
+
+from benchmark.reference import canon, interp, invariants
+from benchmark.reference import spec as S
+from benchmark.reference.bounds import Bounds
+
+SAMPLE = 256             # parents drawn with --seed
+MIN_LEVEL_STATES = 4096  # the reference BFS stops at the first such level
+#                          (a configuration may say otherwise: the toy does)
+
+
+# the parity-mode state: what crosses between the program's PyState and the
+# reference's (two classes of the same shape, by design unrelated)
+STATE_FIELDS = ("role", "term", "votedFor", "commitIndex", "log", "vResp",
+                "vGrant", "nextIndex", "matchIndex", "msgs")
+
+
+def _ref_state(s):
+    return interp.PyState(**{f: getattr(s, f) for f in STATE_FIELDS})
+
+
+def pass_checks(pass_list: list, pins: list, end_level: int) -> list:
+    """(a) every pass's cumulative count at every level 0..B equals the
+    pins and all passes agree; (b) no violation."""
+    want = pins[:end_level + 1]
+    mismatched = sum(
+        sum(a != b for a, b in zip(p.levels, want))
+        + abs(len(p.levels) - len(want)) for p in pass_list)
+    tables = {tuple(p.levels) for p in pass_list}
+    return [
+        ("pass_level_mismatches", mismatched, 0),
+        ("pass_tables_distinct_minus_1", len(tables) - 1, 0),
+        ("passes_short_of_B", sum(not p.reached for p in pass_list), 0),
+        ("violations", sum(p.violation is not None for p in pass_list), 0),
+    ]
+
+
+def reference_sample(cfg: dict, seed: int):
+    """The plain reference's own BFS of the first levels, and the seeded
+    sample of its deepest level with that sample's successor orbits."""
+    bounds = Bounds(**cfg["bounds"])
+    sym = bool(cfg["symmetry"])
+    if sym and cfg["symmetry"] != ["Server"]:
+        raise ValueError("the reference reduces over Server only")
+    cum, level, viol = canon.bfs_levels(
+        bounds, cfg["spec"], sym, tuple(cfg["invariants"]),
+        cfg.get("sample_min_level_states", MIN_LEVEL_STATES))
+    rng = random.Random(seed)
+    parents = rng.sample(level, min(SAMPLE, len(level)))
+    reps, n_trans, con = canon.successor_orbits(parents, bounds, cfg["spec"],
+                                                sym)
+    return {"cumulative": cum, "violations": viol, "level": level,
+            "parents": parents, "orbits": reps, "n_transitions": n_trans, "constraint": con,
+            "sym": sym}
+
+
+def sample_checks(ref: dict, got: dict, pins: list) -> list:
+    """(c) the compiled segment's stream for the sampled parents against the
+    reference: the same set of successor orbits (states canonicalised in
+    plain Python), key and orbit in one-to-one correspondence (the 64-bit
+    key is exact on the sample), the same transition count and constraint
+    flags."""
+    key = canon.canonical if ref["sym"] else canon.as_tuple
+    streamed = [key(_ref_state(s)) for s in got["states"]]
+    sset = set(streamed)
+    con_wrong = sum(ref["constraint"].get(k) is not c
+                    for k, c in zip(streamed, got["con"]) if k in ref["orbits"])
+    # the stream may repeat a key (the device filter is lossy; the host
+    # dedups exactly), but key and orbit must name each other one to one
+    by_key, by_orbit = {}, {}
+    for k, o in zip(got["keys"].tolist(), streamed):
+        by_key.setdefault(k, set()).add(o)
+        by_orbit.setdefault(o, set()).add(k)
+    conflicts = sum(len(v) > 1 for v in by_key.values()) \
+        + sum(len(v) > 1 for v in by_orbit.values())
+    cum = ref["cumulative"]
+    return [
+        ("ref_bfs_level_mismatches",
+         sum(a != b for a, b in zip(cum, pins)) + max(0, len(cum) - len(pins)),
+         0),
+        ("ref_bfs_violations", ref["violations"], 0),
+        ("sample_orbits_missing", len(ref["orbits"] - sset), 0),
+        ("sample_orbits_extra", len(sset - ref["orbits"]), 0),
+        ("sample_key_orbit_conflicts", conflicts, 0),
+        ("sample_transitions_diff",
+         abs(got["n_transitions"] - ref["n_transitions"]), 0),
+        ("sample_constraint_flags_wrong", con_wrong, 0),
+        ("sample_segment_flags", int(got["fail"] != 0)
+         + int(not got["done"]), 0),
+    ]
+
+
+def planted_fault(cfg: dict, level: list, seed: int) -> dict:
+    """The planted fault: a state of the reference's level, drawn with the
+    seed, rewritten so that server i leads term t and server j is a
+    candidate of term t holding a quorum of votes.  It holds every invariant
+    itself; its ``BecomeLeader(j)`` successor has two leaders in one term.
+    Returns the parent and ``{orbit of a violating successor: names of the
+    invariants it breaks}``, both judged by the plain reference."""
+    bounds = Bounds(**cfg["bounds"])
+    n = bounds.n_servers
+    invs = {nm: invariants.REGISTRY[nm] for nm in cfg["invariants"]}
+    key = canon.canonical if cfg["symmetry"] else canon.as_tuple
+    table = S.action_table(bounds, cfg["spec"])
+    rng = random.Random(f"plant/{seed}")
+    for s in rng.sample(level, len(level)):
+        i, j = rng.sample(range(n), 2)
+        t = max(s.term)
+        votes = 1 << j
+        for k in rng.sample([k for k in range(n) if k != j], n // 2):
+            votes |= 1 << k
+        role = tuple(S.LEADER if k == i else S.CANDIDATE if k == j
+                     else S.FOLLOWER if (r == S.LEADER and s.term[k] == t)
+                     else r for k, r in enumerate(s.role))
+        term = tuple(t if k in (i, j) else x for k, x in enumerate(s.term))
+        parent = s._replace(
+            role=role, term=term,
+            votedFor=tuple(j + 1 if k == j else v
+                           for k, v in enumerate(s.votedFor)),
+            vResp=tuple(votes if k == j else v
+                        for k, v in enumerate(s.vResp)),
+            vGrant=tuple(votes if k == j else v
+                         for k, v in enumerate(s.vGrant)))
+        if not interp.constraint_ok(parent, bounds) \
+                or not all(f(parent, bounds) for f in invs.values()):
+            continue
+        violators = {}
+        for _a, nxt in interp.successors(parent, bounds, table):
+            broken = [nm for nm, f in invs.items() if not f(nxt, bounds)]
+            if broken:
+                violators[key(nxt)] = broken
+        if violators:
+            return {"parent": parent, "violators": violators, "key": key}
+    raise ValueError("no state of the reference level takes the planted "
+                     "fault; the configuration lists no invariant it breaks")
+
+
+def planted_checks(plant: dict, got: dict) -> list:
+    """(d) every invariant is evaluated by the compiled segment: run from
+    the planted parent, the engine has to report a violation, and the state
+    and invariant it names have to be among the reference's."""
+    missed = got["invariant"] is None
+    wrong = 0
+    if not missed:
+        names = plant["violators"].get(plant["key"](_ref_state(got["state"])))
+        wrong = int(names is None or got["invariant"] not in names)
+    return [("planted_violation_missed", int(missed), 0),
+            ("planted_violation_misnamed", wrong, 0)]
+
+
+def decide(checks: list, out=print) -> bool:
+    ok = True
+    for name, value, limit in checks:
+        good = value <= limit
+        ok = ok and good
+        out(f"check {name}={value} limit={limit} {'ok' if good else 'FAIL'}")
+    return ok

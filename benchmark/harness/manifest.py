@@ -1,0 +1,159 @@
+"""BENCHMARK.json and the files it names: everything a cell is made of is
+found by name, so a later PR adds a cell, a configuration, a traffic mix or a
+per-layer metric as new files plus new manifest entries, and edits nothing."""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import re
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+BENCH = os.path.join(ROOT, "benchmark")
+# everything a run writes (level files, events, traces) goes here; the JAX
+# compile cache sits beside it at the program's own <checkout>/.jax_cache
+SCRATCH = os.path.join(ROOT, ".bench_scratch")
+
+_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+_UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+_SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+
+def load(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def read_json(*parts: str) -> dict:
+    with open(os.path.join(BENCH, *parts), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def cell(manifest: dict, workload: str) -> dict:
+    """The cell's entry with its configuration and traffic files read in."""
+    hits = [w for w in manifest["workloads"] if w["name"] == workload]
+    if not hits:
+        known = ", ".join(w["name"] for w in manifest["workloads"])
+        raise KeyError(f"unknown workload {workload!r} (known: {known})")
+    w = dict(hits[0])
+    cfg = next(c for c in manifest["configs"] if c["name"] == w["config"])
+    with open(os.path.join(ROOT, cfg["file"]), encoding="utf-8") as f:
+        w["config_data"] = json.load(f)
+    w["traffic_data"] = read_json("traffic", w["traffic"] + ".json")
+    return w
+
+
+def metric_names(manifest: dict, workload: str, kind: str) -> list:
+    """Names of the ``end_to_end`` / ``per_layer`` metrics this cell reports:
+    those with no ``workloads`` key, or that list the cell."""
+    return [m["name"] for m in manifest[kind]
+            if "workloads" not in m or workload in m["workloads"]]
+
+
+def metric_reader(name: str):
+    """``benchmark/metrics/<name>.py``'s ``read(evidence)`` — end-to-end
+    and per-layer metrics alike."""
+    path = os.path.join(BENCH, "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "benchmark.metrics." + name.replace(".", "_").replace("-", "_"),
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def peaks(device_kind: str) -> dict:
+    """``benchmark/peaks/<device kind, spaces as _>.json``: published peaks
+    with their source.  A kind with no file is an error, not a default."""
+    path = os.path.join(BENCH, "peaks", device_kind.replace(" ", "_") + ".json")
+    if not os.path.isfile(path):
+        raise KeyError(f"device kind {device_kind!r} has no {path}; add it "
+                       "with the source of its peaks")
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def problems(manifest: dict) -> list:
+    """What the contract would refuse before any run (the checkable part)."""
+    bad = []
+
+    def name_ok(s, what):
+        if not isinstance(s, str) or not _NAME.match(s):
+            bad.append(f"{what}: bad name {s!r}")
+
+    configs = {c["name"] for c in manifest["configs"]}
+    cells = [w["name"] for w in manifest["workloads"]]
+    e2e = {m["name"] for m in manifest["end_to_end"]}
+    if "setup_s" not in e2e:
+        bad.append("end_to_end lacks setup_s")
+    for c in manifest["configs"]:
+        name_ok(c["name"], "config")
+        for k in c["reduced"]:
+            name_ok(k, f"config {c['name']} reduced")
+        if not os.path.isfile(os.path.join(ROOT, c["file"])):
+            bad.append(f"config {c['name']}: no file {c['file']}")
+        if not any(w["config"] == c["name"] for w in manifest["workloads"]):
+            bad.append(f"config {c['name']} is used by no cell")
+    pairs = set()
+    for w in manifest["workloads"]:
+        name_ok(w["name"], "workload")
+        name_ok(w["traffic"], "traffic")
+        if w["config"] not in configs:
+            bad.append(f"cell {w['name']}: unknown config {w['config']}")
+        if w["chips"] not in (1, 4):
+            bad.append(f"cell {w['name']}: chips {w['chips']}")
+        if not 1 <= len(w["why"]) <= 200 or "\n" in w["why"]:
+            bad.append(f"cell {w['name']}: why is not one line of <= 200")
+        if (w["config"], w["traffic"]) in pairs:
+            bad.append(f"cell {w['name']}: config/traffic pair repeats")
+        pairs.add((w["config"], w["traffic"]))
+        if not os.path.isfile(os.path.join(
+                BENCH, "traffic", w["traffic"] + ".json")):
+            bad.append(f"cell {w['name']}: no traffic file")
+    if sum(w["chips"] == 4 for w in manifest["workloads"]) > \
+            max(1, len(cells) // 2):
+        bad.append("too many four-chip cells")
+    names = cells + sorted(configs)
+    for kind in ("end_to_end", "per_layer"):
+        for m in manifest[kind]:
+            name_ok(m["name"], kind)
+            names.append(m["name"])
+            if not _UNIT.match(m["unit"]):
+                bad.append(f"{m['name']}: bad unit {m['unit']!r}")
+            if m["better"] not in ("lower", "higher"):
+                bad.append(f"{m['name']}: better={m['better']!r}")
+            if m["source"] not in _SOURCES:
+                bad.append(f"{m['name']}: source={m['source']!r}")
+            for wl in m.get("workloads", ()):
+                if wl not in cells:
+                    bad.append(f"{m['name']}: unknown cell {wl}")
+            allowed = {"name", "unit", "better", "source", "workloads"} | (
+                {"bound"} if kind == "end_to_end" else {"layer", "moves"})
+            if set(m) - allowed:
+                bad.append(f"{m['name']}: extra keys {set(m) - allowed}")
+        if kind == "end_to_end":
+            for m in manifest[kind]:
+                if not 0.01 <= m["bound"] <= 0.25:
+                    bad.append(f"{m['name']}: bound {m['bound']}")
+                if m["source"] not in ("host_clock", "device_trace"):
+                    bad.append(f"{m['name']}: end-to-end source")
+        else:
+            for m in manifest[kind]:
+                if m["moves"] not in e2e:
+                    bad.append(f"{m['name']}: moves unknown {m['moves']}")
+        for m in manifest[kind]:
+            if not os.path.isfile(os.path.join(
+                    BENCH, "metrics", m["name"] + ".py")):
+                bad.append(f"{m['name']}: no reader file")
+    if len(set(names)) != len(names):
+        bad.append("a name is used twice")
+    for w in manifest["workloads"]:
+        if len(metric_names(manifest, w["name"], "end_to_end")) < 2:
+            bad.append(f"cell {w['name']}: fewer than two end-to-end metrics")
+        if not metric_names(manifest, w["name"], "per_layer"):
+            bad.append(f"cell {w['name']}: no per-layer metric")
+    if not 1 <= manifest["run_seconds"] <= 51:
+        bad.append(f"run_seconds {manifest['run_seconds']}")
+    return bad

@@ -1,0 +1,164 @@
+"""The reading: one pass = one ``check()`` from Init to the pinned count at
+level B.  A run's rate is all the orbits its passes admitted over the whole
+window they ran in; each pass is also clocked between the two progress records
+whose ``n_states`` equal the pinned counts at levels A and B (the at-depth
+span), for the per-layer readings."""
+
+from __future__ import annotations
+
+import dataclasses
+import itertools
+import signal
+import statistics
+import time
+
+
+@dataclasses.dataclass
+class Pass:
+    """What one pass left behind.  Times are ``time.monotonic()`` seconds."""
+
+    index: int
+    t_call: float
+    t_a: float | None = None        # stamp at the pinned count of level A
+    t_b: float | None = None        # stamp at the pinned count of level B
+    t_return: float | None = None
+    traced: bool = False
+    levels: list = dataclasses.field(default_factory=list)   # cumulative
+    overshoot_levels: list = dataclasses.field(default_factory=list)
+    violation: str | None = None
+    problem: str | None = None      # why the pass counts as failed
+    compiles: int = 0               # compile events between call and return
+    events: str | None = None       # the pass's run-event log, if any
+    trace_dir: str | None = None
+    anchor: tuple | None = None     # (monotonic ns, annotation name)
+    t_trace_end: float | None = None   # the capture covers t_a..t_trace_end
+
+    @property
+    def reached(self) -> bool:
+        return self.t_a is not None and self.t_b is not None
+
+    def rate(self, orbits: int) -> float | None:
+        return orbits / (self.t_b - self.t_a) if self.reached else None
+
+    @property
+    def ramp_s(self):
+        return None if self.t_a is None else self.t_a - self.t_call
+
+    @property
+    def span_s(self):
+        return self.t_b - self.t_a if self.reached else None
+
+    @property
+    def overshoot_s(self):
+        if self.t_b is None or self.t_return is None:
+            return None
+        return self.t_return - self.t_b
+
+
+class SpanClock:
+    """The ``on_progress`` callback of one pass.  Stamps the benchmark's own
+    clock at the two level-boundary records whose count equals the pins, then
+    asks the engine for its lossless stop (first SIGINT = stop at the next
+    segment/window boundary: ``ddd_engine.install_sigint_boundary_stop``).
+
+    A record's ``n_states`` is exact at a level boundary only, so a stamp
+    also needs the record's ``level`` to be the boundary's."""
+
+    def __init__(self, p: Pass, pins: list, level_a: int, level_b: int,
+                 at_a=None, after_first_level=None):
+        self.p = p
+        self.pins = pins
+        self.level_a, self.level_b = level_a, level_b
+        # the traced pass's capture: at_a() opens it at stamp A,
+        # after_first_level(now) closes it at the boundary of level A + 1
+        self.at_a, self.after_first_level = at_a, after_first_level
+
+    def __call__(self, rec: dict) -> None:
+        now = time.monotonic()
+        p = self.p
+        level = rec["level"]
+        if not 0 <= level < len(self.pins) \
+                or rec["n_states"] != self.pins[level]:
+            if level > self.level_b and p.t_b is None and p.problem is None:
+                # past B with no boundary record at the pin: stop, failed
+                p.problem = (f"no level-{self.level_b} boundary record at "
+                             f"the pinned count {self.pins[self.level_b]}; "
+                             f"now at {rec['n_states']}, level {level}")
+                signal.raise_signal(signal.SIGINT)
+            return
+        hook = self.after_first_level \
+            if p.t_a is not None and level == self.level_a + 1 else None
+        if level == self.level_a and p.t_a is None:
+            p.t_a = now
+            if self.at_a is not None:
+                self.at_a()
+                p.t_a = time.monotonic()    # the span starts after the hook
+        elif level == self.level_b and p.t_a is not None and p.t_b is None:
+            p.t_b = now
+            if hook is not None:
+                hook(now)
+            signal.raise_signal(signal.SIGINT)
+        elif hook is not None:
+            hook(now)
+
+
+def finish(p: Pass, result, pins: list, end_level: int) -> Pass:
+    """Fill the pass from the engine's result and hold it to the pins."""
+    p.t_return = time.monotonic()
+    p.levels = list(itertools.accumulate(result.levels))
+    p.violation = result.violation.invariant if result.violation else None
+    # what the engine did past B before it stopped (at most a segment) is
+    # overshoot: kept apart, bounded by the pins
+    p.levels, p.overshoot_levels = (p.levels[:end_level + 1],
+                                    p.levels[end_level + 1:])
+    want = pins[:end_level + 1]
+    over_pins = pins[end_level + 1:]
+    if p.problem is None and any(
+            k >= len(over_pins) or got > over_pins[k]
+            for k, got in enumerate(p.overshoot_levels)):
+        p.problem = (f"overshoot levels {p.overshoot_levels} exceed the pins "
+                     f"{over_pins[:len(p.overshoot_levels)]}")
+    if p.problem is None:
+        if not p.reached:
+            p.problem = "the pass ended before the pinned count at B"
+        elif p.violation:
+            p.problem = f"violation {p.violation}"
+        elif p.levels != want:
+            k = next((i for i, (a, b) in enumerate(zip(p.levels, want))
+                      if a != b), min(len(p.levels), len(want)))
+            p.problem = (f"level table differs from the pins at level {k}: "
+                         f"got {p.levels[k:k + 1]}, pinned {want[k:k + 1]}")
+    return p
+
+
+def window_rate(made: list, orbits_to_b: int, window_s: float) -> dict:
+    """The end-to-end reading: every orbit the window's sound passes
+    admitted (a pass from Init to level B admits the pinned count at B; a
+    failed pass counts for nothing) over ALL the window's time, from the
+    first pass's call to the last one's return: ramp, span, overshoot and
+    whatever lies between passes, stalls included."""
+    orbits = orbits_to_b * sum(p.problem is None for p in made)
+    # the ramp's share is read on untraced passes: a traced pass's span
+    # holds the capture's write-out
+    plain = [p for p in made if not p.traced and p.ramp_s is not None]
+    wall = sum(p.t_return - p.t_call for p in plain)
+    return {"window_s": window_s, "orbits": orbits,
+            "rate": orbits / window_s if orbits else None,
+            "ramp_share_pct": 100.0 * sum(p.ramp_s for p in plain) / wall
+            if wall else None}
+
+
+def summarise(rates: list) -> dict:
+    """Median and spread of a run's A->B span rates (sound, untraced
+    passes): the at-depth reading, per layer."""
+    med = statistics.median(rates)
+    return {"median": med, "min": min(rates), "max": max(rates),
+            "spread_pct": 100.0 * (max(rates) - min(rates)) / med}
+
+
+def room_for_another(elapsed: float, last_pass_s: float, seconds: float,
+                     made: int, min_passes: int) -> bool:
+    """Whole passes until ``seconds`` of run time are used, at least
+    ``min_passes``: a pass is started only if one as long as the last still
+    ends inside the window."""
+    return made < min_passes or elapsed + last_pass_s <= seconds

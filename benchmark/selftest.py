@@ -1,0 +1,352 @@
+#!/usr/bin/env python3
+"""The benchmark's own tests; no chip needed:
+
+    JAX_PLATFORMS=cpu python3 benchmark/selftest.py [-k substring]
+
+Covers: the manifest's names and units; every cell's configuration, traffic
+and metric files resolving by name; the window-rate and span-median
+arithmetic and the pass clock; the trace reduction on the recorded trace; the
+plain reference against its own definition and the planted fault; a toy-size
+REHEARSAL of one whole run (labelled as such, writes no metric); and the
+controls — the same rehearsal with one guarantee or the timed path broken
+underneath has to come out ``correct: false``.
+"""
+
+from __future__ import annotations
+
+import itertools
+import json
+import os
+import random
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+from benchmark.harness import manifest as mf          # noqa: E402
+from benchmark.harness import passes, tracered, work  # noqa: E402
+
+
+def toy_cell() -> dict:
+    return {"name": "toy.rehearsal", "config": "toy_elect3",
+            "traffic": "toy_traffic", "chips": 1,
+            "config_data": mf.read_json("testdata", "toy_config.json"),
+            "traffic_data": mf.read_json("testdata", "toy_traffic.json")}
+
+
+# ------------------------------------------------------------ the manifest
+
+def test_manifest_meets_the_checkable_contract():
+    assert mf.problems(mf.load()) == []
+
+
+def test_every_cell_resolves_its_files_by_name():
+    m = mf.load()
+    for w in m["workloads"]:
+        c = mf.cell(m, w["name"])
+        pins = c["config_data"]["level_pins"]
+        t = c["traffic_data"]
+        assert pins[t["start_level"]] == t["count_at_start"]
+        assert pins[t["end_level"]] == t["count_at_end"]
+        assert t["min_passes"] >= 3
+        for kind in ("end_to_end", "per_layer"):
+            assert mf.metric_names(m, w["name"], kind)
+    for metric in m["per_layer"]:
+        assert callable(mf.metric_reader(metric["name"]))
+    assert mf.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    try:
+        mf.peaks("TPU v9")
+        raise AssertionError("an unknown device kind got peaks")
+    except KeyError:
+        pass
+
+
+def test_a_reader_with_nothing_to_read_returns_nothing():
+    ev = {"trace": None, "summary": None, "passes": [], "hbm_peak_bytes": 0,
+          "device": {"count": 1}}
+    for name in ("scan_words_per_s", "step_hbm_share", "device_idle_share",
+                 "export_wall_s", "dedup_exposed_s", "upload_wait_ms",
+                 "pass_spread_pct", "peak_hbm_mb"):
+        assert mf.metric_reader(name)(ev) is None, name
+    ev = {"summary": passes.summarise([3.0, 1.0, 2.0]),
+          "window": {"rate": 1.5, "ramp_share_pct": 40.0},
+          "clocks": {"setup_s": 18.5, "open_s": 11.0, "compile_s": 7.0}}
+    assert mf.metric_reader("orbits_per_s")(ev) == 1.5
+    assert mf.metric_reader("pass_median_rate")(ev) == 2.0
+    assert mf.metric_reader("ramp_share_pct")(ev) == 40.0
+    assert mf.metric_reader("setup_s")(ev) == 18.5
+    assert mf.metric_reader("pass_spread_pct")(ev) == 100.0
+
+
+# ------------------------------------------------- the reading's arithmetic
+
+def test_the_window_rate_keeps_a_stall_that_the_span_median_drops():
+    def made(ramps, spans):
+        t, out = 0.0, []
+        for k, (r, sp) in enumerate(zip(ramps, spans)):
+            out.append(passes.Pass(index=k, t_call=t, t_a=t + r,
+                                   t_b=t + r + sp, t_return=t + r + sp + 0.1))
+            t += r + sp + 0.1
+        return out, t
+    # four passes of 1000 orbits to B, 600 of them in the clocked span
+    steady, w0 = made([2.0] * 4, [4.0] * 4)
+    stalled, w1 = made([2.0, 2.0, 15.0, 2.0], [4.0, 4.0, 4.0, 5.0])
+    a = passes.window_rate(steady, 1000, w0)
+    b = passes.window_rate(stalled, 1000, w1)
+    assert abs(a["rate"] - 4000 / 24.4) < 1e-9 and a["orbits"] == 4000
+    assert abs(b["rate"] - 4000 / 38.4) < 1e-9          # both stalls count
+    assert abs(a["ramp_share_pct"] - 100 * 8 / 24.4) < 1e-9
+    stalled[2].traced = True                 # read on untraced passes only
+    assert abs(passes.window_rate(stalled, 1000, w1)["ramp_share_pct"]
+               - 100 * 6 / 19.3) < 1e-9
+    stalled[2].traced = False
+    stalled[1].problem = "short of B"        # a failed pass: time, no work
+    assert passes.window_rate(stalled, 1000, w1)["orbits"] == 3000
+    # the span median sees neither the ramp stall nor one slow span
+    s = passes.summarise([p.rate(600) for p in stalled])
+    assert s["median"] == 150.0
+    assert abs(s["spread_pct"] - 100 * (150.0 - 120.0) / 150.0) < 1e-9
+    assert passes.summarise([5.0, 1.0, 3.0])["median"] == 3.0
+
+
+def test_whole_passes_until_the_window_is_used_at_least_three():
+    assert passes.room_for_another(31.0, 10.0, 40.0, 2, 3)       # minimum
+    assert passes.room_for_another(29.0, 10.0, 40.0, 3, 3)       # fits
+    assert not passes.room_for_another(31.0, 10.0, 40.0, 3, 3)   # would spill
+    assert passes.room_for_another(99.0, 10.0, 0.0, 1, 3)        # --seconds 0
+
+
+def test_span_clock_stamps_at_the_pinned_counts_only():
+    import signal
+    hits = []
+    old = signal.signal(signal.SIGINT, lambda *_: hits.append(1))
+    try:
+        pins = [1, 50, 100, 250, 400, 900]
+        p = passes.Pass(index=0, t_call=time.monotonic())
+        clock = passes.SpanClock(p, pins, 2, 4)
+        # (count, level): 100 shows up early as a mid-level upper bound and
+        # must not start the clock; the boundary record of level 2 does
+        for n, lvl in ((50, 1), (100, 1), (100, 2), (250, 3), (400, 4)):
+            assert p.t_a is None or lvl > 1
+            clock({"n_states": n, "level": lvl})
+        assert p.reached and p.t_b >= p.t_a and hits == [1]
+
+        class R:
+            levels = [1, 49, 50, 150, 150]
+            violation = None
+        passes.finish(p, R, pins, 4)
+        assert p.problem is None and p.levels == [1, 50, 100, 250, 400]
+        assert abs(p.rate(300) - 300 / (p.t_b - p.t_a)) < 1e-6
+        # past B with no boundary record at the pin: stopped, failed
+        q = passes.Pass(index=1, t_call=time.monotonic())
+        clock = passes.SpanClock(q, pins, 2, 4)
+        for n, lvl in ((100, 2), (401, 4), (950, 5)):
+            clock({"n_states": n, "level": lvl})
+        assert not q.reached and "no level-4 boundary" in q.problem
+        assert hits == [1, 1]
+        # a level table off the pins
+        r = passes.Pass(index=2, t_call=0.0, t_a=1.0, t_b=2.0)
+        R.levels = [1, 49, 50, 151, 149]
+        passes.finish(r, R, [1, 50, 100, 250, 400], 4)
+        assert "level 3" in r.problem
+    finally:
+        signal.signal(signal.SIGINT, old)
+
+
+def test_chunk_steps_and_words_from_shapes():
+    pins = [1, 2, 6, 5000, 9000]
+    # level 2 -> 3: frontier of 4 rows = 1 step; level 3 -> 4: 4994 rows in
+    # blocks of 4096 = ceil(4096/1024) + ceil(898/1024) = 4 + 1
+    assert work.chunk_steps(pins, 2, 4, 4096, 1024) == 1 + 5
+    assert work.scan_words(4096, 38, 5, 104, True) == 4096 * 38 * 120 * 104
+    assert work.scan_words(4096, 38, 5, 104, False) == 4096 * 38 * 104
+
+
+# ------------------------------------------------------ the trace reduction
+
+def test_interval_arithmetic():
+    assert tracered.union_ns([(0, 10), (5, 20), (30, 40)]) == 30
+    assert tracered.gaps_ns([(5, 10), (8, 20), (30, 50)], 0, 40) == \
+        [(0, 5), (20, 30)]
+    # a while holding its body: self time goes to the body's ops
+    ev = [["while.1", 0, 100], ["fusion.2", 10, 30], ["fusion.3", 50, 40],
+          ["copy.4", 120, 5]]
+    assert tracered.self_times(ev) == {"while.1": 30, "fusion.2": 30,
+                                       "fusion.3": 40, "copy.4": 5}
+    spans = [["upload", "MainThread", 0, 50], ["export", "MainThread", 60, 80],
+             ["dedup_wait", "MainThread", 62, 70]]
+    assert tracered.covering_kind(spans, 40, 75) == \
+        {"upload": 10, "dedup": 8, "export": 7, "unattributed": 10}
+
+
+def test_reduction_of_the_recorded_trace():
+    rec = mf.read_json("testdata", "trace_small.json")
+    got = tracered.reduce(rec["trace"], rec["spans"], rec["anchor_mono_ns"],
+                          rec["t_a_s"], rec["t_b_s"])
+    want = rec["expected"]
+    for k in ("devices", "window_s", "busy_s", "segment_device_s"):
+        assert abs(got[k] - want[k]) <= 1e-9 * max(1.0, abs(want[k])), k
+    assert [n for n, _ in got["device_ops"]] == \
+        [n for n, _ in want["device_ops"]]
+    assert dict(got["idle_gaps"]).keys() == dict(want["idle_gaps"]).keys()
+    assert 0.0 < got["busy_s"] <= got["window_s"]
+
+
+# ------------------------------------------------------ the plain reference
+
+def test_reference_orbit_representative_is_renaming_invariant():
+    from benchmark.reference import canon, interp
+    from benchmark.reference.bounds import Bounds
+    b = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2,
+               max_dup=1)
+    cum, level, viol = canon.bfs_levels(
+        b, "full", True, ("NoTwoLeaders", "LogMatching"), 600)
+    flagship = mf.read_json("configs", "flagship3.json")["level_pins"]
+    assert cum == flagship[:len(cum)] and viol == 0
+    rng = random.Random(5)
+    perms = list(itertools.permutations(range(3)))
+    for s in rng.sample(level, 100):
+        twin = interp.PyState(*canon.permute(s, rng.choice(perms)))
+        assert canon.canonical(s) == canon.canonical(twin)
+        assert canon.canonical_all_perms(s) == canon.canonical_all_perms(twin)
+    # the shortcut partitions states exactly as the definition does
+    some = rng.sample(level, 300)
+    assert len({canon.canonical(s) for s in some}) == \
+        len({canon.canonical_all_perms(s) for s in some})
+
+
+# ------------------------------------------- a whole run, toy size, the CPU
+
+def rehearse(seed: int, trace: bool = False) -> dict:
+    from benchmark import run
+    return run.execute(toy_cell(), mf.load(), seed, 0.0, trace,
+                       rehearsal=True)
+
+
+def test_rehearsal_of_one_run_is_correct_and_writes_no_metric():
+    res = rehearse(3_000_000_019, trace=True)     # more than 32 signed bits
+    assert res["rehearsal"] is True and res["metrics"] == {}
+    assert res["correct"] is True
+    assert res["attempted"] >= 3 and res["failed"] == 0
+
+
+def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
+    from benchmark.harness import correct
+    from benchmark.reference import canon, interp, invariants
+    from benchmark.reference.bounds import Bounds
+    for cfg in (mf.read_json("testdata", "toy_config.json"),
+                mf.read_json("configs", "elect5.json"),
+                mf.read_json("configs", "flagship3.json")):
+        b = Bounds(**cfg["bounds"])
+        _cum, level, _v = canon.bfs_levels(
+            b, cfg["spec"], True, tuple(cfg["invariants"]), 200)
+        plants = [correct.planted_fault(cfg, level, seed)
+                  for seed in (1, 2, 2_147_483_659)]
+        assert len({p["parent"] for p in plants}) > 1     # the seed draws it
+        for p in plants:
+            assert interp.constraint_ok(p["parent"], b)
+            assert all(invariants.REGISTRY[nm](p["parent"], b)
+                       for nm in cfg["invariants"])
+            assert p["violators"] and all(
+                "NoTwoLeaders" in v for v in p["violators"].values())
+    # a sound engine names the planted state; a blind or wrong one fails
+    p = plants[0]
+    orbit, names = next(iter(p["violators"].items()))
+
+    class Hit:
+        def __init__(self, t):
+            for f, v in zip(correct.STATE_FIELDS, t):
+                setattr(self, f, v)
+    ok = correct.planted_checks(p, {"invariant": names[0],
+                                    "state": Hit(orbit)})
+    assert [v for _n, v, _l in ok] == [0, 0]
+    blind = correct.planted_checks(p, {"invariant": None, "state": None})
+    assert [v for _n, v, _l in blind] == [1, 0]
+    other = correct.planted_checks(p, {"invariant": "LogMatching",
+                                       "state": Hit(orbit)})
+    assert [v for _n, v, _l in other] == [0, 1]
+    elsewhere = correct.planted_checks(
+        p, {"invariant": names[0], "state": p["parent"]})
+    assert [v for _n, v, _l in elsewhere] == [0, 1]
+
+
+def test_short_keys_come_out_not_correct():
+    # 32 bits collide ~16 and ~105 times at the cells' 3.7e5 and 9.5e5 keys
+    # (control.py shows that on the chip); the toy's 6.6e3 keys need 16 bits
+    # for collisions as sure
+    from benchmark.harness import breakers
+    with breakers.short_keys(16):
+        res = rehearse(11)
+    assert res["correct"] is False
+
+
+def test_filter_only_dedup_comes_out_not_correct():
+    from benchmark.harness import breakers
+    with breakers.filter_only_dedup():
+        res = rehearse(12)
+    assert res["correct"] is False
+
+
+def test_invariants_switched_off_come_out_not_correct():
+    # no sound run's counts depend on the invariant pass; only the planted
+    # fault (check d) sees it gone
+    from benchmark.harness import breakers
+    with breakers.invariants_off():
+        res = rehearse(14)
+    assert res["correct"] is False and res["failed"] == 0
+
+
+def test_a_pass_cut_short_comes_out_not_correct():
+    # the timed path broken underneath: an engine that stops one level early
+    import signal
+    from benchmark.harness import drive
+    real = drive.build_engine
+    cell = toy_cell()
+    stop_at = cell["config_data"]["level_pins"][
+        cell["traffic_data"]["end_level"] - 1]
+
+    def build(cfg):
+        eng = real(cfg)
+        check = eng.check
+
+        def short(on_progress=None, **kw):
+            def cb(rec):
+                on_progress(rec)
+                if rec["n_states"] == stop_at:
+                    signal.raise_signal(signal.SIGINT)
+            return check(on_progress=cb, **kw)
+
+        eng.check = short
+        return eng
+
+    drive.build_engine = build
+    try:
+        res = rehearse(13)
+    finally:
+        drive.build_engine = real
+    assert res["correct"] is False and res["failed"] == res["attempted"]
+
+
+def main(argv) -> int:
+    pick = argv[argv.index("-k") + 1] if "-k" in argv else ""
+    tests = [(n, f) for n, f in sorted(globals().items())
+             if n.startswith("test_") and pick in n]
+    failed = 0
+    for name, fn in tests:
+        t0 = time.monotonic()
+        try:
+            fn()
+            print(f"PASS {name} ({time.monotonic() - t0:.1f}s)", flush=True)
+        except Exception:
+            import traceback
+            failed += 1
+            traceback.print_exc()
+            print(f"FAIL {name}", flush=True)
+    print(json.dumps({"selftest": "ok" if not failed else "failed",
+                      "ran": len(tests), "failed": failed}))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
