@@ -68,7 +68,7 @@ from raft_tla_tpu.device_engine import (
     aggregate_coverage, decode_fail)
 from raft_tla_tpu.engine import DEADLOCK, EngineResult, Violation
 from raft_tla_tpu.models import interp, invariants as inv_mod, spec as S
-from raft_tla_tpu.obs import RunTelemetry
+from raft_tla_tpu.obs import RunTelemetry, compiles
 from raft_tla_tpu.ops import bitpack
 from raft_tla_tpu.ops import devdedup
 from raft_tla_tpu.ops import kernels
@@ -768,7 +768,10 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
         rows_b = r0 + jnp.arange(B, dtype=I32)
         row_act = rows_b < block_rows
         bidx = jnp.minimum(rows_b, caps.block - 1)
-        vecs = schema.unpack(fbuf[bidx], jnp)
+        # stage scopes (kernels.STAGE_SCOPES): metadata for the device
+        # trace, no computation
+        with jax.named_scope("unpack"):
+            vecs = schema.unpack(fbuf[bidx], jnp)
         row_ok = row_act & fcon[bidx]
         out = step(vecs, row_ok) if routed else step(vecs)
         valid = out["valid"] & row_ok[:, None]
@@ -810,40 +813,44 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
         # kept) vs first dead row (its and later rows' candidates cut),
         # ordered the way streamed_engine orders them (flat candidate
         # position vs drow * A)
-        inv_bad = cand_act & jnp.any(~inv_ok_rows, axis=-1) if n_inv \
-            else jnp.zeros((NK,), bool)
-        first_inv = jnp.min(jnp.where(inv_bad, order, BIG))
-        if config.check_deadlock:
-            dead = row_act & fcon[bidx] & ~jnp.any(out["valid"], axis=1)
-            drow = jnp.min(jnp.where(dead, jnp.arange(B, dtype=I32), BIG))
-            dpos = jnp.where(drow < BIG // A, drow * A, BIG)
-        else:
-            drow = BIG
-            dpos = BIG
-        use_dead = dpos < first_inv
-        has_inv = (first_inv < BIG) & ~use_dead
-        cut_incl = jnp.where(use_dead, dpos - 1,
-                             jnp.where(first_inv < BIG, first_inv, BIG))
-        keep = order <= cut_incl
-        kvalid = cand_act & keep
-        n_valid_a = n_valid_a + jnp.sum(kvalid.astype(I32))
-        fail = fail | jnp.any(kvalid & ovf_rows).astype(I32) * FAIL_WIDTH
+        with jax.named_scope("invariants"):
+            inv_bad = cand_act & jnp.any(~inv_ok_rows, axis=-1) if n_inv \
+                else jnp.zeros((NK,), bool)
+            first_inv = jnp.min(jnp.where(inv_bad, order, BIG))
+            if config.check_deadlock:
+                dead = row_act & fcon[bidx] & ~jnp.any(out["valid"], axis=1)
+                drow = jnp.min(jnp.where(dead, jnp.arange(B, dtype=I32), BIG))
+                dpos = jnp.where(drow < BIG // A, drow * A, BIG)
+            else:
+                drow = BIG
+                dpos = BIG
+            use_dead = dpos < first_inv
+            has_inv = (first_inv < BIG) & ~use_dead
+            cut_incl = jnp.where(use_dead, dpos - 1,
+                                 jnp.where(first_inv < BIG, first_inv, BIG))
+            keep = order <= cut_incl
+            kvalid = cand_act & keep
+            n_valid_a = n_valid_a + jnp.sum(kvalid.astype(I32))
+            fail = fail | jnp.any(kvalid & ovf_rows).astype(I32) * FAIL_WIDTH
 
-        tbl_hi, tbl_lo, stream = _filter_insert(tbl_hi, tbl_lo, kh, kl,
-                                                kvalid)
-        pos = cursor + jnp.cumsum(stream.astype(I32)) - 1
-        sl = jnp.where(stream, pos, OCAP)
-        svecs = schema.pack(word_rows, jnp)
-        okey_hi = okey_hi.at[sl].set(kh, mode="drop")
-        okey_lo = okey_lo.at[sl].set(kl, mode="drop")
-        orows = orows.at[sl].set(svecs, mode="drop")
-        # BLOCK-RELATIVE parent (always fits int32 regardless of how
-        # deep the campaign is); the harvest rebases to the global int64
-        # discovery index by adding the block start on the host
-        opar = opar.at[sl].set(r0 + src // A, mode="drop")
-        olane = olane.at[sl].set(src % A, mode="drop")
-        ocon = ocon.at[sl].set(con_rows, mode="drop")
-        cursor = cursor + jnp.sum(stream.astype(I32))
+        with jax.named_scope("filter_insert"):
+            tbl_hi, tbl_lo, stream = _filter_insert(tbl_hi, tbl_lo, kh,
+                                                    kl, kvalid)
+        with jax.named_scope("pack"):
+            svecs = schema.pack(word_rows, jnp)
+        with jax.named_scope("stream"):
+            pos = cursor + jnp.cumsum(stream.astype(I32)) - 1
+            sl = jnp.where(stream, pos, OCAP)
+            okey_hi = okey_hi.at[sl].set(kh, mode="drop")
+            okey_lo = okey_lo.at[sl].set(kl, mode="drop")
+            orows = orows.at[sl].set(svecs, mode="drop")
+            # BLOCK-RELATIVE parent (always fits int32 regardless of how
+            # deep the campaign is); the harvest rebases to the global
+            # int64 discovery index by adding the block start on the host
+            opar = opar.at[sl].set(r0 + src // A, mode="drop")
+            olane = olane.at[sl].set(src % A, mode="drop")
+            ocon = ocon.at[sl].set(con_rows, mode="drop")
+            cursor = cursor + jnp.sum(stream.astype(I32))
 
         viol_kind = jnp.where(use_dead, 2, jnp.where(has_inv, 1, 0)) \
             .astype(I32)
@@ -973,6 +980,9 @@ class DDDEngine:
         self._merge_budget = max(1 << 16,
                                  (8 * self.caps.flush)
                                  // keyset.DEFAULT_PARTS)
+        # the compile ledger listens before this engine's first program
+        # is traced (idempotent; obs/compiles)
+        compiles.install()
         self._segment = jax.jit(
             _build_segment(config, self.caps, self.A, self.lay.width,
                            self.schema),
@@ -1105,6 +1115,14 @@ class DDDEngine:
             on_progress=on_progress, events=events,
             resumed=resume is not None, n0=1, t0=t0)
         _cleanup.callback(tel.close)
+        # Span tree (obs/trace; every site is the shared null handle with
+        # tracing off): pass > level > upload / expand / export >
+        # {segment_wait, d2h} / level_close, the flush worker's and the
+        # prefetcher's spans on their own threads, one ``segment`` per
+        # harvested segment on the synthetic ``segments`` track.
+        tr = tel.trace
+        pass_sp = tr.open("pass", engine="ddd", resumed=resume is not None)
+        _cleanup.callback(pass_sp.close)     # raise paths; idempotent
         bounds = self.bounds
         init_py = init_override if init_override is not None \
             else interp.init_state(bounds)
@@ -1119,6 +1137,8 @@ class DDDEngine:
                     coverage=Counter(),
                     violation=Violation(nm, init_py, [(None, init_py)]),
                     levels=[1], wall_s=time.monotonic() - t0)
+                pass_sp.set(levels=1, n_states=1,
+                            stopped_by="violation").close()
                 tel.run_end(res)
                 return res
 
@@ -1237,12 +1257,20 @@ class DDDEngine:
             candidate, exactly as in the synchronous engine."""
             nonlocal n_states
             if worker is not None:
-                with tel.phases.phase("dedup_wait"):
+                with tel.phases.phase("dedup_wait") as ph:
+                    if tr.enabled:
+                        ph.set(backlog=worker.backlog())
                     n_states += worker.drain()
-            with tel.phases.phase("dedup"):
+            with tel.phases.phase("dedup") as ph:
+                if tr.enabled:
+                    ph.set(keys=sum(len(k) for k in pend["keys"]))
                 n_states += self._flush(pend, master, host, constore,
                                         keystore, cov)
         Fcap = self.caps.block
+        # what one frontier block and one segment's buffers weigh on the
+        # wire, from shapes (span args; the transfers are whole buffers)
+        up_bytes = Fcap * (self.schema.P * 4 + 1)
+        buf_bytes = self.caps.seg_rows * (self.schema.P * 4 + 17)
         # Upload prefetcher (RAFT_TLA_PREFETCH): while the device
         # expands block k, a daemon thread reads block k+1's rows +
         # constraint column and stages them into one of two
@@ -1314,10 +1342,24 @@ class DDDEngine:
                 dev_dedup_hits=dd_hits if self._dd_apply else None)
 
         n_trans_mark = n_trans   # n_trans as of the current block's start
+        stopped_by = None
+        lvl_segs = lvl_steps = lvl_rows = 0   # the open level's work
+
+        def end_level():
+            level_sp.set(segments=lvl_segs, steps=lvl_steps,
+                         streamed_rows=lvl_rows,
+                         new_states=n_states - lvl_hi).close()
+
         while not stopped:
             lvl_lo = level_ends[-2] if len(level_ends) > 1 else 0
             lvl_hi = level_ends[-1]
             b0 = lvl_lo + blocks_done * Fcap
+            # explicit handle: every exit of the body lands on
+            # end_level(), here or after the loop (close is idempotent)
+            level_sp = tr.open("level", level=len(level_ends),
+                               rows=lvl_hi - lvl_lo,
+                               blocks=-(-(lvl_hi - b0) // Fcap))
+            lvl_segs = lvl_steps = lvl_rows = 0
             if prefetcher is not None and b0 < lvl_hi:
                 # level start: every block address in [lvl_lo, lvl_hi)
                 # is known now — warm the first block immediately
@@ -1334,8 +1376,12 @@ class DDDEngine:
                     # checkpoint drains: that asymmetry in the phase
                     # timers is the gate's signature.
                     with tel.phases.phase("upload") as ph:
+                        hits0 = prefetcher.hits
                         fbuf, fcon = ph.sync(
                             prefetcher.take(b_start, b_rows))
+                        ph.set(rows=b_rows, padded_rows=Fcap,
+                               bytes=up_bytes,
+                               prefetch_hit=prefetcher.hits > hits0)
                     nxt = b_start + Fcap
                     if nxt < lvl_hi:
                         prefetcher.schedule(nxt,
@@ -1345,9 +1391,13 @@ class DDDEngine:
                         # without the prefetcher's disjointness
                         # discipline, settle the in-flight flush before
                         # reading the block
-                        with tel.phases.phase("dedup_wait"):
+                        with tel.phases.phase("dedup_wait") as ph:
+                            if tr.enabled:
+                                ph.set(backlog=worker.backlog())
                             n_states += worker.drain()
                     with tel.phases.phase("upload") as ph:
+                        ph.set(rows=b_rows, padded_rows=Fcap,
+                               bytes=up_bytes)
                         blk = host.read(b_start, b_rows)
                         con = constore.read(b_start,
                                             b_rows)[:, 0].astype(bool)
@@ -1383,10 +1433,12 @@ class DDDEngine:
                             and time.monotonic() - t_warm > deadline_s):
                         complete = False
                         stopped = True
+                        stopped_by = "deadline"
                         tel.stop_requested("deadline")
                     if not stopped and self._sigint:
                         complete = False      # graceful-stop contract:
                         stopped = True        # flush+snapshot below
+                        stopped_by = "sigint"
                         tel.stop_requested("sigint")
                     if not (block_done or stopped) and free:
                         idx = free.pop(0)
@@ -1410,12 +1462,12 @@ class DDDEngine:
                                     self._dd_apply(dst, bufsets[idx],
                                                    stats.cursor)
                                 ph.sync(ncur)
-                        q.append((idx, stats, ncur, dhits, t_disp))
+                        q.append((idx, stats, ncur, dhits, t_disp, budget))
                         if len(q) < 2:
                             continue         # keep the pipeline full
                     if not q:                # stop landed with nothing
                         break                # in flight
-                    idx, stats, ncur, dhits, t_disp = q.pop(0)
+                    idx, stats, ncur, dhits, t_disp, seg_budget = q.pop(0)
                     # Stats first (tiny); the OCAP-sized buffers transfer
                     # only when the segment streamed anything.  The full-
                     # buffer transfer (vs the old jitted prefix slice) is
@@ -1427,19 +1479,39 @@ class DDDEngine:
                     # percent; zero-stream segments (every block end) now
                     # skip it entirely.
                     with tel.phases.phase("export"):
-                        st_h = jax.device_get(stats)
-                        # gate on: the harvest slices the POST-filter
-                        # cursor — dropped rows never cross d2h at all
-                        ns = int(st_h.cursor) if ncur is None \
-                            else int(jax.device_get(ncur))
+                        # the stats fetch is where the host waits for the
+                        # segment: device time, not transfer
+                        with tr.span("segment_wait"):
+                            st_h = jax.device_get(stats)
+                            # gate on: the harvest slices the POST-filter
+                            # cursor — dropped rows never cross d2h at all
+                            ns = int(st_h.cursor) if ncur is None \
+                                else int(jax.device_get(ncur))
                         nv = int(st_h.n_valid)
                         vk = int(st_h.viol_kind)
+                        n_steps = int(st_h.steps)
                         route_peak = max(route_peak, int(st_h.peak))
-                        bufs_h = jax.device_get(bufsets[idx]) \
-                            if ns and not stopped else None
+                        if tr.enabled:
+                            # dispatch -> stats ready, on its own track
+                            # (segments overlap: two are in flight)
+                            tr.emit_span(
+                                "segment", t_disp,
+                                time.monotonic() - t_disp,
+                                thread="segments", level=len(level_ends),
+                                block=(b_start - lvl_lo) // Fcap,
+                                budget=seg_budget, steps=n_steps,
+                                streamed_rows=ns, n_valid=nv,
+                                dropped=stopped)
+                        lvl_segs += 1
+                        lvl_steps += n_steps
+                        bufs_h = None
+                        if ns and not stopped:
+                            with tr.span("d2h", rows=ns, bytes=buf_bytes):
+                                bufs_h = jax.device_get(bufsets[idx])
                     free.append(idx)
                     if stopped:
                         continue             # drop post-stop segments
+                    lvl_rows += ns
                     n_trans += nv
                     fail |= int(st_h.fail)
                     if dhits is not None:
@@ -1480,10 +1552,9 @@ class DDDEngine:
                     # zero-chunk speculative segment (block already done)
                     # is pure transfer time — no pacing signal, and it
                     # would poison the watchdog ratchet
-                    if int(st_h.steps) > 0:
+                    if n_steps > 0:
                         budget = pacer.update(
-                            now - max(t_disp, t_last_harvest),
-                            int(st_h.steps))
+                            now - max(t_disp, t_last_harvest), n_steps)
                     t_last_harvest = now
                     self.seg_chunks = budget
                     block_done = block_done or bool(st_h.done)
@@ -1498,11 +1569,17 @@ class DDDEngine:
                             # at every drain point keeps the ceiling
                             # honest.
                             n_pend = sum(len(x) for x in pend["keys"])
-                            with tel.phases.phase("dedup_submit"):
+                            with tel.phases.phase("dedup_submit") as ph:
+                                if tr.enabled:
+                                    ph.set(keys=n_pend,
+                                           backlog=worker.backlog())
                                 worker.submit(seal(pend), n_pend)
                             n_states += worker.collect()
                         else:
-                            with tel.phases.phase("dedup"):
+                            with tel.phases.phase("dedup") as ph:
+                                if tr.enabled:
+                                    ph.set(keys=sum(
+                                        len(k) for k in pend["keys"]))
                                 n_states += self._flush(pend, master,
                                                         host, constore,
                                                         keystore, cov)
@@ -1529,42 +1606,48 @@ class DDDEngine:
                     last_ckpt = time.monotonic()
             if stopped:
                 break
-            blocks_done = 0
-            flush_sync()
-            progress()
-            if n_states > _IDX_CEIL:
-                fail = FAIL_INDEX
-                break
-            if n_states == level_ends[-1]:       # no new states: done
-                break
-            level_ends.append(n_states)
-            if self._dd_apply is not None:
-                # the set is within-level by contract: reset it empty
-                # at every boundary so capacity tracks one level's
-                # stream, not the whole run (a next-level re-sight of a
-                # previous-level state streams and the master drops it,
-                # exactly as with the gate off)
-                dst = self._init_devset()
-            if prefetcher is not None:
-                # quiesce before any rotation/teardown below; by now the
-                # last take() consumed the final scheduled block, so
-                # this is a no-op unless a stop raced the level end
-                prefetcher.invalidate()
-            if frontier:
-                # the just-finished level's rows are dead weight now.
-                # With snapshots, the files outlive the rotation until
-                # the npz commits (save_frontier_snapshot.delete_old);
-                # without (tmpdir mode) there is nothing to resume, so
-                # delete immediately or every level accumulates.
-                keep = self.caps.keep_levels
-                host.rotate(delete_old=tmpdir is not None and not keep)
-                constore.rotate(delete_old=tmpdir is not None
-                                and not keep)
-            if len(level_ends) > self.caps.levels:
-                _cleanup.close()
-                raise RuntimeError(
-                    f"DDD search aborted: {decode_fail(FAIL_LEVEL)} "
-                    f"(caps={self.caps}) — grow DDDCapacities and rerun")
+            # level_close: everything between the level's last block and
+            # the next level's first upload
+            with tr.span("level_close"):
+                blocks_done = 0
+                flush_sync()
+                progress()
+                if n_states > _IDX_CEIL:
+                    fail = FAIL_INDEX
+                    break
+                if n_states == level_ends[-1]:       # no new states: done
+                    break
+                level_ends.append(n_states)
+                if self._dd_apply is not None:
+                    # the set is within-level by contract: reset it empty
+                    # at every boundary so capacity tracks one level's
+                    # stream, not the whole run (a next-level re-sight of
+                    # a previous-level state streams and the master drops
+                    # it, exactly as with the gate off)
+                    dst = self._init_devset()
+                if prefetcher is not None:
+                    # quiesce before any rotation/teardown below; by now
+                    # the last take() consumed the final scheduled block,
+                    # so this is a no-op unless a stop raced the level end
+                    prefetcher.invalidate()
+                if frontier:
+                    # the just-finished level's rows are dead weight now.
+                    # With snapshots, the files outlive the rotation until
+                    # the npz commits (save_frontier_snapshot.delete_old);
+                    # without (tmpdir mode) there is nothing to resume, so
+                    # delete immediately or every level accumulates.
+                    keep = self.caps.keep_levels
+                    host.rotate(delete_old=tmpdir is not None and not keep)
+                    constore.rotate(delete_old=tmpdir is not None
+                                    and not keep)
+                if len(level_ends) > self.caps.levels:
+                    _cleanup.close()
+                    raise RuntimeError(
+                        f"DDD search aborted: {decode_fail(FAIL_LEVEL)} "
+                        f"(caps={self.caps}) — grow DDDCapacities and "
+                        "rerun")
+            end_level()
+        end_level()          # the exits by break; a no-op after the above
 
         if prefetcher is not None:
             # stop paths (violation/SIGINT/deadline) can leave a
@@ -1664,6 +1747,9 @@ class DDDEngine:
             n_transitions=n_trans, coverage=coverage,
             violation=violation, levels=levels_arr,
             wall_s=time.monotonic() - t0, complete=complete)
+        pass_sp.set(levels=len(levels_arr), n_states=n_states,
+                    stopped_by="violation" if violation is not None
+                    else stopped_by).close()
         tel.run_end(result)
         _cleanup.close()
         return result

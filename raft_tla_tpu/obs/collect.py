@@ -19,8 +19,8 @@ report can say which processes are on the degraded clock.
 
 The collection is a plain dict (processes / spans / instants / counters)
 consumed by obs/perfetto.py (Chrome ``trace_event`` export) and by
-:func:`report` (wall attribution, device-idle gaps, per-level critical
-path) — and by ``raft-tla-monitor``'s directory mode, which reuses
+:func:`report` (wall attribution by self time, idle gaps, one row per
+``level`` span) — and by ``raft-tla-monitor``'s directory mode, which reuses
 :func:`find_logs` to sweep a fleet.
 """
 
@@ -145,7 +145,6 @@ def collect(paths: list) -> dict:
                         "span_id", "parent_id", "args"}, ...],
          "instants":  [{"pid", "name", "ts", "args"}, ...],
          "counters":  [{"pid", "name", "ts", "value"}, ...],
-         "levels":    [{"pid", "level", "ts", "n_states"}, ...],
          "t_min", "t_max", "skew_bound_s", "n_invalid", "n_logs"}
 
     ``ts`` everywhere is absolute wall seconds; ``skew_bound_s`` is the
@@ -164,7 +163,6 @@ def collect(paths: list) -> dict:
     spans: list = []
     instants: list = []
     counters: list = []
-    levels: list = []
     n_invalid = 0
 
     for path in paths:
@@ -227,10 +225,6 @@ def collect(paths: list) -> dict:
                         {"pid": pid, "name": "inc_states_per_sec",
                          "ts": float(e["ts"]),
                          "value": float(e["inc_states_per_sec"])})
-            elif ev == "level_end" and e.get("ts") is not None:
-                levels.append({"pid": pid, "level": int(e["level"]),
-                               "ts": float(e["ts"]),
-                               "n_states": int(e["n_states"])})
 
     stamps = ([s["ts"] for s in spans]
               + [s["ts"] + s["dur"] for s in spans]
@@ -239,7 +233,6 @@ def collect(paths: list) -> dict:
              if p["skew_bound_s"] is not None]
     return {"processes": processes, "spans": spans,
             "instants": instants, "counters": counters,
-            "levels": levels,
             "t_min": min(stamps) if stamps else 0.0,
             "t_max": max(stamps) if stamps else 0.0,
             "skew_bound_s": max(skews) if skews else None,
@@ -262,20 +255,46 @@ def _merge_intervals(ivals: list) -> list:
     return out
 
 
-def _thread_report(tspans: list) -> dict:
-    """Attribution for one (process, thread) track.
+def self_times(tspans: list) -> tuple:
+    """``(self_s by span_id, children by span_id, roots)`` of one
+    (process, thread) track.  A span's self time is its duration less
+    the part of it that its child spans cover (their union, clipped to
+    the span) — so over a tree the self times sum to the root's wall,
+    whatever the nesting depth.  A span whose parent is not on the track
+    (none recorded, or a manual span) is a root."""
+    by_id = {s["span_id"]: s for s in tspans if s["span_id"] is not None}
+    kids: dict = {}
+    roots = []
+    for s in tspans:
+        if s["parent_id"] in by_id:
+            kids.setdefault(s["parent_id"], []).append(s)
+        else:
+            roots.append(s)
+    self_s = {}
+    for s in tspans:
+        lo, hi = s["ts"], s["ts"] + s["dur"]
+        covered = sum(
+            e - b for b, e in _merge_intervals(
+                [[max(lo, c["ts"]), min(hi, c["ts"] + c["dur"])]
+                 for c in kids.get(s["span_id"], ())
+                 if c["ts"] < hi and c["ts"] + c["dur"] > lo]))
+        self_s[id(s)] = max(0.0, s["dur"] - covered)
+    return self_s, kids, roots
 
-    Top-level spans (no parent) carve the track's wall into named work
-    and the gaps between them; nested spans refine but never double-
-    count.  ``attributed_frac`` is the acceptance metric: the share of
-    the track's span wall (first start to last end) covered by named
-    top-level spans, the remainder being reported as gaps — so
-    attributed + gaps == 1.0 by construction, and the interesting
-    number is how much of the wall the *named* side claims.
+
+def _thread_report(tspans: list, tree: tuple) -> dict:
+    """Attribution for one (process, thread) track, by **self time**.
+
+    The track's wall runs from its first root span's start to its last
+    one's end; what no root covers is gaps, so attributed + gaps == 1.0
+    by construction.  Inside the roots every second belongs to exactly
+    one span — the innermost open one — and ``phases`` totals that self
+    time per span name: a ``pass`` root that wraps the whole run reads
+    as what its children left uncovered, not as 100 %.  ``total_s`` is
+    the name's self time, ``span_s`` its spans' plain durations.
+    ``tree`` is :func:`self_times` of the track.
     """
-    top = [s for s in tspans if s["parent_id"] is None]
-    if not top:
-        top = tspans  # manual-span tracks (tickets/workers) have no stack
+    self_s, _kids, top = tree
     t0 = min(s["ts"] for s in top)
     t1 = max(s["ts"] + s["dur"] for s in top)
     wall = max(1e-9, t1 - t0)
@@ -288,56 +307,51 @@ def _thread_report(tspans: list) -> dict:
             gaps.append({"ts": prev, "dur": s - prev})
         prev = max(prev, e)
     phases: dict = {}
-    counts: dict = {}
-    for s in top:
-        phases[s["name"]] = phases.get(s["name"], 0.0) + s["dur"]
-        counts[s["name"]] = counts.get(s["name"], 0) + 1
+    for s in tspans:
+        ph = phases.setdefault(s["name"], [0.0, 0.0, 0])
+        ph[0] += self_s[id(s)]
+        ph[1] += s["dur"]
+        ph[2] += 1
     return {"wall_s": wall, "t0": t0, "t1": t1,
             "attributed_s": covered,
             "attributed_frac": covered / wall,
-            "phases": {k: {"total_s": v, "n": counts[k],
-                           "frac": v / wall}
+            "phases": {k: {"total_s": v[0], "span_s": v[1], "n": v[2],
+                           "frac": v[0] / wall}
                        for k, v in sorted(phases.items(),
-                                          key=lambda kv: -kv[1])},
+                                          key=lambda kv: -kv[1][0])},
             "gap_s": wall - covered,
             "gap_frac": (wall - covered) / wall,
             "largest_gaps": sorted(gaps, key=lambda g: -g["dur"])[:5]}
 
 
-def _level_critical_path(col: dict, proc: dict, threads: dict) -> list:
-    """Per-level summary for one process: each level's wall (between
-    consecutive ``level_end`` stamps) and its dominant main-track phase
-    — the critical-path row the report prints per level."""
-    marks = sorted((lv for lv in col["levels"]
-                    if lv["pid"] == proc["pid"]),
-                   key=lambda lv: lv["ts"])
-    if not marks:
-        return []
-    main = threads.get("MainThread") or threads.get("main")
-    tspans = main or []
-    out = []
-    prev_ts = min((s["ts"] for s in tspans), default=marks[0]["ts"])
-    prev_n = 0
-    for m in marks:
-        window = [s for s in tspans
-                  if prev_ts <= s["ts"] < m["ts"]
-                  and s["parent_id"] is None]
-        acc: dict = {}
-        for s in window:
-            acc[s["name"]] = acc.get(s["name"], 0.0) + s["dur"]
-        dom = max(acc.items(), key=lambda kv: kv[1]) if acc else None
-        out.append({"level": m["level"],
-                    "wall_s": m["ts"] - prev_ts,
-                    "new_states": m["n_states"] - prev_n,
-                    "dominant_phase": dom[0] if dom else None,
-                    "dominant_s": dom[1] if dom else 0.0})
-        prev_ts, prev_n = m["ts"], m["n_states"]
-    return out
+def _level_rows(threads: dict, trees: dict) -> list:
+    """One row per ``level`` span of a process (the ddd engines open one
+    per BFS level, with their work counts as ``args``): wall, the counts,
+    the level's own self time and the child name that took most of it."""
+    rows = []
+    for name, tspans in threads.items():
+        self_s, kids, _roots = trees[name]
+        for s in (s for s in tspans if s["name"] == "level"):
+            acc: dict = {}
+            for c in kids.get(s["span_id"], ()):
+                acc[c["name"]] = acc.get(c["name"], 0.0) + c["dur"]
+            dom = max(acc.items(), key=lambda kv: kv[1]) if acc else None
+            a = s["args"]
+            rows.append({"level": a.get("level"), "ts": s["ts"],
+                         "wall_s": s["dur"], "rows": a.get("rows"),
+                         "segments": a.get("segments"),
+                         "steps": a.get("steps"),
+                         "new_states": a.get("new_states"),
+                         "self_s": self_s[id(s)],
+                         "dominant_child": dom[0] if dom else None,
+                         "dominant_s": dom[1] if dom else 0.0})
+    return sorted(rows, key=lambda r: r["ts"])
 
 
 def report(col: dict) -> dict:
     """Wall attribution over a collection: per process, per thread —
-    named-phase totals, idle gaps, and the per-level critical path."""
+    self time by span name and idle gaps — and one row per ``level``
+    span."""
     by_track: dict = {}
     for s in col["spans"]:
         by_track.setdefault(s["pid"], {}).setdefault(
@@ -345,14 +359,16 @@ def report(col: dict) -> dict:
     procs = []
     for proc in col["processes"]:
         threads = by_track.get(proc["pid"], {})
+        trees = {name: self_times(tspans)
+                 for name, tspans in threads.items()}
         procs.append({
             "pid": proc["pid"], "os_pid": proc["os_pid"],
             "label": proc["label"],
             "anchored": proc["anchored"],
             "skew_bound_s": proc["skew_bound_s"],
-            "threads": {name: _thread_report(tspans)
+            "threads": {name: _thread_report(tspans, trees[name])
                         for name, tspans in sorted(threads.items())},
-            "levels": _level_critical_path(col, proc, threads),
+            "levels": _level_rows(threads, trees),
         })
     return {"processes": procs,
             "t_min": col["t_min"], "t_max": col["t_max"],
@@ -380,15 +396,18 @@ def render_report(rep: dict) -> str:
                 f"{100 * tr['gap_frac']:.1f}% gaps")
             for pname, ph in tr["phases"].items():
                 lines.append(
-                    f"    {pname:<14} {ph['total_s']:8.3f}s "
-                    f"{100 * ph['frac']:5.1f}%  x{ph['n']}")
+                    f"    {pname:<14} {ph['total_s']:8.3f}s self "
+                    f"{100 * ph['frac']:5.1f}%  x{ph['n']}"
+                    f"  ({ph['span_s']:.3f}s in spans)")
             for g in tr["largest_gaps"][:3]:
                 lines.append(f"    (gap)          {g['dur']:8.3f}s "
                              f"at +{g['ts'] - rep['t_min']:.3f}s")
         for lv in proc["levels"]:
-            dom = (f"{lv['dominant_phase']} {lv['dominant_s']:.3f}s"
-                   if lv["dominant_phase"] else "-")
-            lines.append(f"  L{lv['level']}: {lv['wall_s']:.3f}s, "
-                         f"+{lv['new_states']:,} states, "
-                         f"critical: {dom}")
+            dom = (f"{lv['dominant_child']} {lv['dominant_s']:.3f}s"
+                   if lv["dominant_child"] else "-")
+            lines.append(
+                f"  L{lv['level']}: {lv['wall_s']:.3f}s, "
+                f"{lv['rows']} rows, {lv['segments']} segments, "
+                f"{lv['steps']} steps, +{lv['new_states']} states, "
+                f"self {lv['self_s']:.3f}s, most in: {dom}")
     return "\n".join(lines)

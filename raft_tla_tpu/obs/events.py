@@ -25,7 +25,7 @@ wall).  This module replaces all of that with:
   ``level_end`` derived automatically from level transitions and
   ``violation`` derived from the final :class:`~raft_tla_tpu.engine.EngineResult`.
 
-Event grammar (``SCHEMA_VERSION`` = 10; earlier-version lines remain
+Event grammar (``SCHEMA_VERSION`` = 11; earlier-version lines remain
 valid) —
 every line is one JSON object with base fields ``v`` (schema version),
 ``event`` (type) and ``ts`` (unix epoch seconds):
@@ -149,13 +149,25 @@ Version 10 adds the live metrics layer (obs/metrics.py — gated by
                     the event log alone; ``port`` the bound endpoint
                     port, ``root`` the swept log directory)
 
+Version 11 adds the compile ledger's totals (obs/compiles.py):
+``run_end.compiles`` — what JAX compiled between this run's start and
+end, ``{"trace"|"lower"|"backend"|"cache_load": [events, seconds],
+"cache_requests"|"cache_hits"|"cache_misses": count}``, kinds that did
+not move left out; in a traced run's log only (seconds are volatile), and
+absent where the process never installed the ledger.
+Version 11 also grows the ddd engines' ``span`` vocabulary into a tree
+(``pass`` > ``level`` > ``upload`` / ``expand`` / ``export`` >
+{``segment_wait``, ``d2h``} / ``level_close``, plus the synthetic
+``segments`` and ``compiles`` tracks) — names and ``args`` only, the
+``span`` event itself is unchanged since version 8.
+
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the
-v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9-only fields) are
-invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` / ``"v" < 10``
+v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11-only fields)
+are invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` / ``"v" < 10``
 (resp. ``"v" < 3`` / ``"v" < 4`` / ``"v" < 5`` / ``"v" < 6`` /
-``"v" < 8`` / ``"v" < 9``) line, so any addition requires a version
-bump (versioning policy in README.md).
+``"v" < 8`` / ``"v" < 9`` / ``"v" < 11``) line, so any addition requires
+a version bump (versioning policy in README.md).
 """
 
 from __future__ import annotations
@@ -168,8 +180,8 @@ import subprocess
 import threading
 import time
 
-SCHEMA_VERSION = 10
-_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10)  # versions validate_event accepts
+SCHEMA_VERSION = 11
+_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11)  # versions validate_event accepts
 
 # Environment knobs (set by check.py --events/--phase-timers; inherited by
 # liveness re-runs and bench children the same way RAFT_TLA_SIGPRUNE is).
@@ -283,6 +295,10 @@ _V8_FIELDS = {"run_start": frozenset({"anchor", "host"})}
 # attribution) — invalid on a "v" < 9 line.
 _V9_FIELDS = {"segment": frozenset({"export_rows", "dev_dedup_hits"})}
 
+# Fields that only exist from schema version 11 on (the compile
+# ledger's per-run totals) — invalid on a "v" < 11 line.
+_V11_FIELDS = {"run_end": frozenset({"compiles"})}
+
 _OPTIONAL = {
     "run_start": {"bounds": dict, "symmetry": list, "view": str,
                   "chunk": int, "caps": str, "n_states": int,
@@ -298,7 +314,7 @@ _OPTIONAL = {
     "violation": {"kind": str},
     "stop_requested": {"source": str, "pid": int},
     "run_end": {"diameter": int, "levels": list, "wall_s": _NUM,
-                "sim": dict},
+                "sim": dict, "compiles": dict},
     "preempt": {"detail": str, "pid": int, "stale_s": _NUM,
                 "drift": dict},
     "reshard": {"n_states": int, "path": str, "block": int},
@@ -357,6 +373,7 @@ def validate_event(d: dict) -> list:
     v6_only = _V6_FIELDS.get(ev, frozenset())
     v8_only = _V8_FIELDS.get(ev, frozenset())
     v9_only = _V9_FIELDS.get(ev, frozenset())
+    v11_only = _V11_FIELDS.get(ev, frozenset())
     for k, val in d.items():
         if k in _BASE or k in req:
             continue
@@ -377,6 +394,8 @@ def validate_event(d: dict) -> list:
             errs.append(f"{ev}: field {k!r} requires schema version >= 8")
         elif k in v9_only and d["v"] in _VERSIONS and d["v"] < 9:
             errs.append(f"{ev}: field {k!r} requires schema version >= 9")
+        elif k in v11_only and d["v"] in _VERSIONS and d["v"] < 11:
+            errs.append(f"{ev}: field {k!r} requires schema version >= 11")
     return errs
 
 
@@ -628,6 +647,7 @@ class RunTelemetry:
                  on_progress=None, events: str | None = None,
                  resumed: bool = False, n0: int | None = 1,
                  n_devices: int | None = None, t0: float | None = None):
+        from raft_tla_tpu.obs import compiles
         from raft_tla_tpu.obs.phases import PhaseTimers
         from raft_tla_tpu.obs.trace import (NULL_TRACER, SpanTracer,
                                             trace_enabled)
@@ -643,6 +663,12 @@ class RunTelemetry:
         self.trace = (SpanTracer(self.log.emit)
                       if self.log is not None and trace_enabled()
                       else NULL_TRACER)
+        if self.trace.enabled:
+            self._annotate_spans()
+            compiles.LEDGER.attach(self.trace)
+        # what the process had compiled when this run began: a traced
+        # run's run_end reports the difference
+        self._compiles0 = compiles.LEDGER.totals()
         self.phases = PhaseTimers.from_env()
         self.phases.tracer = self.trace
         inv = tuple(config.invariants) if config is not None else ()
@@ -657,6 +683,18 @@ class RunTelemetry:
     def active(self) -> bool:
         """True when someone is listening (else skip the stats fetches)."""
         return self.on_progress is not None or self.log is not None
+
+    def _annotate_spans(self) -> None:
+        """One clock: in a process that has opened a backend, a live
+        tracer's spans are ``jax.profiler.TraceAnnotation``s as well, so
+        a profiler capture holds them beside the device ops.  Like
+        ``host_context`` this never opens a backend (a supervisor's log
+        goes through here too) and imports JAX only behind that test."""
+        from raft_tla_tpu.utils import device
+        if device.backends_initialized():
+            import jax.profiler
+            # set here, before the engine starts its worker threads
+            self.trace.annotate = jax.profiler.TraceAnnotation
 
     # -- lifecycle events ---------------------------------------------------
 
@@ -774,7 +812,17 @@ class RunTelemetry:
             n_transitions=int(result.n_transitions),
             complete=bool(result.complete), outcome=outcome,
             diameter=int(result.diameter), levels=list(result.levels),
-            wall_s=round(float(result.wall_s), 3))
+            wall_s=round(float(result.wall_s), 3), **self._compiles())
+
+    def _compiles(self) -> dict:
+        """``run_end.compiles``: the ledger's totals over this run, in a
+        traced run's log only — seconds are volatile, and untraced logs
+        stay comparable line for line (serve's parity checks)."""
+        from raft_tla_tpu.obs import compiles
+        if not self.trace.enabled or not compiles.LEDGER.installed:
+            return {}
+        return {"compiles": compiles.totals_since(
+            self._compiles0, compiles.LEDGER.totals())}
 
     def run_end_sim(self, *, n_states: int, n_behaviors: int,
                     max_depth: int, wall_s: float, complete: bool,
@@ -806,5 +854,8 @@ class RunTelemetry:
         self.log.emit("run_end", **fields)
 
     def close(self) -> None:
+        if self.trace.enabled:
+            from raft_tla_tpu.obs import compiles
+            compiles.LEDGER.detach(self.trace)
         if self.log is not None:
             self.log.close()

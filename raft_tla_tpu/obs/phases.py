@@ -84,6 +84,9 @@ class _NullPhase:
     def sync(self, value=None):
         return value
 
+    def set(self, **args):
+        return self
+
 
 _NULL = _NullPhase()
 
@@ -109,6 +112,13 @@ class _Phase:
     def sync(self, value=None):
         self._pending = value
         return value
+
+    def set(self, **args):
+        """Work counts for the phase's span (rows, bytes, keys); the
+        ``phase_s`` bucket holds seconds only and ignores them."""
+        if self._span is not None:
+            self._span.set(**args)
+        return self
 
     def __exit__(self, *exc):
         timers = self._timers

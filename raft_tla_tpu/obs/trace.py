@@ -31,6 +31,22 @@ Design points, in the order they matter:
   spans on synthetic tracks (``thread="tickets"``/``"workers"``) for
   lifetimes that start and end in different stack frames (dispatch
   tickets, pool worker lifetimes).
+- **A causal tree with work counts.**  A span's ``parent_id`` is the
+  innermost span open on its thread, and ``set(**args)`` attaches how
+  much work the region held (rows, bytes, steps, keys), so a reader can
+  compute self time (duration less what the children cover) and rates
+  without a second source.  The ddd engines open ``pass`` > ``level`` >
+  ``upload`` / ``expand`` / ``export`` > {``segment_wait``, ``d2h``} /
+  ``level_close``; :meth:`SpanTracer.open` gives the explicit
+  open/close handle for the level loop, whose body is too long to
+  re-indent.
+- **One clock with the profiler.**  A tracer built with an ``annotate``
+  factory (``jax.profiler.TraceAnnotation``; ``RunTelemetry`` hands it
+  in once the process has opened a backend — this module never imports
+  JAX, supervisors import it) opens each span as a host annotation of
+  the same name and ``span_id`` too: a ``jax.profiler`` capture taken
+  while tracing is on holds the program's spans, from every thread, on
+  the profiler's own clock beside the device ops.
 - **One sink, no new I/O machinery.**  Spans ride the existing
   non-blocking ``EventLog`` (engines: ``tracer = SpanTracer(log.emit)``)
   or the synchronous validated ``append_event`` (supervisors, low rate),
@@ -98,6 +114,9 @@ class _NullSpan:
     def set(self, **args):
         return self
 
+    def close(self):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -112,6 +131,9 @@ class NullTracer:
     def span(self, name: str, **args):
         return _NULL_SPAN
 
+    def open(self, name: str, **args):
+        return _NULL_SPAN
+
     def emit_span(self, name: str, t0: float, dur: float,
                   thread: str | None = None, **args) -> None:
         pass
@@ -124,14 +146,21 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """An open traced region; emitted as one ``span`` event at exit."""
+    """An open traced region; emitted as one ``span`` event at exit.
 
-    __slots__ = ("_tr", "_name", "_args", "_id", "_parent", "_t0")
+    Used as a context manager, or — for a region whose body is too long
+    to re-indent (the ddd engines' per-level loop) — through
+    :meth:`SpanTracer.open` and :meth:`close`, which push and pop the
+    same per-thread parent stack.  ``close`` is idempotent, so a region
+    with several exits can close at each and once more after them."""
+
+    __slots__ = ("_tr", "_name", "_args", "_id", "_parent", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self._tr = tracer
         self._name = name
         self._args = args
+        self._t0 = None
 
     def __enter__(self):
         tr = self._tr
@@ -139,16 +168,32 @@ class _Span:
         self._parent = stack[-1] if stack else None
         self._id = next(tr._ids)
         stack.append(self._id)
+        ann = tr.annotate
         self._t0 = time.monotonic()
+        # the profiler's own clock: the same region as a TraceMe in any
+        # capture that is running (a flag test when none is)
+        self._ann = ann(self._name, span_id=self._id) \
+            if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def set(self, **args):
         """Attach result attributes discovered inside the region (row
-        counts, hit/miss) — lands in the event's ``args`` dict."""
-        self._args.update(args)
+        counts, hit/miss) — lands in the event's ``args`` dict.  A no-op
+        once the span has closed: the event is with the sink then."""
+        if self._t0 is not None:
+            self._args.update(args)
         return self
 
+    def close(self):
+        self.__exit__(None, None, None)
+
     def __exit__(self, *exc):
+        if self._t0 is None:             # never opened, or closed already
+            return False
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         dur = time.monotonic() - self._t0
         stack = self._tr._stack()
         if stack and stack[-1] == self._id:
@@ -160,6 +205,7 @@ class _Span:
             fields["parent_id"] = self._parent
         if self._args:
             fields["args"] = self._args
+        self._t0 = None
         self._tr._emit("span", **fields)
         return False
 
@@ -172,12 +218,20 @@ class SpanTracer:
     ``functools.partial(append_event, path)`` (synchronous + validated;
     supervisors).  Span ids are unique per tracer; parenthood nests via
     a per-thread stack, so concurrent threads trace independently.
+
+    ``annotate`` (``jax.profiler.TraceAnnotation``, handed in by whoever
+    builds the tracer in a process that has opened a backend — this
+    module never imports JAX) opens each span a second time as a host
+    annotation named like it, with its ``span_id``: a ``jax.profiler``
+    capture then holds the program's spans of every thread on the
+    profiler's own clock, beside the device ops.
     """
 
     enabled = True
 
-    def __init__(self, emit):
+    def __init__(self, emit, annotate=None):
         self._emit = emit
+        self.annotate = annotate         # set once, before any worker runs
         self._ids = itertools.count(1)   # CPython-atomic __next__
         self._tls = threading.local()
 
@@ -190,6 +244,11 @@ class SpanTracer:
     def span(self, name: str, **args) -> _Span:
         """Context manager for a region on the current thread."""
         return _Span(self, name, args)
+
+    def open(self, name: str, **args) -> _Span:
+        """The same region as an explicit handle, already entered: pair
+        with ``handle.close()`` on the same thread."""
+        return _Span(self, name, args).__enter__()
 
     def emit_span(self, name: str, t0: float, dur: float,
                   thread: str | None = None, **args) -> None:

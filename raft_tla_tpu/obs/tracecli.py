@@ -9,7 +9,7 @@ Three subcommands over the logs a ``--trace`` run leaves behind:
 - ``export PATH... -o trace.json`` — the same merge, written as Chrome
   ``trace_event`` JSON for ui.perfetto.dev / chrome://tracing.
 - ``report PATH...`` — wall attribution: per process and thread, named-
-  phase totals and idle gaps; per level, the critical-path summary.
+  self-time totals and idle gaps; one row per ``level`` span.
   ``--json`` prints the machine form.
 
 Typical flow after a traced pool run::
@@ -86,7 +86,7 @@ def main(argv=None) -> int:
 
     pr = sub.add_parser("report",
                         help="wall attribution: phases, gaps, per-level "
-                             "critical path")
+                             "level rows")
     pr.add_argument("paths", nargs="+", metavar="PATH")
     pr.add_argument("--json", action="store_true",
                     help="print the machine-readable report")

@@ -581,6 +581,20 @@ def _alllogs_update(bounds, s, n_lanes):
     return jnp.broadcast_to(new_all, (n_lanes, Wa))
 
 
+# The compiled step's stage scopes (``jax.named_scope``: HLO metadata
+# only — no computation and no compile-cache key changes with them).  A
+# device trace names each op's scope path, so per-stage device time is
+# readable from a capture (benchmark ``stage_*_ms``) and stays readable
+# when an edit renumbers the fusions.  ``unpack``/``expand``/``pack``
+# open in the step builders, ``prescan`` (raw fingerprint + ladder
+# compaction, its scans nested as ``orbit_scan``) to ``constraint`` in
+# :func:`apply_stages`, ``filter_insert`` and ``stream`` (compaction into
+# the segment buffers at the cursor) in ``ddd_engine._build_segment``.
+STAGE_SCOPES = ("unpack", "expand", "pack", "prescan", "orbit_scan",
+                "plain_fp", "invariants", "constraint", "filter_insert",
+                "stream")
+
+
 def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
                  symmetry: tuple, view: str | None = None,
                  family_kernels=None):
@@ -678,9 +692,12 @@ def build_step(bounds: Bounds, spec: str = "full", invariants: tuple = (),
     expand = stages[2]
 
     def step(vecs):
-        structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(vecs)
-        succs, valid, ovf = jax.vmap(expand)(structs)
-        svecs = jax.vmap(jax.vmap(lambda t: st.pack(t, jnp)))(succs)
+        with jax.named_scope("unpack"):
+            structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(vecs)
+        with jax.named_scope("expand"):
+            succs, valid, ovf = jax.vmap(expand)(structs)
+        with jax.named_scope("pack"):
+            svecs = jax.vmap(jax.vmap(lambda t: st.pack(t, jnp)))(succs)
         # (EP-routed twin: build_step_routed compacts the valid lanes
         # before these per-candidate stages — same values, K-shaped.)
         fp_hi, fp_lo, inv_ok, con_ok = apply_stages(
@@ -828,6 +845,8 @@ def _orbit_fp_prescan(orbit_fp, flat, raw_hi, raw_lo, N):
     _PRESCAN_RUNGS comment; runs/step_anatomy.out has the measured
     justification).  Keys are (hi, lo) uint32 pairs — x64 is disabled,
     a u64 fuse would silently truncate."""
+    # traced under apply_stages' ``prescan`` scope; the scans it places
+    # are ``orbit_scan`` inside it
     idx = jnp.lexsort((raw_lo, raw_hi))
     sh, sl = raw_hi[idx], raw_lo[idx]
     first = jnp.concatenate(
@@ -845,13 +864,15 @@ def _orbit_fp_prescan(orbit_fp, flat, raw_hi, raw_lo, N):
                 jnp.where(first, gid_sorted, K)].set(
                 idx.astype(jnp.int32), mode="drop")
             flat_k = jax.tree.map(lambda a: a[rep], flat)
-            fh_k, fl_k = orbit_fp(flat_k)
+            with jax.named_scope("orbit_scan"):
+                fh_k, fl_k = orbit_fp(flat_k)
             return fh_k[gid], fl_k[gid]
 
         return compact
 
     def full(_):
-        return orbit_fp(flat)
+        with jax.named_scope("orbit_scan"):
+            return orbit_fp(flat)
 
     # build the elif chain inside-out: largest K wraps full first, so
     # the final test order is smallest-K-first (tightest rung wins)
@@ -889,12 +910,15 @@ def apply_stages(bounds, stages, symmetry, succs, svecs, valid):
             # wrong).  In-chunk raw collisions are strictly inside the
             # globally-accepted fp-collision class; invalid lanes
             # collapse into one all-ones sentinel group
-            rh, rl = fpr.fingerprint(svecs.reshape(N, -1), consts, jnp)
-            rh = jnp.where(vmask, rh, ~jnp.uint32(0))
-            rl = jnp.where(vmask, rl, ~jnp.uint32(0))
-            fh, fl = _orbit_fp_prescan(orbit_fp, flat, rh, rl, N)
+            with jax.named_scope("prescan"):
+                rh, rl = fpr.fingerprint(svecs.reshape(N, -1), consts,
+                                         jnp)
+                rh = jnp.where(vmask, rh, ~jnp.uint32(0))
+                rl = jnp.where(vmask, rl, ~jnp.uint32(0))
+                fh, fl = _orbit_fp_prescan(orbit_fp, flat, rh, rl, N)
         else:
-            fh, fl = orbit_fp(flat)
+            with jax.named_scope("orbit_scan"):
+                fh, fl = orbit_fp(flat)
         # invalid lanes: ZERO, not whichever garbage the sentinel
         # group's rep produced — deterministic across step variants
         # (the CP per-lane parity test compares every lane)
@@ -903,14 +927,17 @@ def apply_stages(bounds, stages, symmetry, succs, svecs, valid):
         fp_hi = fh.reshape(svecs.shape[:2])
         fp_lo = fl.reshape(svecs.shape[:2])
     else:
-        fp_hi, fp_lo = fpr.fingerprint(ksvecs, consts, jnp)
-    if inv_fns:
-        inv_ok = jnp.stack(
-            [jax.vmap(jax.vmap(f))(succs) for f in inv_fns], axis=-1)
-    else:
-        inv_ok = jnp.ones(valid.shape + (0,), dtype=bool)
-    con_ok = jax.vmap(jax.vmap(
-        lambda t: st.constraint_ok(t, bounds, jnp)))(succs)
+        with jax.named_scope("plain_fp"):
+            fp_hi, fp_lo = fpr.fingerprint(ksvecs, consts, jnp)
+    with jax.named_scope("invariants"):
+        if inv_fns:
+            inv_ok = jnp.stack(
+                [jax.vmap(jax.vmap(f))(succs) for f in inv_fns], axis=-1)
+        else:
+            inv_ok = jnp.ones(valid.shape + (0,), dtype=bool)
+    with jax.named_scope("constraint"):
+        con_ok = jax.vmap(jax.vmap(
+            lambda t: st.constraint_ok(t, bounds, jnp)))(succs)
     return fp_hi, fp_lo, inv_ok, con_ok
 
 
@@ -978,8 +1005,10 @@ def build_step_routed(bounds: Bounds, spec: str = "full",
 
     def step(vecs, row_ok=None):
         B = vecs.shape[0]
-        structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(vecs)
-        succs, valid, ovf = jax.vmap(expand)(structs)
+        with jax.named_scope("unpack"):
+            structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(vecs)
+        with jax.named_scope("expand"):
+            succs, valid, ovf = jax.vmap(expand)(structs)
         A = valid.shape[1]
         N = B * A
         live = valid if row_ok is None else valid & row_ok[:, None]
@@ -998,23 +1027,28 @@ def build_step_routed(bounds: Bounds, spec: str = "full",
         gidx = jnp.minimum(cidx, N - 1)
         flat = jax.tree.map(lambda a: a.reshape((N,) + a.shape[2:]), succs)
         csucc = jax.tree.map(lambda a: a[gidx], flat)
-        csvecs = jax.vmap(lambda t: st.pack(t, jnp))(csucc)
+        with jax.named_scope("pack"):
+            csvecs = jax.vmap(lambda t: st.pack(t, jnp))(csucc)
         ksucc, ksvecs = csucc, csvecs      # dedup-key inputs
         if viewer is not None:
             ksucc = jax.vmap(viewer)(csucc)
             if not symmetry:
                 ksvecs = jax.vmap(lambda t: st.pack(t, jnp))(ksucc)
         if symmetry:
-            cfp_hi, cfp_lo = orbit_fp(ksucc)
+            with jax.named_scope("orbit_scan"):
+                cfp_hi, cfp_lo = orbit_fp(ksucc)
         else:
-            cfp_hi, cfp_lo = fpr.fingerprint(ksvecs, consts, jnp)
-        if inv_fns:
-            cinv_ok = jnp.stack([jax.vmap(f)(csucc) for f in inv_fns],
-                                axis=-1)
-        else:
-            cinv_ok = jnp.ones((K, 0), dtype=bool)
-        ccon_ok = jax.vmap(
-            lambda t: st.constraint_ok(t, bounds, jnp))(csucc)
+            with jax.named_scope("plain_fp"):
+                cfp_hi, cfp_lo = fpr.fingerprint(ksvecs, consts, jnp)
+        with jax.named_scope("invariants"):
+            if inv_fns:
+                cinv_ok = jnp.stack([jax.vmap(f)(csucc) for f in inv_fns],
+                                    axis=-1)
+            else:
+                cinv_ok = jnp.ones((K, 0), dtype=bool)
+        with jax.named_scope("constraint"):
+            ccon_ok = jax.vmap(
+                lambda t: st.constraint_ok(t, bounds, jnp))(csucc)
         return {"valid": valid, "overflow": ovf, "cidx": cidx,
                 "cvalid": cvalid, "csvecs": csvecs, "cfp_hi": cfp_hi,
                 "cfp_lo": cfp_lo, "cinv_ok": cinv_ok, "ccon_ok": ccon_ok,

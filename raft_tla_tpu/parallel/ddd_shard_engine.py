@@ -291,7 +291,11 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
             rows_l = r0 + jnp.arange(B, dtype=I32)
             row_act = rows_l < nrows[0]
             bidx = jnp.minimum(rows_l, caps.block - 1)
-            vecs = schema.unpack(fbuf[bidx], jnp)
+            # stage scopes as in ddd_engine (kernels.STAGE_SCOPES), plus
+            # ``exchange`` around the all_to_all: a device trace names
+            # the collective's ops, nested ones included
+            with jax.named_scope("unpack"):
+                vecs = schema.unpack(fbuf[bidx], jnp)
             row_ok = row_act & fcon[bidx]
             if caps.cp:
                 dev = jax.lax.axis_index(_AXIS).astype(I32) \
@@ -320,7 +324,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
             # ---- route candidates to their fingerprint owners ----
             fhi = out["fp_hi"].reshape(BA)
             flo = out["fp_lo"].reshape(BA)
-            svecs = schema.pack(out["svecs"].reshape(BA, W), jnp)
+            with jax.named_scope("pack"):
+                svecs = schema.pack(out["svecs"].reshape(BA, W), jnp)
             par_g = fpar[r0 + jnp.arange(BA, dtype=I32) // A_loc]
             if caps.cp:
                 # dense action-table index of each local lane (coverage
@@ -337,35 +342,42 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                     axis=1)
 
             dest_a = jnp.where(fvalid, owner(fhi) % nici, nici)
-            (r_vec, r_hi, r_lo, r_par, r_lane, r_flags), ovf = exchange(
-                _AXIS, nici, Csend, dest_a,
-                ((svecs, 0, I32), (fhi, _EMPTY, U32), (flo, _EMPTY, U32),
-                 (par_g, -1, I32), (lane_a, -1, I32), (flags, 0, I32)))
+            with jax.named_scope("exchange"):
+                (r_vec, r_hi, r_lo, r_par, r_lane, r_flags), ovf = \
+                    exchange(
+                        _AXIS, nici, Csend, dest_a,
+                        ((svecs, 0, I32), (fhi, _EMPTY, U32),
+                         (flo, _EMPTY, U32), (par_g, -1, I32),
+                         (lane_a, -1, I32), (flags, 0, I32)))
             fa = fa | ovf.astype(I32) * FAIL_ROUTE
             active = (r_flags & 1) == 1
             if nslice > 1:
                 dest_b = jnp.where(active, owner(r_hi) // nici, nslice)
-                (r_vec, r_hi, r_lo, r_par, r_lane, r_flags), ovf2 = \
-                    exchange(
-                        _DCN, nslice, Csend2, dest_b,
-                        ((r_vec, 0, I32), (r_hi, _EMPTY, U32),
-                         (r_lo, _EMPTY, U32), (r_par, -1, I32),
-                         (r_lane, -1, I32), (r_flags, 0, I32)))
+                with jax.named_scope("exchange"):
+                    (r_vec, r_hi, r_lo, r_par, r_lane, r_flags), ovf2 = \
+                        exchange(
+                            _DCN, nslice, Csend2, dest_b,
+                            ((r_vec, 0, I32), (r_hi, _EMPTY, U32),
+                             (r_lo, _EMPTY, U32), (r_par, -1, I32),
+                             (r_lane, -1, I32), (r_flags, 0, I32)))
                 fa = fa | ovf2.astype(I32) * FAIL_ROUTE
                 active = (r_flags & 1) == 1
 
             # ---- owner-side lossy filter; stream to my buffer ----
-            tbl_hi, tbl_lo, stream = _filter_insert(tbl_hi, tbl_lo, r_hi,
-                                                    r_lo, active)
-            pos = cur + jnp.cumsum(stream.astype(I32)) - 1
-            sl = jnp.where(stream, pos, OCAP)
-            okey_hi = okey_hi.at[sl].set(r_hi, mode="drop")
-            okey_lo = okey_lo.at[sl].set(r_lo, mode="drop")
-            orows = orows.at[sl].set(r_vec, mode="drop")
-            opar = opar.at[sl].set(r_par, mode="drop")
-            olane = olane.at[sl].set(r_lane, mode="drop")
-            ocon = ocon.at[sl].set(((r_flags >> 1) & 1) == 1, mode="drop")
-            cur = cur + jnp.sum(stream.astype(I32))
+            with jax.named_scope("filter_insert"):
+                tbl_hi, tbl_lo, stream = _filter_insert(
+                    tbl_hi, tbl_lo, r_hi, r_lo, active)
+            with jax.named_scope("stream"):
+                pos = cur + jnp.cumsum(stream.astype(I32)) - 1
+                sl = jnp.where(stream, pos, OCAP)
+                okey_hi = okey_hi.at[sl].set(r_hi, mode="drop")
+                okey_lo = okey_lo.at[sl].set(r_lo, mode="drop")
+                orows = orows.at[sl].set(r_vec, mode="drop")
+                opar = opar.at[sl].set(r_par, mode="drop")
+                olane = olane.at[sl].set(r_lane, mode="drop")
+                ocon = ocon.at[sl].set(((r_flags >> 1) & 1) == 1,
+                                       mode="drop")
+                cur = cur + jnp.sum(stream.astype(I32))
 
             # ---- first violating streamed candidate (relaxed stop) ----
             if n_inv:
@@ -740,6 +752,13 @@ class DDDShardEngine:
             resumed=resume is not None, n0=1,
             n_devices=self.ndev, t0=t0)
         _cleanup.callback(tel.close)
+        # the ddd engine's span tree, where this loop has the same seams
+        # (host side only): pass > level > upload / expand / export >
+        # {segment_wait, d2h} / level_close
+        tr = tel.trace
+        pass_sp = tr.open("pass", engine="ddd-shard",
+                          resumed=resume is not None)
+        _cleanup.callback(pass_sp.close)     # raise paths; idempotent
         bounds = self.bounds
         init_py = init_override if init_override is not None \
             else interp.init_state(bounds)
@@ -754,6 +773,8 @@ class DDDShardEngine:
                     coverage=Counter(),
                     violation=Violation(nm, init_py, [(None, init_py)]),
                     levels=[1], wall_s=time.monotonic() - t0)
+                pass_sp.set(levels=1, n_states=1,
+                            stopped_by="violation").close()
                 tel.run_end(res)
                 return res
 
@@ -900,10 +921,22 @@ class DDDShardEngine:
                 export_rows=export_rows,
                 dev_dedup_hits=dd_hits if self._dd_apply else None)
 
+        lvl_segs = lvl_steps = 0             # the open level's work
+
+        def end_level():
+            level_sp.set(segments=lvl_segs, steps=lvl_steps,
+                         new_states=n_states - lvl_hi).close()
+
         while not stopped:
             lvl_lo = level_ends[-2] if len(level_ends) > 1 else 0
             lvl_hi = level_ends[-1]
             w0 = lvl_lo + blocks_done * W
+            # explicit handle: every exit of the body lands on
+            # end_level(), here or after the loop (close is idempotent)
+            level_sp = tr.open("level", level=len(level_ends),
+                               rows=lvl_hi - lvl_lo,
+                               blocks=-(-(lvl_hi - w0) // W))
+            lvl_segs = lvl_steps = 0
             if prefetcher is not None and w0 < lvl_hi:
                 # level start: all window addresses are known — warm the
                 # first window immediately
@@ -970,14 +1003,19 @@ class DDDShardEngine:
                         break
                     idx, stats, ncur, dhits, nvp, t_disp = q.pop(0)
                     with tel.phases.phase("export"):
-                        st_h = jax.device_get(stats)
-                        # gate on: harvest the POST-filter cursors —
-                        # dropped rows never cross d2h at all
-                        cursors = np.asarray(st_h.cursor) \
-                            if ncur is None \
-                            else np.asarray(jax.device_get(ncur))
-                        bufs_h = jax.device_get(bufsets[idx]) \
-                            if cursors.sum() and not stopped else None
+                        with tr.span("segment_wait"):
+                            st_h = jax.device_get(stats)
+                            # gate on: harvest the POST-filter cursors —
+                            # dropped rows never cross d2h at all
+                            cursors = np.asarray(st_h.cursor) \
+                                if ncur is None \
+                                else np.asarray(jax.device_get(ncur))
+                        lvl_segs += 1
+                        lvl_steps += int(st_h.steps)
+                        bufs_h = None
+                        if cursors.sum() and not stopped:
+                            with tr.span("d2h", rows=int(cursors.sum())):
+                                bufs_h = jax.device_get(bufsets[idx])
                     free.append(idx)
                     if stopped:
                         continue             # drop post-stop segments
@@ -1099,32 +1137,38 @@ class DDDShardEngine:
                     break
             if stopped:
                 break
-            blocks_done = 0
-            if n_states == level_ends[-1]:       # no new states: done
-                break
-            level_ends.append(n_states)
-            if self._dd_apply is not None:
-                # within-level sets by contract: reset empty at every
-                # boundary (re-sights of previous-level states stream
-                # and the per-shard masters drop them, as with gate off)
-                dst = self._init_devset()
-            if prefetcher is not None:
-                # quiesce before rotation (no-op unless a stop raced the
-                # level end — the last take() consumed the final window)
-                prefetcher.invalidate()
-            if self.caps.retention == "frontier":
-                # finished level's rows are dead weight (snapshots keep
-                # files alive until their npz commits; tmpdir runs have
-                # nothing to resume — delete immediately)
-                keep = self.caps.keep_levels
-                host.rotate(delete_old=tmpdir is not None and not keep)
-                constore.rotate(delete_old=tmpdir is not None
-                                and not keep)
-            progress()
-            if len(level_ends) > self.caps.levels:
-                raise RuntimeError(
-                    f"DDD-shard search aborted: {decode_fail(FAIL_LEVEL)} "
-                    f"(caps={self.caps}) — grow capacities and rerun")
+            with tr.span("level_close"):
+                blocks_done = 0
+                if n_states == level_ends[-1]:       # no new states: done
+                    break
+                level_ends.append(n_states)
+                if self._dd_apply is not None:
+                    # within-level sets by contract: reset empty at every
+                    # boundary (re-sights of previous-level states stream
+                    # and the per-shard masters drop them, as with the
+                    # gate off)
+                    dst = self._init_devset()
+                if prefetcher is not None:
+                    # quiesce before rotation (no-op unless a stop raced
+                    # the level end — the last take() consumed the final
+                    # window)
+                    prefetcher.invalidate()
+                if self.caps.retention == "frontier":
+                    # finished level's rows are dead weight (snapshots
+                    # keep files alive until their npz commits; tmpdir
+                    # runs have nothing to resume — delete immediately)
+                    keep = self.caps.keep_levels
+                    host.rotate(delete_old=tmpdir is not None and not keep)
+                    constore.rotate(delete_old=tmpdir is not None
+                                    and not keep)
+                progress()
+                if len(level_ends) > self.caps.levels:
+                    raise RuntimeError(
+                        "DDD-shard search aborted: "
+                        f"{decode_fail(FAIL_LEVEL)} (caps={self.caps}) — "
+                        "grow capacities and rerun")
+            end_level()
+        end_level()          # the exits by break; a no-op after the above
 
         if prefetcher is not None:
             # stop paths can leave a window prefetch in flight; no store
@@ -1211,6 +1255,9 @@ class DDDShardEngine:
             n_transitions=n_trans, coverage=coverage,
             violation=violation, levels=levels_arr,
             wall_s=time.monotonic() - t0, complete=complete)
+        pass_sp.set(levels=len(levels_arr), n_states=n_states,
+                    stopped_by="violation" if violation is not None
+                    else None if complete else "sigint").close()
         tel.run_end(result)
         return result
 

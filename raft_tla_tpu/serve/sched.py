@@ -95,8 +95,21 @@ def enable_compile_cache(path: str | None = None, *,
     daemon test), and CPU runs here are correctness runs that have no
     use for a warm start."""
     import jax
+
+    from raft_tla_tpu.obs import compiles
+    # every process that compiles comes through here: the compile ledger
+    # (what was traced, lowered, compiled or loaded, and for how long)
+    # listens from now on
+    compiles.install()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # The step's stage scopes (ops/kernels.STAGE_SCOPES) are HLO metadata,
+    # which JAX strips from the cache key by default: a cache filled before
+    # a scope was added or renamed would hand back an executable whose
+    # device trace names the OLD scopes (or none), and every per-stage
+    # reading would be silently wrong.  With metadata in the key a cached
+    # program always carries the names its source has.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if outside:
         return outside

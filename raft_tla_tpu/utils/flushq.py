@@ -72,8 +72,9 @@ class DedupWorker:
             batch, _n_keys = item
             try:
                 if self._phases is not None:
-                    with self._phases.phase("dedup"):
+                    with self._phases.phase("dedup") as ph:
                         n_new = int(self._fn(batch))
+                        ph.set(keys=int(_n_keys), new=n_new)
                 else:
                     n_new = int(self._fn(batch))
                 with self._lock:

@@ -127,7 +127,8 @@ class BlockPrefetcher:
                 self._busy = True
             try:
                 if self._phases is not None:
-                    with self._phases.phase("prefetch"):
+                    with self._phases.phase("prefetch") as ph:
+                        ph.set(start=int(start), rows=int(rows))
                         res, err = self._loader(start, rows, slot), None
                 else:
                     res, err = self._loader(start, rows, slot), None

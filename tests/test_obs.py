@@ -147,7 +147,8 @@ def test_validate_rejects_unknowns_and_type_drift():
     assert validate_event({**ok, "v": 8}) == []             # v8 superset
     assert validate_event({**ok, "v": 9}) == []             # v9 superset
     assert validate_event({**ok, "v": 10}) == []            # v10 superset
-    assert validate_event({**ok, "v": 11})                  # future version
+    assert validate_event({**ok, "v": 11}) == []            # v11 superset
+    assert validate_event({**ok, "v": 12})                  # future version
     assert validate_event({"v": 1, "event": "level_end", "ts": 0.0,
                            "level": 3})                     # missing field
 
@@ -317,6 +318,22 @@ def test_validate_v10_metrics_snapshot():
     assert validate_event({**snap, "surprise": 1})        # unknown field
     assert validate_event({"v": 10, "event": "metrics_snapshot",
                            "ts": 0.0})                    # missing metrics
+
+
+def test_validate_v11_run_end_compiles():
+    """The compile ledger's per-run totals (``run_end.compiles``,
+    obs/compiles.py) exist only from schema v11 — field-gated like the
+    v9 segment fields, so a v10 consumer never sees them."""
+    end = {"v": 11, "event": "run_end", "ts": 0.0, "n_states": 10,
+           "n_transitions": 20, "complete": True, "outcome": "ok",
+           "compiles": {"trace": [2, 0.8], "lower": [2, 0.2],
+                        "backend": [2, 1.5], "cache_misses": 2}}
+    assert validate_event(end) == []
+    assert validate_event({**end, "compiles": {}}) == []
+    errs = validate_event({**end, "v": 10})  # v11-only field, v10 line
+    assert errs and all("requires schema version >= 11" in e
+                        for e in errs)
+    assert validate_event({**end, "compiles": [1, 2]})     # type drift
 
 
 def test_monitor_pool_attribution_rows(tmp_path):

@@ -59,6 +59,58 @@ def test_span_nesting_parent_ids_and_set():
     assert inner["dur"] <= outer["dur"]
 
 
+def test_open_close_handle_nests_like_the_context_manager():
+    """``open``/``close`` push and pop the same per-thread parent stack
+    as ``with``: a region whose body is too long to re-indent parents
+    what opens inside it, and closing twice emits once."""
+    tr, rows = _capture_tracer()
+    outer = tr.open("level", level=3)
+    assert tr.current_id() == 1
+    with tr.span("upload"):
+        pass
+    inner = tr.open("level_close")
+    inner.close()
+    outer.set(segments=2).close()
+    outer.set(segments=99).close()           # idempotent: nothing more
+    assert tr.current_id() is None
+    assert [r["name"] for r in rows] == ["upload", "level_close", "level"]
+    up, lc, lvl = rows
+    assert up["parent_id"] == lc["parent_id"] == lvl["span_id"] == 1
+    assert "parent_id" not in lvl
+    assert lvl["args"]["level"] == 3 and lvl["args"]["segments"] == 2
+
+
+def test_spans_open_an_annotation_of_the_same_name_and_id():
+    """One clock: a tracer given an annotation factory (the engines hand
+    in ``jax.profiler.TraceAnnotation``) opens each span a second time
+    under its own name and ``span_id``, entered and left once each, on
+    both the ``with`` and the ``open``/``close`` path; manual spans
+    (``emit_span``) are in the past and open none."""
+    log = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            self.key = (name, kw["span_id"])
+
+        def __enter__(self):
+            log.append(("enter",) + self.key)
+
+        def __exit__(self, *exc):
+            log.append(("exit",) + self.key)
+
+    rows = []
+    tr = SpanTracer(lambda event, **f: rows.append(f), annotate=Ann)
+    with tr.span("export"):
+        h = tr.open("segment_wait")
+        h.close()
+        h.close()
+    tr.emit_span("segment", 0.0, 1.0, thread="segments")
+    assert log == [("enter", "export", 1), ("enter", "segment_wait", 2),
+                   ("exit", "segment_wait", 2), ("exit", "export", 1)]
+    assert [r["name"] for r in rows] == ["segment_wait", "export",
+                                         "segment"]
+
+
 def test_span_thread_attribution_is_per_thread():
     tr, rows = _capture_tracer()
 
@@ -111,6 +163,9 @@ def test_off_path_is_one_shared_handle():
     assert s1 is s2                      # no per-call allocation
     with s1 as sp:
         assert sp.set(y=2) is sp
+    # the explicit handle of the level loop is the same shared object
+    assert NULL_TRACER.open("level", level=1) is s1
+    assert s1.set(segments=2) is s1 and s1.close() is None
     assert NULL_TRACER.current_id() is None
     NULL_TRACER.emit_span("x", 0.0, 1.0)  # no-op, nothing to observe
 
@@ -200,6 +255,13 @@ def test_phase_timers_trace_only_emits_spans_without_sync():
     assert pt.snapshot() == {}
     pt.tracer = NULL_TRACER
     assert pt.phase("expand") is pt.phase("upload")  # shared null handle
+    # work counts ride the phase's span; the null handle swallows them
+    assert pt.phase("upload").set(rows=4) is pt.phase("upload")
+    pt.tracer = tr
+    with pt.phase("upload") as ph:
+        ph.set(rows=4, padded_rows=256)
+    assert rows[-1]["args"] == {"rows": 4, "padded_rows": 256}
+    assert pt.snapshot() == {}               # counts are not seconds
 
 
 # --------------------------------------------------------------------------
@@ -300,45 +362,314 @@ def test_perfetto_export_structure(tmp_path):
 # end-to-end: traced engine run, report attribution, CLI
 
 
-@pytest.mark.smoke
-def test_traced_ddd_run_report_attribution(tmp_path, monkeypatch):
-    """The acceptance bar on one process: a traced toy ddd run (host
-    dedup + prefetch on) collects into a timeline whose main thread is
-    >= 95% attributed to named phases, with the prefetch thread on its
-    own track — and the traced result equals the untraced oracle."""
-    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
-    monkeypatch.setenv("RAFT_TLA_HOSTDEDUP", "on")
-    monkeypatch.setenv("RAFT_TLA_PREFETCH", "on")
+_TOY_CAPS = dict(block=256, table=1 << 14, flush=1 << 10, levels=64)
+
+
+@pytest.fixture(scope="module")
+def traced_toy(tmp_path_factory):
+    """One traced toy ddd run (host dedup + prefetch on) shared by the
+    tests below: ``(result, events, log directory)``."""
     from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
-    log = str(tmp_path / "ddd.events")
-    eng = DDDEngine(CFG, DDDCapacities(block=256, table=1 << 14,
-                                       flush=1 << 10, levels=64))
-    res = eng.check(events=log)
+    env = {"RAFT_TLA_TRACE": "1", "RAFT_TLA_HOSTDEDUP": "on",
+           "RAFT_TLA_PREFETCH": "on"}
+    old = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        d = tmp_path_factory.mktemp("traced_toy")
+        log = str(d / "ddd.events")
+        res = DDDEngine(CFG, DDDCapacities(**_TOY_CAPS)).check(events=log)
+    finally:
+        for k, v in old.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+    return res, [json.loads(l) for l in open(log)], str(d)
+
+
+def _main_spans(evs):
+    return [e for e in evs if e["event"] == "span"
+            and e["thread"] == "MainThread"]
+
+
+@pytest.mark.smoke
+def test_traced_ddd_run_report_attribution(traced_toy):
+    """The acceptance bar on one process: a traced toy ddd run collects
+    into a timeline whose main thread is one ``pass`` tree, attributed
+    by SELF time (the root reads as what its children left uncovered,
+    not as 100 %), with the prefetch thread on its own track — and the
+    traced result equals the untraced oracle."""
+    res, evs, d = traced_toy
     assert res.n_states == N_TOY
-    evs = [json.loads(l) for l in open(log)]
     assert all(validate_event(e) == [] for e in evs)
     spans = [e for e in evs if e["event"] == "span"]
     assert {s["name"] for s in spans} >= {"expand", "upload", "dedup"}
     assert "raft-tla-prefetch" in {s["thread"] for s in spans}
 
-    col = obs_collect.collect(obs_collect.find_logs(str(tmp_path)))
+    col = obs_collect.collect(obs_collect.find_logs(d))
     rep = obs_collect.report(col)
     (proc,) = rep["processes"]
     main = proc["threads"]["MainThread"]
     assert main["attributed_frac"] >= 0.95
     assert abs(main["attributed_frac"] + main["gap_frac"] - 1.0) < 1e-9
-    assert proc["levels"], "level_end marks should yield critical path"
+    # self times partition the root: they sum to the pass's wall, and the
+    # root's own row is the small remainder, not the whole run
+    ph = main["phases"]
+    assert abs(sum(p["total_s"] for p in ph.values())
+               - main["attributed_s"]) < 1e-3   # t0, dur: 6 decimals
+    assert ph["pass"]["span_s"] == pytest.approx(main["wall_s"])
+    assert ph["pass"]["total_s"] < 0.5 * ph["pass"]["span_s"]
+    assert ph["expand"]["total_s"] == pytest.approx(ph["expand"]["span_s"])
+    assert proc["levels"], "level spans should yield one row a level"
     text = obs_collect.render_report(rep)
     assert "MainThread" in text and "expand" in text
 
     # the CLI over the same directory: collect, export, report
     from raft_tla_tpu.obs.tracecli import main as trace_main
-    out = str(tmp_path / "trace.json")
-    assert trace_main(["export", str(tmp_path), "-o", out]) == 0
+    out = os.path.join(d, "trace.json")
+    assert trace_main(["export", d, "-o", out]) == 0
     doc = json.loads(open(out).read())
     assert any(e["ph"] == "X" for e in doc["traceEvents"])
-    assert trace_main(["collect", str(tmp_path)]) == 0
-    assert trace_main(["report", str(tmp_path), "--json"]) == 0
+    assert trace_main(["collect", d]) == 0
+    assert trace_main(["report", d, "--json"]) == 0
+
+
+@pytest.mark.smoke
+def test_traced_ddd_spans_form_one_tree(traced_toy):
+    """pass > level > upload / expand / export > {segment_wait, d2h} /
+    level_close, by ``parent_id`` alone."""
+    res, evs, _d = traced_toy
+    main = _main_spans(evs)
+    by_id = {s["span_id"]: s for s in main}
+    roots = [s for s in main if "parent_id" not in s]
+    assert [s["name"] for s in roots] == ["pass"]
+    parent_of = {s["span_id"]: by_id[s["parent_id"]]["name"]
+                 for s in main if "parent_id" in s}
+    names = {}
+    for s in main:
+        if "parent_id" in s:
+            names.setdefault(s["name"], set()).add(parent_of[s["span_id"]])
+    assert names["level"] == {"pass"}
+    for child in ("upload", "expand", "export", "level_close"):
+        assert names[child] == {"level"}, child
+    assert names["segment_wait"] == names["d2h"] == {"export"}
+    assert names["take"] == {"upload"}
+    # the last drain runs after the levels, under the pass itself
+    assert names["dedup_wait"] <= {"level_close", "pass"}
+    assert "level_close" in names["dedup_wait"]
+    p = roots[0]["args"]
+    assert p["engine"] == "ddd" and p["resumed"] is False
+    assert p["n_states"] == N_TOY and p["stopped_by"] is None
+    assert p["levels"] == len(res.levels)
+
+
+@pytest.mark.smoke
+def test_level_spans_carry_their_work_and_match_the_segments_track(
+        traced_toy):
+    res, evs, _d = traced_toy
+    spans = [e for e in evs if e["event"] == "span"]
+    levels = sorted((s for s in spans if s["name"] == "level"),
+                    key=lambda s: s["t0"])
+    assert [s["args"]["level"] for s in levels] \
+        == list(range(1, len(res.levels) + 1))
+    for s in levels:
+        assert {"level", "rows", "blocks", "segments", "steps",
+                "streamed_rows", "new_states"} <= set(s["args"])
+    # a level's frontier is what the previous level discovered
+    assert [s["args"]["rows"] for s in levels] == res.levels
+    assert [s["args"]["new_states"] for s in levels[:-1]] \
+        == res.levels[1:]
+    segs = [s for s in spans if s["name"] == "segment"]
+    assert segs and {s["thread"] for s in segs} == {"segments"}
+    assert all("parent_id" not in s for s in segs)
+    for lv in levels:
+        mine = [s for s in segs
+                if s["args"]["level"] == lv["args"]["level"]]
+        assert len(mine) == lv["args"]["segments"]
+        assert sum(s["args"]["steps"] for s in mine) \
+            == lv["args"]["steps"]
+        assert sum(s["args"]["streamed_rows"] for s in mine
+                   if not s["args"]["dropped"]) \
+            == lv["args"]["streamed_rows"]
+    assert sum(s["args"]["n_valid"] for s in segs) == res.n_transitions
+    # one segment_wait per harvested segment; d2h only where it streamed
+    waits = [s for s in spans if s["name"] == "segment_wait"]
+    d2h = [s for s in spans if s["name"] == "d2h"]
+    assert len(waits) == len(segs)
+    assert len(d2h) == sum(1 for s in segs if s["args"]["streamed_rows"])
+    assert all(s["args"]["rows"] > 0 and s["args"]["bytes"] > 0
+               for s in d2h)
+    ups = [s for s in spans if s["name"] == "upload"]
+    assert all(s["args"]["padded_rows"] == _TOY_CAPS["block"]
+               and 0 < s["args"]["rows"] <= s["args"]["padded_rows"]
+               and "prefetch_hit" in s["args"] for s in ups)
+    flush = [s for s in spans if s["name"] == "dedup"]
+    assert flush and all("keys" in s["args"] for s in flush)
+
+
+@pytest.mark.smoke
+def test_self_times_over_the_tree_sum_to_the_pass_wall(traced_toy):
+    _res, evs, d = traced_toy
+    col = obs_collect.collect(obs_collect.find_logs(d))
+    main = [s for s in col["spans"] if s["thread"] == "MainThread"]
+    self_s, kids, roots = obs_collect.self_times(main)
+    (root,) = roots
+    assert root["name"] == "pass"
+    assert sum(self_s.values()) == pytest.approx(root["dur"], abs=1e-4)
+    # and level by level: a level's self time plus its children's walls
+    for s in main:
+        if s["name"] == "level":
+            covered = sum(c["dur"] for c in kids[s["span_id"]])
+            assert self_s[id(s)] + covered \
+                == pytest.approx(s["dur"], abs=1e-4)
+
+
+@pytest.mark.smoke
+def test_trace_report_prints_level_rows_from_the_level_spans(
+        traced_toy, capsys):
+    res, _evs, d = traced_toy
+    assert not hasattr(obs_collect, "_level_critical_path")
+    from raft_tla_tpu.obs.tracecli import main as trace_main
+    assert trace_main(["report", d]) == 0
+    text = capsys.readouterr().out
+    rows = [l for l in text.splitlines() if l.startswith("  L")]
+    assert len(rows) == len(res.levels)
+    assert rows[0].startswith("  L1: ") and "1 rows" in rows[0]
+    assert all("segments" in r and "steps" in r and "self " in r
+               and "most in: " in r for r in rows)
+    assert " self " in text and "in spans)" in text
+    rep = obs_collect.report(obs_collect.collect(
+        obs_collect.find_logs(d)))
+    (proc,) = rep["processes"]
+    assert [r["level"] for r in proc["levels"]] \
+        == list(range(1, len(res.levels) + 1))
+    assert sum(r["steps"] for r in proc["levels"]) > 0
+    assert {r["dominant_child"] for r in proc["levels"]} \
+        <= {"upload", "expand", "export", "level_close", "dedup_submit",
+            "dedup", "devdedup", "snapshot"}
+
+
+@pytest.mark.smoke
+def test_perfetto_exports_the_segments_and_compiles_tracks(traced_toy):
+    _res, _evs, d = traced_toy
+    col = obs_collect.collect(obs_collect.find_logs(d))
+    evs = obs_perfetto.to_trace_events(col)
+    tracks = {e["args"]["name"]: e["tid"] for e in evs
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert tracks["MainThread"] == 1
+    assert {"segments", "compiles", "raft-tla-prefetch"} <= set(tracks)
+    on = {}
+    for e in evs:
+        if e["ph"] == "X":
+            on.setdefault(e["name"], set()).add(e["tid"])
+    assert on["segment"] == {tracks["segments"]}
+    assert on["compile"] == {tracks["compiles"]}
+    seg = next(e for e in evs if e["ph"] == "X" and e["name"] == "segment")
+    assert {"level", "block", "budget", "steps"} <= set(seg["args"])
+
+
+@pytest.mark.smoke
+def test_untraced_ddd_run_emits_no_span(tmp_path, monkeypatch):
+    """The off path: the engine's new sites (pass, level, level_close,
+    segment_wait, d2h, the segments track) all go through the one shared
+    null handle, and the log of an untraced run holds no span."""
+    monkeypatch.delenv("RAFT_TLA_TRACE", raising=False)
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+    from raft_tla_tpu.obs import trace as obs_trace
+    opened = []
+    real_open = obs_trace.NullTracer.open
+    monkeypatch.setattr(
+        obs_trace.NullTracer, "open",
+        lambda self, name, **a: opened.append(name) or real_open(
+            self, name, **a))
+    log = str(tmp_path / "off.events")
+    res = DDDEngine(CFG, DDDCapacities(**_TOY_CAPS)).check(events=log)
+    assert res.n_states == N_TOY
+    evs = [json.loads(l) for l in open(log)]
+    assert not [e for e in evs if e["event"] == "span"]
+    assert opened[0] == "pass" and set(opened[1:]) == {"level"}
+    assert len(opened) == 1 + len(res.levels)
+    assert evs[-1]["event"] == "run_end"
+
+
+def test_compile_ledger_counts_a_fresh_jit_once():
+    """The program's own compile counter: a fresh jit is one trace, one
+    lowering and one backend compile; its second call is nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tla_tpu.obs import compiles
+    compiles.install()
+    assert compiles.install() is False       # idempotent
+    x = jnp.arange(8)
+
+    @jax.jit
+    def fresh_for_the_ledger(v):
+        return jnp.where(v > 3, v * 2, v).sum()  # nested jits inside
+
+    before = compiles.LEDGER.totals()
+    fresh_for_the_ledger(x).block_until_ready()
+    once = compiles.totals_since(before, compiles.LEDGER.totals())
+    assert once["trace"][0] == once["lower"][0] == once["backend"][0] == 1
+    assert all(once[k][1] > 0 for k in ("trace", "lower", "backend"))
+    mid = compiles.LEDGER.totals()
+    fresh_for_the_ledger(x).block_until_ready()
+    assert compiles.totals_since(mid, compiles.LEDGER.totals()) == {}
+    recs = [r for r in compiles.snapshot()["records"]
+            if r["fun"] and "fresh_for_the_ledger" in r["fun"]]
+    assert sorted(r["kind"] for r in recs) == ["backend", "lower", "trace"]
+
+
+def test_compile_ledger_keeps_outermost_traces_and_feeds_the_tracer():
+    """Driven without JAX: a trace that opens inside another is held by
+    the outer one's duration and not recorded again; every record is a
+    ``compile`` span on the ``compiles`` track while a tracer is
+    attached; the list is bounded and says what it dropped."""
+    from raft_tla_tpu.obs.compiles import CompileLedger, totals_since
+    tr, rows = _capture_tracer()
+    led = CompileLedger(max_records=3)
+    led.attach(tr)
+    ev = "/jax/core/compile/jaxpr_trace_duration"
+    led.on_scalar(ev, 0.0, fun_name="segment")
+    led.on_scalar(ev, 0.0, fun_name="_where")
+    led.on_duration(ev, 0.001, fun_name="_where")      # nested: dropped
+    led.on_duration(ev, 0.5, fun_name="segment")
+    led.on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                    0.2, fun_name="jit(segment)")
+    led.on_event("/jax/compilation_cache/cache_hits")
+    led.on_duration("/jax/compilation_cache/cache_retrieval_time_sec",
+                    0.1)
+    led.on_duration("/jax/core/compile/backend_compile_duration", 0.3,
+                    fun_name="jit(segment)")
+    led.on_duration("/jax/unrelated", 9.0)
+    snap = led.snapshot()
+    assert snap["totals"] == {"trace": [1, 0.5], "lower": [1, 0.2],
+                              "cache_hits": 1, "cache_load": [1, 0.1],
+                              "backend": [1, 0.3]}
+    assert [r["kind"] for r in snap["records"]] \
+        == ["lower", "cache_load", "backend"] and snap["dropped"] == 1
+    assert [(r["name"], r["thread"], r["args"]["kind"]) for r in rows] \
+        == [("compile", "compiles", k)
+            for k in ("trace", "lower", "cache_load", "backend")]
+    assert rows[0]["args"]["fun"] == "segment" and rows[0]["dur"] == 0.5
+    led.detach(tr)
+    led.on_duration("/jax/core/compile/backend_compile_duration", 0.3)
+    assert len(rows) == 4
+    assert totals_since(snap["totals"], led.totals()) \
+        == {"backend": [1, 0.3]}
+
+
+def test_run_end_carries_the_runs_compile_totals(traced_toy):
+    _res, evs, _d = traced_toy
+    end = evs[-1]
+    assert end["event"] == "run_end" and validate_event(end) == []
+    comp = [e for e in evs if e["event"] == "span"
+            and e["name"] == "compile"]
+    assert {e["thread"] for e in comp} == {"compiles"}
+    for kind in ("trace", "lower", "backend"):
+        n = sum(1 for e in comp if e["args"]["kind"] == kind)
+        assert n >= 1 and end["compiles"][kind][0] == n
+    assert any(e["args"].get("fun") == "jit(segment)" for e in comp)
 
 
 @pytest.mark.smoke
