@@ -243,6 +243,10 @@ class SegStats(NamedTuple):
     done: jax.Array       # block exhausted
     peak: jax.Array       # max live enabled lanes in any chunk — the
                           # route_rows sizing signal (both step shapes)
+    stream_peak: jax.Array   # most rows one chunk streamed — the
+                             # _S_OUT sizing signal
+    stream_slabs: jax.Array  # slab writes; == steps unless a chunk
+                             # streamed more than one slab holds
 
 
 class _SegCarry(NamedTuple):
@@ -265,6 +269,8 @@ class _SegCarry(NamedTuple):
     dead_g: jax.Array
     c: jax.Array
     peak: jax.Array
+    stream_peak: jax.Array
+    stream_slabs: jax.Array
 
 
 def save_ddd_snapshot(path, host, constore, keystore, n_states, n_trans,
@@ -649,24 +655,49 @@ def frontier_checkpoint_setup(resume, checkpoint, checkpoint_every_s,
 
 # Per-call compacted-insert budget: only streamed keys reach the table
 # scatter (typically a few thousand of the N=chunk*A candidates — 3.7k
-# at flagship shapes, runs/filter_anatomy.out), and a chunk streaming
-# more than this simply drops the excess INSERTS — the key still
-# streams to the host, so exactness is untouched and the only cost is
-# re-sighted traffic.  Chip-measured (runs/scatter_menu.out +
-# runs/filter_inengine.out): TPU scatter cost is per-UPDATE (~80 ns)
-# regardless of how few updates really write (mode="drop" masking is
-# not free), so compacting 172k masked updates to 16k is the win; a
-# combined [TB, BUCKET, 2] table layout that would fix this with one
-# row scatter was measured SLOWER in-engine (rank-3 minor-dim-2 layout
-# wrecks the probe gather) and rejected.
+# at flagship shapes, round 4), and a chunk streaming more than this
+# simply drops the excess INSERTS — the key still streams to the host,
+# so exactness is untouched and the only cost is re-sighted traffic.
+# Chip-measured in round 4 (the record is `git show 51d3f6c^:RESULTS.md`
+# lines 305-325; the runs/*.out files it names were never committed):
+# TPU scatter cost is per-UPDATE (~80 ns) regardless of how few updates
+# really write (mode="drop" masking is not free), so compacting 172k
+# masked updates to 16k is the win; a combined [TB, BUCKET, 2] table
+# layout that would fix this with one row scatter was measured SLOWER
+# in-engine (rank-3 minor-dim-2 layout wrecks the probe gather) and
+# rejected.
 _S_INS = 1 << 14
+
+# Rows one slab of the candidate stream holds (_build_segment's
+# ``stream`` stage): the same lesson applied to the output buffers — a
+# chunk's streamed rows are gathered in compaction order and written
+# with one contiguous dynamic_update_slice per buffer, not scattered
+# lane by lane.  Unlike a filter insert a streamed row may never be
+# dropped, so a chunk that streams more than one slab writes further
+# slabs.  Sized from SegStats.stream_peak (PERF.md, PR 25).
+_S_OUT = 1 << 14
+
+
+def _slab_plan(nk: int) -> tuple[int, int]:
+    """``(S, slack)`` for a chunk of ``nk`` candidate rows: the rows a
+    slab holds, and how far past ``nk`` whole slabs reach — the rows the
+    segment buffers carry beyond ``seg_rows`` so that a chunk's last
+    slab always lands inside them."""
+    s = min(_S_OUT, nk)
+    return s, -nk % s
 
 
 def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
+    """``_filter_insert_ordered`` without the compaction order."""
+    return _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo,
+                                  active)[:3]
+
+
+def _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo, active):
     """Lossy one-gather filter probe + compacted insert.
 
-    Returns ``(tbl_hi, tbl_lo, stream)`` where ``stream[c]`` is True iff
-    candidate c is active, is the first active candidate carrying its key
+    Returns ``(tbl_hi, tbl_lo, stream, compact)`` where ``stream[c]`` is
+    True iff candidate c is active, is the first active candidate carrying its key
     in this batch (same two-sort first-occurrence pass as
     device_engine._dedup_insert stage 1), and its key is NOT in the
     filter — bit-identical stream semantics to the rounds-1-3
@@ -687,6 +718,11 @@ def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
     only the first insert per (bucket, slot) per batch removes the
     reliance outright; the loser key simply isn't remembered and may
     re-stream later, which the host dedups.
+
+    ``compact`` is the stable compaction order of all candidates, streamed
+    ones first in batch order (``stream[compact[:sum(stream)]]`` is all
+    True): the insert takes its first ``_S_INS`` entries, the segment's
+    ``stream`` stage its slabs — one N-wide sort serves both.
     """
     BA = key_hi.shape[0]
     TB, Sb = tbl_hi.shape
@@ -714,7 +750,8 @@ def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
     # compact the streamed inserts (stable: stream-first, batch order),
     # then scatter only S updates instead of BA
     S = min(_S_INS, BA)
-    sel = jnp.argsort(~stream, stable=True)[:S]
+    compact = jnp.argsort(~stream, stable=True)
+    sel = compact[:S]
     ok = stream[sel]
     wb = jnp.where(ok, bidx[sel], TB)            # TB row = dropped
     ws = wslot[sel]
@@ -727,7 +764,7 @@ def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
     wb = jnp.where(jnp.zeros((S,), bool).at[order].set(~dup), wb, TB)
     tbl_hi = tbl_hi.at[wb, ws].set(key_hi[sel], mode="drop")
     tbl_lo = tbl_lo.at[wb, ws].set(key_lo[sel], mode="drop")
-    return tbl_hi, tbl_lo, stream
+    return tbl_hi, tbl_lo, stream, compact
 
 
 def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
@@ -736,7 +773,9 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
     compacting every chunk's candidate stream into the segment output
     buffers at a running cursor.  The loop stops when the block is done,
     the next chunk might overflow the output buffers, a violation or
-    failure is flagged, or the budget is spent."""
+    failure is flagged, or the budget is spent.  ``bufs`` hold
+    ``seg_rows`` rows plus the slack of ``_slab_plan``; rows at and
+    past ``stats.cursor`` are unspecified."""
     B = config.chunk
     N = B * A
     routed = caps.route_rows > 0
@@ -745,6 +784,7 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
     if OCAP < NK:
         raise ValueError(
             f"seg_rows={OCAP} must be >= per-chunk candidate rows = {NK}")
+    SLAB, SLACK = _slab_plan(NK)
     n_inv = len(config.invariants)
     # Both step flavors share _step_stages, so the orbit-scan variants
     # (prescan ladder, sig-prune coset scan) resolve from their env
@@ -763,7 +803,7 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
     def chunk_body(carry: _SegCarry) -> _SegCarry:
         (tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar, olane, ocon,
          cursor, n_valid_a, fail, viol_kind, viol_inv, dead_g, c,
-         peak) = carry
+         peak, stream_peak, stream_slabs) = carry
         r0 = c * B
         rows_b = r0 + jnp.arange(B, dtype=I32)
         row_act = rows_b < block_rows
@@ -834,23 +874,55 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             fail = fail | jnp.any(kvalid & ovf_rows).astype(I32) * FAIL_WIDTH
 
         with jax.named_scope("filter_insert"):
-            tbl_hi, tbl_lo, stream = _filter_insert(tbl_hi, tbl_lo, kh,
-                                                    kl, kvalid)
+            tbl_hi, tbl_lo, stream, compact = _filter_insert_ordered(
+                tbl_hi, tbl_lo, kh, kl, kvalid)
         with jax.named_scope("pack"):
             svecs = schema.pack(word_rows, jnp)
         with jax.named_scope("stream"):
-            pos = cursor + jnp.cumsum(stream.astype(I32)) - 1
-            sl = jnp.where(stream, pos, OCAP)
-            okey_hi = okey_hi.at[sl].set(kh, mode="drop")
-            okey_lo = okey_lo.at[sl].set(kl, mode="drop")
-            orows = orows.at[sl].set(svecs, mode="drop")
-            # BLOCK-RELATIVE parent (always fits int32 regardless of how
-            # deep the campaign is); the harvest rebases to the global
-            # int64 discovery index by adding the block start on the host
-            opar = opar.at[sl].set(r0 + src // A, mode="drop")
-            olane = olane.at[sl].set(src % A, mode="drop")
-            ocon = ocon.at[sl].set(con_rows, mode="drop")
-            cursor = cursor + jnp.sum(stream.astype(I32))
+            # compact once, write contiguously: slab j is the streamed
+            # lanes j*SLAB.. of the filter's compaction order, gathered
+            # and laid down at cursor + j*SLAB.  A slab's entries past
+            # the streamed count are other lanes' rows above the new
+            # cursor: the next chunk overwrites them and the harvest
+            # never reads past stats.cursor.  The buffers' slack rows
+            # (_slab_plan) keep the last slab inside them, so no write
+            # is ever clamped.
+            n_stream = jnp.sum(stream.astype(I32))
+            compact_p = jnp.pad(compact, (0, SLACK))
+
+            def write_slab(j, bufs):
+                sel = jax.lax.dynamic_slice(compact_p, (j * SLAB,),
+                                            (SLAB,))
+                lane = src[sel] if routed else sel
+                # the packed rows word by word: P lane gathers keep the
+                # [N, P] rows and the buffer in their compact layouts; one
+                # row gather made the TPU compiler keep both row-major,
+                # 8..11 words padded to 128 lanes (PERF.md, PR 25)
+                rows = jnp.stack([svecs[:, p][sel]
+                                  for p in range(schema.P)], axis=1)
+                # BLOCK-RELATIVE parent (always fits int32 regardless of
+                # how deep the campaign is); the harvest rebases to the
+                # global int64 discovery index by adding the block start
+                # on the host
+                slab = (kh[sel], kl[sel], rows, r0 + lane // A,
+                        lane % A, con_rows[sel])
+                at = cursor + j * SLAB
+                return tuple(
+                    jax.lax.dynamic_update_slice(
+                        b, v, (at,) + (0,) * (b.ndim - 1))
+                    for b, v in zip(bufs, slab))
+
+            # the trip count is the observed count: one slab for nearly
+            # every chunk (an empty chunk still writes one, of garbage
+            # above the cursor), more only past SLAB streamed rows
+            n_slabs = jnp.maximum((n_stream + SLAB - 1) // SLAB, 1)
+            (okey_hi, okey_lo, orows, opar, olane,
+             ocon) = jax.lax.fori_loop(
+                0, n_slabs, write_slab,
+                (okey_hi, okey_lo, orows, opar, olane, ocon))
+            cursor = cursor + n_stream
+            stream_peak = jnp.maximum(stream_peak, n_stream)
+            stream_slabs = stream_slabs + n_slabs
 
         viol_kind = jnp.where(use_dead, 2, jnp.where(has_inv, 1, 0)) \
             .astype(I32)
@@ -869,7 +941,8 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             use_dead, r0 + jnp.minimum(drow, B - 1), dead_g)
         return _SegCarry(tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar,
                          olane, ocon, cursor, n_valid_a, fail, viol_kind,
-                         viol_inv_c.astype(I32), dead_g, c + 1, peak)
+                         viol_inv_c.astype(I32), dead_g, c + 1, peak,
+                         stream_peak, stream_slabs)
 
     def cond(sc):
         s, carry = sc
@@ -891,7 +964,8 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             fc.tbl_hi, fc.tbl_lo, *bufs,
             cursor=jnp.int32(0), n_valid=jnp.int32(0), fail=jnp.int32(0),
             viol_kind=jnp.int32(0), viol_inv=jnp.int32(0),
-            dead_g=jnp.int32(-1), c=fc.c, peak=jnp.int32(0))
+            dead_g=jnp.int32(-1), c=fc.c, peak=jnp.int32(0),
+            stream_peak=jnp.int32(0), stream_slabs=jnp.int32(0))
         steps, carry = jax.lax.while_loop(cond, body,
                                           (jnp.int32(0), carry))
         n_chunks = (block_rows + B - 1) // B
@@ -900,7 +974,8 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
                         carry.opar, carry.olane, carry.ocon),
                 SegStats(carry.cursor, carry.n_valid, carry.fail,
                          carry.viol_kind, carry.viol_inv, carry.dead_g,
-                         steps, carry.c >= n_chunks, carry.peak))
+                         steps, carry.c >= n_chunks, carry.peak,
+                         carry.stream_peak, carry.stream_slabs))
 
     fbuf = fcon = budget = block_rows = None
     return segment
@@ -983,6 +1058,11 @@ class DDDEngine:
         # the compile ledger listens before this engine's first program
         # is traced (idempotent; obs/compiles)
         compiles.install()
+        # rows of one segment buffer: seg_rows plus the slack that keeps
+        # a chunk's last slab inside it, fixed here with the program that
+        # writes the slabs
+        self._buf_rows = self.caps.seg_rows + _slab_plan(
+            self.caps.route_rows or config.chunk * self.A)[1]
         self._segment = jax.jit(
             _build_segment(config, self.caps, self.A, self.lay.width,
                            self.schema),
@@ -1004,7 +1084,7 @@ class DDDEngine:
             devdedup.init_set(self.caps.table, self._devdedup))
 
     def _make_bufs(self) -> SegBufs:
-        OCAP = self.caps.seg_rows
+        OCAP = self._buf_rows
         return SegBufs(
             okey_hi=jnp.zeros((OCAP,), U32),
             okey_lo=jnp.zeros((OCAP,), U32),
@@ -1270,7 +1350,7 @@ class DDDEngine:
         # what one frontier block and one segment's buffers weigh on the
         # wire, from shapes (span args; the transfers are whole buffers)
         up_bytes = Fcap * (self.schema.P * 4 + 1)
-        buf_bytes = self.caps.seg_rows * (self.schema.P * 4 + 17)
+        buf_bytes = self._buf_rows * (self.schema.P * 4 + 17)
         # Upload prefetcher (RAFT_TLA_PREFETCH): while the device
         # expands block k, a daemon thread reads block k+1's rows +
         # constraint column and stages them into one of two
@@ -1308,6 +1388,8 @@ class DDDEngine:
         viol_key = None
         fail = 0
         route_peak = 0       # max live enabled lanes seen in any chunk
+        stream_peak = 0      # most rows any chunk streamed (sizes _S_OUT)
+        stream_slabs = 0     # slab writes of the pass
         complete = True
         stopped = False
         t_warm = None
@@ -1334,6 +1416,7 @@ class DDDEngine:
                 level=len(level_ends), n_transitions=n_trans,
                 coverage=dict(aggregate_coverage(self.table, cov)),
                 route_peak=route_peak,
+                stream_peak=stream_peak, stream_slabs=stream_slabs,
                 flush_backlog=worker.backlog() if worker else None,
                 upload_wait_ms=round(prefetcher.wait_s * 1e3, 3)
                 if prefetcher else None,
@@ -1343,11 +1426,13 @@ class DDDEngine:
 
         n_trans_mark = n_trans   # n_trans as of the current block's start
         stopped_by = None
-        lvl_segs = lvl_steps = lvl_rows = 0   # the open level's work
+        # the open level's work
+        lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
 
         def end_level():
             level_sp.set(segments=lvl_segs, steps=lvl_steps,
                          streamed_rows=lvl_rows,
+                         stream_peak=lvl_peak, stream_slabs=lvl_slabs,
                          new_states=n_states - lvl_hi).close()
 
         while not stopped:
@@ -1359,7 +1444,7 @@ class DDDEngine:
             level_sp = tr.open("level", level=len(level_ends),
                                rows=lvl_hi - lvl_lo,
                                blocks=-(-(lvl_hi - b0) // Fcap))
-            lvl_segs = lvl_steps = lvl_rows = 0
+            lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
             if prefetcher is not None and b0 < lvl_hi:
                 # level start: every block address in [lvl_lo, lvl_hi)
                 # is known now — warm the first block immediately
@@ -1490,7 +1575,11 @@ class DDDEngine:
                         nv = int(st_h.n_valid)
                         vk = int(st_h.viol_kind)
                         n_steps = int(st_h.steps)
+                        seg_peak = int(st_h.stream_peak)
+                        seg_slabs = int(st_h.stream_slabs)
                         route_peak = max(route_peak, int(st_h.peak))
+                        stream_peak = max(stream_peak, seg_peak)
+                        stream_slabs += seg_slabs
                         if tr.enabled:
                             # dispatch -> stats ready, on its own track
                             # (segments overlap: two are in flight)
@@ -1501,9 +1590,13 @@ class DDDEngine:
                                 block=(b_start - lvl_lo) // Fcap,
                                 budget=seg_budget, steps=n_steps,
                                 streamed_rows=ns, n_valid=nv,
+                                stream_peak=seg_peak,
+                                stream_slabs=seg_slabs,
                                 dropped=stopped)
                         lvl_segs += 1
                         lvl_steps += n_steps
+                        lvl_slabs += seg_slabs
+                        lvl_peak = max(lvl_peak, seg_peak)
                         bufs_h = None
                         if ns and not stopped:
                             with tr.span("d2h", rows=ns, bytes=buf_bytes):

@@ -341,6 +341,8 @@ def _level_rows(threads: dict, trees: dict) -> list:
                          "wall_s": s["dur"], "rows": a.get("rows"),
                          "segments": a.get("segments"),
                          "steps": a.get("steps"),
+                         "stream_peak": a.get("stream_peak"),
+                         "stream_slabs": a.get("stream_slabs"),
                          "new_states": a.get("new_states"),
                          "self_s": self_s[id(s)],
                          "dominant_child": dom[0] if dom else None,
@@ -408,6 +410,10 @@ def render_report(rep: dict) -> str:
             lines.append(
                 f"  L{lv['level']}: {lv['wall_s']:.3f}s, "
                 f"{lv['rows']} rows, {lv['segments']} segments, "
-                f"{lv['steps']} steps, +{lv['new_states']} states, "
+                f"{lv['steps']} steps, "
+                + (f"{lv['stream_slabs']} slabs (peak "
+                   f"{lv['stream_peak']} rows), "
+                   if lv["stream_slabs"] is not None else "")
+                + f"+{lv['new_states']} states, "
                 f"self {lv['self_s']:.3f}s, most in: {dom}")
     return "\n".join(lines)

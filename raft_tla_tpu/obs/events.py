@@ -25,7 +25,7 @@ wall).  This module replaces all of that with:
   ``level_end`` derived automatically from level transitions and
   ``violation`` derived from the final :class:`~raft_tla_tpu.engine.EngineResult`.
 
-Event grammar (``SCHEMA_VERSION`` = 11; earlier-version lines remain
+Event grammar (``SCHEMA_VERSION`` = 12; earlier-version lines remain
 valid) —
 every line is one JSON object with base fields ``v`` (schema version),
 ``event`` (type) and ``ts`` (unix epoch seconds):
@@ -161,12 +161,21 @@ Version 11 also grows the ddd engines' ``span`` vocabulary into a tree
 ``segments`` and ``compiles`` tracks) — names and ``args`` only, the
 ``span`` event itself is unchanged since version 8.
 
+Version 12 adds the ddd segment program's slab-write counters
+(ddd_engine ``SegStats.stream_peak`` / ``stream_slabs``): segment
+``stream_peak`` (most rows any one chunk has streamed so far in the
+pass — the sizing signal for the slab size, as ``route_peak`` is for
+``route_rows``) and ``stream_slabs`` (cumulative slab writes; equals the
+chunk steps unless a chunk streamed more than one slab holds).  The
+``segments`` track's ``segment`` spans carry the same two per segment.
+
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the
-v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11-only fields)
-are invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` / ``"v" < 10``
-(resp. ``"v" < 3`` / ``"v" < 4`` / ``"v" < 5`` / ``"v" < 6`` /
-``"v" < 8`` / ``"v" < 9`` / ``"v" < 11``) line, so any addition requires
+v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12-only
+fields) are invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` /
+``"v" < 10`` (resp. ``"v" < 3`` / ``"v" < 4`` / ``"v" < 5`` /
+``"v" < 6`` / ``"v" < 8`` / ``"v" < 9`` / ``"v" < 11`` / ``"v" < 12``)
+line, so any addition requires
 a version bump (versioning policy in README.md).
 """
 
@@ -180,8 +189,8 @@ import subprocess
 import threading
 import time
 
-SCHEMA_VERSION = 11
-_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11)  # versions validate_event accepts
+SCHEMA_VERSION = 12
+_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12)  # versions validate_event accepts
 
 # Environment knobs (set by check.py --events/--phase-timers; inherited by
 # liveness re-runs and bench children the same way RAFT_TLA_SIGPRUNE is).
@@ -299,6 +308,10 @@ _V9_FIELDS = {"segment": frozenset({"export_rows", "dev_dedup_hits"})}
 # ledger's per-run totals) — invalid on a "v" < 11 line.
 _V11_FIELDS = {"run_end": frozenset({"compiles"})}
 
+# Fields that only exist from schema version 12 on (the ddd segment
+# program's slab-write counters) — invalid on a "v" < 12 line.
+_V12_FIELDS = {"segment": frozenset({"stream_peak", "stream_slabs"})}
+
 _OPTIONAL = {
     "run_start": {"bounds": dict, "symmetry": list, "view": str,
                   "chunk": int, "caps": str, "n_states": int,
@@ -308,7 +321,8 @@ _OPTIONAL = {
                 "inv_evals": dict, "phase_s": dict, "device_rates": list,
                 "bin": str, "inflight": int, "flush_backlog": int,
                 "upload_wait_ms": _NUM, "prefetch_hits": int,
-                "export_rows": int, "dev_dedup_hits": int},
+                "export_rows": int, "dev_dedup_hits": int,
+                "stream_peak": int, "stream_slabs": int},
     "level_end": {},
     "checkpoint": {"n_states": int},
     "violation": {"kind": str},
@@ -374,6 +388,7 @@ def validate_event(d: dict) -> list:
     v8_only = _V8_FIELDS.get(ev, frozenset())
     v9_only = _V9_FIELDS.get(ev, frozenset())
     v11_only = _V11_FIELDS.get(ev, frozenset())
+    v12_only = _V12_FIELDS.get(ev, frozenset())
     for k, val in d.items():
         if k in _BASE or k in req:
             continue
@@ -396,6 +411,8 @@ def validate_event(d: dict) -> list:
             errs.append(f"{ev}: field {k!r} requires schema version >= 9")
         elif k in v11_only and d["v"] in _VERSIONS and d["v"] < 11:
             errs.append(f"{ev}: field {k!r} requires schema version >= 11")
+        elif k in v12_only and d["v"] in _VERSIONS and d["v"] < 12:
+            errs.append(f"{ev}: field {k!r} requires schema version >= 12")
     return errs
 
 
@@ -439,6 +456,8 @@ class ProgressRecord:
     prefetch_hits: int | None = None  # ddd: staged-buffer block uploads
     export_rows: int | None = None    # ddd: cumulative d2h export rows
     dev_dedup_hits: int | None = None  # ddd: device-set pre-export drops
+    stream_peak: int | None = None    # ddd: most rows one chunk streamed
+    stream_slabs: int | None = None   # ddd: cumulative slab writes
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -488,7 +507,9 @@ class ProgressTracker:
                upload_wait_ms: float | None = None,
                prefetch_hits: int | None = None,
                export_rows: int | None = None,
-               dev_dedup_hits: int | None = None) -> ProgressRecord:
+               dev_dedup_hits: int | None = None,
+               stream_peak: int | None = None,
+               stream_slabs: int | None = None) -> ProgressRecord:
         wall = time.monotonic() - self.t0
         reported = n_states if n_incl is None else max(n_states, n_incl)
         if self._prev_n is None:  # unknown baseline: anchor, rate 0
@@ -525,6 +546,8 @@ class ProgressTracker:
             prefetch_hits=prefetch_hits,
             export_rows=export_rows,
             dev_dedup_hits=dev_dedup_hits,
+            stream_peak=stream_peak,
+            stream_slabs=stream_slabs,
         )
 
 
@@ -755,7 +778,9 @@ class RunTelemetry:
                 upload_wait_ms: float | None = None,
                 prefetch_hits: int | None = None,
                 export_rows: int | None = None,
-                dev_dedup_hits: int | None = None) -> ProgressRecord:
+                dev_dedup_hits: int | None = None,
+                stream_peak: int | None = None,
+                stream_slabs: int | None = None) -> ProgressRecord:
         rec = self.tracker.record(
             n_states, level, n_transitions, coverage=coverage,
             route_peak=route_peak, n_incl=n_incl,
@@ -766,7 +791,8 @@ class RunTelemetry:
             upload_wait_ms=upload_wait_ms,
             prefetch_hits=prefetch_hits,
             export_rows=export_rows,
-            dev_dedup_hits=dev_dedup_hits)
+            dev_dedup_hits=dev_dedup_hits,
+            stream_peak=stream_peak, stream_slabs=stream_slabs)
         if self.log is not None:
             if self._last_level is not None and level > self._last_level:
                 # The boundary count is the count as observed at the first

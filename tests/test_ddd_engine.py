@@ -549,3 +549,282 @@ def test_routed_violation_never_masked_by_budget():
         assert got.violation.trace == ref.violation.trace
         reported += 1
     assert reported >= 1          # the sweep must exercise the report path
+
+
+# -- slab writes of the candidate stream (ddd_engine._S_OUT) ----------------
+#
+# The segment program writes a chunk's streamed rows as contiguous slabs
+# of _S_OUT rows in the filter's compaction order.  The slab size must be
+# invisible: every streamed row lands, in order, at any size.
+
+import jax
+import jax.numpy as jnp
+
+from raft_tla_tpu import ddd_engine as ddd_mod
+from raft_tla_tpu.ops import kernels
+
+_OLD_STATS = ("cursor", "n_valid", "fail", "viol_kind", "viol_inv",
+              "dead_g", "steps", "done", "peak")
+_FLAVOURS = ("dense", "routed")
+
+
+def _flavour_caps(flavour, cfg=CFG, **kw):
+    caps = dataclasses.replace(CAPS, **kw)
+    return _routed(caps, _n_lanes(cfg) // 2) if flavour == "routed" \
+        else caps
+
+
+def _frontier_block(cfg, depth, n_rows):
+    """The first ``n_rows`` states of BFS level ``depth`` (plain Python,
+    models/interp) as one padded frontier block: packed rows + flags."""
+    seen = {interp.init_state(cfg.bounds)}
+    level = list(seen)
+    for _ in range(depth):
+        nxt = []
+        for s in level:
+            if not interp.constraint_ok(s, cfg.bounds):
+                continue             # kept, never expanded (refbfs)
+            for _a, t in interp.successors(s, cfg.bounds, spec=cfg.spec):
+                if t not in seen:
+                    seen.add(t)
+                    nxt.append(t)
+        level = nxt
+    level = level[:n_rows]
+    assert len(level) == n_rows
+    vecs = np.stack([interp.to_vec(s, cfg.bounds) for s in level])
+    con = np.array([interp.constraint_ok(s, cfg.bounds) for s in level])
+    return vecs, con
+
+
+def _run_segment(eng, vecs, con, budget=1 << 10):
+    """One dispatch of the engine's compiled segment over ``vecs`` as one
+    block behind an empty filter: host copies of (bufs, stats)."""
+    block = eng.caps.block
+    rows = np.zeros((block, eng.schema.P), np.int32)
+    rows[:len(vecs)] = eng.schema.pack(vecs, np)
+    flags = np.zeros((block,), bool)
+    flags[:len(vecs)] = con
+    _fc, bufs, stats = eng._segment(
+        eng._init_filter(), eng._make_bufs(), jnp.asarray(rows),
+        jnp.asarray(flags), jnp.int32(budget), jnp.int32(len(vecs)))
+    return jax.device_get(bufs), jax.device_get(stats)
+
+
+def _slab_counts(per_chunk, slab):
+    """NumPy replay of the two counters from the rows each chunk
+    streamed: the peak, and one slab a chunk plus its overflow slabs."""
+    per_chunk = np.asarray(per_chunk)
+    return (int(per_chunk.max(initial=0)),
+            int(np.maximum(-(-per_chunk // slab), 1).sum()))
+
+
+@pytest.fixture(scope="module")
+def whole_slab_runs():
+    """What ``S >= N`` gives (the shipped _S_OUT dwarfs a toy chunk): the
+    toy universe's check() and one deep block through the segment, per
+    step flavour."""
+    vecs, con = _frontier_block(CFG, 9, 200)
+    out = {}
+    for flavour in _FLAVOURS:
+        caps = _flavour_caps(flavour)
+        assert ddd_mod._S_OUT >= _n_lanes(CFG)
+        out[flavour] = (DDDEngine(CFG, caps).check(),
+                        _run_segment(DDDEngine(CFG, caps), vecs, con))
+    return vecs, con, out
+
+
+@pytest.mark.parametrize("slab", [8, 7])      # 7 divides neither N nor K
+@pytest.mark.parametrize("flavour", _FLAVOURS)
+def test_slab_size_never_shows_in_check(flavour, slab, whole_slab_runs,
+                                        monkeypatch):
+    _vecs, _con, ref = whole_slab_runs
+    monkeypatch.setattr(ddd_mod, "_S_OUT", slab)
+    got = DDDEngine(CFG, _flavour_caps(flavour)).check()
+    want = ref[flavour][0]
+    for f in ("n_states", "diameter", "levels", "n_transitions",
+              "coverage", "complete", "violation"):
+        assert getattr(got, f) == getattr(want, f), f
+
+
+@pytest.mark.parametrize("slab", [8, 7])
+@pytest.mark.parametrize("flavour", _FLAVOURS)
+def test_slab_size_never_shows_in_segment(flavour, slab, whole_slab_runs,
+                                          monkeypatch):
+    """SegBufs[:cursor] and the old SegStats fields are byte-identical to
+    the whole-slab run's; the two new counters equal a NumPy replay of
+    the stream (rows per chunk, read off the parents it carries)."""
+    vecs, con, ref = whole_slab_runs
+    want_bufs, want_stats = ref[flavour][1]
+    monkeypatch.setattr(ddd_mod, "_S_OUT", slab)
+    eng = DDDEngine(CFG, _flavour_caps(flavour))
+    nk = eng.caps.route_rows or _n_lanes(CFG)
+    assert eng._buf_rows == eng.caps.seg_rows + (-nk % slab)
+    bufs, stats = _run_segment(eng, vecs, con)
+    for f in _OLD_STATS:
+        assert getattr(stats, f) == getattr(want_stats, f), f
+    n = int(stats.cursor)
+    assert n > 0 and int(stats.steps) == -(-len(vecs) // CFG.chunk)
+    for f in bufs._fields:
+        got, want = getattr(bufs, f)[:n], getattr(want_bufs, f)[:n]
+        assert got.dtype == want.dtype and got.tobytes() == want.tobytes(), f
+    per_chunk = np.bincount(bufs.opar[:n] // CFG.chunk,
+                            minlength=int(stats.steps))
+    assert per_chunk.max() > slab          # the slab loop really ran
+    assert (int(stats.stream_peak), int(stats.stream_slabs)) \
+        == _slab_counts(per_chunk, slab)
+    assert (int(want_stats.stream_peak), int(want_stats.stream_slabs)) \
+        == _slab_counts(per_chunk, _n_lanes(CFG)) \
+        == (per_chunk.max(), int(stats.steps))
+
+
+class _PlannedStep:
+    """Stand-in for kernels.build_step / build_step_routed: a chunk whose
+    first row has term[0] == t enables exactly ``plan[t]`` lanes (a seeded
+    choice), with keys, rows and flags that are functions of the lane —
+    so the test knows, in NumPy, every row a chunk must stream."""
+
+    def __init__(self, cfg, plan, seed=0):
+        from raft_tla_tpu.models import spec as S
+        from raft_tla_tpu.ops import state as st
+        self.B = cfg.chunk
+        self.A = len(S.action_table(cfg.bounds, cfg.spec))
+        self.N = self.B * self.A
+        self.n_inv = len(cfg.invariants)
+        self.term0 = cfg.bounds.n_servers      # flat offset of term[0]
+        self.counts = np.zeros((cfg.bounds.max_term + 1,), np.int32)
+        for t, n in plan.items():
+            self.counts[t] = n
+        rng = np.random.default_rng(seed)
+        self.rank = rng.permutation(self.N).astype(np.int32)
+        tmpl, _con = _frontier_block(cfg, 6, 40)
+        self.tmpl = tmpl.astype(np.int32)
+        assert self.tmpl.shape[1] == st.Layout.of(cfg.bounds).width
+
+    def lanes(self, t):
+        """Flat lanes a term-``t`` chunk enables, in stream order."""
+        return np.flatnonzero(self.rank < self.counts[t])
+
+    def expect(self, schema, t, r0):
+        """The SegBufs rows a term-``t`` chunk at block row ``r0`` must
+        stream, field by field."""
+        ln = self.lanes(t)
+        return {
+            "okey_hi": ((np.uint32(t) << np.uint32(20))
+                        | ln.astype(np.uint32)),
+            "okey_lo": (ln.astype(np.uint64) * 2654435761
+                        % (1 << 32)).astype(np.uint32),
+            "orows": schema.pack(self.tmpl[ln % len(self.tmpl)], np),
+            "opar": (r0 + ln // self.A).astype(np.int32),
+            "olane": (ln % self.A).astype(np.int32),
+            "ocon": ln % 3 != 0,
+        }
+
+    def dense(self, *_a, **_kw):
+        B, A, N = self.B, self.A, self.N
+
+        def step(vecs, row_ok=None):
+            t = vecs[0, self.term0]
+            lane = jnp.arange(N, dtype=jnp.int32)
+            valid = jnp.asarray(self.rank) < jnp.asarray(self.counts)[t]
+            tm = jnp.asarray(self.tmpl)
+            return {
+                "valid": valid.reshape(B, A),
+                "overflow": jnp.zeros((B, A), bool),
+                "fp_hi": ((t.astype(jnp.uint32) << 20)
+                          | lane.astype(jnp.uint32)).reshape(B, A),
+                "fp_lo": (lane.astype(jnp.uint32)
+                          * jnp.uint32(2654435761)).reshape(B, A),
+                "inv_ok": jnp.ones((B, A, self.n_inv), bool),
+                "con_ok": (lane % 3 != 0).reshape(B, A),
+                "svecs": tm[lane % tm.shape[0]].reshape(B, A, -1),
+            }
+        return step
+
+    def routed(self, *_a, k_rows, **_kw):
+        dense, N = self.dense(), self.N
+
+        def step(vecs, row_ok):
+            d = dense(vecs)
+            live = (d["valid"] & row_ok[:, None]).reshape(-1)
+            lane = jnp.arange(N, dtype=jnp.int32)
+            cidx = jnp.sort(jnp.where(live, lane, N))[:k_rows]
+            g = jnp.minimum(cidx, N - 1)
+            n_en = jnp.sum(live.astype(jnp.int32))
+            return {
+                "valid": d["valid"], "overflow": d["overflow"],
+                "cidx": cidx, "cvalid": cidx < N,
+                "csvecs": d["svecs"].reshape(N, -1)[g],
+                "cfp_hi": d["fp_hi"].reshape(-1)[g],
+                "cfp_lo": d["fp_lo"].reshape(-1)[g],
+                "cinv_ok": d["inv_ok"].reshape(N, -1)[g],
+                "ccon_ok": d["con_ok"].reshape(-1)[g],
+                "route_ovf": n_en > k_rows, "n_en": n_en}
+        return step
+
+    def install(self, monkeypatch):
+        monkeypatch.setattr(kernels, "build_step", self.dense)
+        monkeypatch.setattr(kernels, "build_step_routed", self.routed)
+
+
+def _term_rows(cfg, terms):
+    """One chunk of copies of Init per entry of ``terms``, with term[0]
+    set to it (what _PlannedStep keys a chunk's count on)."""
+    init = interp.init_state(cfg.bounds)
+    vecs = [interp.to_vec(init._replace(
+        term=(t,) + init.term[1:]), cfg.bounds)
+        for t in terms for _ in range(cfg.chunk)]
+    return np.stack(vecs), np.ones((len(vecs),), bool)
+
+
+def _assert_streamed(bufs, lo, want):
+    for f, w in want.items():
+        got = getattr(bufs, f)[lo:lo + len(w)]
+        assert got.dtype == w.dtype and np.array_equal(got, w), f
+
+
+@pytest.mark.parametrize("n_rows", ["0", "S", "S+1", "NK"])
+@pytest.mark.parametrize("flavour", _FLAVOURS)
+def test_chunk_streaming_exactly(flavour, n_rows, monkeypatch):
+    """One chunk that streams exactly 0, S, S+1 and NK rows: every row
+    lands in order, and the counters read the count and its slabs."""
+    slab = 8
+    caps = _flavour_caps(flavour)
+    nk = caps.route_rows or _n_lanes(CFG)
+    n = {"0": 0, "S": slab, "S+1": slab + 1, "NK": nk}[n_rows]
+    plan = _PlannedStep(CFG, {1: n})
+    plan.install(monkeypatch)
+    monkeypatch.setattr(ddd_mod, "_S_OUT", slab)
+    eng = DDDEngine(CFG, caps)
+    bufs, stats = _run_segment(eng, *_term_rows(CFG, [1]))
+    assert (int(stats.cursor), int(stats.n_valid), int(stats.steps),
+            int(stats.fail), bool(stats.done)) == (n, n, 1, 0, True)
+    _assert_streamed(bufs, 0, plan.expect(eng.schema, 1, 0))
+    assert (int(stats.stream_peak), int(stats.stream_slabs)) \
+        == _slab_counts([n], slab) == (n, max(1, -(-n // slab)))
+
+
+@pytest.mark.parametrize("flavour", _FLAVOURS)
+def test_chunk_entered_at_the_last_admissible_cursor_is_not_clamped(
+        flavour, monkeypatch):
+    """A chunk may start with ``cursor + NK == seg_rows`` and stream NK
+    rows; with a slab size that does not divide NK its last slab then
+    reaches past ``seg_rows``.  The buffers' slack rows take that: a
+    clamped dynamic_update_slice would shift the slab down over rows
+    already streamed."""
+    slab, n1 = 7, 5
+    nk = _flavour_caps(flavour).route_rows or _n_lanes(CFG)
+    assert nk % slab
+    caps = _flavour_caps(flavour, seg_rows=n1 + nk)
+    plan = _PlannedStep(CFG, {1: n1, 2: nk})
+    plan.install(monkeypatch)
+    monkeypatch.setattr(ddd_mod, "_S_OUT", slab)
+    eng = DDDEngine(CFG, caps)
+    assert eng._buf_rows == caps.seg_rows + slab - nk % slab
+    bufs, stats = _run_segment(eng, *_term_rows(CFG, [1, 2]))
+    assert (int(stats.cursor), int(stats.steps), int(stats.fail),
+            bool(stats.done)) == (n1 + nk, 2, 0, True)
+    assert len(bufs.okey_hi) == eng._buf_rows
+    _assert_streamed(bufs, 0, plan.expect(eng.schema, 1, 0))
+    _assert_streamed(bufs, n1, plan.expect(eng.schema, 2, CFG.chunk))
+    assert (int(stats.stream_peak), int(stats.stream_slabs)) \
+        == _slab_counts([n1, nk], slab)

@@ -148,7 +148,8 @@ def test_validate_rejects_unknowns_and_type_drift():
     assert validate_event({**ok, "v": 9}) == []             # v9 superset
     assert validate_event({**ok, "v": 10}) == []            # v10 superset
     assert validate_event({**ok, "v": 11}) == []            # v11 superset
-    assert validate_event({**ok, "v": 12})                  # future version
+    assert validate_event({**ok, "v": 12}) == []            # v12 superset
+    assert validate_event({**ok, "v": 13})                  # future version
     assert validate_event({"v": 1, "event": "level_end", "ts": 0.0,
                            "level": 3})                     # missing field
 
@@ -334,6 +335,40 @@ def test_validate_v11_run_end_compiles():
     assert errs and all("requires schema version >= 11" in e
                         for e in errs)
     assert validate_event({**end, "compiles": [1, 2]})     # type drift
+
+
+def test_validate_v12_segment_slab_counters():
+    """The ddd segment program's slab-write counters (``stream_peak``,
+    ``stream_slabs``) exist only from schema v12, field-gated like the
+    v9 segment fields."""
+    seg = {"v": 12, "event": "segment", "ts": 0.0, "wall_s": 1.0,
+           "n_states": 10, "level": 2, "n_transitions": 20,
+           "dedup_hit_rate": 0.5, "states_per_sec": 10.0,
+           "inc_states_per_sec": 10.0, "since_resume": True,
+           "stream_peak": 7, "stream_slabs": 3}
+    assert validate_event(seg) == []
+    errs = validate_event({**seg, "v": 11})  # v12-only fields, v11 line
+    assert len(errs) == 2 and all("requires schema version >= 12" in e
+                                  for e in errs)
+    assert validate_event({**seg, "stream_peak": 7.5})     # type drift
+
+
+def test_ddd_segment_records_carry_the_slab_counters(tmp_path):
+    """RunTelemetry keeps the pass's running maximum and total: the
+    segment records (log and on_progress alike) carry ``stream_peak``
+    non-decreasing and ``stream_slabs`` cumulative, and the last record
+    equals what the ``segments`` track sums to."""
+    path = str(tmp_path / "ddd.events")
+    recs = []
+    res = _run_engine("ddd", path, on_progress=recs.append)
+    segs = [e for e in _read_log(path) if e["event"] == "segment"]
+    assert res.n_states == N_TOY and segs and len(recs) == len(segs)
+    for key in ("stream_peak", "stream_slabs"):
+        vals = [s[key] for s in segs]
+        assert vals == sorted(vals) and vals[-1] > 0
+        assert vals == [r[key] for r in recs]
+    # every chunk of the toy space fits one slab: one slab write a step
+    assert 0 < segs[-1]["stream_peak"] <= 32 * 11
 
 
 def test_monitor_pool_attribution_rows(tmp_path):

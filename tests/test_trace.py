@@ -508,6 +508,37 @@ def test_level_spans_carry_their_work_and_match_the_segments_track(
 
 
 @pytest.mark.smoke
+def test_segment_spans_carry_the_slab_counters(traced_toy):
+    """``stream_peak`` / ``stream_slabs`` (the segment program's slab
+    writes, SegStats) ride the ``segment`` spans beside
+    ``streamed_rows``; the ``level`` span holds its segments' maximum
+    and total.  A toy chunk never overflows one slab, so a segment
+    writes one slab a step and its peak bounds what it streamed."""
+    _res, evs, _d = traced_toy
+    spans = [e for e in evs if e["event"] == "span"]
+    segs = [s for s in spans if s["name"] == "segment"]
+    assert segs
+    for s in segs:
+        a = s["args"]
+        assert {"stream_peak", "stream_slabs"} <= set(a)
+        assert a["stream_slabs"] == a["steps"]
+        assert a["stream_peak"] <= a["streamed_rows"] \
+            <= a["stream_peak"] * max(a["steps"], 1)
+    for lv in (s for s in spans if s["name"] == "level"):
+        mine = [s["args"] for s in segs
+                if s["args"]["level"] == lv["args"]["level"]]
+        assert lv["args"]["stream_slabs"] \
+            == sum(a["stream_slabs"] for a in mine)
+        assert lv["args"]["stream_peak"] \
+            == max(a["stream_peak"] for a in mine)
+    last = [e for e in evs if e["event"] == "segment"][-1]
+    assert last["stream_slabs"] == sum(s["args"]["stream_slabs"]
+                                       for s in segs)
+    assert last["stream_peak"] == max(s["args"]["stream_peak"]
+                                      for s in segs)
+
+
+@pytest.mark.smoke
 def test_self_times_over_the_tree_sum_to_the_pass_wall(traced_toy):
     _res, evs, d = traced_toy
     col = obs_collect.collect(obs_collect.find_logs(d))
@@ -536,7 +567,8 @@ def test_trace_report_prints_level_rows_from_the_level_spans(
     assert len(rows) == len(res.levels)
     assert rows[0].startswith("  L1: ") and "1 rows" in rows[0]
     assert all("segments" in r and "steps" in r and "self " in r
-               and "most in: " in r for r in rows)
+               and " slabs (peak " in r and "most in: " in r
+               for r in rows)
     assert " self " in text and "in spans)" in text
     rep = obs_collect.report(obs_collect.collect(
         obs_collect.find_logs(d)))
