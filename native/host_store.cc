@@ -193,8 +193,8 @@ int64_t store_trace_chain(Store* s, int64_t from_row, int64_t* out,
 }
 
 // Bit-identical twin of ops/fingerprint.fingerprint (two-lane multilinear
-// multiply-sum mod 2^32 + murmur3 fmix32).  c1/c2 are the lane_constants
-// rows; seeds are _LANE_SEEDS.
+// multiply-sum mod 2^32 of the folded words x ^ (x >> 16), + murmur3
+// fmix32).  c1/c2 are the lane_constants rows; seeds are _LANE_SEEDS.
 static inline uint32_t fmix32(uint32_t h) {
     h ^= h >> 16;
     h *= 0x85EBCA6Bu;
@@ -213,6 +213,7 @@ void fingerprint_rows(const int32_t* rows, int64_t n, int32_t width,
         uint32_t s1 = 0, s2 = 0;
         for (int32_t w = 0; w < width; ++w) {
             uint32_t v = (uint32_t)row[w];
+            v ^= v >> 16;
             s1 += v * c1[w];
             s2 += v * c2[w];
         }

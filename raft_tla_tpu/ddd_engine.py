@@ -1428,9 +1428,14 @@ class DDDEngine:
         stopped_by = None
         # the open level's work
         lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
+        lvl_valid = lvl_route = 0
 
         def end_level():
+            # lanes: what the dense step computed, enabled or not;
+            # n_valid / lanes is how much of it was enabled work
             level_sp.set(segments=lvl_segs, steps=lvl_steps,
+                         lanes=lvl_steps * N, n_valid=lvl_valid,
+                         route_peak=lvl_route,
                          streamed_rows=lvl_rows,
                          stream_peak=lvl_peak, stream_slabs=lvl_slabs,
                          new_states=n_states - lvl_hi).close()
@@ -1445,6 +1450,7 @@ class DDDEngine:
                                rows=lvl_hi - lvl_lo,
                                blocks=-(-(lvl_hi - b0) // Fcap))
             lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
+            lvl_valid = lvl_route = 0
             if prefetcher is not None and b0 < lvl_hi:
                 # level start: every block address in [lvl_lo, lvl_hi)
                 # is known now — warm the first block immediately
@@ -1577,7 +1583,8 @@ class DDDEngine:
                         n_steps = int(st_h.steps)
                         seg_peak = int(st_h.stream_peak)
                         seg_slabs = int(st_h.stream_slabs)
-                        route_peak = max(route_peak, int(st_h.peak))
+                        seg_route = int(st_h.peak)
+                        route_peak = max(route_peak, seg_route)
                         stream_peak = max(stream_peak, seg_peak)
                         stream_slabs += seg_slabs
                         if tr.enabled:
@@ -1589,12 +1596,16 @@ class DDDEngine:
                                 thread="segments", level=len(level_ends),
                                 block=(b_start - lvl_lo) // Fcap,
                                 budget=seg_budget, steps=n_steps,
+                                lanes=n_steps * N,
                                 streamed_rows=ns, n_valid=nv,
+                                route_peak=seg_route,
                                 stream_peak=seg_peak,
                                 stream_slabs=seg_slabs,
                                 dropped=stopped)
                         lvl_segs += 1
                         lvl_steps += n_steps
+                        lvl_valid += nv
+                        lvl_route = max(lvl_route, seg_route)
                         lvl_slabs += seg_slabs
                         lvl_peak = max(lvl_peak, seg_peak)
                         bufs_h = None

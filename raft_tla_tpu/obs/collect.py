@@ -341,6 +341,9 @@ def _level_rows(threads: dict, trees: dict) -> list:
                          "wall_s": s["dur"], "rows": a.get("rows"),
                          "segments": a.get("segments"),
                          "steps": a.get("steps"),
+                         "lanes": a.get("lanes"),
+                         "n_valid": a.get("n_valid"),
+                         "route_peak": a.get("route_peak"),
                          "stream_peak": a.get("stream_peak"),
                          "stream_slabs": a.get("stream_slabs"),
                          "new_states": a.get("new_states"),
@@ -411,6 +414,9 @@ def render_report(rep: dict) -> str:
                 f"  L{lv['level']}: {lv['wall_s']:.3f}s, "
                 f"{lv['rows']} rows, {lv['segments']} segments, "
                 f"{lv['steps']} steps, "
+                + (f"{lv['n_valid']} of {lv['lanes']} lanes enabled "
+                   f"(peak {lv['route_peak']} a step), "
+                   if lv["lanes"] else "")
                 + (f"{lv['stream_slabs']} slabs (peak "
                    f"{lv['stream_peak']} rows), "
                    if lv["stream_slabs"] is not None else "")

@@ -6,13 +6,29 @@ that role for the tensor encoding: a canonical ``int32[W]`` state vector hashes
 to two independent 32-bit lanes, combined host-side into one ``uint64``.
 
 Scheme: two-lane *multilinear* hash + murmur3 finalizer.  Lane k computes
-``fmix32(seed_k + sum_w c_k[w] * state[w] mod 2^32)`` with per-position odd
-random constants ``c_k``.  The multilinear family is pairwise almost-universal
-(collision probability ~2^-32 per lane per pair); two independent lanes give
-~2^-64 per pair — the same regime TLC operates in.  The linear part is one
-elementwise multiply + reduction (TPU-friendly: no sequential dependency over
-W, unlike a rolling hash), and the fmix32 avalanche decorrelates lanes from
-the raw linear structure for use as a hash-table index.
+``fmix32(seed_k + sum_w c_k[w] * fold(state[w]) mod 2^32)`` with per-position
+odd random constants ``c_k`` and ``fold(x) = x ^ (x >> 16)``.  The linear part
+is one elementwise multiply + reduction (TPU-friendly: no sequential
+dependency over W, unlike a rolling hash), and the fmix32 avalanche
+decorrelates lanes from the raw linear structure for use as a hash-table
+index.
+
+What the family guarantees, and why the fold (scheme 2, PR 26).  Two vectors
+collide in a lane iff ``sum_w c[w] * d[w] == 0 (mod 2^32)`` over their word
+differences ``d``.  A single differing word never collides (``c`` is odd);
+for several, the probability is ``2^-(32-t)`` a lane where ``2^t`` is the
+largest power of two dividing every ``d[w]`` — ``2^-32`` only when some
+difference is odd, and both lanes see the same ``t``.  Packed message words
+keep ``src``/``dst`` at bits 21-28 (ops/msgbits), so two bags that differ
+only in who sent to whom had ``t = 21``: ``2^-11`` a lane, ``2^-22`` a pair
+of states.  At 5 servers under the full ``Next`` that merged distinct orbits
+from BFS level 6 on (936 counted of 937; 261,499 of 261,844 by level 12 —
+found by the benchmark's plain reference, which never sees a fingerprint).
+Folding each word's high half into its low half (a bijection on 32 bits,
+the identity on words below 2^16) brings those differences down to bits
+5-12: ``t <= 12``, ``2^-40`` a pair or better.  Differences confined to bits
+13-15 of several words stay where they were (``2^-34`` at worst); no packed
+field of this schema lives there alone.
 
 Bit-identical across backends: all arithmetic is uint32 wraparound, explicit
 dtypes everywhere, same constants (fixed PRNG seed) — NumPy host, jnp device,
@@ -25,6 +41,10 @@ from __future__ import annotations
 import numpy as np
 
 _SEED = 0x5AF7_0001
+# Joins every checkpoint digest (utils/ckpt.config_digest): a snapshot's
+# master keys are fingerprints, so one written under another scheme must be
+# refused, not resumed.  1 = no fold (through PR 25); 2 = ``x ^ (x >> 16)``.
+SCHEME = 2
 _LANE_SEEDS = (np.uint32(0x9E3779B9), np.uint32(0x85EBCA77))
 
 
@@ -52,6 +72,7 @@ def fingerprint(vec, consts, xp):
     # scalar-overflow warning (no-op under jnp, which never warns).
     with np.errstate(over="ignore"):
         w = vec.astype(xp.uint32)
+        w = w ^ (w >> xp.uint32(16))      # the fold (module docstring)
         c1 = consts[0].astype(xp.uint32)
         c2 = consts[1].astype(xp.uint32)
         s1 = xp.sum(w * c1, axis=-1, dtype=xp.uint32)

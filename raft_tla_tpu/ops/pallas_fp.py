@@ -62,6 +62,7 @@ def _fp_kernel(vec_ref, c1_ref, c2_ref, hi_ref, lo_ref):
     # shifts are made explicitly logical.
     srl = jax.lax.shift_right_logical
     w = vec_ref[...]
+    w = w ^ srl(w, jnp.int32(16))       # the fold (ops/fingerprint)
     s1 = jnp.sum(w * c1_ref[...], axis=1, dtype=jnp.int32)
     s2 = jnp.sum(w * c2_ref[...], axis=1, dtype=jnp.int32)
 

@@ -25,6 +25,8 @@ import zipfile
 
 import numpy as np
 
+from raft_tla_tpu.ops import fingerprint as fpr
+
 _STREAM_ROWS = 1 << 20      # rows per streamed block
 
 
@@ -68,6 +70,10 @@ def config_digest(config, caps, init_key: tuple) -> int:
     extras = (("check_deadlock", True),) if config.check_deadlock else ()
     if getattr(config, "view", None):
         extras += (("view", config.view),)
+    # the fingerprint scheme joins it too: a snapshot's master keys ARE
+    # fingerprints (Init's key alone would not tell: the fold of
+    # ops/fingerprint is the identity on a state with no message)
+    extras += (("fp_scheme", fpr.SCHEME),)
     key = repr((_stable(config.bounds), config.spec, config.invariants,
                 config.symmetry, config.chunk, _stable(caps),
                 init_key, *extras)).encode()

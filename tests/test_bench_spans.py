@@ -19,7 +19,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.harness import ledgerred, passes, spanred, stagered  # noqa: E402
+from benchmark.harness import (lanered, ledgerred, passes, spanred,  # noqa: E402
+                               stagered)
 from benchmark.harness import manifest as mf  # noqa: E402
 
 TESTDATA = os.path.join(ROOT, "benchmark", "testdata")
@@ -34,6 +35,9 @@ STAGE_METRICS = ("stage_expand_ms", "stage_orbit_ms", "stage_check_ms",
 LEDGER_METRICS = ("setup_trace_s", "setup_backend_s", "setup_programs")
 OTHER_METRICS = ("clock_skew_us", "span_overhead_pct")
 NEW_METRICS = SPAN_METRICS + STAGE_METRICS + LEDGER_METRICS + OTHER_METRICS
+# PR 26: the lane counts on the ``level`` spans (benchmark/harness/lanered.py)
+LANE_METRICS = ("lane_fill_pct", "slabs_per_step", "route_peak_rows")
+LANE_LOG = os.path.join(TESTDATA, "lanes_small.jsonl")
 
 
 @pytest.fixture(scope="module")
@@ -70,17 +74,52 @@ def test_manifest_gains_the_nineteen_readers_and_nothing_else():
     manifest = mf.load()
     assert mf.problems(manifest) == []
     names = [m["name"] for m in manifest["per_layer"]]
-    assert tuple(names[-19:]) == NEW_METRICS        # appended, in order
-    new = {m["name"]: m for m in manifest["per_layer"][-19:]}
+    # found by name, in the order PR 24 appended them (later PRs append
+    # their own readers after these)
+    at = [names.index(n) for n in NEW_METRICS]
+    assert at == list(range(at[0], at[0] + 19))
+    new = {m["name"]: m for m in manifest["per_layer"]
+           if m["name"] in NEW_METRICS}
     assert {m["moves"] for n, m in new.items() if n in LEDGER_METRICS} \
         == {"setup_s"}
     assert {m["moves"] for n, m in new.items()
             if n not in LEDGER_METRICS} == {"orbits_per_s"}
-    # each lists the cells its reader finds something to read in
-    assert all(m["workloads"] == ["elect5.passes", "flagship3.passes"]
-               for m in new.values())
+    # each lists the cells its reader finds something to read in: the two
+    # of PR 24 first, cells of later PRs appended
+    cells = [w["name"] for w in manifest["workloads"]]
+    for m in new.values():
+        assert m["workloads"][:2] == ["elect5.passes", "flagship3.passes"]
+        assert set(m["workloads"]) <= set(cells)
     assert {new[n]["source"] for n in SPAN_METRICS} == {"program_span"}
     assert {new[n]["source"] for n in STAGE_METRICS} == {"device_trace"}
+
+
+def test_manifest_gains_the_three_lane_readers_and_the_cell_full5():
+    """PR 26: one configuration, one cell, three readers of the ``level``
+    spans' lane counts, each listing every cell; the nineteen of PR 24 gain
+    the cell and nothing else."""
+    manifest = mf.load()
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert tuple(names[names.index(NEW_METRICS[-1]) + 1:][:3]) == LANE_METRICS
+    for m in manifest["per_layer"]:
+        if m["name"] in LANE_METRICS:
+            assert (m["layer"], m["moves"], m["source"]) \
+                == ("fused step", "orbits_per_s", "program_span")
+            assert m["workloads"] == ["elect5.passes", "flagship3.passes",
+                                      "full5.passes"]
+        elif m["name"] in NEW_METRICS:
+            assert m["workloads"] == ["elect5.passes", "flagship3.passes",
+                                      "full5.passes"]
+    cell = mf.cell(manifest, "full5.passes")
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == ("full5", "passes_l10_l12", 1)
+    pins, t = cell["config_data"]["level_pins"], cell["traffic_data"]
+    assert (pins[t["start_level"]], pins[t["end_level"]]) \
+        == (t["count_at_start"], t["count_at_end"]) == (45236, 261844)
+    # the cfg the configuration names is the one under runs/, word for word
+    with open(os.path.join(ROOT, "runs", "MC5s2v_full.cfg"),
+              encoding="utf-8") as f:
+        assert f.read() == cell["config_data"]["cfg_text"]
 
 
 # ----------------------------------------------------- the span reduction
@@ -327,6 +366,68 @@ def test_a_log_of_the_flat_spans_reads_as_nothing(tmp_path, recorded):
     ev["stagered"] = None
     for n in SPAN_METRICS + STAGE_METRICS + ("clock_skew_us",):
         assert mf.metric_reader(n)(ev) is None, n
+
+
+# ------------------------------------------- the lane counts (PR 26)
+
+def _lane_evidence(log=LANE_LOG):
+    traced = passes.Pass(index=2, t_call=10.0, t_a=13.0, t_trace_end=14.7,
+                         traced=True, events=log)
+    return {"passes": [traced], "work": {"traced_levels": [10, 11]}}
+
+
+def test_lane_reduction_of_the_recorded_log():
+    """``lanes_small.jsonl``: the ``pass`` / ``level`` / ``segment`` spans
+    of one traced pass of ``full5.passes`` on the v5e (PR 26).  The level
+    spans' sums against the segments' own, and against the shapes: 37
+    chunk steps of 4,096 rows x 84 actions."""
+    spans = spanred.load(LANE_LOG)
+    red = lanered.reduce(spans)
+    segs = [s["args"] for s in spans if s["name"] == "segment"]
+    assert red == {"levels": 13, "steps": 37, "lanes": 37 * 4096 * 84,
+                   "n_valid": 721572, "stream_slabs": 37,
+                   "route_peak": 38892}
+    assert red["steps"] == sum(a["steps"] for a in segs)
+    assert red["lanes"] == sum(a["lanes"] for a in segs)
+    assert red["n_valid"] == sum(a["n_valid"] for a in segs)
+    assert red["route_peak"] == max(a["route_peak"] for a in segs)
+    assert lanered.reduce([s for s in spans if s["name"] != "level"]) is None
+
+
+def test_each_lane_reader_on_the_recorded_pass(capsys):
+    ev = _lane_evidence()
+    got = {n: mf.metric_reader(n)(ev) for n in LANE_METRICS}
+    assert got["lane_fill_pct"] == pytest.approx(100 * 721572 / 12730368)
+    assert got["slabs_per_step"] == 1.0
+    assert got["route_peak_rows"] == 38892
+    assert capsys.readouterr().out.count("lane counts pass 2: ") == 1
+
+
+@pytest.mark.parametrize("name", LANE_METRICS)
+def test_a_lane_reader_with_nothing_to_read_returns_nothing(name):
+    plain = [passes.Pass(index=k, t_call=10.0 * k, t_a=10.0 * k + 2.7,
+                         t_b=10.0 * k + 6.0) for k in (1, 2, 3)]
+    assert mf.metric_reader(name)({"passes": plain, "work": {}}) is None
+    # PR 24's log: level spans, but from before any of the counts
+    assert mf.metric_reader(name)(_lane_evidence(SPAN_LOG)) is None
+
+
+def test_the_parents_level_spans_give_the_slab_ratio_alone(tmp_path):
+    """PR 25's program: ``steps`` and ``stream_slabs`` on the level spans,
+    no ``lanes``, ``n_valid`` or ``route_peak`` — the one reader that has
+    its counts reports, the other two leave their metric out."""
+    log = tmp_path / "pr25.events"
+    with open(LANE_LOG, encoding="utf-8") as f, open(log, "w") as out:
+        for line in f:
+            ev = json.loads(line)
+            for k in ("lanes", "n_valid", "route_peak"):
+                if ev.get("name") == "level":
+                    ev["args"].pop(k, None)
+            out.write(json.dumps(ev) + "\n")
+    ev = _lane_evidence(str(log))
+    assert mf.metric_reader("slabs_per_step")(ev) == 1.0
+    assert mf.metric_reader("lane_fill_pct")(ev) is None
+    assert mf.metric_reader("route_peak_rows")(ev) is None
 
 
 # ------------------------------------------------- the scopes in the step

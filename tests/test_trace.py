@@ -539,6 +539,35 @@ def test_segment_spans_carry_the_slab_counters(traced_toy):
 
 
 @pytest.mark.smoke
+def test_segment_and_level_spans_carry_the_lane_counters(traced_toy):
+    """``lanes`` (chunk steps x chunk x A: what the dense step computed),
+    ``n_valid`` and ``route_peak`` (SegStats.n_valid / .peak: how many of
+    those lanes were enabled, in all and at most in one step) ride the
+    ``segment`` spans; the ``level`` span holds its segments' sums and
+    maximum; the pass's last ``segment`` record holds the same peak."""
+    from raft_tla_tpu.models import spec as S
+    res, evs, _d = traced_toy
+    n_lanes = CFG.chunk * len(S.action_table(CFG.bounds, CFG.spec))
+    spans = [e for e in evs if e["event"] == "span"]
+    segs = [s["args"] for s in spans if s["name"] == "segment"]
+    assert segs
+    for a in segs:
+        assert a["lanes"] == a["steps"] * n_lanes
+        assert a["route_peak"] <= a["n_valid"] \
+            <= a["route_peak"] * max(a["steps"], 1) <= max(a["lanes"], 0)
+    levels = [s["args"] for s in spans if s["name"] == "level"]
+    for lv in levels:
+        mine = [a for a in segs if a["level"] == lv["level"]]
+        assert lv["lanes"] == sum(a["lanes"] for a in mine) \
+            == lv["steps"] * n_lanes
+        assert lv["n_valid"] == sum(a["n_valid"] for a in mine)
+        assert lv["route_peak"] == max(a["route_peak"] for a in mine)
+    assert sum(lv["n_valid"] for lv in levels) == res.n_transitions
+    last = [e for e in evs if e["event"] == "segment"][-1]
+    assert last["route_peak"] == max(lv["route_peak"] for lv in levels) > 0
+
+
+@pytest.mark.smoke
 def test_self_times_over_the_tree_sum_to_the_pass_wall(traced_toy):
     _res, evs, d = traced_toy
     col = obs_collect.collect(obs_collect.find_logs(d))
@@ -568,7 +597,7 @@ def test_trace_report_prints_level_rows_from_the_level_spans(
     assert rows[0].startswith("  L1: ") and "1 rows" in rows[0]
     assert all("segments" in r and "steps" in r and "self " in r
                and " slabs (peak " in r and "most in: " in r
-               for r in rows)
+               and " lanes enabled (peak " in r for r in rows)
     assert " self " in text and "in spans)" in text
     rep = obs_collect.report(obs_collect.collect(
         obs_collect.find_logs(d)))
