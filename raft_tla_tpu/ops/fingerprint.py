@@ -40,6 +40,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from raft_tla_tpu.ops import state as st
+
 _SEED = 0x5AF7_0001
 # Joins every checkpoint digest (utils/ckpt.config_digest): a snapshot's
 # master keys are fingerprints, so one written under another scheme must be
@@ -77,6 +79,42 @@ def fingerprint(vec, consts, xp):
         c2 = consts[1].astype(xp.uint32)
         s1 = xp.sum(w * c1, axis=-1, dtype=xp.uint32)
         s2 = xp.sum(w * c2, axis=-1, dtype=xp.uint32)
+        h1 = _fmix32(s1 + _LANE_SEEDS[0], xp)
+        h2 = _fmix32(s2 + _LANE_SEEDS[1], xp)
+    return h1, h2
+
+
+def fingerprint_fields(struct, consts, xp):
+    """State struct (ops/state.py; leading batch dims pass through) ->
+    (hi, lo) uint32 lanes, bit-identical to
+    ``fingerprint(state.pack(struct), consts, xp)``.
+
+    The sum before the finaliser is mod 2^32, so it may be taken in any
+    order: each field is folded where it lies, multiplied by the constants
+    of its packed positions (``state.pack``'s order, row-major inside a
+    field) and reduced over its own axes, and the per-field sums are
+    added.  No ``[..., W]`` row is built.  The orbit scan keys from here
+    |G| times a chunk step (ops/symmetry.build_orbit_fp), where the packed
+    row was written to HBM and read back once a permutation for nothing.
+    ``consts`` must be concrete (closed over, never a traced argument):
+    its slices become literals of the compiled program."""
+    c = np.asarray(consts).astype(np.uint32)
+    batch = struct["role"].ndim - 1
+    s1 = s2 = xp.uint32(0)
+    off = 0
+    with np.errstate(over="ignore"):
+        for f in st.fields_of(struct):
+            a = struct[f]
+            shape = a.shape[batch:]
+            size = int(np.prod(shape))
+            axes = tuple(range(batch, a.ndim))
+            w = a.astype(xp.uint32)
+            w = w ^ (w >> xp.uint32(16))      # the fold (module docstring)
+            s1 = s1 + xp.sum(w * c[0, off:off + size].reshape(shape),
+                             axis=axes, dtype=xp.uint32)
+            s2 = s2 + xp.sum(w * c[1, off:off + size].reshape(shape),
+                             axis=axes, dtype=xp.uint32)
+            off += size
         h1 = _fmix32(s1 + _LANE_SEEDS[0], xp)
         h2 = _fmix32(s2 + _LANE_SEEDS[1], xp)
     return h1, h2

@@ -611,7 +611,7 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     consts = jnp.asarray(fpr.lane_constants(lay.width))
     expand = build_expand(bounds, spec, family_kernels=family_kernels)
     inv_fns = [inv_mod.jnp_invariant(nm, bounds) for nm in invariants]
-    # Scan-compiled orbit pass: ONE copy of the permute/canonicalize/pack/
+    # Scan-compiled orbit pass: ONE copy of the permute/canonicalize/
     # fingerprint pipeline iterated over the n!*V! group, not n!*V!
     # unrolled copies (ops/symmetry.build_orbit_fp) — bit-identical keys.
     # The sig-prune gate selects the coset-pruned variant of the SAME
@@ -629,10 +629,16 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     # compilation at P=24 (kernel stack scales with the unrolled group;
     # 73 MB at P=120 vs the 16 MB scoped-vmem limit, and the P=24
     # remote-compile returned HTTP 500 — runs/pallas_orbit_p24.out),
-    # and was deleted: XLA's scan fusion already keeps one copy of the
-    # permute/canonicalize/pack/fingerprint pipeline resident, which is
-    # all the kernel could offer.  Mosaic findings preserved in
-    # RESULTS.md "Pallas orbit kernel" and runs/pallas_orbit_p24.out.
+    # and was deleted.  The scan does NOT compile to one resident fused
+    # pipeline: read from the v5e's compiled program, its body is some
+    # 290 device operations a permutation (~55 loop fusions, 21 unfused
+    # dynamic-update-slices and ~15 copies from the message sort
+    # network, two custom gather fusions a permuted field).  The packed
+    # [lanes, W] row is not among them: fingerprint.fingerprint_fields
+    # keys from the fields, where a concatenate wrote the row to HBM and
+    # a reduce read it back once a permutation (PERF.md, PR 27; what
+    # stands is in its section 7).  Mosaic findings: git show
+    # f293573:RESULTS.md "Pallas orbit kernel", runs/pallas_orbit_p24.out.
     # (Distinct bet, different scope: the WHOLE-step Pallas megakernel,
     # ops/pallas_step.py, stages this very program into one kernel to
     # eliminate the HBM round-trips BETWEEN the stage fusions — gated

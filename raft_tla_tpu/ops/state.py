@@ -163,10 +163,16 @@ def init_struct(bounds: Bounds, xp):
     return out
 
 
+def fields_of(struct) -> tuple:
+    """A struct's field names in packed order (parity then history)."""
+    return STATE_FIELDS + (HISTORY_FIELDS if "allLogs" in struct else ())
+
+
 def pack(struct, xp):
-    """Struct -> flat int32[W] vector (field order = parity then history)."""
-    fields = STATE_FIELDS + (HISTORY_FIELDS if "allLogs" in struct else ())
-    return xp.concatenate([xp.reshape(struct[f], (-1,)) for f in fields])
+    """Struct -> flat int32[W] vector (field order = :func:`fields_of`,
+    row-major inside a field)."""
+    return xp.concatenate([xp.reshape(struct[f], (-1,))
+                           for f in fields_of(struct)])
 
 
 def unpack(vec, lay: Layout, xp):

@@ -13,8 +13,8 @@ and one store row — the reachable count becomes the orbit count, exactly
 TLC's SYMMETRY semantics (including its property: the stored witness per
 orbit is whichever member was discovered first).  On device this is |π|
 static transforms batched over the candidate block — pure gathers, bit
-arithmetic, and the existing canonicalize/pack/fingerprint pipeline, fused
-by XLA; no extra passes over HBM.
+arithmetic, and the existing canonicalize/fingerprint pipeline (the key of
+each image is taken from its fields, never from a packed row).
 
 Permuting one state under ``p`` (new index of old server j is ``p[j]``):
 
@@ -558,8 +558,10 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
                    prune: bool = False):
     """Batched orbit-minimal fingerprints: ``struct[N, ...] -> (hi, lo)[N]``.
 
-    Bit-identical to :func:`orbit_fingerprint` (same permute/canonicalize/
-    pack/fingerprint arithmetic; the (hi, lo) lexicographic min is
+    Bit-identical to :func:`orbit_fingerprint` (same permute/canonicalize
+    arithmetic, and the key of each image taken from its fields,
+    ``fingerprint_fields``, which equals the fingerprint of the packed
+    row the loop builds; the (hi, lo) lexicographic min is
     order-independent) but compiled as ONE transform iterated by
     ``lax.scan`` over the |G| = n!·V! group elements, instead of |G|
     unrolled copies of the pipeline.  The round-1 unrolled graph at five
@@ -585,9 +587,13 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
     P = len(permutations(bounds)) if "Server" in axes else 1
     Q = len(value_permutations(bounds)) if "Value" in axes else 1
 
-    def orbit_fp(struct):
-        N = struct["role"].shape[0]
+    def canon_fp(s):
+        # the key of one group element's image, from its fields: neither
+        # scan body below builds the packed row (a concatenate and two
+        # relayouts through HBM every iteration; PERF.md, PR 27)
+        return fpr.fingerprint_fields(st.canonicalize(s, jnp), consts, jnp)
 
+    def orbit_fp(struct):
         def body(best, k):
             pi, qi = k // Q, k % Q
             t = struct
@@ -597,9 +603,7 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
                                           bit_lut[pi], p_lut[pi], jnp)
             if vluts is not None:
                 t = _permute_values_batch(t, vluts, qi, bounds, jnp)
-            packed = jax.vmap(
-                lambda s: st.pack(st.canonicalize(s, jnp), jnp))(t)
-            hi, lo = fpr.fingerprint(packed, consts, jnp)
+            hi, lo = jax.vmap(canon_fp)(t)
             bh, bl = best
             take = (hi < bh) | ((hi == bh) & (lo < bl))
             return (jnp.where(take, hi, bh), jnp.where(take, lo, bl)), None
@@ -705,13 +709,12 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
                         t = _permute_values_traced(
                             t, {kk: vv[qi] for kk, vv in vluts.items()},
                             bounds, jnp)
-                    return st.pack(st.canonicalize(t, jnp), jnp)
+                    return canon_fp(t)
 
                 def body(best, j):
                     k = kidx[:, j]
                     pi, qi = k // Q, k % Q
-                    packed = jax.vmap(one)(struct, pi, qi)
-                    hi, lo = fpr.fingerprint(packed, consts, jnp)
+                    hi, lo = jax.vmap(one)(struct, pi, qi)
                     bh, bl = best
                     take = (hi < bh) | ((hi == bh) & (lo < bl))
                     return (jnp.where(take, hi, bh),

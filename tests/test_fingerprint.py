@@ -141,3 +141,65 @@ def test_a_snapshot_of_another_scheme_is_refused(monkeypatch):
     now = ckpt.config_digest(cfg, Caps(), (1, 2))
     monkeypatch.setattr(fpr, "SCHEME", 1)
     assert ckpt.config_digest(cfg, Caps(), (1, 2)) != now
+
+
+# -- the key taken from the fields (PR 27) -----------------------------------
+
+def _layout_bounds(n, faithful):
+    if faithful:
+        return Bounds(n_servers=n, n_values=2, max_term=2, max_log=1,
+                      max_msgs=2, history=True, max_elections=3)
+    return Bounds(n_servers=n, n_values=2, max_term=2, max_log=1, max_msgs=2,
+                  max_dup=1)
+
+
+def _reachable_vecs(bounds, cap=160):
+    frontier = [interp.init_state(bounds)]
+    seen = list(frontier)
+    while len(seen) < cap:
+        nxt = []
+        for s in frontier:
+            if interp.constraint_ok(s, bounds):     # counted, not expanded
+                nxt += [t for _a, t in interp.successors(s, bounds,
+                                                         spec="full")]
+        frontier = nxt[:60]
+        seen += frontier
+    return np.stack([interp.to_vec(s, bounds) for s in seen[:cap]])
+
+
+@pytest.mark.parametrize("source", ["random", "reachable"])
+@pytest.mark.parametrize("n", [3, 5])
+@pytest.mark.parametrize("faithful", [False, True],
+                         ids=["parity", "faithful"])
+@pytest.mark.parametrize("backend", ["numpy", "jnp"])
+def test_fingerprint_fields_equals_fingerprint_of_the_packed_row(
+        backend, faithful, n, source):
+    """``fingerprint_fields(s) == fingerprint(pack(s))`` bit for bit: the
+    orbit scan keys from the fields (ops/symmetry.build_orbit_fp), every
+    other site from the packed row, and the two must be one key."""
+    bounds = _layout_bounds(n, faithful)
+    lay = st.Layout.of(bounds)
+    consts = fpr.lane_constants(lay.width)
+    if source == "random":      # any int32: the fold sees high halves too
+        rng = np.random.default_rng(lay.width * 10 + n)
+        vecs = rng.integers(-2**31, 2**31, size=(96, lay.width),
+                            dtype=np.int64).astype(np.int32)
+    else:
+        vecs = _reachable_vecs(bounds)
+    want = fpr.fingerprint(vecs, consts, np)
+    if backend == "numpy":
+        xp, rows = np, vecs
+    else:
+        import jax.numpy as xp
+        rows = xp.asarray(vecs)
+    struct = st.unpack(rows, lay, xp)
+    assert list(struct) == list(lay.fields)
+    assert np.array_equal(np.asarray(st.pack(
+        {f: a[0] for f, a in struct.items()}, xp)), vecs[0])
+    got = fpr.fingerprint_fields(struct, consts, xp)           # batched
+    assert got[0].dtype == xp.uint32 and got[0].shape == (len(vecs),)
+    assert np.array_equal(np.asarray(got[0]), want[0])
+    assert np.array_equal(np.asarray(got[1]), want[1])
+    one = fpr.fingerprint_fields({f: a[7] for f, a in struct.items()},
+                                 consts, xp)                   # one state
+    assert (int(one[0]), int(one[1])) == (int(want[0][7]), int(want[1][7]))

@@ -199,46 +199,102 @@ def test_value_symmetry_faithful_mode():
     assert ref.violation is None and got.violation is None
 
 
-def test_scan_orbit_fp_bit_identical_to_loop():
+_B3S = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2)
+_BH2 = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2,
+              history=True, max_elections=4)
+# the benchmark's 5-server bounds (benchmark/configs/elect5.json, full5.json)
+_ELECT5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=0, max_msgs=2,
+                 max_dup=1)
+_FULL5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=1, max_msgs=2,
+                max_dup=1)
+# (bounds, axes, spec, BFS depth, lanes kept a level, states compared)
+_SCAN_CASES = {
+    "3s-server": (_B3S, ("Server",), "full", 4, 40, 200),
+    "3s-value": (_B3S, ("Value",), "full", 4, 40, 200),
+    "3s-server-value": (_B3S, ("Server", "Value"), "full", 4, 40, 200),
+    "2s-faithful-server-value": (_BH2, ("Server", "Value"), "full", 4, 40,
+                                 200),
+    "2s-faithful-value": (_BH2, ("Value",), "full", 6, 60, 300),
+    "elect5-server": (_ELECT5, ("Server",), "election", 7, 60, 300),
+    "full5-server": (_FULL5, ("Server",), "full", 7, 60, 300),
+}
+
+
+def _scan_case_states(bounds, spec, depth, lane_cap, cap):
+    """A bag of reachable states: BFS prefix via the interpreter."""
+    frontier = [interp.init_state(bounds)]
+    seen = list(frontier)
+    for _ in range(depth):
+        nxt = []
+        for s in frontier:
+            if interp.constraint_ok(s, bounds):     # counted, not expanded
+                nxt += [t for _i, t in interp.successors(s, bounds,
+                                                         spec=spec)]
+        # every lane_cap-th successor: late ones carry the deeper histories
+        frontier = nxt[::max(1, len(nxt) // lane_cap)][:lane_cap]
+        seen += frontier
+    return seen[:cap]
+
+
+@pytest.mark.parametrize("case", list(_SCAN_CASES))
+def test_scan_orbit_fp_bit_identical_to_loop(case):
     """The scan-compiled orbit pass (build_orbit_fp — ONE transform
-    iterated over the group) must produce bit-identical (hi, lo) keys to
-    the reference unrolled loop (orbit_fingerprint): checkpointed runs
-    resume across the upgrade only if the keys are unchanged."""
+    iterated over the group, keying each image from its fields) must
+    produce bit-identical (hi, lo) keys to the reference unrolled loop
+    (orbit_fingerprint, which packs the row): checkpointed runs resume
+    across the upgrade only if the keys are unchanged.  The coset-pruned
+    scan shares the body and has to give the same keys on the same
+    states."""
     import jax
     import jax.numpy as jnp
     from raft_tla_tpu.ops import fingerprint as fpr
     from raft_tla_tpu.ops import state as st
 
-    def drive(bounds, axes, spec="full", depth=4):
-        lay = st.Layout.of(bounds)
-        consts = fpr.lane_constants(lay.width)
-        # a bag of reachable states: BFS prefix via the interpreter
-        frontier = [interp.init_state(bounds)]
-        seen = list(frontier)
-        for _ in range(depth):
-            nxt = []
-            for s in frontier:
-                nxt += [t for _i, t in interp.successors(s, bounds,
-                                                         spec=spec)]
-            frontier = nxt[:40]
-            seen += frontier
-        vecs = np.stack([interp.to_vec(s, bounds) for s in seen])
-        structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(
-            jnp.asarray(vecs))
-        fn = sym.build_orbit_fp(bounds, axes, jnp.asarray(consts),
-                                "allLogs" in lay.shapes)
-        hi_s, lo_s = jax.jit(fn)(structs)
-        for k, s in enumerate(seen):
-            struct = st.unpack(vecs[k], lay, np)
-            hi_l, lo_l = sym.orbit_fingerprint(struct, bounds, consts,
-                                               np, axes)
-            assert (int(hi_s[k]), int(lo_s[k])) == (int(hi_l), int(lo_l)), \
-                (axes, k, s)
+    bounds, axes, spec, depth, lane_cap, cap = _SCAN_CASES[case]
+    lay = st.Layout.of(bounds)
+    consts = fpr.lane_constants(lay.width)
+    seen = _scan_case_states(bounds, spec, depth, lane_cap, cap)
+    assert len(seen) >= (300 if bounds.n_servers == 5 else 100), len(seen)
+    vecs = np.stack([interp.to_vec(s, bounds) for s in seen])
+    structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(jnp.asarray(vecs))
+    faithful = "allLogs" in lay.shapes
+    fn = sym.build_orbit_fp(bounds, axes, jnp.asarray(consts), faithful)
+    hi_s, lo_s = (np.asarray(a) for a in jax.jit(fn)(structs))
+    pruned = sym.build_orbit_fp(bounds, axes, jnp.asarray(consts), faithful,
+                                prune=True)
+    hi_p, lo_p = (np.asarray(a) for a in jax.jit(pruned)(structs))
+    assert np.array_equal(hi_p, hi_s) and np.array_equal(lo_p, lo_s)
+    for k, s in enumerate(seen):
+        struct = st.unpack(vecs[k], lay, np)
+        hi_l, lo_l = sym.orbit_fingerprint(struct, bounds, consts, np, axes)
+        assert (int(hi_s[k]), int(lo_s[k])) == (int(hi_l), int(lo_l)), \
+            (axes, k, s)
 
-    b = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2)
-    drive(b, ("Server",))
-    drive(b, ("Value",))
-    drive(b, ("Server", "Value"))
-    bh = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2,
-                history=True, max_elections=4)
-    drive(bh, ("Server", "Value"))
+
+def test_scan_body_builds_no_packed_row():
+    """The row must not come back: lowered at 5 servers, the orbit scan
+    holds no ``[lanes, W]`` tensor at all, so no ``concatenate`` (nor
+    ``reshape``) of one in its loop body — PR 27 took it out of the body,
+    where it was written to HBM and read back 120 times a chunk step.
+    The packed form of the same states does show it (the test can see)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from raft_tla_tpu.ops import fingerprint as fpr
+    from raft_tla_tpu.ops import state as st
+
+    lanes = 24
+    for bounds in (_ELECT5, _FULL5):
+        lay = st.Layout.of(bounds)
+        consts = jnp.asarray(fpr.lane_constants(lay.width))
+        struct = {f: jax.ShapeDtypeStruct((lanes,) + tuple(shape), jnp.int32)
+                  for f, shape in lay.shapes.items()}
+        row = re.compile(rf"tensor<{lanes}x{lay.width}xu?i32>")
+        fn = sym.build_orbit_fp(bounds, ("Server",), consts, False)
+        text = jax.jit(fn).lower(struct).as_text()
+        assert "stablehlo.while" in text
+        assert not row.search(text), row.pattern
+        packed = jax.jit(jax.vmap(lambda s: st.pack(s, jnp))) \
+            .lower(struct).as_text()
+        assert re.search(r"stablehlo\.concatenate.*" + row.pattern, packed)
