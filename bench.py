@@ -131,8 +131,6 @@ def run_fiducial() -> None:
 
     # pin the step program: policy changes must not move the fiducial
     os.environ["RAFT_TLA_PRESCAN"] = "off"
-    os.environ["RAFT_TLA_SIGPRUNE"] = "off"
-    os.environ["RAFT_TLA_MEGAKERNEL"] = "off"
     os.environ["RAFT_TLA_HOSTDEDUP"] = "off"
     os.environ["RAFT_TLA_PREFETCH"] = "off"
     os.environ["RAFT_TLA_DEVDEDUP"] = "off"

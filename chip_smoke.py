@@ -475,13 +475,11 @@ import jax, jax.numpy as jnp
 from raft_tla_tpu.config import Bounds
 from raft_tla_tpu.models import interp
 from raft_tla_tpu.ops import fingerprint as fpr
-from raft_tla_tpu.ops import kernels, pallas_fp, pallas_step
+from raft_tla_tpu.ops import pallas_fp
 chunk, interpret = int(sys.argv[1]), sys.argv[2] == "interpret"
 bounds = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2,
                 max_dup=1)
-spec, sym = "full", ("Server",)
-inv = ("NoTwoLeaders", "LogMatching", "CommittedWithinLog",
-       "LeaderCompleteness")
+spec = "full"
 pool, frontier, seen = [], [interp.init_state(bounds)], set()
 for _ in range(2):                      # reachable rows, depth <= 2
     nxt = []
@@ -511,41 +509,23 @@ attempt("pallas_fp",
         lambda: pallas_fp.fingerprint_rows(vecs, interpret=interpret),
         lambda: fpr.fingerprint(vecs, jnp.asarray(
             fpr.lane_constants(vecs.shape[1])), jnp))
-attempt("megakernel",
-        lambda: jax.jit(pallas_step.build_step_megakernel(
-            bounds, spec, inv, sym, interpret=interpret))(vecs),
-        lambda: jax.jit(kernels.build_step(
-            bounds, spec, inv, sym, megakernel=False))(vecs))
 print(json.dumps(out))
 """
 
 
-def phase_kernels(ctx: Ctx, chunk: int = 4096, interpret: bool = False,
-                  flags=FLAGSHIP_BOUNDS, cfg: str = FLAGSHIP_CFG) -> dict:
-    """Do the two Pallas kernels compile for this chip?  Mosaic taking a
-    kernel obliges it to be bit-equal to its XLA twin on one flagship-
-    shaped chunk; Mosaic refusing the megakernel obliges ``--megakernel
-    on`` to fail loudly with the compiler's message instead of running
-    something else.  Either verdict passes and is printed."""
+def phase_kernels(ctx: Ctx, chunk: int = 4096,
+                  interpret: bool = False) -> dict:
+    """Does the Pallas fingerprint kernel compile for this chip?  Mosaic
+    taking it obliges it to be bit-equal to its XLA twin on one flagship-
+    shaped chunk.  Either verdict passes and is printed."""
     got = run_snippet(ctx, "kernels", _KERNELS,
                       [str(chunk), "interpret" if interpret else "mosaic"],
                       timeout=900)
     need(got["device"]["platform"] == ctx.platform,
          f"kernels: ran on {got['device']['platform']!r}")
-    for k in ("pallas_fp", "megakernel"):
-        if got[k]["mosaic_ok"]:
-            need(got[k]["bit_equal"], f"{k}: Mosaic build is not bit-equal "
-                                      "to its XLA twin")
-    if not got["megakernel"]["mosaic_ok"]:
-        d = run_check(ctx, "megakernel-on", cfg,
-                      ["--engine", "ddd", "--chunk", str(chunk), *flags,
-                       "--megakernel", "on", "--deadline", "5",
-                       "--no-trace"])
-        errs = [ln for ln in d.err.splitlines() if ln.startswith("Error:")]
-        need(d.rc == 1 and errs,
-             f"--megakernel on: exit {d.rc} with no Error line although "
-             f"Mosaic refused the kernel: {tail(d.out + d.err)}")
-        got["megakernel"]["cli_error"] = errs[-1][:300]
+    if got["pallas_fp"]["mosaic_ok"]:
+        need(got["pallas_fp"]["bit_equal"],
+             "pallas_fp: Mosaic build is not bit-equal to its XLA twin")
     return got
 
 

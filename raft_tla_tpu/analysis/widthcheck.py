@@ -479,46 +479,6 @@ def check_transfer_coverage(bounds: Bounds, spec: str,
     return findings
 
 
-def check_fused_coverage(bounds: Bounds, spec: str) -> list:
-    """Cross-check the megakernel's whole-step write surface
-    (``ops/pallas_step.FUSED_WRITES`` — hand-maintained, like the
-    per-family twins) against the union of the spec subset's per-family
-    kernel declarations plus the expansion postlude.  The fused kernel
-    evaluates the same staged program as the XLA step, so its write
-    surface must be EXACTLY that union: a family growing a new write, a
-    subset gaining a family, or the fused table going stale all surface
-    here as loud drift — the width-safety proof keeps covering the hot
-    path whichever step build the gate selects."""
-    from raft_tla_tpu.ops import kernels, pallas_step
-    findings = []
-    if spec not in pallas_step.FUSED_WRITES:
-        findings.append(Finding(
-            WIDTH, ERROR, "fused-missing",
-            f"spec subset {spec!r} has no megakernel write-surface entry "
-            "(ops/pallas_step.FUSED_WRITES)", transition=spec))
-        return findings
-    mode = set(_mode_fields(bounds))
-    fams = {a.family for a in SP.action_table(bounds, spec)}
-    union = set(kernels.POSTLUDE_WRITES)
-    for fam in fams:
-        union |= set(kernels.TRANSFER_WRITES.get(fam, ()))
-    declared = set(pallas_step.FUSED_WRITES[spec]) & mode
-    modeled = union & mode
-    for f in sorted(declared - modeled):
-        findings.append(Finding(
-            WIDTH, ERROR, "fused-drift",
-            f"megakernel write surface for {spec!r} declares {f}, which "
-            "no per-family transfer twin proves", transition=spec,
-            field=f))
-    for f in sorted(modeled - declared):
-        findings.append(Finding(
-            WIDTH, ERROR, "fused-drift",
-            f"family kernels of {spec!r} can write {f} but the megakernel "
-            "write surface (ops/pallas_step.FUSED_WRITES) omits it",
-            transition=spec, field=f))
-    return findings
-
-
 def check_widths(bounds: Bounds, spec: str = "full", *,
                  field_bits_table=None, hi_fields=None, lo_fields=None,
                  transfers=None, expansion_env=None,
@@ -595,7 +555,6 @@ def check_widths(bounds: Bounds, spec: str = "full", *,
 
     if coverage_check:
         findings += check_transfer_coverage(bounds, spec, transfers)
-        findings += check_fused_coverage(bounds, spec)
     return findings
 
 

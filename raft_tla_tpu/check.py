@@ -215,30 +215,6 @@ def build_argparser() -> argparse.ArgumentParser:
                         "process-wide so every engine inherits one "
                         "decision; default: leave the env/auto policy "
                         "alone")
-    p.add_argument("--sig-prune", default=None,
-                   choices=("auto", "on", "off"),
-                   help="signature-refinement orbit-scan pruning: scan one "
-                        "permutation per coset of the verified per-state "
-                        "stabilizer instead of the whole group (bit-"
-                        "identical keys; ops/symmetry.py has the soundness "
-                        "argument). Sets RAFT_TLA_SIGPRUNE process-wide so "
-                        "every engine inherits one decision; default: "
-                        "leave the env/auto policy alone (auto is "
-                        "currently OFF — 0.94x in-engine on the CPU, "
-                        "not measured on the chip)")
-    p.add_argument("--megakernel", default=None,
-                   choices=("auto", "on", "off"),
-                   help="Pallas megakernel build of the fused step: the "
-                        "whole expand/canonicalize/orbit/filter pipeline "
-                        "in ONE kernel with candidates VMEM-resident "
-                        "across stages (ops/pallas_step.py; bit-identical "
-                        "lane for lane). Sets RAFT_TLA_MEGAKERNEL "
-                        "process-wide so every engine inherits one "
-                        "decision; default: leave the env/auto policy "
-                        "alone (auto is currently OFF — 0.82x in-engine "
-                        "on the CPU under the Pallas interpreter; on the "
-                        "TPU Mosaic refuses the kernel's gathers, so 'on' "
-                        "exits with the compiler's message)")
     p.add_argument("--host-dedup", default=None,
                    choices=("auto", "on", "off"),
                    help="partitioned + background host dedup for the ddd "
@@ -649,18 +625,8 @@ def main(argv=None) -> int:
         # re-runs build engines of their own.
         import os
         os.environ["RAFT_TLA_PRESCAN"] = args.prescan
-    if args.sig_prune is not None:
-        # Same contract as --prescan: resolved at step-construction time
-        # (ops/kernels._sigprune_enabled) by every engine family.
-        import os
-        os.environ["RAFT_TLA_SIGPRUNE"] = args.sig_prune
-    if args.megakernel is not None:
-        # Same contract as --sig-prune: resolved at step-construction
-        # time (ops/kernels._megakernel_enabled) by every engine family.
-        import os
-        os.environ["RAFT_TLA_MEGAKERNEL"] = args.megakernel
     if args.host_dedup is not None:
-        # Same contract: resolved at engine construction
+        # Same contract as --prescan: resolved at engine construction
         # (utils/keyset.host_dedup_enabled) by the ddd engine families.
         import os
         os.environ["RAFT_TLA_HOSTDEDUP"] = args.host_dedup
@@ -690,11 +656,6 @@ def main(argv=None) -> int:
                 "the routed step is not built for other engines — "
                 "dropping it silently would run a different program "
                 "than configured")
-    if args.route and args.megakernel == "on":
-        p.error("--megakernel on does not compose with --route (the "
-                "routed step's lane compaction is an XLA scatter between "
-                "the megakernel's fused phases); use --route 0 or leave "
-                "the megakernel gate auto/off")
     if (args.checkpoint or args.resume) and \
             args.engine not in _DEVICE_ENGINES:
         p.error(f"--checkpoint/--resume require a device-class engine "
@@ -718,7 +679,7 @@ def main(argv=None) -> int:
                 "run-event log; without a log there is nowhere to put "
                 "them)")
     if args.events or args.phase_timers or args.trace:
-        # Process-wide, like --sig-prune: every engine an invocation
+        # Process-wide, like --prescan: every engine an invocation
         # builds (including liveness re-runs) reads the same env gate.
         import os
         from raft_tla_tpu.obs.events import ENV_EVENTS

@@ -792,10 +792,9 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             f"seg_rows={OCAP} must be >= per-chunk candidate rows = {NK}")
     SLAB, SLACK = _slab_plan(NK)
     n_inv = len(config.invariants)
-    # Both step flavors share _step_stages, so the orbit-scan variants
-    # (prescan ladder, sig-prune coset scan) resolve from their env
-    # gates here at build time — set RAFT_TLA_SIGPRUNE before
-    # constructing the engine; keys are bit-identical either way.
+    # Both step flavors share _step_stages; the dense one resolves the
+    # prescan ladder (kernels._prescan_enabled) here at build time —
+    # keys are bit-identical either way.
     if routed:
         step = kernels.build_step_routed(
             config.bounds, config.spec, tuple(config.invariants),
@@ -1035,7 +1034,7 @@ class DDDEngine:
         self.schema = bitpack.BitSchema(self.bounds)
         # RAFT_TLA_HOSTDEDUP gate: partitioned master keys + background
         # flush worker.  Resolved once at construction (like the
-        # sig-prune/megakernel gates) and deliberately NOT part of
+        # prescan gate) and deliberately NOT part of
         # _DigestCaps — checkpoints are compatible both directions.
         self._host_dedup = keyset.host_dedup_enabled()
         # RAFT_TLA_PREFETCH gate: double-buffered background staging of

@@ -323,9 +323,9 @@ def _build_segment(config: CheckConfig, caps: Capacities, A: int, W: int):
     """
     B = config.chunk
     n_inv = len(config.invariants)
-    # Orbit-scan variants (prescan ladder, sig-prune) are resolved inside
-    # build_step at CONSTRUCTION time from their env gates — set
-    # RAFT_TLA_SIGPRUNE/RAFT_TLA_PRESCAN before building the engine.
+    # The prescan ladder is resolved inside build_step at CONSTRUCTION
+    # time (kernels._prescan_enabled) — set RAFT_TLA_PRESCAN before
+    # building the engine to override it.
     step = kernels.build_step(config.bounds, config.spec,
                               tuple(config.invariants), config.symmetry,
                               view=config.view)

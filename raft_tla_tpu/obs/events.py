@@ -193,7 +193,7 @@ SCHEMA_VERSION = 12
 _VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12)  # versions validate_event accepts
 
 # Environment knobs (set by check.py --events/--phase-timers; inherited by
-# liveness re-runs and bench children the same way RAFT_TLA_SIGPRUNE is).
+# liveness re-runs and bench children the same way RAFT_TLA_PRESCAN is).
 ENV_EVENTS = "RAFT_TLA_EVENTS"
 
 

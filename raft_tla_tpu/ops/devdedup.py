@@ -53,11 +53,11 @@ backend keep-decisions stay equivalent.
 The set is **within-level** by construction: the engine resets it at
 every level boundary (and resume starts it empty — mid-level resumes
 just re-stream, which the master dedups).  The gate is resolved once at
-engine construction like sig-prune/hostdedup/prefetch and is
+engine construction like prescan/hostdedup/prefetch and is
 deliberately NOT part of the checkpoint digest: snapshots resume across
 either gate setting in both directions.
 
-Auto policy: measured by ``runs/devdedup_ab.py`` per the sig-prune /
+Auto policy: measured by ``runs/devdedup_ab.py`` per the
 hostdedup protocol (bracketing fiducials, interleaved reps, per-level
 export-row parity) — see ``_auto_backend`` below and RESULTS.md
 "Device dedup A/B".
@@ -92,7 +92,7 @@ def _auto_backend() -> str | None:
     held at all 74 parity segments — but only ~0.1% of rows at the
     flagship shape, whose 2^22-slot filter leaks few within-level
     re-sights) cost 0.43-0.44x warm rate instead of buying wall time —
-    the sig-prune precedent, honest refutation -> auto=OFF, with the
+    an honest refutation -> auto=OFF, with the
     on-chip re-A/B queued under ROADMAP item 2 (PCIe d2h is where the
     dropped rows are real bandwidth, and the eviction-heavy elect5
     capacity regime is where the duplicate rate is not 0.1%)."""

@@ -614,13 +614,9 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     # Scan-compiled orbit pass: ONE copy of the permute/canonicalize/
     # fingerprint pipeline iterated over the n!*V! group, not n!*V!
     # unrolled copies (ops/symmetry.build_orbit_fp) — bit-identical keys.
-    # The sig-prune gate selects the coset-pruned variant of the SAME
-    # scan (still bit-identical; ops/symmetry._SIGPRUNE_RUNGS comment);
-    # every engine's step builder flows through here, so one gate covers
-    # ddd/device/streamed and the parallel shard family alike.
+    # Every engine's step builder flows through here.
     orbit_fp = sym.build_orbit_fp(bounds, symmetry, consts,
-                                  "allLogs" in lay.shapes,
-                                  prune=_sigprune_enabled(bounds, symmetry)) \
+                                  "allLogs" in lay.shapes) \
         if symmetry else None
     # The lax.scan orbit pass above is the PERMANENT design (VERDICT r3
     # next #9, decided round 4): a VMEM-resident Pallas orbit kernel was
@@ -639,10 +635,6 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     # a reduce read it back once a permutation (PERF.md, PR 27; what
     # stands is in its section 7).  Mosaic findings: git show
     # f293573:RESULTS.md "Pallas orbit kernel", runs/pallas_orbit_p24.out.
-    # (Distinct bet, different scope: the WHOLE-step Pallas megakernel,
-    # ops/pallas_step.py, stages this very program into one kernel to
-    # eliminate the HBM round-trips BETWEEN the stage fusions — gated
-    # RAFT_TLA_MEGAKERNEL, auto=OFF; see _megakernel_enabled.)
     # The view folds into the DEDUP KEY only: stored rows, invariants and
     # the constraint all see the full successor (TLC VIEW semantics).
     viewer = None
@@ -654,7 +646,7 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
 
 def build_step(bounds: Bounds, spec: str = "full", invariants: tuple = (),
                symmetry: tuple = (), view: str | None = None,
-               megakernel: bool | None = None, family_kernels=None):
+               family_kernels=None):
     """One fused frontier step: packed vecs -> everything the engine needs.
 
     ``step(vecs[B, W]) -> dict`` with packed successors ``svecs [B, A, W]``,
@@ -669,29 +661,10 @@ def build_step(bounds: Bounds, spec: str = "full", invariants: tuple = (),
     (ops/symmetry.py) — the dedup key that quotients the state space the
     way TLC's SYMMETRY stanza does.
 
-    ``megakernel`` selects the Pallas megakernel build of the SAME step
-    (ops/pallas_step.py: one kernel, candidates VMEM-resident across all
-    stages, bit-identical lane for lane); ``None`` defers to the
-    ``RAFT_TLA_MEGAKERNEL`` gate (:func:`_megakernel_enabled`) so every
-    engine family inherits one process-wide decision at construction
-    time, exactly like sig-prune.  The compile signature — everything
-    this builder specializes on, gates included — is
-    :func:`step_signature`; keep serving-side bin keys on that helper.
+    The compile signature — everything this builder specializes on,
+    the prescan resolution included — is :func:`step_signature`; keep
+    serving-side bin keys on that helper.
     """
-    if megakernel is None:
-        megakernel = _megakernel_enabled(bounds, symmetry)
-    if megakernel:
-        if family_kernels is not None:
-            # The megakernel stages the HAND kernel bodies; an IR kernel
-            # table has no fused build.  Refuse loudly rather than
-            # silently dropping the override.
-            raise ValueError(
-                "RAFT_TLA_MEGAKERNEL=on does not compose with a "
-                "family_kernels override (IR-compiled specs); leave the "
-                "megakernel gate auto/off")
-        from raft_tla_tpu.ops import pallas_step
-        return pallas_step.build_step_megakernel(
-            bounds, spec, invariants, symmetry, view)
     stages = _step_stages(bounds, spec, invariants, symmetry, view,
                           family_kernels=family_kernels)
     lay = stages[0]
@@ -757,78 +730,11 @@ def _prescan_enabled(bounds, symmetry):
     return g >= 120
 
 
-def _sigprune_enabled(bounds, symmetry):
-    """Platform/shape gate for signature-refinement orbit pruning
-    (ops/symmetry.build_orbit_fp ``prune=``; the _SIGPRUNE_RUNGS comment
-    has the soundness argument).  Env override ``RAFT_TLA_SIGPRUNE``
-    {auto, on, off} mirrors RAFT_TLA_PRESCAN; ``check.py --sig-prune``
-    sets it process-wide so every engine inherits one decision.
-
-    Auto policy: OFF.  Measured (runs/sigprune_ab.py, sync-timed
-    medians on reachable chunks; runs/bench_sigprune_inengine_ab.out):
-    the kept scan only shortens when EVERY state in the chunk has a
-    non-trivial verified stabilizer, and reachable mid-depth chunks are
-    dominated by fully-asymmetric states (avg orbit size ~= |G| — the
-    flagship's 94.4M orbits over ~6x raw states), so the probe overhead
-    buys no rung and the A/B lands at loss-to-parity on CPU: mid-depth
-    0.80-0.98x, shallow 0.74-1.02x, in-engine exhaustive 0.94x — the
-    best case (|G|=120 shallow) only reaches parity, so even the
-    symmetric-rich regime does not pay here.  The pruned path stays
-    available via the override for on-chip
-    re-measurement (the probe/min-scan trade is bandwidth-vs-flops and
-    may invert on the VPU); composition with the prescan ladder is free
-    because the prescan calls orbit_fp on its compacted rows."""
-    if not symmetry:
-        return False
-    import os
-    force = os.environ.get("RAFT_TLA_SIGPRUNE", "auto")
-    if force == "on":            # measurement override (runs/sigprune_ab,
-        return True              # in-engine bench A/B) and symmetric-rich
-    if force == "off":           # workloads — not the default
-        return False
-    return False
-
-
-def _megakernel_enabled(bounds, symmetry):
-    """Platform gate for the Pallas megakernel build of the fused step
-    (ops/pallas_step.py: the whole expand->canonicalize->orbit->filter
-    pipeline in ONE kernel, candidates VMEM-resident across stages).
-    Env override ``RAFT_TLA_MEGAKERNEL`` {auto, on, off} mirrors
-    RAFT_TLA_SIGPRUNE; ``check.py --megakernel`` sets it process-wide so
-    every engine inherits one decision at step-construction time.
-
-    Auto policy: OFF.  Measured on CPU (runs/megakernel_ab.py: sync-timed
-    per-chunk medians, in-engine northstar probe with per-phase
-    attribution, chip-state fiducials bracketing): in-engine the gate-on
-    arm is a 0.82x warm-rate LOSS (7,384 vs 9,006 orbits/s; the whole
-    delta is the expand phase, 135.4 s vs 112.6 s) even though the
-    block-sliced program wins 2-5% on pinned-gate step timings — under
-    the production auto policy the prescan ladder makes the XLA step
-    >2x faster, and the staged ladder is BLOCK-LOCAL (its signature
-    grouping sees one 128-row block instead of the whole chunk), so the
-    blocking that helps the pinned program costs the production one
-    (RESULTS.md "Megakernel A/B" attributes the loss entirely to the
-    expand phase).  On the chip there is nothing to A/B yet: Mosaic
-    refuses the staged step (TPU v5 lite, JAX 0.9.0, chip_smoke.py
-    kernels phase, PR 21) — ``ValueError: Shape mismatch in input,
-    indices and output`` from its gather lowering rule, which takes only
-    take_along_axis-shaped 2-D gathers (ops/pallas_step.py "Mosaic
-    status").  ``on`` there fails loudly at step construction with that
-    message; it never falls back to the XLA step."""
-    import os
-    force = os.environ.get("RAFT_TLA_MEGAKERNEL", "auto")
-    if force == "on":            # measurement override (runs/megakernel_ab,
-        return True              # interpreter only) — not the default
-    if force == "off":
-        return False
-    return False
-
-
 def step_signature(bounds, spec, invariants, symmetry, view):
     """Everything :func:`build_step` specializes the compiled step on —
     universe bounds, spec subset, invariant set, symmetry axes, the
     dedup-key view, and the construction-time gate resolutions
-    (megakernel / prescan / sig-prune).  THE definition of step-compile
+    (prescan / devdedup).  THE definition of step-compile
     identity: serve/batch.bin_key delegates here, so two jobs share a
     lane-packed bin (and a compile) iff this tuple matches — bins can
     never mix step variants when a gate flips between admissions.
@@ -839,9 +745,7 @@ def step_signature(bounds, spec, invariants, symmetry, view):
     # this module — a top-level import would cycle
     from raft_tla_tpu.ops import devdedup
     return (bounds, spec, tuple(invariants), tuple(symmetry), view,
-            ("megakernel", _megakernel_enabled(bounds, symmetry)),
             ("prescan", _prescan_enabled(bounds, symmetry)),
-            ("sigprune", _sigprune_enabled(bounds, symmetry)),
             ("devdedup", devdedup.devdedup_backend()))
 
 
@@ -950,7 +854,7 @@ def apply_stages(bounds, stages, symmetry, succs, svecs, valid):
 def build_step_routed(bounds: Bounds, spec: str = "full",
                       invariants: tuple = (), symmetry: tuple = (),
                       k_rows: int = 0, view: str | None = None,
-                      megakernel: bool | None = None, family_kernels=None):
+                      family_kernels=None):
     """EP-style routed frontier step (SURVEY §2.9, EP row): compact the
     enabled lanes, then run the expensive per-candidate stages densely.
 
@@ -990,18 +894,6 @@ def build_step_routed(bounds: Bounds, spec: str = "full",
     default.  Correct for parity AND faithful mode (the expansion twin
     carries the allLogs update; history fields ride the same gather).
     """
-    if megakernel is None:
-        megakernel = _megakernel_enabled(bounds, symmetry)
-    if megakernel:
-        # The routed step's stable-order compaction is an XLA scatter
-        # BETWEEN the expand and stage phases — there is no fused-kernel
-        # build of it.  Refusing loudly at construction beats silently
-        # ignoring the gate (check.py rejects --megakernel on + --route
-        # up front; direct env users land here).
-        raise ValueError(
-            "RAFT_TLA_MEGAKERNEL=on does not compose with the EP-routed "
-            "step (build_step_routed); use the dense step (--route 0) or "
-            "leave the megakernel gate auto/off")
     (lay, consts, expand, inv_fns, orbit_fp,
      viewer) = _step_stages(bounds, spec, invariants, symmetry, view,
                             family_kernels=family_kernels)

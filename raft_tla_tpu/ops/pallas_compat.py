@@ -1,13 +1,8 @@
-"""Shared platform probe + execution-mode switch for the Pallas kernels.
-
-Both hand-scheduled kernels in the tree (the standalone fingerprint,
-ops/pallas_fp.py, and the fused-step megakernel, ops/pallas_step.py)
-need the same decision made the same way: compile for Mosaic when a TPU
-backend is present, run the kernel under the Pallas interpreter when the
-caller is testing on CPU, and — where a bit-identical jnp twin exists —
-fall back to it off-TPU rather than paying interpreter overhead in
-production paths.  One definition site so the two kernels can never
-disagree about what "off-TPU" means.
+"""Platform probe + execution-mode switch for the Pallas fingerprint
+kernel (ops/pallas_fp.py): compile for Mosaic when a TPU backend is
+present, run the kernel under the Pallas interpreter when the caller is
+testing on CPU, and otherwise fall back to the bit-identical jnp twin
+rather than paying interpreter overhead in production paths.
 
 Modes (returned by :func:`resolve`):
 
@@ -15,10 +10,9 @@ Modes (returned by :func:`resolve`):
 - ``INTERPRET`` — ``pallas_call(interpret=True)``: the kernel body runs
   as ordinary traced JAX under the grid emulator.  Bit-identical to the
   Mosaic build by Pallas's contract; this is how every CPU parity test
-  executes the kernels.
-- ``JNP``       — skip Pallas entirely and use the caller's portable
-  jnp twin (only offered when the caller HAS one; the megakernel's twin
-  is the XLA step itself, selected a level above by the gate).
+  executes the kernel.
+- ``JNP``       — skip Pallas entirely and use the portable jnp twin
+  (``ops.fingerprint.fingerprint``).
 """
 
 from __future__ import annotations
@@ -35,19 +29,18 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def resolve(interpret: bool | None, *, jnp_fallback: bool) -> str:
+def resolve(interpret: bool | None) -> str:
     """Pick the execution mode for a Pallas kernel call.
 
     ``interpret=True`` forces the interpreter (CPU tests assert parity
     through this path); ``interpret=False`` forces a real Mosaic build
     (loud failure off-TPU beats silently testing nothing); ``None``
-    means auto: Mosaic on TPU, otherwise the jnp twin when the caller
-    has one (``jnp_fallback=True``), else the interpreter.
+    means auto: Mosaic on TPU, otherwise the jnp twin.
     """
     if interpret:
         return INTERPRET
     if on_tpu():
         return MOSAIC
     if interpret is None:
-        return JNP if jnp_fallback else INTERPRET
+        return JNP
     return MOSAIC                    # interpret=False off-TPU: fail loudly

@@ -127,7 +127,8 @@ def fingerprint_rows(vecs, interpret: bool | None = None):
     """
     vecs = jnp.asarray(vecs, jnp.int32)
     B, W = vecs.shape
-    if pc.resolve(interpret, jnp_fallback=True) == pc.JNP:
+    mode = pc.resolve(interpret)
+    if mode == pc.JNP:
         # the portable jnp path (XLA-fused; bit-identical by construction)
         return fpr.fingerprint(vecs, jnp.asarray(fpr.lane_constants(W)),
                                jnp)
@@ -135,7 +136,5 @@ def fingerprint_rows(vecs, interpret: bool | None = None):
     Bp = ((B + _BLOCK_ROWS - 1) // _BLOCK_ROWS) * _BLOCK_ROWS
     vp = jnp.zeros((Bp, Wp), jnp.int32).at[:B, :W].set(vecs)
     c1, c2 = _padded_constants(W, Wp)
-    hi, lo = _fp_call(vp, c1, c2,
-                      interpret=pc.resolve(interpret,
-                                           jnp_fallback=True) == pc.INTERPRET)
+    hi, lo = _fp_call(vp, c1, c2, interpret=mode == pc.INTERPRET)
     return hi[:B].astype(jnp.uint32), lo[:B].astype(jnp.uint32)

@@ -259,212 +259,6 @@ def _value_luts(bounds: Bounds, faithful: bool) -> dict:
     return out
 
 
-# -- signature-refinement pruning (sig-prune) --------------------------------
-#
-# The orbit scan pays |G| = n!*V! pipeline iterations per state even when
-# the state has symmetry left over — e.g. two followers with equal terms,
-# logs and relations, which every checker's initial states and election
-# churn produce in bulk.  For such states many group elements map the
-# state to the SAME orbit member, and recomputing a duplicate member's
-# fingerprint cannot change the min.  Sig-prune removes exactly those
-# provable duplicates and nothing else, so the min — the dedup key every
-# checkpoint and parity guarantee rests on — is bit-identical:
-#
-# 1. **Exact interchangeability classes.**  Servers a, b are
-#    interchangeable iff the transposition (a b) maps the state to itself
-#    (compared as packed canonical rows — exact equality, no hashing).
-#    Stab(s) is a group, so the relation is transitive and partitions the
-#    servers; the generated subgroup H = ∏ Sym(class) stabilizes s.
-# 2. **Coset representatives.**  π and π∘σ produce the same permuted
-#    state for σ ∈ H, so the scan only needs one element per left coset
-#    πH: keep π iff π is increasing on every class (exactly one member
-#    per coset satisfies this).  Every distinct orbit member is still
-#    scanned — the pruned min is the full min, bit for bit.  Value
-#    permutations factor the same way; kept(π, q) = kept_s(π) & kept_v(q).
-# 3. **Signature prefilter.**  A cheap per-server invariant signature
-#    (role, term class, log-content hash, votedFor class, vote popcounts)
-#    is a NECESSARY condition for interchangeability, so a chunk whose
-#    states nowhere repeat a signature skips the exact transposition
-#    probes wholesale (lax.cond, jit-stable shapes).
-# 4. **Static-slot cond ladder.**  The kept count is data-dependent; like
-#    the prescan rungs, the kept scan runs at the smallest static slot
-#    count |G|/d (d in _SIGPRUNE_RUNGS) that fits the chunk's max kept
-#    count, falling back to the unpruned scan (shared-LUT body) when any
-#    state in the chunk keeps the whole group.  Pad slots re-scan the
-#    identity element (always kept) — a real orbit member, harmless to
-#    the min.
-#
-# Note the one-sided failure mode this construction rules out: pruning by
-# signature classes ALONE (keep only partition-preserving permutations)
-# is unsound — for a state with all-distinct signatures it would scan
-# only the identity and miss every other orbit member.  The exact probe
-# step is what makes the mask a duplicate-eliminator instead of an
-# orbit-truncator; tests/test_sigprune.py asserts both directions.
-_SIGPRUNE_RUNGS = (8, 4, 2)      # divisors of |G|, tried smallest-slot-first
-
-
-@functools.lru_cache(maxsize=None)
-def _transposition_pairs(bounds: Bounds) -> tuple:
-    """Static probe table: ``(a, b, perm_index)`` for every server pair
-    a < b, where ``perm_index`` locates the transposition (a b) in
-    :func:`permutations` order."""
-    ps = permutations(bounds)
-    n = bounds.n_servers
-    out = []
-    for a in range(n):
-        for b in range(a + 1, n):
-            t = list(range(n))
-            t[a], t[b] = b, a
-            out.append((a, b, ps.index(tuple(t))))
-    return tuple(out)
-
-
-@functools.lru_cache(maxsize=None)
-def _value_transposition_pairs(bounds: Bounds) -> tuple:
-    """Value-axis analog of :func:`_transposition_pairs`."""
-    qs = value_permutations(bounds)
-    V = bounds.n_values
-    out = []
-    for a in range(V):
-        for b in range(a + 1, V):
-            t = list(range(V))
-            t[a], t[b] = b, a
-            out.append((a, b, qs.index(tuple(t))))
-    return tuple(out)
-
-
-def _pair_less_lut(perms: tuple, pairs: tuple) -> np.ndarray:
-    """bool[P, n_pairs]: permutation p is increasing on pair (a, b),
-    i.e. ``p[a] < p[b]`` — the coset-representative condition per pair."""
-    arr = np.asarray(perms, np.int32)
-    return np.stack([arr[:, a] < arr[:, b] for (a, b, _) in pairs], axis=1)
-
-
-def _server_sig(struct: dict, xp):
-    """Cheap per-server invariant signature ``[..., n] uint32``.
-
-    Equal signatures are NECESSARY for two servers to be exactly
-    interchangeable (every hashed field moves with its server under a
-    transposition; popcounts and the votedFor nil/self/other class are
-    renaming-invariant), so distinct signatures let the sig-prune path
-    skip the exact probe for that pair chunk-wide.  Not sufficient —
-    relational fields (nextIndex columns, vote bit positions, message
-    endpoints) are deliberately out; the exact probe certifies those."""
-    u = xp.uint32
-
-    def mix(h, x):
-        return (h ^ x.astype(xp.uint32)) * u(0x9E3779B1)
-
-    def popcount(x):
-        x = x - ((x >> 1) & 0x55555555)
-        x = (x & 0x33333333) + ((x >> 2) & 0x33333333)
-        x = (x + (x >> 4)) & 0x0F0F0F0F
-        return (x * 0x01010101) >> 24
-
-    n = struct["role"].shape[-1]
-    vf = struct["votedFor"]
-    self_id = xp.arange(n) + 1
-    vf_cls = xp.where(vf == 0, 0, xp.where(vf == self_id, 1, 2))
-    h = xp.zeros_like(struct["role"]).astype(xp.uint32) + u(0x811C9DC5)
-    for f in ("role", "term", "commitIndex", "logLen"):
-        h = mix(h, struct[f])
-    h = mix(h, vf_cls)
-    h = mix(h, popcount(struct["vResp"]))
-    h = mix(h, popcount(struct["vGrant"]))
-    lt, lv = struct["logTerm"], struct["logVal"]
-    for c in range(lt.shape[-1]):
-        h = mix(h, lt[..., c])
-        h = mix(h, lv[..., c])
-    return h
-
-
-def _permute_struct_traced(struct: dict, inv, vf_map, bit_lut, p_lut, xp):
-    """``permute_struct`` for ONE state with the permutation as traced LUT
-    rows — the sig-prune kept scan vmaps this over per-state permutation
-    indices (each state walks its own kept-coset list, so the LUT row
-    varies along the batch axis)."""
-    def rows(a):
-        return xp.take(a, inv, axis=0)
-
-    s_sh, s_w = mb._HI_FIELDS["src"]
-    d_sh, d_w = mb._HI_FIELDS["dst"]
-    keep = ~(((1 << s_w) - 1) << s_sh | ((1 << d_w) - 1) << d_sh)
-    hi = struct["msgHi"]
-    occupied = struct["msgCount"] > 0
-    new_hi = (hi & keep) \
-        | (p_lut[(hi >> s_sh) & ((1 << s_w) - 1)] << s_sh) \
-        | (p_lut[(hi >> d_sh) & ((1 << d_w) - 1)] << d_sh)
-    new_hi = xp.where(occupied, new_hi, hi)
-
-    out = {
-        "role": rows(struct["role"]),
-        "term": rows(struct["term"]),
-        "votedFor": vf_map[rows(struct["votedFor"])],
-        "commitIndex": rows(struct["commitIndex"]),
-        "logLen": rows(struct["logLen"]),
-        "logTerm": rows(struct["logTerm"]),
-        "logVal": rows(struct["logVal"]),
-        "vResp": bit_lut[rows(struct["vResp"])],
-        "vGrant": bit_lut[rows(struct["vGrant"])],
-        "nextIndex": xp.take(rows(struct["nextIndex"]), inv, axis=1),
-        "matchIndex": xp.take(rows(struct["matchIndex"]), inv, axis=1),
-        "msgHi": new_hi,
-        "msgLo": struct["msgLo"],
-        "msgCount": struct["msgCount"],
-    }
-    if "eTerm" in struct:
-        eocc = struct["eTerm"] > 0
-        out.update({
-            "allLogs": struct["allLogs"],
-            "vLog": xp.take(rows(struct["vLog"]), inv, axis=1),
-            "eTerm": struct["eTerm"],
-            "eLeader": xp.where(eocc, p_lut[struct["eLeader"]],
-                                struct["eLeader"]),
-            "eLog": struct["eLog"],
-            "eVotes": xp.where(eocc, bit_lut[struct["eVotes"]],
-                               struct["eVotes"]),
-            "eVLog": xp.take(struct["eVLog"], inv, axis=1),
-        })
-    return out
-
-
-def _permute_values_traced(struct: dict, luts: dict, bounds: Bounds, xp):
-    """``permute_values`` for ONE state with the value permutation as
-    traced LUT rows (sig-prune kept scan; see _permute_struct_traced)."""
-    vlut = luts["vlut"]
-    e_lut = luts["e_lut"]
-    e_sh, e_w = mb._LO_FIELDS["e"]
-    lo = struct["msgLo"]
-    out = dict(struct)
-    out["logVal"] = vlut[struct["logVal"]]
-    new_lo = (lo & ~(((1 << e_w) - 1) << e_sh)) \
-        | (e_lut[(lo >> e_sh) & ((1 << e_w) - 1)] << e_sh)
-    if "allLogs" in struct:
-        rmap = luts["rmap"]
-        rlut1 = luts["rlut1"]
-        g_lut = luts["g_lut"]
-        U = int(rmap.shape[0])
-        out["vLog"] = rlut1[struct["vLog"]]
-        out["eLog"] = rmap[struct["eLog"]]
-        out["eVLog"] = rlut1[struct["eVLog"]]
-        g_sh, g_w = mb._LO_FIELDS["g"]
-        new_lo = (new_lo & ~(((1 << g_w) - 1) << g_sh)) \
-            | (g_lut[(new_lo >> g_sh) & ((1 << g_w) - 1)] << g_sh)
-        rs = xp.arange(U)
-        bits = ((struct["allLogs"][rs // 32] >> (rs % 32)) & 1)
-        Wa = struct["allLogs"].shape[0]
-        in_word = (rmap[None, :] // 32) == xp.arange(Wa)[:, None]  # [Wa, U]
-        tb = rmap[None, :] % 32
-        low = xp.where(in_word & (tb < 31) & (bits[None, :] > 0),
-                       xp.asarray(1, xp.int32) << tb, 0).sum(axis=1)
-        top = (in_word & (tb == 31) & (bits[None, :] > 0)).any(axis=1)
-        out["allLogs"] = (low.astype(xp.int32)
-                          | xp.where(top, xp.asarray(-2**31, xp.int32), 0))
-    occupied = struct["msgCount"] > 0
-    out["msgLo"] = xp.where(occupied, new_lo, struct["msgLo"])
-    return out
-
-
 def _permute_struct_batch(struct: dict, inv, vf_map, bit_lut, p_lut, xp):
     """``permute_struct`` over a leading batch axis, with the permutation
     given as traced LUT rows (same arithmetic, same bits — the gathers
@@ -554,8 +348,7 @@ def _permute_values_batch(struct: dict, luts: dict, qi, bounds: Bounds, xp):
     return out
 
 
-def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
-                   prune: bool = False):
+def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     """Batched orbit-minimal fingerprints: ``struct[N, ...] -> (hi, lo)[N]``.
 
     Bit-identical to :func:`orbit_fingerprint` (same permute/canonicalize
@@ -568,13 +361,6 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
     servers (120 copies) crashed compiles at chunk 2048 and capped the
     elect5 run at ~3k orbits/s; the scan keeps the program size constant
     in |G| so large chunks compile and the VPU sees one tight loop.
-
-    With ``prune=True`` the scan runs the signature-refinement pruned
-    path (see the _SIGPRUNE_RUNGS comment): exact interchangeability
-    classes from transposition probes, then a min over one permutation
-    per stabilizer coset — still bit-identical, by construction, because
-    only provable duplicate orbit members are skipped.  Gated at the
-    call sites (ops/kernels._sigprune_enabled); default off.
     """
     import jax
     import jax.numpy as jnp
@@ -588,8 +374,8 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
     Q = len(value_permutations(bounds)) if "Value" in axes else 1
 
     def canon_fp(s):
-        # the key of one group element's image, from its fields: neither
-        # scan body below builds the packed row (a concatenate and two
+        # the key of one group element's image, from its fields: the
+        # scan body never builds the packed row (a concatenate and two
         # relayouts through HBM every iteration; PERF.md, PR 27)
         return fpr.fingerprint_fields(st.canonicalize(s, jnp), consts, jnp)
 
@@ -618,127 +404,7 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool,
                                    jnp.arange(P * Q, dtype=jnp.int32))
         return bh, bl
 
-    spairs = _transposition_pairs(bounds) if "Server" in axes else ()
-    vpairs = _value_transposition_pairs(bounds) if "Value" in axes else ()
-    if not prune or (not spairs and not vpairs):
-        return orbit_fp
-
-    less_s = jnp.asarray(_pair_less_lut(permutations(bounds), spairs)) \
-        if spairs else None                                   # [P, Ps]
-    less_v = jnp.asarray(_pair_less_lut(value_permutations(bounds), vpairs)) \
-        if vpairs else None                                   # [Q, Pv]
-    sprobes = jnp.asarray([(pi, a, b) for (a, b, pi) in spairs], jnp.int32)
-    vprobes = jnp.asarray([pi for (_a, _b, pi) in vpairs], jnp.int32)
-
-    def pruned_orbit_fp(struct):
-        N = struct["role"].shape[0]
-        if sluts is not None:
-            inv_idx, vf_map, bit_lut, p_lut = sluts
-        canon_pack = jax.vmap(lambda s: st.pack(st.canonicalize(s, jnp), jnp))
-        id_row = canon_pack(struct)                           # [N, W]
-
-        def keep_from(eq, less):
-            # keep[s, p] <=> no verified-equal pair (a, b) with p[a] > p[b]
-            # — small exact-int matmul (counts <= n_pairs, exact in f32)
-            bad = jnp.matmul(eq.astype(jnp.float32),
-                             (~less).astype(jnp.float32).T)
-            return bad < 0.5                                  # [N, P]
-
-        if spairs:
-            sig = _server_sig(struct, jnp)                    # [N, n]
-
-            def sbody(carry, row):
-                pidx, a, b = row[0], row[1], row[2]
-
-                def probe(_):
-                    t = _permute_struct_batch(
-                        struct, inv_idx[pidx], vf_map[pidx],
-                        bit_lut[pidx], p_lut[pidx], jnp)
-                    return jnp.all(canon_pack(t) == id_row, axis=1)
-
-                # signature prefilter: equal sigs are necessary for the
-                # exact probe to fire anywhere in the chunk
-                cand = jnp.any(jnp.take(sig, a, axis=1)
-                               == jnp.take(sig, b, axis=1))
-                eq = jax.lax.cond(cand, probe,
-                                  lambda _: jnp.zeros((N,), bool), None)
-                return carry, eq
-
-            _, eq_sT = jax.lax.scan(sbody, None, sprobes)     # [Ps, N]
-            keep_s = keep_from(eq_sT.T, less_s)               # [N, P]
-        else:
-            keep_s = jnp.ones((N, P), bool)
-
-        if vpairs:
-            def vbody(carry, qidx):
-                t = _permute_values_batch(struct, vluts, qidx, bounds, jnp)
-                return carry, jnp.all(canon_pack(t) == id_row, axis=1)
-
-            _, eq_vT = jax.lax.scan(vbody, None, vprobes)     # [Pv, N]
-            keep_v = keep_from(eq_vT.T, less_v)               # [N, Q]
-        else:
-            keep_v = jnp.ones((N, Q), bool)
-
-        keptf = (keep_s[:, :, None] & keep_v[:, None, :]).reshape(N, P * Q)
-        n_kept = jnp.sum(keptf.astype(jnp.int32), axis=1)
-        max_kept = jnp.max(n_kept)
-
-        top = jnp.zeros_like(struct["role"][:, 0]).astype(jnp.uint32) \
-            | jnp.uint32(0xFFFFFFFF)
-
-        def scan_kept_at(K):
-            def run(_):
-                # compact each state's kept group-element indices into K
-                # static slots (built INSIDE the rung branch: untaken
-                # rungs must cost nothing); pad slots stay 0 = identity,
-                # which is always kept — re-scanning it is harmless
-                pos = jnp.cumsum(keptf.astype(jnp.int32), axis=1) - 1
-                slot = jnp.where(keptf & (pos < K), pos, K)
-                kidx = jnp.zeros((N, K), jnp.int32).at[
-                    jnp.arange(N)[:, None], slot].set(
-                    jnp.arange(P * Q, dtype=jnp.int32)[None, :],
-                    mode="drop")
-
-                def one(s, pi, qi):
-                    t = s
-                    if sluts is not None:
-                        t = _permute_struct_traced(
-                            t, inv_idx[pi], vf_map[pi], bit_lut[pi],
-                            p_lut[pi], jnp)
-                    if vluts is not None:
-                        t = _permute_values_traced(
-                            t, {kk: vv[qi] for kk, vv in vluts.items()},
-                            bounds, jnp)
-                    return canon_fp(t)
-
-                def body(best, j):
-                    k = kidx[:, j]
-                    pi, qi = k // Q, k % Q
-                    hi, lo = jax.vmap(one)(struct, pi, qi)
-                    bh, bl = best
-                    take = (hi < bh) | ((hi == bh) & (lo < bl))
-                    return (jnp.where(take, hi, bh),
-                            jnp.where(take, lo, bl)), None
-
-                (bh, bl), _ = jax.lax.scan(
-                    body, (top, top), jnp.arange(K, dtype=jnp.int32))
-                return bh, bl
-
-            return run
-
-        # elif chain inside-out like _orbit_fp_prescan: smallest rung
-        # tested first; chunks with any fully-asymmetric state fall back
-        # to the unpruned shared-LUT scan (same arithmetic, zero overlay)
-        out = lambda _: orbit_fp(struct)
-        for div in sorted(_SIGPRUNE_RUNGS):
-            K = max(1, (P * Q) // div)
-            if K >= P * Q:
-                continue
-            out = (lambda _, _r=scan_kept_at(K), _o=out, _K=K:
-                   jax.lax.cond(max_kept <= _K, _r, _o, None))
-        return out(None)
-
-    return pruned_orbit_fp
+    return orbit_fp
 
 
 def orbit_fingerprint(struct: dict, bounds: Bounds, consts, xp,

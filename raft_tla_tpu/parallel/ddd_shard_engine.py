@@ -246,8 +246,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
         lane_map = jnp.asarray(cpx.cp_lane_map(config.bounds, config.spec,
                                                ndev))     # [ndev, A_loc]
     else:
-        # Orbit-scan variants (prescan, sig-prune) resolve from their
-        # env gates at build time — bit-identical keys either way.
+        # The prescan ladder resolves at build time
+        # (kernels._prescan_enabled) — bit-identical keys either way.
         step = kernels.build_step(config.bounds, config.spec,
                                   tuple(config.invariants),
                                   config.symmetry, view=config.view)

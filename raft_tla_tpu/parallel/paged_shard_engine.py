@@ -130,8 +130,8 @@ def _build_segment(config: CheckConfig, caps: PagedShardCapacities, A: int,
     n_inv = len(config.invariants)
     if n_inv > 29:
         raise ValueError("at most 29 invariants (bit-packed int32 flags)")
-    # Orbit-scan variants (prescan, sig-prune) resolve from their env
-    # gates at build time — bit-identical keys either way.
+    # The prescan ladder resolves at build time
+    # (kernels._prescan_enabled) — bit-identical keys either way.
     step = kernels.build_step(config.bounds, config.spec,
                               tuple(config.invariants), config.symmetry,
                               view=config.view)

@@ -234,8 +234,8 @@ def _build_segment(config: CheckConfig, caps: ShardCapacities,
     n_inv = len(config.invariants)
     if n_inv > 29:
         raise ValueError("at most 29 invariants (bit-packed into int32 flags)")
-    # Orbit-scan variants (prescan, sig-prune) resolve from their env
-    # gates at build time; keys stay bit-identical either way, so mixed
+    # The prescan ladder resolves at build time
+    # (kernels._prescan_enabled); keys stay bit-identical either way, so mixed
     # settings across reshard/resume cannot corrupt the store.
     step = kernels.build_step(config.bounds, config.spec,
                               tuple(config.invariants), config.symmetry,
@@ -764,8 +764,8 @@ def reshard_checkpoint(config: CheckConfig, caps_src: ShardCapacities,
     consts_j = jnp.asarray(fpr.lane_constants(W))
     faithful = "allLogs" in lay.shapes
     if config.symmetry:
-        # host one-off: the unpruned scan is fine here (sig-prune keys
-        # are bit-identical, so either variant reproduces the store)
+        # host one-off: the bare scan, no prescan ladder (keys are
+        # bit-identical, so either reproduces the store)
         orbit = sym_mod.build_orbit_fp(bounds, tuple(config.symmetry),
                                        consts_j, faithful)
 

@@ -48,7 +48,7 @@ def bin_key(config: CheckConfig) -> tuple:
 
     Delegates to ``ops/kernels.step_signature`` — THE definition of
     step-compile identity, including the construction-time gate
-    resolutions (megakernel / prescan / sig-prune) — so a gate flipping
+    resolutions (prescan / devdedup) — so a gate flipping
     between admissions can never mix step variants inside one bin.
     (Previously this tuple was hand-maintained here, so a new
     step-compile toggle had to be remembered in two places.)

@@ -109,8 +109,8 @@ def _build_segment(config: CheckConfig, caps: StreamedCapacities, A: int,
                    W: int, schema: bitpack.BitSchema):
     B = config.chunk
     n_inv = len(config.invariants)
-    # Orbit-scan variants (prescan, sig-prune) resolve from their env
-    # gates at build time — the segment must be rebuilt to change them.
+    # The prescan ladder resolves at build time
+    # (kernels._prescan_enabled) — the segment must be rebuilt to change it.
     step = kernels.build_step(config.bounds, config.spec,
                               tuple(config.invariants), config.symmetry,
                               view=config.view)

@@ -7,6 +7,8 @@ import pytest
 from raft_tla_tpu.utils.cfgparse import parse_cfg, load_cfg
 
 REF_CFG = pathlib.Path("/root/reference/raft.cfg")
+if not REF_CFG.exists():        # not mounted here: the vendored copy
+    REF_CFG = pathlib.Path(__file__).parent / "fixtures" / "raft.cfg"
 
 
 def test_reference_cfg_parses():

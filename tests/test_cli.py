@@ -7,6 +7,7 @@ artifacts are validated structurally and by cfgparse round-trip
 """
 
 import io
+import os
 import re
 from contextlib import redirect_stdout
 
@@ -21,6 +22,8 @@ from raft_tla_tpu.utils import render
 from raft_tla_tpu.utils.cfgparse import parse_cfg
 
 REF_CFG = "/root/reference/raft.cfg"
+if not os.path.exists(REF_CFG):     # not mounted here: the vendored copy
+    REF_CFG = os.path.join(os.path.dirname(__file__), "fixtures", "raft.cfg")
 
 
 

@@ -145,22 +145,36 @@ def test_differential_5server_north_star_universe():
     _diff_on_states(states, bounds, "full")
 
 
-def test_routed_step_matches_dense():
+# the benchmark's five-server bounds (benchmark/configs/elect5.json,
+# full5.json): the routed step is what `full5.passes`' 5.7 %-live dense
+# step is to be judged against (PERF.md section 7)
+_ROUTED_CASES = {
+    "3s-full": (B3, "full", ("NoTwoLeaders", "LogMatching"), 16),
+    "elect5": (Bounds(n_servers=5, n_values=2, max_term=2, max_log=0,
+                      max_msgs=2, max_dup=1), "election",
+               ("NoTwoLeaders",), 8),
+    "full5": (Bounds(n_servers=5, n_values=2, max_term=2, max_log=1,
+                     max_msgs=2, max_dup=1), "full",
+              ("NoTwoLeaders", "LogMatching"), 8),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROUTED_CASES))
+def test_routed_step_matches_dense(case):
     """build_step_routed (EP routing, SURVEY §2.9): the compacted stream
     is exactly the dense step's valid lanes, in flat order, with
     identical per-candidate values — and the budget overflow is loud."""
-    bounds = B3
+    bounds, spec, invs, n_states = _ROUTED_CASES[case]
     rng = np.random.default_rng(17)
-    states = [random_pystate(rng, bounds) for _ in range(16)]
+    states = [random_pystate(rng, bounds) for _ in range(n_states)]
     vecs = jnp.asarray(np.stack([interp.to_vec(s, bounds) for s in states]))
-    invs = ("NoTwoLeaders", "LogMatching")
     for sym in ((), ("Server",)):
-        dense = jax.jit(kernels.build_step(bounds, "full", invs,
+        dense = jax.jit(kernels.build_step(bounds, spec, invs,
                                            sym))(vecs)
         A = dense["valid"].shape[1]
         N = len(states) * A
         routed = jax.jit(kernels.build_step_routed(
-            bounds, "full", invs, sym, k_rows=N))(vecs)
+            bounds, spec, invs, sym, k_rows=N))(vecs)
         np.testing.assert_array_equal(dense["valid"], routed["valid"])
         np.testing.assert_array_equal(dense["overflow"],
                                       routed["overflow"])
@@ -185,13 +199,13 @@ def test_routed_step_matches_dense():
             np.asarray(dense["inv_ok"]).reshape(N, len(invs))[en])
     # a budget below the enabled count must flag, never silently drop
     tight = jax.jit(kernels.build_step_routed(
-        bounds, "full", invs, k_rows=max(1, en.size // 2)))(vecs)
+        bounds, spec, invs, k_rows=max(1, en.size // 2)))(vecs)
     assert bool(tight["route_ovf"])
     # row_ok: dead rows (stale padding / constraint-excluded parents)
     # must not consume routing slots — only live rows' lanes compact
     row_ok = np.arange(len(states)) % 2 == 0
     masked = jax.jit(kernels.build_step_routed(
-        bounds, "full", invs, k_rows=N))(vecs, jnp.asarray(row_ok))
+        bounds, spec, invs, k_rows=N))(vecs, jnp.asarray(row_ok))
     np.testing.assert_array_equal(masked["valid"], dense["valid"])
     live = fvalid & np.repeat(row_ok, A)
     en_live = np.flatnonzero(live)

@@ -207,33 +207,79 @@ _ELECT5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=0, max_msgs=2,
                  max_dup=1)
 _FULL5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=1, max_msgs=2,
                 max_dup=1)
-# (bounds, axes, spec, BFS depth, lanes kept a level, states compared)
-_SCAN_CASES = {
-    "3s-server": (_B3S, ("Server",), "full", 4, 40, 200),
-    "3s-value": (_B3S, ("Value",), "full", 4, 40, 200),
-    "3s-server-value": (_B3S, ("Server", "Value"), "full", 4, 40, 200),
-    "2s-faithful-server-value": (_BH2, ("Server", "Value"), "full", 4, 40,
-                                 200),
-    "2s-faithful-value": (_BH2, ("Value",), "full", 6, 60, 300),
-    "elect5-server": (_ELECT5, ("Server",), "election", 7, 60, 300),
-    "full5-server": (_FULL5, ("Server",), "full", 7, 60, 300),
-}
-
-
-def _scan_case_states(bounds, spec, depth, lane_cap, cap):
-    """A bag of reachable states: BFS prefix via the interpreter."""
+def _scan_case_states(bounds, spec, depth, lane_cap, cap, first=False):
+    """A bag of reachable states: BFS prefix via the interpreter, keeping
+    ``lane_cap`` successors a level — every k-th one (late ones carry the
+    deeper histories), or with ``first`` the first ones (the low action
+    ids: timeouts, vote requests and their replies, where servers still
+    look alike) with the constraint ignored."""
     frontier = [interp.init_state(bounds)]
     seen = list(frontier)
     for _ in range(depth):
         nxt = []
         for s in frontier:
-            if interp.constraint_ok(s, bounds):     # counted, not expanded
+            # a state past the constraint is counted, not expanded
+            if first or interp.constraint_ok(s, bounds):
                 nxt += [t for _i, t in interp.successors(s, bounds,
                                                          spec=spec)]
-        # every lane_cap-th successor: late ones carry the deeper histories
-        frontier = nxt[::max(1, len(nxt) // lane_cap)][:lane_cap]
+        stride = 1 if first else max(1, len(nxt) // lane_cap)
+        frontier = nxt[::stride][:lane_cap]
         seen += frontier
     return seen[:cap]
+
+
+def _random_states(bounds, n, seed):
+    from test_state import random_pystate
+    rng = np.random.default_rng(seed)
+    return [random_pystate(rng, bounds) for _ in range(n)]
+
+
+def _all_distinct_state():
+    """No two servers interchangeable: every one of the 6 permutations
+    gives another orbit member, so the min really ranges over the group."""
+    return interp.init_state(_B3S)._replace(
+        role=(0, 1, 2), term=(1, 2, 2), votedFor=(0, 2, 3))
+
+
+# name -> (bounds, axes, VIEW or None, states, at least this many)
+_SCAN_CASES = {
+    "3s-server": (_B3S, ("Server",), None,
+                  lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
+    "3s-value": (_B3S, ("Value",), None,
+                 lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
+    "3s-server-value": (
+        _B3S, ("Server", "Value"), None,
+        lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
+    "2s-faithful-server-value": (
+        _BH2, ("Server", "Value"), None,
+        lambda: _scan_case_states(_BH2, "full", 4, 40, 200), 100),
+    "2s-faithful-value": (
+        _BH2, ("Value",), None,
+        lambda: _scan_case_states(_BH2, "full", 6, 60, 300), 100),
+    "elect5-server": (
+        _ELECT5, ("Server",), None,
+        lambda: _scan_case_states(_ELECT5, "election", 7, 60, 300), 300),
+    "full5-server": (
+        _FULL5, ("Server",), None,
+        lambda: _scan_case_states(_FULL5, "full", 7, 60, 300), 300),
+    # the poles of the orbit: every permutation ties / none does
+    "5s-all-identical": (
+        _ELECT5, ("Server",), None,
+        lambda: [interp.init_state(_ELECT5)] * 4, 4),
+    "3s-all-distinct": (
+        _B3S, ("Server",), None, lambda: [_all_distinct_state()], 1),
+    "3s-first-lanes-server-value": (
+        _B3S, ("Server", "Value"), None,
+        lambda: _scan_case_states(_B3S, "full", 3, 60, 150, first=True), 100),
+    "2s-faithful-first-lanes-server-value": (
+        _BH2, ("Server", "Value"), None,
+        lambda: _scan_case_states(_BH2, "full", 4, 60, 150, first=True), 100),
+    # the engines hand the scan the VIEWED struct; random bounded states,
+    # because votes on a server that is no candidate (what the view
+    # folds) are rare in a BFS prefix
+    "3s-view-server": (_B3S, ("Server",), "deadvotes",
+                       lambda: _random_states(_B3S, 120, seed=28), 120),
+}
 
 
 @pytest.mark.parametrize("case", list(_SCAN_CASES))
@@ -242,33 +288,41 @@ def test_scan_orbit_fp_bit_identical_to_loop(case):
     iterated over the group, keying each image from its fields) must
     produce bit-identical (hi, lo) keys to the reference unrolled loop
     (orbit_fingerprint, which packs the row): checkpointed runs resume
-    across the upgrade only if the keys are unchanged.  The coset-pruned
-    scan shares the body and has to give the same keys on the same
-    states."""
+    across the upgrade only if the keys are unchanged.  Under a VIEW the
+    scan sees the device view of the struct and the loop the host view
+    of the state."""
     import jax
     import jax.numpy as jnp
+    from raft_tla_tpu.models import views
     from raft_tla_tpu.ops import fingerprint as fpr
     from raft_tla_tpu.ops import state as st
 
-    bounds, axes, spec, depth, lane_cap, cap = _SCAN_CASES[case]
+    bounds, axes, view, make, at_least = _SCAN_CASES[case]
     lay = st.Layout.of(bounds)
     consts = fpr.lane_constants(lay.width)
-    seen = _scan_case_states(bounds, spec, depth, lane_cap, cap)
-    assert len(seen) >= (300 if bounds.n_servers == 5 else 100), len(seen)
+    seen = make()
+    assert len(seen) >= at_least, len(seen)
     vecs = np.stack([interp.to_vec(s, bounds) for s in seen])
     structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(jnp.asarray(vecs))
+    if view:
+        structs = jax.vmap(views.jnp_view(view, bounds))(structs)
+        host_view = views.py_view(view)
+        raw = vecs
+        vecs = np.stack([interp.to_vec(host_view(s, bounds), bounds)
+                         for s in seen])
+        assert (vecs != raw).any(axis=1).sum() >= len(seen) // 2
     faithful = "allLogs" in lay.shapes
     fn = sym.build_orbit_fp(bounds, axes, jnp.asarray(consts), faithful)
     hi_s, lo_s = (np.asarray(a) for a in jax.jit(fn)(structs))
-    pruned = sym.build_orbit_fp(bounds, axes, jnp.asarray(consts), faithful,
-                                prune=True)
-    hi_p, lo_p = (np.asarray(a) for a in jax.jit(pruned)(structs))
-    assert np.array_equal(hi_p, hi_s) and np.array_equal(lo_p, lo_s)
     for k, s in enumerate(seen):
         struct = st.unpack(vecs[k], lay, np)
         hi_l, lo_l = sym.orbit_fingerprint(struct, bounds, consts, np, axes)
         assert (int(hi_s[k]), int(lo_s[k])) == (int(hi_l), int(lo_l)), \
             (axes, k, s)
+    if case == "3s-all-distinct":
+        # the case can see: the identity's image is not the orbit's min
+        ih, il = fpr.fingerprint(vecs, consts, np)
+        assert (int(ih[0]), int(il[0])) != (int(hi_s[0]), int(lo_s[0]))
 
 
 def test_scan_body_builds_no_packed_row():

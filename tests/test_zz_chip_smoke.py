@@ -87,8 +87,8 @@ def test_one_failed_phase_fails_the_script(monkeypatch, tmp_path, capsys):
 def test_probe_reports_device_gates_and_native(ctx):
     info = cs.phase_probe(ctx)
     assert info["device"]["platform"] == "cpu" and info["has_native"]
-    assert set(info["gates"]) == {"megakernel", "prescan", "sigprune",
-                                  "devdedup", "host_dedup", "prefetch"}
+    assert set(info["gates"]) == {"prescan", "devdedup", "host_dedup",
+                                  "prefetch"}
     tpu = cs.Ctx(out=ctx.out, platform="tpu")
     with pytest.raises(cs.Failed, match="not 'tpu'"):
         cs.phase_probe(tpu)
@@ -144,7 +144,6 @@ def test_serve_and_campaign_rehearsal(ctx):
 def test_kernels_rehearsal_under_the_interpreter(ctx):
     got = cs.phase_kernels(ctx, chunk=128, interpret=True)
     assert got["pallas_fp"] == {"mosaic_ok": True, "bit_equal": True}
-    assert got["megakernel"] == {"mosaic_ok": True, "bit_equal": True}
 
 
 def test_multichip_rehearsal_on_two_virtual_devices(ctx, capsys):

@@ -147,17 +147,6 @@ python -m raft_tla_tpu.check "$SERVE_TMP/2pc.cfg" \
 grep -q "^56 distinct states found" "$SERVE_TMP/2pc.out" \
     || { echo "frontend smoke FAILED: expected 56 states"; exit 1; }
 
-begin megakernel "megakernel smoke (toy cfg, staged whole-step Pallas, CPU)"
-# Gate forced ON: off-TPU this runs the kernel in Pallas interpret
-# mode (ops/pallas_compat.resolve), so the block walks the real
-# pallas_call staging path end-to-end inside a real engine.
-python -m raft_tla_tpu.check "$SERVE_TMP/toy.cfg" \
-    --spec election --max-term 2 --max-log 0 --max-msgs 2 \
-    --chunk 256 --megakernel on --cpu --no-lint --no-trace \
-    | tee "$SERVE_TMP/megakernel.out" | tail -2
-grep -q "^3014 distinct states found" "$SERVE_TMP/megakernel.out" \
-    || { echo "megakernel smoke FAILED: expected 3014 states"; exit 1; }
-
 begin host-dedup "host-dedup smoke (ddd engine, background partitioned flush, CPU)"
 # Gate forced ON: the toy cfg runs end-to-end through the ddd engine
 # with partitioned master keys and the depth-1 background flush worker,
@@ -232,15 +221,15 @@ off_line="$(grep '^3014 distinct states found' "$SERVE_TMP/devdedup_off.out" \
          echo "  on:  $on_line"; echo "  off: $off_line"; exit 1; }
 echo "device-dedup smoke ok: on/off byte-identical ($on_line)"
 
-begin gates "gates smoke (--sig-prune/--prescan/--phase-timers/--compile-cache, CPU)"
-# The four remaining RAFT_TLA_* gates exercised in one identity check:
+begin gates "gates smoke (--prescan/--phase-timers/--compile-cache, CPU)"
+# The three remaining RAFT_TLA_* gates exercised in one identity check:
 # every gate forced away from its auto default (the phase-timer sync
-# path, both kernel-policy gates, the persistent compile cache), then a
+# path, the prescan ladder, the persistent compile cache), then a
 # default run — the result lines (wall stripped) must be byte-identical,
 # and the compile cache directory must actually be populated.
 python -m raft_tla_tpu.check "$SERVE_TMP/toy.cfg" \
     --spec election --max-term 2 --max-log 0 --max-msgs 2 \
-    --engine ddd --chunk 32 --sig-prune on --prescan on \
+    --engine ddd --chunk 32 --prescan on \
     --phase-timers --compile-cache "$SERVE_TMP/jaxcache" \
     --cpu --no-lint --no-trace \
     | tee "$SERVE_TMP/gates_on.out" | tail -2
@@ -250,7 +239,7 @@ grep -q "^3014 distinct states found" "$SERVE_TMP/gates_on.out" \
     || { echo "gates smoke FAILED: compile cache dir empty"; exit 1; }
 python -m raft_tla_tpu.check "$SERVE_TMP/toy.cfg" \
     --spec election --max-term 2 --max-log 0 --max-msgs 2 \
-    --engine ddd --chunk 32 --sig-prune off --prescan off \
+    --engine ddd --chunk 32 --prescan off \
     --cpu --no-lint --no-trace \
     > "$SERVE_TMP/gates_off.out"
 on_line="$(grep '^3014 distinct states found' "$SERVE_TMP/gates_on.out" \
