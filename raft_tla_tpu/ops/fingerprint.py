@@ -68,56 +68,77 @@ def _fmix32(h, xp):
     return h
 
 
+def fold(a, xp):
+    """The fold (module docstring): ``x ^ (x >> 16)`` on the uint32 word."""
+    w = a.astype(xp.uint32)
+    return w ^ (w >> xp.uint32(16))
+
+
+def finalise(s1, s2, xp):
+    """The lanes' sums before the finaliser -> the (hi, lo) key."""
+    with np.errstate(over="ignore"):
+        return (_fmix32(s1 + _LANE_SEEDS[0], xp),
+                _fmix32(s2 + _LANE_SEEDS[1], xp))
+
+
 def fingerprint(vec, consts, xp):
     """Canonical int32[..., W] -> (hi, lo) uint32 lanes, shape [...]."""
     # uint32 wraparound is the *point* of the arithmetic; silence NumPy's
     # scalar-overflow warning (no-op under jnp, which never warns).
     with np.errstate(over="ignore"):
-        w = vec.astype(xp.uint32)
-        w = w ^ (w >> xp.uint32(16))      # the fold (module docstring)
+        w = fold(vec, xp)
         c1 = consts[0].astype(xp.uint32)
         c2 = consts[1].astype(xp.uint32)
         s1 = xp.sum(w * c1, axis=-1, dtype=xp.uint32)
         s2 = xp.sum(w * c2, axis=-1, dtype=xp.uint32)
-        h1 = _fmix32(s1 + _LANE_SEEDS[0], xp)
-        h2 = _fmix32(s2 + _LANE_SEEDS[1], xp)
-    return h1, h2
+    return finalise(s1, s2, xp)
+
+
+def field_constants(shapes: dict, consts) -> dict:
+    """field -> ``uint32[2, *shape]``: each field's slice of the two
+    lanes' constants at its packed positions (``shapes``: a layout's, in
+    ``state.pack``'s order, row-major inside a field).  ``consts`` must
+    be concrete (closed over, never a traced argument): the slices
+    become literals of the compiled program."""
+    c = np.asarray(consts).astype(np.uint32)
+    out, off = {}, 0
+    for f, shape in shapes.items():
+        size = int(np.prod(shape))
+        out[f] = c[:, off:off + size].reshape((2,) + tuple(shape))
+        off += size
+    return out
+
+
+def field_sums(struct, consts, xp, fields=None):
+    """The two lanes' sums before the finaliser, over ``fields`` (default:
+    every field) of a state struct (ops/state.py; leading batch dims pass
+    through): each field is folded where it lies, multiplied by the
+    constants of its packed positions (:func:`field_constants`) and
+    reduced over its own axes.  The sums are mod 2^32, so partial sums
+    over disjoint field sets simply add."""
+    batch = struct["role"].ndim - 1
+    fc = field_constants({f: struct[f].shape[batch:]
+                          for f in st.fields_of(struct)}, consts)
+    s1 = s2 = xp.uint32(0)
+    with np.errstate(over="ignore"):
+        for f in (fc if fields is None else fields):
+            w = fold(struct[f], xp)
+            axes = tuple(range(batch, w.ndim))
+            s1 = s1 + xp.sum(w * fc[f][0], axis=axes, dtype=xp.uint32)
+            s2 = s2 + xp.sum(w * fc[f][1], axis=axes, dtype=xp.uint32)
+    return s1, s2
 
 
 def fingerprint_fields(struct, consts, xp):
-    """State struct (ops/state.py; leading batch dims pass through) ->
-    (hi, lo) uint32 lanes, bit-identical to
-    ``fingerprint(state.pack(struct), consts, xp)``.
-
-    The sum before the finaliser is mod 2^32, so it may be taken in any
-    order: each field is folded where it lies, multiplied by the constants
-    of its packed positions (``state.pack``'s order, row-major inside a
-    field) and reduced over its own axes, and the per-field sums are
-    added.  No ``[..., W]`` row is built.  The orbit scan keys from here
-    |G| times a chunk step (ops/symmetry.build_orbit_fp), where the packed
-    row was written to HBM and read back once a permutation for nothing.
-    ``consts`` must be concrete (closed over, never a traced argument):
-    its slices become literals of the compiled program."""
-    c = np.asarray(consts).astype(np.uint32)
-    batch = struct["role"].ndim - 1
-    s1 = s2 = xp.uint32(0)
-    off = 0
-    with np.errstate(over="ignore"):
-        for f in st.fields_of(struct):
-            a = struct[f]
-            shape = a.shape[batch:]
-            size = int(np.prod(shape))
-            axes = tuple(range(batch, a.ndim))
-            w = a.astype(xp.uint32)
-            w = w ^ (w >> xp.uint32(16))      # the fold (module docstring)
-            s1 = s1 + xp.sum(w * c[0, off:off + size].reshape(shape),
-                             axis=axes, dtype=xp.uint32)
-            s2 = s2 + xp.sum(w * c[1, off:off + size].reshape(shape),
-                             axis=axes, dtype=xp.uint32)
-            off += size
-        h1 = _fmix32(s1 + _LANE_SEEDS[0], xp)
-        h2 = _fmix32(s2 + _LANE_SEEDS[1], xp)
-    return h1, h2
+    """State struct -> (hi, lo) uint32 lanes, bit-identical to
+    ``fingerprint(state.pack(struct), consts, xp)``, and no ``[..., W]``
+    row is built: the sum before the finaliser may be taken in any order,
+    so it is taken field by field (:func:`field_sums`).  The orbit scan
+    (ops/symmetry.build_orbit_fp) keyed every image from here from PR 27
+    to PR 29; it now adds ``field_sums`` of the few fields it still moves
+    to a sum it takes without moving any, and this function is the
+    whole-struct form the tests hold both against."""
+    return finalise(*field_sums(struct, consts, xp), xp)
 
 
 def to_u64(hi: np.ndarray, lo: np.ndarray) -> np.ndarray:

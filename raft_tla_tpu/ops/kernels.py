@@ -611,9 +611,9 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     consts = jnp.asarray(fpr.lane_constants(lay.width))
     expand = build_expand(bounds, spec, family_kernels=family_kernels)
     inv_fns = [inv_mod.jnp_invariant(nm, bounds) for nm in invariants]
-    # Scan-compiled orbit pass: ONE copy of the permute/canonicalize/
-    # fingerprint pipeline iterated over the n!*V! group, not n!*V!
-    # unrolled copies (ops/symmetry.build_orbit_fp) — bit-identical keys.
+    # Scan-compiled orbit pass: ONE body iterated over the n!*V! group,
+    # not n!*V! unrolled copies of the permute/canonicalize/fingerprint
+    # pipeline (ops/symmetry.build_orbit_fp) — bit-identical keys.
     # Every engine's step builder flows through here.
     orbit_fp = sym.build_orbit_fp(bounds, symmetry, consts,
                                   "allLogs" in lay.shapes) \
@@ -625,16 +625,17 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     # compilation at P=24 (kernel stack scales with the unrolled group;
     # 73 MB at P=120 vs the 16 MB scoped-vmem limit, and the P=24
     # remote-compile returned HTTP 500 — runs/pallas_orbit_p24.out),
-    # and was deleted.  The scan does NOT compile to one resident fused
-    # pipeline: read from the v5e's compiled program, its body is some
-    # 290 device operations a permutation (~55 loop fusions, 21 unfused
-    # dynamic-update-slices and ~15 copies from the message sort
-    # network, two custom gather fusions a permuted field).  The packed
-    # [lanes, W] row is not among them: fingerprint.fingerprint_fields
-    # keys from the fields, where a concatenate wrote the row to HBM and
-    # a reduce read it back once a permutation (PERF.md, PR 27; what
-    # stands is in its section 7).  Mosaic findings: git show
-    # f293573:RESULTS.md "Pallas orbit kernel", runs/pallas_orbit_p24.out.
+    # and was deleted.  What the scan compiles to on the v5e, since PR
+    # 29: a body of some 20 device operations a permutation, two of them
+    # over the lanes — one multiply-reduce of the byte-wide feature
+    # matrix against that permutation's row of constants, one fusion
+    # that relabels and ranks the message bag.  No state array is
+    # gathered, relabelled, sorted or packed in it (through PR 28: ~290
+    # operations, 21 unfused dynamic-update-slices from the message sort
+    # network, two custom gather fusions a permuted field; through PR
+    # 26 also the packed [lanes, W] row).  PERF.md, PRs 27 and 29.
+    # Mosaic findings: git show f293573:RESULTS.md "Pallas orbit
+    # kernel", runs/pallas_orbit_p24.out.
     # The view folds into the DEDUP KEY only: stored rows, invariants and
     # the constraint all see the full successor (TLC VIEW semantics).
     viewer = None

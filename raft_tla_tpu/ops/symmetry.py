@@ -11,15 +11,19 @@ renumbers every server-indexed axis and server-valued field.  The min is
 orbit-invariant, so two states equal up to server renaming share one key
 and one store row — the reachable count becomes the orbit count, exactly
 TLC's SYMMETRY semantics (including its property: the stored witness per
-orbit is whichever member was discovered first).  On device this is |π|
-static transforms batched over the candidate block — pure gathers, bit
-arithmetic, and the existing canonicalize/fingerprint pipeline (the key of
-each image is taken from its fields, never from a packed row).
+orbit is whichever member was discovered first).  The loop that says what
+a key is — permute, canonicalize, pack, fingerprint, |π| times — is
+:func:`orbit_fingerprint`, and the host oracle runs it as written.  On
+device nothing is permuted (:func:`build_orbit_fp`): the key is linear in
+the state's words before its finaliser, so the image's key is taken from
+the *unmoved* state against that permutation's row of a host-built table
+of permuted constants, and the message bag is ranked where the loop sorts
+it — the same bits, |π| slices and multiply-reduces a candidate block.
 
 Permuting one state under ``p`` (new index of old server j is ``p[j]``):
 
 - per-server axes (role, term, votedFor, commitIndex, logLen, log*,
-  vResp, vGrant): rows reordered by the inverse permutation;
+  vResp, vGrant): the image's row ``p[j]`` is the state's row ``j``;
 - server-valued *contents*: ``votedFor`` ids map through ``p`` (0 = Nil
   fixed); vote bitmasks move bit j to bit ``p[j]``;
 - ``nextIndex``/``matchIndex`` reorder both axes;
@@ -209,29 +213,24 @@ def permute_struct(struct: dict, p: tuple, bounds: Bounds, xp) -> dict:
     return out
 
 
-def _server_luts(bounds: Bounds) -> tuple:
+def _server_luts(bounds: Bounds) -> dict:
     """Stacked lookup tables for every server permutation — the data that
     lets ONE compiled transform apply any group element (build_orbit_fp):
-    ``inv_idx [P, n]`` row gathers, ``vf_map [P, n+1]`` votedFor relabel,
-    ``bit_lut [P, 2^n]`` vote-bitmask permutation, ``p_lut [P, 16]``
-    message src/dst relabel (4-bit fields)."""
+    ``src`` / ``dst`` ``[P, n]``, the message relabel already shifted to
+    its field of the hi word, and for the fields the scan still moves
+    ``inv [P, n]`` row gathers, ``bit_lut [P, 2^n]`` vote-bitmask
+    permutation and ``p_lut [P, n]`` server relabel."""
     ps = permutations(bounds)
     n = bounds.n_servers
-    P = len(ps)
-    inv_idx = np.empty((P, n), np.int32)
-    vf_map = np.empty((P, n + 1), np.int32)
-    bit_lut = np.empty((P, 1 << n), np.int32)
-    p_lut = np.zeros((P, 16), np.int32)
-    masks = np.arange(1 << n, dtype=np.int64)
-    for i, p in enumerate(ps):
-        inv_idx[i] = [p.index(k) for k in range(n)]
-        vf_map[i] = (0,) + tuple(p[j] + 1 for j in range(n))
-        bm = np.zeros((1 << n,), np.int64)
-        for j in range(n):
-            bm |= ((masks >> j) & 1) << p[j]
-        bit_lut[i] = bm
-        p_lut[i, :n] = p
-    return inv_idx, vf_map, bit_lut, p_lut
+    p_lut = np.asarray(ps, np.int32)
+    masks = np.arange(1 << n, dtype=np.int32)
+    bit_lut = np.zeros((len(ps), 1 << n), np.int32)
+    for j in range(n):
+        bit_lut |= ((masks >> j) & 1)[None, :] << p_lut[:, j:j + 1]
+    return {"src": p_lut << mb.HI_FIELDS["src"][0],
+            "dst": p_lut << mb.HI_FIELDS["dst"][0],
+            "inv": np.argsort(p_lut, axis=1).astype(np.int32),
+            "bit_lut": bit_lut, "p_lut": p_lut}
 
 
 def _value_luts(bounds: Bounds, faithful: bool) -> dict:
@@ -259,78 +258,209 @@ def _value_luts(bounds: Bounds, faithful: bool) -> dict:
     return out
 
 
-def _permute_struct_batch(struct: dict, inv, vf_map, bit_lut, p_lut, xp):
-    """``permute_struct`` over a leading batch axis, with the permutation
-    given as traced LUT rows (same arithmetic, same bits — the gathers
-    read precomputed tables instead of Python-side tuples)."""
-    def rows(a):
-        return xp.take(a, inv, axis=1)
+# ---------------------------------------------------------------------------
+# The orbit scan's key of one image, taken without moving the state
+# (build_orbit_fp).  Before its finaliser the key is linear in the folded
+# words (ops/fingerprint), and a server permutation of server-indexed data
+# under a fixed linear form is the inverse permutation of the form's
+# constants: the fields below give, once a call, a vector of features that
+# no permutation changes, and every image's share of the key is its dot
+# product with that permutation's row of a host-built table.
+# ---------------------------------------------------------------------------
 
-    s_sh, s_w = mb._HI_FIELDS["src"]
-    d_sh, d_w = mb._HI_FIELDS["dst"]
-    keep = ~(((1 << s_w) - 1) << s_sh | ((1 << d_w) - 1) << d_sh)
-    hi = struct["msgHi"]
-    occupied = struct["msgCount"] > 0
-    new_hi = (hi & keep) \
-        | (p_lut[(hi >> s_sh) & ((1 << s_w) - 1)] << s_sh) \
-        | (p_lut[(hi >> d_sh) & ((1 << d_w) - 1)] << d_sh)
-    new_hi = xp.where(occupied, new_hi, hi)
+_BAG = ("msgHi", "msgLo", "msgCount")
+# fields a server permutation only moves -> how many of their leading axes
+# are server-indexed; a feature is the word itself
+_MOVED_AXES = {"role": 1, "term": 1, "commitIndex": 1, "logLen": 1,
+               "logTerm": 1, "logVal": 1, "nextIndex": 2, "matchIndex": 2}
+# fields whose words also name servers: votedFor by id (one feature a
+# value, its one-hot), the vote masks by bit (one feature a bit)
+_VOTE_MASKS = ("vResp", "vGrant")
+_RELABELLED = ("votedFor",) + _VOTE_MASKS
 
-    out = {
-        "role": rows(struct["role"]),
-        "term": rows(struct["term"]),
-        "votedFor": vf_map[rows(struct["votedFor"])],
-        "commitIndex": rows(struct["commitIndex"]),
-        "logLen": rows(struct["logLen"]),
-        "logTerm": rows(struct["logTerm"]),
-        "logVal": rows(struct["logVal"]),
-        "vResp": bit_lut[rows(struct["vResp"])],
-        "vGrant": bit_lut[rows(struct["vGrant"])],
-        "nextIndex": xp.take(rows(struct["nextIndex"]), inv, axis=2),
-        "matchIndex": xp.take(rows(struct["matchIndex"]), inv, axis=2),
-        "msgHi": new_hi,
-        "msgLo": struct["msgLo"],
-        "msgCount": struct["msgCount"],
-    }
-    if "eTerm" in struct:
-        eocc = struct["eTerm"] > 0
-        out.update({
-            "allLogs": struct["allLogs"],
-            "vLog": xp.take(rows(struct["vLog"]), inv, axis=2),
-            "eTerm": struct["eTerm"],
-            "eLeader": xp.where(eocc, p_lut[struct["eLeader"]],
-                                struct["eLeader"]),
-            "eLog": struct["eLog"],
-            "eVotes": xp.where(eocc, bit_lut[struct["eVotes"]],
-                               struct["eVotes"]),
-            "eVLog": xp.take(struct["eVLog"], inv, axis=2),
-        })
+
+def _linear_fields(axes: tuple) -> tuple:
+    """The fields whose share of an image's key is ``features . table``:
+    every per-server parity field, less ``logVal`` where a value
+    permutation relabels its contents (it keeps the data-moving path,
+    with the faithful-mode history; the bag is ranked, :func:`_bag_sums`)."""
+    return tuple(f for f in st.STATE_FIELDS
+                 if (f in _MOVED_AXES or f in _RELABELLED)
+                 and not (f == "logVal" and "Value" in axes))
+
+
+def _key_features(struct: dict, fields: tuple, xp):
+    """``struct[N, ...] -> uint8[F, N]``: the permutation-independent
+    features of ``fields``, in :func:`_key_table`'s order, lanes minor (a
+    ``[N, small]`` array is kept padded to 128 lanes on the TPU).  Every
+    entry is below 2^8 — terms, indices, values and server ids are capped
+    at 63 by ``config.Bounds``, the rest are bits — so the fold
+    (``x ^ (x >> 16)``) is the identity on all of them and a byte holds
+    each: the scan reads this array once a group element."""
+    n = struct["role"].shape[1]
+    N = struct["role"].shape[0]
+    parts = []
+    for f in fields:
+        a = struct[f]
+        if f == "votedFor":
+            a = a[:, :, None] == xp.arange(1, n + 1)      # [N, j, id - 1]
+        elif f in _VOTE_MASKS:
+            a = (a[:, :, None] >> xp.arange(n)) & 1       # [N, j, bit]
+        a = xp.reshape(a, (N, -1))
+        parts.append(a.astype(xp.uint8))    # 6-bit: Bounds caps them at 63
+    return xp.concatenate(parts, axis=1).T
+
+
+def _key_table(bounds: Bounds, consts, fields: tuple,
+               perms: tuple) -> np.ndarray:
+    """``uint32[len(perms), 2, F]``: for each server permutation ``p`` the
+    two lanes' constants of :func:`_key_features`' entries, such that
+    ``features . table[p]`` (mod 2^32) is what ``fields`` add to the sum
+    before the finaliser of ``fingerprint(pack(permute_struct(s, p)))``.
+    With ``c`` a field's slice of the lane constants (``state.pack``'s
+    order) and the image's row ``p[j]`` holding old row ``j``:
+
+    - moved only: ``c[p[j]]`` (``c[p[i], p[j]]`` for the index matrices);
+    - ``votedFor`` = id: ``c[p[j]] * (p[id - 1] + 1)`` — the relabelled
+      id, which the fold leaves alone;
+    - vote masks, bit ``b``: ``c[p[j]] << p[b]`` — distinct bits, so the
+      relabelled mask is their sum.
+    """
+    fc = fpr.field_constants(st.Layout.of(bounds).shapes, consts)
+    table = []
+    for p in perms:
+        p = np.asarray(p)
+        row = []
+        for f in fields:
+            cf = fc[f][:, p]
+            if _MOVED_AXES.get(f) == 2:
+                cf = cf[:, :, p]
+            elif f == "votedFor":
+                cf = cf[:, :, None] * (p + 1).astype(np.uint32)
+            elif f in _VOTE_MASKS:
+                cf = cf[:, :, None] << p.astype(np.uint32)
+            row.append(cf.reshape(2, -1))
+        table.append(np.concatenate(row, axis=1))
+    return np.stack(table)
+
+
+def _linear_sums(phi, row, xp):
+    """``phi uint8[F, N]`` (:func:`_key_features`) times one permutation's
+    ``row uint32[2, F]`` of :func:`_key_table` -> the lanes' two sums."""
+    with np.errstate(over="ignore"):
+        w = phi.astype(xp.uint32)
+        return (xp.sum(w * row[0][:, None], axis=0, dtype=xp.uint32),
+                xp.sum(w * row[1][:, None], axis=0, dtype=xp.uint32))
+
+
+def _relabel_hi(hi, src_row, dst_row, xp):
+    """Message hi words with ``src`` / ``dst`` mapped through one server
+    permutation, given as its rows of ``_server_luts``' ``src`` / ``dst``
+    (``p[j]`` shifted to the field).  Same bits as ``permute_struct`` on
+    an occupied slot; an empty slot's word is not kept (the caller drops
+    it)."""
+    (s_sh, s_w), (d_sh, d_w) = mb.HI_FIELDS["src"], mb.HI_FIELDS["dst"]
+    s_mask, d_mask = (1 << s_w) - 1, (1 << d_w) - 1
+    src, dst = (hi >> s_sh) & s_mask, (hi >> d_sh) & d_mask
+    out = hi & ~(s_mask << s_sh | d_mask << d_sh)
+    for j in range(src_row.shape[0]):
+        out = out | xp.where(src == j, src_row[j], 0) \
+            | xp.where(dst == j, dst_row[j], 0)
     return out
 
 
-def _permute_values_batch(struct: dict, luts: dict, qi, bounds: Bounds, xp):
-    """``permute_values`` over a leading batch axis with traced LUT rows."""
-    vlut = luts["vlut"][qi]
-    e_lut = luts["e_lut"][qi]
-    e_sh, e_w = mb._LO_FIELDS["e"]
-    lo = struct["msgLo"]
-    out = dict(struct)
-    out["logVal"] = vlut[struct["logVal"]]
-    new_lo = (lo & ~(((1 << e_w) - 1) << e_sh)) \
-        | (e_lut[(lo >> e_sh) & ((1 << e_w) - 1)] << e_sh)
-    if "allLogs" in struct:
-        rmap = luts["rmap"][qi]
-        rlut1 = luts["rlut1"][qi]
-        g_lut = luts["g_lut"][qi]
+def _relabel_lo(lo, luts: dict, xp):
+    """Message lo words with the entry value (field ``e``) and, in
+    faithful mode, the ``mlog`` rank (field ``g``) mapped through one
+    value permutation, given as its rows of :func:`_value_luts`.  Same
+    bits as ``permute_values`` on an occupied slot; an empty slot's word
+    is not kept (the caller drops it)."""
+    e_sh, e_w = mb.LO_FIELDS["e"]
+    lo = (lo & ~(((1 << e_w) - 1) << e_sh)) \
+        | (luts["e_lut"][(lo >> e_sh) & ((1 << e_w) - 1)] << e_sh)
+    if "g_lut" in luts:
+        g_sh, g_w = mb.LO_FIELDS["g"]
+        lo = (lo & ~(((1 << g_w) - 1) << g_sh)) \
+            | (luts["g_lut"][(lo >> g_sh) & ((1 << g_w) - 1)] << g_sh)
+    return lo
+
+
+def _bag_sums(hi, lo, ct, cbag, xp):
+    """What the message bag adds to the lanes' sums before the finaliser,
+    **ranked, not sorted**.  ``hi``, ``lo``, ``ct``: a slot a sequence
+    element, each ``int32[N]``: the words of an image's slots before
+    ``state.canonicalize`` would sort them (every slot a vector over the
+    lanes of its own: all of this is elementwise and fuses); ``cbag``:
+    ``uint32[2, 3, S]``, the lane constants of ``msgHi`` / ``msgLo`` /
+    ``msgCount``.  ``canonicalize`` zeroes an empty slot, which then adds
+    0 wherever it lands, and sorts the occupied ones first by (hi, lo):
+    an occupied slot lands at its rank among the occupied, and takes that
+    position's constants.  The comparison is ``_network_sort``'s own
+    (``<=`` on int32, the earlier slot first on a tie), so the rank is the
+    network's position even for slots it could not tell apart."""
+    S = len(hi)
+    occ = [c > 0 for c in ct]
+    rank = [0] * S
+    for s in range(S):
+        for t in range(s + 1, S):
+            le = (hi[s] < hi[t]) | ((hi[s] == hi[t]) & (lo[s] <= lo[t]))
+            rank[t] = rank[t] + (occ[s] & le).astype(xp.int32)
+            rank[s] = rank[s] + (occ[t] & ~le).astype(xp.int32)
+    s1 = s2 = xp.uint32(0)
+    with np.errstate(over="ignore"):
+        for s in range(S):
+            at = [rank[s] == r for r in range(S - 1)]
+            t1 = t2 = xp.uint32(0)
+            for a, c in zip((hi, lo, ct), np.moveaxis(cbag, 1, 0)):
+                c1, c2 = c[0, S - 1], c[1, S - 1]
+                for r, here in enumerate(at):
+                    c1 = xp.where(here, c[0, r], c1)
+                    c2 = xp.where(here, c[1, r], c2)
+                w = fpr.fold(a[s], xp)
+                t1, t2 = t1 + w * c1, t2 + w * c2
+            s1 = s1 + xp.where(occ[s], t1, xp.uint32(0))
+            s2 = s2 + xp.where(occ[s], t2, xp.uint32(0))
+    return s1, s2
+
+
+def _permute_struct_batch(struct: dict, fields: tuple, luts: dict, xp):
+    """The images of ``fields`` under one server permutation, over a
+    leading batch axis, the permutation given as its traced rows of
+    :func:`_server_luts` (``permute_struct``'s arithmetic, same bits).
+    Only what the scan's linear key does not cover comes through here:
+    ``logVal`` on its way to a value relabel, and the faithful-mode
+    history, where log ranks hold no server ids (``allLogs``, ``eTerm``,
+    ``eLog`` are fixed points)."""
+    inv, bit_lut, p_lut = luts["inv"], luts["bit_lut"], luts["p_lut"]
+
+    def rows(a):
+        return xp.take(a, inv, axis=1)
+
+    image = {
+        "logVal": lambda: rows(struct["logVal"]),
+        "vLog": lambda: xp.take(rows(struct["vLog"]), inv, axis=2),
+        "eLeader": lambda: xp.where(struct["eTerm"] > 0,
+                                    p_lut[struct["eLeader"]],
+                                    struct["eLeader"]),
+        "eVotes": lambda: xp.where(struct["eTerm"] > 0,
+                                   bit_lut[struct["eVotes"]],
+                                   struct["eVotes"]),
+        "eVLog": lambda: xp.take(struct["eVLog"], inv, axis=2),
+    }
+    return {f: image[f]() if f in image else struct[f] for f in fields}
+
+
+def _permute_values_batch(struct: dict, fields: tuple, luts: dict, xp):
+    """The images of ``fields`` under one value permutation, over a
+    leading batch axis, the permutation given as its traced rows of
+    :func:`_value_luts` (``permute_values``' arithmetic, same bits).
+    Fields a value permutation leaves alone pass through."""
+    def all_logs():
+        # bit r of the old mask becomes bit rmap[r] of the new one (the
+        # sum-as-OR trick of permute_values; the sign bit is OR'd in
+        # separately — no x64 under jit)
+        rmap = luts["rmap"]
         U = int(rmap.shape[0])
-        out["vLog"] = rlut1[struct["vLog"]]
-        out["eLog"] = rmap[struct["eLog"]]
-        out["eVLog"] = rlut1[struct["eVLog"]]
-        g_sh, g_w = mb._LO_FIELDS["g"]
-        new_lo = (new_lo & ~(((1 << g_w) - 1) << g_sh)) \
-            | (g_lut[(new_lo >> g_sh) & ((1 << g_w) - 1)] << g_sh)
-        # allLogs bit-permute, batched (same sum-as-OR trick as
-        # permute_values; sign bit handled separately — no x64 under jit)
         rs = np.arange(U)
         Wa = struct["allLogs"].shape[1]
         bits = (struct["allLogs"][:, rs // 32] >> (rs % 32)) & 1   # [N, U]
@@ -341,55 +471,100 @@ def _permute_values_batch(struct: dict, luts: dict, qi, bounds: Bounds, xp):
             xp.asarray(1, xp.int32) << tb, 0).sum(axis=2)
         top = (in_word[None] & (tb == 31)[None, None]
                & (bits[:, None, :] > 0)).any(axis=2)
-        out["allLogs"] = (low.astype(xp.int32)
-                          | xp.where(top, xp.asarray(-2**31, xp.int32), 0))
-    occupied = struct["msgCount"] > 0
-    out["msgLo"] = xp.where(occupied, new_lo, struct["msgLo"])
-    return out
+        return (low.astype(xp.int32)
+                | xp.where(top, xp.asarray(-2**31, xp.int32), 0))
+
+    image = {
+        "logVal": lambda: luts["vlut"][struct["logVal"]],
+        "allLogs": all_logs,
+        "vLog": lambda: luts["rlut1"][struct["vLog"]],
+        "eLog": lambda: luts["rmap"][struct["eLog"]],
+        "eVLog": lambda: luts["rlut1"][struct["eVLog"]],
+    }
+    return {f: image[f]() if f in image else struct[f] for f in fields}
 
 
 def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     """Batched orbit-minimal fingerprints: ``struct[N, ...] -> (hi, lo)[N]``.
 
-    Bit-identical to :func:`orbit_fingerprint` (same permute/canonicalize
-    arithmetic, and the key of each image taken from its fields,
-    ``fingerprint_fields``, which equals the fingerprint of the packed
-    row the loop builds; the (hi, lo) lexicographic min is
-    order-independent) but compiled as ONE transform iterated by
-    ``lax.scan`` over the |G| = n!·V! group elements, instead of |G|
-    unrolled copies of the pipeline.  The round-1 unrolled graph at five
-    servers (120 copies) crashed compiles at chunk 2048 and capped the
-    elect5 run at ~3k orbits/s; the scan keeps the program size constant
-    in |G| so large chunks compile and the VPU sees one tight loop.
+    Bit-identical to :func:`orbit_fingerprint` (the loop that permutes,
+    canonicalises, packs and fingerprints each image; the (hi, lo)
+    lexicographic min is order-independent), compiled as ONE body
+    iterated by ``lax.scan`` over the |G| = n!·V! group elements — and
+    the body **moves no state data**.  The sum before the finaliser is a
+    sum of per-field sums (``fingerprint.field_sums``), and each field
+    takes the form its image allows, chosen here, statically, from
+    ``axes`` and ``faithful``:
+
+    - the per-server fields (:func:`_linear_fields`): one vector of
+      features built **once a call, outside the scan**
+      (:func:`_key_features`), times row ``p`` of a host-built table of
+      permuted constants (:func:`_key_table`): a slice and one
+      multiply-reduce an image, no gather, no relabel;
+    - the message bag: ``src`` / ``dst`` relabelled (the fold is not
+      linear in them), then ranked, not sorted (:func:`_bag_sums`);
+    - what neither covers — ``logVal`` under Value symmetry, the
+      faithful-mode history with its ``elections`` sort — is moved and
+      canonicalised as the loop does, for those fields alone.
+
+    The round-1 unrolled graph at five servers (120 copies) crashed
+    compiles at chunk 2048 and capped the elect5 run at ~3k orbits/s;
+    the scan keeps the program size constant in |G|.  What an image
+    costs on the chip, and what it cost while the body regathered,
+    relabelled and re-sorted the state 120 times a step: PERF.md, PR 29.
     """
     import jax
     import jax.numpy as jnp
 
-    sluts = tuple(jnp.asarray(a) for a in _server_luts(bounds)) \
-        if "Server" in axes else None
+    server, value = "Server" in axes, "Value" in axes
+    lay = st.Layout.of(bounds)
+    n = lay.n
+    perms = permutations(bounds) if server else (tuple(range(n)),)
+    P = len(perms)
+    Q = len(value_permutations(bounds)) if value else 1
+    linear = _linear_fields(axes)
+    moved = tuple(f for f in lay.fields
+                  if f not in linear and f not in _BAG)
+    table = jnp.asarray(_key_table(bounds, consts, linear, perms))
+    sluts = {k: jnp.asarray(v) for k, v in _server_luts(bounds).items()} \
+        if server else None
     vluts = {k: jnp.asarray(v)
              for k, v in _value_luts(bounds, faithful).items()} \
-        if "Value" in axes else None
-    P = len(permutations(bounds)) if "Server" in axes else 1
-    Q = len(value_permutations(bounds)) if "Value" in axes else 1
-
-    def canon_fp(s):
-        # the key of one group element's image, from its fields: the
-        # scan body never builds the packed row (a concatenate and two
-        # relayouts through HBM every iteration; PERF.md, PR 27)
-        return fpr.fingerprint_fields(st.canonicalize(s, jnp), consts, jnp)
+        if value else None
+    fc = fpr.field_constants(lay.shapes, consts)
+    cbag = np.stack([fc[f] for f in _BAG], axis=1)       # [2, 3, S]
 
     def orbit_fp(struct):
+        # what no group element changes, once a call: the features of
+        # the linear fields and the bag slot-major, a vector a slot
+        phi = _key_features(struct, linear, jnp)
+        hi0, lo0, ct = ([struct[f][:, s] for s in range(lay.S)]
+                        for f in _BAG)
+
         def body(best, k):
             pi, qi = k // Q, k % Q
-            t = struct
-            if sluts is not None:
-                inv_idx, vf_map, bit_lut, p_lut = sluts
-                t = _permute_struct_batch(t, inv_idx[pi], vf_map[pi],
-                                          bit_lut[pi], p_lut[pi], jnp)
-            if vluts is not None:
-                t = _permute_values_batch(t, vluts, qi, bounds, jnp)
-            hi, lo = jax.vmap(canon_fp)(t)
+            s1, s2 = _linear_sums(phi, table[pi], jnp)
+            hi, lo = hi0, lo0
+            if server:
+                hi = [_relabel_hi(w, sluts["src"][pi], sluts["dst"][pi],
+                                  jnp) for w in hi]
+            if value:
+                vl = {f: a[qi] for f, a in vluts.items()}
+                lo = [_relabel_lo(w, vl, jnp) for w in lo]
+            b1, b2 = _bag_sums(hi, lo, ct, cbag, jnp)
+            s1, s2 = s1 + b1, s2 + b2
+            if moved:
+                t = struct
+                if server:
+                    sl = {f: a[pi] for f, a in sluts.items()}
+                    t = {**t, **_permute_struct_batch(t, moved, sl, jnp)}
+                if value:
+                    t = {**t, **_permute_values_batch(t, moved, vl, jnp)}
+                if faithful:     # the elections sort (its bag sort is dead)
+                    t = jax.vmap(lambda s: st.canonicalize(s, jnp))(t)
+                m1, m2 = fpr.field_sums(t, consts, jnp, moved)
+                s1, s2 = s1 + m1, s2 + m2
+            hi, lo = fpr.finalise(s1, s2, jnp)
             bh, bl = best
             take = (hi < bh) | ((hi == bh) & (lo < bl))
             return (jnp.where(take, hi, bh), jnp.where(take, lo, bl)), None

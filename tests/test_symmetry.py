@@ -15,6 +15,7 @@ from raft_tla_tpu.config import Bounds, CheckConfig
 from raft_tla_tpu.device_engine import Capacities, DeviceEngine
 from raft_tla_tpu.models import interp, refbfs, spec as S
 from raft_tla_tpu.ops import msgbits as mb
+from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym
 
 B2 = Bounds(n_servers=2, n_values=1, max_term=2, max_log=0, max_msgs=2)
@@ -241,7 +242,57 @@ def _all_distinct_state():
         role=(0, 1, 2), term=(1, 2, 2), votedFor=(0, 2, 3))
 
 
-# name -> (bounds, axes, VIEW or None, states, at least this many)
+def _distinct5():
+    """Five servers no two of which are interchangeable, empty bag."""
+    return interp.init_state(_FULL5)._replace(
+        role=(0, 1, 2, 0, 1), term=(1, 2, 2, 3, 1), votedFor=(0, 2, 3, 0, 5))
+
+
+def _bag_states():
+    """Bags the scan has to rank as ``canonicalize`` sorts them: three
+    occupied slots whose (dst, src) order a permutation changes; two
+    slots equal in ``hi`` that differ in ``lo`` alone (the same
+    AppendEntriesRequest but for its entry); a multiplicity of 2 beside a
+    1; one message; none."""
+    rv, ae = mb.rv_request, mb.ae_request
+    bags = [
+        bag(rv(2, 0, 0, 0, 4), rv(2, 0, 0, 3, 1), rv(2, 0, 0, 2, 2)),
+        bag(ae(2, 0, 0, 1, 1, 1, 0, 1, 3), ae(2, 0, 0, 1, 2, 2, 0, 1, 3),
+            rv(1, 0, 0, 4, 0)),
+        tuple(sorted([(rv(2, 0, 0, 0, 1), 2), (rv(1, 0, 0, 4, 2), 1)])),
+        bag(mb.rv_response(2, 1, 3, 0)),
+        (),
+    ]
+    ae_hi = [hi for (hi, _lo), _c in bags[1] if mb.mtype(hi) == 3]
+    assert len(ae_hi) == 2 and len(set(ae_hi)) == 1     # equal hi words
+    return [_distinct5()._replace(msgs=b) for b in bags]
+
+
+def _stale_slot_vecs():
+    """Packed rows no ``to_vec`` writes: an EMPTY slot (``msgCount`` 0)
+    that still holds content words, as a kernel that counts a message
+    down to 0 may leave it — in front of, between and behind the
+    occupied slots.  ``canonicalize`` zeroes it before it sorts; the
+    scan must drop it from its ranking.  Row 0 is the clean state."""
+    lay = st.Layout.of(_FULL5)
+    rv = mb.rv_request
+    clean = interp.to_vec(_distinct5()._replace(
+        msgs=bag(rv(2, 0, 0, 0, 4), rv(2, 0, 0, 3, 1))), _FULL5)
+    (h0, l0), (h1, l1) = sorted([rv(2, 0, 0, 0, 4), rv(2, 0, 0, 3, 1)])
+    stale_hi, stale_lo = rv(2, 0, 0, 2, 2)[0], 0x155
+    vecs = [clean]
+    for slots in ([(stale_hi, stale_lo, 0), (h0, l0, 1), (h1, l1, 1)],
+                  [(h0, l0, 1), (stale_hi, stale_lo, 0), (h1, l1, 1)],
+                  [(h0, l0, 1), (h1, l1, 1), (stale_hi, stale_lo, 0)]):
+        t = st.unpack(clean, lay, np)
+        t["msgHi"], t["msgLo"], t["msgCount"] = (
+            np.asarray(w, np.int32) for w in zip(*slots))
+        vecs.append(st.pack(t, np))
+    return np.stack(vecs)
+
+
+# name -> (bounds, axes, VIEW or None, states (or packed rows), at least
+# this many)
 _SCAN_CASES = {
     "3s-server": (_B3S, ("Server",), None,
                   lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
@@ -279,6 +330,14 @@ _SCAN_CASES = {
     # folds) are rare in a BFS prefix
     "3s-view-server": (_B3S, ("Server",), "deadvotes",
                        lambda: _random_states(_B3S, 120, seed=28), 120),
+    # the bag, which the scan ranks and the loop sorts (PR 29)
+    "full5-bags": (_FULL5, ("Server",), None, _bag_states, 5),
+    "full5-stale-slots": (_FULL5, ("Server",), None, _stale_slot_vecs, 4),
+    "full5-random": (_FULL5, ("Server",), None,
+                     lambda: _random_states(_FULL5, 60, seed=29), 60),
+    "3s-random-server-value": (
+        _B3S, ("Server", "Value"), None,
+        lambda: _random_states(_B3S, 60, seed=30), 60),
 }
 
 
@@ -302,7 +361,8 @@ def test_scan_orbit_fp_bit_identical_to_loop(case):
     consts = fpr.lane_constants(lay.width)
     seen = make()
     assert len(seen) >= at_least, len(seen)
-    vecs = np.stack([interp.to_vec(s, bounds) for s in seen])
+    vecs = seen if isinstance(seen, np.ndarray) \
+        else np.stack([interp.to_vec(s, bounds) for s in seen])
     structs = jax.vmap(lambda v: st.unpack(v, lay, jnp))(jnp.asarray(vecs))
     if view:
         structs = jax.vmap(views.jnp_view(view, bounds))(structs)
@@ -323,6 +383,65 @@ def test_scan_orbit_fp_bit_identical_to_loop(case):
         # the case can see: the identity's image is not the orbit's min
         ih, il = fpr.fingerprint(vecs, consts, np)
         assert (int(ih[0]), int(il[0])) != (int(hi_s[0]), int(lo_s[0]))
+    if case == "full5-stale-slots":
+        # a stale slot is no message: every row keys as the clean one
+        assert len({(int(h), int(l)) for h, l in zip(hi_s, lo_s)}) == 1
+
+
+@pytest.mark.parametrize("name, bounds, n_perms", [
+    ("3s", _B3S, None), ("elect5", _ELECT5, 20), ("full5", _FULL5, 20)])
+def test_key_table_and_ranked_bag_equal_the_permuted_packed_row(
+        name, bounds, n_perms):
+    """The algebra of the scan's body, in NumPy alone (PR 29): for a
+    server permutation ``p``, ``features(s) . table[p]`` plus the ranked
+    bag's sum, finalised, is
+    ``fingerprint(pack(canonicalize(permute_struct(s, p))))`` on both
+    lanes — no field of ``s`` moved.  Every ``p`` at 3 servers, a sample
+    at 5 (the identity and the reversal among them), on random bounded
+    states: ``votedFor`` set, vote masks non-empty, index matrices and
+    logs non-trivial, bags of up to three slots."""
+    from raft_tla_tpu.ops import fingerprint as fpr
+
+    lay = st.Layout.of(bounds)
+    consts = fpr.lane_constants(lay.width)
+    vecs = np.stack([interp.to_vec(s, bounds)
+                     for s in _random_states(bounds, 40, seed=len(name))])
+    batch = st.unpack(vecs, lay, np)
+    assert (batch["votedFor"] > 0).any() and (batch["vGrant"] > 0).any()
+    assert ((batch["msgCount"] > 0).sum(axis=1) == lay.S).any()
+    assert len(np.unique(batch["nextIndex"])) > 1
+    perms = sym.permutations(bounds)
+    if n_perms is None:
+        picked = range(len(perms))
+    else:
+        rng = np.random.default_rng(29)
+        picked = sorted({0, len(perms) - 1,
+                         *rng.choice(len(perms), n_perms, replace=False)})
+    assert perms[0] == tuple(range(bounds.n_servers))
+    assert perms[-1] == tuple(reversed(range(bounds.n_servers)))
+    fields = sym._linear_fields(("Server",))
+    phi = sym._key_features(batch, fields, np)
+    assert phi.dtype == np.uint8 and phi.shape[1] == len(vecs)
+    table = sym._key_table(bounds, consts, fields,
+                           tuple(perms[i] for i in picked))
+    assert table.shape == (len(picked), 2, phi.shape[0])
+    luts = sym._server_luts(bounds)
+    fc = fpr.field_constants(lay.shapes, consts)
+    cbag = np.stack([fc[f] for f in sym._BAG], axis=1)
+    slots = {f: [batch[f][:, s] for s in range(lay.S)] for f in sym._BAG}
+    for row, i in zip(table, picked):
+        s1, s2 = sym._linear_sums(phi, row, np)
+        hi = [sym._relabel_hi(w, luts["src"][i], luts["dst"][i], np)
+              for w in slots["msgHi"]]
+        b1, b2 = sym._bag_sums(hi, slots["msgLo"], slots["msgCount"], cbag,
+                               np)
+        got = fpr.finalise(s1 + b1, s2 + b2, np)
+        for k in range(len(vecs)):
+            image = st.canonicalize(sym.permute_struct(
+                st.unpack(vecs[k], lay, np), perms[i], bounds, np), np)
+            want = fpr.fingerprint(st.pack(image, np), consts, np)
+            assert (int(got[0][k]), int(got[1][k])) \
+                == (int(want[0]), int(want[1])), (perms[i], k)
 
 
 def test_scan_body_builds_no_packed_row():
@@ -352,3 +471,50 @@ def test_scan_body_builds_no_packed_row():
         packed = jax.jit(jax.vmap(lambda s: st.pack(s, jnp))) \
             .lower(struct).as_text()
         assert re.search(r"stablehlo\.concatenate.*" + row.pattern, packed)
+
+
+def test_scan_moves_no_state_data():
+    """Nor may the state move (PR 29): lowered at 5 servers under Server
+    symmetry, the orbit scan — its ``stablehlo.while`` body and what is
+    hoisted in front of it — holds no ``gather`` (the parent regathered
+    every ``[lanes, n]`` / ``[lanes, n, n]`` field by the inverse
+    permutation, once an image), no ``scatter`` /
+    ``dynamic_update_slice`` and no ``sort`` (the message sort network's
+    ``.at[..., i].set`` lowers to scatters, 21 ``dynamic-update-slice``
+    an image in the chip's program): each image's key is a slice of the
+    table of permuted constants, one multiply-reduce and a ranking of
+    the bag.  The forms that do move
+    data show all of it (the test can see): ``canonicalize`` lowered
+    alone, and the same scan under Value symmetry, where ``logVal`` keeps
+    the data-moving path."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from raft_tla_tpu.ops import fingerprint as fpr
+
+    lanes = 24
+    for bounds in (_ELECT5, _FULL5):
+        lay = st.Layout.of(bounds)
+        n, L = lay.n, lay.L
+        consts = jnp.asarray(fpr.lane_constants(lay.width))
+        struct = {f: jax.ShapeDtypeStruct((lanes,) + tuple(shape), jnp.int32)
+                  for f, shape in lay.shapes.items()}
+        text = jax.jit(sym.build_orbit_fp(bounds, ("Server",), consts,
+                                          False)).lower(struct).as_text()
+        assert "stablehlo.while" in text
+        for op in ("gather", "dynamic_update_slice", "stablehlo.sort",
+                   "scatter"):
+            assert op not in text, op
+        # the one array that follows the lanes into the body is the
+        # byte-wide feature matrix, lanes minor
+        F = 4 * n + 2 * n * L + 5 * n * n
+        assert f"tensor<{F}x{lanes}xui8>" in text
+        moved = jax.jit(jax.vmap(lambda s: st.canonicalize(s, jnp))) \
+            .lower(struct).as_text()
+        assert "stablehlo.scatter" in moved
+        valued = jax.jit(sym.build_orbit_fp(
+            bounds, ("Server", "Value"), consts, False)) \
+            .lower(struct).as_text()
+        assert re.search(
+            rf"stablehlo\.gather.*tensor<{lanes}x{n}x{L}xi32>", valued)
