@@ -41,8 +41,8 @@ class _AdmitAll:
     """A host key set that forgets: every streamed candidate is 'new', so
     the only dedup left is the device's lossy filter."""
 
-    def __init__(self):
-        self.n = 0
+    def __init__(self, n: int = 0):
+        self.n = n
 
     def __len__(self):
         return self.n
@@ -59,14 +59,16 @@ class _AdmitAll:
 @contextlib.contextmanager
 def filter_only_dedup():
     """Guarantee broken: the device filter only advises.  The exact host
-    key set is replaced by one that admits everything it is shown."""
+    key set is replaced by one that admits everything it is shown, whether
+    a pass builds it empty or rebuilds it from a snapshot's keys."""
     from raft_tla_tpu.utils import keyset
-    real = keyset.new_master
+    real = keyset.new_master, keyset.master_from_keys
     keyset.new_master = lambda *a, **kw: _AdmitAll()
+    keyset.master_from_keys = lambda keys, *a, **kw: _AdmitAll(len(keys))
     try:
         yield
     finally:
-        keyset.new_master = real
+        keyset.new_master, keyset.master_from_keys = real
 
 
 @contextlib.contextmanager

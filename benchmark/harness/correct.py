@@ -41,6 +41,26 @@ def pass_checks(pass_list: list, pins: list, end_level: int) -> list:
     ]
 
 
+def snapshot_checks(snapshot: dict, pass_list: list, pins: list,
+                    end_level: int) -> list:
+    """A traffic that starts at depth: the pass that wrote the snapshot met
+    every pin 0..S; the snapshot holds the pin at S and whatever its stop
+    overshot it by, at most 2 % of a pass's orbits; and every resumed
+    engine's first count (its ``run_start``) is the count the snapshot was
+    written with.  Levels 0..S of a resumed pass's table come out of the
+    snapshot, so check (a) holds the snapshot itself to the pins."""
+    s = snapshot["level"]
+    first = [p.start_keys for p in pass_list]
+    return [
+        ("snapshot_pass_problems", int(snapshot["problem"] is not None), 0),
+        ("snapshot_overshoot_orbits", abs(snapshot["keys"] - pins[s]),
+         (pins[end_level] - pins[s]) // 50),
+        ("snapshot_keys_diff",
+         sum(snapshot["keys"] if k is None else abs(k - snapshot["keys"])
+             for k in first), 0),
+    ]
+
+
 def reference_sample(cfg: dict, seed: int):
     """The plain reference's own BFS of the first levels, and the seeded
     sample of its deepest level with that sample's successor orbits."""

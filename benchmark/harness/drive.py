@@ -5,8 +5,10 @@ building the engine a cell names, driving passes through its public
 from __future__ import annotations
 
 import contextlib
+import json
 import os
 import shutil
+import threading
 import time
 
 from benchmark.harness import correct
@@ -79,6 +81,58 @@ def build_engine(cfg: dict):
                      DDDCapacities(**cfg["engine_caps"]["ddd"]))
 
 
+class CaptureCloser(threading.Thread):
+    """Bounds a resumed pass's traced window by chunk steps.  The program
+    writes a ``segment`` span to the pass's event log as it harvests each
+    segment; this thread tails the log and, at the segment that brings the
+    steps since the resume to ``steps``, stamps that segment's harvest as the
+    window's end and stops the profiler (no progress record arrives inside a
+    level at depth, so the pass's own thread has no place to do it)."""
+
+    def __init__(self, p: passes.Pass, steps: int, stop_trace):
+        super().__init__(name="bench-capture-closer", daemon=True)
+        self.p, self.steps, self.stop_trace = p, steps, stop_trace
+        self._over = threading.Event()
+        self.error = None
+
+    def run(self) -> None:
+        seen, tail, pos = 0, "", 0
+        while self.p.t_trace_end is None:
+            over = self._over.is_set()      # read once more after the pass
+            if os.path.exists(self.p.events):
+                with open(self.p.events, encoding="utf-8") as f:
+                    f.seek(pos)
+                    tail += f.read()
+                    pos = f.tell()
+            *lines, tail = tail.split("\n")
+            for line in lines:
+                if '"segment"' not in line:
+                    continue
+                ev = json.loads(line)
+                if ev.get("event") == "span" and ev["name"] == "segment" \
+                        and not ev["args"].get("dropped"):
+                    seen += ev["args"]["steps"]
+                    if seen >= self.steps:
+                        self.p.t_trace_end = ev["t0"] + ev["dur"]
+                        try:
+                            self.stop_trace()
+                        except RuntimeError as e:
+                            self.error = e
+                        return
+            if over:
+                return
+            self._over.wait(0.01)
+
+    def finish(self) -> None:
+        """After the pass: let the thread see the whole log, then wait."""
+        self._over.set()
+        self.join(timeout=120.0)
+        if self.is_alive():
+            raise SystemExit("benchmark: the capture never closed")
+        if self.error is not None:
+            raise self.error
+
+
 class Driver:
     """One engine object, many passes over one pinned span."""
 
@@ -89,39 +143,98 @@ class Driver:
         self.pins = self.cfg["level_pins"]
         t = self.traffic
         self.a, self.b = t["start_level"], t["end_level"]
+        # where a pass starts: Init, or the run's own snapshot of level S
+        start = t.get("start", "init")
+        self.snapshot_level = None if start == "init" \
+            else start["snapshot_level"]
+        if self.snapshot_level not in (None, self.a):
+            raise ValueError(
+                f"traffic {cell['traffic']}: the span of a resumed pass "
+                f"starts at its snapshot, level {self.snapshot_level}, "
+                f"not at start_level {self.a}")
+        if self.snapshot_level is not None \
+                and self.cfg["engine_caps"]["ddd"].get(
+                    "retention", "full") != "full":
+            # frontier retention resumes in place: a resumed pass would
+            # write its own stop over the snapshot the next one needs
+            raise ValueError(
+                f"traffic {cell['traffic']} resumes every pass from one "
+                f"snapshot; configuration {self.cfg['name']} keeps its rows "
+                "in level files that a resume rewrites (retention is not "
+                "'full'): it would need one copy a pass")
+        self.snapshot = None            # set by build_snapshot()
         count_a, count_b = self.pins[self.a], self.pins[self.b]
         if (t["count_at_start"], t["count_at_end"]) != (count_a, count_b):
             raise ValueError(
                 f"traffic {cell['traffic']} pins {t['count_at_start']}/"
                 f"{t['count_at_end']}, configuration {self.cfg['name']} has "
                 f"{count_a}/{count_b} at levels {self.a}/{self.b}")
-        self.orbits = count_b - count_a
+        self.orbits = count_b - count_a     # admitted in the clocked span
+        self.pass_orbits = count_b          # ... and in a whole pass
         self.scratch = scratch
         self.compiles = CompileCounter()
         self.engine = build_engine(self.cfg)
         self._seg_chunks0 = self.engine.seg_chunks
         self.made = 0
 
+    def build_snapshot(self) -> passes.Pass | None:
+        """Set-up of a traffic with ``start.snapshot_level`` S: one pass
+        from Init on the run's engine object through the public
+        ``check(checkpoint=, checkpoint_every_s=inf)``, stopped at the
+        boundary record of level S whose count equals the pin, so that the
+        engine's own lossless stop writes the snapshot.  Built in every run
+        (a kept one would be another commit's work).  What a resumed pass
+        admits is the pin at B less the keys the snapshot holds, as the
+        stopped pass's result counts them; never assumed to be the pin."""
+        if self.snapshot_level is None:
+            return None
+        snap_dir = os.path.join(self.scratch, "snap")
+        os.makedirs(snap_dir)
+        path = os.path.join(snap_dir, "run")
+        p = self.run_pass(end_level=self.snapshot_level, start_level=1,
+                          checkpoint=path, checkpoint_every_s=float("inf"))
+        self.snapshot = {
+            "path": path, "level": self.snapshot_level, "keys": p.n_states,
+            "build_s": p.t_return - p.t_call, "problem": p.problem,
+            "bytes": sum(f.stat().st_size for f in os.scandir(snap_dir))}
+        self.orbits = self.pass_orbits = self.pins[self.b] - p.n_states
+        return p
+
+    def timed_pass(self, trace: bool = False) -> passes.Pass:
+        """One pass of the window: from Init, or resumed from the snapshot."""
+        if self.snapshot is None:
+            return self.run_pass(trace=trace)
+        return self.run_pass(trace=trace, resume=self.snapshot["path"])
+
     def run_pass(self, end_level: int | None = None, trace: bool = False,
-                 start_level: int | None = None) -> passes.Pass:
-        """One ``check()`` from Init, stopped losslessly once the record at
-        ``end_level``'s pinned count is stamped.  ``trace``: the program's
-        own spans go to an event log and the first level of the span runs
-        under ``jax.profiler``."""
+                 start_level: int | None = None,
+                 **check_kw) -> passes.Pass:
+        """One ``check()``, stopped losslessly once the record at
+        ``end_level``'s pinned count is stamped; ``check_kw`` are further
+        public arguments of it (``checkpoint=``, ``resume=``).  ``trace``:
+        the program's own spans go to an event log and the first level of
+        the span, or of a resumed pass its first ``passes.TRACED_STEPS``
+        chunk steps, run under ``jax.profiler``.  A pass to or from a
+        snapshot keeps an event log without spans: a resumed pass is clocked
+        from its ``run_start``, and the one that writes the snapshot pays in
+        set-up what the program's log does once a process."""
         import jax
         end = self.b if end_level is None else end_level
         start = self.a if start_level is None else start_level
-        p = passes.Pass(index=self.made, t_call=0.0, traced=trace)
+        resumed = "resume" in check_kw
+        p = passes.Pass(index=self.made, t_call=0.0, traced=trace,
+                        resumed=resumed)
         self.made += 1
-        kw, at_a, trace_end = {}, None, None
+        kw, at_a, trace_end, closer = dict(check_kw), None, None, None
         env_trace = os.environ.get("RAFT_TLA_TRACE")
-        if trace:
+        if trace or check_kw:
             pdir = os.path.join(self.scratch, f"pass{p.index}")
             shutil.rmtree(pdir, ignore_errors=True)
             os.makedirs(pdir)
             p.events = os.path.join(pdir, "run.events")
-            p.trace_dir = os.path.join(pdir, "profile")
             kw["events"] = p.events
+        if trace:
+            p.trace_dir = os.path.join(pdir, "profile")
             os.environ["RAFT_TLA_TRACE"] = "1"
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0    # host TraceMe only: the anchor
@@ -137,6 +250,15 @@ class Driver:
                 p.t_trace_end = now
                 jax.profiler.stop_trace()
 
+            if resumed:
+                # no record arrives at A: the capture opens before the call
+                # (the resume itself runs nothing on the device) and is
+                # closed by steps, from beside the pass
+                at_a()
+                at_a = trace_end = None
+                closer = CaptureCloser(p, passes.TRACED_STEPS,
+                                       jax.profiler.stop_trace)
+
         # the capture is one level: a whole span is millions of op events,
         # and writing them out takes a minute
         clock = passes.SpanClock(p, self.pins, start, end, at_a, trace_end)
@@ -145,6 +267,8 @@ class Driver:
         self.engine.seg_chunks = self._seg_chunks0
         n0 = self.compiles.n
         try:
+            if closer is not None:
+                closer.start()
             p.t_call = time.monotonic()
             result = self.engine.check(on_progress=clock, **kw)
         finally:
@@ -153,9 +277,14 @@ class Driver:
                     os.environ.pop("RAFT_TLA_TRACE", None)
                 else:
                     os.environ["RAFT_TLA_TRACE"] = env_trace
-                if p.t_a is not None and p.t_trace_end is None:
+                if closer is not None:
+                    closer.finish()
+                if p.anchor is not None and p.t_trace_end is None:
                     with contextlib.suppress(RuntimeError):
                         jax.profiler.stop_trace()    # never got that far
+                    if closer is not None:
+                        # a pass shorter than the step bound: all of it
+                        p.t_trace_end = p.t_b
         passes.finish(p, result, self.pins, end)
         p.compiles = self.compiles.n - n0
         return p

@@ -1,16 +1,25 @@
-"""The reading: one pass = one ``check()`` from Init to the pinned count at
-level B.  A run's rate is all the orbits its passes admitted over the whole
-window they ran in; each pass is also clocked between the two progress records
-whose ``n_states`` equal the pinned counts at levels A and B (the at-depth
-span), for the per-layer readings."""
+"""The reading: one pass = one ``check()`` to the pinned count at level B,
+from Init or (a traffic with ``start.snapshot_level``) resumed from the run's
+own level-pinned snapshot.  A run's rate is all the orbits its passes admitted
+over the whole window they ran in; each pass is also clocked over its at-depth
+span, for the per-layer readings: between the two progress records whose
+``n_states`` equal the pinned counts at levels A and B, or, resumed, from the
+engine's ``run_start`` (the snapshot is loaded, the first upload is next) to
+the record at B."""
 
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import json
 import signal
 import statistics
 import time
+
+# the traced window of a resumed pass: closed at the harvest of the segment
+# that brings the chunk steps since the resume to this many (a whole level at
+# depth is 256 steps and several million op events)
+TRACED_STEPS = 40
 
 
 @dataclasses.dataclass
@@ -32,6 +41,9 @@ class Pass:
     trace_dir: str | None = None
     anchor: tuple | None = None     # (monotonic ns, annotation name)
     t_trace_end: float | None = None   # the capture covers t_a..t_trace_end
+    resumed: bool = False           # check(resume=): t_a is its run_start
+    start_keys: int | None = None   # resumed: the engine's first count
+    n_states: int | None = None     # the result's count at the return
 
     @property
     def reached(self) -> bool:
@@ -62,7 +74,9 @@ class SpanClock:
     segment/window boundary: ``ddd_engine.install_sigint_boundary_stop``).
 
     A record's ``n_states`` is exact at a level boundary only, so a stamp
-    also needs the record's ``level`` to be the boundary's."""
+    also needs the record's ``level`` to be the boundary's.  A resumed pass
+    has no record at A (its first boundary is A + 1): ``run_start`` of the
+    pass's event log is its stamp A, read after the return."""
 
     def __init__(self, p: Pass, pins: list, level_a: int, level_b: int,
                  at_a=None, after_first_level=None):
@@ -93,7 +107,8 @@ class SpanClock:
             if self.at_a is not None:
                 self.at_a()
                 p.t_a = time.monotonic()    # the span starts after the hook
-        elif level == self.level_b and p.t_a is not None and p.t_b is None:
+        elif level == self.level_b and p.t_b is None \
+                and (p.t_a is not None or p.resumed):
             p.t_b = now
             if hook is not None:
                 hook(now)
@@ -102,9 +117,27 @@ class SpanClock:
             hook(now)
 
 
+def run_start(events_path: str) -> dict | None:
+    """``{"mono", "n_states"}`` of the log's ``run_start`` event: the
+    engine's monotonic clock and key count when its level loop begins (after
+    a resume: the snapshot loaded, the key set rebuilt)."""
+    with open(events_path, encoding="utf-8") as f:
+        for line in f:
+            ev = json.loads(line)
+            if ev.get("event") == "run_start":
+                return {"mono": ev["anchor"]["mono"],
+                        "n_states": ev.get("n_states")}
+    return None
+
+
 def finish(p: Pass, result, pins: list, end_level: int) -> Pass:
     """Fill the pass from the engine's result and hold it to the pins."""
     p.t_return = time.monotonic()
+    p.n_states = result.n_states
+    if p.resumed:
+        began = run_start(p.events)
+        if began is not None:
+            p.t_a, p.start_keys = began["mono"], began["n_states"]
     p.levels = list(itertools.accumulate(result.levels))
     p.violation = result.violation.invariant if result.violation else None
     # what the engine did past B before it stopped (at most a segment) is
@@ -131,13 +164,14 @@ def finish(p: Pass, result, pins: list, end_level: int) -> Pass:
     return p
 
 
-def window_rate(made: list, orbits_to_b: int, window_s: float) -> dict:
+def window_rate(made: list, orbits_a_pass: int, window_s: float) -> dict:
     """The end-to-end reading: every orbit the window's sound passes
-    admitted (a pass from Init to level B admits the pinned count at B; a
-    failed pass counts for nothing) over ALL the window's time, from the
-    first pass's call to the last one's return: ramp, span, overshoot and
+    admitted (a pass from Init to level B admits the pinned count at B, a
+    resumed one that count less the keys its snapshot held; a failed pass
+    counts for nothing) over ALL the window's time, from the first pass's
+    call to the last one's return: ramp or resume, span, overshoot and
     whatever lies between passes, stalls included."""
-    orbits = orbits_to_b * sum(p.problem is None for p in made)
+    orbits = orbits_a_pass * sum(p.problem is None for p in made)
     # the ramp's share is read on untraced passes: a traced pass's span
     # holds the capture's write-out
     plain = [p for p in made if not p.traced and p.ramp_s is not None]

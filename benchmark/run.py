@@ -4,12 +4,14 @@
     python3 benchmark/run.py --workload NAME --seed N --seconds S --trace 0|1
 
 A run opens the TPU (no chip, no run), builds the cell's engine, warms its
-shapes with a short pass (all of that is ``setup_s``), then makes whole passes
-(Init to the cell's pinned level B) for S seconds of run time, at least the
-traffic's ``min_passes``.  ``orbits_per_s`` is every orbit those passes
-admitted over the whole window; every pass's own numbers (ramp, the clocked
-A->B span, overshoot) are printed on earlier lines.  After the window it
-decides ``correct`` and prints the contract's JSON object last.
+shapes with a short pass and, where the traffic starts at depth, builds the
+snapshot its passes resume from (all of that is ``setup_s``), then makes whole
+passes (Init, or the snapshot, to the cell's pinned level B) for S seconds of
+run time, at least the traffic's ``min_passes``.  ``orbits_per_s`` is every
+orbit those passes admitted over the whole window; every pass's own numbers
+(ramp or resume, the clocked A->B span, overshoot) are printed on earlier
+lines.  After the window it decides ``correct`` and prints the contract's JSON
+object last.
 """
 
 from __future__ import annotations
@@ -83,16 +85,26 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         f" peak_rss_mb={rss_mb():.0f}"
         + (f" PROBLEM {warm.problem}" if warm.problem else ""))
     t_ready = time.monotonic()
+    snap = drv.build_snapshot()
+    if snap is not None:
+        sn = drv.snapshot
+        say(f"snapshot at level {sn['level']}: keys={sn['keys']} "
+            f"pinned={drv.pins[sn['level']]} built in {sn['build_s']:.3f}s "
+            f"({sn['bytes'] / 1e6:.1f} MB under {os.path.dirname(sn['path'])})"
+            f" compiles={snap.compiles}; a resumed pass admits "
+            f"{drv.pass_orbits} orbits peak_rss_mb={rss_mb():.0f}"
+            + (f" PROBLEM {snap.problem}" if snap.problem else ""))
 
     made = []
     t_first = time.monotonic()
     while True:
-        p = drv.run_pass(trace=trace and len(made) == 1)
+        p = drv.timed_pass(trace=trace and len(made) == 1)
         made.append(p)
         rate = p.rate(drv.orbits)
         say(f"pass {len(made)} "
             + (f"rate={rate:.3f} orbits/s " if rate else "rate=none ")
-            + f"ramp_s={_f(p.ramp_s)} span_s={_f(p.span_s)} "
+            + f"{'resume_s' if p.resumed else 'ramp_s'}={_f(p.ramp_s)} "
+            f"span_s={_f(p.span_s)} "
             f"overshoot_s={_f(p.overshoot_s)} compiles={p.compiles} "
             f"peak_rss_mb={rss_mb():.0f}"
             + (" traced" if p.traced else "")
@@ -102,9 +114,9 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
                 len(made), traffic["min_passes"]):
             break
     window_s = time.monotonic() - t_first
-    win = passes.window_rate(made, drv.pins[drv.b], window_s)
+    win = passes.window_rate(made, drv.pass_orbits, window_s)
     say(f"window {window_s:.3f}s of --seconds {seconds:g}: {len(made)} passes "
-        f"to level {drv.b} ({drv.pins[drv.b]} orbits each, {drv.orbits} of "
+        f"to level {drv.b} ({drv.pass_orbits} orbits each, {drv.orbits} of "
         f"them in the clocked span {drv.a}..{drv.b}) rate="
         + (f"{win['rate']:.3f}" if win["rate"] else "none")
         + f" orbits/s ramp_share_pct={_f(win['ramp_share_pct'])}")
@@ -115,6 +127,9 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
     # the warm pass stops early by design and was held to its own prefix
     checks = correct.pass_checks(made, drv.pins, drv.b) + [
         ("warm_pass_problems", int(warm.problem is not None), 0)]
+    if snap is not None:
+        checks += correct.snapshot_checks(drv.snapshot, made, drv.pins,
+                                          drv.b)
     ref = correct.reference_sample(drv.cfg, seed)
     got = drv.expand_sample(ref["parents"])
     checks += correct.sample_checks(ref, got, drv.pins)
@@ -139,7 +154,8 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         "orbits": drv.orbits, "trace": None, "device": dev,
         "rss_mb": rss_mb(),
         "hbm_peak_bytes": drive.memory_peak_bytes(),
-        "work": _work(drv, work),
+        "work": _work(drv, work, made),
+        "span_levels": [drv.a, drv.b], "snapshot": drv.snapshot,
     }
     device = {"platform": dev["platform"], "kind": dev["kind"],
               "count": dev["count"],
@@ -147,10 +163,14 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
     result = {"correct": is_correct, "attempted": len(made),
               "failed": sum(p.problem is not None for p in made),
               "metrics": {}, "device": device}
+    # every number compared, beside its limit: the result line's last key
+    compared = {name: {"value": _num(value), "limit": _num(limit)}
+                for name, value, limit in checks}
     if rehearsal:
         # a CPU rehearsal writes no number under a device metric's name
         result["rehearsal"] = True
         say("REHEARSAL: not a measurement; no metric is written")
+        result["checks"] = compared
         return result
     evidence["peaks"] = mf.peaks(dev["kind"])
     if trace:
@@ -167,7 +187,8 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         result["breakdown"] = {"device_ops": red["device_ops"],
                                "idle_gaps": red["idle_gaps"]}
         say(f"traced pass {tp.index} levels {evidence['work']['traced_levels']}"
-            f" steps={evidence['work']['steps']}: "
+            f" steps={evidence['work']['steps']} "
+            f"streamed_or_admitted={evidence['work']['traced_orbits']}: "
             f"anchor_mono_ns={tp.anchor[0]} t_a={tp.t_a:.6f} "
             f"t_end={tp.t_trace_end:.6f} window_s={red['window_s']:.6f} "
             f"busy_s={red['busy_s']:.6f} span walls "
@@ -178,6 +199,7 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         value = mf.metric_reader(name)(evidence)
         if value is not None:
             result["metrics"][name] = {"value": value, "unit": units[name]}
+    result["checks"] = compared
     return result
 
 
@@ -185,15 +207,31 @@ def _f(x) -> str:
     return "none" if x is None else f"{x:.3f}"
 
 
-def _work(drv, work) -> dict:
-    """Analytic work of the TRACED part of the span (its first level)."""
+def _num(x):
+    return x if isinstance(x, (int, float)) else float(x)
+
+
+def _work(drv, work, made: list) -> dict:
+    """Analytic work of the TRACED part of the span: its first level, whose
+    chunk steps follow from the pins; of a resumed pass the segments
+    harvested inside the step-bounded window, as the program's own
+    ``segment`` spans count them (steps, and rows streamed for export)."""
+    from benchmark.harness import depthred, spanred
     eng = drv.engine
     te = drv.a + 1
+    steps = work.chunk_steps(drv.pins, drv.a, te, eng.caps.block,
+                             eng.config.chunk)
+    exported = drv.pins[te] - drv.pins[drv.a]
+    tp = next((p for p in made if p.traced and p.resumed
+               and p.t_a is not None and p.t_trace_end is not None), None)
+    if tp is not None:
+        seen = depthred.window_segments(spanred.load(tp.events), tp.t_a,
+                                        tp.t_trace_end)
+        steps, exported = seen["steps"], seen["streamed_rows"]
     return {
         "traced_levels": [drv.a, te],
-        "traced_orbits": drv.pins[te] - drv.pins[drv.a],
-        "steps": work.chunk_steps(drv.pins, drv.a, te, eng.caps.block,
-                                  eng.config.chunk),
+        "traced_orbits": exported,
+        "steps": steps,
         "words_per_step": work.scan_words(
             eng.config.chunk, eng.A, eng.bounds.n_servers, eng.lay.width,
             bool(eng.config.symmetry)),
@@ -222,6 +260,12 @@ def main(argv=None) -> int:
     result = execute(cell, manifest, args.seed, args.seconds,
                      bool(args.trace))
     say(json.dumps(result))
+    # ... and as the last lines on standard error
+    for name, c in result["checks"].items():
+        print(f"check {name}={c['value']} limit={c['limit']} "
+              f"{'ok' if c['value'] <= c['limit'] else 'FAIL'}",
+              file=sys.stderr)
+    print(f"correct={result['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -5,11 +5,13 @@
 
 Covers: the manifest's names and units; every cell's configuration, traffic
 and metric files resolving by name; the window-rate and span-median
-arithmetic and the pass clock; the trace reduction on the recorded trace; the
-plain reference against its own definition and the planted fault; a toy-size
-REHEARSAL of one whole run (labelled as such, writes no metric); and the
-controls — the same rehearsal with one guarantee or the timed path broken
-underneath has to come out ``correct: false``.
+arithmetic and the pass clock, from Init and resumed from a snapshot; the
+trace reduction on the recorded trace; the readers of a pass at depth on a
+recorded span log; the plain reference against its own definition and the
+planted fault; a toy-size REHEARSAL of one whole run from Init and of one whose
+passes resume from a level-pinned snapshot (labelled as such, write no
+metric); and the controls — the same rehearsals with one guarantee or the
+timed path broken underneath have to come out ``correct: false``.
 """
 
 from __future__ import annotations
@@ -35,6 +37,20 @@ def toy_cell() -> dict:
             "traffic_data": mf.read_json("testdata", "toy_traffic.json")}
 
 
+def toy_resume_cell() -> dict:
+    """The toy under a traffic whose passes resume from a snapshot of level
+    12; full retention, as a resumed pass needs it (the toy's own frontier
+    retention resumes in place)."""
+    cell = toy_cell()
+    cell.update(name="toy.resume", traffic="toy_resume",
+                traffic_data=mf.read_json("testdata", "toy_resume.json"))
+    cell["config_data"]["engine_caps"]["ddd"]["retention"] = "full"
+    return cell
+
+
+RESUME_LOG = os.path.join(mf.BENCH, "testdata", "spans_resume_small.jsonl")
+
+
 # ------------------------------------------------------------ the manifest
 
 def test_manifest_meets_the_checkable_contract():
@@ -49,7 +65,7 @@ def test_every_cell_resolves_its_files_by_name():
         t = c["traffic_data"]
         assert pins[t["start_level"]] == t["count_at_start"]
         assert pins[t["end_level"]] == t["count_at_end"]
-        assert t["min_passes"] >= 3
+        assert t["min_passes"] >= 2
         for kind in ("end_to_end", "per_layer"):
             assert mf.metric_names(m, w["name"], kind)
     for metric in m["per_layer"]:
@@ -135,6 +151,7 @@ def test_span_clock_stamps_at_the_pinned_counts_only():
         class R:
             levels = [1, 49, 50, 150, 150]
             violation = None
+            n_states = 400
         passes.finish(p, R, pins, 4)
         assert p.problem is None and p.levels == [1, 50, 100, 250, 400]
         assert abs(p.rate(300) - 300 / (p.t_b - p.t_a)) < 1e-6
@@ -161,6 +178,182 @@ def test_chunk_steps_and_words_from_shapes():
     assert work.chunk_steps(pins, 2, 4, 4096, 1024) == 1 + 5
     assert work.scan_words(4096, 38, 5, 104, True) == 4096 * 38 * 120 * 104
     assert work.scan_words(4096, 38, 5, 104, False) == 4096 * 38 * 104
+
+
+def test_a_resumed_pass_is_clocked_from_its_run_start_and_counts_less():
+    import signal
+    hits = []
+    old = signal.signal(signal.SIGINT, lambda *_: hits.append(1))
+    try:
+        pins = [1, 50, 100, 250, 400, 900]
+        # the recorded log of a resumed toy pass: its run_start holds the
+        # engine's clock and first count
+        began = passes.run_start(RESUME_LOG)
+        assert began == {"mono": 1956.552841, "n_states": 2325}
+        p = passes.Pass(index=0, t_call=began["mono"] - 0.5, resumed=True,
+                        events=RESUME_LOG)
+        clock = passes.SpanClock(p, pins, 2, 4)
+        # resumed from level 2: the first boundary record is level 3's
+        for n, lvl in ((180, 3), (250, 3), (400, 4)):
+            clock({"n_states": n, "level": lvl})
+        assert p.t_a is None and p.t_b is not None and hits == [1]
+
+        class R:
+            levels = [1, 49, 50, 150, 150]
+            violation = None
+            n_states = 400
+        passes.finish(p, R, pins, 4)
+        assert p.problem is None and p.reached and p.n_states == 400
+        assert p.t_a == began["mono"] and p.start_keys == 2325
+        assert abs(p.ramp_s - 0.5) < 1e-9              # the resume
+        # a pass from Init is never stamped B before A
+        q = passes.Pass(index=1, t_call=0.0)
+        passes.SpanClock(q, pins, 2, 4)({"n_states": 400, "level": 4})
+        assert q.t_b is None and hits == [1]
+    finally:
+        signal.signal(signal.SIGINT, old)
+    # the window: pins[B] less the snapshot's keys for every sound pass
+    made = [passes.Pass(index=k, t_call=10.0 * k, t_a=10.0 * k + 1,
+                        t_b=10.0 * k + 9, t_return=10.0 * k + 9.5,
+                        resumed=True) for k in range(3)]
+    made[1].problem = "short of B"
+    win = passes.window_rate(made, 400 - 102, 30.0)
+    assert win["orbits"] == 2 * 298 and abs(win["rate"] - 596 / 30.0) < 1e-9
+
+
+def test_snapshot_checks_hold_the_snapshot_to_the_pin_and_the_passes_to_it():
+    from benchmark.harness import correct
+    pins = [1, 50, 100, 250, 400, 900]
+    snap = {"level": 2, "keys": 102, "problem": None}
+    made = [passes.Pass(index=k, t_call=0.0, resumed=True, start_keys=102)
+            for k in range(2)]
+    got = dict((n, (v, lim)) for n, v, lim in
+               correct.snapshot_checks(snap, made, pins, 4))
+    assert got == {"snapshot_pass_problems": (0, 0),
+                   "snapshot_overshoot_orbits": (2, 6),     # 2 % of 300
+                   "snapshot_keys_diff": (0, 0)}
+    made[1].start_keys = 101            # a key went missing on the way
+    made.append(passes.Pass(index=2, t_call=0.0, resumed=True))  # no log
+    snap.update(keys=93, problem="level table differs")
+    got = dict((n, v) for n, v, _l in
+               correct.snapshot_checks(snap, made, pins, 4))
+    assert got == {"snapshot_pass_problems": 1,
+                   "snapshot_overshoot_orbits": 7,
+                   "snapshot_keys_diff": 9 + 8 + 93}
+
+
+def test_the_capture_of_a_resumed_pass_closes_by_steps():
+    from benchmark.harness import drive
+    stops = []
+    p = passes.Pass(index=0, t_call=0.0, resumed=True, events=RESUME_LOG)
+    closer = drive.CaptureCloser(p, 40, lambda: stops.append(1))
+    closer.start()
+    closer.finish()
+    # levels 13, 14, 15 hold 13 + 7 + 19 = 39 steps; the 29-step segment of
+    # level 16 brings them past 40, and its harvest ends the window
+    assert stops == [1] and abs(p.t_trace_end - 1956.769046) < 1e-6
+    q = passes.Pass(index=1, t_call=0.0, resumed=True, events=RESUME_LOG)
+    closer = drive.CaptureCloser(q, 1000, lambda: stops.append(1))
+    closer.start()
+    closer.finish()                      # the pass ended first
+    assert stops == [1] and q.t_trace_end is None
+
+
+class _FakeEngine:
+    """Stands in for the program: records how check() was called, feeds the
+    clock one boundary record a level and stops at the first SIGINT."""
+
+    seg_chunks = 7
+
+    def __init__(self, pins):
+        self.pins, self.calls, self.stop = pins, [], False
+
+    def check(self, on_progress, **kw):
+        self.calls.append(kw)
+        first = 13 if "resume" in kw else 0
+        if "events" in kw:
+            with open(kw["events"], "w", encoding="utf-8") as f:
+                f.write(json.dumps({"event": "run_start", "n_states":
+                                    self.pins[first - 1] if first else 1,
+                                    "anchor": {"mono": time.monotonic()}})
+                        + "\n")
+        self.stop, n = False, first
+        for n in range(first, len(self.pins)):
+            on_progress({"level": n, "n_states": self.pins[n]})
+            if self.stop:
+                break
+
+        class Result:
+            levels = [self.pins[0]] + [b - a for a, b in zip(
+                self.pins, self.pins[1:n + 1])]
+            violation = None
+            n_states = self.pins[n]
+        return Result
+
+
+def _drive_fake(cell):
+    import signal
+    import tempfile
+    from benchmark.harness import drive
+    eng = _FakeEngine(cell["config_data"]["level_pins"])
+    real = drive.build_engine
+    drive.build_engine = lambda cfg: eng
+    old = signal.signal(signal.SIGINT,
+                        lambda *_: setattr(eng, "stop", True))
+    try:
+        with tempfile.TemporaryDirectory() as scratch:
+            drv = drive.Driver(cell, scratch)
+            warm = drv.run_pass(end_level=3, start_level=1)
+            snap = drv.build_snapshot()
+            made = [drv.timed_pass(), drv.timed_pass()]
+            calls = [dict(c, events=os.path.relpath(c["events"], scratch))
+                     if "events" in c else c for c in eng.calls]
+            return drv, warm, snap, made, calls
+    finally:
+        signal.signal(signal.SIGINT, old)
+        drive.build_engine = real
+
+
+def test_a_traffic_with_no_start_drives_the_calls_it_always_drove():
+    # from Init: check(on_progress=) and nothing else, no snapshot pass
+    drv, warm, snap, made, calls = _drive_fake(toy_cell())
+    assert snap is None and drv.snapshot is None and calls == [{}, {}, {}]
+    assert warm.problem is None and all(p.problem is None for p in made)
+    assert not any(p.resumed or p.events for p in made)
+    assert drv.pass_orbits == 6652 and drv.orbits == 6652 - 2325
+    cell = toy_cell()
+    cell["traffic_data"]["start"] = "init"
+    assert _drive_fake(cell)[4] == [{}, {}, {}]
+    # from a snapshot of level 12: one pass from Init writes it through the
+    # public checkpoint arguments, every timed pass resumes from it
+    drv, warm, snap, made, calls = _drive_fake(toy_resume_cell())
+    path = drv.snapshot["path"]
+    assert path.endswith(os.path.join("snap", "run"))
+    assert calls == [
+        {},
+        {"checkpoint": path, "checkpoint_every_s": float("inf"),
+         "events": os.path.join("pass1", "run.events")},
+        {"resume": path, "events": os.path.join("pass2", "run.events")},
+        {"resume": path, "events": os.path.join("pass3", "run.events")}]
+    assert snap.problem is None and drv.snapshot["keys"] == 2325
+    assert drv.pass_orbits == drv.orbits == 6652 - 2325
+    assert all(p.problem is None and p.resumed and p.start_keys == 2325
+               and p.t_call <= p.t_a <= p.t_b for p in made)
+    # a configuration that resumes in place cannot share one snapshot
+    cell = toy_resume_cell()
+    cell["config_data"]["engine_caps"]["ddd"]["retention"] = "frontier"
+    try:
+        _drive_fake(cell)
+        raise AssertionError("frontier retention took a snapshot traffic")
+    except ValueError as e:
+        assert "one copy a pass" in str(e)
+    cell = toy_resume_cell()
+    cell["traffic_data"]["start_level"] = 11
+    try:
+        _drive_fake(cell)
+        raise AssertionError("a span that starts off its snapshot")
+    except ValueError as e:
+        assert "starts at its snapshot" in str(e)
 
 
 # ------------------------------------------------------ the trace reduction
@@ -193,7 +386,71 @@ def test_reduction_of_the_recorded_trace():
     assert 0.0 < got["busy_s"] <= got["window_s"]
 
 
+def test_readers_of_a_pass_at_depth_on_the_recorded_resumed_pass():
+    from benchmark.harness import depthred, spanred
+    spans = spanred.load(RESUME_LOG)
+    red = depthred.reduce(spans, 12, 16)
+    inline = red.pop("dedup_inline_s")
+    assert abs(inline - (0.002747 + 0.008381 + 0.0071 + 0.009088)) < 1e-9
+    assert red == {"levels": 4, "streamed_rows": 4736, "new_states": 4327,
+                   "blocks": 4, "uploads": 4, "prefetch_hits": 4,
+                   "flush_submits": 0, "flush_backlog_max": None}
+    assert depthred.reduce(spans, 16, 16) is None
+    assert depthred.reduce([s for s in spans if s["name"] != "level"],
+                           12, 16) is None
+    # the step-bounded window: the segments harvested inside it
+    t_a = passes.run_start(RESUME_LOG)["mono"]
+    assert depthred.window_segments(spans, t_a, 1956.769046) == \
+        {"segments": 7, "steps": 68, "streamed_rows": 4736}
+    assert depthred.window_segments(spans, t_a, 1956.62) == \
+        {"segments": 3, "steps": 20, "streamed_rows": 1856}
+    traced = passes.Pass(index=1, t_call=t_a - 0.014, t_a=t_a,
+                         t_b=1956.7815, t_trace_end=1956.769046,
+                         traced=True, resumed=True, events=RESUME_LOG)
+    plain = [passes.Pass(index=k, t_call=10.0 * k, t_a=10.0 * k + r,
+                         t_b=10.0 * k + 9, resumed=True)
+             for k, r in ((0, 0.25), (2, 0.75), (3, 0.5))]
+    ev = {"passes": [plain[0], traced] + plain[1:], "span_levels": [12, 16],
+          "snapshot": {"build_s": 12.5}}
+    read = {n: mf.metric_reader(n)(ev) for n in (
+        "host_dedup_hit_pct", "blocks_per_level", "prefetch_hit_pct",
+        "flush_backlog_keys", "resume_s", "snapshot_build_s",
+        "dedup_inline_s")}
+    assert abs(read["host_dedup_hit_pct"] - 100 * 409 / 4736) < 1e-9
+    assert read["blocks_per_level"] == 1.0
+    assert read["prefetch_hit_pct"] == 100.0
+    assert read["flush_backlog_keys"] is None     # no batch was handed over
+    assert read["resume_s"] == 0.5 and read["snapshot_build_s"] == 12.5
+    assert abs(read["dedup_inline_s"] - inline) < 1e-12
+    # a hand-over with keys behind it is what the backlog reader reads
+    submit = {"name": "dedup_submit", "thread": "MainThread", "t0": 1956.6,
+              "dur": 0.001, "id": 9001, "args": {"keys": 7, "backlog": 3},
+              "parent": next(s["id"] for s in spans if s["name"] == "level"
+                             and s["args"]["level"] == 14)}
+    assert depthred.reduce(spans + [submit], 12, 16)[
+        "flush_backlog_max"] == 3
+    # nothing to read: an untraced run, a run from Init, a log with no level
+    bare = {"passes": [passes.Pass(index=0, t_call=0.0, t_a=1.0, t_b=2.0)],
+            "span_levels": [12, 16], "snapshot": None}
+    for n in read:
+        assert mf.metric_reader(n)(bare) is None, n
+
+
 # ------------------------------------------------------ the plain reference
+
+def test_deep_pins_count_what_the_plain_bfs_counts():
+    from benchmark.reference import deep_pins
+    cfg = mf.read_json("testdata", "toy_config.json")
+    for workers in (1, 2):
+        cum, viol = deep_pins.bfs_counts(
+            cfg["bounds"], cfg["spec"], True, tuple(cfg["invariants"]), 40,
+            workers, out=lambda _m: None)
+        assert cum == cfg["level_pins"] and viol == 0
+    cum, _v = deep_pins.bfs_counts(
+        cfg["bounds"], cfg["spec"], True, tuple(cfg["invariants"]), 5, 1,
+        out=lambda _m: None)
+    assert cum == cfg["level_pins"][:6]
+
 
 def test_reference_orbit_representative_is_renaming_invariant():
     from benchmark.reference import canon, interp
@@ -229,6 +486,69 @@ def test_rehearsal_of_one_run_is_correct_and_writes_no_metric():
     assert res["rehearsal"] is True and res["metrics"] == {}
     assert res["correct"] is True
     assert res["attempted"] >= 3 and res["failed"] == 0
+    # every number compared stands beside its limit, under the last key
+    assert list(res)[-1] == "checks" and len(res["checks"]) >= 8
+    assert all(c["value"] <= c["limit"] for c in res["checks"].values())
+    json.dumps(res)
+
+
+def rehearse_resumed(seed: int, trace: bool = False) -> dict:
+    from benchmark import run
+    return run.execute(toy_resume_cell(), mf.load(), seed, 0.0, trace,
+                       rehearsal=True)
+
+
+def test_rehearsal_of_resumed_passes_is_correct_and_writes_no_metric():
+    res = rehearse_resumed(3_000_000_029, trace=True)
+    assert res["rehearsal"] is True and res["metrics"] == {}
+    assert res["correct"] is True
+    assert res["attempted"] >= 2 and res["failed"] == 0
+    assert {"snapshot_overshoot_orbits", "snapshot_keys_diff"} <= set(
+        res["checks"])
+
+
+def test_a_snapshot_with_one_key_removed_comes_out_not_correct():
+    # the snapshot damaged between set-up and the window: its metadata (an
+    # npz beside the four streams; the layout is the program's, known to
+    # this test alone) rewritten to end one key early
+    import numpy as np
+    from benchmark.harness import drive
+    real = drive.Driver.build_snapshot
+
+    def build_then_damage(self):
+        p = real(self)
+        path = self.snapshot["path"]
+        with np.load(path) as z:
+            meta = {k: z[k] for k in z.files if k != "content_sha"}
+        meta["n_states"] = meta["n_states"] - 1
+        meta["level_ends"] = meta["level_ends"].copy()
+        meta["level_ends"][-1] -= 1
+        with open(path, "wb") as f:
+            np.savez(f, **meta)
+        return p
+
+    drive.Driver.build_snapshot = build_then_damage
+    try:
+        res = rehearse_resumed(15)
+    finally:
+        drive.Driver.build_snapshot = real
+    assert res["correct"] is False and res["failed"] == res["attempted"]
+
+
+def test_short_keys_under_resumed_passes_come_out_not_correct():
+    from benchmark.harness import breakers
+    with breakers.short_keys(16):
+        res = rehearse_resumed(16)
+    assert res["correct"] is False
+
+
+def test_filter_only_dedup_under_resumed_passes_comes_out_not_correct():
+    # the resume rebuilds the key set from the snapshot's keys: that one
+    # forgets too
+    from benchmark.harness import breakers
+    with breakers.filter_only_dedup():
+        res = rehearse_resumed(17)
+    assert res["correct"] is False
 
 
 def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
