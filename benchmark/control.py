@@ -1,11 +1,13 @@
 #!/usr/bin/env python3
 """Run a cell with one guarantee broken and see ``correct`` come out false.
 
-    python3 benchmark/control.py --workload NAME --control key32|filter_only|invariants_off \
-        --seeds 1,2,3 [--seconds 0]
+    python3 benchmark/control.py --workload NAME --seeds 1,2,3 [--seconds 0] \
+        --control key32|filter_only|invariants_off|misroute
 
-Not part of a benchmark run.  One process, one run per seed at the cell's
-own size (the traffic's minimum number of passes when --seconds is 0).
+Not part of a benchmark run.  ``misroute`` is a mesh cell's control (a cell
+on one chip has no exchange to misroute, and passes it).  One process, one
+run per seed at the cell's own size (the traffic's minimum number of passes
+when --seconds is 0).
 Exits 0 when every seed's run was refused, 1 when one passed.
 """
 

@@ -97,6 +97,30 @@ def invariants_off():
         kernels.build_step = real
 
 
+@contextlib.contextmanager
+def misrouted_exchange():
+    """Guarantee broken (a mesh configuration's own): every candidate is
+    filtered and deduplicated by the shard that owns its key.  Every mesh
+    segment traced inside sends each candidate to the shard AFTER its owner.
+    All duplicates of a key still meet on one shard, so every count stays
+    right; only ``owner_misrouted`` sees it.  Does nothing to a one-chip
+    engine, which has no exchange."""
+    import jax.numpy as jnp
+    from raft_tla_tpu.parallel import ddd_shard_engine as mesh_engine
+    real = mesh_engine.exchange
+
+    def exchange(axis_name, n_dest, cap, dest, payload):
+        wrong = jnp.where(dest < n_dest, (dest + 1) % n_dest, dest)
+        return real(axis_name, n_dest, cap, wrong, payload)
+
+    mesh_engine.exchange = exchange
+    try:
+        yield
+    finally:
+        mesh_engine.exchange = real
+
+
 CONTROLS = {"key32": lambda: short_keys(32),
             "filter_only": filter_only_dedup,
-            "invariants_off": invariants_off}
+            "invariants_off": invariants_off,
+            "misroute": misrouted_exchange}

@@ -85,7 +85,10 @@ def sample_checks(ref: dict, got: dict, pins: list) -> list:
     reference: the same set of successor orbits (states canonicalised in
     plain Python), key and orbit in one-to-one correspondence (the 64-bit
     key is exact on the sample), the same transition count and constraint
-    flags."""
+    flags.  Where the segment ran on a mesh (``got["misrouted"]``), the
+    stream is the union of the shards' streams and one number joins:
+    ``owner_misrouted``, streamed keys found on a shard other than
+    ``key_hi % ndev``."""
     key = canon.canonical if ref["sym"] else canon.as_tuple
     streamed = [key(_ref_state(s)) for s in got["states"]]
     sset = set(streamed)
@@ -100,6 +103,10 @@ def sample_checks(ref: dict, got: dict, pins: list) -> list:
     conflicts = sum(len(v) > 1 for v in by_key.values()) \
         + sum(len(v) > 1 for v in by_orbit.values())
     cum = ref["cumulative"]
+    # on a mesh, the guarantee the exchange adds: every candidate is filtered
+    # and streamed by the shard that owns its key
+    owner = [("owner_misrouted", got["misrouted"], 0)] \
+        if "misrouted" in got else []
     return [
         ("ref_bfs_level_mismatches",
          sum(a != b for a, b in zip(cum, pins)) + max(0, len(cum) - len(pins)),
@@ -113,7 +120,7 @@ def sample_checks(ref: dict, got: dict, pins: list) -> list:
         ("sample_constraint_flags_wrong", con_wrong, 0),
         ("sample_segment_flags", int(got["fail"] != 0)
          + int(not got["done"]), 0),
-    ]
+    ] + owner
 
 
 def planted_fault(cfg: dict, level: list, seed: int) -> dict:

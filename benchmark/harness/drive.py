@@ -74,11 +74,20 @@ def check_config(cfg: dict):
 
 
 def build_engine(cfg: dict):
-    """The ``ddd`` engine with the configuration's capacities for it.  Its
+    """The engine the configuration names, with its capacities for it: the
+    ``ddd`` engine on one device, or ``ddd-shard`` on a mesh of the
+    configuration's ``devices`` (the constructors ``check.py`` uses).  The
     constructor builds the jitted segment once."""
+    name, devices = mf.engine_of(cfg)
+    caps = cfg["engine_caps"][name]
+    if name == "ddd-shard":
+        from raft_tla_tpu.parallel.ddd_shard_engine import (
+            DDDShardCapacities, DDDShardEngine)
+        from raft_tla_tpu.parallel.shard_engine import make_mesh
+        return DDDShardEngine(check_config(cfg), make_mesh(devices),
+                              DDDShardCapacities(**caps))
     from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
-    return DDDEngine(check_config(cfg),
-                     DDDCapacities(**cfg["engine_caps"]["ddd"]))
+    return DDDEngine(check_config(cfg), DDDCapacities(**caps))
 
 
 class CaptureCloser(threading.Thread):
@@ -152,8 +161,10 @@ class Driver:
                 f"traffic {cell['traffic']}: the span of a resumed pass "
                 f"starts at its snapshot, level {self.snapshot_level}, "
                 f"not at start_level {self.a}")
+        self.engine_name, self.ndev = mf.engine_of(self.cfg, cell["chips"])
+        self._level_ahead = mf.ENGINES[self.engine_name]["record_level_ahead"]
         if self.snapshot_level is not None \
-                and self.cfg["engine_caps"]["ddd"].get(
+                and self.cfg["engine_caps"][self.engine_name].get(
                     "retention", "full") != "full":
             # frontier retention resumes in place: a resumed pass would
             # write its own stop over the snapshot the next one needs
@@ -261,7 +272,8 @@ class Driver:
 
         # the capture is one level: a whole span is millions of op events,
         # and writing them out takes a minute
-        clock = passes.SpanClock(p, self.pins, start, end, at_a, trace_end)
+        clock = passes.SpanClock(p, self.pins, start, end, at_a, trace_end,
+                                 level_ahead=self._level_ahead)
         # every pass starts from the same segment budget: check() leaves its
         # pacer's last budget on the object
         self.engine.seg_chunks = self._seg_chunks0
@@ -293,44 +305,108 @@ class Driver:
 
     def expand_sample(self, parents: list) -> dict:
         """Feed ``parents`` (reference states) to the SAME compiled segment
-        program the passes drove, as one frontier block behind an empty
-        filter, and decode what it streams.  The program symbols used here
-        are the benchmark's frozen interface (README, "What the benchmark
-        holds the program to")."""
-        import jax
-        import jax.numpy as jnp
+        program the passes drove, as one frontier block (on the mesh: one
+        window, dealt to the shards by the engine's own upload) behind an
+        empty filter, and decode what it streams.  The program symbols used
+        here are the benchmark's frozen interface (README, "What the
+        benchmark holds the program to")."""
         import numpy as np
         from raft_tla_tpu.models import interp as pinterp
         from raft_tla_tpu.ops import state as st
         eng = self.engine
         n, P = len(parents), eng.schema.P
-        block = eng.caps.block
-        rows = np.zeros((block, P), np.int32)
-        con = np.zeros((block,), bool)
+        rows = np.zeros((n, P), np.int32)
+        con = np.zeros((n,), bool)
         for k, s in enumerate(parents):
             ps = _program_state(s)
             rows[k] = eng.schema.pack(
                 np.asarray(pinterp.to_vec(ps, eng.bounds), np.int32), np)
             con[k] = pinterp.constraint_ok(ps, eng.bounds)
-        n_chunks = -(-n // eng.config.chunk)
-        _fc, bufs, stats = eng._segment(
-            eng._init_filter(), eng._make_bufs(), jnp.asarray(rows),
-            jnp.asarray(con), jnp.int32(n_chunks), jnp.int32(n))
-        st_h = jax.device_get(stats)
-        bufs_h = jax.device_get(bufs)
-        take = slice(0, int(st_h.cursor))
+        n0 = self.compiles.n
+        got = (self._stream_mesh if self.engine_name == "ddd-shard"
+               else self._stream_ddd)(rows, con)
         states = []
-        for row in bufs_h.orows[take]:
+        for row in got.pop("orows"):
             vec = eng.schema.unpack(np.asarray(row), np)
             states.append(pinterp.from_struct(
                 st.unpack(vec, eng.lay, np), eng.bounds))
-        keys = (bufs_h.okey_hi[take].astype(np.uint64) << np.uint64(32)) \
-            | bufs_h.okey_lo[take].astype(np.uint64)
-        return {"states": states, "keys": keys,
+        got["keys"] = (got.pop("key_hi").astype(np.uint64) << np.uint64(32)) \
+            | got.pop("key_lo").astype(np.uint64)
+        got["states"] = states
+        got["compiles"] = self.compiles.n - n0
+        return got
+
+    def _stream_ddd(self, rows, con) -> dict:
+        """One frontier block through ``DDDEngine._segment``."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        eng = self.engine
+        n, block = len(rows), eng.caps.block
+        brows = np.zeros((block, rows.shape[1]), np.int32)
+        bcon = np.zeros((block,), bool)
+        brows[:n], bcon[:n] = rows, con
+        n_chunks = -(-n // eng.config.chunk)
+        _fc, bufs, stats = eng._segment(
+            eng._init_filter(), eng._make_bufs(), jnp.asarray(brows),
+            jnp.asarray(bcon), jnp.int32(n_chunks), jnp.int32(n))
+        st_h = jax.device_get(stats)
+        bufs_h = jax.device_get(bufs)
+        take = slice(0, int(st_h.cursor))
+        return {"orows": bufs_h.orows[take], "key_hi": bufs_h.okey_hi[take],
+                "key_lo": bufs_h.okey_lo[take],
                 "con": [bool(c) for c in bufs_h.ocon[take]],
                 "n_transitions": int(st_h.n_valid),
                 "done": bool(st_h.done),
                 "fail": int(st_h.fail) | int(st_h.viol_kind)}
+
+    def _stream_mesh(self, rows, con) -> dict:
+        """One window through ``DDDShardEngine._segment`` under its
+        ``shard_map``: the engine's own ``_upload_window`` deals the rows to
+        the shards (from two stand-in stores that hold nothing else), every
+        shard starts behind an empty filter, and the segment is dispatched
+        as the window loop dispatches it until it reports the window done.
+        The shards' streams are taken shard by shard, as the harvest takes
+        them; ``misrouted`` counts streamed keys on a shard that does not
+        own them (the engine's owner map: ``key_hi % ndev``)."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        eng = self.engine
+        nd, ocap = eng.ndev, eng.caps.seg_rows
+        fbuf, fcon, fpar, nrows, n_chunks = eng._upload_window(
+            _Rows(rows), _Rows(con.astype(np.int32)[:, None]), 0, len(rows))
+        fc, bufs = eng._init_filter(), eng._make_bufs()
+        out = {k: [] for k in ("orows", "key_hi", "key_lo", "con")}
+        n_trans = fail = misrouted = 0
+        done = False
+        for _ in range(n_chunks + 1):       # a segment runs >= 1 chunk
+            fc, bufs, stats = eng._segment(
+                fc, bufs, fbuf, fcon, fpar, nrows,
+                jnp.int32(self._seg_chunks0), jnp.int32(n_chunks))
+            st_h = jax.device_get(stats)
+            bufs_h = jax.device_get(bufs)
+            cursors = np.asarray(st_h.cursor)
+            for s in range(nd):
+                take = slice(s * ocap, s * ocap + int(cursors[s]))
+                hi = bufs_h.okey_hi[take]
+                misrouted += int(np.sum(hi % np.uint32(nd) != s))
+                out["orows"].append(bufs_h.orows[take])
+                out["key_hi"].append(hi)
+                out["key_lo"].append(bufs_h.okey_lo[take])
+                out["con"].append(bufs_h.ocon[take])
+            n_trans += int(np.asarray(st_h.n_valid).sum())
+            fail |= int(np.bitwise_or.reduce(np.asarray(st_h.fail))) \
+                | int((np.asarray(st_h.viol_pos) >= 0).any()) \
+                | int((np.asarray(st_h.dead_g) >= 0).any())
+            done = bool(st_h.done)
+            if done or fail:
+                break
+        got = {k: np.concatenate(v) for k, v in out.items()}
+        got["con"] = [bool(c) for c in got["con"]]
+        got.update(n_transitions=n_trans, done=done, fail=fail,
+                   misrouted=misrouted, shards=nd)
+        return got
 
     def planted_violation(self, parent) -> dict:
         """One ``check()`` of the run's engine object from ``parent`` (a
@@ -351,6 +427,16 @@ class Driver:
         return {"invariant": v.invariant if v else None,
                 "state": v.state if v else None,
                 "levels": list(result.levels)}
+
+
+class _Rows:
+    """A stand-in row store for ``_upload_window``: ``read(base, n)``."""
+
+    def __init__(self, a):
+        self.a = a
+
+    def read(self, base: int, n: int):
+        return self.a[base:base + n]
 
 
 def _program_state(s):

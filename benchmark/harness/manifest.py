@@ -21,6 +21,38 @@ _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 _SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 
 
+# the engines a configuration may name (``"engine"``; absent = ``ddd``), each
+# with the level its boundary record carries: the ddd engine reports a level's
+# close before it opens the next (``level`` = the level just counted), the
+# mesh engine after (``level`` = the level about to open)
+ENGINES = {"ddd": {"record_level_ahead": 0},
+           "ddd-shard": {"record_level_ahead": 1}}
+
+
+def engine_of(cfg: dict, chips: int | None = None) -> tuple:
+    """``(engine name, devices it spans)`` as the configuration states them,
+    refused by name where the engine is unknown, its capacities are missing,
+    or the cell (``chips``) holds fewer chips than the mesh needs.  Touches
+    no device."""
+    name = cfg.get("engine", "ddd")
+    if name not in ENGINES:
+        raise ValueError(
+            f"configuration {cfg['name']}: unknown engine {name!r} "
+            f"(known: {', '.join(sorted(ENGINES))})")
+    devices = cfg.get("devices", 1)
+    if name == "ddd" and devices != 1:
+        raise ValueError(f"configuration {cfg['name']}: engine 'ddd' runs "
+                         f"on one device, the file says {devices}")
+    if name not in cfg["engine_caps"]:
+        raise ValueError(f"configuration {cfg['name']}: no engine_caps "
+                         f"for its engine {name!r}")
+    if chips is not None and chips < devices:
+        raise ValueError(
+            f"configuration {cfg['name']} spans {devices} devices "
+            f"({name}); the cell holds {chips} chip(s)")
+    return name, devices
+
+
 def load(root: str = ROOT) -> dict:
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         return json.load(f)
@@ -84,6 +116,7 @@ def problems(manifest: dict) -> list:
             bad.append(f"{what}: bad name {s!r}")
 
     configs = {c["name"] for c in manifest["configs"]}
+    files = {c["name"]: c["file"] for c in manifest["configs"]}
     cells = [w["name"] for w in manifest["workloads"]]
     e2e = {m["name"] for m in manifest["end_to_end"]}
     if "setup_s" not in e2e:
@@ -112,6 +145,15 @@ def problems(manifest: dict) -> list:
         if not os.path.isfile(os.path.join(
                 BENCH, "traffic", w["traffic"] + ".json")):
             bad.append(f"cell {w['name']}: no traffic file")
+        # the engine the configuration names: known, and the cell holds
+        # the chips its mesh spans
+        path = os.path.join(ROOT, files.get(w["config"], ""))
+        if os.path.isfile(path):
+            with open(path, encoding="utf-8") as f:
+                try:
+                    engine_of(json.load(f), w["chips"])
+                except ValueError as e:
+                    bad.append(f"cell {w['name']}: {e}")
     if sum(w["chips"] == 4 for w in manifest["workloads"]) > \
             max(1, len(cells) // 2):
         bad.append("too many four-chip cells")

@@ -76,13 +76,20 @@ class SpanClock:
     A record's ``n_states`` is exact at a level boundary only, so a stamp
     also needs the record's ``level`` to be the boundary's.  A resumed pass
     has no record at A (its first boundary is A + 1): ``run_start`` of the
-    pass's event log is its stamp A, read after the return."""
+    pass's event log is its stamp A, read after the return.
+
+    ``level_ahead``: how far the engine's boundary record runs ahead of the
+    level it closes.  The ddd engine reports before it opens the next level
+    (0); the mesh engine after (1), and then repeats that pair of level and
+    count from inside the next level's window until its drain, so the hook
+    ``after_first_level`` fires at the first such record only."""
 
     def __init__(self, p: Pass, pins: list, level_a: int, level_b: int,
-                 at_a=None, after_first_level=None):
+                 at_a=None, after_first_level=None, level_ahead: int = 0):
         self.p = p
         self.pins = pins
         self.level_a, self.level_b = level_a, level_b
+        self.level_ahead = level_ahead
         # the traced pass's capture: at_a() opens it at stamp A,
         # after_first_level(now) closes it at the boundary of level A + 1
         self.at_a, self.after_first_level = at_a, after_first_level
@@ -90,7 +97,7 @@ class SpanClock:
     def __call__(self, rec: dict) -> None:
         now = time.monotonic()
         p = self.p
-        level = rec["level"]
+        level = rec["level"] - self.level_ahead
         if not 0 <= level < len(self.pins) \
                 or rec["n_states"] != self.pins[level]:
             if level > self.level_b and p.t_b is None and p.problem is None:
@@ -100,8 +107,9 @@ class SpanClock:
                              f"now at {rec['n_states']}, level {level}")
                 signal.raise_signal(signal.SIGINT)
             return
-        hook = self.after_first_level \
-            if p.t_a is not None and level == self.level_a + 1 else None
+        hook = None
+        if p.t_a is not None and level == self.level_a + 1:
+            hook, self.after_first_level = self.after_first_level, None
         if level == self.level_a and p.t_a is None:
             p.t_a = now
             if self.at_a is not None:

@@ -172,30 +172,45 @@ def load_xplane(trace_dir: str, anchor_name: str | None = None) -> dict:
     return out
 
 
+def module_self_times(lines: dict, w0: int, w1: int, key_of,
+                      module_hint: str = "segment") -> tuple | None:
+    """One device plane: self time (a ``while`` holds its body's ops:
+    tracered's own clip and arithmetic) of the ops that start inside the
+    segment module's intervals, clipped to ``[w0, w1]`` ns and keyed by
+    ``(op name, key_of(scope path))``; with the module's time there.
+    ``None`` where the plane did not run the module inside the window."""
+    mods = [(max(s, w0), min(s + d, w1))
+            for n, s, d in lines.get(MODULES_LINE, [])
+            if module_hint in n and s < w1 and s + d > w0]
+    if not mods:
+        return None
+    keyed = [[(name, key_of(path)), s, d]
+             for name, s, d, path in lines.get(OPS_LINE, [])]
+    by_op = tracered.self_times(
+        [ev for ev in tracered.clip(keyed, w0, w1)
+         if any(a <= ev[1] < b for a, b in mods)])
+    return by_op, sum(b - a for a, b in mods)
+
+
 def stage_times(trace: dict, w0: int, w1: int,
                 module_hint: str = "segment") -> dict | None:
     """Device self time per stage scope of the ops that run inside the
     segment module's intervals, clipped to ``[w0, w1]`` ns; averaged over
-    the devices that ran any.  ``stage_ns`` + ``unscoped_ns`` ==
-    ``total_ns`` exactly (one partition of the same events)."""
+    the devices that ran any, each device's own kept beside the mean in
+    plane order.  ``stage_ns`` + ``unscoped_ns`` == ``total_ns`` exactly
+    (one partition of the same events)."""
     per_dev = []
     for _plane, lines in sorted(trace["devices"].items()):
-        mods = [(max(s, w0), min(s + d, w1))
-                for n, s, d in lines.get(MODULES_LINE, [])
-                if module_hint in n and s < w1 and s + d > w0]
-        if not mods:
+        got = module_self_times(lines, w0, w1,
+                                lambda path: stage_of(path) or UNSCOPED,
+                                module_hint)
+        if got is None:
             continue
-        # tracered's own clip and self-time (a ``while`` holds its body's
-        # ops), keyed by (op, stage) instead of the op's name alone
-        keyed = [[(name, stage_of(path) or UNSCOPED), s, d]
-                 for name, s, d, path in lines.get(OPS_LINE, [])]
-        by_op = tracered.self_times(
-            [ev for ev in tracered.clip(keyed, w0, w1)
-             if any(a <= ev[1] < b for a, b in mods)])
+        by_op, module_ns = got
         acc: dict = {}
         for (_name, stage), ns in by_op.items():
             acc[stage] = acc.get(stage, 0) + ns
-        per_dev.append((acc, by_op, sum(b - a for a, b in mods)))
+        per_dev.append((acc, by_op, module_ns))
     if not per_dev:
         return None
     n = len(per_dev)
@@ -212,6 +227,7 @@ def stage_times(trace: dict, w0: int, w1: int,
             "total_ns": total,
             "module_ns": sum(m for _a, _e, m in per_dev) / n,
             "scoped": any(stage_ns.values()),
+            "stage_ns_by_device": [a for a, _e, _m in per_dev],
             # the breakdown with stable names: each op beside its stage
             "top_ops": [[name, stage, ns]
                         for (name, stage), ns in ranked[:10]],
@@ -266,16 +282,18 @@ def anchor_check_ms(trace: dict, spans: list, anchor_mono_ns: int,
     return (min(starts) - min(exp)) / 1e6
 
 
-def of(ev: dict) -> dict | None:
+def of(ev: dict, trace: dict | None = None) -> dict | None:
     """The stage and clock reductions of this run's traced pass (computed
     once a run and kept on the evidence; prints its one line the first
-    time).  ``None`` where the run was not traced."""
+    time).  ``None`` where the run was not traced.  ``trace``: the capture
+    as ``load_xplane`` gives it, where the caller has loaded it already."""
     if "stagered" in ev:
         return ev["stagered"]
     p = spanred.traced_pass(ev)
     red = None
     if p is not None and p.trace_dir and p.anchor:
-        trace = load_xplane(p.trace_dir, p.anchor[1])
+        if trace is None:
+            trace = load_xplane(p.trace_dir, p.anchor[1])
         if trace["anchor"] is not None:
             a_ns = trace["anchor"][1]
             w0 = tracered.to_trace_ns(p.t_a, p.anchor[0], a_ns)

@@ -153,7 +153,9 @@ def reduce(trace: dict, spans: list, anchor_mono_ns: int,
            module_hint: str = "segment") -> dict:
     """Busy and idle time, top ops, idle gaps by host span and the segment
     program's device time over the traced window; per-device numbers are
-    averaged over the devices that ran anything."""
+    averaged over the devices that ran anything (the chips of a mesh step in
+    lockstep: the mean is one chip's), with each device's own busy and
+    segment time kept beside the means, in plane order."""
     if trace["anchor"] is None:
         raise ValueError("the capture holds no anchor annotation")
     a_ns = trace["anchor"][1]
@@ -188,6 +190,8 @@ def reduce(trace: dict, spans: list, anchor_mono_ns: int,
         "window_s": (w1 - w0) / 1e9,
         "busy_s": sum(busy) / n_dev / 1e9,
         "segment_device_s": sum(seg_ns) / n_dev / 1e9,
+        "busy_by_device_s": [ns / 1e9 for ns in busy],
+        "segment_by_device_s": [ns / 1e9 for ns in seg_ns],
         "device_ops": [[n, ns / n_dev / 1e9] for n, ns in top],
         "idle_gaps": [[k, ns / n_dev / 1e9] for k, ns in gaps],
         "span_wall_s": _span_walls(spans, t_a_s, t_b_s),

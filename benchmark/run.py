@@ -60,6 +60,13 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
     from benchmark.harness import correct, drive, passes, tracered, work
     from benchmark.harness import manifest as mf
 
+    # the engine the configuration names, refused by name before any device
+    # work where the cell cannot hold it
+    try:
+        engine_name, ndev = mf.engine_of(cell["config_data"],
+                                            cell["chips"])
+    except ValueError as e:
+        raise SystemExit(f"benchmark: {e}") from None
     scratch = drive.scratch_dir(cell["name"])
     dev = drive.open_device(cell["chips"], rehearsal=rehearsal)
     t_open = time.monotonic()
@@ -73,6 +80,8 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
     sig = kernels.step_signature(drv.engine.bounds, drv.cfg["spec"],
                                  tuple(drv.cfg["invariants"]),
                                  tuple(drv.cfg["symmetry"]), None)
+    say(f"engine {engine_name} devices={ndev} "
+        f"caps={json.dumps(drv.cfg['engine_caps'][engine_name])}")
     say(f"gates {json.dumps(dict(sig[5:]))} "
         f"host_dedup={getattr(drv.engine, '_host_dedup', None)} "
         f"prefetch={getattr(drv.engine, '_prefetch', None)} "
@@ -131,7 +140,12 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         checks += correct.snapshot_checks(drv.snapshot, made, drv.pins,
                                           drv.b)
     ref = correct.reference_sample(drv.cfg, seed)
+    t_smp = time.monotonic()
     got = drv.expand_sample(ref["parents"])
+    say(f"sample: {len(ref['parents'])} reference states through the run's "
+        f"segment on {got.get('shards', 1)} shard(s): {len(got['states'])} "
+        f"rows streamed in {time.monotonic() - t_smp:.3f}s "
+        f"compiles={got['compiles']}")
     checks += correct.sample_checks(ref, got, drv.pins)
     plant = correct.planted_fault(drv.cfg, ref["level"], seed)
     t_plant, n0 = time.monotonic(), drv.compiles.n
@@ -219,9 +233,14 @@ def _work(drv, work, made: list) -> dict:
     from benchmark.harness import depthred, spanred
     eng = drv.engine
     te = drv.a + 1
+    # a mesh: one SHARD's work a lockstep step (every shard runs the whole
+    # dense step on its chunk, then filters what all the shards send it),
+    # and a shard's share of the exported stream (keys are owned evenly),
+    # to stand over one chip's segment time and one chip's peak
+    shards = drv.ndev
     steps = work.chunk_steps(drv.pins, drv.a, te, eng.caps.block,
-                             eng.config.chunk)
-    exported = drv.pins[te] - drv.pins[drv.a]
+                             eng.config.chunk, shards)
+    exported = (drv.pins[te] - drv.pins[drv.a]) // shards
     tp = next((p for p in made if p.traced and p.resumed
                and p.t_a is not None and p.t_trace_end is not None), None)
     if tp is not None:
@@ -235,9 +254,11 @@ def _work(drv, work, made: list) -> dict:
         "words_per_step": work.scan_words(
             eng.config.chunk, eng.A, eng.bounds.n_servers, eng.lay.width,
             bool(eng.config.symmetry)),
-        "bytes_per_step": work.step_bytes(eng.config.chunk, eng.A,
-                                          eng.schema.P),
+        "bytes_per_step": work.step_bytes(
+            eng.config.chunk, eng.A, eng.schema.P, shards,
+            getattr(eng.caps, "send", None)),
         "packed_words": eng.schema.P,
+        "shards": shards,
     }
 
 

@@ -8,10 +8,12 @@ and metric files resolving by name; the window-rate and span-median
 arithmetic and the pass clock, from Init and resumed from a snapshot; the
 trace reduction on the recorded trace; the readers of a pass at depth on a
 recorded span log; the plain reference against its own definition and the
-planted fault; a toy-size REHEARSAL of one whole run from Init and of one whose
-passes resume from a level-pinned snapshot (labelled as such, write no
-metric); and the controls — the same rehearsals with one guarantee or the
-timed path broken underneath have to come out ``correct: false``.
+planted fault; a toy-size REHEARSAL of one whole run from Init, of one whose
+passes resume from a level-pinned snapshot and of one on the mesh engine over
+four host devices (labelled as such, write no metric); and the controls — the
+same rehearsals with one guarantee or the timed path broken underneath (the
+exchange between shards misrouted, on the mesh) have to come out
+``correct: false``.
 """
 
 from __future__ import annotations
@@ -46,6 +48,42 @@ def toy_resume_cell() -> dict:
                 traffic_data=mf.read_json("testdata", "toy_resume.json"))
     cell["config_data"]["engine_caps"]["ddd"]["retention"] = "full"
     return cell
+
+
+def toy_mesh_cell() -> dict:
+    """The toy on the mesh engine: four shards (host devices here)."""
+    cell = toy_cell()
+    cell.update(name="toy.mesh4", config="toy_elect3_mesh4", chips=4,
+                config_data=mf.read_json("testdata", "toy_mesh4.json"))
+    return cell
+
+
+def four_devices() -> None:
+    """The mesh rehearsals need four devices: on the CPU four host devices,
+    which have to be asked for before JAX opens its backend (``main`` does;
+    a caller whose process already holds fewer gets the case in a child)."""
+    import jax
+    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        try:
+            jax.config.update("jax_num_cpu_devices", 4)
+        except RuntimeError:
+            pass                        # the backend is open already
+
+
+def on_four_devices(test_name: str) -> bool:
+    """True where this process can run a mesh case itself; otherwise the
+    case is run by name in a child with four host devices and has to pass
+    there."""
+    import subprocess
+    import jax
+    if jax.device_count() >= 4:
+        return True
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run([sys.executable, os.path.abspath(__file__), "-k",
+                           test_name], env=env, stdout=subprocess.PIPE,
+                          stderr=subprocess.DEVNULL, text=True, timeout=900)
+    assert done.returncode == 0, done.stdout[-2000:]
+    return False
 
 
 RESUME_LOG = os.path.join(mf.BENCH, "testdata", "spans_resume_small.jsonl")
@@ -93,6 +131,37 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
     assert mf.metric_reader("ramp_share_pct")(ev) == 40.0
     assert mf.metric_reader("setup_s")(ev) == 18.5
     assert mf.metric_reader("pass_spread_pct")(ev) == 100.0
+
+
+def test_a_cell_names_an_engine_it_can_hold():
+    cfg = mf.read_json("testdata", "toy_mesh4.json")
+    assert mf.engine_of(cfg, 4) == ("ddd-shard", 4)
+    assert mf.engine_of(mf.read_json("testdata", "toy_config.json"),
+                           1) == ("ddd", 1)        # no key: the ddd engine
+    for change, chips, word in (({}, 1, "holds 1 chip"),
+                                ({"engine": "bfs9"}, 4, "unknown engine"),
+                                ({"engine": "ddd"}, 4, "on one device"),
+                                ({"engine_caps": {}}, 4, "no engine_caps")):
+        try:
+            mf.engine_of(dict(cfg, **change), chips)
+            raise AssertionError(f"{change} on {chips} chip(s) was accepted")
+        except ValueError as e:
+            assert word in str(e) and cfg["name"] in str(e), e
+    # ... and the manifest's own check names the cell
+    m = mf.load()
+    cell = next(w for w in m["workloads"] if w["name"] == "elect5.shard4")
+    assert cell["chips"] == 4
+    cell["chips"] = 1
+    assert any("elect5.shard4" in b and "holds 1 chip" in b
+               for b in mf.problems(m))
+    # a run of such a cell ends before it opens a device, by name
+    from benchmark import run
+    try:
+        run.execute(dict(toy_mesh_cell(), chips=1), m, 1, 0.0, False,
+                    rehearsal=True)
+        raise AssertionError("a one-chip cell ran a four-device mesh")
+    except SystemExit as e:
+        assert "toy_elect3_mesh4 spans 4 devices" in str(e)
 
 
 # ------------------------------------------------- the reading's arithmetic
@@ -171,11 +240,49 @@ def test_span_clock_stamps_at_the_pinned_counts_only():
         signal.signal(signal.SIGINT, old)
 
 
+def test_the_mesh_engines_boundary_record_runs_one_level_ahead():
+    import signal
+    hits, closed = [], []
+    old = signal.signal(signal.SIGINT, lambda *_: hits.append(1))
+    try:
+        pins = [1, 50, 100, 250, 400, 900]
+        p = passes.Pass(index=0, t_call=time.monotonic())
+        clock = passes.SpanClock(p, pins, 2, 4, None, closed.append,
+                                 level_ahead=1)
+        # the mesh engine: in-window records carry the count of the last
+        # drain, the boundary record the level about to open; both repeat
+        for n, lvl in ((50, 2), (50, 2), (100, 3), (100, 3), (100, 3),
+                       (250, 4), (250, 4), (400, 5)):
+            clock({"n_states": n, "level": lvl})
+            assert (p.t_a is None) == (n < 100)
+        assert p.reached and hits == [1] and len(closed) == 1
+        clock({"n_states": 400, "level": 5})     # from the overshoot window
+        assert hits == [1]                        # one SIGINT, never two
+        # the ddd engine's pair (level 2, 100) is no boundary here
+        q = passes.Pass(index=1, t_call=0.0)
+        passes.SpanClock(q, pins, 2, 4, level_ahead=1)(
+            {"n_states": 100, "level": 2})
+        assert q.t_a is None
+    finally:
+        signal.signal(signal.SIGINT, old)
+
+
 def test_chunk_steps_and_words_from_shapes():
     pins = [1, 2, 6, 5000, 9000]
     # level 2 -> 3: frontier of 4 rows = 1 step; level 3 -> 4: 4994 rows in
     # blocks of 4096 = ceil(4096/1024) + ceil(898/1024) = 4 + 1
     assert work.chunk_steps(pins, 2, 4, 4096, 1024) == 1 + 5
+    # four shards, a window of 4 x 2048 rows dealt block by block: shard 0
+    # takes 2048 of the 4994, and the window runs as long as it does
+    assert work.chunk_steps(pins, 2, 4, 2048, 1024, 4) == 1 + 2
+    assert work.chunk_steps(pins, 2, 4, 1024, 1024, 4) == 1 + 1 + 1
+    # one shard's bytes a step: its chunk in, a bucket for every lane the
+    # four shards send it
+    assert work.step_bytes(4096, 38, 11) == 4096 * 11 * 4 + 4096 * 38 * 8 * 8
+    assert work.step_bytes(4096, 38, 11, 4) \
+        == 4096 * 11 * 4 + 4 * 4096 * 38 * 8 * 8
+    assert work.step_bytes(4096, 38, 11, 4, send=1000) \
+        == 4096 * 11 * 4 + 4 * 1000 * 8 * 8
     assert work.scan_words(4096, 38, 5, 104, True) == 4096 * 38 * 120 * 104
     assert work.scan_words(4096, 38, 5, 104, False) == 4096 * 38 * 104
 
@@ -551,6 +658,108 @@ def test_filter_only_dedup_under_resumed_passes_comes_out_not_correct():
     assert res["correct"] is False
 
 
+# ------------------------------------------------ the mesh, toy size, the CPU
+
+def rehearse_mesh(seed: int, trace: bool = False) -> dict:
+    from benchmark import run
+    return run.execute(toy_mesh_cell(), mf.load(), seed, 0.0, trace,
+                       rehearsal=True)
+
+
+def test_mesh_rehearsal_is_correct_and_every_key_is_on_its_owner():
+    if not on_four_devices(
+            "test_mesh_rehearsal_is_correct_and_every_key_is_on_its_owner"):
+        return
+    res = rehearse_mesh(3_000_000_031, trace=True)
+    assert res["rehearsal"] is True and res["metrics"] == {}
+    assert res["correct"] is True and res["device"]["count"] >= 4
+    assert res["attempted"] >= 3 and res["failed"] == 0
+    assert res["checks"]["owner_misrouted"] == {"value": 0, "limit": 0}
+    assert all(c["value"] <= c["limit"] for c in res["checks"].values())
+    # the one-chip rehearsal compares the same numbers but that one
+    assert set(res["checks"]) - set(rehearse(21)["checks"]) \
+        == {"owner_misrouted"}
+
+
+def test_a_misrouted_exchange_comes_out_not_correct():
+    # the exchange between chips broken underneath: every candidate goes to
+    # the shard after its owner.  All duplicates of a key still meet, so
+    # every count holds; the one number that the mesh adds sees it
+    if not on_four_devices(
+            "test_a_misrouted_exchange_comes_out_not_correct"):
+        return
+    from benchmark.harness import breakers
+    with breakers.misrouted_exchange():
+        res = rehearse_mesh(22)
+    assert res["correct"] is False and res["failed"] == 0
+    wrong = {n for n, c in res["checks"].items() if c["value"] > c["limit"]}
+    assert wrong == {"owner_misrouted"}
+    assert res["checks"]["owner_misrouted"]["value"] > 100
+    # a one-chip engine has no exchange: the control passes it
+    with breakers.misrouted_exchange():
+        assert rehearse(23)["correct"] is True
+
+
+def test_short_keys_on_the_mesh_come_out_not_correct():
+    if not on_four_devices(
+            "test_short_keys_on_the_mesh_come_out_not_correct"):
+        return
+    from benchmark.harness import breakers
+    with breakers.short_keys(16):
+        res = rehearse_mesh(24)
+    assert res["correct"] is False
+
+
+def test_exchange_time_counts_nested_ops_and_busy_skew_is_of_the_chips():
+    from benchmark.harness import meshred, stagered
+    seg = "jit(segment)/while/body/"
+    ops = [["while.1", 0, 1000, "jit(segment)/while"],
+           ["fusion.2", 10, 200, seg + "filter_insert/gather"],
+           ["all-to-all.3", 300, 400, seg + "exchange/all_to_all"],
+           # nested under the collective: its own self time is counted too
+           ["copy.4", 350, 100, seg + "exchange/all_to_all/copy"],
+           ["scatter.5", 720, 80, seg + "exchange/scatter"],
+           ["fusion.6", 820, 50, seg + "exchanged/not_the_scope"],
+           ["fusion.7", 2000, 50, seg + "exchange/outside_the_module"]]
+    plane = {"XLA Ops": ops, "XLA Modules": [["jit_segment(1)", 0, 1000]]}
+    slow = {"XLA Ops": [[n, 2 * s, 2 * d, path] for n, s, d, path in ops],
+            "XLA Modules": [["jit_segment(1)", 0, 2000]]}
+    idle = {"XLA Ops": [], "XLA Modules": []}
+    trace = {"devices": {"/device:TPU:0": plane, "/device:TPU:1": slow,
+                         "/device:TPU:9": idle}}
+    red = meshred.scope_times(trace, 0, 3000)
+    assert red["devices"] == 2
+    assert [p["scope_ns"] for p in red["planes"]] == [480, 960]
+    assert red["scope_ns"] == 720 and red["scope_ns_max"] == 960
+    by_dev = stagered.stage_times(trace, 0, 3000)["stage_ns_by_device"]
+    assert [d["filter_insert"] for d in by_dev] == [200, 400]
+    assert red["planes"][0]["top"][0] == ("all-to-all.3", 300)
+    assert meshred.scope_times({"devices": {"/device:TPU:9": idle}},
+                               0, 1500) is None
+    ev = {"meshred": red, "work": {"steps": 3}, "passes": []}
+    assert abs(mf.metric_reader("stage_exchange_ms")(ev)
+               - 720 / 1e6 / 3) < 1e-15
+    # no op under the scope: nothing to read, never 0.0
+    ev["meshred"] = dict(red, scope_ns=0)
+    assert mf.metric_reader("stage_exchange_ms")(ev) is None
+    assert meshred.busy_skew_pct([2.0, 1.5, 1.9, 2.0]) == 25.0
+    assert meshred.busy_skew_pct([2.0]) is None
+    tr = {"busy_by_device_s": [0.8, 1.0, 1.0, 0.9]}
+    assert abs(mf.metric_reader("chip_busy_skew_pct")({"trace": tr})
+               - 20.0) < 1e-9
+    assert mf.metric_reader("chip_busy_skew_pct")({"trace": None}) is None
+
+
+def test_overshoot_is_the_median_of_the_sound_untraced_passes():
+    made = [passes.Pass(index=k, t_call=0.0, t_a=1.0, t_b=2.0,
+                        t_return=2.0 + over, traced=k == 1)
+            for k, over in enumerate((0.05, 9.0, 0.07, 0.50))]
+    made[3].problem = "short of B"
+    assert abs(mf.metric_reader("overshoot_s")({"passes": made})
+               - 0.06) < 1e-9
+    assert mf.metric_reader("overshoot_s")({"passes": []}) is None
+
+
 def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
     from benchmark.harness import correct
     from benchmark.reference import canon, interp, invariants
@@ -649,6 +858,7 @@ def test_a_pass_cut_short_comes_out_not_correct():
 
 
 def main(argv) -> int:
+    four_devices()
     pick = argv[argv.index("-k") + 1] if "-k" in argv else ""
     tests = [(n, f) for n, f in sorted(globals().items())
              if n.startswith("test_") and pick in n]
