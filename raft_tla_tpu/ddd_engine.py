@@ -1068,6 +1068,11 @@ class DDDEngine:
         # writes the slabs
         self._buf_rows = self.caps.seg_rows + _slab_plan(
             self.caps.route_rows or config.chunk * self.A)[1]
+        # what the step below is built with, for the ``pass`` span: a
+        # trace then names the program that ran (the routed step has no
+        # ladder: it compacts the live lanes before its scan)
+        self._prescan = not self.caps.route_rows and \
+            kernels._prescan_enabled(config.bounds, config.symmetry)
         self._segment = jax.jit(
             _build_segment(config, self.caps, self.A, self.lay.width,
                            self.schema),
@@ -1206,7 +1211,8 @@ class DDDEngine:
         # prefetcher's spans on their own threads, one ``segment`` per
         # harvested segment on the synthetic ``segments`` track.
         tr = tel.trace
-        pass_sp = tr.open("pass", engine="ddd", resumed=resume is not None)
+        pass_sp = tr.open("pass", engine="ddd", resumed=resume is not None,
+                          prescan=self._prescan)
         _cleanup.callback(pass_sp.close)     # raise paths; idempotent
         bounds = self.bounds
         init_py = init_override if init_override is not None \

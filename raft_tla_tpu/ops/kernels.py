@@ -32,7 +32,6 @@ prefixes.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -630,10 +629,9 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     # over the lanes — one multiply-reduce of the byte-wide feature
     # matrix against that permutation's row of constants, one fusion
     # that relabels and ranks the message bag.  No state array is
-    # gathered, relabelled, sorted or packed in it (through PR 28: ~290
-    # operations, 21 unfused dynamic-update-slices from the message sort
-    # network, two custom gather fusions a permuted field; through PR
-    # 26 also the packed [lanes, W] row).  PERF.md, PRs 27 and 29.
+    # gathered, relabelled, sorted or packed in it: an image costs
+    # 0.43-0.79 ns a lane (runs/prescan_ab.out), which is what
+    # _prescan_enabled's rule for the ladder below is derived from.
     # Mosaic findings: git show f293573:RESULTS.md "Pallas orbit
     # kernel", runs/pallas_orbit_p24.out.
     # The view folds into the DEDUP KEY only: stored rows, invariants and
@@ -691,28 +689,64 @@ def build_step(bounds: Bounds, spec: str = "full", invariants: tuple = (),
 
 # Pre-orbit dedup compaction ladder: the orbit scan runs on the
 # smallest static slot count the chunk's raw-unique candidates fit —
-# N/4, then N/2, then the full N lanes.  Justification (measured,
-# runs/step_anatomy.out "distinct-row measurement"): on 4,096 DISTINCT
-# depth-9 flagship rows the valid share is 0.419 and the raw in-chunk
-# duplicate share 0.450, so unique candidates (+1 sentinel group for
-# every invalid lane) are 23.0% of N — the N/4 rung; the elect5
-# campaign's deeper regime (valid share to 0.63) lands on N/2.
-# Measured effect at that shape: 815.9 -> 367.4 ms/chunk on an idle
-# CPU core (2.22x; the .out records both runs).  Raw-identical
-# successors are the SAME state, so the group representative's
-# canonical fingerprint is bit-identical to every member's — counts,
-# discovery order and checkpoints are unchanged on every rung.
+# N/4, then N/2, then the full N lanes.  What real chunks of 4,096
+# frontier rows hold (runs/prescan_ab.out: valid_share, uniq_share_max):
+# the dense step calls 0.40 of the flagship's lanes valid at level 15
+# and their raw-distinct rows (+1 sentinel group for every invalid
+# lane) are up to 23.2% of N, full5's at level 10 0.36 and 20.3% — the
+# N/4 rung; elect5 at level 13 (0.66, 37.6%) and six servers at level
+# 13 (0.67, 35.3%) land on N/2.  Raw-identical successors are the SAME
+# state, so the group representative's canonical fingerprint is
+# bit-identical to every member's — counts, discovery order and
+# checkpoints are unchanged on every rung, and with no ladder at all.
 _PRESCAN_RUNGS = (4, 2)      # divisors of N, tried in order
 
 
 def _prescan_enabled(bounds, symmetry):
-    """Platform/shape gate for the prescan ladder.  The lexsort is a
-    fixed per-chunk cost while the saving scales with |G| (the scan
-    iterations skipped per deduplicated lane), and TPU sorts are slow:
-    measured on-chip (runs/prescan_ab.py, sync-timed medians), the
-    ladder is a 1.44x LOSS at |G|=6 (flagship, 117.5 vs 81.5 ms/chunk)
-    but a 1.25x win at |G|=120 (elect5, 201.7 vs 251.5 ms/chunk).  On
-    CPU it wins already at |G|=6 (2.22x, runs/step_anatomy.out)."""
+    """Whether a program gets the prescan ladder.  The code's choice,
+    from the backend: the CPU's programs take it, a TPU's never do.
+
+    The ladder is a fixed cost a lane — a raw fingerprint of every packed
+    row, an N-lane lexsort, a scatter, one ``a[rep]`` gather a struct
+    field — and what it saves is scan: |G| images a lane on the lanes a
+    rung leaves out, half or three quarters of them.  It pays where that
+    saving passes the fixed cost, so the rule follows what an image
+    costs, and since PR 29 (the scan's body moves no state data) an
+    image is cheap on the chip.
+
+    On a TPU v5e (runs/prescan_ab.out: PR 32, one call, the whole fused
+    step on real frontier chunks of 4,096 rows, ladder forced on / off /
+    no orbit stage at all, ms a chunk):
+
+    - |G| = 6, flagship3, 172,032 lanes: 18.06 / 6.59 / 5.03 — 0.37x;
+    - |G| = 120, elect5, 155,648 lanes: 28.58 / 13.94 / 3.65 — 0.49x;
+    - |G| = 120, full5, 344,064 lanes: 62.86 / 44.91 / 12.31 — 0.71x;
+    - |G| = 720, six-server election, 208,896 lanes: 75.66 / 69.35 /
+      4.40 — 0.92x.
+
+    A full scan costs 0.43-0.79 ns a lane an image (1.5 at |G| = 6,
+    where building the key table shows), the ladder 74-186 ns a lane
+    (on less no-orbit-stage less the rung's share of the scan; it grows
+    with the row's width and the rung's slots), and at depth both
+    elections take the N/2 rung, which saves half.  So the ladder loses
+    at every |G| ops/symmetry admits on one axis (MAX_SYM_SERVERS = 6),
+    and in the benchmark it cost 12.8 and 16.5 ms of a 39.6 and 89.3 ms
+    step (``stage_orbit_ms`` 22.53 -> 9.73 in elect5.passes, 45.86 ->
+    29.33 in full5.passes: PERF.md section 6, PR 32).  Only a program
+    with both axes at six servers (|G| >= 1,440), or a six-server one
+    whose chunks fit the N/4 rung as full5's do, would gain by these
+    figures; none is measured and no cell runs one, so the TPU's rule
+    is never, and the ladder is the CPU backend's.
+
+    On the CPU it is on for every symmetric program (runs/prescan_ab.py
+    --cpu, PR 32, this repository's sandbox, the same step and chunks at
+    shallower levels): 0.98x at |G| = 6 (flagship3, level 10), 2.42x at
+    |G| = 120 (elect5, level 11), 2.38x at |G| = 720 (six servers, level
+    10) — an image costs 80-190 ns a lane there, and the tier-1 suite's
+    wall rests on it.
+
+    ``RAFT_TLA_PRESCAN`` / ``--prescan {on,off}`` is the measuring
+    override; keys are bit-identical either way."""
     if not _PRESCAN_RUNGS or not symmetry:
         return False
     import os
@@ -721,14 +755,7 @@ def _prescan_enabled(bounds, symmetry):
         return True              # in-engine bench A/B) — not for prod
     if force == "off":
         return False
-    if jax.default_backend() == "cpu":
-        return True
-    g = 1
-    if "Server" in symmetry:
-        g *= math.factorial(bounds.n_servers)
-    if "Value" in symmetry:
-        g *= math.factorial(bounds.n_values)
-    return g >= 120
+    return jax.default_backend() == "cpu"
 
 
 def step_signature(bounds, spec, invariants, symmetry, view):
@@ -753,9 +780,9 @@ def step_signature(bounds, spec, invariants, symmetry, view):
 def _orbit_fp_prescan(orbit_fp, flat, raw_hi, raw_lo, N):
     """Orbit-scan only the first occurrence of each raw key, gather the
     canonical fingerprints back through the group map (see the
-    _PRESCAN_RUNGS comment; runs/step_anatomy.out has the measured
-    justification).  Keys are (hi, lo) uint32 pairs — x64 is disabled,
-    a u64 fuse would silently truncate."""
+    _PRESCAN_RUNGS comment; _prescan_enabled says which programs take
+    it and why).  Keys are (hi, lo) uint32 pairs — x64 is disabled, a
+    u64 fuse would silently truncate."""
     # traced under apply_stages' ``prescan`` scope; the scans it places
     # are ``orbit_scan`` inside it
     idx = jnp.lexsort((raw_lo, raw_hi))

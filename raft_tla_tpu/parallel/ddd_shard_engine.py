@@ -522,6 +522,10 @@ class DDDShardEngine:
         st_specs = MStats(*(getattr(specs, f)
                             for f in MStats._fields[:-2]), P(), P())
         dp = P(self._ax)
+        # what the step below is built with, for the ``pass`` span (the
+        # dense and the CP step both go through kernels.apply_stages)
+        self._prescan = kernels._prescan_enabled(config.bounds,
+                                                 config.symmetry)
         fn = _build_segment(config, self.caps, self.A, self.lay.width,
                             self.schema, self.ndev, nici, axes)
         self._segment = jax.jit(
@@ -757,7 +761,8 @@ class DDDShardEngine:
         # {segment_wait, d2h} / level_close
         tr = tel.trace
         pass_sp = tr.open("pass", engine="ddd-shard",
-                          resumed=resume is not None)
+                          resumed=resume is not None,
+                          prescan=self._prescan)
         _cleanup.callback(pass_sp.close)     # raise paths; idempotent
         bounds = self.bounds
         init_py = init_override if init_override is not None \

@@ -8,16 +8,21 @@ the ladder at all, and that nothing else splits a compile.
   full scan), at |G| = 6, 12 and 120 (the benchmark's two five-server
   universes), under a VIEW and under faithful history; the rung is seen,
   not inferred: the scan a ``lax.cond`` takes reports its lane count;
-- ``_prescan_enabled``'s auto policy by backend and |G|: it decides which
-  program each benchmark cell compiles;
+- ``_prescan_enabled``'s auto policy, by backend at |G| = 6 to 720: it
+  decides which program each benchmark cell compiles, and which
+  configurations of ``benchmark/configs/`` have the ladder on the TPU
+  (none) is pinned;
 - ``step_signature`` / ``serve.batch.bin_key`` split on the prescan
   resolution and on no other environment variable;
 - the ``ddd`` engine with the ladder forced off against on: the same
   level counts and the same discovery order, rows and keys.
 """
 
+import dataclasses
 import functools
 import hashlib
+import json
+import os
 import zlib
 
 import jax
@@ -115,21 +120,43 @@ _B3 = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2,
              max_dup=1)                      # flagship3: |G| = 6
 _B5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=0, max_msgs=2,
              max_dup=1)                      # elect5 / full5: |G| = 120
+_B6 = dataclasses.replace(_B5, n_servers=6)  # no cell's: |G| = 720
 
 
 @pytest.mark.parametrize("backend,bounds,axes,want", [
     ("cpu", _B3, ("Server",), True),
     ("tpu", _B3, ("Server",), False),        # flagship3.passes
-    ("tpu", _B5, ("Server",), True),         # elect5.passes, full5.passes
+    ("tpu", _B5, ("Server",), False),        # elect5.passes, full5.passes
     ("tpu", _B5, (), False),
     ("tpu", _B3, ("Server", "Value"), False),    # the axes multiply: 3!·2!
-    ("tpu", _B5, ("Server", "Value"), True),
+    ("tpu", _B5, ("Server", "Value"), False),
+    ("tpu", _B6, ("Server",), False),        # runs/prescan_ab.out: 0.92x
 ], ids=["cpu-G6", "tpu-G6", "tpu-G120", "tpu-no-symmetry", "tpu-G12",
-        "tpu-G240"])
+        "tpu-G240", "tpu-G720"])
 def test_prescan_auto_policy(monkeypatch, backend, bounds, axes, want):
     monkeypatch.delenv("RAFT_TLA_PRESCAN", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert kernels._prescan_enabled(bounds, axes) is want
+
+
+_CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs")
+# the benchmark's configurations whose program has the ladder on the
+# TPU: a change of the rule that re-programs a cell has to name it here
+_LADDER_ON_THE_TPU = set()
+
+
+@pytest.mark.parametrize("name", sorted(
+    f[:-len(".json")] for f in os.listdir(_CONFIGS) if f.endswith(".json")))
+def test_which_benchmark_configurations_take_the_ladder_on_the_tpu(
+        monkeypatch, name):
+    with open(os.path.join(_CONFIGS, name + ".json")) as f:
+        cfg = json.load(f)
+    monkeypatch.delenv("RAFT_TLA_PRESCAN", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = kernels._prescan_enabled(Bounds(**cfg["bounds"]),
+                                   tuple(cfg["symmetry"]))
+    assert got is (name in _LADDER_ON_THE_TPU)
 
 
 # -- compile identity --------------------------------------------------------
@@ -167,7 +194,6 @@ def test_compile_identity_splits_on_prescan_alone(monkeypatch, signature):
     """Five positional items, then ("prescan", .), ("devdedup", .): the
     prescan resolution moves the signature, and the two names of that
     tail are the only ``RAFT_TLA_*`` variables it is computed from."""
-    import os
     args = (_B3, "full", ("NoTwoLeaders",), ("Server",), None)
     env = _ReadsOf(os.environ)
     monkeypatch.setattr(os, "environ", env)
@@ -211,6 +237,7 @@ def test_ddd_engine_prescan_off_equals_on(monkeypatch, cfg, depth):
     for mode in ("off", "on"):
         monkeypatch.setenv("RAFT_TLA_PRESCAN", mode)
         eng = DDDEngine(cfg, caps)
+        assert eng._prescan is (mode == "on")
 
         def stop_past_depth(rec):
             if rec["level"] > depth:
@@ -233,3 +260,43 @@ def test_ddd_engine_prescan_off_equals_on(monkeypatch, cfg, depth):
         seen[mode] = (cum, order.hexdigest())
     assert seen["on"] == seen["off"]
     assert seen["on"][0][-1] > 1000
+
+
+@pytest.mark.parametrize("engine,mode,want", [
+    ("ddd", "on", True), ("ddd", "off", False),
+    ("ddd-routed", "on", False),             # compacts before its scan
+    ("ddd-shard", "on", True)])
+def test_pass_span_says_which_step_ran(monkeypatch, tmp_path, engine, mode,
+                                       want):
+    """A trace names the program without the environment: the ``pass``
+    span's ``prescan`` is what the engine's step was built with."""
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+    from raft_tla_tpu.models import spec as S
+
+    monkeypatch.setenv("RAFT_TLA_PRESCAN", mode)
+    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
+    cfg = CheckConfig(
+        bounds=Bounds(n_servers=2, n_values=1, max_term=2, max_log=0,
+                      max_msgs=2),
+        spec="election", invariants=("NoTwoLeaders",), symmetry=("Server",),
+        chunk=32)
+    caps = dict(block=256, table=1 << 14, flush=1 << 10, levels=64)
+    if engine == "ddd-shard":
+        from raft_tla_tpu.parallel.ddd_shard_engine import (
+            DDDShardCapacities, DDDShardEngine)
+        from raft_tla_tpu.parallel.shard_engine import make_mesh
+        eng = DDDShardEngine(cfg, make_mesh(2),
+                             DDDShardCapacities(seg_rows=1 << 14, **caps))
+    else:
+        if engine == "ddd-routed":
+            caps["route_rows"] = cfg.chunk * len(
+                S.action_table(cfg.bounds, cfg.spec))
+        eng = DDDEngine(cfg, DDDCapacities(**caps))
+    log = str(tmp_path / "pass.events")
+    res = eng.check(events=log)
+    assert res.violation is None and res.complete
+    with open(log) as f:
+        evs = [json.loads(line) for line in f]
+    (root,) = [e for e in evs
+               if e["event"] == "span" and e["name"] == "pass"]
+    assert root["args"]["prescan"] is want

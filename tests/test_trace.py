@@ -459,6 +459,7 @@ def test_traced_ddd_spans_form_one_tree(traced_toy):
     assert "level_close" in names["dedup_wait"]
     p = roots[0]["args"]
     assert p["engine"] == "ddd" and p["resumed"] is False
+    assert p["prescan"] is False             # CFG has no SYMMETRY
     assert p["n_states"] == N_TOY and p["stopped_by"] is None
     assert p["levels"] == len(res.levels)
 

@@ -156,9 +156,9 @@ def test_multichip_rehearsal_on_two_virtual_devices(ctx, capsys):
 
 
 def test_symmetric_election_with_the_prescan_ladder_off(monkeypatch):
-    """The step the CHIP selects at |G|=6: on the CPU the prescan ladder
-    is on for every symmetric run, so without this tier-1 never runs the
-    flagship's on-chip program."""
+    """The step the CHIP selects, at every |G| since PR 32: on the CPU the
+    prescan ladder is on for every symmetric run, so without this tier-1
+    never runs a cell's on-chip program."""
     monkeypatch.setenv("RAFT_TLA_PRESCAN", "off")
     from raft_tla_tpu.config import Bounds, CheckConfig
     from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
