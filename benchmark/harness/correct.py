@@ -62,22 +62,22 @@ def snapshot_checks(snapshot: dict, pass_list: list, pins: list,
 
 
 def reference_sample(cfg: dict, seed: int):
-    """The plain reference's own BFS of the first levels, and the seeded
-    sample of its deepest level with that sample's successor orbits."""
+    """The plain reference's own BFS of the first levels (from the Init the
+    configuration states, under its SYMMETRY axes), and the seeded sample of
+    its deepest level with that sample's successor orbits."""
     bounds = Bounds(**cfg["bounds"])
-    sym = bool(cfg["symmetry"])
-    if sym and cfg["symmetry"] != ["Server"]:
-        raise ValueError("the reference reduces over Server only")
+    sym = cfg["symmetry"]
     cum, level, viol = canon.bfs_levels(
         bounds, cfg["spec"], sym, tuple(cfg["invariants"]),
-        cfg.get("sample_min_level_states", MIN_LEVEL_STATES))
+        cfg.get("sample_min_level_states", MIN_LEVEL_STATES),
+        init=canon.stated_init(bounds, cfg.get("init"), cfg["invariants"]))
     rng = random.Random(seed)
     parents = rng.sample(level, min(SAMPLE, len(level)))
     reps, n_trans, con = canon.successor_orbits(parents, bounds, cfg["spec"],
                                                 sym)
     return {"cumulative": cum, "violations": viol, "level": level,
-            "parents": parents, "orbits": reps, "n_transitions": n_trans, "constraint": con,
-            "sym": sym}
+            "parents": parents, "orbits": reps, "n_transitions": n_trans,
+            "constraint": con, "sym": sym, "n_values": bounds.n_values}
 
 
 def sample_checks(ref: dict, got: dict, pins: list) -> list:
@@ -89,7 +89,7 @@ def sample_checks(ref: dict, got: dict, pins: list) -> list:
     stream is the union of the shards' streams and one number joins:
     ``owner_misrouted``, streamed keys found on a shard other than
     ``key_hi % ndev``."""
-    key = canon.canonical if ref["sym"] else canon.as_tuple
+    key = canon.orbit_key(ref["sym"], ref.get("n_values", 0))
     streamed = [key(_ref_state(s)) for s in got["states"]]
     sset = set(streamed)
     con_wrong = sum(ref["constraint"].get(k) is not c
@@ -123,38 +123,79 @@ def sample_checks(ref: dict, got: dict, pins: list) -> list:
     ] + owner
 
 
+def _two_leaders_in_a_term(s, bounds: Bounds, rng):
+    """``s`` rewritten so that server i leads term t and server j is a
+    candidate of term t holding a quorum of votes: its ``BecomeLeader(j)``
+    successor has two leaders in one term."""
+    n = bounds.n_servers
+    i, j = rng.sample(range(n), 2)
+    t = max(s.term)
+    votes = 1 << j
+    for k in rng.sample([k for k in range(n) if k != j], n // 2):
+        votes |= 1 << k
+    role = tuple(S.LEADER if k == i else S.CANDIDATE if k == j
+                 else S.FOLLOWER if (r == S.LEADER and s.term[k] == t)
+                 else r for k, r in enumerate(s.role))
+    term = tuple(t if k in (i, j) else x for k, x in enumerate(s.term))
+    return s._replace(
+        role=role, term=term,
+        votedFor=tuple(j + 1 if k == j else v
+                       for k, v in enumerate(s.votedFor)),
+        vResp=tuple(votes if k == j else v for k, v in enumerate(s.vResp)),
+        vGrant=tuple(votes if k == j else v
+                     for k, v in enumerate(s.vGrant)))
+
+
+def _commit_a_later_leader_lacks(s, bounds: Bounds, rng):
+    """``s`` rewritten so that beside its leader i of the newest term t a
+    server j leads term t - 1 with one entry of that term in its log, and
+    ``matchIndex[j]`` claims a quorum for it: ``AdvanceCommitIndex(j)``
+    commits an entry that the later leader's log lacks.  It needs only the
+    log actions.  None where ``s`` has no leader of a term above 1."""
+    n, t = bounds.n_servers, max(s.term)
+    leaders = [k for k in range(n) if s.role[k] == S.LEADER and s.term[k] == t]
+    if not leaders or t < 2:
+        return None
+    i = rng.choice(leaders)
+    j = rng.choice([k for k in range(n) if k != i])
+    entry = (t - 1, rng.randint(1, bounds.n_values))
+    agreed = set(rng.sample([k for k in range(n) if k != j], n // 2))
+
+    def put(row, v):
+        return tuple(v if k == j else x for k, x in enumerate(row))
+
+    return s._replace(
+        role=put(s.role, S.LEADER), term=put(s.term, t - 1),
+        commitIndex=put(s.commitIndex, 0), log=put(s.log, (entry,)),
+        matchIndex=put(s.matchIndex,
+                       tuple(int(k in agreed) for k in range(n))))
+
+
 def planted_fault(cfg: dict, level: list, seed: int) -> dict:
     """The planted fault: a state of the reference's level, drawn with the
-    seed, rewritten so that server i leads term t and server j is a
-    candidate of term t holding a quorum of votes.  It holds every invariant
-    itself; its ``BecomeLeader(j)`` successor has two leaders in one term.
+    seed and rewritten so that it holds every invariant itself and one step
+    breaks one.  Which rewrite is decided by the configuration's action
+    table, never by its file: where the table has ``BecomeLeader``, two
+    leaders in one term; where it has not and has ``AdvanceCommitIndex``, a
+    commit that a later leader's log lacks (``LeaderCompleteness``).
     Returns the parent and ``{orbit of a violating successor: names of the
     invariants it breaks}``, both judged by the plain reference."""
     bounds = Bounds(**cfg["bounds"])
-    n = bounds.n_servers
     invs = {nm: invariants.REGISTRY[nm] for nm in cfg["invariants"]}
-    key = canon.canonical if cfg["symmetry"] else canon.as_tuple
+    key = canon.orbit_key(cfg["symmetry"], bounds.n_values)
     table = S.action_table(bounds, cfg["spec"])
+    families = {a.family for a in table}
+    if S.BECOMELEADER in families:
+        rewrite = _two_leaders_in_a_term
+    elif S.ADVANCECOMMIT in families:
+        rewrite = _commit_a_later_leader_lacks
+    else:
+        raise ValueError(f"spec {cfg['spec']!r} has neither BecomeLeader nor "
+                         "AdvanceCommitIndex: no planted fault is known for it")
     rng = random.Random(f"plant/{seed}")
     for s in rng.sample(level, len(level)):
-        i, j = rng.sample(range(n), 2)
-        t = max(s.term)
-        votes = 1 << j
-        for k in rng.sample([k for k in range(n) if k != j], n // 2):
-            votes |= 1 << k
-        role = tuple(S.LEADER if k == i else S.CANDIDATE if k == j
-                     else S.FOLLOWER if (r == S.LEADER and s.term[k] == t)
-                     else r for k, r in enumerate(s.role))
-        term = tuple(t if k in (i, j) else x for k, x in enumerate(s.term))
-        parent = s._replace(
-            role=role, term=term,
-            votedFor=tuple(j + 1 if k == j else v
-                           for k, v in enumerate(s.votedFor)),
-            vResp=tuple(votes if k == j else v
-                        for k, v in enumerate(s.vResp)),
-            vGrant=tuple(votes if k == j else v
-                         for k, v in enumerate(s.vGrant)))
-        if not interp.constraint_ok(parent, bounds) \
+        parent = rewrite(s, bounds, rng)
+        if parent is None or not interp.constraint_ok(parent, bounds) \
                 or not all(f(parent, bounds) for f in invs.values()):
             continue
         violators = {}

@@ -14,6 +14,8 @@ import time
 from benchmark.harness import correct
 from benchmark.harness import manifest as mf
 from benchmark.harness import passes
+from benchmark.reference import canon
+from benchmark.reference.bounds import Bounds as RefBounds
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -174,6 +176,19 @@ class Driver:
                 "in level files that a resume rewrites (retention is not "
                 "'full'): it would need one copy a pass")
         self.snapshot = None            # set by build_snapshot()
+        # a configuration that states its Init: every warm and timed pass is
+        # handed it through the public ``init_override``; one that states
+        # none drives the calls it always drove
+        self._from = {}
+        if "init" in self.cfg:
+            self._from["init_override"] = _program_state(canon.stated_init(
+                RefBounds(**self.cfg["bounds"]), self.cfg["init"],
+                self.cfg["invariants"]))
+        if self._from and self.snapshot_level is not None:
+            raise ValueError(
+                f"configuration {self.cfg['name']} states its Init and "
+                f"traffic {cell['traffic']} resumes from a snapshot: no cell "
+                "has driven a resume with init_override yet")
         count_a, count_b = self.pins[self.a], self.pins[self.b]
         if (t["count_at_start"], t["count_at_end"]) != (count_a, count_b):
             raise ValueError(
@@ -282,7 +297,8 @@ class Driver:
             if closer is not None:
                 closer.start()
             p.t_call = time.monotonic()
-            result = self.engine.check(on_progress=clock, **kw)
+            result = self.engine.check(on_progress=clock, **self._from,
+                                       **kw)
         finally:
             if trace:
                 if env_trace is None:

@@ -44,6 +44,7 @@ class Pass:
     resumed: bool = False           # check(resume=): t_a is its run_start
     start_keys: int | None = None   # resumed: the engine's first count
     n_states: int | None = None     # the result's count at the return
+    coverage: dict | None = None    # the result's count by action family
 
     @property
     def reached(self) -> bool:
@@ -142,6 +143,7 @@ def finish(p: Pass, result, pins: list, end_level: int) -> Pass:
     """Fill the pass from the engine's result and hold it to the pins."""
     p.t_return = time.monotonic()
     p.n_states = result.n_states
+    p.coverage = dict(result.coverage)
     if p.resumed:
         began = run_start(p.events)
         if began is not None:

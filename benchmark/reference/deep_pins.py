@@ -5,9 +5,11 @@ than a run's own check reaches.
     python3 benchmark/reference/deep_pins.py --config flagship3 --end-level 23 \
         [--workers 7]
 
-The same search as ``canon.bfs_levels`` (level-synchronous BFS from Init,
-states canonicalised in plain Python and compared as states, a state failing
-the StateConstraint counted and checked but not expanded), with two changes
+The same search as ``canon.bfs_levels`` (level-synchronous BFS from Init, the
+spec's or the one the configuration states, under the configuration's
+SYMMETRY axes; states canonicalised in plain Python and compared as states, a
+state failing the StateConstraint counted and checked but not expanded), with
+two changes
 that let it reach millions of orbits: ``seen`` holds the 16-byte
 ``hashlib.blake2b`` digest of the canonical tuple's ``repr`` instead of the
 tuple (2^-128 a pair: nothing a count can show), and the frontier is expanded
@@ -44,10 +46,10 @@ def digest(key: tuple) -> bytes:
     return hashlib.blake2b(repr(key).encode("ascii"), digest_size=16).digest()
 
 
-def _start(bounds_kw: dict, spec: str, symmetry: bool, inv_names: tuple):
+def _start(bounds_kw: dict, spec: str, symmetry: tuple, inv_names: tuple):
     bounds = Bounds(**bounds_kw)
     _W.update(bounds=bounds, table=S.action_table(bounds, spec),
-              key=canon.canonical if symmetry else canon.as_tuple,
+              key=canon.orbit_key(symmetry, bounds.n_values),
               invs=[invariants.REGISTRY[nm] for nm in inv_names])
 
 
@@ -69,13 +71,16 @@ def _expand(parents: list) -> list:
     return out
 
 
-def bfs_counts(bounds_kw: dict, spec: str, symmetry: bool, inv_names: tuple,
-               end_level: int, workers: int = 1, out=print):
-    """``(cumulative counts 0..end_level, invariant violations seen)``; the
-    list is shorter where the space ends first."""
-    args = (bounds_kw, spec, symmetry, tuple(inv_names))
+def bfs_counts(bounds_kw: dict, spec: str, symmetry, inv_names: tuple,
+               end_level: int, workers: int = 1, out=print, init=None):
+    """``(cumulative counts 0..end_level, invariant violations seen)`` of
+    the search from the spec's Init, or from the state ``init``, under the
+    SYMMETRY axes ``symmetry``; the list is shorter where the space ends
+    first."""
+    args = (bounds_kw, spec, tuple(symmetry), tuple(inv_names))
     _start(*args)
-    init = interp.init_state(_W["bounds"])
+    if init is None:
+        init = interp.init_state(_W["bounds"])
     seen = {digest(_W["key"](init))}
     violations = sum(not f(init, _W["bounds"]) for f in _W["invs"])
     cumulative, frontier = [1], [init]
@@ -122,11 +127,15 @@ def main(argv=None) -> int:
                         a.config + ".json")
     with open(path, encoding="utf-8") as f:
         cfg = json.load(f)
-    if cfg["symmetry"] not in ([], ["Server"]):
-        raise SystemExit("the reference reduces over Server only")
-    cum, viol = bfs_counts(cfg["bounds"], cfg["spec"], bool(cfg["symmetry"]),
+    try:
+        init = canon.stated_init(Bounds(**cfg["bounds"]), cfg.get("init"),
+                                 cfg["invariants"])
+        canon.orbit_key(cfg["symmetry"], cfg["bounds"]["n_values"])
+    except ValueError as e:
+        raise SystemExit(f"configuration {a.config}: {e}") from None
+    cum, viol = bfs_counts(cfg["bounds"], cfg["spec"], cfg["symmetry"],
                            tuple(cfg["invariants"]), a.end_level, a.workers,
-                           out=lambda m: print(m, flush=True))
+                           out=lambda m: print(m, flush=True), init=init)
     have = cfg.get("level_pins", [])
     diff = [k for k, (x, y) in enumerate(zip(cum, have)) if x != y]
     print(json.dumps({"config": a.config, "cumulative": cum,

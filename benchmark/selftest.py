@@ -8,9 +8,11 @@ and metric files resolving by name; the window-rate and span-median
 arithmetic and the pass clock, from Init and resumed from a snapshot; the
 trace reduction on the recorded trace; the readers of a pass at depth on a
 recorded span log; the plain reference against its own definition and the
-planted fault; a toy-size REHEARSAL of one whole run from Init, of one whose
-passes resume from a level-pinned snapshot and of one on the mesh engine over
-four host devices (labelled as such, write no metric); and the controls — the
+planted fault (two leaders in a term, or, where the action table has no
+BecomeLeader, a commit that a later leader lacks); a toy-size REHEARSAL of one
+whole run from Init, of one whose passes resume from a level-pinned snapshot,
+of one on the mesh engine over four host devices and of one from a stated
+Init under SYMMETRY Server and Value (labelled as such, write no metric); and the controls — the
 same rehearsals with one guarantee or the timed path broken underneath (the
 exchange between shards misrouted, on the mesh) have to come out
 ``correct: false``.
@@ -56,6 +58,16 @@ def toy_mesh_cell() -> dict:
     cell.update(name="toy.mesh4", config="toy_elect3_mesh4", chips=4,
                 config_data=mf.read_json("testdata", "toy_mesh4.json"))
     return cell
+
+
+def toy_repl_cell() -> dict:
+    """The log-replication toy: its Init stated by the configuration (s1
+    leads term 2), SYMMETRY over Server and Value."""
+    return {"name": "toy.repl", "config": "toy_repl3",
+            "traffic": "toy_repl_traffic", "chips": 1,
+            "config_data": mf.read_json("testdata", "toy_repl3.json"),
+            "traffic_data": mf.read_json("testdata",
+                                         "toy_repl_traffic.json")}
 
 
 def four_devices() -> None:
@@ -221,6 +233,7 @@ def test_span_clock_stamps_at_the_pinned_counts_only():
             levels = [1, 49, 50, 150, 150]
             violation = None
             n_states = 400
+            coverage = {}
         passes.finish(p, R, pins, 4)
         assert p.problem is None and p.levels == [1, 50, 100, 250, 400]
         assert abs(p.rate(300) - 300 / (p.t_b - p.t_a)) < 1e-6
@@ -309,6 +322,7 @@ def test_a_resumed_pass_is_clocked_from_its_run_start_and_counts_less():
             levels = [1, 49, 50, 150, 150]
             violation = None
             n_states = 400
+            coverage = {}
         passes.finish(p, R, pins, 4)
         assert p.problem is None and p.reached and p.n_states == 400
         assert p.t_a == began["mono"] and p.start_keys == 2325
@@ -395,6 +409,7 @@ class _FakeEngine:
                 self.pins, self.pins[1:n + 1])]
             violation = None
             n_states = self.pins[n]
+            coverage = {"Timeout": self.pins[n] - 1}
         return Result
 
 
@@ -431,6 +446,28 @@ def test_a_traffic_with_no_start_drives_the_calls_it_always_drove():
     cell = toy_cell()
     cell["traffic_data"]["start"] = "init"
     assert _drive_fake(cell)[4] == [{}, {}, {}]
+    assert all(p.coverage == {"Timeout": 6651} for p in made)
+    # a configuration that states its Init: every pass, the warm one too, is
+    # handed it as init_override (the program's own state class), and
+    # nothing else joins the call
+    drv, warm, snap, made, calls = _drive_fake(toy_repl_cell())
+    assert snap is None and [sorted(c) for c in calls] \
+        == [["init_override"]] * 3
+    start = calls[0]["init_override"]
+    assert all(c["init_override"] is start for c in calls)
+    assert type(start).__module__ == "raft_tla_tpu.models.interp"
+    assert (start.role, start.term, start.votedFor, start.log, start.msgs) \
+        == ((2, 0, 0), (2, 2, 2), (1, 1, 1), ((), (), ()), ())
+    assert warm.problem is None and all(p.problem is None for p in made)
+    # ... and it cannot yet be combined with passes resumed from a snapshot
+    cell = toy_repl_cell()
+    cell.update(traffic_data=dict(cell["traffic_data"],
+                                  start={"snapshot_level": 12}))
+    try:
+        _drive_fake(cell)
+        raise AssertionError("a stated Init took a snapshot traffic")
+    except ValueError as e:
+        assert "states its Init" in str(e)
     # from a snapshot of level 12: one pass from Init writes it through the
     # public checkpoint arguments, every timed pass resumes from it
     drv, warm, snap, made, calls = _drive_fake(toy_resume_cell())
@@ -550,13 +587,60 @@ def test_deep_pins_count_what_the_plain_bfs_counts():
     cfg = mf.read_json("testdata", "toy_config.json")
     for workers in (1, 2):
         cum, viol = deep_pins.bfs_counts(
-            cfg["bounds"], cfg["spec"], True, tuple(cfg["invariants"]), 40,
-            workers, out=lambda _m: None)
+            cfg["bounds"], cfg["spec"], cfg["symmetry"],
+            tuple(cfg["invariants"]), 40, workers, out=lambda _m: None)
         assert cum == cfg["level_pins"] and viol == 0
     cum, _v = deep_pins.bfs_counts(
-        cfg["bounds"], cfg["spec"], True, tuple(cfg["invariants"]), 5, 1,
-        out=lambda _m: None)
+        cfg["bounds"], cfg["spec"], cfg["symmetry"],
+        tuple(cfg["invariants"]), 5, 1, out=lambda _m: None)
     assert cum == cfg["level_pins"][:6]
+
+
+def test_deep_pins_start_from_the_stated_init_under_both_axes():
+    from benchmark.reference import canon, deep_pins
+    from benchmark.reference.bounds import Bounds
+    cfg = mf.read_json("testdata", "toy_repl3.json")
+    init = canon.stated_init(Bounds(**cfg["bounds"]), cfg["init"],
+                             cfg["invariants"])
+    for workers in (1, 2):
+        cum, viol = deep_pins.bfs_counts(
+            cfg["bounds"], cfg["spec"], cfg["symmetry"],
+            tuple(cfg["invariants"]), 40, workers, out=lambda _m: None,
+            init=init)
+        assert cum == cfg["level_pins"] and viol == 0     # the space ends
+    # from the spec's own Init the sub-spec has nothing to do
+    cum, _v = deep_pins.bfs_counts(
+        cfg["bounds"], cfg["spec"], cfg["symmetry"],
+        tuple(cfg["invariants"]), 40, 1, out=lambda _m: None)
+    assert cum == [1]
+
+
+def test_a_stated_init_is_held_to_the_bounds_and_the_invariants():
+    from benchmark.reference import canon, interp
+    from benchmark.reference.bounds import Bounds
+    b = Bounds(n_servers=3, n_values=2, max_term=2, max_log=2, max_msgs=1,
+               max_dup=1)
+    assert canon.stated_init(b, None) == interp.init_state(b)
+    s = canon.stated_init(b, {
+        "role": ["Follower", "Leader", "Candidate"], "term": [1, 2, 2],
+        "votedFor": ["Nil", "s2", "s3"], "log": [[], [[1, 2], [2, 1]], []],
+        "vGrant": [[], [], ["s3", "s1"]],
+        "matchIndex": [[0, 0, 0], [1, 0, 2], [0, 0, 0]]})
+    assert (s.role, s.term, s.votedFor) == ((0, 2, 1), (1, 2, 2), (0, 2, 3))
+    assert s.log == ((), ((1, 2), (2, 1)), ()) and s.vGrant == (0, 0, 0b101)
+    assert s.matchIndex[1] == (1, 0, 2) and s.nextIndex == ((1, 1, 1),) * 3
+    for bad, word in (
+            ({"term": [3, 1, 1]}, "outside the bounds"),
+            ({"term": [1, 1]}, "each of the 3 servers"),
+            ({"log": [[[1, 3]], [], []]}, "outside the bounds"),
+            ({"votedFor": ["s4", "Nil", "Nil"]}, "no server"),
+            ({"msgs": []}, "it may state"),
+            ({"role": ["Leader", "Leader", "Follower"]}, "NoTwoLeaders")):
+        try:
+            canon.stated_init(b, bad, ("NoTwoLeaders",))
+            raise AssertionError(f"{bad} was accepted")
+        except ValueError as e:
+            assert word in str(e), e
 
 
 def test_reference_orbit_representative_is_renaming_invariant():
@@ -578,6 +662,39 @@ def test_reference_orbit_representative_is_renaming_invariant():
     some = rng.sample(level, 300)
     assert len({canon.canonical(s) for s in some}) == \
         len({canon.canonical_all_perms(s) for s in some})
+    # over Server and Value: the replication toy from its stated Init,
+    # logs that differ and entries in flight in the sample
+    cfg = mf.read_json("testdata", "toy_repl3.json")
+    b = Bounds(**cfg["bounds"])
+    init = canon.stated_init(b, cfg["init"], cfg["invariants"])
+    assert canon.bfs_levels(b, cfg["spec"], ["Server"], (), 10**9,
+                            init=init)[0][-1] > cfg["level_pins"][-1]
+    _cum, level, _v = canon.bfs_levels(b, cfg["spec"], [], (), 500,
+                                       init=init)
+    assert any(len(set(s.log)) == 3 for s in level) and any(
+        canon.mb.fc(lo) for s in level for (_hi, lo), _c in s.msgs)
+    values = list(itertools.permutations(range(2)))
+    for s in rng.sample(level, 200):
+        twin = interp.PyState(*canon.permute(s, rng.choice(perms),
+                                             rng.choice(values)))
+        assert canon.canonical(s, 2) == canon.canonical(twin, 2) \
+            == canon.canonical_all_perms(s, 2) \
+            == canon.canonical_all_perms(twin, 2)
+        assert canon.canonical(s, 2) <= canon.canonical(s)
+    # a renaming of the values moves the state and keeps the orbit
+    moved = [s for s in level
+             if canon.permute(s, (0, 1, 2), (1, 0)) != canon.as_tuple(s)]
+    assert len(moved) > len(level) // 2
+    key = canon.orbit_key(cfg["symmetry"], 2)
+    assert key(moved[0]) == canon.canonical(moved[0], 2)
+    assert canon.orbit_key(True, 2) is canon.orbit_key(["Server"], 2) \
+        is canon.canonical and canon.orbit_key([], 2) is canon.as_tuple
+    for axes in (["Value"], ["Server", "Term"]):
+        try:
+            canon.orbit_key(axes, 2)
+            raise AssertionError(f"SYMMETRY {axes} was accepted")
+        except ValueError as e:
+            assert str(axes) in str(e)
 
 
 # ------------------------------------------- a whole run, toy size, the CPU
@@ -597,6 +714,38 @@ def test_rehearsal_of_one_run_is_correct_and_writes_no_metric():
     assert list(res)[-1] == "checks" and len(res["checks"]) >= 8
     assert all(c["value"] <= c["limit"] for c in res["checks"].values())
     json.dumps(res)
+
+
+def rehearse_repl(seed: int, trace: bool = False) -> dict:
+    from benchmark import run
+    return run.execute(toy_repl_cell(), mf.load(), seed, 0.0, trace,
+                       rehearsal=True)
+
+
+def test_rehearsal_from_a_stated_init_under_both_axes_is_correct():
+    res = rehearse_repl(3_000_000_033, trace=True)
+    assert res["rehearsal"] is True and res["metrics"] == {}
+    assert res["correct"] is True
+    assert res["attempted"] >= 3 and res["failed"] == 0
+    assert all(c["value"] <= c["limit"] for c in res["checks"].values())
+
+
+def test_the_log_fault_is_found_and_invariants_off_misses_it():
+    # the replication table has no BecomeLeader: check (d) plants a commit
+    # that a later leader's log lacks, and only the invariant pass sees it
+    from benchmark.harness import breakers
+    with breakers.invariants_off():
+        res = rehearse_repl(34)
+    assert res["correct"] is False and res["failed"] == 0
+    assert {n for n, c in res["checks"].items() if c["value"] > c["limit"]} \
+        == {"planted_violation_missed"}
+
+
+def test_short_keys_from_a_stated_init_come_out_not_correct():
+    from benchmark.harness import breakers
+    with breakers.short_keys(10):
+        res = rehearse_repl(35)
+    assert res["correct"] is False
 
 
 def rehearse_resumed(seed: int, trace: bool = False) -> dict:
@@ -760,6 +909,21 @@ def test_overshoot_is_the_median_of_the_sound_untraced_passes():
     assert mf.metric_reader("overshoot_s")({"passes": []}) is None
 
 
+def test_log_share_is_of_the_sound_untraced_passes_coverage():
+    read = mf.metric_reader("log_transitions_share_pct")
+    cov = {"ClientRequest": 10, "AppendEntries": 20, "Receive": 60,
+           "AdvanceCommitIndex": 10}
+    made = [passes.Pass(index=k, t_call=0.0, traced=k == 1, coverage=c)
+            for k, c in enumerate((cov, {"Receive": 1}, cov,
+                                   {"ClientRequest": 5}))]
+    made[3].problem = "short of B"
+    assert abs(read({"passes": made}) - 40.0) < 1e-9
+    assert read({"passes": [passes.Pass(index=0, t_call=0.0,
+                                        coverage={"Timeout": 9})]}) == 0.0
+    assert read({"passes": made[1:2]}) is None and \
+        read({"passes": []}) is None
+
+
 def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
     from benchmark.harness import correct
     from benchmark.reference import canon, interp, invariants
@@ -779,6 +943,32 @@ def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
                        for nm in cfg["invariants"])
             assert p["violators"] and all(
                 "NoTwoLeaders" in v for v in p["violators"].values())
+    # a table with no BecomeLeader takes the log fault: AdvanceCommitIndex
+    # commits an entry that the leader of the later term lacks
+    from benchmark.reference import spec as S
+    cfg = mf.read_json("testdata", "toy_repl3.json")
+    b = Bounds(**cfg["bounds"])
+    _cum, level, _v = canon.bfs_levels(
+        b, cfg["spec"], cfg["symmetry"], tuple(cfg["invariants"]), 60,
+        init=canon.stated_init(b, cfg["init"]))
+    logs = [correct.planted_fault(cfg, level, seed)
+            for seed in (1, 2, 2_147_483_659)]
+    assert len({p["parent"] for p in logs}) > 1
+    table = S.action_table(b, cfg["spec"])
+    for p in logs:
+        assert interp.constraint_ok(p["parent"], b)
+        assert all(invariants.REGISTRY[nm](p["parent"], b)
+                   for nm in cfg["invariants"])
+        assert p["violators"] and all(
+            v == ["LeaderCompleteness"] for v in p["violators"].values())
+        by = {table[a].family for a, t in interp.successors(
+            p["parent"], b, table) if p["key"](t) in p["violators"]}
+        assert by == {S.ADVANCECOMMIT}
+    try:
+        correct.planted_fault(dict(cfg, invariants=["LogMatching"]), level, 1)
+        raise AssertionError("a fault with no invariant to break")
+    except ValueError as e:
+        assert "lists no invariant it breaks" in str(e)
     # a sound engine names the planted state; a blind or wrong one fails
     p = plants[0]
     orbit, names = next(iter(p["violators"].items()))
