@@ -2,10 +2,12 @@
 """Run a cell with one guarantee broken and see ``correct`` come out false.
 
     python3 benchmark/control.py --workload NAME --seeds 1,2,3 [--seconds 0] \
-        --control key32|filter_only|invariants_off|misroute
+        --control key32|filter_only|invariants_off|misroute|drops_last_level
 
 Not part of a benchmark run.  ``misroute`` is a mesh cell's control (a cell
-on one chip has no exchange to misroute, and passes it).  One process, one
+on one chip has no exchange to misroute, and passes it); ``drops_last_level``
+is the control of a cell whose passes run to their own end (a pass stopped at
+a pin never reaches the level it drops, and passes it).  One process, one
 run per seed at the cell's own size (the traffic's minimum number of passes
 when --seconds is 0).
 Exits 0 when every seed's run was refused, 1 when one passed.

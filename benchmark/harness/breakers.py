@@ -120,7 +120,55 @@ def misrouted_exchange():
         mesh_engine.exchange = real
 
 
+@contextlib.contextmanager
+def drops_last_level():
+    """Guarantee broken (a configuration's whose passes run to their own
+    end): every reachable orbit inside the bounds was admitted.  Every
+    engine built inside hands its compiled segment the frontier of the
+    second-to-last pinned level as an empty block, so the last level that
+    would admit anything admits nothing: the search ends a level early,
+    ``complete = True``, with a smaller total.  A pass stopped at a pin
+    below that level never gets there and passes the control."""
+    from benchmark.harness import drive
+    real = drive.build_engine
+
+    def build_engine(cfg):
+        import jax.numpy as jnp
+        eng = real(cfg)
+        pins = cfg["level_pins"]
+        last = len(pins) - 2    # its frontier is the last that bears fruit
+        segment, check = eng._segment, eng.check
+        armed = []
+
+        def empty_frontier(fc, bufs, rows, con, budget, n_rows):
+            return segment(fc, bufs, rows, con, budget,
+                           jnp.int32(0) if armed else n_rows)
+
+        def watched(on_progress=None, **kw):
+            def cb(rec):
+                # the boundary record of that level: what follows is the
+                # upload and the expansion of its frontier
+                if (rec["level"], rec["n_states"]) == (last, pins[last]):
+                    armed.append(1)
+                if on_progress is not None:
+                    on_progress(rec)
+            try:
+                return check(on_progress=cb, **kw)
+            finally:
+                del armed[:]    # check (c) feeds the same segment its sample
+
+        eng._segment, eng.check = empty_frontier, watched
+        return eng
+
+    drive.build_engine = build_engine
+    try:
+        yield
+    finally:
+        drive.build_engine = real
+
+
 CONTROLS = {"key32": lambda: short_keys(32),
             "filter_only": filter_only_dedup,
             "invariants_off": invariants_off,
-            "misroute": misrouted_exchange}
+            "misroute": misrouted_exchange,
+            "drops_last_level": drops_last_level}

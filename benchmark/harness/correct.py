@@ -41,6 +41,25 @@ def pass_checks(pass_list: list, pins: list, end_level: int) -> list:
     ]
 
 
+def fixpoint_checks(pass_list: list, pins: list) -> list:
+    """A traffic whose passes run to their own end: every pass's result
+    says ``complete = True``, and its count is the last pin, the space's
+    total.  Where a pass's ``n_states`` is the total, its level table's own
+    total is held to it too, levels past the last pin included (a table
+    longer than the pins is held by check (a) at the pinned levels only)."""
+    total = pins[-1]
+
+    def off(p):
+        table = (p.overshoot_levels or p.levels or [0])[-1]
+        return abs((p.n_states or 0) - total) or abs(table - total)
+
+    return [
+        ("passes_incomplete",
+         sum(p.complete is not True for p in pass_list), 0),
+        ("fixpoint_total_diff", sum(off(p) for p in pass_list), 0),
+    ]
+
+
 def snapshot_checks(snapshot: dict, pass_list: list, pins: list,
                     end_level: int) -> list:
     """A traffic that starts at depth: the pass that wrote the snapshot met

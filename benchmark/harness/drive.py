@@ -164,6 +164,8 @@ class Driver:
                 f"starts at its snapshot, level {self.snapshot_level}, "
                 f"not at start_level {self.a}")
         self.engine_name, self.ndev = mf.engine_of(self.cfg, cell["chips"])
+        # how a pass ends: stopped at B's pin, or left to its own end
+        self.fixpoint = mf.end_of(t, self.cfg, cell["traffic"]) == "fixpoint"
         self._level_ahead = mf.ENGINES[self.engine_name]["record_level_ahead"]
         if self.snapshot_level is not None \
                 and self.cfg["engine_caps"][self.engine_name].get(
@@ -237,7 +239,10 @@ class Driver:
                  **check_kw) -> passes.Pass:
         """One ``check()``, stopped losslessly once the record at
         ``end_level``'s pinned count is stamped; ``check_kw`` are further
-        public arguments of it (``checkpoint=``, ``resume=``).  ``trace``:
+        public arguments of it (``checkpoint=``, ``resume=``).  Where the
+        traffic runs to the fixpoint, a pass to the traffic's own end (no
+        ``end_level`` given) is stopped by nothing and held to the verdict;
+        the warm pass names its level and is stopped there.  ``trace``:
         the program's own spans go to an event log and the first level of
         the span, or of a resumed pass its first ``passes.TRACED_STEPS``
         chunk steps, run under ``jax.profiler``.  A pass to or from a
@@ -248,6 +253,7 @@ class Driver:
         end = self.b if end_level is None else end_level
         start = self.a if start_level is None else start_level
         resumed = "resume" in check_kw
+        fixpoint = self.fixpoint and end_level is None
         p = passes.Pass(index=self.made, t_call=0.0, traced=trace,
                         resumed=resumed)
         self.made += 1
@@ -288,7 +294,8 @@ class Driver:
         # the capture is one level: a whole span is millions of op events,
         # and writing them out takes a minute
         clock = passes.SpanClock(p, self.pins, start, end, at_a, trace_end,
-                                 level_ahead=self._level_ahead)
+                                 level_ahead=self._level_ahead,
+                                 fixpoint=fixpoint)
         # every pass starts from the same segment budget: check() leaves its
         # pacer's last budget on the object
         self.engine.seg_chunks = self._seg_chunks0
@@ -313,7 +320,7 @@ class Driver:
                     if closer is not None:
                         # a pass shorter than the step bound: all of it
                         p.t_trace_end = p.t_b
-        passes.finish(p, result, self.pins, end)
+        passes.finish(p, result, self.pins, end, fixpoint=fixpoint)
         p.compiles = self.compiles.n - n0
         return p
 

@@ -53,6 +53,42 @@ def engine_of(cfg: dict, chips: int | None = None) -> tuple:
     return name, devices
 
 
+# how a traffic's pass ends (``"end"``; absent = ``pin``): stopped by the first
+# SIGINT at the pinned count of ``end_level``, or left to run to its own end
+ENDS = ("pin", "fixpoint")
+
+
+def end_of(traffic: dict, cfg: dict, traffic_name: str) -> str:
+    """How this traffic's passes end on this configuration: ``"pin"`` or
+    ``"fixpoint"`` (the pass is stopped by nothing and ``check()`` returns
+    its verdict).  A fixpoint traffic is refused by name where its
+    ``end_level`` is not the last level the configuration pins (the last
+    that admits anything), where it also starts from a snapshot, and on the
+    mesh engine: no cell needs either yet.  Touches no device."""
+    end = traffic.get("end", "pin")
+    if end not in ENDS:
+        raise ValueError(f"traffic {traffic_name}: unknown end {end!r} "
+                         f"(known: {', '.join(ENDS)})")
+    if end == "pin":
+        return end
+    last = len(cfg["level_pins"]) - 1
+    if traffic["end_level"] != last:
+        raise ValueError(
+            f"traffic {traffic_name} runs to the fixpoint: its end_level "
+            f"{traffic['end_level']} has to be the last level configuration "
+            f"{cfg['name']} pins, {last}")
+    if traffic.get("start", "init") != "init":
+        raise ValueError(
+            f"traffic {traffic_name} runs to the fixpoint and starts from a "
+            "snapshot: no cell has driven a resumed pass to its end yet")
+    if cfg.get("engine", "ddd") != "ddd":
+        raise ValueError(
+            f"traffic {traffic_name} runs to the fixpoint; configuration "
+            f"{cfg['name']} names the engine {cfg['engine']!r}: only 'ddd' "
+            "has been driven to its own end")
+    return end
+
+
 def load(root: str = ROOT) -> dict:
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         return json.load(f)
@@ -150,10 +186,15 @@ def problems(manifest: dict) -> list:
         path = os.path.join(ROOT, files.get(w["config"], ""))
         if os.path.isfile(path):
             with open(path, encoding="utf-8") as f:
-                try:
-                    engine_of(json.load(f), w["chips"])
-                except ValueError as e:
-                    bad.append(f"cell {w['name']}: {e}")
+                cfg = json.load(f)
+            try:
+                engine_of(cfg, w["chips"])
+                tpath = os.path.join(BENCH, "traffic", w["traffic"] + ".json")
+                if os.path.isfile(tpath):
+                    end_of(read_json("traffic", w["traffic"] + ".json"),
+                           cfg, w["traffic"])
+            except ValueError as e:
+                bad.append(f"cell {w['name']}: {e}")
     if sum(w["chips"] == 4 for w in manifest["workloads"]) > \
             max(1, len(cells) // 2):
         bad.append("too many four-chip cells")

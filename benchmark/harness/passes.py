@@ -5,7 +5,9 @@ over the whole window they ran in; each pass is also clocked over its at-depth
 span, for the per-layer readings: between the two progress records whose
 ``n_states`` equal the pinned counts at levels A and B, or, resumed, from the
 engine's ``run_start`` (the snapshot is loaded, the first upload is next) to
-the record at B."""
+the record at B.  A traffic with ``"end": "fixpoint"`` lets the pass run to its
+own end instead: nothing stops it, B is the last level that admits anything,
+and the span ends where ``check()`` returns with its verdict."""
 
 from __future__ import annotations
 
@@ -45,6 +47,9 @@ class Pass:
     start_keys: int | None = None   # resumed: the engine's first count
     n_states: int | None = None     # the result's count at the return
     coverage: dict | None = None    # the result's count by action family
+    fixpoint: bool = False          # ran to its own end: t_b is the return
+    t_last: float | None = None     # fixpoint: first record at the total
+    complete: bool | None = None    # the result's own word on its verdict
 
     @property
     def reached(self) -> bool:
@@ -67,6 +72,23 @@ class Pass:
             return None
         return self.t_return - self.t_b
 
+    @property
+    def verdict_s(self):
+        """``check()``'s call to its return, of a pass that ended by itself
+        with ``complete = True``; None of any other pass."""
+        if not self.fixpoint or self.complete is not True \
+                or self.t_return is None:
+            return None
+        return self.t_return - self.t_call
+
+    @property
+    def close_s(self):
+        """Fixpoint: the first record at the space's total -> the return
+        (the empty last expansion, the final flush, the result)."""
+        if self.t_last is None or self.t_return is None:
+            return None
+        return self.t_return - self.t_last
+
 
 class SpanClock:
     """The ``on_progress`` callback of one pass.  Stamps the benchmark's own
@@ -83,14 +105,23 @@ class SpanClock:
     level it closes.  The ddd engine reports before it opens the next level
     (0); the mesh engine after (1), and then repeats that pair of level and
     count from inside the next level's window until its drain, so the hook
-    ``after_first_level`` fires at the first such record only."""
+    ``after_first_level`` fires at the first such record only.
+
+    ``fixpoint``: the pass runs to its own end.  Stamp A as ever; at the
+    first record that carries the pin of B (the space's total) the clock
+    stamps ``t_last`` and raises nothing: ``check()`` returns by itself, and
+    that return is stamp B (``finish``).  Only a record whose count passes
+    the total stops the pass, failed: a search that overruns its pins must
+    not run for an hour."""
 
     def __init__(self, p: Pass, pins: list, level_a: int, level_b: int,
-                 at_a=None, after_first_level=None, level_ahead: int = 0):
+                 at_a=None, after_first_level=None, level_ahead: int = 0,
+                 fixpoint: bool = False):
         self.p = p
         self.pins = pins
         self.level_a, self.level_b = level_a, level_b
         self.level_ahead = level_ahead
+        self.fixpoint = fixpoint
         # the traced pass's capture: at_a() opens it at stamp A,
         # after_first_level(now) closes it at the boundary of level A + 1
         self.at_a, self.after_first_level = at_a, after_first_level
@@ -99,9 +130,17 @@ class SpanClock:
         now = time.monotonic()
         p = self.p
         level = rec["level"] - self.level_ahead
+        if self.fixpoint and p.problem is None \
+                and rec["n_states"] > self.pins[self.level_b]:
+            p.problem = (f"the search ran past the last pin "
+                         f"{self.pins[self.level_b]} (level {self.level_b}): "
+                         f"now at {rec['n_states']}, level {level}")
+            signal.raise_signal(signal.SIGINT)
+            return
         if not 0 <= level < len(self.pins) \
                 or rec["n_states"] != self.pins[level]:
-            if level > self.level_b and p.t_b is None and p.problem is None:
+            if level > self.level_b and p.t_b is None and p.problem is None \
+                    and not self.fixpoint:
                 # past B with no boundary record at the pin: stop, failed
                 p.problem = (f"no level-{self.level_b} boundary record at "
                              f"the pinned count {self.pins[self.level_b]}; "
@@ -116,12 +155,16 @@ class SpanClock:
             if self.at_a is not None:
                 self.at_a()
                 p.t_a = time.monotonic()    # the span starts after the hook
-        elif level == self.level_b and p.t_b is None \
+        elif level == self.level_b and p.t_b is None and p.t_last is None \
                 and (p.t_a is not None or p.resumed):
-            p.t_b = now
+            if self.fixpoint:
+                p.t_last = now      # nothing is raised: the pass ends itself
+            else:
+                p.t_b = now
             if hook is not None:
                 hook(now)
-            signal.raise_signal(signal.SIGINT)
+            if not self.fixpoint:
+                signal.raise_signal(signal.SIGINT)
         elif hook is not None:
             hook(now)
 
@@ -139,11 +182,20 @@ def run_start(events_path: str) -> dict | None:
     return None
 
 
-def finish(p: Pass, result, pins: list, end_level: int) -> Pass:
-    """Fill the pass from the engine's result and hold it to the pins."""
+def finish(p: Pass, result, pins: list, end_level: int,
+           fixpoint: bool = False) -> Pass:
+    """Fill the pass from the engine's result and hold it to the pins.
+    ``fixpoint``: the pass ran to its own end; its return is stamp B, and it
+    is held to ``complete is True``, a count equal to the last pin, the whole
+    level table equal to the pins and no level past them."""
     p.t_return = time.monotonic()
     p.n_states = result.n_states
     p.coverage = dict(result.coverage)
+    p.complete = getattr(result, "complete", None)
+    if fixpoint:
+        p.fixpoint = True
+        if p.t_last is not None:
+            p.t_b = p.t_return
     if p.resumed:
         began = run_start(p.events)
         if began is not None:
@@ -161,6 +213,13 @@ def finish(p: Pass, result, pins: list, end_level: int) -> Pass:
             for k, got in enumerate(p.overshoot_levels)):
         p.problem = (f"overshoot levels {p.overshoot_levels} exceed the pins "
                      f"{over_pins[:len(p.overshoot_levels)]}")
+    if p.problem is None and fixpoint:
+        if p.complete is not True:
+            p.problem = (f"check() returned complete = {p.complete}, not a "
+                         "verdict")
+        elif p.n_states != pins[end_level]:
+            p.problem = (f"the pass ended with {p.n_states} orbits, the "
+                         f"last pin is {pins[end_level]}")
     if p.problem is None:
         if not p.reached:
             p.problem = "the pass ended before the pinned count at B"

@@ -10,8 +10,11 @@ passes (Init, or the snapshot, to the cell's pinned level B) for S seconds of
 run time, at least the traffic's ``min_passes``.  ``orbits_per_s`` is every
 orbit those passes admitted over the whole window; every pass's own numbers
 (ramp or resume, the clocked A->B span, overshoot) are printed on earlier
-lines.  After the window it decides ``correct`` and prints the contract's JSON
-object last.
+lines.  Where the traffic says ``"end": "fixpoint"`` nothing stops a pass:
+``check()`` runs to the level that admits nothing and returns its verdict, and
+``verdict_wall_s`` is the median call -> return of the sound untraced passes.
+After the window it decides ``correct`` and prints the contract's JSON object
+last.
 """
 
 from __future__ import annotations
@@ -65,6 +68,8 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
     try:
         engine_name, ndev = mf.engine_of(cell["config_data"],
                                             cell["chips"])
+        mf.end_of(cell["traffic_data"], cell["config_data"],
+                  cell["traffic"])
     except ValueError as e:
         raise SystemExit(f"benchmark: {e}") from None
     scratch = drive.scratch_dir(cell["name"])
@@ -116,6 +121,9 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
             f"span_s={_f(p.span_s)} "
             f"overshoot_s={_f(p.overshoot_s)} compiles={p.compiles} "
             f"peak_rss_mb={rss_mb():.0f}"
+            + (f" verdict_s={_f(p.verdict_s)} close_s={_f(p.close_s)} "
+               f"complete={p.complete} levels={len(p.levels)}"
+               if p.fixpoint else "")
             + (" traced" if p.traced else "")
             + (f" FAILED: {p.problem}" if p.problem else ""))
         if not passes.room_for_another(
@@ -139,6 +147,8 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
     if snap is not None:
         checks += correct.snapshot_checks(drv.snapshot, made, drv.pins,
                                           drv.b)
+    if drv.fixpoint:
+        checks += correct.fixpoint_checks(made, drv.pins)
     ref = correct.reference_sample(drv.cfg, seed)
     t_smp = time.monotonic()
     got = drv.expand_sample(ref["parents"])
@@ -171,6 +181,13 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         "work": _work(drv, work, made),
         "span_levels": [drv.a, drv.b], "snapshot": drv.snapshot,
     }
+    if drv.fixpoint:
+        # what a user waits for: set-up, then one check() to its verdict
+        verdict = mf.metric_reader("verdict_wall_s")(evidence)
+        if verdict is not None:
+            say(f"a user's whole wait: setup_s {clocks['setup_s']:.3f} + "
+                f"verdict_wall_s {verdict:.3f} = "
+                f"{clocks['setup_s'] + verdict:.3f}s (the sum is no metric)")
     device = {"platform": dev["platform"], "kind": dev["kind"],
               "count": dev["count"],
               "memory_peak_bytes": evidence["hbm_peak_bytes"]}
@@ -259,6 +276,7 @@ def _work(drv, work, made: list) -> dict:
             getattr(eng.caps, "send", None)),
         "packed_words": eng.schema.P,
         "shards": shards,
+        "chunk": eng.config.chunk,
     }
 
 

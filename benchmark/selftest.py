@@ -11,11 +11,14 @@ recorded span log; the plain reference against its own definition and the
 planted fault (two leaders in a term, or, where the action table has no
 BecomeLeader, a commit that a later leader lacks); a toy-size REHEARSAL of one
 whole run from Init, of one whose passes resume from a level-pinned snapshot,
-of one on the mesh engine over four host devices and of one from a stated
-Init under SYMMETRY Server and Value (labelled as such, write no metric); and the controls — the
+of one on the mesh engine over four host devices, of one from a stated
+Init under SYMMETRY Server and Value and of one whose passes run to the
+space's own end (labelled as such, write no metric); the clock, the refusals
+and the readers of a pass that ends by itself; and the controls — the
 same rehearsals with one guarantee or the timed path broken underneath (the
-exchange between shards misrouted, on the mesh) have to come out
-``correct: false``.
+exchange between shards misrouted, on the mesh; the last level dropped, or
+the pass stopped at its total, where it should end by itself) have to come
+out ``correct: false``.
 """
 
 from __future__ import annotations
@@ -70,6 +73,16 @@ def toy_repl_cell() -> dict:
                                          "toy_repl_traffic.json")}
 
 
+def toy_verdict_cell() -> dict:
+    """The replication toy under a traffic whose passes run to the space's
+    own end (2,137 orbits, 24 levels past the stated Init)."""
+    cell = toy_repl_cell()
+    cell.update(name="toy.verdict", traffic="toy_repl_fixpoint",
+                traffic_data=mf.read_json("testdata",
+                                          "toy_repl_fixpoint.json"))
+    return cell
+
+
 def four_devices() -> None:
     """The mesh rehearsals need four devices: on the CPU four host devices,
     which have to be asked for before JAX opens its backend (``main`` does;
@@ -99,6 +112,8 @@ def on_four_devices(test_name: str) -> bool:
 
 
 RESUME_LOG = os.path.join(mf.BENCH, "testdata", "spans_resume_small.jsonl")
+FIXPOINT_LOG = os.path.join(mf.BENCH, "testdata",
+                            "spans_fixpoint_small.jsonl")
 
 
 # ------------------------------------------------------------ the manifest
@@ -385,6 +400,7 @@ class _FakeEngine:
     clock one boundary record a level and stops at the first SIGINT."""
 
     seg_chunks = 7
+    fault: dict = {}      # what a broken engine would get wrong at its end
 
     def __init__(self, pins):
         self.pins, self.calls, self.stop = pins, [], False
@@ -403,21 +419,27 @@ class _FakeEngine:
             on_progress({"level": n, "n_states": self.pins[n]})
             if self.stop:
                 break
+        else:
+            # nobody stopped it: the last frontier expands to nothing
+            on_progress({"level": n + 1, "n_states": self.pins[n]})
+        fault = self.fault
 
         class Result:
             levels = [self.pins[0]] + [b - a for a, b in zip(
-                self.pins, self.pins[1:n + 1])]
+                self.pins, self.pins[1:n + 1])] + fault.get("more", [])
             violation = None
-            n_states = self.pins[n]
+            n_states = self.pins[n] + fault.get("count_off", 0)
             coverage = {"Timeout": self.pins[n] - 1}
+            complete = not self.stop and fault.get("complete", True)
         return Result
 
 
-def _drive_fake(cell):
+def _drive_fake(cell, fault=None):
     import signal
     import tempfile
     from benchmark.harness import drive
     eng = _FakeEngine(cell["config_data"]["level_pins"])
+    eng.fault = fault or {}
     real = drive.build_engine
     drive.build_engine = lambda cfg: eng
     old = signal.signal(signal.SIGINT,
@@ -498,6 +520,252 @@ def test_a_traffic_with_no_start_drives_the_calls_it_always_drove():
         raise AssertionError("a span that starts off its snapshot")
     except ValueError as e:
         assert "starts at its snapshot" in str(e)
+
+
+# ---------------------------------------- a pass that runs to its own end
+
+def test_a_fixpoint_traffic_is_refused_where_it_cannot_end():
+    cell = toy_verdict_cell()
+    cfg, t = cell["config_data"], cell["traffic_data"]
+    assert mf.end_of(t, cfg, "toy_repl_fixpoint") == "fixpoint"
+    assert mf.end_of(toy_cell()["traffic_data"], cfg, "toy_traffic") == "pin"
+    assert mf.end_of(dict(t, end="pin"), cfg, "x") == "pin"
+    mesh = dict(mf.read_json("testdata", "toy_mesh4.json"))
+    for traffic, conf, word in (
+            (dict(t, end_level=23, count_at_end=2129), cfg,
+             "has to be the last level configuration toy_repl3 pins, 24"),
+            (dict(t, start={"snapshot_level": 12}), cfg,
+             "starts from a snapshot"),
+            (dict(t, end_level=31), mesh, "names the engine 'ddd-shard'"),
+            (dict(t, end="never"), cfg, "unknown end 'never'")):
+        try:
+            mf.end_of(traffic, conf, "toy_repl_fixpoint")
+            raise AssertionError(f"{word!r}: accepted")
+        except ValueError as e:
+            assert word in str(e) and "toy_repl_fixpoint" in str(e), e
+    # the Driver refuses it too, and a run ends before it opens a device
+    from benchmark import run
+    bad = toy_verdict_cell()
+    bad["traffic_data"].update(end_level=23, count_at_end=2129)
+    try:
+        _drive_fake(bad)
+        raise AssertionError("a fixpoint traffic that ends short was driven")
+    except ValueError as e:
+        assert "last level" in str(e)
+    from benchmark.harness import drive
+    real = drive.open_device
+    drive.open_device = lambda *a, **kw: (_ for _ in ()).throw(
+        AssertionError("a device was opened"))
+    try:
+        run.execute(bad, mf.load(), 1, 0.0, False, rehearsal=True)
+        raise AssertionError("a fixpoint traffic that ends short ran")
+    except SystemExit as e:
+        assert "toy_repl_fixpoint runs to the fixpoint" in str(e)
+    finally:
+        drive.open_device = real
+
+
+def test_the_clock_lets_a_fixpoint_pass_end_by_itself():
+    import signal
+    hits, closed = [], []
+    old = signal.signal(signal.SIGINT, lambda *_: hits.append(1))
+    try:
+        pins = [1, 50, 100, 250, 400, 410]
+        p = passes.Pass(index=0, t_call=time.monotonic())
+        clock = passes.SpanClock(p, pins, 2, 5, None, closed.append,
+                                 fixpoint=True)
+        # every boundary, an in-level record at the total, the boundary of
+        # the last level and the empty expansion past it: nothing is raised
+        for n, lvl in ((50, 1), (100, 2), (250, 3), (400, 4), (410, 5),
+                       (410, 5), (410, 6)):
+            clock({"n_states": n, "level": lvl})
+        assert hits == [] and len(closed) == 1      # the traced level A+1
+        assert p.t_a is not None and p.t_last is not None and p.t_b is None
+        assert p.problem is None
+
+        class R:
+            levels = [1, 49, 50, 150, 150, 10]
+            violation = None
+            n_states = 410
+            coverage = {}
+            complete = True
+        passes.finish(p, R, pins, 5, fixpoint=True)
+        assert p.problem is None and p.reached and p.fixpoint
+        assert p.t_b == p.t_return and p.levels == pins
+        assert p.verdict_s == p.t_return - p.t_call
+        assert 0.0 <= p.close_s <= p.verdict_s and p.overshoot_s == 0.0
+        # a pass stopped at a pin has no verdict to clock
+        q = passes.Pass(index=1, t_call=0.0, t_a=1.0, t_b=2.0)
+        passes.finish(q, R, pins, 5)
+        assert q.problem is None and q.verdict_s is None and not q.fixpoint
+        # a record past the total stops the pass, failed, once
+        r = passes.Pass(index=2, t_call=time.monotonic())
+        clock = passes.SpanClock(r, pins, 2, 5, fixpoint=True)
+        for n, lvl in ((100, 2), (410, 5), (411, 6), (450, 6)):
+            clock({"n_states": n, "level": lvl})
+        assert hits == [1] and "ran past the last pin 410" in r.problem
+        # what finish holds a fixpoint pass to, fault by fault
+        for change, word in (
+                ({"complete": False}, "complete = False"),
+                ({"n_states": 409}, "409 orbits, the last pin is 410"),
+                ({"levels": R.levels + [1], "n_states": 411},
+                 "overshoot levels [411] exceed the pins []"),
+                ({"levels": R.levels[:-1], "n_states": 400},
+                 "400 orbits, the last pin is 410")):
+            f = passes.Pass(index=3, t_call=0.0, t_a=1.0, t_last=2.0)
+            passes.finish(f, type("F", (R,), change), pins, 5, fixpoint=True)
+            assert f.problem and word in f.problem, (change, f.problem)
+    finally:
+        signal.signal(signal.SIGINT, old)
+
+
+def test_a_fixpoint_traffic_is_stopped_by_nothing_and_held_to_the_verdict():
+    from benchmark.harness import correct
+    cell = toy_verdict_cell()
+    pins = cell["config_data"]["level_pins"]
+    drv, warm, snap, made, calls = _drive_fake(cell)
+    # the calls a stated Init always made; the warm pass stops at level 3
+    # by SIGINT, a timed pass is stopped by nothing and returns complete
+    assert snap is None and [sorted(c) for c in calls] \
+        == [["init_override"]] * 3 and drv.fixpoint
+    assert warm.problem is None and not warm.fixpoint \
+        and warm.complete is False and len(warm.levels) == 4
+    assert (drv.a, drv.b, drv.pass_orbits, drv.orbits) \
+        == (12, 24, 2137, 2137 - 565)
+    for p in made:
+        assert p.problem is None and p.fixpoint and p.complete is True
+        assert p.levels == pins and p.overshoot_levels == []
+        assert p.t_call <= p.t_a <= p.t_last <= p.t_b == p.t_return
+    sound = {n: v for n, v, _l in correct.pass_checks(made, pins, 24)
+             + correct.fixpoint_checks(made, pins)}
+    assert set(sound.values()) == {0} and {
+        "passes_incomplete", "fixpoint_total_diff"} <= set(sound)
+    ev = {"passes": made}
+    assert mf.metric_reader("verdict_wall_s")(ev) > 0.0
+    assert mf.metric_reader("fixpoint_close_s")(ev) >= 0.0
+    assert mf.metric_reader("levels_per_pass")(ev) == 25
+    # an engine that gets its end wrong fails the check that names it
+    for fault, wrong in (
+            ({"complete": False}, {"passes_incomplete": 2}),
+            ({"count_off": -8}, {"fixpoint_total_diff": 16}),
+            # a state past the last pin, in a result that still says 2,137
+            ({"more": [1]}, {"fixpoint_total_diff": 2})):
+        _d, _w, _s, broken, _c = _drive_fake(toy_verdict_cell(), fault)
+        assert all(p.problem is not None for p in broken), fault
+        got = {n: v for n, v, _l in correct.pass_checks(broken, pins, 24)
+               + correct.fixpoint_checks(broken, pins) if v}
+        assert got == wrong, (fault, got)
+        for n in ("verdict_wall_s", "fixpoint_close_s", "levels_per_pass"):
+            assert mf.metric_reader(n)({"passes": broken}) is None, n
+    # a pass stopped at a pin reads none of the three
+    _d, _w, _s, pinned, _c = _drive_fake(toy_repl_cell())
+    assert all(p.problem is None and p.complete is False for p in pinned)
+    for n in ("verdict_wall_s", "fixpoint_close_s", "levels_per_pass"):
+        assert mf.metric_reader(n)({"passes": pinned}) is None, n
+
+
+def test_readers_of_a_pass_that_ends_by_itself_on_the_recorded_toy_pass():
+    from benchmark.harness import spanred, tailred
+    spans = spanred.load(FIXPOINT_LOG)
+    # the replication toy to its end, chunk 64: the most rows any level
+    # expands are level 18's 234; after it, levels 23-25 admit 30, 8 and 0
+    red = tailred.reduce(spans, 64)
+    wall = red.pop("tail_wall_s")
+    assert red == {"levels": 25, "peak_level": 18, "peak_frontier_rows": 234,
+                   "tail_levels": [23, 24, 25],
+                   "tail_new_states": [30, 8, 0]}
+    assert abs(wall - (0.012846 + 0.007973 + 0.005527)) < 1e-9
+    # under a smaller chunk only the empty expansion is the tail
+    assert tailred.reduce(spans, 8)["tail_levels"] == [25]
+    assert tailred.reduce([s for s in spans if s["name"] != "level"],
+                          64) is None
+    traced = passes.Pass(index=2, t_call=0.0, t_a=1.0, t_b=2.0,
+                         t_trace_end=1.5, traced=True, fixpoint=True,
+                         events=FIXPOINT_LOG)
+    ev = {"passes": [traced], "work": {"chunk": 64}}
+    assert mf.metric_reader("peak_frontier_rows")(ev) == 234
+    assert abs(mf.metric_reader("tail_levels_s")(ev) - wall) < 1e-12
+    # nothing to read: an untraced run; a pass whose levels never fall
+    # under a chunk after the peak (a pass cut on the way up)
+    bare = {"passes": [passes.Pass(index=0, t_call=0.0, t_a=1.0, t_b=2.0)],
+            "work": {"chunk": 64}}
+    for n in ("peak_frontier_rows", "tail_levels_s"):
+        assert mf.metric_reader(n)(bare) is None, n
+    rising = [s for s in spans if s["name"] != "level"
+              or s["args"]["level"] <= 18]
+    assert tailred.reduce(rising, 64)["tail_wall_s"] is None
+
+
+def rehearse_verdict(seed: int, trace: bool = False) -> dict:
+    from benchmark import run
+    return run.execute(toy_verdict_cell(), mf.load(), seed, 0.0, trace,
+                       rehearsal=True)
+
+
+def test_rehearsal_of_passes_that_end_by_themselves_is_correct():
+    res = rehearse_verdict(3_000_000_041, trace=True)
+    assert res["rehearsal"] is True and res["metrics"] == {}
+    assert res["correct"] is True
+    assert res["attempted"] >= 3 and res["failed"] == 0
+    assert res["checks"]["passes_incomplete"] == {"value": 0, "limit": 0}
+    assert res["checks"]["fixpoint_total_diff"] == {"value": 0, "limit": 0}
+    # a pass stopped at a pin compares every number but those two
+    assert set(res["checks"]) - set(rehearse_repl(43)["checks"]) \
+        == {"passes_incomplete", "fixpoint_total_diff"}
+
+
+def test_a_dropped_last_level_comes_out_not_correct():
+    # the timed path broken underneath: the frontier that bears the last
+    # level is handed to the compiled segment empty, so the engine returns
+    # complete = True a level early, 8 orbits short a pass
+    from benchmark.harness import breakers
+    with breakers.drops_last_level():
+        res = rehearse_verdict(44)
+        assert rehearse_repl(45)["correct"] is True    # stopped at a pin
+    assert res["correct"] is False and res["failed"] == res["attempted"]
+    wrong = {n: c["value"] for n, c in res["checks"].items()
+             if c["value"] > c["limit"]}
+    assert wrong == {"fixpoint_total_diff": 8 * res["attempted"],
+                     "pass_level_mismatches": res["attempted"],
+                     "passes_short_of_B": res["attempted"]}
+
+
+def test_a_pass_stopped_at_the_total_has_no_verdict():
+    # what every older cell does, done to this one: the pass is stopped at
+    # the record of the last pin.  Every count holds and every older number
+    # reads 0; the empty last expansion was never made, so the engine says
+    # complete = False, and the one new number sees it
+    import signal
+    from benchmark.harness import drive
+    real = drive.build_engine
+    total = toy_verdict_cell()["config_data"]["level_pins"][-1]
+
+    def build(cfg):
+        eng = real(cfg)
+        check = eng.check
+
+        def stopped(on_progress=None, **kw):
+            hit = []
+
+            def cb(rec):
+                on_progress(rec)
+                if rec["n_states"] == total and not hit:
+                    hit.append(1)
+                    signal.raise_signal(signal.SIGINT)
+            return check(on_progress=cb, **kw)
+
+        eng.check = stopped
+        return eng
+
+    drive.build_engine = build
+    try:
+        res = rehearse_verdict(46)
+    finally:
+        drive.build_engine = real
+    assert res["correct"] is False and res["failed"] == res["attempted"]
+    assert {n: c["value"] for n, c in res["checks"].items()
+            if c["value"] > c["limit"]} \
+        == {"passes_incomplete": res["attempted"]}
 
 
 # ------------------------------------------------------ the trace reduction
