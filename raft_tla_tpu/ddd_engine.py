@@ -247,6 +247,8 @@ class SegStats(NamedTuple):
                              # _S_OUT sizing signal
     stream_slabs: jax.Array  # slab writes; == steps unless a chunk
                              # streamed more than one slab holds
+    probe_tiles: jax.Array   # tiles of the filter probe; a step takes
+                             # ceil(its live lanes / _T_PROBE)
 
 
 class _SegCarry(NamedTuple):
@@ -271,6 +273,7 @@ class _SegCarry(NamedTuple):
     peak: jax.Array
     stream_peak: jax.Array
     stream_slabs: jax.Array
+    probe_tiles: jax.Array
 
 
 def save_ddd_snapshot(path, host, constore, keystore, n_states, n_trans,
@@ -665,7 +668,8 @@ def frontier_checkpoint_setup(resume, checkpoint, checkpoint_every_s,
 # masked updates to 16k is the win; a combined [TB, BUCKET, 2] table
 # layout that would fix this with one row scatter was measured SLOWER
 # in-engine (rank-3 minor-dim-2 layout wrecks the probe gather) and
-# rejected.
+# rejected.  The budget's entries are the head of the stage's second
+# sort, keys and slots its payload: nothing is gathered for them.
 _S_INS = 1 << 14
 
 # Rows one slab of the candidate stream holds (_build_segment's
@@ -677,33 +681,108 @@ _S_INS = 1 << 14
 # slabs.  Sized from SegStats.stream_peak (PERF.md, PR 25).
 _S_OUT = 1 << 14
 
+# Sorted positions one tile of the filter probe holds
+# (_filter_insert_ordered): the same lesson applied to the bucket
+# gathers — a gather costs per lane gathered, so the stage gathers the
+# live prefix of its own sort tile by tile, the tile count taken from
+# the live lanes the step observes, and not every lane.  Unlike a slab
+# a tile is work for every position in it, live or not, so the size
+# trades the dead part of a step's last tile against the loop's trips.
+# Chip-measured (PERF.md, PR 35; the stage alone, random keys, a 2^19 x 8
+# table): a position costs 17 ns, a trip nothing that shows (all 172,032
+# lanes live: 42 tiles of 2^12 4.01 ms, 21 of 2^13 3.96, 11 of 2^14 4.44,
+# 6 of 2^15 4.97), and a step's live lanes (SegStats.n_valid over steps)
+# are 8-20 k in the benchmark's cells, so 2^13 wastes the least: at
+# 344,064 lanes with 19.6 k live 1.75 ms against 2.79 at 2^14, at 73,728
+# with 8.1 k live 0.92 against 1.41.  SegStats.probe_tiles over steps
+# says how many tiles a step took.
+_T_PROBE = 1 << 13
+
+
+def _tile_plan(t: int, nk: int) -> tuple[int, int]:
+    """``(T, slack)`` for ``nk`` entries cut into tiles of at most ``t``:
+    the entries a tile holds, and how far past ``nk`` whole tiles
+    reach."""
+    t = min(t, nk)
+    return t, -nk % t
+
 
 def _slab_plan(nk: int) -> tuple[int, int]:
     """``(S, slack)`` for a chunk of ``nk`` candidate rows: the rows a
     slab holds, and how far past ``nk`` whole slabs reach — the rows the
     segment buffers carry beyond ``seg_rows`` so that a chunk's last
     slab always lands inside them."""
-    s = min(_S_OUT, nk)
-    return s, -nk % s
+    return _tile_plan(_S_OUT, nk)
 
 
 def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
-    """``_filter_insert_ordered`` without the compaction order."""
-    return _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo,
-                                  active)[:3]
+    """``_filter_insert_ordered`` with the streamed candidates as a mask
+    in lane order (``stream[c]`` is True iff candidate c streamed) in
+    place of the compaction order: one scatter of the streamed lanes
+    (3.9 ms at the mesh engine's 622,592 lanes, where the stage before it
+    is 2.9 and the lane-order stage was 51.8; PERF.md, PR 35)."""
+    BA = key_hi.shape[0]
+    tbl_hi, tbl_lo, n_stream, compact, _ = _filter_insert_ordered(
+        tbl_hi, tbl_lo, key_hi, key_lo, active)
+    lane = jnp.where(jnp.arange(BA, dtype=I32) < n_stream, compact, BA)
+    stream = jnp.zeros((BA,), bool).at[lane].set(True, mode="drop")
+    return tbl_hi, tbl_lo, stream
 
 
 def _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo, active):
-    """Lossy one-gather filter probe + compacted insert.
+    """Lossy one-gather filter probe + compacted insert, in key-sorted
+    space over the live prefix of the stage's own sort.
 
-    Returns ``(tbl_hi, tbl_lo, stream, compact)`` where ``stream[c]`` is
-    True iff candidate c is active, is the first active candidate carrying its key
-    in this batch (same two-sort first-occurrence pass as
-    device_engine._dedup_insert stage 1), and its key is NOT in the
+    Returns ``(tbl_hi, tbl_lo, n_stream, compact, n_tiles)``.  A
+    candidate c *streams* iff it is active, is the first active candidate
+    carrying its key in this batch (same two-sort first-occurrence pass
+    as device_engine._dedup_insert stage 1), and its key is NOT in the
     filter — bit-identical stream semantics to the rounds-1-3
     implementation (discovery order never depends on filter contents: a
     filter hit proves the key already streamed, so the parity argument
-    is insert-policy-independent).
+    is insert-policy-independent).  ``compact[:n_stream]`` are the
+    streamed candidates in batch order (the entries past them are
+    in-range lanes of no meaning): the insert takes its first ``_S_INS``
+    entries, the segment's ``stream`` stage its slabs.  ``n_tiles`` is
+    the probe's trip count (``SegStats.probe_tiles``).
+
+    How (the work follows the live candidates, of which a dense step
+    has one lane in ten, not the lanes):
+
+    1. One ``lax.sort`` over ``(key_hi, key_lo, lane)`` — ties in lane
+       order, what a stable sort on the keys gives — so the sorted keys
+       are the sort's own output and nothing is gathered back by a
+       permutation.  Inactive lanes sort under ``_EMPTY``, the largest
+       key, so the live candidates are the first ``n_valid`` sorted
+       positions, and first-of-key is a compare with the left neighbour
+       there, with no scatter back to lane order.  (An active key that
+       IS all-ones interleaves with the dead lanes: the lane word's low
+       bit keeps who is live, and the tiles cover up to the last live
+       position, so the corner probes like any other key.)
+    2. The bucket rows are gathered for ``T = _T_PROBE`` sorted positions
+       a tile, ``ceil(n_valid / T)`` tiles — the ``stream`` stage's slab
+       loop again, the trip count what the step observes.  The tables
+       are read only: every probe sees them as they stood before this
+       batch's inserts.  A tile leaves one word a position: the lane and
+       the write slot of a position that streams, a dead mark otherwise.
+       The loop's operands are the sort's results, so no gather can be
+       scheduled ahead of the sort (PR 27 needed an optimization barrier
+       for that), and the compiler still copies one table into fast
+       memory under the sort (read in the segment program compiled for
+       a described v5e: a ``ConcatBitcast`` of four ``slice-start``s).
+    3. A second sort, on that word with the keys as payload, brings the
+       streamed positions to the head in batch order: the compaction
+       order, the insert's keys and its slots in one op.
+
+    What it costs (one v5e chip, the flagship's 172,032 lanes, 9 % of
+    them live, 2.4 tiles a step; PERF.md, PR 35): the stage 0.86 ms a
+    step in the segment program, where probing every lane in lane order
+    took 9.93 — sort 1 0.24 ms, sort 2 0.22, a tile's two bucket gathers
+    17 ns a position (18 + 5 ns a lane over all N in lane order, 4.0
+    ms), and that stage's 4.4 ms of N-lane gathers by the permutation
+    and scatter back to lane order have nothing left to do.  With every lane live the
+    tiles do the gathers' whole work and the stage still reads 4.0 ms
+    (the stage alone): one path at any fill.
 
     Inserts: first empty slot, else overwrite the key-hashed slot —
     eviction, the ``_S_INS`` compaction budget, and the in-batch
@@ -718,49 +797,66 @@ def _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo, active):
     only the first insert per (bucket, slot) per batch removes the
     reliance outright; the loser key simply isn't remembered and may
     re-stream later, which the host dedups.
-
-    ``compact`` is the stable compaction order of all candidates, streamed
-    ones first in batch order (``stream[compact[:sum(stream)]]`` is all
-    True): the insert takes its first ``_S_INS`` entries, the segment's
-    ``stream`` stage its slabs — one N-wide sort serves both.
     """
     BA = key_hi.shape[0]
     TB, Sb = tbl_hi.shape
     bmask = jnp.uint32(TB - 1)
+    T, slack = _tile_plan(_T_PROBE, BA)
+    # one int32 a position: (lane * Sb + write slot) of a position that
+    # streams, DEAD otherwise
+    DEAD = BA * Sb
+    if DEAD >= 1 << 30:
+        raise ValueError(
+            f"{BA} candidate lanes x {Sb} bucket slots exceed 30 bits")
+    iota = jnp.arange(BA, dtype=I32)
+
     skh = jnp.where(active, key_hi, _EMPTY)
     skl = jnp.where(active, key_lo, _EMPTY)
-    perm = jnp.lexsort((skl, skh))       # stable: ties keep stream order
-    ph, pl, pa = key_hi[perm], key_lo[perm], active[perm]
+    # the lane id as third key keeps ties in lane order with no stable
+    # sort's hidden iota operand; its low bit carries who is live
+    ph, pl, tag = jax.lax.sort((skh, skl, iota * 2 + active.astype(I32)),
+                               num_keys=3, is_stable=False)
+    pa = (tag & 1) == 1
     same_as_prev = jnp.concatenate([
         jnp.zeros((1,), bool),
         (ph[1:] == ph[:-1]) & (pl[1:] == pl[:-1]) & pa[1:] & pa[:-1]])
-    first_of_key = jnp.zeros((BA,), bool).at[perm].set(~same_as_prev)
-    probe = active & first_of_key
+    # lane * Sb of a first-of-key live position, DEAD otherwise
+    base = jnp.where(pa & ~same_as_prev, (tag >> 1) * Sb, DEAD)
+    n_tiles = (jnp.max(jnp.where(pa, iota + 1, 0)) + T - 1) // T
+    ph_p, pl_p = jnp.pad(ph, (0, slack)), jnp.pad(pl, (0, slack))
+    base_p = jnp.pad(base, (0, slack), constant_values=DEAD)
 
-    bidx = (key_lo & bmask).astype(I32)
-    # The bucket gathers need only the keys, so the compiler is free to run
-    # them before the sort above — and then has nothing to overlap the
-    # tables' copy into fast memory with, and gathers both from HBM (4.5
-    # against 0.85 ms a table at N = 172,032; PERF.md, PR 27: a change in
-    # the orbit scan moved them there).  Tie them to the sort's result.
-    bidx, probe = jax.lax.optimization_barrier((bidx, probe))
-    row_hi, row_lo = tbl_hi[bidx], tbl_lo[bidx]          # [BA, Sb] gather
-    seen = jnp.any((row_hi == key_hi[:, None])
-                   & (row_lo == key_lo[:, None]), axis=1)
-    stream = probe & ~seen
-    slot_empty = (row_hi == _EMPTY) & (row_lo == _EMPTY)
-    has_empty = jnp.any(slot_empty, axis=1)
-    evict = (key_hi % jnp.uint32(Sb)).astype(I32)
-    wslot = jnp.where(has_empty, jnp.argmax(slot_empty, axis=1), evict)
+    def probe_tile(t, word):
+        at = (t * T,)
+        kh = jax.lax.dynamic_slice(ph_p, at, (T,))
+        kl = jax.lax.dynamic_slice(pl_p, at, (T,))
+        b = jax.lax.dynamic_slice(base_p, at, (T,))
+        bidx = (kl & bmask).astype(I32)
+        row_hi, row_lo = tbl_hi[bidx], tbl_lo[bidx]      # [T, Sb] gather
+        seen = jnp.any((row_hi == kh[:, None])
+                       & (row_lo == kl[:, None]), axis=1)
+        slot_empty = (row_hi == _EMPTY) & (row_lo == _EMPTY)
+        wslot = jnp.where(jnp.any(slot_empty, axis=1),
+                          jnp.argmax(slot_empty, axis=1).astype(I32),
+                          (kh % jnp.uint32(Sb)).astype(I32))
+        return jax.lax.dynamic_update_slice(
+            word, jnp.where((b < DEAD) & ~seen, b + wslot, DEAD), at)
 
-    # compact the streamed inserts (stable: stream-first, batch order),
-    # then scatter only S updates instead of BA
+    word = jax.lax.fori_loop(0, n_tiles, probe_tile,
+                             jnp.full((BA + slack,), DEAD, I32))[:BA]
+
+    # the streamed positions to the head, batch order (lanes are
+    # distinct, so the order of the dead tail is nobody's business)
+    word, kh, kl = jax.lax.sort((word, ph, pl), num_keys=1,
+                                is_stable=False)
+    n_stream = jnp.sum((word < DEAD).astype(I32))
+    compact = jnp.minimum(word // Sb, BA - 1)
+
+    # scatter only S updates instead of BA
     S = min(_S_INS, BA)
-    compact = jnp.argsort(~stream, stable=True)
-    sel = compact[:S]
-    ok = stream[sel]
-    wb = jnp.where(ok, bidx[sel], TB)            # TB row = dropped
-    ws = wslot[sel]
+    word, kh, kl = word[:S], kh[:S], kl[:S]
+    wb = jnp.where(word < DEAD, (kl & bmask).astype(I32), TB)  # TB = dropped
+    ws = word % Sb
     # in-batch (bucket, slot) dedup: duplicate-free scatter indices have
     # no update-order semantics to rely on (see docstring)
     lin = wb * Sb + ws
@@ -768,9 +864,9 @@ def _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo, active):
     dup = jnp.concatenate(
         [jnp.zeros((1,), bool), lin[order][1:] == lin[order][:-1]])
     wb = jnp.where(jnp.zeros((S,), bool).at[order].set(~dup), wb, TB)
-    tbl_hi = tbl_hi.at[wb, ws].set(key_hi[sel], mode="drop")
-    tbl_lo = tbl_lo.at[wb, ws].set(key_lo[sel], mode="drop")
-    return tbl_hi, tbl_lo, stream, compact
+    tbl_hi = tbl_hi.at[wb, ws].set(kh, mode="drop")
+    tbl_lo = tbl_lo.at[wb, ws].set(kl, mode="drop")
+    return tbl_hi, tbl_lo, n_stream, compact, n_tiles
 
 
 def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
@@ -808,7 +904,7 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
     def chunk_body(carry: _SegCarry) -> _SegCarry:
         (tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar, olane, ocon,
          cursor, n_valid_a, fail, viol_kind, viol_inv, dead_g, c,
-         peak, stream_peak, stream_slabs) = carry
+         peak, stream_peak, stream_slabs, probe_tiles) = carry
         r0 = c * B
         rows_b = r0 + jnp.arange(B, dtype=I32)
         row_act = rows_b < block_rows
@@ -879,8 +975,9 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             fail = fail | jnp.any(kvalid & ovf_rows).astype(I32) * FAIL_WIDTH
 
         with jax.named_scope("filter_insert"):
-            tbl_hi, tbl_lo, stream, compact = _filter_insert_ordered(
-                tbl_hi, tbl_lo, kh, kl, kvalid)
+            (tbl_hi, tbl_lo, n_stream, compact,
+             n_tiles) = _filter_insert_ordered(tbl_hi, tbl_lo, kh, kl, kvalid)
+            probe_tiles = probe_tiles + n_tiles
         with jax.named_scope("pack"):
             svecs = schema.pack(word_rows, jnp)
         with jax.named_scope("stream"):
@@ -892,7 +989,6 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             # never reads past stats.cursor.  The buffers' slack rows
             # (_slab_plan) keep the last slab inside them, so no write
             # is ever clamped.
-            n_stream = jnp.sum(stream.astype(I32))
             compact_p = jnp.pad(compact, (0, SLACK))
 
             def write_slab(j, bufs):
@@ -947,7 +1043,7 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
         return _SegCarry(tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar,
                          olane, ocon, cursor, n_valid_a, fail, viol_kind,
                          viol_inv_c.astype(I32), dead_g, c + 1, peak,
-                         stream_peak, stream_slabs)
+                         stream_peak, stream_slabs, probe_tiles)
 
     def cond(sc):
         s, carry = sc
@@ -970,7 +1066,8 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             cursor=jnp.int32(0), n_valid=jnp.int32(0), fail=jnp.int32(0),
             viol_kind=jnp.int32(0), viol_inv=jnp.int32(0),
             dead_g=jnp.int32(-1), c=fc.c, peak=jnp.int32(0),
-            stream_peak=jnp.int32(0), stream_slabs=jnp.int32(0))
+            stream_peak=jnp.int32(0), stream_slabs=jnp.int32(0),
+            probe_tiles=jnp.int32(0))
         steps, carry = jax.lax.while_loop(cond, body,
                                           (jnp.int32(0), carry))
         n_chunks = (block_rows + B - 1) // B
@@ -980,7 +1077,8 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
                 SegStats(carry.cursor, carry.n_valid, carry.fail,
                          carry.viol_kind, carry.viol_inv, carry.dead_g,
                          steps, carry.c >= n_chunks, carry.peak,
-                         carry.stream_peak, carry.stream_slabs))
+                         carry.stream_peak, carry.stream_slabs,
+                         carry.probe_tiles))
 
     fbuf = fcon = budget = block_rows = None
     return segment
@@ -1401,6 +1499,7 @@ class DDDEngine:
         route_peak = 0       # max live enabled lanes seen in any chunk
         stream_peak = 0      # most rows any chunk streamed (sizes _S_OUT)
         stream_slabs = 0     # slab writes of the pass
+        probe_tiles = 0      # tiles of the filter probe (sizes _T_PROBE)
         complete = True
         stopped = False
         t_warm = None
@@ -1428,6 +1527,7 @@ class DDDEngine:
                 coverage=dict(aggregate_coverage(self.table, cov)),
                 route_peak=route_peak,
                 stream_peak=stream_peak, stream_slabs=stream_slabs,
+                probe_tiles=probe_tiles,
                 flush_backlog=worker.backlog() if worker else None,
                 upload_wait_ms=round(prefetcher.wait_s * 1e3, 3)
                 if prefetcher else None,
@@ -1439,7 +1539,7 @@ class DDDEngine:
         stopped_by = None
         # the open level's work
         lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
-        lvl_valid = lvl_route = 0
+        lvl_valid = lvl_route = lvl_tiles = 0
 
         def end_level():
             # lanes: what the dense step computed, enabled or not;
@@ -1449,6 +1549,7 @@ class DDDEngine:
                          route_peak=lvl_route,
                          streamed_rows=lvl_rows,
                          stream_peak=lvl_peak, stream_slabs=lvl_slabs,
+                         probe_tiles=lvl_tiles,
                          new_states=n_states - lvl_hi).close()
 
         while not stopped:
@@ -1461,7 +1562,7 @@ class DDDEngine:
                                rows=lvl_hi - lvl_lo,
                                blocks=-(-(lvl_hi - b0) // Fcap))
             lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
-            lvl_valid = lvl_route = 0
+            lvl_valid = lvl_route = lvl_tiles = 0
             if prefetcher is not None and b0 < lvl_hi:
                 # level start: every block address in [lvl_lo, lvl_hi)
                 # is known now — warm the first block immediately
@@ -1594,10 +1695,12 @@ class DDDEngine:
                         n_steps = int(st_h.steps)
                         seg_peak = int(st_h.stream_peak)
                         seg_slabs = int(st_h.stream_slabs)
+                        seg_tiles = int(st_h.probe_tiles)
                         seg_route = int(st_h.peak)
                         route_peak = max(route_peak, seg_route)
                         stream_peak = max(stream_peak, seg_peak)
                         stream_slabs += seg_slabs
+                        probe_tiles += seg_tiles
                         if tr.enabled:
                             # dispatch -> stats ready, on its own track
                             # (segments overlap: two are in flight)
@@ -1612,12 +1715,14 @@ class DDDEngine:
                                 route_peak=seg_route,
                                 stream_peak=seg_peak,
                                 stream_slabs=seg_slabs,
+                                probe_tiles=seg_tiles,
                                 dropped=stopped)
                         lvl_segs += 1
                         lvl_steps += n_steps
                         lvl_valid += nv
                         lvl_route = max(lvl_route, seg_route)
                         lvl_slabs += seg_slabs
+                        lvl_tiles += seg_tiles
                         lvl_peak = max(lvl_peak, seg_peak)
                         bufs_h = None
                         if ns and not stopped:

@@ -346,6 +346,7 @@ def _level_rows(threads: dict, trees: dict) -> list:
                          "route_peak": a.get("route_peak"),
                          "stream_peak": a.get("stream_peak"),
                          "stream_slabs": a.get("stream_slabs"),
+                         "probe_tiles": a.get("probe_tiles"),
                          "new_states": a.get("new_states"),
                          "self_s": self_s[id(s)],
                          "dominant_child": dom[0] if dom else None,
@@ -420,6 +421,8 @@ def render_report(rep: dict) -> str:
                 + (f"{lv['stream_slabs']} slabs (peak "
                    f"{lv['stream_peak']} rows), "
                    if lv["stream_slabs"] is not None else "")
+                + (f"{lv['probe_tiles']} probe tiles, "
+                   if lv["probe_tiles"] is not None else "")
                 + f"+{lv['new_states']} states, "
                 f"self {lv['self_s']:.3f}s, most in: {dom}")
     return "\n".join(lines)

@@ -25,7 +25,7 @@ wall).  This module replaces all of that with:
   ``level_end`` derived automatically from level transitions and
   ``violation`` derived from the final :class:`~raft_tla_tpu.engine.EngineResult`.
 
-Event grammar (``SCHEMA_VERSION`` = 12; earlier-version lines remain
+Event grammar (``SCHEMA_VERSION`` = 13; earlier-version lines remain
 valid) —
 every line is one JSON object with base fields ``v`` (schema version),
 ``event`` (type) and ``ts`` (unix epoch seconds):
@@ -169,13 +169,21 @@ pass — the sizing signal for the slab size, as ``route_peak`` is for
 chunk steps unless a chunk streamed more than one slab holds).  The
 ``segments`` track's ``segment`` spans carry the same two per segment.
 
+Version 13 adds the tile counter of the ddd filter probe (ddd_engine
+``SegStats.probe_tiles``): segment ``probe_tiles`` (cumulative tiles of
+``ddd_engine._T_PROBE`` sorted positions the ``filter_insert`` stage
+gathered bucket rows for; a chunk step takes ``ceil(its live candidate
+lanes / _T_PROBE)``, so tiles over steps is the sizing signal for the
+tile).  The ``segment`` and ``level`` spans carry the per-segment and
+per-level counts.
+
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the
-v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12-only
+v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12/v13-only
 fields) are invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` /
 ``"v" < 10`` (resp. ``"v" < 3`` / ``"v" < 4`` / ``"v" < 5`` /
-``"v" < 6`` / ``"v" < 8`` / ``"v" < 9`` / ``"v" < 11`` / ``"v" < 12``)
-line, so any addition requires
+``"v" < 6`` / ``"v" < 8`` / ``"v" < 9`` / ``"v" < 11`` / ``"v" < 12`` /
+``"v" < 13``) line, so any addition requires
 a version bump (versioning policy in README.md).
 """
 
@@ -189,8 +197,8 @@ import subprocess
 import threading
 import time
 
-SCHEMA_VERSION = 12
-_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12)  # versions validate_event accepts
+SCHEMA_VERSION = 13
+_VERSIONS = tuple(range(1, SCHEMA_VERSION + 1))  # validate_event accepts
 
 # Environment knobs (set by check.py --events/--phase-timers; inherited by
 # liveness re-runs and bench children the same way RAFT_TLA_PRESCAN is).
@@ -312,6 +320,15 @@ _V11_FIELDS = {"run_end": frozenset({"compiles"})}
 # program's slab-write counters) — invalid on a "v" < 12 line.
 _V12_FIELDS = {"segment": frozenset({"stream_peak", "stream_slabs"})}
 
+# Fields that only exist from schema version 13 on (the tile counter of
+# the ddd filter probe) — invalid on a "v" < 13 line.
+_V13_FIELDS = {"segment": frozenset({"probe_tiles"})}
+
+# schema version -> the fields that exist only from it on, by event
+_FIELDS_SINCE = {3: _V3_FIELDS, 4: _V4_FIELDS, 5: _V5_FIELDS,
+                 6: _V6_FIELDS, 8: _V8_FIELDS, 9: _V9_FIELDS,
+                 11: _V11_FIELDS, 12: _V12_FIELDS, 13: _V13_FIELDS}
+
 _OPTIONAL = {
     "run_start": {"bounds": dict, "symmetry": list, "view": str,
                   "chunk": int, "caps": str, "n_states": int,
@@ -322,7 +339,8 @@ _OPTIONAL = {
                 "bin": str, "inflight": int, "flush_backlog": int,
                 "upload_wait_ms": _NUM, "prefetch_hits": int,
                 "export_rows": int, "dev_dedup_hits": int,
-                "stream_peak": int, "stream_slabs": int},
+                "stream_peak": int, "stream_slabs": int,
+                "probe_tiles": int},
     "level_end": {},
     "checkpoint": {"n_states": int},
     "violation": {"kind": str},
@@ -381,14 +399,6 @@ def validate_event(d: dict) -> list:
             errs.append(f"{ev}: missing required field {k!r}")
         elif not _is(d[k], spec):
             errs.append(f"{ev}: field {k!r} has wrong type")
-    v3_only = _V3_FIELDS.get(ev, frozenset())
-    v4_only = _V4_FIELDS.get(ev, frozenset())
-    v5_only = _V5_FIELDS.get(ev, frozenset())
-    v6_only = _V6_FIELDS.get(ev, frozenset())
-    v8_only = _V8_FIELDS.get(ev, frozenset())
-    v9_only = _V9_FIELDS.get(ev, frozenset())
-    v11_only = _V11_FIELDS.get(ev, frozenset())
-    v12_only = _V12_FIELDS.get(ev, frozenset())
     for k, val in d.items():
         if k in _BASE or k in req:
             continue
@@ -397,22 +407,12 @@ def validate_event(d: dict) -> list:
                         "additions need a version bump)")
         elif not _is(val, opt[k]):
             errs.append(f"{ev}: field {k!r} has wrong type")
-        elif k in v3_only and d["v"] in _VERSIONS and d["v"] < 3:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 3")
-        elif k in v4_only and d["v"] in _VERSIONS and d["v"] < 4:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 4")
-        elif k in v5_only and d["v"] in _VERSIONS and d["v"] < 5:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 5")
-        elif k in v6_only and d["v"] in _VERSIONS and d["v"] < 6:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 6")
-        elif k in v8_only and d["v"] in _VERSIONS and d["v"] < 8:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 8")
-        elif k in v9_only and d["v"] in _VERSIONS and d["v"] < 9:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 9")
-        elif k in v11_only and d["v"] in _VERSIONS and d["v"] < 11:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 11")
-        elif k in v12_only and d["v"] in _VERSIONS and d["v"] < 12:
-            errs.append(f"{ev}: field {k!r} requires schema version >= 12")
+        elif d["v"] in _VERSIONS:
+            since = next((v for v, fields in _FIELDS_SINCE.items()
+                          if k in fields.get(ev, ())), 1)
+            if d["v"] < since:
+                errs.append(f"{ev}: field {k!r} requires schema version "
+                            f">= {since}")
     return errs
 
 
@@ -458,6 +458,7 @@ class ProgressRecord:
     dev_dedup_hits: int | None = None  # ddd: device-set pre-export drops
     stream_peak: int | None = None    # ddd: most rows one chunk streamed
     stream_slabs: int | None = None   # ddd: cumulative slab writes
+    probe_tiles: int | None = None    # ddd: cumulative filter-probe tiles
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -509,7 +510,8 @@ class ProgressTracker:
                export_rows: int | None = None,
                dev_dedup_hits: int | None = None,
                stream_peak: int | None = None,
-               stream_slabs: int | None = None) -> ProgressRecord:
+               stream_slabs: int | None = None,
+               probe_tiles: int | None = None) -> ProgressRecord:
         wall = time.monotonic() - self.t0
         reported = n_states if n_incl is None else max(n_states, n_incl)
         if self._prev_n is None:  # unknown baseline: anchor, rate 0
@@ -548,6 +550,7 @@ class ProgressTracker:
             dev_dedup_hits=dev_dedup_hits,
             stream_peak=stream_peak,
             stream_slabs=stream_slabs,
+            probe_tiles=probe_tiles,
         )
 
 
@@ -780,7 +783,8 @@ class RunTelemetry:
                 export_rows: int | None = None,
                 dev_dedup_hits: int | None = None,
                 stream_peak: int | None = None,
-                stream_slabs: int | None = None) -> ProgressRecord:
+                stream_slabs: int | None = None,
+                probe_tiles: int | None = None) -> ProgressRecord:
         rec = self.tracker.record(
             n_states, level, n_transitions, coverage=coverage,
             route_peak=route_peak, n_incl=n_incl,
@@ -792,7 +796,8 @@ class RunTelemetry:
             prefetch_hits=prefetch_hits,
             export_rows=export_rows,
             dev_dedup_hits=dev_dedup_hits,
-            stream_peak=stream_peak, stream_slabs=stream_slabs)
+            stream_peak=stream_peak, stream_slabs=stream_slabs,
+            probe_tiles=probe_tiles)
         if self.log is not None:
             if self._last_level is not None and level > self._last_level:
                 # The boundary count is the count as observed at the first

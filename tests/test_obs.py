@@ -149,7 +149,8 @@ def test_validate_rejects_unknowns_and_type_drift():
     assert validate_event({**ok, "v": 10}) == []            # v10 superset
     assert validate_event({**ok, "v": 11}) == []            # v11 superset
     assert validate_event({**ok, "v": 12}) == []            # v12 superset
-    assert validate_event({**ok, "v": 13})                  # future version
+    assert validate_event({**ok, "v": 13}) == []            # v13 superset
+    assert validate_event({**ok, "v": 14})                  # future version
     assert validate_event({"v": 1, "event": "level_end", "ts": 0.0,
                            "level": 3})                     # missing field
 
@@ -353,6 +354,23 @@ def test_validate_v12_segment_slab_counters():
     assert validate_event({**seg, "stream_peak": 7.5})     # type drift
 
 
+def test_validate_v13_segment_probe_tiles():
+    """The ddd filter probe's tile counter (``probe_tiles``) exists only
+    from schema v13, field-gated like the v12 segment fields; a v12 line as
+    PR 25's program wrote it still reads."""
+    v12 = {"v": 12, "event": "segment", "ts": 0.0, "wall_s": 1.0,
+           "n_states": 10, "level": 2, "n_transitions": 20,
+           "dedup_hit_rate": 0.5, "states_per_sec": 10.0,
+           "inc_states_per_sec": 10.0, "since_resume": True,
+           "stream_peak": 7, "stream_slabs": 3}
+    seg = {**v12, "v": 13, "probe_tiles": 5}
+    assert validate_event(v12) == [] and validate_event(seg) == []
+    errs = validate_event({**seg, "v": 12})  # v13-only field, v12 line
+    assert len(errs) == 1 and "requires schema version >= 13" in errs[0]
+    assert validate_event({**seg, "probe_tiles": 5.5})     # type drift
+    assert json.loads(json.dumps(seg)) == seg              # round trip
+
+
 def test_ddd_segment_records_carry_the_slab_counters(tmp_path):
     """RunTelemetry keeps the pass's running maximum and total: the
     segment records (log and on_progress alike) carry ``stream_peak``
@@ -363,7 +381,7 @@ def test_ddd_segment_records_carry_the_slab_counters(tmp_path):
     res = _run_engine("ddd", path, on_progress=recs.append)
     segs = [e for e in _read_log(path) if e["event"] == "segment"]
     assert res.n_states == N_TOY and segs and len(recs) == len(segs)
-    for key in ("stream_peak", "stream_slabs"):
+    for key in ("stream_peak", "stream_slabs", "probe_tiles"):
         vals = [s[key] for s in segs]
         assert vals == sorted(vals) and vals[-1] > 0
         assert vals == [r[key] for r in recs]

@@ -569,6 +569,61 @@ def test_segment_and_level_spans_carry_the_lane_counters(traced_toy):
 
 
 @pytest.mark.smoke
+def test_segment_spans_carry_the_probe_tiles(traced_toy):
+    """``probe_tiles`` (the filter probe's tiles, SegStats) rides the
+    ``segment`` spans beside ``stream_slabs``; the ``level`` span holds its
+    segments' total and the last segment record the pass's.  A toy chunk
+    is narrower than the shipped tile, so a step takes one tile, or none
+    when no lane of it is live."""
+    _res, evs, _d = traced_toy
+    spans = [e for e in evs if e["event"] == "span"]
+    segs = [s for s in spans if s["name"] == "segment"]
+    assert segs
+    for s in segs:
+        a = s["args"]
+        assert (a["n_valid"] > 0) <= a["probe_tiles"] <= a["steps"]
+    for lv in (s for s in spans if s["name"] == "level"):
+        mine = [s["args"] for s in segs
+                if s["args"]["level"] == lv["args"]["level"]]
+        assert lv["args"]["probe_tiles"] \
+            == sum(a["probe_tiles"] for a in mine)
+    last = [e for e in evs if e["event"] == "segment"][-1]
+    assert last["probe_tiles"] == sum(s["args"]["probe_tiles"]
+                                      for s in segs) > 0
+
+
+@pytest.mark.parametrize("tile", [8, 50])
+def test_probe_tiles_are_the_live_lanes_over_the_tile(
+        tile, tmp_path, monkeypatch):
+    """One chunk step a segment, a tile far under the chunk's lanes: every
+    ``segment`` span's ``probe_tiles`` is ``ceil(n_valid / T)`` of its one
+    step, so the pass's total is that sum — and the verdict is the one
+    the shipped tile gives."""
+    import raft_tla_tpu.ddd_engine as ddd_mod
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
+    monkeypatch.setattr(ddd_mod, "_T_PROBE", tile)
+    eng = DDDEngine(CFG, DDDCapacities(**_TOY_CAPS), seg_chunks=1)
+    eng.SEG_MIN = eng.SEG_MAX = 1          # the pacer may not widen it
+    log = str(tmp_path / "tiles.events")
+    res = eng.check(events=log)
+    assert res.n_states == N_TOY
+    evs = [json.loads(l) for l in open(log)]
+    assert all(validate_event(e) == [] for e in evs)
+    segs = [e["args"] for e in evs
+            if e["event"] == "span" and e["name"] == "segment"]
+    assert segs and all(a["steps"] <= 1 for a in segs)
+    want = [-(-a["n_valid"] // tile) for a in segs]
+    assert [a["probe_tiles"] for a in segs] == want
+    assert max(want) > 1                   # some step took several tiles
+    last = [e for e in evs if e["event"] == "segment"][-1]
+    assert last["probe_tiles"] == sum(want)
+    levels = [e["args"] for e in evs
+              if e["event"] == "span" and e["name"] == "level"]
+    assert sum(a["probe_tiles"] for a in levels) == sum(want)
+
+
+@pytest.mark.smoke
 def test_self_times_over_the_tree_sum_to_the_pass_wall(traced_toy):
     _res, evs, d = traced_toy
     col = obs_collect.collect(obs_collect.find_logs(d))
@@ -597,7 +652,8 @@ def test_trace_report_prints_level_rows_from_the_level_spans(
     assert len(rows) == len(res.levels)
     assert rows[0].startswith("  L1: ") and "1 rows" in rows[0]
     assert all("segments" in r and "steps" in r and "self " in r
-               and " slabs (peak " in r and "most in: " in r
+               and " slabs (peak " in r and " probe tiles, " in r
+               and "most in: " in r
                and " lanes enabled (peak " in r for r in rows)
     assert " self " in text and "in spans)" in text
     rep = obs_collect.report(obs_collect.collect(
