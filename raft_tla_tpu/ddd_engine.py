@@ -1301,13 +1301,15 @@ class DDDEngine:
         tel = RunTelemetry(
             "ddd", config=self.config, caps=self.caps,
             on_progress=on_progress, events=events,
-            resumed=resume is not None, n0=1, t0=t0)
+            resumed=resume is not None, n0=1, t0=t0, level_log=True)
         _cleanup.callback(tel.close)
-        # Span tree (obs/trace; every site is the shared null handle with
-        # tracing off): pass > level > upload / expand / export >
+        # Span tree (obs/trace): pass > level > upload / expand / export >
         # {segment_wait, d2h} / level_close, the flush worker's and the
         # prefetcher's spans on their own threads, one ``segment`` per
-        # harvested segment on the synthetic ``segments`` track.
+        # harvested segment on the synthetic ``segments`` track.  With
+        # tracing off nothing is emitted (``tr.enabled`` is False) and the
+        # sites the pass ledger reads (obs/passlog) are timed for it alone;
+        # every other site is the shared null handle.
         tr = tel.trace
         pass_sp = tr.open("pass", engine="ddd", resumed=resume is not None,
                           prescan=self._prescan)
@@ -1325,7 +1327,8 @@ class DDDEngine:
                     n_states=1, diameter=0, n_transitions=0,
                     coverage=Counter(),
                     violation=Violation(nm, init_py, [(None, init_py)]),
-                    levels=[1], wall_s=time.monotonic() - t0)
+                    levels=[1], wall_s=time.monotonic() - t0,
+                    level_log=tel.passlog.record)
                 pass_sp.set(levels=1, n_states=1,
                             stopped_by="violation").close()
                 tel.run_end(res)
@@ -1962,14 +1965,15 @@ class DDDEngine:
             host.close()
             constore.close()
             keystore.close()
+        pass_sp.set(levels=len(levels_arr), n_states=n_states,
+                    stopped_by="violation" if violation is not None
+                    else stopped_by).close()
         result = EngineResult(
             n_states=n_states, diameter=len(levels_arr) - 1,
             n_transitions=n_trans, coverage=coverage,
             violation=violation, levels=levels_arr,
-            wall_s=time.monotonic() - t0, complete=complete)
-        pass_sp.set(levels=len(levels_arr), n_states=n_states,
-                    stopped_by="violation" if violation is not None
-                    else stopped_by).close()
+            wall_s=time.monotonic() - t0, complete=complete,
+            level_log=tel.passlog.record)
         tel.run_end(result)
         _cleanup.close()
         return result

@@ -72,6 +72,12 @@ class EngineResult:
     # deadline_s — the bench's time-boxed north-star workload); every
     # exhaustive verdict above requires complete=True.
     complete: bool = True
+    # the pass ledger's record of this check() (obs/passlog.py: one entry a
+    # level, the head, the tail, the workers' seams), shared with the
+    # ledger: read it, do not change it.  The ddd engines fill it; the
+    # engines that keep no ledger leave it None.
+    level_log: Optional[dict] = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     @property
     def states_per_sec(self) -> float:

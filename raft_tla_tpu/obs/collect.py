@@ -139,7 +139,7 @@ def collect(paths: list) -> dict:
     Returns::
 
         {"processes": [{"pid", "os_pid", "label", "log", "engine",
-                        "anchored", "skew_bound_s",
+                        "anchored", "skew_bound_s", "level_log",
                         "threads": [...]}, ...],
          "spans":     [{"pid", "thread", "name", "ts", "dur",
                         "span_id", "parent_id", "args"}, ...],
@@ -190,6 +190,12 @@ def collect(paths: list) -> dict:
                 "anchored": anchor is not None,
                 "skew_bound_s": (float(anchor["err_s"])
                                  if anchor else None),
+                # the pass ledger's record (run_end.level_log, v14): what
+                # the report prints of a ddd log that holds no span
+                "level_log": next(
+                    (e["level_log"] for e in reversed(events)
+                     if e["event"] == "run_end"
+                     and isinstance(e.get("level_log"), dict)), None),
                 "threads": []}
         processes.append(proc)
         threads = proc["threads"]
@@ -375,6 +381,7 @@ def report(col: dict) -> dict:
             "threads": {name: _thread_report(tspans, trees[name])
                         for name, tspans in sorted(threads.items())},
             "levels": _level_rows(threads, trees),
+            "level_log": None if threads else proc.get("level_log"),
         })
     return {"processes": procs,
             "t_min": col["t_min"], "t_max": col["t_max"],
@@ -425,4 +432,31 @@ def render_report(rep: dict) -> str:
                    if lv["probe_tiles"] is not None else "")
                 + f"+{lv['new_states']} states, "
                 f"self {lv['self_s']:.3f}s, most in: {dom}")
+        if proc.get("level_log"):
+            lines.extend(_render_level_log(proc["level_log"]))
     return "\n".join(lines)
+
+
+def _render_level_log(rec: dict) -> list:
+    """The level table of an untraced ddd run, from the pass ledger's
+    record in its ``run_end`` (obs/passlog.py): one row a level with its
+    wall, counts, the main thread's seams, its CPU time and the three
+    usual suspects of a stall; then the stalls the ledger itself named."""
+    from raft_tla_tpu.obs.passlog import SEAM_FIELDS, stall_line
+    lines = [f"  pass ledger (no spans in this log): {rec['engine']} "
+             f"wall {rec['wall_s']:.3f}s, head {rec['head_s']:.3f}s, "
+             f"tail {rec['tail_s']:.3f}s, stopped by {rec['stopped_by']}"]
+    for lv in rec["levels"]:
+        seams = " ".join(f"{key[:-2]} {lv[key]:.4f}" for key in SEAM_FIELDS)
+        lines.append(
+            f"  L{lv['level']}: {lv['wall_s']:.3f}s "
+            f"(+{lv['gap_s']:.4f} before), {lv['rows']} rows, "
+            f"{lv['segments']} segments, {lv['steps']} steps, "
+            f"{lv['streamed_rows']} streamed, +{lv['new_states']} states; "
+            f"{seams} ({lv['uploads']} uploads); cpu {lv['cpu_s']:.4f} "
+            f"gc {lv['gc_s']:.4f} majflt {lv['majflt']} "
+            f"nivcsw {lv['nivcsw']}")
+    for key, secs in sorted(rec["threads"].items()):
+        lines.append(f"  {key}: {secs:.3f}s")
+    lines.extend("  " + stall_line(rec, st) for st in rec["stalls"])
+    return lines

@@ -25,7 +25,7 @@ wall).  This module replaces all of that with:
   ``level_end`` derived automatically from level transitions and
   ``violation`` derived from the final :class:`~raft_tla_tpu.engine.EngineResult`.
 
-Event grammar (``SCHEMA_VERSION`` = 13; earlier-version lines remain
+Event grammar (``SCHEMA_VERSION`` = 14; earlier-version lines remain
 valid) —
 every line is one JSON object with base fields ``v`` (schema version),
 ``event`` (type) and ``ts`` (unix epoch seconds):
@@ -177,13 +177,21 @@ lanes / _T_PROBE)``, so tiles over steps is the sizing signal for the
 tile).  The ``segment`` and ``level`` spans carry the per-segment and
 per-level counts.
 
+Version 14 adds the pass ledger's record (obs/passlog.py, always on in
+the ddd engines): ``run_end.level_log`` — the pass's own account, one
+entry a level (wall, the gap since the level before, the main thread's
+seams, its CPU time, the collector's, page faults and involuntary
+switches), with the head before the first level, the tail after the last,
+the workers' seams by thread and the stalls the ledger named; in the log of every ddd run, traced or not, and absent from the
+engines that keep no ledger.  Seconds rounded to the microsecond.
+
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the
-v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12/v13-only
+v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12/v13/v14-only
 fields) are invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` /
 ``"v" < 10`` (resp. ``"v" < 3`` / ``"v" < 4`` / ``"v" < 5`` /
 ``"v" < 6`` / ``"v" < 8`` / ``"v" < 9`` / ``"v" < 11`` / ``"v" < 12`` /
-``"v" < 13``) line, so any addition requires
+``"v" < 13`` / ``"v" < 14``) line, so any addition requires
 a version bump (versioning policy in README.md).
 """
 
@@ -193,11 +201,10 @@ import dataclasses
 import json
 import os
 import queue
-import subprocess
 import threading
 import time
 
-SCHEMA_VERSION = 13
+SCHEMA_VERSION = 14
 _VERSIONS = tuple(range(1, SCHEMA_VERSION + 1))  # validate_event accepts
 
 # Environment knobs (set by check.py --events/--phase-timers; inherited by
@@ -324,10 +331,15 @@ _V12_FIELDS = {"segment": frozenset({"stream_peak", "stream_slabs"})}
 # the ddd filter probe) — invalid on a "v" < 13 line.
 _V13_FIELDS = {"segment": frozenset({"probe_tiles"})}
 
+# Fields that only exist from schema version 14 on (the pass ledger's
+# record of the run) — invalid on a "v" < 14 line.
+_V14_FIELDS = {"run_end": frozenset({"level_log"})}
+
 # schema version -> the fields that exist only from it on, by event
 _FIELDS_SINCE = {3: _V3_FIELDS, 4: _V4_FIELDS, 5: _V5_FIELDS,
                  6: _V6_FIELDS, 8: _V8_FIELDS, 9: _V9_FIELDS,
-                 11: _V11_FIELDS, 12: _V12_FIELDS, 13: _V13_FIELDS}
+                 11: _V11_FIELDS, 12: _V12_FIELDS, 13: _V13_FIELDS,
+                 14: _V14_FIELDS}
 
 _OPTIONAL = {
     "run_start": {"bounds": dict, "symmetry": list, "view": str,
@@ -346,7 +358,7 @@ _OPTIONAL = {
     "violation": {"kind": str},
     "stop_requested": {"source": str, "pid": int},
     "run_end": {"diameter": int, "levels": list, "wall_s": _NUM,
-                "sim": dict, "compiles": dict},
+                "sim": dict, "compiles": dict, "level_log": dict},
     "preempt": {"detail": str, "pid": int, "stale_s": _NUM,
                 "drift": dict},
     "reshard": {"n_states": int, "path": str, "block": int},
@@ -632,21 +644,45 @@ def append_event(log_path: str, event: str, **fields) -> dict:
 
 
 _GIT_SHA_CACHE: list = []
+_HEX = frozenset("0123456789abcdef")
+
+
+def _read_head(root: str) -> str | None:
+    """The commit ``HEAD`` names in the checkout at ``root``, from the files
+    of its ``.git`` directory: ``HEAD``, then the loose ref or
+    ``packed-refs``.  Anything else (a worktree's ``.git`` file, no
+    checkout) raises ``OSError`` or gives None: no sha."""
+    git = os.path.join(root, ".git")
+    with open(os.path.join(git, "HEAD"), encoding="utf-8") as f:
+        head = f.read().strip()
+    if not head.startswith("ref:"):
+        return head
+    ref = head[4:].strip()
+    if os.path.isfile(os.path.join(git, ref)):
+        with open(os.path.join(git, ref), encoding="utf-8") as f:
+            return f.read().strip()
+    with open(os.path.join(git, "packed-refs"), encoding="utf-8") as f:
+        for line in f:
+            sha, _, name = line.strip().partition(" ")
+            if name == ref:
+                return sha
+    return None
 
 
 def git_sha() -> str | None:
-    """Short commit sha of the checkout (best-effort, cached)."""
+    """Short commit sha of the checkout (best-effort, cached).  Read from
+    ``.git``'s own files, never through a child process: the first logged
+    run of a process stamps it inside ``run_start``, on the path a pass is
+    clocked over, from a process that holds the device and gigabytes of
+    resident memory (PR 38)."""
     if not _GIT_SHA_CACHE:
         try:
-            out = subprocess.run(
-                ["git", "rev-parse", "--short=12", "HEAD"],
-                cwd=os.path.dirname(os.path.dirname(
-                    os.path.dirname(os.path.abspath(__file__)))),
-                capture_output=True, text=True, timeout=5)
-            sha = out.stdout.strip() if out.returncode == 0 else ""
-            _GIT_SHA_CACHE.append(sha or None)
-        except Exception:
-            _GIT_SHA_CACHE.append(None)
+            sha = _read_head(os.path.dirname(os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__))))) or ""
+        except (OSError, UnicodeDecodeError):
+            sha = ""
+        ok = len(sha) >= 12 and set(sha) <= _HEX
+        _GIT_SHA_CACHE.append(sha[:12] if ok else None)
     return _GIT_SHA_CACHE[0]
 
 
@@ -672,8 +708,10 @@ class RunTelemetry:
     def __init__(self, engine: str, config=None, caps=None,
                  on_progress=None, events: str | None = None,
                  resumed: bool = False, n0: int | None = 1,
-                 n_devices: int | None = None, t0: float | None = None):
+                 n_devices: int | None = None, t0: float | None = None,
+                 level_log: bool = False):
         from raft_tla_tpu.obs import compiles
+        from raft_tla_tpu.obs.passlog import PassLog
         from raft_tla_tpu.obs.phases import PhaseTimers
         from raft_tla_tpu.obs.trace import (NULL_TRACER, SpanTracer,
                                             trace_enabled)
@@ -682,12 +720,20 @@ class RunTelemetry:
         self.caps = caps
         self.on_progress = on_progress
         self.resumed = resumed
+        if t0 is None:
+            t0 = time.monotonic()
         path = events_path(events)
         self.log = EventLog(path) if path else None
-        # Spans need a sink: tracing stays NULL (the off path) without a
-        # log even when the gate is on, preserving `active`'s contract.
-        self.trace = (SpanTracer(self.log.emit)
-                      if self.log is not None and trace_enabled()
+        # ``level_log`` (the ddd engines): the pass keeps its account in
+        # the pass ledger, traced or not, through the tracer's sites
+        self.passlog = PassLog(engine, resumed, t0) if level_log else None
+        # Span events need a log: without one they stay off even when the
+        # gate is on, preserving `active`'s contract; a tracer that only
+        # feeds the pass ledger emits nothing (`trace.enabled` is False).
+        emit = (self.log.emit
+                if self.log is not None and trace_enabled() else None)
+        self.trace = (SpanTracer(emit, sink=self.passlog)
+                      if emit is not None or self.passlog is not None
                       else NULL_TRACER)
         if self.trace.enabled:
             self._annotate_spans()
@@ -699,8 +745,8 @@ class RunTelemetry:
         self.phases.tracer = self.trace
         inv = tuple(config.invariants) if config is not None else ()
         self.tracker = ProgressTracker(
-            t0 if t0 is not None else time.monotonic(),
-            n0=n0, invariants=inv, resumed=resumed, n_devices=n_devices)
+            t0, n0=n0, invariants=inv, resumed=resumed,
+            n_devices=n_devices)
         self._n_devices = n_devices
         self._last_level: int | None = None
         self._ended = False
@@ -843,7 +889,16 @@ class RunTelemetry:
             n_transitions=int(result.n_transitions),
             complete=bool(result.complete), outcome=outcome,
             diameter=int(result.diameter), levels=list(result.levels),
-            wall_s=round(float(result.wall_s), 3), **self._compiles())
+            wall_s=round(float(result.wall_s), 3), **self._compiles(),
+            **self._level_log(result))
+
+    @staticmethod
+    def _level_log(result) -> dict:
+        """``run_end.level_log``: the pass ledger's record of the run,
+        where the engine keeps one (``EngineResult.level_log``)."""
+        from raft_tla_tpu.obs import passlog
+        rec = getattr(result, "level_log", None)
+        return {} if rec is None else {"level_log": passlog.rounded(rec)}
 
     def _compiles(self) -> dict:
         """``run_end.compiles``: the ledger's totals over this run, in a

@@ -55,7 +55,12 @@ named region lands in both the per-segment ``phase_s`` aggregate and the
 merged trace timeline.  With tracing on but timers off the handle skips
 ``sync`` (no ``block_until_ready``), so spans record honest *host-side*
 walls — dispatch time, not device time — and the engine pipelining the
-timers would serialise stays intact.
+timers would serialise stays intact.  The same holds for a tracer that
+emits nothing and only feeds the ddd engines' pass ledger
+(obs/passlog.py, always on): the phases the ledger reads (``upload``,
+``expand``, ``dedup*``, a worker's ``prefetch``) are live without
+``RAFT_TLA_PHASE_TIMERS`` and without a sync, every other phase stays the
+null handle.
 
 This module is host-path orchestration only — nothing here runs under
 jit (the no-op handle is what jit-adjacent code touches).
@@ -104,7 +109,7 @@ class _Phase:
 
     def __enter__(self):
         tr = self._timers.tracer
-        if tr is not None and tr.enabled:
+        if tr is not None and tr.wants(self._name):
             self._span = tr.span(self._name).__enter__()
         self._t0 = time.monotonic()
         return self
@@ -148,7 +153,8 @@ class PhaseTimers:
 
     ``tracer`` (attached by ``RunTelemetry``) piggybacks v8 trace spans
     on the same phase sites: the handle is live when *either* layer is
-    on, but syncs (and accumulates) only when the timers are.
+    on (or the tracer's sink reads the phase), but syncs (and
+    accumulates) only when the timers are.
     """
 
     def __init__(self, enabled: bool = False):
@@ -166,7 +172,7 @@ class PhaseTimers:
     def phase(self, name: str):
         if not self.enabled:
             tr = self.tracer
-            if tr is None or not tr.enabled:
+            if tr is None or not tr.wants(name):
                 return _NULL
         return _Phase(self, name)
 

@@ -17,6 +17,12 @@ Design points, in the order they matter:
   the same discipline as ``PhaseTimers``'s null handle (A/B'd by the
   ``runs/obs_overhead_ab.py`` protocol; ``bench.py`` pins the per-call
   cost as the ``trace_emit_overhead_us`` fiducial).
+- **One set of sites, two sinks.**  A tracer may also carry a ``sink``
+  (the ddd engines' pass ledger, obs/passlog.py): the spans whose names
+  the sink reads are then timed whether events are emitted or not, and
+  handed to it with the very ``t0`` and ``dur`` an emitted span carries.
+  ``enabled`` still says only whether ``span`` events are emitted;
+  :meth:`SpanTracer.wants` says whether a site's span goes anywhere.
 - **Monotonic timestamps + a wall anchor.**  Span ``t0`` is
   ``time.monotonic()`` in the emitting process (immune to NTP steps
   mid-run); each process stamps one wall/monotonic :func:`clock_anchor`
@@ -127,6 +133,10 @@ class NullTracer:
 
     __slots__ = ()
     enabled = False
+    sink = None
+
+    def wants(self, name: str) -> bool:
+        return False
 
     def span(self, name: str, **args):
         return _NULL_SPAN
@@ -164,6 +174,10 @@ class _Span:
 
     def __enter__(self):
         tr = self._tr
+        self._ann = None
+        if not tr.enabled:               # timed for the sink alone: no
+            self._t0 = time.monotonic()  # id, no parent, no annotation
+            return self
         stack = tr._stack()
         self._parent = stack[-1] if stack else None
         self._id = next(tr._ids)
@@ -172,9 +186,8 @@ class _Span:
         self._t0 = time.monotonic()
         # the profiler's own clock: the same region as a TraceMe in any
         # capture that is running (a flag test when none is)
-        self._ann = ann(self._name, span_id=self._id) \
-            if ann is not None else None
-        if self._ann is not None:
+        if ann is not None:
+            self._ann = ann(self._name, span_id=self._id)
             self._ann.__enter__()
         return self
 
@@ -194,19 +207,23 @@ class _Span:
             return False
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        dur = time.monotonic() - self._t0
-        stack = self._tr._stack()
-        if stack and stack[-1] == self._id:
-            stack.pop()
-        fields = {"name": self._name, "span_id": self._id,
-                  "t0": round(self._t0, 6), "dur": round(dur, 6),
-                  "thread": threading.current_thread().name}
-        if self._parent is not None:
-            fields["parent_id"] = self._parent
-        if self._args:
-            fields["args"] = self._args
-        self._t0 = None
-        self._tr._emit("span", **fields)
+        t0, self._t0 = self._t0, None
+        dur = time.monotonic() - t0
+        tr = self._tr
+        if tr.feeds(self._name):
+            tr.sink.closed(self._name, t0, dur, self._args)
+        if tr.enabled:
+            stack = tr._stack()
+            if stack and stack[-1] == self._id:
+                stack.pop()
+            fields = {"name": self._name, "span_id": self._id,
+                      "t0": round(t0, 6), "dur": round(dur, 6),
+                      "thread": threading.current_thread().name}
+            if self._parent is not None:
+                fields["parent_id"] = self._parent
+            if self._args:
+                fields["args"] = self._args
+            tr._emit("span", **fields)
         return False
 
 
@@ -225,12 +242,18 @@ class SpanTracer:
     annotation named like it, with its ``span_id``: a ``jax.profiler``
     capture then holds the program's spans of every thread on the
     profiler's own clock, beside the device ops.
+
+    ``sink`` (an ``obs.passlog.PassLog``) is told of every span whose
+    name is in its ``NAMES``: ``opened(name, t0)`` for the explicit
+    handles of :meth:`open`, ``closed(name, t0, dur, args)`` for all.
+    ``emit`` may then be ``None``: the tracer times those spans for the
+    sink alone, emits nothing, and every other site gets the null span.
     """
 
-    enabled = True
-
-    def __init__(self, emit, annotate=None):
+    def __init__(self, emit, annotate=None, sink=None):
         self._emit = emit
+        self.enabled = emit is not None  # ``span`` events are emitted
+        self.sink = sink
         self.annotate = annotate         # set once, before any worker runs
         self._ids = itertools.count(1)   # CPython-atomic __next__
         self._tls = threading.local()
@@ -241,14 +264,28 @@ class SpanTracer:
             st = self._tls.stack = []
         return st
 
-    def span(self, name: str, **args) -> _Span:
-        """Context manager for a region on the current thread."""
-        return _Span(self, name, args)
+    def feeds(self, name: str) -> bool:
+        """Whether the sink reads spans of this name."""
+        return self.sink is not None and name in self.sink.NAMES
 
-    def open(self, name: str, **args) -> _Span:
+    def wants(self, name: str) -> bool:
+        """Whether a span of this name goes anywhere: to the event log,
+        or to a sink that reads the name."""
+        return self.enabled or self.feeds(name)
+
+    def span(self, name: str, **args):
+        """Context manager for a region on the current thread."""
+        return _Span(self, name, args) if self.wants(name) else _NULL_SPAN
+
+    def open(self, name: str, **args):
         """The same region as an explicit handle, already entered: pair
         with ``handle.close()`` on the same thread."""
-        return _Span(self, name, args).__enter__()
+        if not self.wants(name):
+            return _NULL_SPAN
+        sp = _Span(self, name, args).__enter__()
+        if self.feeds(name):
+            self.sink.opened(name, sp._t0)
+        return sp
 
     def emit_span(self, name: str, t0: float, dur: float,
                   thread: str | None = None, **args) -> None:
@@ -257,6 +294,8 @@ class SpanTracer:
         names the track — pass a synthetic one (``"tickets"``) when the
         span overlaps the emitting thread's nested spans, so renderers
         that require proper nesting per track stay happy."""
+        if not self.enabled:
+            return
         fields = {"name": name, "span_id": next(self._ids),
                   "t0": round(t0, 6), "dur": round(max(0.0, dur), 6),
                   "thread": thread or threading.current_thread().name}

@@ -754,11 +754,12 @@ class DDDShardEngine:
             "ddd-shard", config=self.config, caps=self.caps,
             on_progress=on_progress, events=events,
             resumed=resume is not None, n0=1,
-            n_devices=self.ndev, t0=t0)
+            n_devices=self.ndev, t0=t0, level_log=True)
         _cleanup.callback(tel.close)
         # the ddd engine's span tree, where this loop has the same seams
         # (host side only): pass > level > upload / expand / export >
-        # {segment_wait, d2h} / level_close
+        # {segment_wait, d2h} / level_close; traced or not, the same sites
+        # feed the pass ledger (obs/passlog)
         tr = tel.trace
         pass_sp = tr.open("pass", engine="ddd-shard",
                           resumed=resume is not None,
@@ -777,7 +778,8 @@ class DDDShardEngine:
                     n_states=1, diameter=0, n_transitions=0,
                     coverage=Counter(),
                     violation=Violation(nm, init_py, [(None, init_py)]),
-                    levels=[1], wall_s=time.monotonic() - t0)
+                    levels=[1], wall_s=time.monotonic() - t0,
+                    level_log=tel.passlog.record)
                 pass_sp.set(levels=1, n_states=1,
                             stopped_by="violation").close()
                 tel.run_end(res)
@@ -926,10 +928,11 @@ class DDDShardEngine:
                 export_rows=export_rows,
                 dev_dedup_hits=dd_hits if self._dd_apply else None)
 
-        lvl_segs = lvl_steps = 0             # the open level's work
+        lvl_segs = lvl_steps = lvl_rows = 0  # the open level's work
 
         def end_level():
             level_sp.set(segments=lvl_segs, steps=lvl_steps,
+                         streamed_rows=lvl_rows,
                          new_states=n_states - lvl_hi).close()
 
         while not stopped:
@@ -941,7 +944,7 @@ class DDDShardEngine:
             level_sp = tr.open("level", level=len(level_ends),
                                rows=lvl_hi - lvl_lo,
                                blocks=-(-(lvl_hi - w0) // W))
-            lvl_segs = lvl_steps = 0
+            lvl_segs = lvl_steps = lvl_rows = 0
             if prefetcher is not None and w0 < lvl_hi:
                 # level start: all window addresses are known — warm the
                 # first window immediately
@@ -1044,6 +1047,7 @@ class DDDShardEngine:
                         pend[s]["con"].append(
                             bufs_h.ocon[o:o + ns].copy())
                     n_trans += int(np.asarray(st_h.n_valid).sum())
+                    lvl_rows += int(cursors.sum())
                     export_rows += int(cursors.sum())
                     if dhits is not None:
                         dd_hits += int(np.asarray(
@@ -1255,14 +1259,15 @@ class DDDShardEngine:
         host.close()
         constore.close()
         keystore.close()
+        pass_sp.set(levels=len(levels_arr), n_states=n_states,
+                    stopped_by="violation" if violation is not None
+                    else None if complete else "sigint").close()
         result = EngineResult(
             n_states=n_states, diameter=len(levels_arr) - 1,
             n_transitions=n_trans, coverage=coverage,
             violation=violation, levels=levels_arr,
-            wall_s=time.monotonic() - t0, complete=complete)
-        pass_sp.set(levels=len(levels_arr), n_states=n_states,
-                    stopped_by="violation" if violation is not None
-                    else None if complete else "sigint").close()
+            wall_s=time.monotonic() - t0, complete=complete,
+            level_log=tel.passlog.record)
         tel.run_end(result)
         return result
 

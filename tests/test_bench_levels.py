@@ -1,0 +1,226 @@
+"""The benchmark's readers of the program's pass ledger (PR 38):
+``benchmark/harness/levelred.py`` and the five ``benchmark/metrics/<name>.py``
+behind it, on a small recorded shape (``benchmark/testdata/
+passlog_small.json``: a warm pass, passes 1, 2 (traced), 3, and pass 4 with a
+2.0 s stall planted in level 7, blocked in ``upload``).  Every value below is
+computed by hand from that file's numbers.
+
+No chip here: nothing in this file is a measurement, only the arithmetic that
+turns a ledger into numbers.
+"""
+
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.harness import levelred, passes  # noqa: E402
+from benchmark.harness import manifest as mf  # noqa: E402
+
+DATA = os.path.join(ROOT, "benchmark", "testdata", "passlog_small.json")
+METRICS = ("level_host_ms", "level_cpu_share_pct", "host_exposed_s",
+           "upload_untraced_ms", "stall_s")
+SIX = ["elect5.passes", "flagship3.passes", "full5.passes", "repl3.passes",
+       "flagship3_m1.verdict", "elect5.shard4"]
+
+# by hand, from the file.  Ramp levels 1..4 of the three untraced passes:
+# wall 40 / 42 / 44 / 46 ms less 10 ms of wait each = 30 / 32 / 34 / 36 ms of
+# host, three times over: the median of the twelve is 33.  Their cpu_s is
+# 16.5 ms each: 12 x 16.5 = 198 ms over 3 x 132 = 396 ms of host = 50 %.
+# A pass: head 10 ms + levels (172 + 5 x 100 ms) + tail 5 ms = 0.687 s, of
+# which 4 x 10 + 5 x 60 = 340 ms are wait: 0.347 s exposed; the stalled pass
+# 2.347 s; the median of (0.347, 0.347, 2.347) is 0.347.  Levels 6..8 hold
+# 1 + 1 + 2 uploads of 20 ms each, and the stall adds 2.0 s to one of them:
+# (3 x 80 ms + 2.0 s) / 12 uploads = 186.67 ms.  Level 7 reads 0.1, 0.1 and
+# 2.1 s: low median 0.1, excess 2.0 > max(0.25, 0.1).
+BY_HAND = {"level_host_ms": 33.0, "level_cpu_share_pct": 50.0,
+           "host_exposed_s": 0.347, "upload_untraced_ms": 2240.0 / 12,
+           "stall_s": 2.0}
+
+
+@pytest.fixture()
+def recorded():
+    with open(DATA, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def _evidence(recorded, monkeypatch, snapshot=None):
+    """What ``run.execute`` hands a reader, as far as these look, with the
+    program's ``passlog.snapshot`` standing on the recorded one."""
+    from raft_tla_tpu.obs import passlog
+    monkeypatch.setattr(passlog, "snapshot",
+                        lambda: snapshot or recorded["snapshot"])
+    made = [passes.Pass(index=k + 1, t_call=p["t_call"],
+                        t_return=p["t_return"], traced=p["traced"],
+                        problem=p["problem"])
+            for k, p in enumerate(recorded["passes"])]
+    return {"passes": made, "span_levels": recorded["span_levels"]}
+
+
+@pytest.mark.parametrize("name", METRICS)
+def test_reader_gives_the_value_computed_by_hand(recorded, monkeypatch,
+                                                 capsys, name):
+    ev = _evidence(recorded, monkeypatch)
+    assert mf.metric_reader(name)(ev) == pytest.approx(BY_HAND[name])
+    red = ev["levelred"]
+    assert (red["passes"], red["ramp_levels"], red["dropped"]) == (3, 12, 0)
+    # the untraced level table of the run's log: medians by seam, in ms
+    assert red["ramp_by_seam_ms"] == pytest.approx(
+        {"wall": 43.0, "upload": 20.0, "expand": 2.0, "wait": 10.0,
+         "d2h": 1.0, "dedup": 3.0, "close": 4.0, "cpu": 16.5})
+    assert red["span_by_seam_ms"]["wall"] == pytest.approx(100.0)
+    assert red["span_by_seam_ms"]["wait"] == pytest.approx(60.0)
+    # once a run: a second reader prints nothing more
+    first = capsys.readouterr().out
+    assert first.count("pass ledger, untraced passes: ") == 1
+    mf.metric_reader("stall_s")(ev)
+    assert capsys.readouterr().out == ""
+
+
+def test_the_stall_gets_its_line_with_every_seam(recorded, monkeypatch,
+                                                 capsys):
+    ev = _evidence(recorded, monkeypatch)
+    assert mf.metric_reader("stall_s")(ev) == pytest.approx(2.0)
+    out = capsys.readouterr().out.splitlines()
+    stalls = [line for line in out if line.startswith("stall ")]
+    assert len(stalls) == 1 and stalls == ev["levelred"]["stalls"]
+    line = stalls[0]
+    # pass 4 of the window (as run.py numbers its ``pass N`` lines)
+    assert line.startswith("stall pass 4 level 7: wall 2.100000s against "
+                           "the run's median 0.100000s (+2.000000s): ")
+    assert "upload_s 2.020000" in line and "cpu_s 0.031000" in line
+    for key in ("expand_s", "wait_s", "d2h_s", "dedup_s", "close_s", "gc_s",
+                "uploads", "majflt", "nivcsw 1"):
+        assert key in line
+    # ... and the traced pass's extra head is found where it was planted
+    cost = json.loads(next(
+        line for line in out if line.startswith("tracing's own cost")
+    ).split(": ", 1)[1])
+    assert cost["head_s"] == pytest.approx(0.05)
+    assert cost["pre_s"] == pytest.approx(0.001)
+    assert cost["ramp_wall_s"] == pytest.approx(0.0)
+    assert cost["level_excess_max_s"] == pytest.approx(0.0)
+
+
+def test_stalls_of_head_tail_and_of_a_pair_of_passes(recorded):
+    recs = recorded["snapshot"]["records"]
+    slow_head = {**recs[1], "head_s": 1.010}
+    found = levelred.stalls([(1, slow_head), (2, recs[3])])
+    # two passes: the low median is the faster one, so a pair can tell
+    assert [(s["pass"], s["level"]) for s in found] == [(1, "head")]
+    assert found[0]["excess_s"] == pytest.approx(1.0)
+    assert levelred.stall_line(found[0]) == (
+        "stall pass 1 head: wall 1.010000s against the run's median "
+        "0.010000s (+1.000000s)")
+    # under the floor of 0.25 s nothing is a stall, whatever the ratio
+    quick = {**recs[1], "tail_s": 0.205}
+    assert levelred.stalls([(1, quick), (2, recs[3])]) == []
+    assert levelred.reduce([(1, recs[1]), (2, recs[3])], 5, 8)["stall_s"] \
+        == 0.0
+
+
+def test_the_programs_own_rule_names_the_same_level(recorded):
+    """The ledger holds each pass against the ones before it as it closes
+    (``record["stalls"]``, one line on stderr: the only witness in a run
+    the benchmark does not trace); on this shape it finds what the reader
+    finds over the whole run, and the file holds what it found."""
+    from raft_tla_tpu.obs import passlog
+    recs = recorded["snapshot"]["records"]
+    for k, rec in enumerate(recs):
+        assert passlog._stalls(rec, recs[:k]) == rec["stalls"]
+    assert [len(r["stalls"]) for r in recs] == [0, 0, 0, 0, 1]
+    st = recs[4]["stalls"][0]
+    assert (st["level"], st["wall_s"], st["median_s"], st["passes"]) == \
+        (7, 2.1, 0.1, 3)
+    assert st["upload_s"] == pytest.approx(2.02)
+    assert passlog.stall_line(recs[4], st).startswith(
+        "raft-tla pass ledger: stall in the ddd pass at t0=130.001, level "
+        "7: wall 2.100s against a median of 0.100s over the last 3 passes: "
+        "upload_s 2.020 ")
+
+
+def test_failed_and_traced_passes_are_left_out(recorded, monkeypatch):
+    ev = _evidence(recorded, monkeypatch)
+    ev["passes"][3].problem = "level table differs from the pins"
+    assert mf.metric_reader("stall_s")(ev) == 0.0
+    assert ev["levelred"]["passes"] == 2
+    assert mf.metric_reader("upload_untraced_ms")(ev) == pytest.approx(20.0)
+    assert mf.metric_reader("host_exposed_s")(ev) == pytest.approx(0.347)
+
+
+def test_a_resumed_pass_has_no_ramp_and_still_reads_the_rest(recorded):
+    recs = recorded["snapshot"]["records"]
+    red = levelred.reduce([(1, recs[1])], 0, 8)
+    assert red["level_host_ms"] is None and red["ramp_by_seam_ms"] is None
+    assert red["level_cpu_share_pct"] is None
+    assert red["upload_untraced_ms"] == pytest.approx(20.0)
+    assert red["host_exposed_s"] == pytest.approx(0.347)
+
+
+@pytest.mark.parametrize("name", METRICS)
+def test_without_records_every_reader_gives_none(recorded, monkeypatch,
+                                                 capsys, name):
+    ev = _evidence(recorded, monkeypatch,
+                   snapshot={"records": [], "dropped": 0})
+    assert mf.metric_reader(name)(ev) is None
+    assert capsys.readouterr().out == ""
+
+
+@pytest.mark.parametrize("name", METRICS)
+def test_a_ring_that_dropped_a_timed_pass_gives_none_and_says_so(
+        recorded, monkeypatch, capsys, name):
+    """The ring keeps 64 passes and the checks after the window make more:
+    once a sound untraced pass of the run has no record left, no reader
+    reduces over the rest."""
+    snap = {"records": recorded["snapshot"]["records"][2:], "dropped": 2}
+    ev = _evidence(recorded, monkeypatch, snapshot=snap)
+    assert mf.metric_reader(name)(ev) is None
+    out = capsys.readouterr().out
+    assert out == ("PASS LEDGER: 1 sound untraced passes of this run have "
+                   "no record (the ledger dropped 2): no reading from it\n")
+    assert mf.metric_reader("stall_s")(ev) is None        # said once
+    assert capsys.readouterr().out == ""
+
+
+def test_a_program_without_the_ledger_gives_none(recorded, monkeypatch):
+    """The parent commit has no ``obs/passlog``: the import fails, the
+    reduction is ``None`` and nothing raises."""
+    import raft_tla_tpu.obs
+    ev = _evidence(recorded, monkeypatch)
+    monkeypatch.delattr(raft_tla_tpu.obs, "passlog")
+    monkeypatch.setitem(sys.modules, "raft_tla_tpu.obs.passlog", None)
+    for name in METRICS:
+        assert mf.metric_reader(name)(ev) is None
+    assert ev["levelred"] is None
+
+
+def test_manifest_gains_the_five_readers_at_the_end_and_nothing_else():
+    manifest = mf.load()
+    assert mf.problems(manifest) == []
+    # the seven cells PR 38 found; it adds none
+    cells = [w["name"] for w in manifest["workloads"]][:7]
+    names = [m["name"] for m in manifest["per_layer"]]
+    # the last five of the list as PR 38 leaves it, after PR 35's 51 (later
+    # PRs append their own after these)
+    assert [names.index(n) for n in METRICS] == list(range(51, 56))
+    last = manifest["per_layer"][51:56]
+    for m in last:
+        assert (m["source"], m["moves"]) == ("program_counter",
+                                             "orbits_per_s")
+        assert set(m) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert set(m["workloads"][:7]) <= set(cells)
+    by = {m["name"]: m for m in last}
+    assert by["level_host_ms"]["workloads"][:6] == SIX
+    assert by["level_cpu_share_pct"]["workloads"][:6] == SIX
+    for name in ("host_exposed_s", "upload_untraced_ms", "stall_s"):
+        assert by[name]["workloads"][:7] == cells
+    assert by["upload_untraced_ms"]["layer"] == "store read and h2d upload"
+    assert {by[n]["layer"] for n in METRICS if n != "upload_untraced_ms"} \
+        == {"level loop"}
+    assert [by[n]["unit"] for n in METRICS] == ["ms", "%", "s", "ms", "s"]

@@ -150,7 +150,8 @@ def test_validate_rejects_unknowns_and_type_drift():
     assert validate_event({**ok, "v": 11}) == []            # v11 superset
     assert validate_event({**ok, "v": 12}) == []            # v12 superset
     assert validate_event({**ok, "v": 13}) == []            # v13 superset
-    assert validate_event({**ok, "v": 14})                  # future version
+    assert validate_event({**ok, "v": 14}) == []            # v14 superset
+    assert validate_event({**ok, "v": 15})                  # future version
     assert validate_event({"v": 1, "event": "level_end", "ts": 0.0,
                            "level": 3})                     # missing field
 
@@ -369,6 +370,69 @@ def test_validate_v13_segment_probe_tiles():
     assert len(errs) == 1 and "requires schema version >= 13" in errs[0]
     assert validate_event({**seg, "probe_tiles": 5.5})     # type drift
     assert json.loads(json.dumps(seg)) == seg              # round trip
+
+
+def test_validate_v14_run_end_level_log():
+    """The pass ledger's record (``run_end.level_log``) exists only from
+    schema v14, field-gated like ``run_end.compiles``; a v13 ``run_end``
+    as PR 35's program wrote it still reads."""
+    v13 = {"v": 13, "event": "run_end", "ts": 0.0, "n_states": 10,
+           "n_transitions": 20, "complete": True, "outcome": "ok"}
+    end = {**v13, "v": 14, "level_log": {"wall_s": 1.0, "levels": []}}
+    assert validate_event(v13) == [] and validate_event(end) == []
+    errs = validate_event({**end, "v": 13})  # v14-only field, v13 line
+    assert len(errs) == 1 and "requires schema version >= 14" in errs[0]
+    assert validate_event({**end, "level_log": []})        # type drift
+    assert json.loads(json.dumps(end)) == end              # round trip
+
+
+def test_git_sha_is_read_from_the_checkouts_files(tmp_path, monkeypatch):
+    """``run_start.git_sha`` comes from ``.git``'s own files (loose ref,
+    packed ref, detached HEAD; a worktree's ``.git`` file gives no sha),
+    never from a child process: the first logged run stamps it on a clocked
+    path."""
+    from raft_tla_tpu.obs import events
+    sha = "0123456789abcdef0123456789abcdef01234567"
+    other = "f" * 40
+
+    def checkout(name, head, files=()):
+        root = tmp_path / name
+        (root / ".git" / "refs" / "heads").mkdir(parents=True)
+        (root / ".git" / "HEAD").write_text(head + "\n")
+        for rel, text in files:
+            (root / ".git" / rel).write_text(text)
+        return str(root)
+
+    loose = checkout("loose", "ref: refs/heads/main",
+                     [("refs/heads/main", sha + "\n")])
+    packed = checkout("packed", "ref: refs/heads/main", [(
+        "packed-refs", f"# pack-refs with: peeled\n{other} refs/heads/x\n"
+                       f"{sha} refs/heads/main\n")])
+    detached = checkout("detached", sha)
+    unborn = checkout("unborn", "ref: refs/heads/main",
+                      [("packed-refs", "")])
+    assert events._read_head(loose) == sha
+    assert events._read_head(packed) == sha
+    assert events._read_head(detached) == sha
+    assert events._read_head(unborn) is None
+    # a worktree is not followed out of the checkout: no sha, no error
+    tree = tmp_path / "tree"
+    tree.mkdir()
+    (tree / ".git").write_text(f"gitdir: {loose}/.git/worktrees/tree\n")
+    for root in (tree, tmp_path / "nowhere"):
+        with pytest.raises(OSError):
+            events._read_head(str(root))
+        monkeypatch.setattr(events, "_GIT_SHA_CACHE", [])
+        monkeypatch.setattr(events.os.path, "abspath",
+                            lambda _p, r=root: str(r / "a" / "b" / "c.py"))
+        assert events.git_sha() is None
+    monkeypatch.undo()
+    # the cached face: 12 hex digits or None, and no import of subprocess
+    monkeypatch.setattr(events, "_GIT_SHA_CACHE", [])
+    got = events.git_sha()
+    assert got is None or (len(got) == 12 and set(got) <= events._HEX)
+    assert events._GIT_SHA_CACHE == [got]
+    assert not hasattr(events, "subprocess")
 
 
 def test_ddd_segment_records_carry_the_slab_counters(tmp_path):

@@ -688,18 +688,37 @@ def test_perfetto_exports_the_segments_and_compiles_tracks(traced_toy):
 
 @pytest.mark.smoke
 def test_untraced_ddd_run_emits_no_span(tmp_path, monkeypatch):
-    """The off path: the engine's new sites (pass, level, level_close,
-    segment_wait, d2h, the segments track) all go through the one shared
-    null handle, and the log of an untraced run holds no span."""
+    """The off path: the log of an untraced run holds no span and the
+    run's tracer emits nothing (``enabled`` is False).  Since PR 38 the
+    sites the pass ledger reads (obs/passlog: pass, level, the seams) are
+    timed for it alone; every other site (export, take, snapshot, the
+    segments track) still goes through the one shared null handle."""
     monkeypatch.delenv("RAFT_TLA_TRACE", raising=False)
     from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+    from raft_tla_tpu.obs import passlog
     from raft_tla_tpu.obs import trace as obs_trace
-    opened = []
-    real_open = obs_trace.NullTracer.open
-    monkeypatch.setattr(
-        obs_trace.NullTracer, "open",
-        lambda self, name, **a: opened.append(name) or real_open(
-            self, name, **a))
+    opened, live, null, manual = [], set(), set(), []
+    real_open = obs_trace.SpanTracer.open
+    real_span = obs_trace.SpanTracer.span
+    real_emit = obs_trace.SpanTracer.emit_span
+
+    def spy_open(self, name, **a):
+        assert not self.enabled
+        opened.append(name)
+        return real_open(self, name, **a)
+
+    def spy_span(self, name, **a):
+        sp = real_span(self, name, **a)
+        (null if sp is obs_trace._NULL_SPAN else live).add(name)
+        return sp
+
+    def spy_emit(self, name, *a, **kw):
+        manual.append(name)
+        return real_emit(self, name, *a, **kw)
+
+    monkeypatch.setattr(obs_trace.SpanTracer, "open", spy_open)
+    monkeypatch.setattr(obs_trace.SpanTracer, "span", spy_span)
+    monkeypatch.setattr(obs_trace.SpanTracer, "emit_span", spy_emit)
     log = str(tmp_path / "off.events")
     res = DDDEngine(CFG, DDDCapacities(**_TOY_CAPS)).check(events=log)
     assert res.n_states == N_TOY
@@ -707,6 +726,9 @@ def test_untraced_ddd_run_emits_no_span(tmp_path, monkeypatch):
     assert not [e for e in evs if e["event"] == "span"]
     assert opened[0] == "pass" and set(opened[1:]) == {"level"}
     assert len(opened) == 1 + len(res.levels)
+    assert live and live <= passlog.NAMES and not (null & passlog.NAMES)
+    assert {"segment_wait", "d2h", "level_close"} <= live
+    assert not manual                    # emit_span sits behind tr.enabled
     assert evs[-1]["event"] == "run_end"
 
 

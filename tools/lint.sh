@@ -278,8 +278,18 @@ head -8 "$SERVE_TMP/trace_report.out"
 python -m raft_tla_tpu.check "$SERVE_TMP/toy.cfg" \
     --spec election --max-term 2 --max-log 0 --max-msgs 2 \
     --engine ddd --chunk 32 --host-dedup on --prefetch on \
+    --events "$SERVE_TMP/plain.events" \
     --cpu --no-lint --no-trace \
     > "$SERVE_TMP/trace_off.out"
+# untraced, the log holds no span but its run_end carries the pass ledger's
+# record (always on), and the report prints the level table from it
+if grep -q '"event": "span"' "$SERVE_TMP/plain.events"; then
+    echo "trace smoke FAILED: an untraced log holds spans"; exit 1
+fi
+python -m raft_tla_tpu.obs.tracecli report "$SERVE_TMP/plain.events" \
+    | grep -c "^  L[0-9]*: " | grep -qx 18 \
+    || { echo "trace smoke FAILED: no level table from run_end.level_log"; \
+         exit 1; }
 on_line="$(grep '^3014 distinct states found' "$SERVE_TMP/trace_on.out" \
     | sed 's/, [0-9.]*s.*//')"
 off_line="$(grep '^3014 distinct states found' "$SERVE_TMP/trace_off.out" \
