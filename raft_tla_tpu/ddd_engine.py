@@ -715,6 +715,34 @@ def _slab_plan(nk: int) -> tuple[int, int]:
     return _tile_plan(_S_OUT, nk)
 
 
+# A block's upload sends the rows it has.  The frontier block is a device
+# buffer that stays resident for the whole check(); the host ships the live
+# prefix in pieces of block / _UP_PIECES rows (2^15 at the default block:
+# 1.0-1.7 MB at 8-13 packed words), each laid into the buffer by the one
+# ``_place_piece`` program.  A block whose pieces would cover more than
+# _UP_WHOLE / _UP_PIECES of the buffer goes as one whole-buffer transfer
+# instead: PERF.md (section 5, PR 39) has a piece's measured cost and where
+# the two cross.
+_UP_PIECES = 32
+_UP_WHOLE = 16
+
+
+def _upload_plan(block: int, chunk: int) -> tuple[int, int]:
+    """``(S, whole_above)``: the rows of one upload piece, and the most
+    piece-rounded rows a block sends as pieces — past them (or where whole
+    pieces would overrun the buffer) it goes as one transfer."""
+    return (max(chunk, block // _UP_PIECES),
+            block * _UP_WHOLE // _UP_PIECES)
+
+
+def _place_piece(fbuf, fcon, rows, con, at):
+    """One upload piece laid into the resident frontier block at row
+    ``at``.  ``at`` + the piece's rows never pass the buffer's end
+    (``_upload_plan``), so the update is never clamped."""
+    return (jax.lax.dynamic_update_slice(fbuf, rows, (at, 0)),
+            jax.lax.dynamic_update_slice(fcon, con, (at,)))
+
+
 def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
     """``_filter_insert_ordered`` with the streamed candidates as a mask
     in lane order (``stream[c]`` is True iff candidate c streamed) in
@@ -1175,6 +1203,19 @@ class DDDEngine:
             _build_segment(config, self.caps, self.A, self.lay.width,
                            self.schema),
             donate_argnums=(0, 1))
+        # the frontier block's upload (_upload_plan): one allocator of a
+        # resident block ``(fbuf, fcon)`` and one placer, each of one
+        # shape, so a level's size compiles nothing.  Nothing reads a
+        # block's rows at or past a dispatch's ``block_rows``: what they
+        # hold — zeros at first, an earlier block's rows later — is
+        # immaterial.
+        self._up_rows, self._up_whole = _upload_plan(self.caps.block,
+                                                     config.chunk)
+        block_shape = (self.caps.block, self.schema.P)
+        self._alloc_block = jax.jit(
+            lambda: (jnp.zeros(block_shape, I32),
+                     jnp.zeros(block_shape[:1], bool)))
+        self._place = jax.jit(_place_piece, donate_argnums=(0, 1))
 
     def _new_master(self):
         return keyset.new_master(self._host_dedup,
@@ -1459,42 +1500,68 @@ class DDDEngine:
                 n_states += self._flush(pend, master, host, constore,
                                         keystore, cov)
         Fcap = self.caps.block
-        # what one frontier block and one segment's buffers weigh on the
-        # wire, from shapes (span args; the transfers are whole buffers)
-        up_bytes = Fcap * (self.schema.P * 4 + 1)
+        S_up, whole_above = self._up_rows, self._up_whole
+        row_bytes = self.schema.P * 4 + 1     # a frontier row on the wire
+        # what one segment's buffers weigh on the wire, from shapes (span
+        # args; that transfer is whole buffers)
         buf_bytes = self._buf_rows * (self.schema.P * 4 + 17)
+        # The frontier block is resident: one device buffer pair a slot
+        # for the whole check() (two slots under the prefetcher, so block
+        # k+1 lands while k is expanded), and one host staging pair a
+        # slot.  A block's upload reads its rows into the staging pair
+        # and ships the live prefix only, in pieces of S_up rows that
+        # ``_place`` lays into the resident buffers (donated: in place);
+        # past ``whole_above`` piece-rounded rows one whole-buffer
+        # transfer is cheaper and takes the slot's place.  Either way the
+        # rows past the block's own are whatever an earlier block left,
+        # on the host and on the device: the segment masks them by
+        # ``block_rows``, which only the dispatch after a complete upload
+        # sets — a stop between two pieces leaves nothing a later block
+        # could read as live.  One loader for both RAFT_TLA_PREFETCH arms.
+        n_slots = 2 if self._prefetch else 1
+        resident = [self._alloc_block() for _ in range(n_slots)]
+        stage_rows = [np.zeros((Fcap, self.schema.P), np.int32)
+                      for _ in range(n_slots)]
+        stage_con = [np.zeros((Fcap,), bool) for _ in range(n_slots)]
+
+        def load_block(start, rows, slot):
+            """Rows ``[start, start + rows)`` on the device in ``slot``:
+            ``(fbuf, fcon, rows sent, pieces)``."""
+            # range-disjointness precondition (utils/prefetch)
+            assert start + rows <= level_ends[-1], \
+                (start, rows, level_ends[-1])
+            rb, cb = stage_rows[slot], stage_con[slot]
+            rb[:rows] = host.read(start, rows)
+            cb[:rows] = constore.read(start, rows)[:, 0]
+            pieces = -(-rows // S_up)
+            sent = pieces * S_up
+            if sent > whole_above:
+                pieces, sent = 1, Fcap
+                resident[slot] = None    # freed before its successor lands
+                blk = (jax.device_put(rb), jax.device_put(cb))
+            else:
+                blk = resident[slot]
+                for at in range(0, sent, S_up):
+                    blk = self._place(*blk, rb[at:at + S_up],
+                                      cb[at:at + S_up], np.int32(at))
+            # the staging pair is reusable, and the span honest, only
+            # once the transfers have landed
+            resident[slot] = jax.block_until_ready(blk)
+            return (*blk, sent, pieces)
+
         # Upload prefetcher (RAFT_TLA_PREFETCH): while the device
-        # expands block k, a daemon thread reads block k+1's rows +
-        # constraint column and stages them into one of two
-        # preallocated buffer sets via device_put, so the block
-        # boundary swaps to a resident buffer instead of paying
-        # drain→read→pad→h2d.  Safe concurrently with the flush
-        # worker: block reads target rows < level_ends[-1], all
-        # published before the level began, while in-flight flushes
-        # append only rows >= level_ends[-1] (the store concurrency
-        # contract, utils/native) — so prefetch-on also drops the
-        # upload's unconditional dedup_wait drain.
+        # expands block k, a daemon thread runs ``load_block`` for block
+        # k+1 into the other slot, so the block boundary swaps to a
+        # resident buffer instead of paying drain→read→h2d.  Safe
+        # concurrently with the flush worker: block reads target rows <
+        # level_ends[-1], all published before the level began, while
+        # in-flight flushes append only rows >= level_ends[-1] (the
+        # store concurrency contract, utils/native) — so prefetch-on
+        # also drops the upload's unconditional dedup_wait drain.
         prefetcher = None
         if self._prefetch:
-            pf_rows = [np.zeros((Fcap, self.schema.P), np.int32),
-                       np.zeros((Fcap, self.schema.P), np.int32)]
-            pf_con = [np.zeros((Fcap,), bool), np.zeros((Fcap,), bool)]
-
-            def pf_load(start, rows, slot):
-                # range-disjointness precondition (utils/prefetch)
-                assert start + rows <= level_ends[-1], \
-                    (start, rows, level_ends[-1])
-                rb, cb = pf_rows[slot], pf_con[slot]
-                rb[:rows] = host.read(start, rows)
-                cb[:rows] = constore.read(start, rows)[:, 0]
-                if rows < Fcap:          # zero pad == the sync path's
-                    rb[rows:] = 0        # np.zeros concat, byte-exact
-                    cb[rows:] = False
-                return jax.block_until_ready(
-                    (jax.device_put(rb), jax.device_put(cb)))
-
             prefetcher = prefetch.BlockPrefetcher(
-                pf_load, phases=tel.phases, tracer=tel.trace)
+                load_block, phases=tel.phases, tracer=tel.trace)
             _cleanup.callback(prefetcher.close)
         viol = None          # (kind, inv_idx, dead_g) once detected
         viol_key = None
@@ -1583,10 +1650,10 @@ class DDDEngine:
                     # timers is the gate's signature.
                     with tel.phases.phase("upload") as ph:
                         hits0 = prefetcher.hits
-                        fbuf, fcon = ph.sync(
-                            prefetcher.take(b_start, b_rows))
-                        ph.set(rows=b_rows, padded_rows=Fcap,
-                               bytes=up_bytes,
+                        fbuf, fcon, sent, pieces = prefetcher.take(
+                            b_start, b_rows)
+                        ph.set(rows=b_rows, padded_rows=sent,
+                               bytes=sent * row_bytes, pieces=pieces,
                                prefetch_hit=prefetcher.hits > hits0)
                     nxt = b_start + Fcap
                     if nxt < lvl_hi:
@@ -1602,19 +1669,11 @@ class DDDEngine:
                                 ph.set(backlog=worker.backlog())
                             n_states += worker.drain()
                     with tel.phases.phase("upload") as ph:
-                        ph.set(rows=b_rows, padded_rows=Fcap,
-                               bytes=up_bytes)
-                        blk = host.read(b_start, b_rows)
-                        con = constore.read(b_start,
-                                            b_rows)[:, 0].astype(bool)
-                        if b_rows < Fcap:
-                            blk = np.concatenate([blk, np.zeros(
-                                (Fcap - b_rows, self.schema.P),
-                                np.int32)])
-                            con = np.concatenate(
-                                [con, np.zeros((Fcap - b_rows,), bool)])
-                        fbuf, fcon = ph.sync((jnp.asarray(blk),
-                                              jnp.asarray(con)))
+                        fbuf, fcon, sent, pieces = load_block(
+                            b_start, b_rows, 0)
+                        ph.set(rows=b_rows, padded_rows=sent,
+                               bytes=sent * row_bytes, pieces=pieces,
+                               prefetch_hit=False)
                 fc = fc._replace(c=jnp.int32(0))
                 # Two-deep segment pipeline: segment k+1 depends on k only
                 # through the filter carry, so it is dispatched BEFORE k's

@@ -453,7 +453,9 @@ def _render_level_log(rec: dict) -> list:
             f"(+{lv['gap_s']:.4f} before), {lv['rows']} rows, "
             f"{lv['segments']} segments, {lv['steps']} steps, "
             f"{lv['streamed_rows']} streamed, +{lv['new_states']} states; "
-            f"{seams} ({lv['uploads']} uploads); cpu {lv['cpu_s']:.4f} "
+            f"{seams} ({lv['uploads']} uploads, "
+            f"{lv.get('upload_bytes', 0)} bytes in "
+            f"{lv.get('upload_pieces', 0)} pieces); cpu {lv['cpu_s']:.4f} "
             f"gc {lv['gc_s']:.4f} majflt {lv['majflt']} "
             f"nivcsw {lv['nivcsw']}")
     for key, secs in sorted(rec["threads"].items()):

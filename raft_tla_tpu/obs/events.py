@@ -183,7 +183,10 @@ entry a level (wall, the gap since the level before, the main thread's
 seams, its CPU time, the collector's, page faults and involuntary
 switches), with the head before the first level, the tail after the last,
 the workers' seams by thread and the stalls the ledger named; in the log of every ddd run, traced or not, and absent from the
-engines that keep no ledger.  Seconds rounded to the microsecond.
+engines that keep no ledger.  Seconds rounded to the microsecond.  A
+level's entry also says what its uploads sent (``upload_bytes``,
+``upload_pieces``: keys inside the record, which the schema does not
+enumerate, so no version moved).
 
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the

@@ -26,9 +26,9 @@ last level's close -> the ``pass`` span closes.  One entry a level, in the
 names the ``level`` span uses::
 
     {"level", "t0", "gap_s", "wall_s", "rows", "blocks", "segments", "steps",
-     "streamed_rows", "new_states", "upload_s", "uploads", "expand_s",
-     "wait_s", "d2h_s", "dedup_s", "close_s", "cpu_s", "gc_s", "majflt",
-     "nivcsw"}
+     "streamed_rows", "new_states", "upload_s", "uploads", "upload_bytes",
+     "upload_pieces", "expand_s", "wait_s", "d2h_s", "dedup_s", "close_s",
+     "cpu_s", "gc_s", "majflt", "nivcsw"}
 
 ``wall_s`` is the ``level`` span's own ``dur``; ``gap_s`` is the previous
 level's close -> this one's open (0 for the first: that is the head), which
@@ -40,7 +40,10 @@ doing.
 ``upload_s`` .. ``close_s`` are main-thread wall inside the ``upload``,
 ``expand``, ``segment_wait``, ``d2h``, ``dedup`` + ``dedup_wait`` +
 ``dedup_submit`` and ``level_close`` seams (``level_close`` holds the
-``dedup`` at a level's end, so the two overlap); ``cpu_s`` is the main
+``dedup`` at a level's end, so the two overlap); ``uploads`` counts the
+level's ``upload`` spans and ``upload_bytes`` / ``upload_pieces`` sum their
+``bytes`` and ``pieces`` (what the ddd engine really sent, and in how many
+transfers; 0 where an engine's span does not say); ``cpu_s`` is the main
 thread's CPU time over the level, ``majflt`` / ``nivcsw`` its major faults
 and involuntary switches — all three from one ``getrusage(RUSAGE_THREAD)``
 at each end of the level, so ``cpu_s`` is as fine as the kernel accounts a
@@ -91,7 +94,8 @@ NAMES = frozenset(SEAMS) | {"pass", "level", "prefetch"}
 _COUNTS = ("level", "rows", "blocks", "segments", "steps", "streamed_rows",
            "new_states")
 # beside the seams, what a stall's line says of its level
-_SUSPECTS = ("uploads", "cpu_s", "gc_s", "majflt", "nivcsw")
+_SUSPECTS = ("uploads", "upload_bytes", "upload_pieces", "cpu_s", "gc_s",
+             "majflt", "nivcsw")
 
 _RUSAGE = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
 # the collector's clock: [seconds collecting so far, start of the open one]
@@ -183,7 +187,8 @@ def _stalls(rec: dict, earlier) -> list:
                 "level": key[0], "wall_s": wall, "median_s": med,
                 "passes": len(seen),
                 **({} if lv is None else
-                   {k: lv[k] for k in SEAM_FIELDS + _SUSPECTS})})
+                   {k: lv[k] for k in SEAM_FIELDS + _SUSPECTS
+                    if k in lv})})       # a record from before a field
     return found
 
 
@@ -197,7 +202,8 @@ def stall_line(rec: dict, st: dict) -> str:
     if of_level:
         line += ": " + " ".join(
             f"{k} {st[k]:.3f}" if isinstance(st[k], float)
-            else f"{k} {st[k]}" for k in SEAM_FIELDS + _SUSPECTS)
+            else f"{k} {st[k]}" for k in SEAM_FIELDS + _SUSPECTS
+            if k in st)
     return line
 
 
@@ -238,6 +244,7 @@ class PassLog:
             self.record["head_s"], gap = gap, 0.0
         self._cur = {"level": None, "t0": t0, "gap_s": gap, "wall_s": None,
                      **dict.fromkeys(_COUNTS[1:], 0), "uploads": 0,
+                     "upload_bytes": 0, "upload_pieces": 0,
                      **dict.fromkeys(SEAM_FIELDS, 0.0)}
         self._base = _counters()
 
@@ -256,6 +263,8 @@ class PassLog:
                 cur[field] += dur        # is in head_s / tail_s alone
                 if name == "upload":
                     cur["uploads"] += 1
+                    cur["upload_bytes"] += args.get("bytes", 0)
+                    cur["upload_pieces"] += args.get("pieces", 0)
         elif name == "level":
             self._close_level(t0, dur, args)
         elif name == "pass":

@@ -4,13 +4,15 @@ After PR 13 (async cross-bin dispatch) and PR 14 (background dedup
 flush), the one synchronous host phase left in the harvest loop was the
 per-block frontier **upload**: drain the in-flight flush, read the
 block's rows + constraint column from the host store (a DISK read in
-frontier retention), pad, and ``device_put`` — all while the device
+frontier retention) and send them to the device — all while the device
 sits idle at the block boundary.  `BlockPrefetcher` moves that chain
 onto one daemon thread: while the device expands block k, the worker
 reads block k+1 (its address is known from ``level_ends`` the moment
-the level starts) and stages it into one of two preallocated buffer
-sets via async ``jax.device_put``; at the boundary the engine swaps to
-an already-resident buffer.
+the level starts) and stages it into one of two slots — a host staging
+pair and a device-resident frontier block each; what a stage sends (the
+block's live prefix in fixed-size pieces, or the whole buffer) is the
+engine's loader's business — and at the boundary the engine swaps to an
+already-resident buffer.
 
 Why this is safe (the byte-identity argument):
 
@@ -81,8 +83,9 @@ class BlockPrefetcher:
 
     ``loader(start, rows, slot) -> Any`` is engine-supplied: it reads
     the stores, stages into the slot-indexed preallocated buffers, and
-    returns device-resident arrays (calling ``block_until_ready`` so
-    the slot's host buffers are reusable once the result is taken).
+    returns the slot's device-resident arrays with whatever it counted
+    on the way (calling ``block_until_ready`` so the slot's host buffers
+    are reusable once the result is taken).
     The loader runs on the worker thread on hits and on the caller's
     thread on misses — it must be safe for either, which the store
     concurrency contract (module docstring) provides.

@@ -33,8 +33,8 @@ N_TOY = 3014
 SEAMS = ("upload_s", "expand_s", "wait_s", "d2h_s", "dedup_s", "close_s")
 FIELDS = {"level", "t0", "gap_s", "wall_s", "rows", "blocks", "segments",
           "steps", "streamed_rows", "new_states", "upload_s", "uploads",
-          "expand_s", "wait_s", "d2h_s", "dedup_s", "close_s", "cpu_s", "gc_s",
-          "majflt", "nivcsw"}
+          "upload_bytes", "upload_pieces", "expand_s", "wait_s", "d2h_s",
+          "dedup_s", "close_s", "cpu_s", "gc_s", "majflt", "nivcsw"}
 
 
 def _build(kind):
@@ -160,6 +160,30 @@ def test_traced_pass_leaves_the_same_record_from_the_same_sites(two_passes):
     end = two_passes["events"][-1]
     assert end["event"] == "run_end" and validate_event(end) == []
     assert end["level_log"] == passlog.rounded(rec)
+
+
+def test_upload_bytes_and_pieces_are_the_sums_of_the_upload_spans(two_passes):
+    """What a level's uploads sent (ISSUE 39): ``upload_bytes`` and
+    ``upload_pieces`` of an entry are the sums of ``bytes`` and ``pieces``
+    over the ``upload`` spans under that level's span, traced pass and
+    untraced pass of one engine object alike.  The mesh engine's spans do
+    not say, and its entries read 0."""
+    spans = [e for e in two_passes["events"] if e["event"] == "span"]
+    for key in ("upload_bytes", "upload_pieces"):
+        assert [lv[key] for lv in two_passes["traced"].level_log["levels"]] \
+            == [lv[key] for lv in two_passes["plain"].level_log["levels"]]
+    for lv, sp in zip(two_passes["traced"].level_log["levels"],
+                      two_passes["levels"]):
+        ups = [u.get("args", {}) for u in spans if u["name"] == "upload"
+               and u.get("parent_id") == sp["span_id"]]
+        assert len(ups) == lv["uploads"]
+        assert lv["upload_bytes"] == sum(u.get("bytes", 0) for u in ups)
+        assert lv["upload_pieces"] == sum(u.get("pieces", 0) for u in ups)
+        if two_passes["kind"] == "ddd":
+            assert lv["upload_pieces"] >= lv["uploads"]
+            assert lv["upload_bytes"] >= lv["rows"] * 4
+        else:
+            assert (lv["upload_bytes"], lv["upload_pieces"]) == (0, 0)
 
 
 def _drive(plog, levels=1):

@@ -500,10 +500,21 @@ def test_level_spans_carry_their_work_and_match_the_segments_track(
     assert len(d2h) == sum(1 for s in segs if s["args"]["streamed_rows"])
     assert all(s["args"]["rows"] > 0 and s["args"]["bytes"] > 0
                for s in d2h)
-    ups = [s for s in spans if s["name"] == "upload"]
-    assert all(s["args"]["padded_rows"] == _TOY_CAPS["block"]
-               and 0 < s["args"]["rows"] <= s["args"]["padded_rows"]
-               and "prefetch_hit" in s["args"] for s in ups)
+    # an upload says what it sent: the live prefix rounded to pieces of
+    # ``_up_rows`` rows, or the whole block past ``_up_whole`` of them
+    from raft_tla_tpu.ddd_engine import _upload_plan
+    from raft_tla_tpu.ops.bitpack import BitSchema
+    block = _TOY_CAPS["block"]
+    piece, whole_above = _upload_plan(block, CFG.chunk)
+    row_bytes = 4 * BitSchema(CFG.bounds).P + 1
+    ups = [s["args"] for s in spans if s["name"] == "upload"]
+    assert ups and all(0 < a["rows"] <= a["padded_rows"] <= block
+                       and a["bytes"] == a["padded_rows"] * row_bytes
+                       and "prefetch_hit" in a for a in ups)
+    for a in ups:
+        n = -(-a["rows"] // piece)
+        assert (a["pieces"], a["padded_rows"]) == (
+            (n, n * piece) if n * piece <= whole_above else (1, block))
     flush = [s for s in spans if s["name"] == "dedup"]
     assert flush and all("keys" in s["args"] for s in flush)
 
