@@ -1,28 +1,30 @@
 """What decides ``correct``.  Every number compared is printed beside its
 limit; all limits are 0, the comparisons are exact (counts, and states
-compared as states — the reference never sees a fingerprint)."""
+compared as states — the reference never sees a fingerprint).
+
+The harness's half is here: the pins, the fixpoint and snapshot numbers, the
+seeded draw, the comparison of a stream with the reference's successor
+orbits, ``decide``.  The spec's half — the plain reference itself, how its
+states are named and which fault is planted — is the configuration's family
+(``manifest.family``); every state this module sees is a reference state."""
 
 from __future__ import annotations
 
 import random
 
-from benchmark.reference import canon, interp, invariants
-from benchmark.reference import spec as S
-from benchmark.reference.bounds import Bounds
+from benchmark.harness import manifest as mf
 
 SAMPLE = 256             # parents drawn with --seed
 MIN_LEVEL_STATES = 4096  # the reference BFS stops at the first such level
 #                          (a configuration may say otherwise: the toy does)
 
 
-# the parity-mode state: what crosses between the program's PyState and the
-# reference's (two classes of the same shape, by design unrelated)
-STATE_FIELDS = ("role", "term", "votedFor", "commitIndex", "log", "vResp",
-                "vGrant", "nextIndex", "matchIndex", "msgs")
-
-
-def _ref_state(s):
-    return interp.PyState(**{f: getattr(s, f) for f in STATE_FIELDS})
+def __getattr__(name: str):
+    # ``tests/test_full5.py`` reads the Raft family's STATE_FIELDS here, and
+    # a benchmark PR may not edit it (PERF.md, Open questions)
+    if name == "STATE_FIELDS":
+        return mf.family({}).STATE_FIELDS
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def pass_checks(pass_list: list, pins: list, end_level: int) -> list:
@@ -84,32 +86,40 @@ def reference_sample(cfg: dict, seed: int):
     """The plain reference's own BFS of the first levels (from the Init the
     configuration states, under its SYMMETRY axes), and the seeded sample of
     its deepest level with that sample's successor orbits."""
-    bounds = Bounds(**cfg["bounds"])
-    sym = cfg["symmetry"]
-    cum, level, viol = canon.bfs_levels(
-        bounds, cfg["spec"], sym, tuple(cfg["invariants"]),
-        cfg.get("sample_min_level_states", MIN_LEVEL_STATES),
-        init=canon.stated_init(bounds, cfg.get("init"), cfg["invariants"]))
+    fam = mf.family(cfg)
+    cum, level, viol = fam.bfs_levels(
+        cfg, cfg.get("sample_min_level_states", MIN_LEVEL_STATES))
     rng = random.Random(seed)
     parents = rng.sample(level, min(SAMPLE, len(level)))
-    reps, n_trans, con = canon.successor_orbits(parents, bounds, cfg["spec"],
-                                                sym)
+    reps, n_trans, con = fam.successor_orbits(parents, cfg)
     return {"cumulative": cum, "violations": viol, "level": level,
             "parents": parents, "orbits": reps, "n_transitions": n_trans,
-            "constraint": con, "sym": sym, "n_values": bounds.n_values}
+            "constraint": con, "key": fam.orbit_key(cfg)}
+
+
+def _sample_key(ref: dict):
+    """The function that names the orbit of a state of this sample.
+    ``reference_sample`` states it; a sample put together by hand the way
+    ``tests/test_full5.py`` does says Raft's ``sym`` instead."""
+    if "key" in ref:
+        return ref["key"]
+    return mf.family({}).orbit_key(
+        {"symmetry": ref["sym"],
+         "bounds": {"n_values": ref.get("n_values", 0)}})
 
 
 def sample_checks(ref: dict, got: dict, pins: list) -> list:
     """(c) the compiled segment's stream for the sampled parents against the
-    reference: the same set of successor orbits (states canonicalised in
-    plain Python), key and orbit in one-to-one correspondence (the 64-bit
+    reference (``got["states"]``: the streamed rows as reference states):
+    the same set of successor orbits (states canonicalised in plain
+    Python), key and orbit in one-to-one correspondence (the 64-bit
     key is exact on the sample), the same transition count and constraint
     flags.  Where the segment ran on a mesh (``got["misrouted"]``), the
     stream is the union of the shards' streams and one number joins:
     ``owner_misrouted``, streamed keys found on a shard other than
     ``key_hi % ndev``."""
-    key = canon.orbit_key(ref["sym"], ref.get("n_values", 0))
-    streamed = [key(_ref_state(s)) for s in got["states"]]
+    key = _sample_key(ref)
+    streamed = [key(s) for s in got["states"]]
     sset = set(streamed)
     con_wrong = sum(ref["constraint"].get(k) is not c
                     for k, c in zip(streamed, got["con"]) if k in ref["orbits"])
@@ -142,100 +152,25 @@ def sample_checks(ref: dict, got: dict, pins: list) -> list:
     ] + owner
 
 
-def _two_leaders_in_a_term(s, bounds: Bounds, rng):
-    """``s`` rewritten so that server i leads term t and server j is a
-    candidate of term t holding a quorum of votes: its ``BecomeLeader(j)``
-    successor has two leaders in one term."""
-    n = bounds.n_servers
-    i, j = rng.sample(range(n), 2)
-    t = max(s.term)
-    votes = 1 << j
-    for k in rng.sample([k for k in range(n) if k != j], n // 2):
-        votes |= 1 << k
-    role = tuple(S.LEADER if k == i else S.CANDIDATE if k == j
-                 else S.FOLLOWER if (r == S.LEADER and s.term[k] == t)
-                 else r for k, r in enumerate(s.role))
-    term = tuple(t if k in (i, j) else x for k, x in enumerate(s.term))
-    return s._replace(
-        role=role, term=term,
-        votedFor=tuple(j + 1 if k == j else v
-                       for k, v in enumerate(s.votedFor)),
-        vResp=tuple(votes if k == j else v for k, v in enumerate(s.vResp)),
-        vGrant=tuple(votes if k == j else v
-                     for k, v in enumerate(s.vGrant)))
-
-
-def _commit_a_later_leader_lacks(s, bounds: Bounds, rng):
-    """``s`` rewritten so that beside its leader i of the newest term t a
-    server j leads term t - 1 with one entry of that term in its log, and
-    ``matchIndex[j]`` claims a quorum for it: ``AdvanceCommitIndex(j)``
-    commits an entry that the later leader's log lacks.  It needs only the
-    log actions.  None where ``s`` has no leader of a term above 1."""
-    n, t = bounds.n_servers, max(s.term)
-    leaders = [k for k in range(n) if s.role[k] == S.LEADER and s.term[k] == t]
-    if not leaders or t < 2:
-        return None
-    i = rng.choice(leaders)
-    j = rng.choice([k for k in range(n) if k != i])
-    entry = (t - 1, rng.randint(1, bounds.n_values))
-    agreed = set(rng.sample([k for k in range(n) if k != j], n // 2))
-
-    def put(row, v):
-        return tuple(v if k == j else x for k, x in enumerate(row))
-
-    return s._replace(
-        role=put(s.role, S.LEADER), term=put(s.term, t - 1),
-        commitIndex=put(s.commitIndex, 0), log=put(s.log, (entry,)),
-        matchIndex=put(s.matchIndex,
-                       tuple(int(k in agreed) for k in range(n))))
-
-
 def planted_fault(cfg: dict, level: list, seed: int) -> dict:
-    """The planted fault: a state of the reference's level, drawn with the
-    seed and rewritten so that it holds every invariant itself and one step
-    breaks one.  Which rewrite is decided by the configuration's action
-    table, never by its file: where the table has ``BecomeLeader``, two
-    leaders in one term; where it has not and has ``AdvanceCommitIndex``, a
-    commit that a later leader's log lacks (``LeaderCompleteness``).
-    Returns the parent and ``{orbit of a violating successor: names of the
-    invariants it breaks}``, both judged by the plain reference."""
-    bounds = Bounds(**cfg["bounds"])
-    invs = {nm: invariants.REGISTRY[nm] for nm in cfg["invariants"]}
-    key = canon.orbit_key(cfg["symmetry"], bounds.n_values)
-    table = S.action_table(bounds, cfg["spec"])
-    families = {a.family for a in table}
-    if S.BECOMELEADER in families:
-        rewrite = _two_leaders_in_a_term
-    elif S.ADVANCECOMMIT in families:
-        rewrite = _commit_a_later_leader_lacks
-    else:
-        raise ValueError(f"spec {cfg['spec']!r} has neither BecomeLeader nor "
-                         "AdvanceCommitIndex: no planted fault is known for it")
-    rng = random.Random(f"plant/{seed}")
-    for s in rng.sample(level, len(level)):
-        parent = rewrite(s, bounds, rng)
-        if parent is None or not interp.constraint_ok(parent, bounds) \
-                or not all(f(parent, bounds) for f in invs.values()):
-            continue
-        violators = {}
-        for _a, nxt in interp.successors(parent, bounds, table):
-            broken = [nm for nm, f in invs.items() if not f(nxt, bounds)]
-            if broken:
-                violators[key(nxt)] = broken
-        if violators:
-            return {"parent": parent, "violators": violators, "key": key}
-    raise ValueError("no state of the reference level takes the planted "
-                     "fault; the configuration lists no invariant it breaks")
+    """The planted fault, as the configuration's family plants it: a state
+    of the reference's level, drawn with the seed and rewritten so that it
+    holds every invariant itself and one step breaks one.  Returns the
+    parent, ``violators`` = ``{orbit of a violating successor: names of the
+    invariants it breaks}`` and ``key``, the function that names a state's
+    orbit, all three judged by the plain reference."""
+    return mf.family(cfg).planted_fault(cfg, level, seed)
 
 
 def planted_checks(plant: dict, got: dict) -> list:
     """(d) every invariant is evaluated by the compiled segment: run from
     the planted parent, the engine has to report a violation, and the state
-    and invariant it names have to be among the reference's."""
+    (``got["state"]``, as a reference state) and invariant it names have to
+    be among the reference's."""
     missed = got["invariant"] is None
     wrong = 0
     if not missed:
-        names = plant["violators"].get(plant["key"](_ref_state(got["state"])))
+        names = plant["violators"].get(plant["key"](got["state"]))
         wrong = int(names is None or got["invariant"] not in names)
     return [("planted_violation_missed", int(missed), 0),
             ("planted_violation_misnamed", wrong, 0)]

@@ -1,6 +1,9 @@
 """Everything that touches the system under test: opening the device,
 building the engine a cell names, driving passes through its public
-``check()``, and feeding its compiled segment a seeded sample."""
+``check()``, and feeding its compiled segment a seeded sample.  What is
+particular to a spec (its configuration for the program, the crossing of a
+state, the row codec) is asked of the configuration's family
+(``manifest.family``)."""
 
 from __future__ import annotations
 
@@ -11,11 +14,8 @@ import shutil
 import threading
 import time
 
-from benchmark.harness import correct
 from benchmark.harness import manifest as mf
 from benchmark.harness import passes
-from benchmark.reference import canon
-from benchmark.reference.bounds import Bounds as RefBounds
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -58,30 +58,15 @@ def enable_cache(platform: str):
     return enable_compile_cache(platform=platform)
 
 
-def check_config(cfg: dict):
-    from raft_tla_tpu.config import Bounds, CheckConfig
-    from raft_tla_tpu.utils import cfgparse
-    tlc = cfgparse.parse_cfg(cfg["cfg_text"])
-    b = cfg["bounds"]
-    said = (len(tlc.server_names()), len(tlc.value_names()),
-            sorted(tlc.invariants), sorted(tlc.symmetry))
-    want = (b["n_servers"], b["n_values"], sorted(cfg["invariants"]),
-            sorted(cfg["symmetry"]))
-    if said != want:
-        raise ValueError(f"config {cfg['name']}: cfg_text says {said}, the "
-                         f"fields say {want}")
-    return CheckConfig(bounds=Bounds(**b), spec=cfg["spec"],
-                       invariants=tuple(cfg["invariants"]),
-                       symmetry=tuple(cfg["symmetry"]), chunk=cfg["chunk"])
-
-
 def build_engine(cfg: dict):
     """The engine the configuration names, with its capacities for it: the
     ``ddd`` engine on one device, or ``ddd-shard`` on a mesh of the
-    configuration's ``devices`` (the constructors ``check.py`` uses).  The
-    constructor builds the jitted segment once."""
+    configuration's ``devices`` (the constructors ``check.py`` uses), on
+    the ``CheckConfig`` its family makes of it.  The constructor builds the
+    jitted segment once."""
     name, devices = mf.engine_of(cfg)
     caps = cfg["engine_caps"][name]
+    check_config = mf.family(cfg).check_config
     if name == "ddd-shard":
         from raft_tla_tpu.parallel.ddd_shard_engine import (
             DDDShardCapacities, DDDShardEngine)
@@ -150,6 +135,7 @@ class Driver:
     def __init__(self, cell: dict, scratch: str):
         self.cell = cell
         self.cfg = cell["config_data"]
+        self.family = mf.family(self.cfg)
         self.traffic = cell["traffic_data"]
         self.pins = self.cfg["level_pins"]
         t = self.traffic
@@ -182,10 +168,9 @@ class Driver:
         # handed it through the public ``init_override``; one that states
         # none drives the calls it always drove
         self._from = {}
-        if "init" in self.cfg:
-            self._from["init_override"] = _program_state(canon.stated_init(
-                RefBounds(**self.cfg["bounds"]), self.cfg["init"],
-                self.cfg["invariants"]))
+        init = self.family.stated_init(self.cfg)
+        if init is not None:
+            self._from["init_override"] = self.family.to_program(init)
         if self._from and self.snapshot_level is not None:
             raise ValueError(
                 f"configuration {self.cfg['name']} states its Init and "
@@ -330,32 +315,19 @@ class Driver:
         """Feed ``parents`` (reference states) to the SAME compiled segment
         program the passes drove, as one frontier block (on the mesh: one
         window, dealt to the shards by the engine's own upload) behind an
-        empty filter, and decode what it streams.  The program symbols used
-        here are the benchmark's frozen interface (README, "What the
+        empty filter, and decode what it streams back into reference
+        states.  The family packs and decodes; the engine privates used
+        below are the benchmark's frozen interface (README, "What the
         benchmark holds the program to")."""
         import numpy as np
-        from raft_tla_tpu.models import interp as pinterp
-        from raft_tla_tpu.ops import state as st
-        eng = self.engine
-        n, P = len(parents), eng.schema.P
-        rows = np.zeros((n, P), np.int32)
-        con = np.zeros((n,), bool)
-        for k, s in enumerate(parents):
-            ps = _program_state(s)
-            rows[k] = eng.schema.pack(
-                np.asarray(pinterp.to_vec(ps, eng.bounds), np.int32), np)
-            con[k] = pinterp.constraint_ok(ps, eng.bounds)
+        rows, con = self.family.pack_rows(self.engine, parents)
         n0 = self.compiles.n
         got = (self._stream_mesh if self.engine_name == "ddd-shard"
                else self._stream_ddd)(rows, con)
-        states = []
-        for row in got.pop("orows"):
-            vec = eng.schema.unpack(np.asarray(row), np)
-            states.append(pinterp.from_struct(
-                st.unpack(vec, eng.lay, np), eng.bounds))
+        got["states"] = self.family.decode_rows(self.engine,
+                                                got.pop("orows"))
         got["keys"] = (got.pop("key_hi").astype(np.uint64) << np.uint64(32)) \
             | got.pop("key_lo").astype(np.uint64)
-        got["states"] = states
         got["compiles"] = self.compiles.n - n0
         return got
 
@@ -444,11 +416,12 @@ class Driver:
                 signal.raise_signal(signal.SIGINT)
 
         self.engine.seg_chunks = self._seg_chunks0
-        result = self.engine.check(init_override=_program_state(parent),
-                                   on_progress=stop_after_one_level)
+        result = self.engine.check(
+            init_override=self.family.to_program(parent),
+            on_progress=stop_after_one_level)
         v = result.violation
         return {"invariant": v.invariant if v else None,
-                "state": v.state if v else None,
+                "state": self.family.from_program(v.state) if v else None,
                 "levels": list(result.levels)}
 
 
@@ -460,13 +433,6 @@ class _Rows:
 
     def read(self, base: int, n: int):
         return self.a[base:base + n]
-
-
-def _program_state(s):
-    """A reference state as the program's PyState (same fields, two
-    unrelated classes)."""
-    from raft_tla_tpu.models import interp as pinterp
-    return pinterp.PyState(**{f: getattr(s, f) for f in correct.STATE_FIELDS})
 
 
 def memory_peak_bytes() -> int:

@@ -1,6 +1,7 @@
 """BENCHMARK.json and the files it names: everything a cell is made of is
-found by name, so a later PR adds a cell, a configuration, a traffic mix or a
-per-layer metric as new files plus new manifest entries, and edits nothing."""
+found by name, so a later PR adds a cell, a configuration, a traffic mix, a
+per-layer metric or a spec family as new files plus new manifest entries, and
+edits nothing."""
 
 from __future__ import annotations
 
@@ -87,6 +88,27 @@ def end_of(traffic: dict, cfg: dict, traffic_name: str) -> str:
             f"{cfg['name']} names the engine {cfg['engine']!r}: only 'ddd' "
             "has been driven to its own end")
     return end
+
+
+_FAMILY = re.compile(r"^[A-Za-z][A-Za-z0-9_]{0,63}$")
+
+
+def family(cfg: dict):
+    """``benchmark/families/<name>.py``, the module behind the
+    configuration's ``"family"`` (absent = ``raft``): the spec's plain
+    reference, the crossing of a state to the program and back, the planted
+    fault and a chunk step's counts, under the names ``families/raft.py``
+    lists.  An unknown family is refused by name.  Touches no device."""
+    name = cfg.get("family", "raft")
+    there = os.path.join(BENCH, "families")
+    if not (isinstance(name, str) and _FAMILY.match(name)
+            and os.path.isfile(os.path.join(there, name + ".py"))):
+        known = sorted(f[:-3] for f in os.listdir(there)
+                       if f.endswith(".py") and not f.startswith("_"))
+        raise ValueError(
+            f"configuration {cfg.get('name')}: unknown family {name!r} "
+            f"(known: {', '.join(known)})")
+    return importlib.import_module("benchmark.families." + name)
 
 
 def load(root: str = ROOT) -> dict:
@@ -188,6 +210,7 @@ def problems(manifest: dict) -> list:
             with open(path, encoding="utf-8") as f:
                 cfg = json.load(f)
             try:
+                family(cfg)
                 engine_of(cfg, w["chips"])
                 tpath = os.path.join(BENCH, "traffic", w["traffic"] + ".json")
                 if os.path.isfile(tpath):

@@ -60,12 +60,14 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
             trace: bool, rehearsal: bool = False) -> dict:
     """The run.  Returns the result object (and prints the lines before it).
     ``rehearsal`` skips the look for a chip and nothing else."""
-    from benchmark.harness import correct, drive, passes, tracered, work
+    from benchmark.harness import (correct, drive, levelred, passes,
+                                   tracered, work)
     from benchmark.harness import manifest as mf
 
-    # the engine the configuration names, refused by name before any device
-    # work where the cell cannot hold it
+    # the family and the engine the configuration names, refused by name
+    # before any device work where there is none or the cell cannot hold it
     try:
+        mf.family(cell["config_data"])
         engine_name, ndev = mf.engine_of(cell["config_data"],
                                             cell["chips"])
         mf.end_of(cell["traffic_data"], cell["config_data"],
@@ -81,13 +83,9 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         f"peak_rss_mb={rss_mb():.0f}")
     drv = drive.Driver(cell, scratch)
     traffic = drv.traffic
-    from raft_tla_tpu.ops import kernels
-    sig = kernels.step_signature(drv.engine.bounds, drv.cfg["spec"],
-                                 tuple(drv.cfg["invariants"]),
-                                 tuple(drv.cfg["symmetry"]), None)
     say(f"engine {engine_name} devices={ndev} "
         f"caps={json.dumps(drv.cfg['engine_caps'][engine_name])}")
-    say(f"gates {json.dumps(dict(sig[5:]))} "
+    say(f"gates {json.dumps(drv.family.gates(drv.engine, drv.cfg))} "
         f"host_dedup={getattr(drv.engine, '_host_dedup', None)} "
         f"prefetch={getattr(drv.engine, '_prefetch', None)} "
         f"nproc={os.cpu_count()}")
@@ -181,6 +179,10 @@ def execute(cell: dict, manifest: dict, seed: int, seconds: float,
         "work": _work(drv, work, made),
         "span_levels": [drv.a, drv.b], "snapshot": drv.snapshot,
     }
+    # the pass ledger's account of the untraced passes (its level table and,
+    # where a pass stalled, the stall's line), whatever --trace is; the five
+    # metrics read from it stay in the traced run's result line
+    levelred.of(evidence)
     if drv.fixpoint:
         # what a user waits for: set-up, then one check() to its verdict
         verdict = mf.metric_reader("verdict_wall_s")(evidence)
@@ -268,9 +270,7 @@ def _work(drv, work, made: list) -> dict:
         "traced_levels": [drv.a, te],
         "traced_orbits": exported,
         "steps": steps,
-        "words_per_step": work.scan_words(
-            eng.config.chunk, eng.A, eng.bounds.n_servers, eng.lay.width,
-            bool(eng.config.symmetry)),
+        "words_per_step": drv.family.scan_words(eng),
         "bytes_per_step": work.step_bytes(
             eng.config.chunk, eng.A, eng.schema.P, shards,
             getattr(eng.caps, "send", None)),

@@ -9,7 +9,9 @@ arithmetic and the pass clock, from Init and resumed from a snapshot; the
 trace reduction on the recorded trace; the readers of a pass at depth on a
 recorded span log; the plain reference against its own definition and the
 planted fault (two leaders in a term, or, where the action table has no
-BecomeLeader, a commit that a later leader lacks); a toy-size REHEARSAL of one
+BecomeLeader, a commit that a later leader lacks); a configuration's family
+found by name (stated or absent, unknown, the second family's reference half
+through the harness, what the harness may import); a toy-size REHEARSAL of one
 whole run from Init, of one whose passes resume from a level-pinned snapshot,
 of one on the mesh engine over four host devices, of one from a stated
 Init under SYMMETRY Server and Value and of one whose passes run to the
@@ -189,6 +191,285 @@ def test_a_cell_names_an_engine_it_can_hold():
         raise AssertionError("a one-chip cell ran a four-device mesh")
     except SystemExit as e:
         assert "toy_elect3_mesh4 spans 4 devices" in str(e)
+
+
+# ------------------------------------------------ a configuration's family
+
+def _said(cell, seed):
+    """The lines one untraced rehearsal of ``cell`` prints through
+    ``run.say``, in order."""
+    from benchmark import run
+    lines, real = [], run.say
+    run.say = lambda msg: (lines.append(msg), real(msg))
+    try:
+        res = run.execute(cell, mf.load(), seed, 0.0, False, rehearsal=True)
+    finally:
+        run.say = real
+    assert res["correct"] is True
+    return lines
+
+
+def _check_lines(cell, seed):
+    """The ``check <name>=<value> limit=<limit>`` lines of that rehearsal."""
+    return [ln for ln in _said(cell, seed) if ln.startswith("check ")]
+
+
+def test_a_family_stated_or_absent_is_the_same_run():
+    from benchmark.families import raft
+    cell = toy_cell()
+    assert "family" not in cell["config_data"]
+    assert mf.family(cell["config_data"]) is raft \
+        is mf.family({"family": "raft"})
+    absent = _check_lines(cell, 41)
+    cell = toy_cell()
+    cell["config_data"]["family"] = "raft"
+    stated = _check_lines(cell, 41)
+    assert absent == stated and len(absent) >= 8
+    # every accepted configuration is the default family's
+    m = mf.load()
+    assert all("family" not in mf.cell(m, w["name"])["config_data"]
+               for w in m["workloads"])
+
+
+def test_an_untraced_run_prints_the_pass_ledgers_account():
+    # the reducer of the program's pass ledger is asked once after the
+    # window whatever --trace is: its line (and a stall's, where a pass
+    # stalled) stands in an untraced run's log, after the verdict on correct
+    import contextlib
+    import io
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        _said(toy_cell(), 42)
+    printed = out.getvalue().splitlines()
+    account = [k for k, ln in enumerate(printed)
+               if ln.startswith("pass ledger, untraced passes: ")]
+    assert len(account) == 1
+    red = json.loads(printed[account[0]].split(": ", 1)[1])
+    assert red["passes"] >= 3 and red["ramp_by_seam_ms"] \
+        and red["span_by_seam_ms"] and red["stall_s"] >= 0.0
+    decided = next(k for k, ln in enumerate(printed)
+                   if ln.startswith("correct=True decided"))
+    assert decided < account[0]
+
+
+def test_an_unknown_family_is_refused_by_name_before_any_device():
+    from benchmark import run
+    from benchmark.harness import drive
+    for name in ("paxos", "../reference/canon", "_hidden", 7):
+        try:
+            mf.family({"name": "toy", "family": name})
+            raise AssertionError(f"family {name!r} was found")
+        except ValueError as e:
+            assert "toy" in str(e) and repr(name) in str(e) \
+                and "known: raft, twophase" in str(e), e
+    opened, real = [], drive.open_device
+    drive.open_device = lambda *a, **kw: opened.append(a) or real(*a, **kw)
+    cell = toy_cell()
+    cell["config_data"]["family"] = "paxos"
+    try:
+        run.execute(cell, mf.load(), 1, 0.0, False, rehearsal=True)
+        raise AssertionError("a run of an unknown family")
+    except SystemExit as e:
+        assert "unknown family 'paxos'" in str(e) and "toy_elect3" in str(e)
+    finally:
+        drive.open_device = real
+    assert opened == []
+    # ... and the manifest's own check names the cell
+    import tempfile
+    m = mf.load()
+    entry = next(c for c in m["configs"] if c["name"] == "elect5")
+    cfg = dict(mf.read_json("configs", "elect5.json"), family="paxos")
+    with tempfile.NamedTemporaryFile("w", suffix=".json") as f:
+        json.dump(cfg, f)
+        f.flush()
+        entry["file"] = f.name
+        assert any("elect5.passes" in b and "unknown family 'paxos'" in b
+                   for b in mf.problems(m))
+
+
+# what a family is for: the harness names nothing of a spec.  breakers.py is
+# the one exception, by design: its controls patch the program underneath
+# the timed path, and these are the symbols it patches.
+_SPEC_MODULES = ("benchmark.reference", "models.interp", "ops.state",
+                 "ops.kernels", "cfgparse")
+_BREAKERS_PATCH = {"raft_tla_tpu.ops.kernels": "build_step",
+                   "raft_tla_tpu.utils.keyset": "new_master, "
+                                                "master_from_keys",
+                   "raft_tla_tpu.parallel.ddd_shard_engine": "exchange",
+                   "benchmark.harness.drive": "build_engine"}
+
+
+def _imports(path: str) -> set:
+    """Every module name ``path`` imports, ``from X import y`` as ``X`` and
+    ``X.y``."""
+    import ast
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), path)
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            found.add(node.module)
+            found.update(f"{node.module}.{a.name}" for a in node.names)
+    return found
+
+
+def test_the_harness_names_no_module_of_a_spec():
+    harness = os.path.join(mf.BENCH, "harness")
+    files = sorted(os.path.join(harness, f) for f in os.listdir(harness)
+                   if f.endswith(".py"))
+    files += [os.path.join(mf.BENCH, "run.py"),
+              os.path.join(mf.BENCH, "control.py")]
+    assert len(files) >= 18
+    for path in files:
+        names = _imports(path)
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        if path.endswith("breakers.py"):
+            assert {n for n in names if n.startswith(("raft_tla_tpu.",
+                                                      "benchmark."))} \
+                == set(_BREAKERS_PATCH) | {
+                    m.rsplit(".", 1)[0] for m in _BREAKERS_PATCH}, names
+            continue
+        for spec in _SPEC_MODULES:
+            assert not any(spec in n for n in names), (path, spec)
+            assert spec not in text, (path, spec)
+    # the guard sees what it is there to see
+    raft = _imports(os.path.join(mf.BENCH, "families", "raft.py"))
+    assert all(any(spec in n for n in raft) for spec in _SPEC_MODULES)
+
+
+def test_twophase_reference_counts_what_tlc_counts():
+    from benchmark.reference import twophase as tp
+    for n, total in ((2, 56), (3, 288), (4, 1568), (5, 8832)):
+        cum, last, viol, _trans = tp.bfs_levels(n)
+        assert cum[-1] == total and len(cum) == 3 * n + 2 and viol == 0
+        assert len(last) == 2       # all committed, or all aborted
+        assert {s.rmState for s in last} == {(tp.COMMITTED,) * n,
+                                             (tp.ABORTED,) * n}
+    cum, _last, viol, trans = tp.bfs_levels(3)
+    assert [b - a for a, b in zip([0] + cum, cum)] \
+        == [1, 7, 21, 38, 50, 54, 49, 36, 21, 9, 2]
+    assert trans == 1145 and viol == 0
+    # a search cut at a level's size stops there, unexpanded
+    cum4, level, _v, _t = tp.bfs_levels(4, min_level_states=200)
+    assert cum4 == [1, 10, 46, 134, 288, 504] and len(level) == 216
+    # the packed form is one to one; the invariants see what they should
+    states, frontier = {tp.init_state(3)}, {tp.init_state(3)}
+    while frontier:
+        frontier = {t for s in frontier for _a, t in tp.successors(s)} \
+            - states
+        states |= frontier
+    assert len(states) == 288 == len({tp.pack(s) for s in states})
+    taken = {a for s in states for (a, _rm), _t in tp.successors(s)}
+    assert taken == set(tp.ACTIONS)
+    bad = tp.init_state(3)._replace(
+        rmState=(tp.ABORTED, tp.COMMITTED, tp.WORKING))
+    assert tp.tp_type_ok(bad) and not tp.tc_consistent(bad)
+    assert not tp.tp_type_ok(bad._replace(msgs=1 << 5))
+    # it is the benchmark's own: nothing of the program, nor of its oracle
+    names = _imports(tp.__file__)
+    assert not any(n.startswith(("raft_tla_tpu", "frontend")) or
+                   "frontend" in n for n in names), names
+
+
+def _toy_twophase(n: int = 4) -> dict:
+    return {"name": f"toy_twophase{n}", "family": "twophase",
+            "bounds": {"n_rms": n}, "symmetry": [],
+            "invariants": ["TPTypeOK", "TCConsistent"],
+            "sample_min_level_states": 200}
+
+
+def test_the_second_familys_reference_half_goes_through_the_harness():
+    import numpy as np
+    from benchmark.families import twophase as fam
+    from benchmark.harness import correct
+    from benchmark.reference import twophase as tp
+    cfg = _toy_twophase(4)
+    assert mf.family(cfg) is fam
+    full = tp.bfs_levels(4)[0]
+    plants = []
+    for seed in (1, 2, 2_147_483_659):
+        ref = correct.reference_sample(cfg, seed)
+        assert ref["cumulative"] == full[:6] and ref["violations"] == 0
+        assert len(ref["level"]) == 216 and len(ref["parents"]) == 216
+        assert not any(fam.holds(s, cfg) for s in ref["level"])
+        # the stream a sound engine would give: every successor, as a state,
+        # under a key of its own
+        states = [t for s in ref["parents"] for _a, t in tp.successors(s)]
+        got = {"states": states, "con": [True] * len(states),
+               "keys": np.asarray([tp.pack(t) for t in states], np.uint64),
+               "n_transitions": len(states), "fail": 0, "done": True}
+        checks = correct.sample_checks(ref, got, full)
+        assert [v for _n, v, _l in checks] == [0] * 8, checks
+        # ... and one that loses a successor, or names two states alike
+        short = dict(got, states=states[1:], con=got["con"][1:],
+                     keys=got["keys"][1:], n_transitions=len(states) - 1)
+        failed = {n for n, v, lim in correct.sample_checks(ref, short, full)
+                  if v > lim}
+        assert "sample_transitions_diff" in failed
+        alike = dict(got, keys=np.zeros(len(states), np.uint64))
+        assert {n for n, v, lim in correct.sample_checks(ref, alike, full)
+                if v > lim} == {"sample_key_orbit_conflicts"}
+        plant = correct.planted_fault(cfg, ref["level"], seed)
+        plants.append(plant)
+        parent = plant["parent"]
+        assert fam.holds(parent, cfg) == []
+        assert parent.tmState == tp.TM_COMMITTED and parent.msgs & 1 << 4
+        assert tp.PREPARED in parent.rmState and tp.ABORTED in parent.rmState
+        assert plant["violators"] and all(
+            v == ["TCConsistent"] for v in plant["violators"].values())
+        by = {a for (a, _rm), t in tp.successors(parent)
+              if plant["key"](t) in plant["violators"]}
+        assert by == {"RMRcvCommitMsg"}
+        hit = next(iter(plant["violators"]))
+        assert [v for _n, v, _l in correct.planted_checks(
+            plant, {"invariant": "TCConsistent", "state": hit})] == [0, 0]
+        assert [v for _n, v, _l in correct.planted_checks(
+            plant, {"invariant": "TPTypeOK", "state": hit})] == [0, 1]
+        assert [v for _n, v, _l in correct.planted_checks(
+            plant, {"invariant": None, "state": None})] == [1, 0]
+    assert len({p["parent"] for p in plants}) > 1      # the seed draws it
+    # what a configuration of this family may not say
+    for change, word in (({"symmetry": ["RM"]}, "no SYMMETRY"),
+                         ({"init": {}}, "starts from TPInit"),
+                         ({"bounds": {"n_rms": 0}}, "n_rms"),
+                         ({"invariants": ["TPTypeOK"]},
+                          "lists no invariant it breaks")):
+        try:
+            bad = dict(cfg, **change)
+            correct.planted_fault(bad, correct.reference_sample(
+                bad, 1)["level"], 1)
+            raise AssertionError(f"{change} was accepted")
+        except ValueError as e:
+            assert word in str(e), e
+
+
+def test_the_second_familys_program_half_says_what_it_waits_for():
+    from benchmark.families import raft, twophase as fam
+    cfg = _toy_twophase(4)
+    s = fam.bfs_levels(cfg, 10)[1][0]
+    for call in (lambda: fam.check_config(cfg), lambda: fam.to_program(s),
+                 lambda: fam.from_program(s),
+                 lambda: fam.pack_rows(None, [s]),
+                 lambda: fam.decode_rows(None, []),
+                 lambda: fam.gates(None, cfg), lambda: fam.scan_words(None)):
+        try:
+            call()
+            raise AssertionError("the TwoPhase family drove a device engine")
+        except fam.NoDeviceEngine as e:
+            assert "no device engine runs this family yet " \
+                "(ROADMAP queue 2 A.1)" in str(e)
+    # the two families answer to the same names
+    def surface(mod):
+        return {n for n, f in vars(mod).items() if not n.startswith("_")
+                and callable(f) and f.__module__ == mod.__name__}
+    assert surface(raft) == surface(fam) - {"NoDeviceEngine"} == {
+        "check_config", "bounds", "stated_init", "to_program",
+        "from_program", "bfs_levels", "successor_orbits", "orbit_key",
+        "holds", "pack_rows", "decode_rows", "planted_fault", "scan_words",
+        "gates"}
 
 
 # ------------------------------------------------- the reading's arithmetic
@@ -1194,7 +1475,7 @@ def test_log_share_is_of_the_sound_untraced_passes_coverage():
 
 def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
     from benchmark.harness import correct
-    from benchmark.reference import canon, interp, invariants
+    from benchmark.reference import canon, interp
     from benchmark.reference.bounds import Bounds
     for cfg in (mf.read_json("testdata", "toy_config.json"),
                 mf.read_json("configs", "elect5.json"),
@@ -1207,8 +1488,7 @@ def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
         assert len({p["parent"] for p in plants}) > 1     # the seed draws it
         for p in plants:
             assert interp.constraint_ok(p["parent"], b)
-            assert all(invariants.REGISTRY[nm](p["parent"], b)
-                       for nm in cfg["invariants"])
+            assert mf.family(cfg).holds(p["parent"], cfg) == []
             assert p["violators"] and all(
                 "NoTwoLeaders" in v for v in p["violators"].values())
     # a table with no BecomeLeader takes the log fault: AdvanceCommitIndex
@@ -1225,8 +1505,7 @@ def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
     table = S.action_table(b, cfg["spec"])
     for p in logs:
         assert interp.constraint_ok(p["parent"], b)
-        assert all(invariants.REGISTRY[nm](p["parent"], b)
-                   for nm in cfg["invariants"])
+        assert mf.family(cfg).holds(p["parent"], cfg) == []
         assert p["violators"] and all(
             v == ["LeaderCompleteness"] for v in p["violators"].values())
         by = {table[a].family for a, t in interp.successors(
@@ -1241,10 +1520,11 @@ def test_the_planted_fault_breaks_an_invariant_in_one_step_only():
     p = plants[0]
     orbit, names = next(iter(p["violators"].items()))
 
-    class Hit:
-        def __init__(self, t):
-            for f, v in zip(correct.STATE_FIELDS, t):
-                setattr(self, f, v)
+    def Hit(t):
+        return interp.PyState(*t)
+    # tests/test_full5.py still reads the Raft family's fields off correct
+    from benchmark.families import raft
+    assert correct.STATE_FIELDS is raft.STATE_FIELDS
     ok = correct.planted_checks(p, {"invariant": names[0],
                                     "state": Hit(orbit)})
     assert [v for _n, v, _l in ok] == [0, 0]
