@@ -46,7 +46,7 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="loaded spec: a Raft Next-disjunct subset (default: "
                         "full raft.tla:454-465) or the bundled twophase "
                         "(two-phase commit, frontend-compiled; --engine "
-                        "host; cfg binds CONSTANT RM)")
+                        "host or ddd; cfg binds CONSTANT RM)")
     p.add_argument("--engine", default="device",
                    choices=("device", "paged", "streamed", "ddd", "shard",
                             "pagedshard", "ddd-shard", "host", "ref"),
@@ -493,8 +493,8 @@ def _run(args, config):
                          checkpoint_every_s=args.checkpoint_every,
                          resume=args.resume)
     if args.engine == "ddd":
-        from raft_tla_tpu.models import spec as S
         from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+        from raft_tla_tpu.frontend import resolve_model
         # the filter table is a traffic optimization, not a capacity
         # bound — size it to the expected state count, capped at the
         # 2 GiB single-buffer limit the exact tables live under
@@ -502,7 +502,7 @@ def _run(args, config):
         table = 1 << max(10, min(28, (2 * args.cap - 1).bit_length()))
         # segment output buffers must hold at least one chunk's worst-case
         # candidate stream (chunk * action fan-out)
-        A = len(S.action_table(config.bounds, config.spec))
+        A = len(resolve_model(config.spec).action_table(config.bounds))
         seg_rows = max(1 << 19, 2 * args.chunk * A)
         if args.route and args.route > seg_rows:
             seg_rows = args.route
@@ -701,6 +701,9 @@ def main(argv=None) -> int:
     if not model.is_raft and args.engine not in model.engines:
         p.error(f"--engine {args.engine} does not support spec "
                 f"{args.spec!r} (supported: {', '.join(model.engines)})")
+    if not model.is_raft and args.route:
+        p.error(f"--route does not support spec {args.spec!r}: the routed "
+                "step is Raft's (the dense step runs every spec)")
     if args.simulate is not None and "simulate" not in model.engines:
         p.error(f"--simulate is not supported by spec {args.spec!r} "
                 f"(supported engines: {', '.join(model.engines)})")

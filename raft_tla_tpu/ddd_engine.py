@@ -67,13 +67,11 @@ from raft_tla_tpu.device_engine import (
     _EMPTY, BUCKET, FAIL_INDEX, FAIL_LEVEL, FAIL_ROUTE, FAIL_WIDTH,
     aggregate_coverage, decode_fail)
 from raft_tla_tpu.engine import DEADLOCK, EngineResult, Violation
-from raft_tla_tpu.models import interp, invariants as inv_mod, spec as S
+from raft_tla_tpu.frontend import resolve_model
 from raft_tla_tpu.obs import RunTelemetry, compiles
 from raft_tla_tpu.ops import bitpack
 from raft_tla_tpu.ops import devdedup
 from raft_tla_tpu.ops import kernels
-from raft_tla_tpu.ops import state as st
-from raft_tla_tpu.ops import symmetry as sym_mod
 from raft_tla_tpu.utils import ckpt
 from raft_tla_tpu.utils import flushq
 from raft_tla_tpu.utils import keyset
@@ -441,7 +439,7 @@ def _mmap_rows(path: str, width: int):
                      shape=(n, width))
 
 
-def frontier_backtrace(config, schema, lay, bounds, table, prefix,
+def frontier_backtrace(config, schema, bounds, table, prefix,
                        level_ends, n_states, viol_g, keystore):
     """TLC-equivalent counterexample reconstruction in frontier mode.
 
@@ -480,8 +478,8 @@ def frontier_backtrace(config, schema, lay, bounds, table, prefix,
 
     A = len(table)
     B = config.chunk
-    step = kernels.build_step(config.bounds, config.spec, (),
-                              config.symmetry, view=config.view)
+    model = resolve_model(config.spec)
+    step = model.build_step(dataclasses.replace(config, invariants=()))
 
     @jax.jit
     def match(fbuf, fcon, nrows, tgt_hi, tgt_lo):
@@ -497,7 +495,7 @@ def frontier_backtrace(config, schema, lay, bounds, table, prefix,
         lo, _ = span_of(fi)
         rows = _mmap_rows(f"{prefix}.rowsL{fi}", P)
         row = schema.unpack(np.asarray(rows[g - lo]), np)
-        return interp.from_struct(st.unpack(row, lay, np), bounds)
+        return model.from_vec(row, bounds)
 
     rev = []                      # [(label_into_state, py)] backwards
     tgt_g = int(viol_g)
@@ -897,15 +895,34 @@ def _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo, active):
     return tbl_hi, tbl_lo, n_stream, compact, n_tiles
 
 
+def _build_step(model, config: CheckConfig, caps: DDDCapacities):
+    """The fused step a segment expands with: the spec's own dense step
+    (``model.build_step``; it resolves the prescan ladder,
+    kernels._prescan_enabled, at build time), or with ``route_rows`` the
+    EP-routed one, which is Raft's — both share _step_stages, keys are
+    bit-identical either way."""
+    if not caps.route_rows:
+        return model.build_step(config)
+    if not model.is_raft:
+        raise ValueError(
+            f"route_rows={caps.route_rows}: the routed step "
+            f"(kernels.build_step_routed) is Raft's; spec {config.spec!r} "
+            "runs the dense step only")
+    return kernels.build_step_routed(
+        config.bounds, model.sub, tuple(config.invariants),
+        config.symmetry, k_rows=caps.route_rows, view=config.view)
+
+
 def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
-                   W: int, schema: bitpack.BitSchema):
+                   W: int, schema: bitpack.BitSchema, step):
     """One dispatch = up to ``budget`` chunks via ``lax.while_loop``,
     compacting every chunk's candidate stream into the segment output
     buffers at a running cursor.  The loop stops when the block is done,
     the next chunk might overflow the output buffers, a violation or
     failure is flagged, or the budget is spent.  ``bufs`` hold
     ``seg_rows`` rows plus the slack of ``_slab_plan``; rows at and
-    past ``stats.cursor`` are unspecified."""
+    past ``stats.cursor`` are unspecified.  ``step`` is the spec's fused
+    step (_build_step), ``schema`` its packed row."""
     B = config.chunk
     N = B * A
     routed = caps.route_rows > 0
@@ -916,17 +933,6 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
             f"seg_rows={OCAP} must be >= per-chunk candidate rows = {NK}")
     SLAB, SLACK = _slab_plan(NK)
     n_inv = len(config.invariants)
-    # Both step flavors share _step_stages; the dense one resolves the
-    # prescan ladder (kernels._prescan_enabled) here at build time —
-    # keys are bit-identical either way.
-    if routed:
-        step = kernels.build_step_routed(
-            config.bounds, config.spec, tuple(config.invariants),
-            config.symmetry, k_rows=caps.route_rows, view=config.view)
-    else:
-        step = kernels.build_step(config.bounds, config.spec,
-                                  tuple(config.invariants), config.symmetry,
-                                  view=config.view)
     BIG = jnp.int32(np.iinfo(np.int32).max)
 
     def chunk_body(carry: _SegCarry) -> _SegCarry:
@@ -1148,8 +1154,12 @@ class DDDEngine:
                  seg_chunks: int = 64):
         self.config = config
         self.bounds = config.bounds
-        self.lay = st.Layout.of(self.bounds)
-        self.table = S.action_table(self.bounds, config.spec)
+        # everything of the spec comes from its model adapter (layout,
+        # action table, step, packed row, Init, row codec), as the host
+        # anchor engine.Engine takes it
+        self.model = resolve_model(config.spec)
+        self.lay = self.model.layout(self.bounds)
+        self.table = self.model.action_table(self.bounds)
         self.A = len(self.table)
         self.caps = caps or DDDCapacities()
         if self.caps.block < config.chunk:
@@ -1157,7 +1167,7 @@ class DDDEngine:
         self.seg_chunks = seg_chunks
         self._digest_caps = _DigestCaps(block=self.caps.block,
                                         levels=self.caps.levels)
-        self.schema = bitpack.BitSchema(self.bounds)
+        self.schema = self.model.bit_schema(self.bounds)
         # RAFT_TLA_HOSTDEDUP gate: partitioned master keys + background
         # flush worker.  Resolved once at construction (like the
         # prescan gate) and deliberately NOT part of
@@ -1201,7 +1211,8 @@ class DDDEngine:
             kernels._prescan_enabled(config.bounds, config.symmetry)
         self._segment = jax.jit(
             _build_segment(config, self.caps, self.A, self.lay.width,
-                           self.schema),
+                           self.schema,
+                           _build_step(self.model, config, self.caps)),
             donate_argnums=(0, 1))
         # the frontier block's upload (_upload_plan): one allocator of a
         # resident block ``(fbuf, fcon)`` and one placer, each of one
@@ -1315,8 +1326,8 @@ class DDDEngine:
 
     # -- main loop ------------------------------------------------------
 
-    def check(self, init_override: interp.PyState | None = None,
-              on_progress=None, checkpoint: str | None = None,
+    def check(self, init_override=None, on_progress=None,
+              checkpoint: str | None = None,
               checkpoint_every_s: float = 600.0,
               resume: str | None = None,
               deadline_s: float | None = None,
@@ -1355,14 +1366,14 @@ class DDDEngine:
         pass_sp = tr.open("pass", engine="ddd", resumed=resume is not None,
                           prescan=self._prescan)
         _cleanup.callback(pass_sp.close)     # raise paths; idempotent
-        bounds = self.bounds
+        bounds, model = self.bounds, self.model
         init_py = init_override if init_override is not None \
-            else interp.init_state(bounds)
-        init_vec = interp.to_vec(init_py, bounds)
-        hi0, lo0 = sym_mod.init_fingerprint(self.config, init_py, init_vec)
+            else model.init_py(bounds)
+        init_vec = model.to_vec(init_py, bounds)
+        hi0, lo0 = model.init_fingerprint(self.config, init_py, init_vec)
 
         for nm in self.config.invariants:
-            if not inv_mod.py_invariant(nm)(init_py, bounds):
+            if not model.py_invariant(nm)(init_py, bounds):
                 from collections import Counter
                 res = EngineResult(
                     n_states=1, diameter=0, n_transitions=0,
@@ -1440,13 +1451,13 @@ class DDDEngine:
                 np.asarray(init_vec, np.int32), np)
             if frontier:
                 host.cur.append(init_packed[None, :])
-                con0 = interp.constraint_ok(init_py, bounds)
+                con0 = model.constraint_ok(init_py, bounds)
                 constore.cur.append(np.asarray([[con0]], np.int32))
             else:
                 host.append(init_packed[None, :])
                 host.append_links(np.asarray([-1], np.int64),
                                   np.asarray([-1], np.int32))
-                con0 = interp.constraint_ok(init_py, bounds)
+                con0 = model.constraint_ok(init_py, bounds)
                 constore.append(np.asarray([[con0]], np.int32))
             keystore.append(np.asarray(
                 [[np.uint32(lo0), np.uint32(hi0)]],
@@ -1981,14 +1992,13 @@ class DDDEngine:
                 # else (-noTrace equivalence) report the state itself
                 row = self.schema.unpack(host.read(int(viol_g), 1)[0],
                                          np)
-                py = interp.from_struct(st.unpack(row, self.lay, np),
-                                        self.bounds)
+                py = model.from_vec(row, self.bounds)
                 host.sync()          # commit cur/nxt for mmap reads
                 constore.sync()
                 trace = frontier_backtrace(
-                    self.config, self.schema, self.lay, self.bounds,
-                    self.table, checkpoint, level_ends, n_states,
-                    int(viol_g), keystore)
+                    self.config, self.schema, self.bounds, self.table,
+                    checkpoint, level_ends, n_states, int(viol_g),
+                    keystore)
                 violation = Violation(invariant=inv_name, state=py,
                                       trace=trace or [(None, py)])
             else:
@@ -1997,8 +2007,7 @@ class DDDEngine:
                 for k, g in enumerate(chain_idx):
                     row = self.schema.unpack(host.read(int(g), 1)[0], np)
                     _, lane_g = host.read_links(int(g), 1)
-                    py = interp.from_struct(st.unpack(row, self.lay, np),
-                                            self.bounds)
+                    py = model.from_vec(row, self.bounds)
                     label = self.table[int(lane_g[0])].label() if k > 0 \
                         else None
                     chain.append((label, py))

@@ -185,16 +185,23 @@ def build_schema_step(schema, defs, table, bounds, predicates=()):
     expand = build_schema_expand(schema, defs, table, bounds)
 
     def step(vecs):
-        structs = jax.vmap(lambda v: lay.unpack(v, jnp))(vecs)
-        succs, valid, ovf = jax.vmap(expand)(structs)
-        svecs = jax.vmap(jax.vmap(lambda t: lay.pack(t, jnp)))(succs)
-        fp_hi, fp_lo = fpr.fingerprint(svecs, consts, jnp)
-        if predicates:
-            inv_ok = jnp.stack(
-                [jax.vmap(jax.vmap(lambda t, p=p: p.ev(t, jnp)))(succs)
-                 for p in predicates], axis=-1)
-        else:
-            inv_ok = jnp.ones(valid.shape + (0,), dtype=bool)
+        # the step's stage scopes (kernels.STAGE_SCOPES), as build_step
+        # opens them: metadata for the device trace, no computation
+        with jax.named_scope("unpack"):
+            structs = jax.vmap(lambda v: lay.unpack(v, jnp))(vecs)
+        with jax.named_scope("expand"):
+            succs, valid, ovf = jax.vmap(expand)(structs)
+        with jax.named_scope("pack"):
+            svecs = jax.vmap(jax.vmap(lambda t: lay.pack(t, jnp)))(succs)
+        with jax.named_scope("plain_fp"):
+            fp_hi, fp_lo = fpr.fingerprint(svecs, consts, jnp)
+        with jax.named_scope("invariants"):
+            if predicates:
+                inv_ok = jnp.stack(
+                    [jax.vmap(jax.vmap(lambda t, p=p: p.ev(t, jnp)))(succs)
+                     for p in predicates], axis=-1)
+            else:
+                inv_ok = jnp.ones(valid.shape + (0,), dtype=bool)
         return {"svecs": svecs, "valid": valid, "overflow": ovf,
                 "fp_hi": fp_hi, "fp_lo": fp_lo, "inv_ok": inv_ok,
                 "con_ok": jnp.ones_like(valid)}

@@ -5,6 +5,8 @@ the registry for a *model adapter* and go through its uniform surface:
 
 - ``layout(bounds)`` / ``action_table(bounds)`` / ``build_step(config)``
   — the compiled step (same fused contract for every model);
+  ``bit_schema(bounds)`` — the packed row the ddd engine stores and
+  uploads (``ops/bitpack.BitSchema``);
 - ``init_py`` / ``to_vec`` / ``from_vec`` / ``init_fingerprint`` /
   ``constraint_ok`` / ``py_invariant`` — the host-side half of the BFS
   (roots, trace decoding, frontier invariant probes);
@@ -69,6 +71,10 @@ class RaftModel:
         return kernels.build_step(
             config.bounds, self.sub, tuple(config.invariants),
             tuple(config.symmetry), view=config.view, family_kernels=fk)
+
+    def bit_schema(self, bounds):
+        from raft_tla_tpu.ops import bitpack
+        return bitpack.BitSchema(bounds)
 
     def init_py(self, bounds):
         from raft_tla_tpu.models import interp
@@ -152,7 +158,7 @@ class TwoPhaseModel:
     sub = "twophase"
     is_raft = False
     use_ir = True
-    engines = ("host", "simulate")
+    engines = ("host", "ddd", "simulate")
 
     def _mod(self):
         from raft_tla_tpu.frontend import twophase
@@ -185,6 +191,10 @@ class TwoPhaseModel:
         return actions.build_schema_step(
             tp.SCHEMA, tp.ACTIONS, tp.action_table(config.bounds),
             config.bounds, predicates=preds)
+
+    def bit_schema(self, bounds):
+        from raft_tla_tpu.ops import bitpack
+        return bitpack.BitSchema.of_schema(self._mod().SCHEMA, bounds)
 
     def init_py(self, bounds):
         return self._mod().init_state(bounds)
@@ -262,13 +272,18 @@ class TwoPhaseModel:
         the non-Raft face of ``serve/jobs.resolve_check_config``."""
         tp = self._mod()
         where = path or "cfg"
-        if cfg.specification not in (None, "Spec"):
+        # the bounded twin's names (emit_tla) and the source's own
+        # (TwoPhase.tla: TPSpec == TPInit /\ [][TPNext]_vars), so that the
+        # source's TwoPhase.cfg runs as it is written
+        if cfg.specification not in (None, "Spec", "TPSpec"):
             raise ValueError(
-                f"{where}: twophase checks SPECIFICATION Spec only "
-                f"(got {cfg.specification!r})")
-        if cfg.init not in (None, "Init") or cfg.next not in (None, "Next"):
+                f"{where}: twophase checks SPECIFICATION Spec (or the "
+                f"source's TPSpec) only (got {cfg.specification!r})")
+        if cfg.init not in (None, "Init", "TPInit") \
+                or cfg.next not in (None, "Next", "TPNext"):
             raise ValueError(
-                f"{where}: twophase supports INIT Init / NEXT Next only")
+                f"{where}: twophase supports INIT Init / NEXT Next (or "
+                "the source's TPInit / TPNext) only")
         if cfg.properties:
             raise ValueError(
                 f"{where}: temporal properties are not supported for "

@@ -74,8 +74,14 @@ ALL_FAMILIES = (TM_RCV_PREPARED, TM_COMMIT, TM_ABORT, RM_PREPARE,
 # TCommit.tla's TCConsistent, in the frontend predicate grammar: no two
 # RMs ever disagree committed-vs-aborted.  Registered names resolve to
 # these texts; whole-line INVARIANT expressions compile directly.
+# TPTypeOK (TwoPhase.tla) is the declared ranges themselves: every field
+# inside ``[lo, hi]`` of its SCHEMA declaration (``msgs \subseteq Messages``
+# is the three flag fields being flags).
 INVARIANTS = {
     "TCConsistent": "~(any(rmState = 3) /\\ any(rmState = 2))",
+    "TPTypeOK": " /\\ ".join(
+        f"all({f.name} >= {f.lo}) /\\ all({f.name} <= {f.hi})"
+        for f in SCHEMA.fields),
 }
 DEFAULT_INVARIANT = "TCConsistent"
 

@@ -105,7 +105,27 @@ class BitSchema:
 
     def __init__(self, bounds: Bounds):
         lay = st.Layout.of(bounds)
-        fb = field_bits(bounds)
+        self._plan(lay, field_bits(bounds))
+
+    @classmethod
+    def of_schema(cls, schema, bounds: Bounds) -> "BitSchema":
+        """The pack plan of a frontend ``Schema`` (frontend/schema.py):
+        every field declares its value range, so its width is that of
+        its declared ``hi``.  A field that may be negative has no packed
+        form here and is refused by name."""
+        from raft_tla_tpu.frontend.schema import envelope
+        fb = {}
+        for name, iv in envelope(schema, bounds).items():
+            if iv.lo < 0:
+                raise ValueError(
+                    f"schema {schema.name!r}: field {name!r} declares "
+                    f"[{iv.lo}, {iv.hi}]; a packed field holds 0..hi")
+            fb[name] = _bits(iv.hi)
+        self = cls.__new__(cls)
+        self._plan(schema.layout(bounds), fb)
+        return self
+
+    def _plan(self, lay, fb: dict) -> None:
         bits = []
         for f in lay.fields:
             bits += [fb[f]] * int(np.prod(lay.shapes[f]))

@@ -79,6 +79,7 @@ from raft_tla_tpu.ddd_engine import (
     frontier_checkpoint_setup, load_ddd_snapshot,
     load_frontier_snapshot, save_ddd_snapshot, save_frontier_snapshot)
 from raft_tla_tpu.engine import DEADLOCK, EngineResult, Violation
+from raft_tla_tpu.frontend import resolve_model
 from raft_tla_tpu.models import interp, invariants as inv_mod, spec as S
 from raft_tla_tpu.obs import RunTelemetry
 from raft_tla_tpu.ops import bitpack
@@ -471,6 +472,12 @@ class DDDShardEngine:
                  seg_chunks: int = 64):
         self.config = config
         self.bounds = config.bounds
+        if not resolve_model(config.spec).is_raft:
+            # the mesh segment builds Raft's step and Raft's packed row;
+            # only the one-chip ddd engine takes them from the registry
+            raise ValueError(
+                f"the ddd-shard engine does not run spec {config.spec!r}: "
+                "its segment is built for Raft's row (use --engine ddd)")
         self.lay = st.Layout.of(self.bounds)
         self.table = S.action_table(self.bounds, config.spec)
         self.A = len(self.table)
@@ -1230,9 +1237,9 @@ class DDDShardEngine:
                 host.sync()
                 constore.sync()
                 trace = frontier_backtrace(
-                    self.config, self.schema, self.lay, self.bounds,
-                    self.table, checkpoint, level_ends, n_states,
-                    int(viol_g), keystore)
+                    self.config, self.schema, self.bounds, self.table,
+                    checkpoint, level_ends, n_states, int(viol_g),
+                    keystore)
                 violation = Violation(invariant=inv_name, state=py,
                                       trace=trace or [(None, py)])
             else:

@@ -101,15 +101,18 @@ def test_manifest_gains_the_three_lane_readers_and_the_cell_full5():
     manifest = mf.load()
     names = [m["name"] for m in manifest["per_layer"]]
     assert tuple(names[names.index(NEW_METRICS[-1]) + 1:][:3]) == LANE_METRICS
+    # the first three cells first; cells of later PRs may be appended
+    # (ROADMAP queue 2 B.1: a benchmark PR appends the five later ones)
+    cells = [w["name"] for w in manifest["workloads"]]
     for m in manifest["per_layer"]:
         if m["name"] in LANE_METRICS:
             assert (m["layer"], m["moves"], m["source"]) \
                 == ("fused step", "orbits_per_s", "program_span")
-            assert m["workloads"] == ["elect5.passes", "flagship3.passes",
-                                      "full5.passes"]
-        elif m["name"] in NEW_METRICS:
-            assert m["workloads"] == ["elect5.passes", "flagship3.passes",
-                                      "full5.passes"]
+        if m["name"] in LANE_METRICS or m["name"] in NEW_METRICS:
+            assert m["workloads"][:3] == ["elect5.passes",
+                                          "flagship3.passes", "full5.passes"]
+            assert set(m["workloads"]) <= set(cells) \
+                and len(set(m["workloads"])) == len(m["workloads"])
     cell = mf.cell(manifest, "full5.passes")
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("full5", "passes_l10_l12", 1)

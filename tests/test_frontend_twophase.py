@@ -196,8 +196,69 @@ def test_service_end_to_end_twophase(tmp_path):
 
 def test_model_engine_gate():
     model = resolve_model("twophase")
-    assert model.engines == ("host", "simulate")
+    assert model.engines == ("host", "ddd", "simulate")
     assert not model.is_raft
+
+
+# the source's own TwoPhase.cfg (tlaplus/Examples, transaction_commit/), as
+# it is written: its spec is TPSpec, its invariants TPTypeOK and TCConsistent
+CFG_SOURCE = ("CONSTANT RM = {r1, r2, r3}\n"
+              "INVARIANTS TPTypeOK TCConsistent\n"
+              "SPECIFICATION TPSpec\n")
+
+
+def test_the_sources_cfg_is_admitted_as_it_is_written():
+    adm = admit(CheckJob("2pc", JobOptions(spec="twophase"),
+                         cfg_text=CFG_SOURCE))
+    assert adm.admitted and adm.reason is None
+    assert adm.config.bounds.n_servers == 3
+    assert adm.config.invariants == ("TPTypeOK", "TCConsistent")
+    init_next = CFG_SOURCE.replace("SPECIFICATION TPSpec",
+                                   "INIT TPInit\nNEXT TPNext")
+    assert admit(CheckJob("2pc", JobOptions(spec="twophase"),
+                          cfg_text=init_next)).admitted
+    other = admit(CheckJob("2pc", JobOptions(spec="twophase"),
+                           cfg_text=CFG_SOURCE.replace("TPSpec", "TCSpec")))
+    assert not other.admitted and other.reason == "cfg-invalid"
+
+
+def test_tp_type_ok_is_the_declared_ranges():
+    b = Bounds(n_servers=3, n_values=1)
+    check = resolve_model("twophase").py_invariant("TPTypeOK")
+    init = tp.init_state(b)
+    assert check(init, b)
+    for bad in (init._replace(tmState=3), init._replace(msgAbort=2),
+                init._replace(rmState=(0, 4, 0)),
+                init._replace(tmPrepared=(0, 0, -1))):
+        assert not check(bad, b)
+    # every field of the schema is held, by its own declaration
+    for f in tp.SCHEMA.fields:
+        assert f"all({f.name} <= {f.hi})" in tp.INVARIANTS["TPTypeOK"]
+    got = engine.check(_config(3, invariants=("TPTypeOK", "TCConsistent")))
+    assert got.violation is None and got.n_states == ORACLE[3][0]
+
+
+def test_cli_runs_the_sources_cfg_on_ddd_and_refuses_the_mesh_and_the_route(
+        tmp_path, capsys):
+    from raft_tla_tpu import check
+    cfg = tmp_path / "TwoPhase.cfg"
+    cfg.write_text(CFG_SOURCE)
+    base = [str(cfg), "--spec", "twophase", "--chunk", "64", "--cpu"]
+    assert check.main(base + ["--engine", "ddd", "--coverage"]) == 0
+    out = capsys.readouterr().out
+    assert "288 distinct states found, diameter 10, 1145 transitions" in out
+    assert "Invariants: TPTypeOK, TCConsistent" in out
+    assert "RMRcvAbortMsg: 152 new states" in out
+    assert "No error has been found" in out
+    for extra, said in ((["--engine", "ddd-shard"],
+                         "--engine ddd-shard does not support spec "
+                         "'twophase' (supported: host, ddd, simulate)"),
+                        (["--engine", "ddd", "--route", "512"],
+                         "--route does not support spec 'twophase'")):
+        with pytest.raises(SystemExit) as e:
+            check.main(base + extra)
+        assert e.value.code == 2
+        assert said in capsys.readouterr().err
 
 
 def test_emit_tla(tmp_path):
@@ -210,6 +271,10 @@ def test_emit_tla(tmp_path):
     assert "SPECIFICATION Spec" in cfg
     assert "RM = {r1, r2, r3}" in cfg
     assert "INVARIANT" in cfg and "TCConsistent" in cfg
+    # the template defines TPTypeOK: both of the source's invariants emit
+    both = model.emit_tla(str(tmp_path), Bounds(n_servers=3, n_values=1),
+                          invariants=("TPTypeOK", "TCConsistent"))
+    assert "INVARIANT TPTypeOK\nINVARIANT TCConsistent" in open(both[1]).read()
     tla = texts["MC2pc.tla"]
     assert "MODULE MC2pc" in tla
     assert "TCConsistent" in tla

@@ -154,8 +154,11 @@ def test_which_benchmark_configurations_take_the_ladder_on_the_tpu(
         cfg = json.load(f)
     monkeypatch.delenv("RAFT_TLA_PRESCAN", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    got = kernels._prescan_enabled(Bounds(**cfg["bounds"]),
-                                   tuple(cfg["symmetry"]))
+    # the program's bounds as the configuration's spec family states them
+    # (a family other than Raft's names its own: twophase10 says n_rms)
+    from benchmark.harness import manifest as mf
+    config = mf.family(cfg).check_config(cfg)
+    got = kernels._prescan_enabled(config.bounds, tuple(cfg["symmetry"]))
     assert got is (name in _LADDER_ON_THE_TPU)
 
 
