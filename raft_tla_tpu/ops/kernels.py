@@ -625,13 +625,17 @@ def _step_stages(bounds: Bounds, spec: str, invariants: tuple,
     # 73 MB at P=120 vs the 16 MB scoped-vmem limit, and the P=24
     # remote-compile returned HTTP 500 — runs/pallas_orbit_p24.out),
     # and was deleted.  What the scan compiles to on the v5e, since PR
-    # 29: a body of some 20 device operations a permutation, two of them
-    # over the lanes — one multiply-reduce of the byte-wide feature
-    # matrix against that permutation's row of constants, one fusion
-    # that relabels and ranks the message bag.  No state array is
-    # gathered, relabelled, sorted or packed in it: an image costs
-    # 0.43-0.79 ns a lane (runs/prescan_ab.out), which is what
-    # _prescan_enabled's rule for the ladder below is derived from.
+    # 42: a body a block of eight server permutations, two operations
+    # of it over the lanes — one int8 matrix product on the MXU (the
+    # block's 64 rows of limbs of the permuted constants times the
+    # byte-wide feature matrix, int32), and one fusion that shifts the
+    # limbs home, relabels and ranks the message bag of the block's
+    # eight images side by side, finalises and keeps the least key.  No
+    # state array is gathered, relabelled, sorted or packed in it, and
+    # no image has a multiply-reduce of its own (PR 29-41: 244 us an
+    # image at 344,064 lanes, 0.43-0.79 ns a lane, runs/prescan_ab.out,
+    # which is what _prescan_enabled's rule for the ladder below was
+    # derived from; what an image costs now: PERF.md, PR 42).
     # Mosaic findings: git show f293573:RESULTS.md "Pallas orbit
     # kernel", runs/pallas_orbit_p24.out.
     # The view folds into the DEDUP KEY only: stored rows, invariants and
@@ -736,7 +740,11 @@ def _prescan_enabled(bounds, symmetry):
     with both axes at six servers (|G| >= 1,440), or a six-server one
     whose chunks fit the N/4 rung as full5's do, would gain by these
     figures; none is measured and no cell runs one, so the TPU's rule
-    is never, and the ladder is the CPU backend's.
+    is never, and the ladder is the CPU backend's.  Since PR 42 the
+    scan's linear part is one int8 matrix product a block of eight
+    images and a full scan is 0.15-0.18 ns a lane an image at |G| = 120
+    (PERF.md section 6): the ladder's fixed cost did not change, so it
+    loses by more.
 
     On the CPU it is on for every symmetric program (runs/prescan_ab.py
     --cpu, PR 32, this repository's sandbox, the same step and chunks at

@@ -18,7 +18,8 @@ device nothing is permuted (:func:`build_orbit_fp`): the key is linear in
 the state's words before its finaliser, so the image's key is taken from
 the *unmoved* state against that permutation's row of a host-built table
 of permuted constants, and the message bag is ranked where the loop sorts
-it — the same bits, |π| slices and multiply-reduces a candidate block.
+it — the same bits, and since PR 42 the table is ``int8`` limbs, so the
+linear part of a block of images' keys is one matrix product on the MXU.
 
 Permuting one state under ``p`` (new index of old server j is ``p[j]``):
 
@@ -290,13 +291,14 @@ def _linear_fields(axes: tuple) -> tuple:
 
 
 def _key_features(struct: dict, fields: tuple, xp):
-    """``struct[N, ...] -> uint8[F, N]``: the permutation-independent
+    """``struct[N, ...] -> int8[F, N]``: the permutation-independent
     features of ``fields``, in :func:`_key_table`'s order, lanes minor (a
     ``[N, small]`` array is kept padded to 128 lanes on the TPU).  Every
-    entry is below 2^8 — terms, indices, values and server ids are capped
-    at 63 by ``config.Bounds``, the rest are bits — so the fold
-    (``x ^ (x >> 16)``) is the identity on all of them and a byte holds
-    each: the scan reads this array once a group element."""
+    entry is below 2^7 — terms, indices, values and server ids are capped
+    at 63 by ``config.Bounds`` (:func:`_feature_cap`), the rest are bits —
+    so the fold (``x ^ (x >> 16)``) is the identity on all of them and a
+    signed byte holds each: the matrix the MXU multiplies by a block of
+    permutations' limbs (:func:`_limb_sums`), read once a block."""
     n = struct["role"].shape[1]
     N = struct["role"].shape[0]
     parts = []
@@ -307,7 +309,7 @@ def _key_features(struct: dict, fields: tuple, xp):
         elif f in _VOTE_MASKS:
             a = (a[:, :, None] >> xp.arange(n)) & 1       # [N, j, bit]
         a = xp.reshape(a, (N, -1))
-        parts.append(a.astype(xp.uint8))    # 6-bit: Bounds caps them at 63
+        parts.append(a.astype(xp.int8))     # 6-bit: Bounds caps them at 63
     return xp.concatenate(parts, axis=1).T
 
 
@@ -345,12 +347,102 @@ def _key_table(bounds: Bounds, consts, fields: tuple,
 
 
 def _linear_sums(phi, row, xp):
-    """``phi uint8[F, N]`` (:func:`_key_features`) times one permutation's
-    ``row uint32[2, F]`` of :func:`_key_table` -> the lanes' two sums."""
+    """``phi int8[F, N]`` (:func:`_key_features`) times one permutation's
+    ``row uint32[2, F]`` of :func:`_key_table` -> the lanes' two sums.
+    The plain statement of the algebra, which the tests hold the limb
+    form to; the device takes the sums from :func:`_limb_sums`."""
     with np.errstate(over="ignore"):
         w = phi.astype(xp.uint32)
         return (xp.sum(w * row[0][:, None], axis=0, dtype=xp.uint32),
                 xp.sum(w * row[1][:, None], axis=0, dtype=xp.uint32))
+
+
+# The same sums on the MXU.  A uint32 constant is four balanced base-256
+# digits, ``c = sum_l d_l * 2^(8l)  (mod 2^32)`` with every ``d_l`` in
+# [-128, 127]: an ``int8`` matrix.  ``sum_f phi_f * d_(f,l)`` is then an
+# int8 x int8 product accumulated in int32 — exact while ``F * cap * 128``
+# stays under 2^31 — and the four partial sums, shifted to their digit and
+# added in uint32, wrap to the word the multiply-reduce gives.  (Each
+# lane's sum is built from its own slices of the product, so that the
+# compiler fuses the shifts into the fusion that ranks the bag.)
+_N_LIMBS = 4
+_LIMB_MAX = 128           # |balanced digit| <= 128
+# A block is at most eight server permutations: their images lie side by
+# side as ``[P_b, N]``, one vector register's sublanes, and the 64-row
+# product is what the v5e multiplies cheapest (PERF.md, PR 42: 15 us a
+# block at 155,648 lanes, 180 us with 160 rows).  The block's int32
+# product ``[P_b * 2 * _N_LIMBS, N]`` may take this much of the device's
+# memory besides (full5's dense step: 344,064 lanes, 88 MB).
+_BLOCK_PERMS = 8
+_PRODUCT_BYTES = 96 << 20
+
+
+def _feature_cap(bounds: Bounds, fields: tuple) -> int:
+    """The largest entry :func:`_key_features` can hold for ``fields``
+    under ``bounds`` (capacities, one past each constraint)."""
+    cap = {"role": 2, "term": bounds.term_cap, "logTerm": bounds.term_cap,
+           "commitIndex": bounds.log_cap, "logLen": bounds.log_cap,
+           "matchIndex": bounds.log_cap, "nextIndex": bounds.log_cap + 1,
+           "logVal": bounds.n_values}
+    return max(cap.get(f, 1) for f in fields)    # one-hots and bits: 1
+
+
+def _check_limb_range(n_features: int, feature_cap: int) -> None:
+    """Refuse a layout whose limb product would not be exact: a feature
+    an ``int8`` cannot hold, or a sum that could leave ``int32``."""
+    if feature_cap > 127:
+        raise ValueError(
+            f"orbit key features must fit int8 (cap {feature_cap} > 127)")
+    if n_features * feature_cap * _LIMB_MAX >= 1 << 31:
+        raise ValueError(
+            f"orbit key limb sums could overflow int32: {n_features} "
+            f"features x cap {feature_cap} x {_LIMB_MAX} >= 2^31")
+
+
+def _key_limbs(table: np.ndarray) -> np.ndarray:
+    """:func:`_key_table`'s ``uint32[P, 2, F]`` as balanced base-256
+    digits, ``int8[P, 2, _N_LIMBS, F]``, least significant first.  The
+    carry out of the top digit is a multiple of 2^32 and is dropped."""
+    c = table.astype(np.int64)
+    limbs = []
+    for _ in range(_N_LIMBS):
+        d = ((c + 128) & 0xFF) - 128
+        limbs.append(d)
+        c = (c - d) >> 8
+    return np.stack(limbs, axis=2).astype(np.int8)   # 8-bit: in [-128, 127]
+
+
+def _limb_sums(limbs, phi, xp):
+    """``limbs int8[P_b, 2, _N_LIMBS, F]`` (:func:`_key_limbs`) times
+    ``phi int8[F, N]`` -> ``uint32[P_b, 2, N]``, bit for bit what
+    :func:`_linear_sums` gives for each of the block's permutations: one
+    matrix product in int32, then the digits shifted home and added."""
+    rows = limbs.reshape((-1, limbs.shape[-1]))
+    if xp is np:
+        d = rows.astype(np.int32) @ phi.astype(np.int32)
+    else:
+        import jax
+        d = jax.lax.dot_general(rows, phi, (((1,), (0,)), ((), ())),
+                                preferred_element_type=xp.int32)
+    d = d.reshape(limbs.shape[:-1] + (phi.shape[1],))
+    sums = []
+    for lane in range(2):
+        s = d[:, lane, 0].astype(xp.uint32)
+        for digit in range(1, _N_LIMBS):
+            s = s + (d[:, lane, digit].astype(xp.uint32)
+                     << xp.uint32(8 * digit))
+        sums.append(s)
+    return xp.stack(sums, axis=1)
+
+
+def _block_perms(n_perms: int, n_lanes: int) -> int:
+    """How many server permutations one product takes: the largest
+    divisor of ``n_perms`` that is at most ``_BLOCK_PERMS`` and whose
+    int32 product stays within ``_PRODUCT_BYTES`` (one permutation where
+    none does)."""
+    per_perm = 2 * _N_LIMBS * 4 * max(1, n_lanes)
+    fit = min(_BLOCK_PERMS, max(1, _PRODUCT_BYTES // per_perm))
+    return max(d for d in range(1, fit + 1) if n_perms % d == 0)
 
 
 def _relabel_hi(hi, src_row, dst_row, xp):
@@ -490,28 +582,42 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     Bit-identical to :func:`orbit_fingerprint` (the loop that permutes,
     canonicalises, packs and fingerprints each image; the (hi, lo)
     lexicographic min is order-independent), compiled as ONE body
-    iterated by ``lax.scan`` over the |G| = n!·V! group elements — and
-    the body **moves no state data**.  The sum before the finaliser is a
-    sum of per-field sums (``fingerprint.field_sums``), and each field
-    takes the form its image allows, chosen here, statically, from
-    ``axes`` and ``faithful``:
+    iterated by ``lax.scan`` over **blocks of ``P_b`` server
+    permutations** (:func:`_block_perms`: eight, or the largest divisor
+    of P under it, from the shapes alone) — and the body **moves no
+    state data**.  The sum before the finaliser is a sum of
+    per-field sums (``fingerprint.field_sums``), and each field takes the
+    form its image allows, chosen here, statically, from ``axes`` and
+    ``faithful``:
 
-    - the per-server fields (:func:`_linear_fields`): one vector of
-      features built **once a call, outside the scan**
-      (:func:`_key_features`), times row ``p`` of a host-built table of
-      permuted constants (:func:`_key_table`): a slice and one
-      multiply-reduce an image, no gather, no relabel;
+    - the per-server fields (:func:`_linear_fields`): one ``int8`` matrix
+      of features built **once a call, outside the scan**
+      (:func:`_key_features`), times the block's rows of a host-built
+      table of permuted constants (:func:`_key_table`) split into
+      ``int8`` limbs (:func:`_key_limbs`): **one** ``dot_general`` a
+      block on the MXU, ``[P_b * 8, F] x [F, N]`` in int32, the limbs
+      shifted home and added in uint32 (:func:`_limb_sums`).  No gather,
+      no relabel, no multiply-reduce an image; under Value symmetry once
+      a server permutation, not once a (p, q);
     - the message bag: ``src`` / ``dst`` relabelled (the fold is not
-      linear in them), then ranked, not sorted (:func:`_bag_sums`);
+      linear in them), then ranked, not sorted (:func:`_bag_sums`), the
+      block's images side by side (``[P_b, N]``, one fusion with the
+      finaliser and the block's least key);
     - what neither covers — ``logVal`` under Value symmetry, the
       faithful-mode history with its ``elections`` sort — is moved and
-      canonicalised as the loop does, for those fields alone.
+      canonicalised as the loop does, for those fields alone, an image at
+      a time.
 
     The round-1 unrolled graph at five servers (120 copies) crashed
     compiles at chunk 2048 and capped the elect5 run at ~3k orbits/s;
     the scan keeps the program size constant in |G|.  What an image
-    costs on the chip, and what it cost while the body regathered,
-    relabelled and re-sorted the state 120 times a step: PERF.md, PR 29.
+    costs on the chip (PERF.md section 6, PR 42): 60 us at full5's
+    344,064 lanes and 23 us at elect5's 155,648 (0.15-0.18 ns a lane: of
+    a block of eight, the product 78 us, the images' fusion 193 us, what
+    the compiler recomputes a block 80 us, at the wider); while each
+    image had its own multiply-reduce on the vector unit, 244 and 81 us
+    (PR 29-41); while the body regathered, relabelled and re-sorted the
+    state 120 times a step: PERF.md, PR 29.
     """
     import jax
     import jax.numpy as jnp
@@ -525,7 +631,9 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     linear = _linear_fields(axes)
     moved = tuple(f for f in lay.fields
                   if f not in linear and f not in _BAG)
-    table = jnp.asarray(_key_table(bounds, consts, linear, perms))
+    table = _key_table(bounds, consts, linear, perms)
+    _check_limb_range(table.shape[-1], _feature_cap(bounds, linear))
+    limbs = jnp.asarray(_key_limbs(table))               # [P, 2, 4, F]
     sluts = {k: jnp.asarray(v) for k, v in _server_luts(bounds).items()} \
         if server else None
     vluts = {k: jnp.asarray(v)
@@ -540,43 +648,66 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
         phi = _key_features(struct, linear, jnp)
         hi0, lo0, ct = ([struct[f][:, s] for s in range(lay.S)]
                         for f in _BAG)
+        Pb = _block_perms(P, phi.shape[1])
 
-        def body(best, k):
-            pi, qi = k // Q, k % Q
-            s1, s2 = _linear_sums(phi, table[pi], jnp)
+        def image(s1, s2, sl, vl):
+            # one image's key from its two sums over everything but the
+            # bag, and its rows of the lookup tables
             hi, lo = hi0, lo0
             if server:
-                hi = [_relabel_hi(w, sluts["src"][pi], sluts["dst"][pi],
-                                  jnp) for w in hi]
+                hi = [_relabel_hi(w, sl["src"], sl["dst"], jnp) for w in hi]
             if value:
-                vl = {f: a[qi] for f, a in vluts.items()}
                 lo = [_relabel_lo(w, vl, jnp) for w in lo]
             b1, b2 = _bag_sums(hi, lo, ct, cbag, jnp)
-            s1, s2 = s1 + b1, s2 + b2
-            if moved:
-                t = struct
-                if server:
-                    sl = {f: a[pi] for f, a in sluts.items()}
-                    t = {**t, **_permute_struct_batch(t, moved, sl, jnp)}
-                if value:
-                    t = {**t, **_permute_values_batch(t, moved, vl, jnp)}
-                if faithful:     # the elections sort (its bag sort is dead)
-                    t = jax.vmap(lambda s: st.canonicalize(s, jnp))(t)
-                m1, m2 = fpr.field_sums(t, consts, jnp, moved)
-                s1, s2 = s1 + m1, s2 + m2
-            hi, lo = fpr.finalise(s1, s2, jnp)
-            bh, bl = best
-            take = (hi < bh) | ((hi == bh) & (lo < bl))
-            return (jnp.where(take, hi, bh), jnp.where(take, lo, bl)), None
+            return fpr.finalise(s1 + b1, s2 + b2, jnp)
+
+        def moved_sums(sl, vl):
+            # what the fields that have to move add to one image's sums
+            t = struct
+            if server:
+                t = {**t, **_permute_struct_batch(t, moved, sl, jnp)}
+            if value:
+                t = {**t, **_permute_values_batch(t, moved, vl, jnp)}
+            if faithful:     # the elections sort (its bag sort is dead)
+                t = jax.vmap(lambda s: st.canonicalize(s, jnp))(t)
+            return fpr.field_sums(t, consts, jnp, moved)
+
+        def lex_min(a, b):
+            (ah, al), (bh, bl) = a, b
+            take = (bh < ah) | ((bh == ah) & (bl < al))
+            return jnp.where(take, bh, ah), jnp.where(take, bl, al)
+
+        def block(best, xs):
+            # the linear sums of P_b permutations in one product; their
+            # images side by side, [P_b, N]; the least of them
+            block_limbs, sl = xs
+            sums = _limb_sums(block_limbs, phi, jnp)     # [P_b, 2, N]
+
+            def under(best, vl):
+                s1, s2 = sums[:, 0], sums[:, 1]
+                if moved:    # an image at a time: they hold [N, n, L] arrays
+                    m1, m2 = jax.lax.map(lambda r: moved_sums(r, vl), sl) \
+                        if server else moved_sums(None, vl)
+                    s1, s2 = s1 + m1, s2 + m2
+                hi, lo = jax.vmap(image, in_axes=(0, 0, 0, None))(
+                    s1, s2, sl, vl)
+                top = jnp.uint32(0xFFFFFFFF)
+                least = jax.lax.reduce((hi, lo), (top, top), lex_min, (0,))
+                return lex_min(best, least), None
+
+            if value:
+                return jax.lax.scan(under, best, vluts)
+            return under(best, None)
 
         # derive the +inf init from the input so it inherits the input's
         # varying manual axes — a constant-built carry breaks the scan
         # type match when this runs inside shard_map (CP lane sharding)
         top = jnp.zeros_like(struct["role"][:, 0]).astype(jnp.uint32) \
             | jnp.uint32(0xFFFFFFFF)
-        init = (top, top)
-        (bh, bl), _ = jax.lax.scan(body, init,
-                                   jnp.arange(P * Q, dtype=jnp.int32))
+        blocks = jax.tree.map(
+            lambda a: a.reshape((P // Pb, Pb) + a.shape[1:]),
+            (limbs, sluts))
+        (bh, bl), _ = jax.lax.scan(block, (top, top), blocks)
         return bh, bl
 
     return orbit_fp

@@ -421,16 +421,26 @@ def test_key_table_and_ranked_bag_equal_the_permuted_packed_row(
     assert perms[-1] == tuple(reversed(range(bounds.n_servers)))
     fields = sym._linear_fields(("Server",))
     phi = sym._key_features(batch, fields, np)
-    assert phi.dtype == np.uint8 and phi.shape[1] == len(vecs)
+    assert phi.dtype == np.int8 and phi.shape[1] == len(vecs)
+    assert 0 <= phi.min() and phi.max() <= sym._feature_cap(bounds, fields)
     table = sym._key_table(bounds, consts, fields,
                            tuple(perms[i] for i in picked))
     assert table.shape == (len(picked), 2, phi.shape[0])
+    # the limb table the device multiplies: the same two sums, all of the
+    # picked permutations in one product
+    limbs = sym._key_limbs(table)
+    assert limbs.dtype == np.int8 \
+        and limbs.shape == (len(picked), 2, sym._N_LIMBS, phi.shape[0])
+    limb_sums = sym._limb_sums(limbs, phi, np)
+    assert limb_sums.dtype == np.uint32
     luts = sym._server_luts(bounds)
     fc = fpr.field_constants(lay.shapes, consts)
     cbag = np.stack([fc[f] for f in sym._BAG], axis=1)
     slots = {f: [batch[f][:, s] for s in range(lay.S)] for f in sym._BAG}
-    for row, i in zip(table, picked):
+    for at, (row, i) in enumerate(zip(table, picked)):
         s1, s2 = sym._linear_sums(phi, row, np)
+        assert (limb_sums[at, 0] == s1).all()
+        assert (limb_sums[at, 1] == s2).all()
         hi = [sym._relabel_hi(w, luts["src"][i], luts["dst"][i], np)
               for w in slots["msgHi"]]
         b1, b2 = sym._bag_sums(hi, slots["msgLo"], slots["msgCount"], cbag,
@@ -444,6 +454,93 @@ def test_key_table_and_ranked_bag_equal_the_permuted_packed_row(
                 == (int(want[0]), int(want[1])), (perms[i], k)
 
 
+# F = 4n + 2nL + 5n^2 of the benchmark's configurations and of six servers
+_LIMB_SHAPES = {"flagship3": (3, 2), "elect5": (5, 1), "full5": (5, 2),
+                "six-servers": (6, 2)}
+
+
+@pytest.mark.parametrize("constants", ["all-ones", "top-bit", "zero",
+                                       "random"])
+@pytest.mark.parametrize("name", list(_LIMB_SHAPES))
+def test_limb_sums_equal_linear_sums_at_the_extremes(name, constants):
+    """The device's form of the linear sums, in NumPy alone: the table of
+    permuted constants as four balanced base-256 digits (``int8``), one
+    int32 matrix product with the features, the digits shifted home and
+    added in uint32 — the same word, on every bit, as ``_linear_sums``'
+    multiply-reduce in uint32.  At the extremes: every feature at the cap
+    ``config.Bounds`` allows (63) and at the most an ``int8`` holds
+    (127), constants whose digits all carry (0xFFFFFFFF), whose top digit
+    is the one negative one (0x80000000), zero and random."""
+    n, L = _LIMB_SHAPES[name]
+    F = 4 * n + 2 * n * L + 5 * n * n
+    rng = np.random.default_rng(F)
+    table = {"all-ones": np.full((3, 2, F), 0xFFFFFFFF, np.uint32),
+             "top-bit": np.full((3, 2, F), 0x80000000, np.uint32),
+             "zero": np.zeros((3, 2, F), np.uint32),
+             "random": rng.integers(0, 2**32, (3, 2, F), dtype=np.uint32),
+             }[constants]
+    limbs = sym._key_limbs(table)
+    assert limbs.dtype == np.int8 and limbs.shape == (3, 2, 4, F)
+    # the digits are the constant (mod 2^32)
+    back = sum(limbs[:, :, l].astype(np.int64) << (8 * l) for l in range(4))
+    assert ((back % 2**32).astype(np.uint32) == table).all()
+    lanes = 64
+    for cap in (63, 127):
+        sym._check_limb_range(F, cap)
+        for phi in (np.full((F, lanes), cap, np.int8),
+                    rng.integers(0, cap + 1, (F, lanes)).astype(np.int8)):
+            got = sym._limb_sums(limbs, phi, np)
+            assert got.dtype == np.uint32 and got.shape == (3, 2, lanes)
+            for p in range(3):
+                want = sym._linear_sums(phi, table[p], np)
+                assert (got[p, 0] == want[0]).all(), (name, constants, cap)
+                assert (got[p, 1] == want[1]).all(), (name, constants, cap)
+
+
+def test_limb_range_check_refuses_what_would_not_be_exact():
+    """``build_orbit_fp`` checks once, at build time, that the product is
+    exact: a feature past 127 does not fit the ``int8`` operand, and F
+    features at the cap times a digit of 128 must stay inside ``int32``.
+    The schemas in the tree are far inside both (full5: 165 features
+    capped at 3)."""
+    for bounds in (_B3S, _ELECT5, _FULL5):
+        cap = sym._feature_cap(bounds, sym._linear_fields(("Server",)))
+        assert cap == max(bounds.term_cap, bounds.log_cap + 1,
+                          bounds.n_values)
+        n, L = bounds.n_servers, bounds.log_cap
+        sym._check_limb_range(4 * n + 2 * n * L + 5 * n * n, cap)
+    sym._check_limb_range(165, 127)
+    with pytest.raises(ValueError, match="int8"):
+        sym._check_limb_range(165, 128)
+    most = (2**31 - 1) // (63 * 128)              # 266,305 features
+    sym._check_limb_range(most, 63)
+    with pytest.raises(ValueError, match="int32"):
+        sym._check_limb_range(most + 1, 63)
+
+
+def _lowered_scan(bounds, lanes, axes=("Server",)):
+    """``build_orbit_fp`` lowered for ``lanes`` states of ``bounds``: the
+    StableHLO text and the struct's shapes."""
+    import jax
+    import jax.numpy as jnp
+    from raft_tla_tpu.ops import fingerprint as fpr
+
+    lay = st.Layout.of(bounds)
+    consts = jnp.asarray(fpr.lane_constants(lay.width))
+    struct = {f: jax.ShapeDtypeStruct((lanes,) + tuple(shape), jnp.int32)
+              for f, shape in lay.shapes.items()}
+    fn = sym.build_orbit_fp(bounds, axes, consts, False)
+    return jax.jit(fn).lower(struct).as_text(), struct
+
+
+def _dot_generals(text):
+    """(lhs, rhs, result) tensor types of every ``stablehlo.dot_general``."""
+    import re
+    return re.findall(
+        r"stablehlo\.dot_general .*: \(tensor<([^>]*)>, tensor<([^>]*)>\)"
+        r" -> tensor<([^>]*)>", text)
+
+
 def test_scan_body_builds_no_packed_row():
     """The row must not come back: lowered at 5 servers, the orbit scan
     holds no ``[lanes, W]`` tensor at all, so no ``concatenate`` (nor
@@ -454,19 +551,14 @@ def test_scan_body_builds_no_packed_row():
 
     import jax
     import jax.numpy as jnp
-    from raft_tla_tpu.ops import fingerprint as fpr
-    from raft_tla_tpu.ops import state as st
 
     lanes = 24
     for bounds in (_ELECT5, _FULL5):
         lay = st.Layout.of(bounds)
-        consts = jnp.asarray(fpr.lane_constants(lay.width))
-        struct = {f: jax.ShapeDtypeStruct((lanes,) + tuple(shape), jnp.int32)
-                  for f, shape in lay.shapes.items()}
         row = re.compile(rf"tensor<{lanes}x{lay.width}xu?i32>")
-        fn = sym.build_orbit_fp(bounds, ("Server",), consts, False)
-        text = jax.jit(fn).lower(struct).as_text()
+        text, struct = _lowered_scan(bounds, lanes)
         assert "stablehlo.while" in text
+        assert len(_dot_generals(text)) == 1
         assert not row.search(text), row.pattern
         packed = jax.jit(jax.vmap(lambda s: st.pack(s, jnp))) \
             .lower(struct).as_text()
@@ -475,46 +567,55 @@ def test_scan_body_builds_no_packed_row():
 
 def test_scan_moves_no_state_data():
     """Nor may the state move (PR 29): lowered at 5 servers under Server
-    symmetry, the orbit scan — its ``stablehlo.while`` body and what is
-    hoisted in front of it — holds no ``gather`` (the parent regathered
-    every ``[lanes, n]`` / ``[lanes, n, n]`` field by the inverse
-    permutation, once an image), no ``scatter`` /
+    symmetry, the orbit scan — its loop over blocks of permutations and
+    what is hoisted in front of it — holds no ``gather`` (PR 29's parent
+    regathered every ``[lanes, n]`` / ``[lanes, n, n]`` field by the
+    inverse permutation, once an image), no ``scatter`` /
     ``dynamic_update_slice`` and no ``sort`` (the message sort network's
     ``.at[..., i].set`` lowers to scatters, 21 ``dynamic-update-slice``
-    an image in the chip's program): each image's key is a slice of the
-    table of permuted constants, one multiply-reduce and a ranking of
-    the bag.  The forms that do move
-    data show all of it (the test can see): ``canonicalize`` lowered
-    alone, and the same scan under Value symmetry, where ``logVal`` keeps
-    the data-moving path."""
+    an image in the chip's program).  Since PR 42 the linear fields'
+    share of a block of eight images' keys is **one** ``dot_general``:
+    the block's ``int8`` limbs ``[8 * 2 * 4, F]`` times the ``int8``
+    feature matrix ``[F, lanes]``, accumulated in ``int32`` — no
+    multiply-reduce an image is left — and the rest of an image is a
+    ranking of the bag; the loop over the fifteen blocks is the one
+    ``stablehlo.while``.  The forms that do move data show all of it (the
+    test can see): ``canonicalize`` lowered alone, and the same scan
+    under Value symmetry, where ``logVal`` keeps the data-moving path."""
     import re
 
     import jax
     import jax.numpy as jnp
-    from raft_tla_tpu.ops import fingerprint as fpr
 
-    lanes = 24
-    for bounds in (_ELECT5, _FULL5):
+    assert sym._block_perms(120, 4096 * 84) == 8      # full5's dense step
+    assert sym._block_perms(6, 4096 * 42) == 6        # flagship3's
+    assert sym._block_perms(720, 1 << 15) == 8
+    # a product that would pass _PRODUCT_BYTES takes fewer permutations
+    assert sym._block_perms(120, 1 << 20) == 3
+    assert sym._block_perms(120, 1 << 24) == 1
+    for bounds, lanes in ((_ELECT5, 24), (_FULL5, 24), (_FULL5, 4096 * 84)):
         lay = st.Layout.of(bounds)
         n, L = lay.n, lay.L
-        consts = jnp.asarray(fpr.lane_constants(lay.width))
-        struct = {f: jax.ShapeDtypeStruct((lanes,) + tuple(shape), jnp.int32)
-                  for f, shape in lay.shapes.items()}
-        text = jax.jit(sym.build_orbit_fp(bounds, ("Server",), consts,
-                                          False)).lower(struct).as_text()
-        assert "stablehlo.while" in text
+        text, struct = _lowered_scan(bounds, lanes)
+        assert text.count("stablehlo.while") == 1
         for op in ("gather", "dynamic_update_slice", "stablehlo.sort",
                    "scatter"):
             assert op not in text, op
-        # the one array that follows the lanes into the body is the
+        # the one array that follows the lanes into the product is the
         # byte-wide feature matrix, lanes minor
         F = 4 * n + 2 * n * L + 5 * n * n
-        assert f"tensor<{F}x{lanes}xui8>" in text
+        assert f"tensor<{F}x{lanes}xi8>" in text
+        assert "xui8>" not in text
+        assert _dot_generals(text) == [
+            (f"64x{F}xi8", f"{F}x{lanes}xi8", f"64x{lanes}xi32")]
+        # no multiply-reduce over the features is left: nothing but the
+        # product's operand holds both F and the lanes
+        assert not re.search(rf"tensor<{F}x{lanes}xu?i32>", text)
+        if lanes > 24:
+            continue
         moved = jax.jit(jax.vmap(lambda s: st.canonicalize(s, jnp))) \
             .lower(struct).as_text()
         assert "stablehlo.scatter" in moved
-        valued = jax.jit(sym.build_orbit_fp(
-            bounds, ("Server", "Value"), consts, False)) \
-            .lower(struct).as_text()
+        valued, _ = _lowered_scan(bounds, lanes, ("Server", "Value"))
         assert re.search(
             rf"stablehlo\.gather.*tensor<{lanes}x{n}x{L}xi32>", valued)
