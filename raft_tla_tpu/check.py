@@ -42,11 +42,15 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("cfg", help="TLC model config (e.g. the reference "
                                "raft.cfg); binds Server/Value/INVARIANT")
     p.add_argument("--spec", default="full",
-                   choices=("full", "election", "replication", "twophase"),
+                   choices=("full", "election", "replication", "twophase",
+                            "paxos"),
                    help="loaded spec: a Raft Next-disjunct subset (default: "
-                        "full raft.tla:454-465) or the bundled twophase "
-                        "(two-phase commit, frontend-compiled; --engine "
-                        "host or ddd; cfg binds CONSTANT RM)")
+                        "full raft.tla:454-465), the bundled twophase "
+                        "(two-phase commit; cfg binds CONSTANT RM) or the "
+                        "bundled paxos (single-decree Paxos; cfg binds "
+                        "Acceptor, Value and Quorum, a set of sets; "
+                        "--max-term is the maximum ballot); both "
+                        "frontend-compiled, --engine host or ddd")
     p.add_argument("--engine", default="device",
                    choices=("device", "paged", "streamed", "ddd", "shard",
                             "pagedshard", "ddd-shard", "host", "ref"),
@@ -755,8 +759,7 @@ def main(argv=None) -> int:
     if not model.is_raft:
         print(f"raft_tla_tpu {__import__('raft_tla_tpu').__version__} — "
               f"exhaustive check of spec {args.spec} (frontend-compiled)")
-        print(f"Universe: {b.n_servers} resource managers "
-              f"(from {args.cfg})")
+        print(f"Universe: {model.universe_line(b)} (from {args.cfg})")
         if dev_line:
             print(dev_line)
         print(f"Invariants: {', '.join(config.invariants) or '(none)'}")

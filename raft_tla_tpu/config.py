@@ -72,6 +72,12 @@ class Bounds:
     # the set (it is derived-finite under the constraint); exceeding the
     # capacity is a loud engine failure, never a clamp (SURVEY §4.5).
     max_elections: int = 6
+    # Further CONSTANTS of a frontend spec's universe that are tables, not
+    # set sizes: ``(name, rows as nested tuples)`` pairs, held to the spec's
+    # ``schema.Const`` declarations when a step is built (Paxos' ``Quorum``,
+    # one 0/1 row a quorum).  Raft binds none.  Like every field here it
+    # joins the checkpoint digest when set.
+    constants: tuple = ()
 
     def __post_init__(self) -> None:
         if not (1 <= self.n_servers <= _MAX_SERVERS):

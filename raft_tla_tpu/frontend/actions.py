@@ -39,6 +39,20 @@ def _as_array_bool(v):
     return jnp.bool_(v) if isinstance(v, bool) else v
 
 
+def _set_at(arr, idx, val):
+    """arr with arr[idx...] = val: ``_set1`` / ``_set2`` at any rank (the
+    same one-hot form, one mask an axis)."""
+    if len(idx) != arr.ndim:
+        raise ValueError(f"SetAt: {len(idx)} indices for {arr.ndim} axes")
+    mask = None
+    for axis, i in enumerate(idx):
+        shape = [1] * arr.ndim
+        shape[axis] = arr.shape[axis]
+        hot = jnp.reshape(jnp.arange(arr.shape[axis]), shape) == i
+        mask = hot if mask is None else (mask & hot)
+    return jnp.where(mask, val, arr)
+
+
 def _apply_update(ctx, out, u):
     """One field write on the branch struct; values read the pre-state
     through ``ctx`` (the hand kernels' functional idiom)."""
@@ -49,6 +63,8 @@ def _apply_update(ctx, out, u):
         return K._set_row(arr, u.i.ev(ctx), u.val.ev(ctx))
     elif isinstance(u, E.Set2):
         written = K._set2(arr, u.i.ev(ctx), u.j.ev(ctx), u.val.ev(ctx))
+    elif isinstance(u, E.SetAt):
+        written = _set_at(arr, [e.ev(ctx) for e in u.idx], u.val.ev(ctx))
     else:
         raise TypeError(f"unknown update node {type(u).__name__}")
     cond = getattr(u, "cond", None)
@@ -109,12 +125,14 @@ def _branch_effects(ctx, s, br):
     return out, ovf
 
 
-def _compile_action(adef):
+def _compile_action(adef, const_tables=None):
     """ActionDef -> kernel(bounds, s, *params) with the grouped_dispatch
-    contract."""
+    contract; ``const_tables`` are the schema's bound constant tables,
+    closed over."""
 
     def kern(bounds, s, *args):
-        ctx = E.Ctx(bounds, s, dict(zip(adef.params, args)), jnp)
+        ctx = E.Ctx(bounds, s, dict(zip(adef.params, args)), jnp,
+                    const_tables)
         valid = _as_array_bool(adef.valid.ev(ctx))
         if len(adef.branches) == 1 and adef.branches[0].guard is None:
             out, contrib = _branch_effects(ctx, s, adef.branches[0])
@@ -139,20 +157,20 @@ def _compile_action(adef):
     return kern
 
 
-def compile_kernels(defs):
+def compile_kernels(defs, const_tables=None):
     """IR table -> ``{family: (kernel, params)}``, the shape
     ``grouped_dispatch(..., family_kernels=...)`` consumes."""
-    return {adef.family: (_compile_action(adef), adef.params)
+    return {adef.family: (_compile_action(adef, const_tables), adef.params)
             for adef in defs}
 
 
-def build_schema_expand(schema, defs, table, bounds):
+def build_schema_expand(schema, defs, table, bounds, const_tables=None):
     """The expand half of :func:`build_schema_step` on its own:
     ``expand(struct) -> (succs[A, ...], valid[A], ovf[A])`` in
     action_table order — the same contract as ``kernels.build_expand``,
     which is what the simulation engines vmap per walker (they sample
     one lane per step instead of fingerprinting the whole fan-out)."""
-    fam_kernels = compile_kernels(defs)
+    fam_kernels = compile_kernels(defs, const_tables)
     groups = K.group_instances(table)
 
     def expand(s):
@@ -167,7 +185,8 @@ def build_schema_expand(schema, defs, table, bounds):
     return expand
 
 
-def build_schema_step(schema, defs, table, bounds, predicates=()):
+def build_schema_step(schema, defs, table, bounds, predicates=(),
+                      const_tables=None):
     """Generic fused step for a schema-declared spec.
 
     ``table`` is the action-instance list (objects with ``.family`` and
@@ -179,10 +198,12 @@ def build_schema_step(schema, defs, table, bounds, predicates=()):
     inv_ok, con_ok.  Canonicalization is the identity (a schema spec
     declares no bag-slot permutation) and ``con_ok`` is all-true; both
     are points where a future schema hook can slot in.
+    ``const_tables`` are the schema's constant tables as the run binds
+    them (``Schema.bind_consts``), read by ``ConstTab`` nodes.
     """
     lay = schema.layout(bounds)
     consts = jnp.asarray(fpr.lane_constants(lay.width))
-    expand = build_schema_expand(schema, defs, table, bounds)
+    expand = build_schema_expand(schema, defs, table, bounds, const_tables)
 
     def step(vecs):
         # the step's stage scopes (kernels.STAGE_SCOPES), as build_step

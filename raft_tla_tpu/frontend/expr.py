@@ -16,12 +16,19 @@ it:
   interval domain (``analysis/intervals``) to *generate* speclint's
   Pass-1 transfer twins, cross-checked against the hand-written ones.
 
-Expression values are scalars (per-action-instance); array effects live
-in the Update/Bag nodes.  Every node carries both a concrete evaluator
-(``ev``) and an interval transfer (``iv``); :class:`Intrinsic` is the
-escape hatch for aggregations the scalar language cannot express (e.g.
-Raft's quorum-max-agree) — a compiler builtin with a declared transfer,
-exactly like the relational ``facts`` a :class:`PackMsg` may declare.
+Expression values are scalars (per-action-instance) or, since Paxos, small
+arrays over a field's axes: :class:`Sel` reads a field with some axes
+selected and the rest kept, :class:`ConstTab` reads a constant table the
+schema declares (``Quorum``), :class:`Reduce` folds an axis (``any`` /
+``all`` / ``max`` / ``min``), :class:`Exists` is the bounded existential
+over a table's rows (``\\E Q \\in Quorum``), and the arithmetic and
+comparison nodes broadcast.  Array effects live in the Update/Bag nodes
+(:class:`SetAt` writes one cell at computed indices).  Every node carries
+both a concrete evaluator (``ev``) and an interval transfer (``iv``);
+:class:`Intrinsic` is the escape hatch for aggregations the language cannot
+express (e.g. Raft's quorum-max-agree) — a compiler builtin with a declared
+transfer, exactly like the relational ``facts`` a :class:`PackMsg` may
+declare.
 """
 
 from __future__ import annotations
@@ -44,11 +51,18 @@ class Infeasible(Exception):
 class Ctx:
     """Concrete evaluation context: one action instance on one state."""
 
-    __slots__ = ("bounds", "s", "params", "xp", "_msg")
+    __slots__ = ("bounds", "s", "params", "xp", "consts", "_msg")
 
-    def __init__(self, bounds, s, params, xp):
+    def __init__(self, bounds, s, params, xp, consts=None):
         self.bounds, self.s, self.params, self.xp = bounds, s, params, xp
+        self.consts = consts or {}      # the schema's bound constant tables
         self._msg = None
+
+    def bind(self, name, value) -> "Ctx":
+        """This context with one more parameter (a quantifier's bound
+        variable)."""
+        return Ctx(self.bounds, self.s, {**self.params, name: value},
+                   self.xp, self.consts)
 
     def msg_words(self):
         """(msgHi[slot], msgLo[slot]) of the instance's ``slot`` param."""
@@ -63,14 +77,16 @@ class IvCtx:
     the message envelope, per-param declared intervals, and the active
     branch's mtype scope for MsgField reads."""
 
-    __slots__ = ("bounds", "env", "menv", "param_iv", "mtype")
+    __slots__ = ("bounds", "env", "menv", "param_iv", "mtype", "const_iv")
 
-    def __init__(self, bounds, env, menv, param_iv, mtype=None):
+    def __init__(self, bounds, env, menv, param_iv, mtype=None,
+                 const_iv=None):
         self.bounds = bounds
         self.env = env
         self.menv = menv
         self.param_iv = param_iv
         self.mtype = mtype
+        self.const_iv = const_iv or {}  # constant table -> declared interval
 
 
 # ---------------------------------------------------------------------------
@@ -150,7 +166,24 @@ _EV = {
     "bor": lambda a, b: a | b,
     "<<": lambda a, b: a << b,
     ">>": lambda a, b: a >> b,
+    "//": lambda a, b: a // b,
+    "%": lambda a, b: a % b,
 }
+
+
+def _iv_div(op):
+    """Transfer of ``//`` / ``%`` for a non-negative dividend and a positive
+    divisor (what an index computation needs); anything else is refused
+    rather than bounded wrongly."""
+    def transfer(a, b):
+        if a.lo < 0 or b.lo < 1:
+            raise ValueError(f"{op!r} takes a non-negative dividend and a "
+                             f"positive divisor, got {a} {op} {b}")
+        if op == "//":
+            return iv.Interval(a.lo // b.hi, a.hi // b.lo)
+        return iv.Interval(0, min(a.hi, b.hi - 1))
+    return transfer
+
 
 _IV = {
     "+": lambda a, b: a + b,
@@ -160,6 +193,8 @@ _IV = {
     "bor": lambda a, b: a.or_(b),
     "<<": lambda a, b: iv.Interval(a.lo << b.lo, a.hi << b.hi),
     ">>": lambda a, b: iv.Interval(a.lo >> b.hi, a.hi >> b.lo),
+    "//": _iv_div("//"),
+    "%": _iv_div("%"),
 }
 
 
@@ -320,6 +355,157 @@ class Intrinsic:
 
 
 # ---------------------------------------------------------------------------
+# Array-valued expressions: reads over a field's axes, constant tables,
+# reductions and the bounded existential
+
+
+def _is_jnp(xp) -> bool:
+    return xp.__name__ == "jax.numpy"
+
+
+def _take(a, i, axis: int, xp):
+    """``a`` with ``axis`` selected at the scalar ``i`` (one-hot form, like
+    ``kernels._set1``: an elementwise mask and a fold, no gather, so a
+    traced or vmapped ``i`` costs what a constant one does)."""
+    shape = [1] * a.ndim
+    shape[axis] = a.shape[axis]
+    hot = xp.reshape(xp.arange(a.shape[axis]), shape) == i
+    return xp.sum(xp.where(hot, a, 0), axis=axis, dtype=a.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Sel:
+    """Array-valued state read: ``idx`` has one entry an axis of the field,
+    ``None`` to keep the axis and a scalar expression to select it
+    (``msg1b[:, b, :]`` is ``Sel("msg1b", (None, b, None))``)."""
+    field: str
+    idx: tuple
+
+    def ev(self, ctx):
+        a = ctx.s[self.field]
+        if len(self.idx) != a.ndim:
+            raise ValueError(f"Sel({self.field!r}): {len(self.idx)} "
+                             f"indices for {a.ndim} axes")
+        for axis in reversed(range(a.ndim)):
+            if self.idx[axis] is not None:
+                a = _take(a, self.idx[axis].ev(ctx), axis, ctx.xp)
+        return a
+
+    def iv(self, ictx):
+        return ictx.env[self.field]
+
+
+@dataclasses.dataclass(frozen=True)
+class ConstTab:
+    """Array-valued read of a constant table the schema declares
+    (``schema.Const``), as the run bound it."""
+    name: str
+
+    def ev(self, ctx):
+        return ctx.xp.asarray(ctx.consts[self.name])
+
+    def iv(self, ictx):
+        return ictx.const_iv[self.name]
+
+
+@dataclasses.dataclass(frozen=True)
+class Iota:
+    """``0..n-1`` along one axis; ``n`` is static (a ``Lit`` / ``Dim``
+    expression)."""
+    n: object
+
+    def ev(self, ctx):
+        return ctx.xp.arange(int(self.n.ev(ctx)))
+
+    def iv(self, ictx):
+        return iv.Interval(0, max(self.n.iv(ictx).hi - 1, 0))
+
+
+@dataclasses.dataclass(frozen=True)
+class Lift:
+    """``a`` with ``k`` unit axes appended, so that it broadcasts along the
+    leading axes of a wider operand (``inQ[a]`` against ``m[a, k]``)."""
+    a: object
+    k: int = 1
+
+    def ev(self, ctx):
+        v = ctx.xp.asarray(self.a.ev(ctx))
+        return ctx.xp.reshape(v, v.shape + (1,) * self.k)
+
+    def iv(self, ictx):
+        return self.a.iv(ictx)
+
+
+_REDUCERS = ("any", "all", "max", "min")
+
+
+@dataclasses.dataclass(frozen=True)
+class Reduce:
+    """Fold an array over ``axis`` (``None``: every axis) with ``any`` /
+    ``all`` (of booleans) or ``max`` / ``min`` (of integers)."""
+    fn: str
+    a: object
+    axis: object = None
+
+    def __post_init__(self):
+        if self.fn not in _REDUCERS:
+            raise ValueError(f"unknown reducer {self.fn!r} (known: "
+                             f"{', '.join(_REDUCERS)})")
+
+    def ev(self, ctx):
+        return getattr(ctx.xp, self.fn)(self.a.ev(ctx), axis=self.axis)
+
+    def iv(self, ictx):
+        a = self.a.iv(ictx)
+        return iv.BOOL if self.fn in ("any", "all") else a
+
+
+@dataclasses.dataclass(frozen=True)
+class Exists:
+    """``\\E var \\in table : body``, ``table`` an array with one row an
+    element (a :class:`ConstTab`): ``body`` is evaluated with ``var`` bound
+    to each row in turn (``Param(var)`` reads it) and the results are
+    or-ed.  The table's length is static, so the existential is unrolled."""
+    var: str
+    table: object
+    body: object
+
+    def ev(self, ctx):
+        rows = self.table.ev(ctx)
+        out = None
+        for q in range(rows.shape[0]):
+            hit = self.body.ev(ctx.bind(self.var, rows[q]))
+            out = hit if out is None else (out | hit)
+        return out
+
+    def iv(self, ictx):
+        inner = IvCtx(ictx.bounds, ictx.env, ictx.menv,
+                      {**ictx.param_iv, self.var: self.table.iv(ictx)},
+                      ictx.mtype, ictx.const_iv)
+        self.body.iv(inner)         # an infeasible body is the caller's
+        return iv.BOOL
+
+
+@dataclasses.dataclass(frozen=True)
+class Scope:
+    """``a`` lowered under a named scope (``jax.named_scope``: metadata
+    for the device trace, no computation), so that a profile splits it
+    from the stage it is nested in."""
+    name: str
+    a: object
+
+    def ev(self, ctx):
+        if not _is_jnp(ctx.xp):
+            return self.a.ev(ctx)
+        import jax
+        with jax.named_scope(self.name):
+            return self.a.ev(ctx)
+
+    def iv(self, ictx):
+        return self.a.iv(ictx)
+
+
+# ---------------------------------------------------------------------------
 # Field updates (array effects; values read the PRE-state, like the
 # functional hand kernels)
 
@@ -349,6 +535,17 @@ class Set2:
     field: str
     i: object
     j: object
+    val: object
+    cond: object = None
+
+
+@dataclasses.dataclass(frozen=True)
+class SetAt:
+    """``field[idx...] := val`` (optionally only when ``cond``): one cell
+    of a field of any rank, every index a scalar expression (a message
+    flag at the indices the message's fields compute)."""
+    field: str
+    idx: tuple
     val: object
     cond: object = None
 

@@ -18,13 +18,21 @@ Grammar (TLA+ ASCII operators, loosest to tightest):
     term   :=  unary ("*" unary)*
     unary  :=  "-" unary | atom
     atom   :=  INT | TRUE | FALSE | NAME | NAME "[" expr "]"
-            |  ("any" | "all" | "count" | "min" | "max") "(" expr ")"
+            |  ("any" | "all" | "count" | "min" | "max")
+               "(" expr ["," ["-"] INT] ")"
+            |  "dot" "(" expr "," expr ")"
             |  "(" expr ")"
 
-NAME reads a schema field elementwise; comparisons and arithmetic
-broadcast; a non-scalar boolean result is implicitly universally
-quantified (``xp.all``) at the top — the quantifier-free reading of
-TLA+'s ``\\A i \\in Server: P(i)``.  ``count`` sums a boolean array.
+NAME reads a schema field elementwise, or a constant table the spec's
+schema declares (``Quorum``: bound when the predicate is compiled, no part
+of the state); comparisons and arithmetic broadcast; a non-scalar boolean
+result is implicitly universally quantified (``xp.all``) at the top — the
+quantifier-free reading of TLA+'s ``\\A i \\in Server: P(i)``.  ``count``
+sums a boolean array.  A reducer folds every axis, or the one axis its
+second argument names; ``dot(A, B)`` contracts the last axis of ``A`` with
+the first of ``B`` (``dot(Quorum, 1 - msg2b)[q, b, v]``: how many members
+of quorum q have not voted for v in ballot b — the bounded reading of
+``\\A a \\in Q``).
 
 Everything is statically typed (BOOL vs INT) so malformed invariants
 fail at admission with a position-carrying ValueError, never inside a
@@ -42,7 +50,7 @@ _TOKEN = re.compile(r"""
     \s*(?:
       (?P<int>\d+)
     | (?P<name>[A-Za-z_]\w*)
-    | (?P<op>=>|\\/|/\\|/=|<=|>=|[~=<>+\-*()\[\]])
+    | (?P<op>=>|\\/|/\\|/=|<=|>=|[~=<>+\-*()\[\],])
     )""", re.VERBOSE)
 
 _REDUCERS = ("any", "all", "count", "min", "max")
@@ -103,6 +111,21 @@ class Name:
 
     def reads(self):
         return frozenset((self.field,))
+
+
+@dataclasses.dataclass(frozen=True)
+class ConstArr:
+    """A constant table of the spec's schema, bound at compile time
+    (nested tuples, so the node stays hashable); reads no state field."""
+    name: str
+    value: tuple
+    kind: str = INT
+
+    def ev(self, struct, xp):
+        return xp.asarray(self.value, dtype="int32")
+
+    def reads(self):
+        return frozenset()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,30 +201,51 @@ class Reduce:
     fn: str
     a: object
     kind: str = INT
+    axis: object = None           # None: every axis
 
     def ev(self, struct, xp):
         v = self.a.ev(struct, xp)
         if self.fn == "any":
-            return xp.any(v)
+            return xp.any(v, axis=self.axis)
         if self.fn == "all":
-            return xp.all(v)
+            return xp.all(v, axis=self.axis)
         if self.fn == "count":
             # sum of a boolean array; int32 keeps it on the state dtype
-            return xp.sum(xp.asarray(v, dtype="int32"))
+            return xp.sum(xp.asarray(v, dtype="int32"), axis=self.axis)
         if self.fn == "min":
-            return xp.min(v)
-        return xp.max(v)
+            return xp.min(v, axis=self.axis)
+        return xp.max(v, axis=self.axis)
 
     def reads(self):
         return self.a.reads()
 
 
+@dataclasses.dataclass(frozen=True)
+class Dot:
+    """``dot(a, b)``: the last axis of ``a`` contracted with the first of
+    ``b``, as a broadcast product and a fold (small integer tables: no
+    matrix unit is worth asking for)."""
+    a: object
+    b: object
+    kind: str = INT
+
+    def ev(self, struct, xp):
+        a = xp.asarray(self.a.ev(struct, xp))
+        b = xp.asarray(self.b.ev(struct, xp))
+        wide = xp.reshape(a, a.shape + (1,) * (b.ndim - 1))
+        return xp.sum(wide * b, axis=a.ndim - 1)
+
+    def reads(self):
+        return self.a.reads() | self.b.reads()
+
+
 class _Parser:
-    def __init__(self, text: str, fields=None):
+    def __init__(self, text: str, fields=None, consts=None):
         self.text = text
         self.toks = _tokenize(text)
         self.i = 0
         self.fields = None if fields is None else tuple(fields)
+        self.consts = dict(consts or {})
 
     def peek(self):
         return self.toks[self.i]
@@ -318,12 +362,35 @@ class _Parser:
             if name in _REDUCERS:
                 self.expect("(")
                 arg = self.impl()
+                axis = None
+                if self.peek()[:2] == ("op", ","):
+                    self.next()
+                    sign = 1
+                    if self.peek()[:2] == ("op", "-"):
+                        self.next()
+                        sign = -1
+                    t_ax = self.next()
+                    if t_ax[0] != "int":
+                        raise self.err("a reducer's axis is an integer "
+                                       "literal", t_ax)
+                    axis = sign * int(t_ax[1])
                 self.expect(")")
                 if name in ("any", "all"):
-                    return Reduce(name, self.want_bool(arg, name), BOOL)
+                    return Reduce(name, self.want_bool(arg, name), BOOL,
+                                  axis)
                 if name == "count":
-                    return Reduce(name, self.want_bool(arg, name), INT)
-                return Reduce(name, self.want_int(arg, name), INT)
+                    return Reduce(name, self.want_bool(arg, name), INT,
+                                  axis)
+                return Reduce(name, self.want_int(arg, name), INT, axis)
+            if name == "dot" and self.peek()[:2] == ("op", "("):
+                self.next()
+                lhs = self.want_int(self.impl(), "dot")
+                self.expect(",")
+                rhs = self.want_int(self.impl(), "dot")
+                self.expect(")")
+                return Dot(lhs, rhs)
+            if name in self.consts:
+                return ConstArr(name, _nested_tuple(self.consts[name]))
             if self.fields is not None and name not in self.fields:
                 raise self.err(
                     f"unknown field {name!r}; schema fields: "
@@ -341,6 +408,15 @@ class _Parser:
         raise self.err(f"unexpected {t[1] or 'end of input'!r}", t)
 
 
+def _nested_tuple(a):
+    """An array (or nested sequence) as nested tuples of ints."""
+    if hasattr(a, "tolist"):
+        a = a.tolist()
+    if isinstance(a, (list, tuple)):
+        return tuple(_nested_tuple(x) for x in a)
+    return int(a)
+
+
 @dataclasses.dataclass(frozen=True)
 class Predicate:
     """A compiled predicate: ``ev(struct, xp)`` -> scalar bool (numpy or
@@ -355,14 +431,15 @@ class Predicate:
         return xp.all(v)
 
 
-def parse(text: str, fields=None):
+def parse(text: str, fields=None, consts=None):
     """Parse to an AST; ``fields`` (optional) enables unknown-field
-    errors at compile time instead of KeyErrors at probe time."""
-    return _Parser(text, fields).parse()
+    errors at compile time instead of KeyErrors at probe time; ``consts``
+    (``{name: array}``) are the constant tables a NAME may read."""
+    return _Parser(text, fields, consts).parse()
 
 
-def compile_predicate(text: str, fields=None) -> Predicate:
-    node = parse(text, fields)
+def compile_predicate(text: str, fields=None, consts=None) -> Predicate:
+    node = parse(text, fields, consts)
     if node.kind != BOOL:
         raise ValueError(
             f"predicate {text!r} is arithmetic, not boolean — an "

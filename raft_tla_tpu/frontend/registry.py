@@ -26,7 +26,8 @@ modules — zero behavior change), with ``ir-full`` / ``ir-election`` /
 ``ir-replication`` the same model stepped through
 ``frontend/raft_ir``-compiled kernels instead of the hand-written ones
 (pinned bit-identical by tests).  ``twophase`` resolves to the bundled
-two-phase-commit spec, compiled entirely from frontend declarations.
+two-phase-commit spec and ``paxos`` to single-decree Paxos, both compiled
+entirely from frontend declarations.
 
 Everything heavy imports inside methods: this module sits under
 ``frontend/__init__`` which ``models/spec.py``'s re-export pulls in, so
@@ -148,49 +149,22 @@ class RaftModel:
         return interp.apply_action(py, inst, bounds)
 
 
-class TwoPhaseModel:
-    """Bounded two-phase commit, compiled from frontend declarations
-    (``frontend/twophase``): schema layout, IR-built step, predicate
-    invariants.  ``bounds.n_servers`` is the RM count; the other bound
-    knobs are inert for this state space."""
+class SchemaModel:
+    """What every model declared purely as frontend data shares: the
+    delegation of layout, action table, packed row, Init, row codec and
+    rendering to its declaration module (``_mod()``: a ``SCHEMA``, an
+    ``action_table``, ``init_state`` / ``to_vec`` / ``from_vec``,
+    ``render_state`` / ``render_trace``).  The step, the invariants and the
+    cfg mapping are each spec's own."""
 
-    name = "twophase"
-    sub = "twophase"
     is_raft = False
     use_ir = True
-    engines = ("host", "ddd", "simulate")
-
-    def _mod(self):
-        from raft_tla_tpu.frontend import twophase
-        return twophase
-
-    def _predicate(self, name: str):
-        from raft_tla_tpu.frontend.predicate import (compile_predicate,
-                                                     is_expression)
-        tp = self._mod()
-        text = tp.INVARIANTS.get(name)
-        if text is None:
-            if not is_expression(name):
-                raise ValueError(
-                    f"unknown twophase invariant {name!r} (known: "
-                    f"{', '.join(sorted(tp.INVARIANTS))}; or write a "
-                    "predicate expression over the state fields)")
-            text = name
-        return compile_predicate(text, fields=tp.SCHEMA.field_names)
 
     def layout(self, bounds):
         return self._mod().SCHEMA.layout(bounds)
 
     def action_table(self, bounds):
         return self._mod().action_table(bounds)
-
-    def build_step(self, config: CheckConfig):
-        from raft_tla_tpu.frontend import actions
-        tp = self._mod()
-        preds = tuple(self._predicate(nm) for nm in config.invariants)
-        return actions.build_schema_step(
-            tp.SCHEMA, tp.ACTIONS, tp.action_table(config.bounds),
-            config.bounds, predicates=preds)
 
     def bit_schema(self, bounds):
         from raft_tla_tpu.ops import bitpack
@@ -215,6 +189,66 @@ class TwoPhaseModel:
     def constraint_ok(self, py, bounds) -> bool:
         return True      # the state space is finite with no constraint
 
+    def render_state(self, py, bounds, indent="    "):
+        return self._mod().render_state(py, bounds, indent)
+
+    def render_trace(self, violation, bounds):
+        return self._mod().render_trace(violation, bounds)
+
+    def emit_tla(self, out_dir, bounds, invariants=()):
+        return self._mod().emit_tla(out_dir, bounds, invariants)
+
+    def _refuse_raft_options(self, cfg, opts) -> None:
+        """SYMMETRY, VIEW and faithful mode are Raft's: refused by name."""
+        if cfg.symmetry or opts.symmetry:
+            raise ValueError("symmetry reduction is not supported for "
+                             f"{self.name}")
+        if cfg.view or opts.view:
+            raise ValueError(f"views are not supported for {self.name}")
+        if opts.faithful:
+            raise ValueError("faithful mode (history variables) is "
+                             "Raft-specific")
+
+
+class TwoPhaseModel(SchemaModel):
+    """Bounded two-phase commit, compiled from frontend declarations
+    (``frontend/twophase``): schema layout, IR-built step, predicate
+    invariants.  ``bounds.n_servers`` is the RM count; the other bound
+    knobs are inert for this state space."""
+
+    name = "twophase"
+    sub = "twophase"
+    engines = ("host", "ddd", "simulate")
+
+    def _mod(self):
+        from raft_tla_tpu.frontend import twophase
+        return twophase
+
+    def universe_line(self, bounds) -> str:
+        return f"{bounds.n_servers} resource managers"
+
+    def _predicate(self, name: str):
+        from raft_tla_tpu.frontend.predicate import (compile_predicate,
+                                                     is_expression)
+        tp = self._mod()
+        text = tp.INVARIANTS.get(name)
+        if text is None:
+            if not is_expression(name):
+                raise ValueError(
+                    f"unknown twophase invariant {name!r} (known: "
+                    f"{', '.join(sorted(tp.INVARIANTS))}; or write a "
+                    "predicate expression over the state fields)")
+            text = name
+        return compile_predicate(text, fields=tp.SCHEMA.field_names)
+
+    def build_step(self, config: CheckConfig):
+        from raft_tla_tpu.frontend import actions
+        tp = self._mod()
+        preds = tuple(self._predicate(nm) for nm in config.invariants)
+        return actions.build_schema_step(
+            tp.SCHEMA, tp.ACTIONS, tp.action_table(config.bounds),
+            config.bounds, predicates=preds)
+
     def py_invariant(self, name):
         tp = self._mod()
         pred = self._predicate(name)
@@ -225,12 +259,6 @@ class TwoPhaseModel:
             return bool(pred.ev(struct, np))
 
         return check
-
-    def render_state(self, py, bounds, indent="    "):
-        return self._mod().render_state(py, bounds, indent)
-
-    def render_trace(self, violation, bounds):
-        return self._mod().render_trace(violation, bounds)
 
     def check_widths(self, bounds):
         from raft_tla_tpu.frontend.schema import check_schema
@@ -264,9 +292,6 @@ class TwoPhaseModel:
     def host_apply(self, py, inst, bounds):
         return self._mod().apply_instance(py, inst, bounds)
 
-    def emit_tla(self, out_dir, bounds, invariants=()):
-        return self._mod().emit_tla(out_dir, bounds, invariants)
-
     def resolve_check_config(self, cfg, opts, path=None):
         """TLC cfg -> (CheckConfig, properties) for the twophase model —
         the non-Raft face of ``serve/jobs.resolve_check_config``."""
@@ -291,14 +316,7 @@ class TwoPhaseModel:
         if cfg.constraints:
             raise ValueError(
                 f"{where}: twophase is finite; CONSTRAINT is not supported")
-        if cfg.symmetry or opts.symmetry:
-            raise ValueError("symmetry reduction is not supported for "
-                             "twophase")
-        if cfg.view or opts.view:
-            raise ValueError("views are not supported for twophase")
-        if opts.faithful:
-            raise ValueError("faithful mode (history variables) is "
-                             "Raft-specific")
+        self._refuse_raft_options(cfg, opts)
         rms = cfg.constants.get("RM", cfg.constants.get("Server"))
         if not isinstance(rms, list) or not rms:
             raise ValueError(
@@ -315,12 +333,135 @@ class TwoPhaseModel:
         return config, ()
 
 
+class PaxosModel(SchemaModel):
+    """Lamport's single-decree Paxos, compiled from frontend declarations
+    (``frontend/paxos``), the twin of :class:`TwoPhaseModel`.
+    ``bounds.n_servers`` / ``n_values`` are the acceptor and value counts,
+    ``bounds.max_term`` the maximum ballot, and ``bounds.constants`` binds
+    the ``Quorum`` table from the cfg; the other bound knobs are inert."""
+
+    name = "paxos"
+    sub = "paxos"
+    engines = ("host", "ddd")
+
+    def _mod(self):
+        from raft_tla_tpu.frontend import paxos
+        return paxos
+
+    def _consts(self, bounds) -> dict:
+        return self._mod().SCHEMA.bind_consts(bounds, bounds.constants)
+
+    def _predicate(self, name: str, bounds):
+        from raft_tla_tpu.frontend.predicate import (compile_predicate,
+                                                     is_expression)
+        px = self._mod()
+        if name in px.INVARIANTS:
+            text = px.INVARIANTS[name](bounds)
+        elif is_expression(name):
+            text = name
+        else:
+            raise ValueError(
+                f"unknown paxos invariant {name!r} (known: "
+                f"{', '.join(sorted(px.INVARIANTS))}; or write a "
+                "predicate expression over the state fields)")
+        return compile_predicate(text, fields=px.SCHEMA.field_names,
+                                 consts=self._consts(bounds))
+
+    def universe_line(self, bounds) -> str:
+        return (f"{bounds.n_servers} acceptors, {bounds.n_values} values, "
+                f"ballots 0..{bounds.max_term}, "
+                f"{len(dict(bounds.constants)['Quorum'])} quorums")
+
+    def build_step(self, config: CheckConfig):
+        from raft_tla_tpu.frontend import actions
+        px, b = self._mod(), config.bounds
+        preds = tuple(self._predicate(nm, b) for nm in config.invariants)
+        return actions.build_schema_step(
+            px.SCHEMA, px.ACTIONS, px.action_table(b), b,
+            predicates=preds, const_tables=self._consts(b))
+
+    def py_invariant(self, name):
+        px = self._mod()
+        compiled = {}           # bounds -> predicate (the table is bound)
+
+        def check(py, bounds) -> bool:
+            if bounds not in compiled:
+                compiled[bounds] = self._predicate(name, bounds)
+            struct = px.SCHEMA.layout(bounds).unpack(
+                px.to_vec(py, bounds), np)
+            return bool(compiled[bounds].ev(struct, np))
+
+        return check
+
+    def check_widths(self, bounds):
+        from raft_tla_tpu.frontend.schema import check_schema
+        from raft_tla_tpu.frontend.widthgen import check_schema_writes
+        px = self._mod()
+        return check_schema(px.SCHEMA, bounds) + check_schema_writes(
+            px.SCHEMA, px.ACTIONS, bounds)
+
+    def resolve_check_config(self, cfg, opts, path=None):
+        """TLC cfg -> (CheckConfig, properties) for the paxos model.  The
+        cfg binds ``Acceptor``, ``Value`` and ``Quorum`` (a set of sets over
+        ``Acceptor``, taken as written); ``Ballot <- MCBallot`` is recorded
+        by the parser and stands for ``0..MaxBallot``: the cfg's
+        ``MaxBallot = N`` where it binds one (the emitted twin does), else
+        ``--max-term``."""
+        from raft_tla_tpu.utils import cfgparse
+        px = self._mod()
+        where = path or "cfg"
+        if cfg.specification not in (None, "Spec"):
+            raise ValueError(
+                f"{where}: paxos checks SPECIFICATION Spec only (got "
+                f"{cfg.specification!r})")
+        if cfg.init not in (None, "Init") or cfg.next not in (None, "Next"):
+            raise ValueError(
+                f"{where}: paxos supports INIT Init / NEXT Next only")
+        if cfg.properties:
+            raise ValueError(
+                f"{where}: temporal properties are not supported for paxos "
+                f"(got {cfg.properties}; the refinement V!Spec waits on "
+                "ROADMAP queue 2 A.5)")
+        if cfg.constraints:
+            raise ValueError(
+                f"{where}: paxos is bounded by its ballots; CONSTRAINT is "
+                "not supported")
+        self._refuse_raft_options(cfg, opts)
+        accs = cfg.constants.get("Acceptor")
+        vals = cfg.constants.get("Value")
+        for nm, v in (("Acceptor", accs), ("Value", vals)):
+            if not isinstance(v, list) or not v \
+                    or not all(isinstance(x, str) for x in v):
+                raise ValueError(
+                    f"{where}: paxos needs CONSTANT {nm} = {{...}} (a "
+                    "nonempty finite set of model values)")
+        rows = cfgparse.set_of_subsets(cfg, "Quorum", "Acceptor", path)
+        max_ballot = opts.max_term
+        if "MaxBallot" in cfg.constants:
+            text = cfg.constants["MaxBallot"]
+            if not (isinstance(text, str) and text.isdigit()):
+                raise ValueError(f"{where}: MaxBallot = {text!r} is not a "
+                                 "natural number")
+            max_ballot = int(text)
+        bounds = Bounds(n_servers=len(accs), n_values=len(vals),
+                        max_term=max_ballot,
+                        constants=(("Quorum", tuple(rows)),))
+        invariants = tuple(cfg.invariants) or (px.DEFAULT_INVARIANT,)
+        for nm in invariants:        # parse/validate now, fail loudly here
+            self._predicate(nm, bounds)
+        config = CheckConfig(
+            bounds=bounds, spec="paxos", invariants=invariants,
+            symmetry=(), chunk=opts.chunk, check_deadlock=opts.deadlock,
+            view=None)
+        return config, ()
+
+
 _RAFT_SUBS = ("full", "election", "replication")
 
 
 def known_specs() -> tuple:
     return _RAFT_SUBS + tuple(f"ir-{s}" for s in _RAFT_SUBS) + (
-        "raft", "twophase")
+        "raft", "twophase", "paxos")
 
 
 def resolve_model(spec: str):
@@ -334,6 +475,8 @@ def resolve_model(spec: str):
         return RaftModel(spec, spec[3:], use_ir=True)
     if spec == "twophase":
         return TwoPhaseModel()
+    if spec == "paxos":
+        return PaxosModel()
     from raft_tla_tpu.utils import cfgparse
     hints = cfgparse.suggest(spec, known_specs())
     hint_txt = f" (did you mean: {', '.join(hints)}?)" if hints else ""

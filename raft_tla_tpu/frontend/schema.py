@@ -58,18 +58,66 @@ class Field:
 
 
 @dataclasses.dataclass(frozen=True)
+class Const:
+    """One constant table of the model (a TLA+ ``CONSTANT`` that is not a
+    plain set size): a small-int tensor with a declared shape and value
+    range, no part of the state.  Its values are bound for a run
+    (``Bounds.constants``, from the cfg) and held to the declaration by
+    :meth:`Schema.bind_consts`.  A ``"*"`` in ``shape`` is a free length,
+    taken from the bound value (the number of quorums)."""
+    name: str
+    shape: tuple = ()
+    lo: int = 0
+    hi: object = 0
+
+
+@dataclasses.dataclass(frozen=True)
 class Schema:
-    """A named tuple of fields; the unit the frontend compiles against."""
+    """A named tuple of fields, and of the constant tables the actions and
+    invariants read beside them; the unit the frontend compiles against."""
     name: str
     fields: tuple
+    consts: tuple = ()
 
     def __post_init__(self):
         seen = set()
-        for f in self.fields:
+        for f in self.fields + self.consts:
             if f.name in seen:
                 raise ValueError(
                     f"schema {self.name!r}: duplicate field {f.name!r}")
             seen.add(f.name)
+
+    def bind_consts(self, bounds, values) -> dict:
+        """``{name: int32 array}`` for every declared constant table, from
+        ``values`` (a mapping, or ``Bounds.constants``' pairs); a
+        missing or undeclared table, a shape or a value outside the
+        declaration is refused by name."""
+        values = dict(values)
+        extra = sorted(set(values) - {c.name for c in self.consts})
+        if extra:
+            raise ValueError(f"schema {self.name!r} declares no constant "
+                             f"{extra[0]!r}")
+        out = {}
+        for c in self.consts:
+            if c.name not in values:
+                raise ValueError(f"schema {self.name!r}: constant "
+                                 f"{c.name!r} is not bound")
+            a = np.asarray(values[c.name], dtype=np.int64)
+            want = tuple(d if d == "*" else _resolve(d, bounds)
+                         for d in c.shape)
+            if a.ndim != len(want) or a.size == 0 or any(
+                    w != "*" and w != d for w, d in zip(want, a.shape)):
+                raise ValueError(
+                    f"schema {self.name!r}: constant {c.name!r} has shape "
+                    f"{a.shape}, declared {want}")
+            hi = _resolve(c.hi, bounds)
+            if a.min() < c.lo or a.max() > hi:
+                raise ValueError(
+                    f"schema {self.name!r}: constant {c.name!r} holds "
+                    f"{int(a.min())}..{int(a.max())}, declared "
+                    f"[{c.lo}, {hi}]")
+            out[c.name] = a.astype(I32)
+        return out
 
     def field(self, name: str) -> Field:
         for f in self.fields:
@@ -149,6 +197,14 @@ def envelope(schema: Schema, bounds) -> dict:
     from raft_tla_tpu.analysis.intervals import Interval
     return {f.name: Interval(f.lo, _resolve(f.hi, bounds))
             for f in schema.fields}
+
+
+def const_envelope(schema: Schema, bounds) -> dict:
+    """Constant table -> declared value interval (what a read of the table
+    abstracts to, whatever a run binds)."""
+    from raft_tla_tpu.analysis.intervals import Interval
+    return {c.name: Interval(c.lo, _resolve(c.hi, bounds))
+            for c in schema.consts}
 
 
 def check_schema(schema: Schema, bounds) -> list:

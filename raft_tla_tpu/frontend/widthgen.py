@@ -60,6 +60,46 @@ def _record(msg, ictx):
     return MsgRecord(msg.mtype, fields)
 
 
+def check_schema_writes(schema, defs, bounds) -> list:
+    """The width gate of a schema-declared spec, from the IR: under the
+    declared envelope, every value an action writes lies inside the
+    written field's declared range (what the packed row carries), and
+    every index of a :class:`~raft_tla_tpu.frontend.expr.SetAt` lies inside
+    the field's axis.  Lint-style findings (empty = proved)."""
+    from raft_tla_tpu.analysis import report
+    from raft_tla_tpu.frontend import schema as sch
+    env = sch.envelope(schema, bounds)
+    const_iv = sch.const_envelope(schema, bounds)
+    shapes = schema.layout(bounds).shapes
+    findings = []
+
+    def bad(code, text, field):
+        findings.append(report.Finding(report.WIDTH, report.ERROR, code,
+                                       text, field=field))
+
+    for adef in defs:
+        param_iv = {name: fn(bounds) for name, fn in adef.param_iv}
+        for br in adef.branches:
+            ictx = E.IvCtx(bounds, env, {}, param_iv, br.mtype, const_iv)
+            for u in br.updates:
+                got, want = u.val.iv(ictx), env[u.field]
+                if got.lo < want.lo or got.hi > want.hi:
+                    bad("schema-write-range",
+                        f"{adef.family}: writes [{got.lo}, {got.hi}] to "
+                        f"{u.field!r}, declared [{want.lo}, {want.hi}]",
+                        u.field)
+                if not isinstance(u, E.SetAt):
+                    continue
+                for axis, e in enumerate(u.idx):
+                    at, dim = e.iv(ictx), shapes[u.field][axis]
+                    if at.lo < 0 or at.hi >= dim:
+                        bad("schema-index-range",
+                            f"{adef.family}: index [{at.lo}, {at.hi}] on "
+                            f"axis {axis} of {u.field!r}, which has {dim} "
+                            "entries", u.field)
+    return findings
+
+
 def transfer_of(adef):
     """ActionDef -> ``transfer(bounds, env, menv) -> TransferResult``,
     the exact callable shape ``widthcheck.TRANSFERS`` holds (and

@@ -30,6 +30,12 @@ from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops.msgbits import _HI_FIELDS, _LO_FIELDS
 
 
+# A TPU vector tile is 128 lanes wide: a row of up to that many words
+# packs column by column (every accepted Raft and TwoPhase row: 33..114
+# words), a wider one row-wise (``BitSchema._pack_rows``).
+_LANE_TILE = 128
+
+
 def _bits(max_value: int) -> int:
     """Bits to represent values 0..max_value."""
     return max(1, int(max_value).bit_length())
@@ -137,6 +143,8 @@ class BitSchema:
 
     def pack(self, vec, xp):
         """``int32[..., W] -> int32[..., P]`` (uint32 bitstream in int32)."""
+        if self.W > _LANE_TILE:
+            return self._pack_rows(vec, xp)
         u = vec.astype(xp.uint32)
         words = [None] * self.P
         for w in range(self.W):
@@ -151,6 +159,35 @@ class BitSchema:
                     else words[o + 1] | spill
         zero = xp.zeros_like(u[..., 0])
         cols = [zero if c is None else c for c in words]
+        return xp.stack(cols, axis=-1).astype(xp.int32)
+
+    def _pack_rows(self, vec, xp):
+        """:meth:`pack` for a row wider than one 128-lane tile, word by
+        word over whole rows: every element masked and shifted where it
+        lies, then one masked sum over the row an output word (the fields
+        of a word share no bit, so the sum is the or).  The column form
+        above slices W single columns off the row; past one tile the TPU
+        compiler stops fusing those slices and lays each out as an
+        ``[N, 1]`` array padded to a tile — at W = 153 that was 72 of a
+        chunk step's 73 ms and 2 GB of temporaries (PERF.md section 6,
+        PR 43) — and forces the successors into a row-minor layout that
+        the fingerprint then pays for too.  Same bits, either way
+        (tests/test_ddd_paxos.py)."""
+        u = vec.astype(xp.uint32)
+        word, sh = self.start // 32, (self.start % 32).astype(np.uint32)
+        straddles = (self.start % 32 + self.bits) > 32
+        v = u & ((np.uint64(1) << self.bits.astype(np.uint64)) - 1) \
+            .astype(np.uint32)
+        low = v << sh
+        spill = v >> np.where(straddles, 32 - sh, 0).astype(np.uint32)
+        zero = xp.uint32(0)
+        cols = []
+        for o in range(self.P):
+            part = xp.where(word == o, low, zero)
+            if straddles[word == o - 1].any():
+                part = part | xp.where((word == o - 1) & straddles, spill,
+                                       zero)
+            cols.append(xp.sum(part, axis=-1, dtype=xp.uint32))
         return xp.stack(cols, axis=-1).astype(xp.int32)
 
     def unpack(self, packed, xp):

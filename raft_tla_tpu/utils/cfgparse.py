@@ -6,8 +6,12 @@ Byte-compatible with the reference's ``raft.cfg:1-15``, whose grammar is:
 - ``INVARIANT NoTwoLeaders``        (``raft.cfg:3``)
 - ``CONSTANTS`` followed by indented ``Name = binding`` lines with optional
   ``\\*`` end-of-line comments (``raft.cfg:5-15``), where a binding is either
-  a model value (``Follower = "Follower"`` / ``Nil = Nil``) or a finite set
-  (``Server = {s1, s2, s3}``).
+  a model value (``Follower = "Follower"`` / ``Nil = Nil``), a finite set
+  (``Server = {s1, s2, s3}``), or a set of sets of model values (``Quorum =
+  {{a1, a2}, {a1, a3}, {a2, a3}}``, a list of lists); ``Name <- Definition``
+  is recorded in :attr:`TLCConfig.substitutions` and not followed (the
+  definition lives in a module this parser never reads: the model that takes
+  the cfg says what the name stands for).
 
 Additionally understood (the TLC stanzas the reference does not use but the
 checker supports): ``INVARIANTS``, ``CONSTRAINT``, ``PROPERTY``,
@@ -60,8 +64,11 @@ class TLCConfig:
     invariants: list[str] = dataclasses.field(default_factory=list)
     properties: list[str] = dataclasses.field(default_factory=list)
     constraints: list[str] = dataclasses.field(default_factory=list)
-    # Name -> python value: list[str] for set bindings, str for model values.
+    # Name -> python value: list[str] for set bindings (list[list[str]] for
+    # a set of sets), str for model values.
     constants: dict = dataclasses.field(default_factory=dict)
+    # Name -> the definition it is replaced by (``Ballot <- MCBallot``)
+    substitutions: dict = dataclasses.field(default_factory=dict)
     symmetry: list[str] = dataclasses.field(default_factory=list)
     view: str | None = None
     # (kind, name) -> 1-based source line, e.g. ("invariant", "NoTwoLeaders")
@@ -95,17 +102,68 @@ def _strip_comment(line: str) -> str:
     return line.strip()
 
 
-def _parse_set(text: str) -> list[str]:
+def _parse_set(text: str) -> list:
+    """``{a, b}`` -> ``["a", "b"]``; an element may itself be a set literal
+    (``{{a, b}, {c}}`` -> ``[["a", "b"], ["c"]]``), to any depth."""
     inner = text.strip()
     if not (inner.startswith("{") and inner.endswith("}")):
         raise ValueError(f"not a set literal: {text!r}")
-    body = inner[1:-1].strip()
-    if not body:
+    toks, depth, start = [], 0, 1
+    for pos in range(1, len(inner) - 1):
+        ch = inner[pos]
+        if ch == "{":
+            depth += 1
+        elif ch == "}":
+            depth -= 1
+            if depth < 0:
+                raise ValueError(f"unbalanced braces in set literal: "
+                                 f"{text!r}")
+        elif ch == "," and depth == 0:
+            toks.append(inner[start:pos].strip())
+            start = pos + 1
+    if depth:
+        raise ValueError(f"unbalanced braces in set literal: {text!r}")
+    last = inner[start:-1].strip()
+    if not toks and not last:
         return []
-    toks = [tok.strip() for tok in body.split(",")]
+    toks.append(last)
     if any(not t for t in toks):
         raise ValueError(f"empty element in set literal: {text!r}")
-    return toks
+    return [_parse_set(t) if t.startswith("{") else t for t in toks]
+
+
+def set_of_subsets(cfg: "TLCConfig", name: str, of: str,
+                   path: str | None = None) -> list:
+    """The constant ``name`` as a set of nonempty subsets of the constant
+    ``of`` (``Quorum`` over ``Acceptor``): one 0/1 row an element of
+    ``name``, one entry a member of ``of`` in the order ``of`` binds them.
+    Refuses, with the binding's line, a ``name`` that is not a set of sets,
+    an element that is empty, and a member that ``of`` does not hold."""
+    line = cfg.line_of("constant", name)
+    where = f"{path or 'cfg'}{f' line {line}' if line else ''}: "
+    base = cfg.constants.get(of)
+    if not isinstance(base, list) or not base \
+            or not all(isinstance(x, str) for x in base):
+        raise ValueError(f"{path or 'cfg'}: {name} needs CONSTANT {of} = "
+                         "{...} (a nonempty finite set of model values)")
+    sets = cfg.constants.get(name)
+    if not isinstance(sets, list) or not sets \
+            or not all(isinstance(q, list) for q in sets):
+        raise ValueError(
+            f"{where}{name} has to be a nonempty set of sets over {of}, "
+            f"e.g. {name} = {{{{{base[0]}}}}}; got {sets!r}")
+    rows = []
+    for q in sets:
+        if not q:
+            raise ValueError(f"{where}{name} holds the empty set: every "
+                             f"element has to name a member of {of}")
+        bad = [x for x in q if x not in base]
+        if bad:
+            raise ValueError(
+                f"{where}{name} element {{{', '.join(map(str, q))}}}: "
+                f"{bad[0]} is not in {of} = {{{', '.join(base)}}}")
+        rows.append(tuple(int(x in q) for x in base))
+    return rows
 
 
 def parse_cfg(text: str) -> TLCConfig:
@@ -169,14 +227,22 @@ def parse_cfg(text: str) -> TLCConfig:
             cfg.view = line
             cfg.lines[("view", line)] = lineno
         elif mode in ("CONSTANT", "CONSTANTS"):
+            sub = re.fullmatch(r"(\w+)\s*<-\s*(\w+)", line)
+            if sub:
+                # a substitution is recorded, never followed
+                cfg.substitutions[sub.group(1)] = sub.group(2)
+                cfg.lines[("constant", sub.group(1))] = lineno
+                continue
             if "=" not in line:
                 raise ValueError(
                     f"line {lineno}: bad CONSTANTS binding: {raw!r}")
             name, _, val = line.partition("=")
             name, val = name.strip(), val.strip()
-            # "<-" substitutions are not supported (not used by the reference).
             if val.startswith("{"):
-                cfg.constants[name] = _parse_set(val)
+                try:
+                    cfg.constants[name] = _parse_set(val)
+                except ValueError as e:
+                    raise ValueError(f"line {lineno}: {e}") from None
             else:
                 cfg.constants[name] = val.strip('"')
             cfg.lines[("constant", name)] = lineno

@@ -63,6 +63,7 @@ host-RAM-resident instead of disk-resident.
 
 from __future__ import annotations
 
+import ctypes
 import functools
 import os
 import threading
@@ -525,10 +526,54 @@ class PartitionedMasterKeys:
 # Factories (gate-aware construction + checkpoint rebuild)
 # ---------------------------------------------------------------------------
 
+# glibc's mallopt parameters (malloc.h) and what keep_freed_memory sets them
+# to: never trim the heap's top, mmap a request only past the largest
+# threshold glibc takes (32 MiB), grow the heap 64 MiB at a time
+_MALLOPT = ((-1, (1 << 31) - 1),      # M_TRIM_THRESHOLD
+            (-3, 32 << 20),           # M_MMAP_THRESHOLD
+            (-2, 64 << 20))           # M_TOP_PAD
+_KEPT: bool | None = None
+
+
+def keep_freed_memory() -> bool:
+    """Tell glibc's malloc to keep what this process frees (once a process;
+    returns whether it was told).
+
+    A level's close sorts, gathers and appends through tens of MB of NumPy
+    temporaries and frees them.  Left alone, glibc fits its thresholds to
+    those sizes and hands the top of the heap back to the kernel whenever
+    twice the largest of them is free there, so the next close faults every
+    page in again (dear where the kernel is a sandbox's) — unless a
+    long-lived block happens to lie above the temporaries and pins them,
+    which thread timing decides once a process: the whole-run "host-side
+    mode" of PERF.md (PR 41, PR 43; inline ``dedup`` 171-178 ms a level or
+    146-149 in ``paxos3b4.passes``, 137-139 in every process with the
+    thresholds fixed; ``twophase10.passes`` +15 %).  Costs ~0.2 GB of RSS
+    that is no longer returned between levels.  A process whose
+    environment sets a ``MALLOC_*`` variable or ``GLIBC_TUNABLES`` is left
+    as it was set, and a libc without ``mallopt`` is left alone."""
+    global _KEPT
+    if _KEPT is None:
+        _KEPT = False
+        if not any(k.startswith("MALLOC_") or k == "GLIBC_TUNABLES"
+                   for k in os.environ):
+            try:
+                mallopt = ctypes.CDLL(None).mallopt
+            except (OSError, AttributeError):
+                mallopt = None
+            if mallopt is not None:
+                _KEPT = all([mallopt(p, v) == 1 for p, v in _MALLOPT])
+    return _KEPT
+
+
 def new_master(partitioned: bool | None = None, *,
                parts: int = DEFAULT_PARTS,
                merge_budget: int | None = None):
-    """Fresh empty master set; ``partitioned=None`` resolves the gate."""
+    """Fresh empty master set; ``partitioned=None`` resolves the gate.
+    Both factories also fix the allocator's thresholds for the process
+    (:func:`keep_freed_memory`): every pass of both DDD engines starts at
+    one of them, before the first large allocation of its stores."""
+    keep_freed_memory()
     if partitioned is None:
         partitioned = host_dedup_enabled()
     if partitioned:
@@ -547,6 +592,7 @@ def master_from_keys(keys: np.ndarray, *, source: str = "checkpoint",
     splits first and sorts per partition on the shared pool, so
     resume-time sort cost drops from one O(N log N) to parallel
     O(N/2^k log N/2^k) tasks."""
+    keep_freed_memory()
     if partitioned is None:
         partitioned = host_dedup_enabled()
     keys = np.ascontiguousarray(keys, dtype=U64)

@@ -54,13 +54,16 @@ def toy_cell(n: int = 5) -> dict:
 def test_manifest_gains_the_configuration_the_cell_and_three_readers():
     manifest = mf.load()
     assert mf.problems(manifest) == []
-    assert manifest["configs"][-1]["name"] == "twophase10"
-    assert manifest["configs"][-1]["reduced"] == ["depth"]
-    assert manifest["workloads"][-1] == {
+    # found by name: a later PR's entries go after these
+    (config,) = [c for c in manifest["configs"] if c["name"] == "twophase10"]
+    assert config["reduced"] == ["depth"]
+    (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
+    assert cell == {
         "name": CELL, "config": "twophase10", "traffic": "passes_l7_l12",
-        "chips": 1, "why": manifest["workloads"][-1]["why"]}
-    assert tuple(m["name"] for m in manifest["per_layer"][-3:]) == NEW_METRICS
-    for m in manifest["per_layer"][-3:]:
+        "chips": 1, "why": cell["why"]}
+    readers = [m for m in manifest["per_layer"] if m["name"] in NEW_METRICS]
+    assert tuple(m["name"] for m in readers) == NEW_METRICS
+    for m in readers:
         assert m["workloads"] == [CELL] and m["moves"] == "orbits_per_s"
     # the cell reports the readers that carry no list, and the three new ones
     names = mf.metric_names(manifest, CELL, "per_layer")

@@ -155,6 +155,49 @@ def test_host_dedup_gate_resolution():
     assert keyset.host_dedup_enabled("AUTO") is auto_expect
 
 
+@pytest.mark.parametrize("env, told", [
+    ({}, True),
+    ({"MALLOC_ARENA_MAX": "2"}, False),
+    ({"GLIBC_TUNABLES": "glibc.malloc.trim_threshold=1"}, False),
+])
+def test_keep_freed_memory_once_and_never_over_the_environment(
+        monkeypatch, env, told):
+    """The allocator's thresholds are fixed once a process through
+    ``mallopt``, by both factories, and a process whose environment sets its
+    own is left as it was set."""
+    calls = []
+
+    class Libc:
+        @staticmethod
+        def mallopt(param, value):
+            calls.append((param, value))
+            return 1
+
+    for k in [k for k in os.environ
+              if k.startswith("MALLOC_") or k == "GLIBC_TUNABLES"]:
+        monkeypatch.delenv(k)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(keyset, "_KEPT", None)
+    monkeypatch.setattr(keyset.ctypes, "CDLL", lambda name: Libc)
+    keyset.new_master(True)
+    assert keyset.keep_freed_memory() is told
+    keyset.master_from_keys(np.arange(5, dtype=np.uint64))
+    assert calls == (list(keyset._MALLOPT) if told else [])
+    assert dict(keyset._MALLOPT) == {-1: 2 ** 31 - 1, -3: 32 << 20,
+                                     -2: 64 << 20}
+
+
+def test_keep_freed_memory_without_mallopt_is_a_no_op(monkeypatch):
+    monkeypatch.setattr(keyset, "_KEPT", None)
+    for k in [k for k in os.environ
+              if k.startswith("MALLOC_") or k == "GLIBC_TUNABLES"]:
+        monkeypatch.delenv(k)
+    monkeypatch.setattr(keyset.ctypes, "CDLL", lambda name: object())
+    assert keyset.keep_freed_memory() is False
+    assert keyset.keep_freed_memory() is False
+
+
 def test_dedup_worker_ordered_depth1_and_exceptions():
     """flushq.DedupWorker: batches run in submission order, depth-1
     (submit i+1 blocks until i completes), drain settles everything,

@@ -146,6 +146,15 @@ python -m raft_tla_tpu.check "$SERVE_TMP/2pc.cfg" \
     | tee "$SERVE_TMP/2pc.out" | tail -2
 grep -q "^56 distinct states found" "$SERVE_TMP/2pc.out" \
     || { echo "frontend smoke FAILED: expected 56 states"; exit 1; }
+# ... and single-decree Paxos: a set-of-sets CONSTANT, `<-` recorded
+printf 'CONSTANTS\n  Acceptor = {a1, a2, a3}\n  Value = {v1, v2}\n  Quorum = {{a1, a2}, {a1, a3}, {a2, a3}}\n  None = None\n  Ballot <- MCBallot\nSPECIFICATION Spec\nINVARIANTS TypeOK Consistency\n' \
+    > "$SERVE_TMP/MCPaxos.cfg"
+python -m raft_tla_tpu.check "$SERVE_TMP/MCPaxos.cfg" \
+    --spec paxos --engine ddd --max-term 1 --chunk 64 --cpu \
+    | tee "$SERVE_TMP/paxos.out" | tail -2
+grep -q "^3921 distinct states found, diameter 16, 22994 transitions" \
+    "$SERVE_TMP/paxos.out" \
+    || { echo "frontend smoke FAILED: expected 3921 Paxos states"; exit 1; }
 
 begin host-dedup "host-dedup smoke (ddd engine, background partitioned flush, CPU)"
 # Gate forced ON: the toy cfg runs end-to-end through the ddd engine
