@@ -91,6 +91,18 @@ U32 = jnp.uint32
 # host-RAM-feasible state count.
 _IDX_CEIL = 1 << 62
 
+# When a pending stream is worth handing to the flush worker below
+# ``DDDCapacities.flush`` (the harvest loop of ``_check_impl``): while the
+# level has at least a third as many chunk steps left to run as streamed the
+# batch.  Measured on the chip (PERF.md section 6, PR 44): the worker merges
+# a batch in up to three quarters of the time the device took to stream it,
+# and a key costs 1.5-1.8x more in such a batch than in the one merge of the
+# level close, so a hand-over pays once about a third of the batch's own
+# device time still lies ahead; with less (a level whose first segment fills
+# the buffer and whose second runs a few steps) the close would wait for the
+# worker longer than it would have merged.
+_HANDOVER_STEPS = 3
+
 
 def install_sigint_boundary_stop(eng, stack, boundary="segment") -> None:
     """The runs/campaign_stop.sh contract, shared by the DDD engine
@@ -139,7 +151,14 @@ class DDDCapacities:
     next chunk might not fit — a dispatch round trip was measured at
     ~100-300 ms on the rounds 2-5 host link (inherited, not re-measured
     on this machine), so per-chunk dispatch is ~10x slower);
-    ``flush``: pending candidates per host dedup pass; ``levels``:
+    ``flush``: the most candidates that may be pending for the host
+    dedup — at it the harvest loop hands over (or, with the flush worker
+    off, merges inline) whatever the worker is doing, waiting for its
+    previous flush; below it the worker is handed the pending stream at
+    a harvest that has enough device work of its level behind it
+    (``_HANDOVER_STEPS``) and finds the worker free, and the level close
+    merges the rest (``_check_impl``);
+    ``levels``:
     host-side BFS-depth bound; ``route_rows``: >0 switches the chunk
     program to the EP-routed step (kernels.build_step_routed) with that
     many compacted candidate slots per chunk — discovery order is
@@ -1475,6 +1494,7 @@ class DDDEngine:
         bufsets = [self._make_bufs(), self._make_bufs()]
         pend = {"keys": [], "rows": [], "par": [],  # resume starts empty
                 "lane": [], "con": []}
+        pend_steps = 0       # chunk steps whose stream ``pend`` holds
         # Background dedup worker (RAFT_TLA_HOSTDEDUP): flushes run on
         # one daemon thread, depth-1 ordered, so flush i's new keys are
         # in the master before flush i+1's dedup starts — cross-flush
@@ -1499,7 +1519,8 @@ class DDDEngine:
             """Drain the background queue, then flush the remaining pend
             inline — afterwards master/stores/cov reflect every streamed
             candidate, exactly as in the synchronous engine."""
-            nonlocal n_states
+            nonlocal n_states, pend_steps
+            pend_steps = 0
             if worker is not None:
                 with tel.phases.phase("dedup_wait") as ph:
                     if tr.enabled:
@@ -1702,6 +1723,10 @@ class DDDEngine:
                 q = []               # in-flight: (bufset idx, stats, t)
                 free = list(range(len(bufsets)))
                 block_done = False
+                # chunk steps of the level past this block, and of this
+                # block not yet harvested (what a hand-over hides behind)
+                later_steps = -(-(lvl_hi - b_start - b_rows) // B)
+                block_steps_left = -(-b_rows // B)
                 t_last_harvest = time.monotonic()
                 while q or not (block_done or stopped):
                     if (not stopped and deadline_s is not None
@@ -1851,8 +1876,29 @@ class DDDEngine:
                     t_last_harvest = now
                     self.seg_chunks = budget
                     block_done = block_done or bool(st_h.done)
-                    if sum(len(x) for x in pend["keys"]) >= \
-                            self.caps.flush:
+                    n_pend = sum(len(x) for x in pend["keys"])
+                    block_steps_left -= n_steps
+                    pend_steps += n_steps
+                    # At ``caps.flush`` the pending stream goes to the
+                    # host dedup whatever else holds (the submit waits for
+                    # the previous flush).  Below it the worker is handed
+                    # the stream only while the level has device work left
+                    # to hide the merge behind (``_HANDOVER_STEPS``) and
+                    # only when it is free, so the submit never blocks and
+                    # a batch grows to what the worker keeps up with.
+                    # ``q`` being non-empty is no sign of work left: the
+                    # segment in flight after a block's last chunk runs
+                    # zero chunks.  So a level's last harvest hands nothing
+                    # over, the level close merges that stream inline, and
+                    # a level of one segment never meets the worker.
+                    hand_over = n_pend >= self.caps.flush
+                    if worker is not None and n_pend and not hand_over:
+                        hand_over = (
+                            (block_steps_left + later_steps)
+                            * _HANDOVER_STEPS >= pend_steps
+                            and not worker.backlog())
+                    if hand_over:
+                        pend_steps = 0
                         if worker is not None:
                             # sealed-batch submission: blocks only until
                             # the PREVIOUS flush completes (depth-1);
@@ -1861,7 +1907,6 @@ class DDDEngine:
                             # in-flight flush — the _IDX_CEIL re-check
                             # at every drain point keeps the ceiling
                             # honest.
-                            n_pend = sum(len(x) for x in pend["keys"])
                             with tel.phases.phase("dedup_submit") as ph:
                                 if tr.enabled:
                                     ph.set(keys=n_pend,

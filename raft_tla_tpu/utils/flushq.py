@@ -12,6 +12,21 @@ one sealed batch is ever in flight.  Cross-flush first-occurrence order
 flush i's new keys are in the master tiers before flush i+1's dedup
 begins, exactly as in the synchronous engine.
 
+When a batch comes (ddd_engine.py's harvest loop): after a harvest that
+leaves device work of the level behind it — chunk steps of the block that
+the segment in flight will run, or a further block; at least a third as many
+as streamed the batch (``ddd_engine._HANDOVER_STEPS``: with less, the level
+close would wait for this thread longer than it would have merged inline) —
+if the worker is free (`backlog()` 0), so the merge of a level's stream runs
+beside the level's next segment, `submit` does not block, and a batch grows
+to what the worker keeps up with.  ``DDDCapacities.flush`` is the most that may be
+pending: at it the batch is submitted whatever the worker is doing, and
+`submit` blocks as above.  A level's last harvest submits nothing (the
+segment in flight behind it runs zero chunks): the level close drains the
+one flush in flight and merges that last stream inline, so a level of one
+segment never comes here.  The mesh engine (ddd_shard_engine.py) submits at
+``flush`` only.
+
 The engine's drain discipline (ddd_engine.py): every reader of state the
 flush mutates — checkpoint save, level boundaries, `_IDX_CEIL` checks,
 violation identity, lossless SIGINT/deadline stops — calls `drain()`

@@ -79,17 +79,19 @@ def test_manifest_gains_the_configuration_the_cell_and_three_readers():
     for m in readers:
         assert m["workloads"] == [CELL] and m["moves"] == "orbits_per_s"
         assert m["layer"] == "fused step"
-    # the cell reports the readers that carry no list, and the three new ones
+    # the cell reports the readers that carry no list, the three new ones
+    # and, since PR 44, flush_offload_pct
     names = mf.metric_names(manifest, CELL, "per_layer")
-    assert len(names) == 17 and set(NEW_METRICS) <= set(names)
+    assert len(names) == 18 and set(NEW_METRICS) <= set(names)
     assert {"scan_words_per_s", "step_hbm_share", "device_idle_share",
             "pass_median_rate"} <= set(names)
     assert mf.metric_names(manifest, CELL, "end_to_end") \
         == ["orbits_per_s", "setup_s"]
     # nothing an accepted metric lists was touched: the cell is in no list
-    # but its own three
+    # but its own three and the one PR 44 brought with it
     assert [m["name"] for m in manifest["per_layer"]
-            if CELL in m.get("workloads", ())] == list(NEW_METRICS)
+            if CELL in m.get("workloads", ())] \
+        == list(NEW_METRICS) + ["flush_offload_pct"]
 
 
 def test_the_configuration_is_the_sources_deployment_at_ballots_0_to_3():

@@ -65,12 +65,13 @@ def test_manifest_gains_the_configuration_the_cell_and_three_readers():
     assert tuple(m["name"] for m in readers) == NEW_METRICS
     for m in readers:
         assert m["workloads"] == [CELL] and m["moves"] == "orbits_per_s"
-    # the cell reports the readers that carry no list, and the three new ones
+    # the cell reports the readers that carry no list, the three new ones
+    # and, since PR 44, flush_offload_pct
     names = mf.metric_names(manifest, CELL, "per_layer")
-    assert len(names) == 17 and set(NEW_METRICS) <= set(names)
-    # flush_backlog_keys (its reader is PR 30's) stays unlisted: a metric
-    # lists the cells in which its reader finds something to read, and no
-    # cell, this one included, hands the flush worker a batch (PERF.md)
+    assert len(names) == 18 and set(NEW_METRICS) <= set(names)
+    # flush_backlog_keys (its reader is PR 30's) stays unlisted until a
+    # benchmark PR lists it: since PR 44 this cell hands the flush worker
+    # batches, so its reader has something to read here (ROADMAP queue 2)
     assert "flush_backlog_keys" not in {m["name"]
                                         for m in manifest["per_layer"]}
     assert {"scan_words_per_s", "step_hbm_share"} <= set(names)
@@ -231,6 +232,36 @@ def test_flush_span_busy_s_is_the_workers_wall_over_the_whole_span(
     assert read(traced_evidence) == 0.0         # the worker had no batch
     quiet.write_text(json.dumps({"event": "run_start"}) + "\n")
     assert read(traced_evidence) is None        # a program without spans
+
+
+def test_flush_offload_pct_is_the_workers_share_of_the_spans_merge(
+        traced_evidence, tmp_path):
+    """PR 44: the worker's ``dedup`` wall over that plus the main thread's,
+    both clipped to the clocked span A->B."""
+    (entry,) = [m for m in mf.load()["per_layer"]
+                if m["name"] == "flush_offload_pct"]
+    assert entry == {
+        "name": "flush_offload_pct", "unit": "%", "better": "higher",
+        "source": "program_span", "layer": "d2h export and host key set",
+        "moves": "orbits_per_s",
+        "workloads": [CELL, "paxos3b4.passes"]}
+    read = mf.metric_reader("flush_offload_pct")
+    assert read(traced_evidence) == pytest.approx(100 * 3.0 / 3.25)
+    quiet = tmp_path / "quiet.events"
+    quiet.write_text("\n".join([
+        _span("dedup", "MainThread", 9.9, 0.2, 6),         # half before A
+        _span("dedup", "MainThread", 12.5, 0.25, 7),
+        _span("dedup", "raft-tla-flush", 18.0, 1.0, 8),    # past B
+    ]) + "\n")
+    traced_evidence["passes"][0].events = str(quiet)
+    assert read(traced_evidence) == 0.0         # every merge ran inline
+    quiet.write_text(_span("level", "MainThread", 10.0, 1.0, 2, level=8)
+                     + "\n")
+    assert read(traced_evidence) is None        # no dedup inside the span
+    quiet.write_text(json.dumps({"event": "run_start"}) + "\n")
+    assert read(traced_evidence) is None        # a program without spans
+    traced_evidence["passes"][0].t_b = None
+    assert read(traced_evidence) is None        # a pass that met no B
 
 
 def test_stage_plainfp_ms_is_the_scopes_self_time_a_step(traced_evidence):

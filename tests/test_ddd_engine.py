@@ -9,6 +9,9 @@ change a verdict or a count), refbfs-exact violation/deadlock stops,
 trace replay, and block-boundary checkpoint/resume with exact counters.
 """
 
+import functools
+import time
+
 import numpy as np
 import pytest
 
@@ -309,6 +312,189 @@ def test_host_dedup_lossless_deadline_stop_with_pending_flush(
     assert resumed.levels == straight.levels
     assert resumed.n_transitions == straight.n_transitions
     assert resumed.coverage == straight.coverage
+
+
+# -- the hand-over to the flush worker at a harvest (PR 44) ------------------
+#
+# ``flush`` stays at its default 2^23, which no toy level reaches: every
+# hand-over below is the harvest's own, taken because the level still has
+# device work behind it.  The election engine's ``seg_rows`` is one chunk's
+# candidates, so a segment runs one chunk and a level of k chunks makes k
+# harvests; the two-phase engine's segments run four chunks (``seg_chunks``
+# pinned, a buffer of four chunks' candidates), so its levels end in tails
+# of one to four chunk steps.
+
+
+@functools.lru_cache(maxsize=None)
+def _handover_case(spec):
+    """One compiled engine a spec for every test below, with a planted
+    state whose violation lies several levels of several segments away
+    (what a reachable state never is: a leader that voted for nobody; an
+    aborted RM the TM holds for prepared) and one from which every level
+    fits a chunk."""
+    from raft_tla_tpu.frontend import resolve_model
+    if spec == "election":
+        from raft_tla_tpu.models import spec as S
+        cfg = dataclasses.replace(CFG, chunk=8)
+        init = interp.init_state(cfg.bounds)
+        planted = init._replace(
+            role=(S.LEADER, S.FOLLOWER), term=(2, 1), vResp=(0b11, 0),
+            vGrant=(0b11, 0))
+        narrow = init._replace(
+            role=(S.LEADER, S.CANDIDATE), term=(2, 2), votedFor=(1, 2),
+            vResp=(0b11, 0b10), vGrant=(0b11, 0b10))
+        invariant = "NoTwoLeaders"
+    else:
+        from benchmark.families import twophase_ddd as fam
+        from benchmark.reference import twophase as ref
+        from test_ddd_twophase import toy_cfg
+        n = 4
+        cfg = fam.check_config(dict(toy_cfg(n), chunk=8))
+        planted = fam.to_program(ref.State(
+            (ref.ABORTED,) + (ref.WORKING,) * (n - 1), ref.TM_INIT, 1, 1))
+        narrow = fam.to_program(ref.State(
+            (ref.PREPARED,) * n, ref.TM_COMMITTED, (1 << n) - 1,
+            (1 << n + 1) - 1))
+        invariant = "TCConsistent"
+    lanes = cfg.chunk * len(resolve_model(cfg.spec).action_table(cfg.bounds))
+    per_seg = 1 if spec == "election" else 4
+    caps = DDDCapacities(block=256, table=1 << 14, seg_rows=per_seg * lanes,
+                         levels=64)
+    assert caps.flush == 1 << 23
+    eng = DDDEngine(cfg, caps, seg_chunks=per_seg)
+    eng.SEG_MAX = max(per_seg, eng.SEG_MIN)  # the pacer keeps the budget
+    return eng, planted, narrow, invariant
+
+
+def _run_arm(eng, arm, monkeypatch, **kw):
+    """``check()`` under ``RAFT_TLA_HOSTDEDUP=arm`` on the one compiled
+    engine (the gate is read again, as the constructor reads it): the
+    result, every byte the stores hold, the batches the worker got and
+    when, on the pass ledger's clock."""
+    from raft_tla_tpu.utils import flushq, keyset
+    from test_upload_prefix import _stores
+    monkeypatch.setenv("RAFT_TLA_HOSTDEDUP", arm)
+    eng._host_dedup = keyset.host_dedup_enabled()
+    handed, handed_at = [], []
+    submit = flushq.DedupWorker.submit
+
+    def counted(self, batch, n_keys):
+        handed.append(n_keys)
+        handed_at.append(time.monotonic())
+        submit(self, batch, n_keys)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(flushq.DedupWorker, "submit", counted)
+        res = eng.check(retain_store=True, **kw)
+    return res, _stores(eng), handed, handed_at
+
+
+def _same_discovery(got, want):
+    (res, stored), (ref_res, ref_stored) = got[:2], want[:2]
+    assert stored == ref_stored          # rows, trace links, flags, keys
+    assert (res.n_states, res.levels, res.n_transitions, res.coverage,
+            res.complete) == (ref_res.n_states, ref_res.levels,
+                              ref_res.n_transitions, ref_res.coverage,
+                              ref_res.complete)
+    assert res.violation == ref_res.violation
+
+
+@pytest.mark.parametrize("spec", ["election", "twophase"])
+def test_harvest_handovers_leave_discovery_byte_identical(spec, monkeypatch):
+    """Levels of several segments hand their stream to the worker harvest
+    by harvest, far below ``flush``; states, trace links, level counts,
+    coverage and a planted fault's trace equal the inline arm's."""
+    eng, planted, _narrow, invariant = _handover_case(spec)
+    on = _run_arm(eng, "on", monkeypatch)
+    off = _run_arm(eng, "off", monkeypatch)
+    _same_discovery(on, off)
+    assert on[0].complete and on[0].violation is None
+    assert max(on[0].levels) > eng.caps.block        # levels of two blocks
+    assert len(on[2]) > len(on[0].levels) and max(on[2]) < 1 << 10
+    assert on[0].level_log["threads"]["dedup@raft-tla-flush"] > 0
+    assert off[2] == [] and \
+        "dedup@raft-tla-flush" not in off[0].level_log["threads"]
+    # the last harvest of a level hands nothing over: what the level close
+    # merges inline is never the whole of a level of several segments
+    assert sum(on[2]) < on[0].n_transitions
+
+    von = _run_arm(eng, "on", monkeypatch, init_override=planted)
+    voff = _run_arm(eng, "off", monkeypatch, init_override=planted)
+    _same_discovery(von, voff)
+    assert von[0].violation.invariant == invariant
+    assert len(von[0].violation.trace) >= 8 and len(von[2]) >= 2
+
+
+@pytest.mark.parametrize("spec", ["election", "twophase"])
+def test_levels_of_one_segment_never_meet_the_worker(spec, monkeypatch):
+    """The guard for the cells that must not move: where every level's
+    first harvest finishes its block, the worker exists and is handed
+    nothing, and the level close merges the stream inline as before."""
+    eng, _planted, narrow, _invariant = _handover_case(spec)
+    res, _stored, handed, _at = _run_arm(eng, "on", monkeypatch,
+                                         init_override=narrow)
+    assert res.complete and len(res.levels) >= 3
+    assert max(res.levels) <= eng.config.chunk
+    assert all(lv["steps"] <= 1 for lv in res.level_log["levels"])
+    assert handed == []
+    assert "dedup@raft-tla-flush" not in res.level_log["threads"]
+
+
+def test_a_short_tail_of_the_level_is_not_worth_a_handover(monkeypatch):
+    """A hand-over is hidden behind the chunk steps the level has left: with
+    under a third of the steps that streamed the batch still to run (a
+    segment of four, then a tail of one) nothing is handed over, though the
+    level has two segments and the worker is free."""
+    eng = _handover_case("twophase")[0]
+    res, _stored, _handed, handed_at = _run_arm(eng, "on", monkeypatch)
+    seen = set()
+    for lv in res.level_log["levels"]:
+        n = sum(lv["t0"] <= t < lv["t0"] + lv["wall_s"] for t in handed_at)
+        if lv["blocks"] == 1 and lv["steps"] <= 5:
+            assert n == 0, lv           # one segment; or four steps and one
+            seen.add(min(lv["steps"], 5) // 5)
+        elif lv["blocks"] == 1 and lv["steps"] >= 8:
+            assert n >= 1, lv
+            seen.add(2)
+    assert seen == {0, 1, 2}
+
+
+@pytest.mark.parametrize("spec", ["election", "twophase"])
+def test_stop_between_two_handovers_is_lossless(spec, tmp_path, monkeypatch):
+    """The SIGINT flag raised right after a hand-over, with more of the
+    level to come: the stopped pass has flushed every streamed candidate
+    (``n_states`` is the key log's distinct keys) and resumes to the
+    uninterrupted run's totals."""
+    from raft_tla_tpu.utils import flushq
+    eng = _handover_case(spec)[0]
+    straight, _stored, handed, _at = _run_arm(eng, "on", monkeypatch)
+    stop_after = len(handed) // 2
+    submit = flushq.DedupWorker.submit
+    seen = []
+
+    def stopping(self, batch, n_keys):
+        submit(self, batch, n_keys)
+        seen.append(n_keys)
+        if len(seen) == stop_after:
+            eng._sigint = True
+
+    ck = str(tmp_path / "handover.ckpt")
+    with monkeypatch.context() as patch:
+        patch.setattr(flushq.DedupWorker, "submit", stopping)
+        got = eng.check(checkpoint=ck, checkpoint_every_s=3600.0,
+                        retain_store=True)
+    host, constore, keystore, n = eng.retained
+    keys = keystore.read(0, n).copy()
+    for store in (host, constore, keystore):
+        store.close()
+    assert not got.complete and len(seen) == stop_after
+    assert 1 < got.n_states < straight.n_states
+    assert got.n_states == n == len(np.unique(keys.view(np.int64)))
+    resumed = eng.check(resume=ck)
+    assert resumed.complete
+    assert (resumed.n_states, resumed.levels, resumed.n_transitions,
+            resumed.coverage) == (straight.n_states, straight.levels,
+                                  straight.n_transitions, straight.coverage)
 
 
 def test_deadline_stops_cleanly():

@@ -12,6 +12,7 @@ violation or count transitions the reference does not have.
 
 import json
 import math
+import threading
 
 import numpy as np
 import pytest
@@ -22,6 +23,7 @@ from raft_tla_tpu.ddd_engine import (_UP_PIECES, _UP_WHOLE, DDDCapacities,
 from raft_tla_tpu.models import interp, refbfs
 from raft_tla_tpu.models import spec as S
 from raft_tla_tpu.obs import compiles
+from raft_tla_tpu.utils import prefetch as prefetch_mod
 
 BOUNDS = Bounds(n_servers=2, n_values=1, max_term=2, max_log=0, max_msgs=2)
 # the toy's frontiers: 1, 2, 7, 20, 44, 88, 140, 156, 220, 384, 306, 294, 472,
@@ -235,22 +237,52 @@ def _stop_inside_the_partial_block(eng, ck):
     upload, between the first and the second piece of level 384's second
     block (a full block, then 128 rows in 16 pieces: the fourth block of
     the pass that goes in more than one piece, after the levels of 20, 44
-    and 88 rows).  Returns the stopped result."""
-    place, second_pieces = eng._place, []
+    and 88 rows).  Returns the stopped result.
+
+    With the prefetcher the upload runs on its thread while the main one
+    expands the level's first block, so the two are held to one order: the
+    main thread waits at that block's second dispatch (the first full block
+    of the pass) until the flag is up, and the upload waits after raising it
+    until the stop path is in ``invalidate()``.  The flag is then seen
+    inside the first block, after a harvest of it, and ``invalidate()``
+    finds the upload in flight, on any host."""
+    place, segment = eng._place, eng._segment
+    invalidate = prefetch_mod.BlockPrefetcher.invalidate
+    flagged, invalidating = threading.Event(), threading.Event()
+    second_pieces, full_block_dispatches, found_in_flight = [], [], []
 
     def place_and_stop(fbuf, fcon, rows, con, at):
         out = place(fbuf, fcon, rows, con, at)
         if int(at) == eng._up_rows:
             second_pieces.append(at)
             if len(second_pieces) == 4:
+                invalidating.clear()
                 eng._sigint = True
+                flagged.set()
+                if eng._prefetch:
+                    found_in_flight.append(invalidating.wait(60.0))
         return out
 
-    eng._place = place_and_stop
+    def segment_after_the_flag(fc, bufs, fbuf, fcon, budget, b_rows):
+        if eng._prefetch and int(b_rows) == eng.caps.block:
+            full_block_dispatches.append(b_rows)
+            if len(full_block_dispatches) == 2:
+                assert flagged.wait(60.0)
+        return segment(fc, bufs, fbuf, fcon, budget, b_rows)
+
+    def invalidate_and_say_so(self):
+        invalidating.set()
+        invalidate(self)
+
+    eng._place, eng._segment = place_and_stop, segment_after_the_flag
+    prefetch_mod.BlockPrefetcher.invalidate = invalidate_and_say_so
     try:
-        return eng.check(checkpoint=ck, checkpoint_every_s=3600.0)
+        got = eng.check(checkpoint=ck, checkpoint_every_s=3600.0)
     finally:
-        eng._place = place
+        eng._place, eng._segment = place, segment
+        prefetch_mod.BlockPrefetcher.invalidate = invalidate
+    assert found_in_flight == ([True] if eng._prefetch else [])
+    return got
 
 
 @pytest.mark.parametrize("prefetch", [True, False], ids=["pf_on", "pf_off"])
