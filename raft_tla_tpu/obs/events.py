@@ -185,8 +185,10 @@ switches), with the head before the first level, the tail after the last,
 the workers' seams by thread and the stalls the ledger named; in the log of every ddd run, traced or not, and absent from the
 engines that keep no ledger.  Seconds rounded to the microsecond.  A
 level's entry also says what its uploads sent (``upload_bytes``,
-``upload_pieces``: keys inside the record, which the schema does not
-enumerate, so no version moved).
+``upload_pieces``) and what its harvests fetched (``d2h_bytes``, the sum
+of its ``d2h`` spans' ``bytes``; the mesh engine's ``d2h`` span also
+carries ``path``, ``"head"`` or ``"whole"``): keys inside the record and
+span ``args``, which the schema does not enumerate, so no version moved.
 
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the

@@ -27,8 +27,8 @@ names the ``level`` span uses::
 
     {"level", "t0", "gap_s", "wall_s", "rows", "blocks", "segments", "steps",
      "streamed_rows", "new_states", "upload_s", "uploads", "upload_bytes",
-     "upload_pieces", "expand_s", "wait_s", "d2h_s", "dedup_s", "close_s",
-     "cpu_s", "gc_s", "majflt", "nivcsw"}
+     "upload_pieces", "d2h_bytes", "expand_s", "wait_s", "d2h_s", "dedup_s",
+     "close_s", "cpu_s", "gc_s", "majflt", "nivcsw"}
 
 ``wall_s`` is the ``level`` span's own ``dur``; ``gap_s`` is the previous
 level's close -> this one's open (0 for the first: that is the head), which
@@ -43,7 +43,10 @@ doing.
 ``dedup`` at a level's end, so the two overlap); ``uploads`` counts the
 level's ``upload`` spans and ``upload_bytes`` / ``upload_pieces`` sum their
 ``bytes`` and ``pieces`` (what the ddd engine really sent, and in how many
-transfers; 0 where an engine's span does not say); ``cpu_s`` is the main
+transfers; 0 where an engine's span does not say) and ``d2h_bytes`` sums the
+``bytes`` of its ``d2h`` spans (what a harvest really fetched: on the mesh
+the head of each shard's buffers, or the whole buffers when a cursor outgrew
+it); ``cpu_s`` is the main
 thread's CPU time over the level, ``majflt`` / ``nivcsw`` its major faults
 and involuntary switches — all three from one ``getrusage(RUSAGE_THREAD)``
 at each end of the level, so ``cpu_s`` is as fine as the kernel accounts a
@@ -94,8 +97,8 @@ NAMES = frozenset(SEAMS) | {"pass", "level", "prefetch"}
 _COUNTS = ("level", "rows", "blocks", "segments", "steps", "streamed_rows",
            "new_states")
 # beside the seams, what a stall's line says of its level
-_SUSPECTS = ("uploads", "upload_bytes", "upload_pieces", "cpu_s", "gc_s",
-             "majflt", "nivcsw")
+_SUSPECTS = ("uploads", "upload_bytes", "upload_pieces", "d2h_bytes", "cpu_s",
+             "gc_s", "majflt", "nivcsw")
 
 _RUSAGE = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
 # the collector's clock: [seconds collecting so far, start of the open one]
@@ -244,7 +247,7 @@ class PassLog:
             self.record["head_s"], gap = gap, 0.0
         self._cur = {"level": None, "t0": t0, "gap_s": gap, "wall_s": None,
                      **dict.fromkeys(_COUNTS[1:], 0), "uploads": 0,
-                     "upload_bytes": 0, "upload_pieces": 0,
+                     "upload_bytes": 0, "upload_pieces": 0, "d2h_bytes": 0,
                      **dict.fromkeys(SEAM_FIELDS, 0.0)}
         self._base = _counters()
 
@@ -265,6 +268,8 @@ class PassLog:
                     cur["uploads"] += 1
                     cur["upload_bytes"] += args.get("bytes", 0)
                     cur["upload_pieces"] += args.get("pieces", 0)
+                elif name == "d2h":
+                    cur["d2h_bytes"] += args.get("bytes", 0)
         elif name == "level":
             self._close_level(t0, dur, args)
         elif name == "pass":

@@ -104,7 +104,9 @@ class DDDShardCapacities:
     """Static shapes (per shard where noted).  ``block``: per-shard rows
     of one frontier window (a window is ``ndev * block`` global rows);
     ``table``: per-shard lossy filter slots (traffic only, never a
-    ceiling); ``seg_rows``: per-shard output-buffer rows per segment;
+    ceiling); ``seg_rows``: per-shard output-buffer rows per segment (the
+    worst case a chunk can receive bounds it from below; a harvest fetches
+    the buffers' head, ``head_rows``, and all of them only past it);
     ``flush``: per-shard pending candidates per host dedup pass;
     ``send``: per-destination exchange depth per chunk (None = the safe
     bound ``chunk * A``; smaller trades memory for a loud FAIL_ROUTE);
@@ -460,6 +462,27 @@ def _dd_filter_shard(backend):
     return apply
 
 
+def head_rows(caps: DDDShardCapacities) -> int:
+    """Rows of each shard's output buffers that a harvest fetches unless
+    a cursor outgrew them: the power of two at or under ``seg_rows / 16``.
+    ``seg_rows`` is sized for the worst case (every lane of every chip
+    streaming to one owner), a segment's stream for what the frontier
+    admits — 19 rows at level 3 of a space whose buffers hold 1,245,184 —
+    so the head is what crosses d2h and the whole buffers are the
+    fallback."""
+    return 1 << max(0, (caps.seg_rows // 16).bit_length() - 1)
+
+
+def _build_head(H: int):
+    """The first ``H`` rows of a shard's six output arrays as arrays of
+    their own (local view under shard_map): a static slice, one program
+    whatever streamed."""
+    def head(bufs: MBufs) -> MBufs:
+        return MBufs(*(a[:H] for a in bufs))
+
+    return head
+
+
 class DDDShardEngine:
     """Mesh-wide exhaustive checker with host-exact sharded dedup."""
 
@@ -552,6 +575,14 @@ class DDDShardEngine:
                               out_specs=(dd_specs, buf_specs, dp, dp, dp),
                               check_vma=False),
                 donate_argnums=(0, 1))
+        # The harvest's d2h (see _check_impl): dispatched between segment
+        # k and segment k+1, so it never queues behind the speculative
+        # segment, and a static slice, so it compiles once.
+        self._head_rows = head_rows(self.caps)
+        self._head = jax.jit(
+            jax.shard_map(_build_head(self._head_rows), mesh=self.mesh,
+                          in_specs=(buf_specs,), out_specs=buf_specs,
+                          check_vma=False))
         self._in_shardings = [
             NamedSharding(self.mesh, dp) for _ in range(4)]
         # window staging, lazy-alloc: one buffer set per prefetch slot
@@ -901,6 +932,9 @@ class DDDShardEngine:
                 pf_load, phases=tel.phases, tracer=tel.trace)
             _cleanup.callback(prefetcher.close)
         OCAP = self.caps.seg_rows
+        H = self._head_rows
+        # one row of the six output arrays (the ddd engine's d2h ``bytes``)
+        row_bytes = self.schema.P * 4 + 17
         fail = 0
         viol = None        # (kind, inv_idx, key_or_gid) once detected
         stopped = False
@@ -984,7 +1018,19 @@ class DDDShardEngine:
                 # (its chunks lie past the chunk-granular stop point),
                 # and one dispatched past the window's last chunk runs
                 # zero chunks.
-                q = []               # in-flight: (bufset idx, stats, t)
+                #
+                # What a harvest fetches is the HEAD of segment k's buffers
+                # (the first ``head_rows`` of each shard's six arrays),
+                # sliced by a program dispatched right here, between
+                # segment k and segment k+1: dispatched at harvest time it
+                # would queue BEHIND the speculative segment k+1 on the
+                # serial device queue and stall the harvest for a whole
+                # segment (the ddd engine's note at its own d2h), and a
+                # slice of ``cursor`` rows would be a new program at every
+                # harvest.  The whole ``seg_rows`` buffers, sized for every
+                # lane of every chip streaming to one owner, cross only
+                # when some shard's cursor outgrew the head.
+                q = []               # in-flight: (bufset idx, head, stats, t)
                 free = list(range(len(bufsets)))
                 window_done = False
                 t_last_harvest = time.monotonic()
@@ -1011,12 +1057,16 @@ class DDDShardEngine:
                                     dst, bufsets[idx], stats.cursor,
                                     stats.viol_pos)
                                 ph.sync(ncur)
-                        q.append((idx, stats, ncur, dhits, nvp, t_disp))
+                        # after the compaction where the gate is on: the
+                        # head holds the post-filter stream
+                        head = self._head(bufsets[idx])
+                        q.append((idx, head, stats, ncur, dhits, nvp,
+                                  t_disp))
                         if len(q) < 2:
                             continue         # keep the pipeline full
                     if not q:
                         break
-                    idx, stats, ncur, dhits, nvp, t_disp = q.pop(0)
+                    idx, head, stats, ncur, dhits, nvp, t_disp = q.pop(0)
                     with tel.phases.phase("export"):
                         with tr.span("segment_wait"):
                             st_h = jax.device_get(stats)
@@ -1028,9 +1078,16 @@ class DDDShardEngine:
                         lvl_segs += 1
                         lvl_steps += int(st_h.steps)
                         bufs_h = None
+                        # ``stride``: rows a shard of the fetched arrays
+                        src, stride, path = (head, H, "head") \
+                            if cursors.max() <= H \
+                            else (bufsets[idx], OCAP, "whole")
                         if cursors.sum() and not stopped:
-                            with tr.span("d2h", rows=int(cursors.sum())):
-                                bufs_h = jax.device_get(bufsets[idx])
+                            with tr.span(
+                                    "d2h", rows=int(cursors.sum()),
+                                    bytes=self.ndev * stride * row_bytes,
+                                    path=path):
+                                bufs_h = jax.device_get(src)
                     free.append(idx)
                     if stopped:
                         continue             # drop post-stop segments
@@ -1039,7 +1096,7 @@ class DDDShardEngine:
                         ns = int(cursors[s])
                         if not ns:
                             continue
-                        o = s * OCAP
+                        o = s * stride
                         pend[s]["keys"].append(keyset.pack_keys(
                             bufs_h.okey_hi[o:o + ns],
                             bufs_h.okey_lo[o:o + ns]))
@@ -1072,9 +1129,9 @@ class DDDShardEngine:
                         s = int(np.nonzero(vpos >= 0)[0][0])
                         viol = (1, int(np.asarray(st_h.viol_inv)[s]),
                                 int(keyset.pack_keys(
-                                    bufs_h.okey_hi[s * OCAP + vpos[s]]
+                                    bufs_h.okey_hi[s * stride + vpos[s]]
                                     [None],
-                                    bufs_h.okey_lo[s * OCAP + vpos[s]]
+                                    bufs_h.okey_lo[s * stride + vpos[s]]
                                     [None])[0]))
                         stopped = True
                         continue

@@ -224,3 +224,74 @@ def test_manifest_gains_the_five_readers_at_the_end_and_nothing_else():
     assert {by[n]["layer"] for n in METRICS if n != "upload_untraced_ms"} \
         == {"level loop"}
     assert [by[n]["unit"] for n in METRICS] == ["ms", "%", "s", "ms", "s"]
+
+
+# --------------------------------- PR 45: the two readers of the mesh harvest
+
+MESH = "elect5.shard4"
+
+
+def test_ramp_d2h_ms_is_the_ramps_d2h_seam(recorded, monkeypatch):
+    """``levelred`` has reduced the ramp's seams since PR 38; the reader
+    hands out the ``d2h`` one.  By hand: every ramp level of the file spends
+    1 ms there."""
+    read = mf.metric_reader("ramp_d2h_ms")
+    assert read(_evidence(recorded, monkeypatch)) == pytest.approx(1.0)
+    # a resumed pass has no ramp; a program without records has no reading
+    assert read({"levelred": {"ramp_by_seam_ms": None}}) is None
+    assert read(_evidence(recorded, monkeypatch,
+                          snapshot={"records": [], "dropped": 0})) is None
+
+
+def _span(name, thread, t0, **args):
+    return json.dumps({"event": "span", "name": name, "thread": thread,
+                       "t0": t0, "dur": 0.005, "span_id": int(t0 * 1e3),
+                       "parent_id": None, "args": args})
+
+
+def test_harvest_head_pct_counts_the_clocked_spans_d2h_by_path(tmp_path):
+    log = tmp_path / "run.events"
+    p = passes.Pass(index=2, t_call=0.0, t_a=10.0, t_b=20.0, traced=True,
+                    events=str(log), t_trace_end=12.0)
+    ev = {"passes": [p]}
+    read = mf.metric_reader("harvest_head_pct")
+    log.write_text("\n".join([
+        _span("d2h", "MainThread", 9.0, rows=19, path="whole"),  # before A
+        _span("d2h", "MainThread", 10.5, rows=900, path="head"),
+        _span("d2h", "MainThread", 12.0, rows=70000, path="whole"),
+        _span("d2h", "MainThread", 15.0, rows=4000, path="head"),
+        _span("d2h", "MainThread", 19.0, rows=100, path="head"),
+        _span("d2h", "raft-tla-flush", 16.0, rows=1, path="whole"),
+        _span("d2h", "MainThread", 20.5, rows=5, path="whole"),  # past B
+    ]) + "\n")
+    assert read(ev) == pytest.approx(75.0)
+    # the parent's spans carry no path: nothing to read, and nothing raised
+    log.write_text(_span("d2h", "MainThread", 10.5, rows=900) + "\n")
+    assert read(ev) is None
+    log.write_text(_span("level", "MainThread", 10.0, level=13) + "\n")
+    assert read(ev) is None                     # no harvest inside the span
+    log.write_text(json.dumps({"event": "run_start"}) + "\n")
+    assert read(ev) is None                     # a program without spans
+    p.t_b = None
+    assert read(ev) is None                     # a pass that met no B
+    assert read({"passes": []}) is None         # an untraced run
+
+
+def test_manifest_gains_the_two_harvest_readers_for_the_mesh_cell_alone():
+    manifest = mf.load()
+    assert mf.problems(manifest) == []
+    # the 63 entries PR 44 left, then these two (later PRs append after)
+    assert manifest["per_layer"][63:65] == [
+        {"name": "ramp_d2h_ms", "unit": "ms", "better": "lower",
+         "source": "program_counter",
+         "layer": "d2h export and host key set", "moves": "orbits_per_s",
+         "workloads": [MESH]},
+        {"name": "harvest_head_pct", "unit": "%", "better": "higher",
+         "source": "program_span",
+         "layer": "d2h export and host key set", "moves": "orbits_per_s",
+         "workloads": [MESH]}]
+    names = mf.metric_names(manifest, MESH, "per_layer")
+    assert {"ramp_d2h_ms", "harvest_head_pct", "level_host_ms",
+            "host_exposed_s"} <= set(names)
+    for name in ("ramp_d2h_ms", "harvest_head_pct"):
+        assert os.path.isfile(os.path.join(mf.BENCH, "metrics", name + ".py"))

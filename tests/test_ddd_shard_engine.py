@@ -18,8 +18,10 @@ import pytest
 
 # needs the virtual multi-device mesh — the slowest compiles on
 # this 1-core host, excluded from the time-boxed tier-1 window
-# (-m 'not slow'); the shard family stays exercised via -m smoke.
-pytestmark = pytest.mark.slow
+# (-m 'not slow'); the shard family stays exercised via -m smoke.  The
+# harvest's tests at the end of the file (PR 45) are not marked: they are
+# what tier-1 runs of this engine's d2h.
+slow = pytest.mark.slow
 
 from raft_tla_tpu.config import Bounds, CheckConfig
 from raft_tla_tpu.models import interp, refbfs, spec as S
@@ -43,6 +45,7 @@ def assert_totals(got, ref):
     assert sum(got.coverage.values()) == sum(ref.coverage.values())
 
 
+@slow
 @pytest.mark.parametrize("host_dedup", ["on", "off"])
 def test_election_2server_parity_8dev(host_dedup, monkeypatch):
     monkeypatch.setenv("RAFT_TLA_HOSTDEDUP", host_dedup)
@@ -53,6 +56,7 @@ def test_election_2server_parity_8dev(host_dedup, monkeypatch):
     assert got.violation is None
 
 
+@slow
 def test_host_dedup_checkpoint_cross_gate_4dev(tmp_path, monkeypatch):
     """Per-shard partitioned masters rebuild from the same gate-agnostic
     key log: a snapshot written under either arm resumes under the
@@ -72,6 +76,7 @@ def test_host_dedup_checkpoint_cross_gate_4dev(tmp_path, monkeypatch):
         assert resumed.violation is None
 
 
+@slow
 def test_single_dev_mesh_equals_single_chip():
     """ndev=1: canonical order degenerates to the single-chip DDD
     engine's stream order — coverage attribution (order-dependent)
@@ -82,6 +87,7 @@ def test_single_dev_mesh_equals_single_chip():
     assert got.coverage == ref.coverage
 
 
+@slow
 def test_ndev_invariance():
     runs = {n: DDDShardEngine(CFG, make_mesh(n), CAPS).check()
             for n in (1, 2, 8)}
@@ -92,6 +98,7 @@ def test_ndev_invariance():
         assert r.n_transitions == base.n_transitions, n
 
 
+@slow
 def test_multi_segment_windows_8dev():
     """Windows needing several device dispatches (tiny segment budget +
     near-full output buffers) must work: the first continuation call
@@ -112,6 +119,7 @@ def test_multi_segment_windows_8dev():
     assert_totals(got, ref)
 
 
+@slow
 def test_parity_under_forced_eviction_8dev():
     """A 128-slot per-shard filter evicts constantly on a 3014-state
     space; the sharded host dedup must absorb every re-sight."""
@@ -122,12 +130,14 @@ def test_parity_under_forced_eviction_8dev():
     assert_totals(got, ref)
 
 
+@slow
 def test_slice_mesh_2x4_parity():
     ref = refbfs.check(CFG)
     got = DDDShardEngine(CFG, make_slice_mesh(2, 4), CAPS).check()
     assert_totals(got, ref)
 
 
+@slow
 def test_symmetry_composes_8dev():
     cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                     max_log=0, max_msgs=2),
@@ -139,41 +149,49 @@ def test_symmetry_composes_8dev():
     assert got.n_states == 1514
 
 
+VIOL_BOUNDS = Bounds(n_servers=3, n_values=1, max_term=3, max_log=0,
+                     max_msgs=4, max_dup=1)
+VIOL_CFG = CheckConfig(bounds=VIOL_BOUNDS, spec="election",
+                       invariants=("NaiveNoTwoLeaders",), chunk=64)
+VIOL_START = interp.init_state(VIOL_BOUNDS)._replace(
+    role=(S.LEADER, S.FOLLOWER, S.CANDIDATE),
+    term=(2, 3, 3),
+    votedFor=(1, 3, 0),
+    vGrant=(0b011, 0, 0b100),
+    msgs=tuple(sorted((m, 1) for m in
+                      (mb.rv_response(3, 1, 1, 2),))),
+)
+VIOL_CAPS = DDDShardCapacities(block=1 << 12, table=1 << 14,
+                               seg_rows=1 << 15, flush=1 << 12, levels=64)
+
+
+def assert_replayable_violation(got):
+    from raft_tla_tpu.models import invariants as inv_mod
+
+    assert got.violation is not None
+    assert got.violation.invariant == "NaiveNoTwoLeaders"
+    trace = got.violation.trace
+    assert trace[0][0] is None and trace[0][1] == VIOL_START
+    for (_l, prev), (_label, cur) in zip(trace, trace[1:]):
+        succs = [t for _i, t in interp.successors(prev, VIOL_BOUNDS,
+                                                  spec="election")]
+        assert cur in succs
+    assert not inv_mod.py_invariant("NaiveNoTwoLeaders")(
+        got.violation.state, VIOL_BOUNDS)
+
+
+@slow
 def test_violation_trace_replayable_8dev():
     """Seeded NaiveNoTwoLeaders violation: the counterexample may be a
     different one than refbfs's (chunk-granular relaxed stop, as
     shard_engine), but must start at Init, follow real transitions, and
     violate the same invariant."""
-    from raft_tla_tpu.models import invariants as inv_mod
-
-    bounds = Bounds(n_servers=3, n_values=1, max_term=3, max_log=0,
-                    max_msgs=4, max_dup=1)
-    cfg = CheckConfig(bounds=bounds, spec="election",
-                      invariants=("NaiveNoTwoLeaders",), chunk=64)
-    start = interp.init_state(bounds)._replace(
-        role=(S.LEADER, S.FOLLOWER, S.CANDIDATE),
-        term=(2, 3, 3),
-        votedFor=(1, 3, 0),
-        vGrant=(0b011, 0, 0b100),
-        msgs=tuple(sorted((m, 1) for m in
-                          (mb.rv_response(3, 1, 1, 2),))),
-    )
-    caps = DDDShardCapacities(block=1 << 12, table=1 << 14,
-                              seg_rows=1 << 15, flush=1 << 12, levels=64)
-    got = DDDShardEngine(cfg, make_mesh(8), caps).check(
-        init_override=start)
-    assert got.violation is not None
-    assert got.violation.invariant == "NaiveNoTwoLeaders"
-    trace = got.violation.trace
-    assert trace[0][0] is None and trace[0][1] == start
-    for (_l, prev), (_label, cur) in zip(trace, trace[1:]):
-        succs = [t for _i, t in interp.successors(prev, bounds,
-                                                  spec="election")]
-        assert cur in succs
-    assert not inv_mod.py_invariant("NaiveNoTwoLeaders")(
-        got.violation.state, bounds)
+    got = DDDShardEngine(VIOL_CFG, make_mesh(8), VIOL_CAPS).check(
+        init_override=VIOL_START)
+    assert_replayable_violation(got)
 
 
+@slow
 def test_deadlock_detected_8dev():
     cfg = CheckConfig(bounds=Bounds(n_servers=1, n_values=1, max_term=2,
                                     max_log=0, max_msgs=2),
@@ -190,6 +208,7 @@ def test_deadlock_detected_8dev():
     assert not list(interp.successors(dead, cfg.bounds, spec="election"))
 
 
+@slow
 def test_routing_overflow_is_loud():
     caps = DDDShardCapacities(block=256, table=1 << 14, seg_rows=1 << 14,
                               flush=1 << 10, levels=64, send=1)
@@ -197,6 +216,7 @@ def test_routing_overflow_is_loud():
         DDDShardEngine(CFG, make_mesh(8), caps).check()
 
 
+@slow
 def test_checkpoint_resume_exact_8dev(tmp_path):
     ck = str(tmp_path / "dddsh.ckpt")
     mesh = make_mesh(8)
@@ -216,6 +236,7 @@ def test_checkpoint_resume_exact_8dev(tmp_path):
         DDDShardEngine(CFG, make_mesh(4), CAPS).check(resume=ck)
 
 
+@slow
 def test_reshard_across_mesh_sizes(tmp_path):
     """8 -> 2 devices with equal global window size (block scaled 4x):
     every window boundary is shared, the streams move verbatim, and the
@@ -234,6 +255,7 @@ def test_reshard_across_mesh_sizes(tmp_path):
     assert_totals(got, ref)
 
 
+@slow
 def test_adopt_single_chip_checkpoint(tmp_path):
     """A single-chip DDD campaign checkpoint migrates onto the mesh:
     ndev_src=1 with the single-chip block inside caps_src (the stream
@@ -258,6 +280,7 @@ def test_adopt_single_chip_checkpoint(tmp_path):
     assert_totals(got, ref)
 
 
+@slow
 def test_cp_mode_parity_8dev():
     """CP mode (lane-sliced expansion over a replicated window) must
     explore the identical state graph: oracle-exact totals on an
@@ -276,6 +299,7 @@ def test_cp_mode_parity_8dev():
     assert got.coverage.keys() == ref.coverage.keys()
 
 
+@slow
 def test_cp_mode_deadlock_and_violation():
     """The cross-shard enabled-lane psum must not miss deadlocks, and
     violations carry valid traces (dense lane labels)."""
@@ -320,6 +344,7 @@ def test_cp_mode_deadlock_and_violation():
         gv.violation.state, bounds)
 
 
+@slow
 def test_cp_mode_checkpoint_resume(tmp_path):
     cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                     max_log=0, max_msgs=4, max_dup=2),
@@ -341,6 +366,7 @@ def test_cp_mode_checkpoint_resume(tmp_path):
         DDDShardEngine(cfg, mesh, dense).check(resume=ck)
 
 
+@slow
 def test_full_spec_small_parity_8dev():
     cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                     max_log=1, max_msgs=2),
@@ -357,6 +383,7 @@ def test_full_spec_small_parity_8dev():
         assert got.coverage[fam] > 0
 
 
+@slow
 def test_sigint_window_boundary_stop_and_resume(tmp_path):
     """ROADMAP item 8 leftover, chaos-tested in-process: the graceful
     SIGINT contract now reaches the ddd-shard child.  The flag is
@@ -389,3 +416,173 @@ def test_sigint_window_boundary_stop_and_resume(tmp_path):
     assert resumed.complete is True
     assert_totals(resumed, straight)
     assert resumed.coverage == straight.coverage
+
+
+# -- the harvest's d2h: the head of each shard's buffers (PR 45) -------------
+#
+# A harvest fetches the first ``head_rows(caps)`` rows of each shard's six
+# output arrays, sliced by a program queued between segment k and k+1, and
+# the whole ``seg_rows`` buffers only when a cursor outgrew the head.  The
+# same rows have to reach the host in the same order either way: every case
+# is held to the one-chip ``ddd`` engine on the same spec, the 1-device mesh
+# down to the bytes of its checkpoint.
+
+def _harvest_caps(case, ndev):
+    kw = dict(block=1024 // ndev, table=1 << 14, seg_rows=1 << 14,
+              flush=1 << 10, levels=64)
+    if case == "whole":
+        # H = 64 / 32 rows a shard; a level's stream outgrows it from level
+        # 5 on (``send`` lowers the 4-device mesh's floor on seg_rows)
+        kw.update(seg_rows=1024) if ndev == 1 else \
+            kw.update(seg_rows=512, send=64)
+    elif case == "devdedup":
+        kw.update(table=1 << 7)      # the lossy filter leaks: the set drops
+    elif case == "frontier":
+        kw.update(retention="frontier")
+    return DDDShardCapacities(**kw)
+
+
+def _snapshot_digest(path):
+    """A checkpoint as comparable values: the npz's fields and every
+    stream file's size and hash."""
+    import glob
+    import hashlib
+    import os
+
+    out = {}
+    for f in sorted(glob.glob(path + "*")):
+        suffix = f[len(path):]
+        if suffix in ("", ".npz"):
+            with np.load(f) as z:
+                out["npz"] = {k: np.asarray(z[k]).tolist() for k in z.files}
+        else:
+            with open(f, "rb") as fh:
+                out[suffix] = (os.path.getsize(f),
+                               hashlib.sha256(fh.read()).hexdigest())
+    return out
+
+
+@pytest.fixture(scope="module")
+def harvest_runs(tmp_path_factory):
+    """``run(case, ndev)``: one traced ``check()`` with a checkpoint, made
+    once a module; ``ndev`` 0 is the one-chip ``ddd`` engine."""
+    import json
+
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+
+    made = {}
+
+    def run(case, ndev):
+        if (case, ndev) in made:
+            return made[case, ndev]
+        tmp = tmp_path_factory.mktemp(f"harvest_{case}_{ndev}")
+        ck, log = str(tmp / "run.ck"), str(tmp / "run.events")
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("RAFT_TLA_TRACE", "1")
+            mp.setenv("RAFT_TLA_DEVDEDUP",
+                      "hash" if case == "devdedup" and ndev else "off")
+            if ndev:
+                eng = DDDShardEngine(CFG, make_mesh(ndev),
+                                     _harvest_caps(case, ndev))
+            else:
+                eng = DDDEngine(CFG, DDDCapacities(
+                    block=1024, table=1 << 14, flush=1 << 10, levels=64,
+                    retention="frontier" if case == "frontier" else "full"))
+            res = eng.check(checkpoint=ck, checkpoint_every_s=0.0,
+                            events=log)
+        with open(log) as f:
+            evs = [json.loads(line) for line in f]
+        made[case, ndev] = {
+            "result": res, "engine": eng, "digest": _snapshot_digest(ck),
+            "d2h": [e for e in evs if e["event"] == "span"
+                    and e["name"] == "d2h"],
+            "dd_hits": max((e.get("dev_dedup_hits") or 0 for e in evs
+                            if e["event"] == "segment"), default=0)}
+        return made[case, ndev]
+
+    return run
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+@pytest.mark.parametrize("case", ["head", "whole", "devdedup", "frontier"])
+def test_harvest_equals_single_chip(case, ndev, harvest_runs):
+    """(i) every harvest takes the head, (ii) some cursors outgrow it and
+    those harvests take the whole buffers, (iii) the device-dedup gate on:
+    the head is sliced after the compaction, (iv) frontier retention."""
+    ref = harvest_runs("frontier" if case == "frontier" else "head", 0)
+    got = harvest_runs(case, ndev)
+    assert got["result"].n_states == ref["result"].n_states == 3014
+    assert got["result"].levels == ref["result"].levels
+    assert got["result"].n_transitions == ref["result"].n_transitions
+    assert got["result"].violation is None and got["result"].complete
+    if ndev == 1:
+        # the 1-device mesh IS the one-chip engine: digest, counters and
+        # every stream, byte for byte
+        assert got["digest"] == ref["digest"]
+    # the case took the paths it is there for, and said what crossed
+    eng = got["engine"]
+    H, ocap = eng._head_rows, eng.caps.seg_rows
+    row_bytes = eng.schema.P * 4 + 17
+    paths = {sp["args"]["path"] for sp in got["d2h"]}
+    assert paths == ({"head", "whole"} if case == "whole" else {"head"})
+    for sp in got["d2h"]:
+        rows = H if sp["args"]["path"] == "head" else ocap
+        assert sp["args"]["bytes"] == ndev * rows * row_bytes
+    if case == "devdedup":
+        assert got["dd_hits"] > 0
+
+
+def test_harvest_path_leaves_the_streams_alone(harvest_runs):
+    """On the 4-device mesh no other engine has the same order, so the
+    cases are held to each other: head, whole buffers and the compacted
+    head give the same checkpoint, byte for byte."""
+    base = harvest_runs("head", 4)["digest"]
+    assert base["npz"]["n_states"] == 3014
+    for case in ("whole", "devdedup"):
+        assert harvest_runs(case, 4)["digest"] == base, case
+
+
+def test_ledger_d2h_bytes_is_the_heads(harvest_runs):
+    """The pass ledger sums what the harvests fetched: a one-step level
+    moved ``ndev * H`` rows, not the buffers' ``ndev * seg_rows``."""
+    got = harvest_runs("head", 4)
+    eng = got["engine"]
+    row_bytes = eng.schema.P * 4 + 17
+    assert eng._head_rows == 1024 and eng.caps.seg_rows == 1 << 14
+    levels = got["result"].level_log["levels"]
+    first = levels[0]
+    assert (first["level"], first["rows"], first["steps"]) == (1, 1, 1)
+    assert first["d2h_bytes"] == 4 * 1024 * row_bytes
+    assert sum(lv["d2h_bytes"] for lv in levels) == \
+        sum(sp["args"]["bytes"] for sp in got["d2h"])
+    assert levels[-1]["streamed_rows"] == 0 and levels[-1]["d2h_bytes"] == 0
+
+
+def test_violation_in_a_head_segment_8dev(tmp_path, monkeypatch):
+    """The violator's key is read out of the fetched head at the head's
+    stride: the same replayable trace as with the whole buffers fetched
+    (``head_rows`` = 1 sends every harvest of two rows down that path)."""
+    import json
+
+    from raft_tla_tpu.parallel import ddd_shard_engine as mod
+
+    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
+
+    def run(name):
+        log = str(tmp_path / name)
+        got = DDDShardEngine(VIOL_CFG, make_mesh(8), VIOL_CAPS).check(
+            init_override=VIOL_START, events=log)
+        with open(log) as f:
+            evs = [json.loads(line) for line in f]
+        return got, [e["args"]["path"] for e in evs
+                     if e["event"] == "span" and e["name"] == "d2h"]
+
+    head, head_paths = run("head.events")
+    monkeypatch.setattr(mod, "head_rows", lambda caps: 1)
+    whole, whole_paths = run("whole.events")
+    assert set(head_paths) == {"head"} and head_paths
+    assert whole_paths[-1] == "whole"
+    assert_replayable_violation(head)
+    assert head.violation.trace == whole.violation.trace
+    assert head.violation.state == whole.violation.state
+    assert (head.n_states, head.levels) == (whole.n_states, whole.levels)

@@ -33,8 +33,8 @@ N_TOY = 3014
 SEAMS = ("upload_s", "expand_s", "wait_s", "d2h_s", "dedup_s", "close_s")
 FIELDS = {"level", "t0", "gap_s", "wall_s", "rows", "blocks", "segments",
           "steps", "streamed_rows", "new_states", "upload_s", "uploads",
-          "upload_bytes", "upload_pieces", "expand_s", "wait_s", "d2h_s",
-          "dedup_s", "close_s", "cpu_s", "gc_s", "majflt", "nivcsw"}
+          "upload_bytes", "upload_pieces", "d2h_bytes", "expand_s", "wait_s",
+          "d2h_s", "dedup_s", "close_s", "cpu_s", "gc_s", "majflt", "nivcsw"}
 
 
 def _build(kind):
@@ -184,6 +184,26 @@ def test_upload_bytes_and_pieces_are_the_sums_of_the_upload_spans(two_passes):
             assert lv["upload_bytes"] >= lv["rows"] * 4
         else:
             assert (lv["upload_bytes"], lv["upload_pieces"]) == (0, 0)
+
+
+def test_d2h_bytes_is_the_sum_of_the_d2h_spans(two_passes):
+    """What a level's harvests fetched (ISSUE 45): ``d2h_bytes`` of an entry
+    is the sum of ``bytes`` over the ``d2h`` spans inside that level's span,
+    traced pass and untraced pass alike, on both engines: the one-chip
+    engine's whole buffer set, the mesh's head of each shard's buffers."""
+    traced = two_passes["traced"].level_log["levels"]
+    assert [lv["d2h_bytes"] for lv in traced] == \
+        [lv["d2h_bytes"] for lv in two_passes["plain"].level_log["levels"]]
+    d2h = [e for e in two_passes["events"]
+           if e["event"] == "span" and e["name"] == "d2h"]
+    assert d2h and all(e["args"]["bytes"] > 0 for e in d2h)
+    for lv, sp in zip(traced, two_passes["levels"]):
+        inside = [e["args"]["bytes"] for e in d2h
+                  if sp["t0"] <= e["t0"] < sp["t0"] + sp["dur"]]
+        assert lv["d2h_bytes"] == sum(inside)
+        assert (lv["d2h_bytes"] > 0) == (lv["streamed_rows"] > 0)
+    if two_passes["kind"] == "ddd-shard":
+        assert {e["args"]["path"] for e in d2h} == {"head"}
 
 
 def _drive(plog, levels=1):
