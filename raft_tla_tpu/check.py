@@ -14,9 +14,11 @@ writes the matching ``MCraft.tla``/``MCraft.cfg`` pair so the identical
 bounded model can be run under stock TLC on a JVM host (oracle parity,
 SURVEY §4.3).
 
-Engines (``--engine``): ``device`` (default; full search resident on the
-accelerator), ``shard`` (multi-device mesh over ICI), ``host`` (per-chunk
-jit, host dedup), ``ref`` (pure-Python oracle BFS).
+Engines (``--engine``), six: ``device`` (default; full search resident on
+the accelerator), ``ddd`` (delayed duplicate detection: exact dedup in host
+RAM, the engine the benchmark measures), ``shard`` (multi-device mesh over
+ICI), ``ddd-shard`` (``ddd`` sharded over a mesh), ``host`` (per-chunk jit,
+host dedup), ``ref`` (pure-Python oracle BFS).
 """
 
 from __future__ import annotations
@@ -52,17 +54,13 @@ def build_argparser() -> argparse.ArgumentParser:
                         "--max-term is the maximum ballot); both "
                         "frontend-compiled, --engine host or ddd")
     p.add_argument("--engine", default="device",
-                   choices=("device", "paged", "streamed", "ddd", "shard",
-                            "pagedshard", "ddd-shard", "host", "ref"),
-                   help="device: search resident in HBM; paged: HBM ring + "
-                        "native host store (capacity bounded by host RAM); "
-                        "streamed: host-streamed frontier (no live-window "
-                        "ceiling — for spaces whose BFS levels outgrow any "
-                        "ring); ddd: delayed duplicate detection — exact "
-                        "dedup on the host, no device fingerprint-table "
-                        "ceiling (for spaces past ~2^28 distinct states); "
-                        "shard: multi-chip mesh; pagedshard: mesh "
-                        "whose per-device stores page to host RAM; "
+                   choices=("device", "ddd", "shard", "ddd-shard", "host",
+                            "ref"),
+                   help="device: search resident in HBM; ddd: delayed "
+                        "duplicate detection — host-streamed frontier "
+                        "blocks, exact dedup on the host, no device "
+                        "fingerprint-table ceiling (for spaces past ~2^28 "
+                        "distinct states); shard: multi-chip mesh; "
                         "ddd-shard: mesh-sharded DDD — host-exact dedup "
                         "partitioned over the fingerprint-owner map (the "
                         "scale engine's multi-chip composition); host: "
@@ -94,15 +92,11 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="frontier states expanded per device step")
     p.add_argument("--cap", type=int, default=1 << 20,
                    help="expected distinct-state capacity: store rows for "
-                        "device/shard; fingerprint-table sizing (2 slots "
-                        "per state) for paged, whose store itself is host-"
-                        "RAM-bounded")
+                        "device/shard; filter-table sizing (2 slots per "
+                        "state) for ddd/ddd-shard, whose store itself is "
+                        "host-RAM-bounded")
     p.add_argument("--levels", type=int, default=256,
                    help="max BFS depth (device/shard engines)")
-    p.add_argument("--ring", type=int, default=None,
-                   help="HBM ring rows for --engine paged (power of two; "
-                        "must hold the widest current+next BFS level pair; "
-                        "default: derived from --cap, at most 4M)")
     p.add_argument("--devices", type=int, default=None,
                    help="mesh size for --engine shard (default: all)")
     p.add_argument("--seg-chunks", type=int, default=256,
@@ -167,7 +161,7 @@ def build_argparser() -> argparse.ArgumentParser:
                         "votesResponded/votesGranted of non-Candidates — "
                         "collapses dead vote-set freight, same verdicts)")
     p.add_argument("--slices", type=int, default=None,
-                   help="multi-slice scale-out for shard/pagedshard: build "
+                   help="multi-slice scale-out for shard/ddd-shard: build "
                         "a 2-D (dcn, ici) mesh of N slices x (devices/N) "
                         "chips with the hierarchical dedup exchange "
                         "(default: single-slice 1-D mesh)")
@@ -190,13 +184,12 @@ def build_argparser() -> argparse.ArgumentParser:
                         "relation; 'none' = no fairness, the reference "
                         "spec's actual Spec, raft.tla:469)")
     p.add_argument("--checkpoint", metavar="PATH",
-                   help="periodically snapshot the search (device/paged/"
-                        "shard engines); resume later with --resume")
+                   help="periodically snapshot the search (every device "
+                        "engine); resume later with --resume")
     p.add_argument("--checkpoint-every", type=float, default=120.0,
                    metavar="SECONDS")
     p.add_argument("--resume", metavar="PATH",
-                   help="resume a --checkpoint snapshot (device/paged/"
-                        "shard engines)")
+                   help="resume a --checkpoint snapshot")
     p.add_argument("--deadline", type=float, default=None,
                    metavar="SECONDS",
                    help="stop losslessly at the first segment boundary "
@@ -275,7 +268,7 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="skip the static width-safety pass")
     p.add_argument("--stats", action="store_true",
                    help="emit one JSON line of run stats per search segment "
-                        "on stderr (device/paged/shard engines)")
+                        "on stderr (every device engine)")
     p.add_argument("--events", metavar="PATH",
                    help="append the versioned JSONL run-event log "
                         "(run_start/segment/level_end/checkpoint/"
@@ -387,7 +380,7 @@ def _simulate(args, config):
     from raft_tla_tpu.engine import DEADLOCK
     if args.fleet:
         from raft_tla_tpu.fleet import FleetSimulator
-        from raft_tla_tpu.parallel.shard_engine import make_mesh
+        from raft_tla_tpu.parallel.mesh import make_mesh
         sim = FleetSimulator(config, mesh=make_mesh(args.devices),
                              walkers=args.walkers, depth=args.depth,
                              seed=args.seed, steer_tau=args.steer,
@@ -441,7 +434,7 @@ def _make_cli_mesh(args):
     """1-D mesh, or the 2-D (dcn, ici) slice mesh when --slices is given."""
     import jax
 
-    from raft_tla_tpu.parallel.shard_engine import make_mesh, make_slice_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh, make_slice_mesh
     if args.slices is None:
         return make_mesh(args.devices)
     nd = args.devices if args.devices is not None else len(jax.devices())
@@ -467,35 +460,6 @@ def _run(args, config):
     if args.engine == "host":
         from raft_tla_tpu import engine
         return engine.check(config)
-    if args.engine == "paged":
-        from raft_tla_tpu.models import spec as S
-        from raft_tla_tpu.paged_engine import PagedCapacities, PagedEngine
-        A = len(S.action_table(config.bounds, config.spec))
-        table = 1 << max(1, (2 * args.cap - 1).bit_length())
-        if args.ring is not None:
-            # Explicit ring: pass through untouched — PagedEngine rejects
-            # undersized rings loudly (never silently resize, SURVEY §4.5).
-            ring = args.ring
-        else:
-            ring = max(1 << min(22, max(12, (args.cap // 4).bit_length())),
-                       1 << (2 * args.chunk * A - 1).bit_length())
-        eng = PagedEngine(config, PagedCapacities(
-            ring=ring, table=table, levels=args.levels))
-        return eng.check(on_progress=_stats_cb(args),
-                         checkpoint=args.checkpoint,
-                         checkpoint_every_s=args.checkpoint_every,
-                         resume=args.resume)
-    if args.engine == "streamed":
-        from raft_tla_tpu.streamed_engine import (StreamedCapacities,
-                                                  StreamedEngine)
-        table = 1 << max(1, (2 * args.cap - 1).bit_length())
-        ring = args.ring if args.ring is not None else 1 << 22
-        eng = StreamedEngine(config, StreamedCapacities(
-            block=1 << 20, ring=ring, table=table, levels=args.levels))
-        return eng.check(on_progress=_stats_cb(args),
-                         checkpoint=args.checkpoint,
-                         checkpoint_every_s=args.checkpoint_every,
-                         resume=args.resume)
     if args.engine == "ddd":
         from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
         from raft_tla_tpu.frontend import resolve_model
@@ -549,27 +513,6 @@ def _run(args, config):
                           ShardCapacities(n_states=args.cap,
                                           levels=args.levels),
                           seg_chunks=args.seg_chunks)
-        return eng.check(checkpoint=args.checkpoint,
-                         checkpoint_every_s=args.checkpoint_every,
-                         resume=args.resume, on_progress=_stats_cb(args))
-    if args.engine == "pagedshard":
-        from raft_tla_tpu.models import spec as S
-        from raft_tla_tpu.parallel.paged_shard_engine import (
-            PagedShardCapacities, PagedShardEngine)
-        A = len(S.action_table(config.bounds, config.spec))
-        # --cap is the expected distinct-state total across the mesh;
-        # tables shard it, rings hold each device's live window share
-        table = 1 << max(1, (2 * args.cap - 1).bit_length())
-        mesh = _make_cli_mesh(args)
-        nd = mesh.devices.size
-        ring = args.ring if args.ring is not None else max(
-            1 << min(22, max(12, (args.cap // (4 * nd)).bit_length())),
-            1 << (2 * args.chunk * A - 1).bit_length())
-        # per-device table share, rounded up to a power of two (the
-        # bucket mask is bitwise)
-        tbl_d = 1 << max(10, ((table + nd - 1) // nd - 1).bit_length())
-        eng = PagedShardEngine(config, mesh, PagedShardCapacities(
-            ring=ring, table=tbl_d, levels=args.levels))
         return eng.check(checkpoint=args.checkpoint,
                          checkpoint_every_s=args.checkpoint_every,
                          resume=args.resume, on_progress=_stats_cb(args))
@@ -644,8 +587,7 @@ def main(argv=None) -> int:
         # (ops/devdedup.devdedup_backend) by the ddd engine families.
         import os
         os.environ["RAFT_TLA_DEVDEDUP"] = args.device_dedup
-    _DEVICE_ENGINES = ("device", "paged", "streamed", "ddd", "shard",
-                       "pagedshard", "ddd-shard")
+    _DEVICE_ENGINES = ("device", "ddd", "shard", "ddd-shard")
     if args.view and args.simulate:
         p.error("--view does not compose with --simulate (random walks "
                 "replay concrete states; a view only folds dedup keys)")
@@ -985,7 +927,7 @@ def _check_liveness(args, config, props) -> int:
         if args.engine in ("host", "ref") and not config.view:
             graph = liveness.explore_graph(config)
         elif config.view or config.symmetry or args.engine in (
-                "ddd", "ddd-shard", "streamed"):
+                "ddd", "ddd-shard"):
             from raft_tla_tpu.ddd_engine import DDDCapacities
             from raft_tla_tpu.models import spec as S
             if config.symmetry:

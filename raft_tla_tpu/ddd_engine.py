@@ -1,7 +1,7 @@
-"""Delayed-duplicate-detection engine — exact dedup on the host (paged v3).
+"""Delayed-duplicate-detection engine — exact dedup on the host.
 
-Every prior device engine keeps the EXACT fingerprint set in HBM, which
-caps distinct-state capacity at ~2^28 slots (2 GiB single-buffer limit;
+The ``device`` and ``shard`` engines keep the EXACT fingerprint set in
+HBM, which caps distinct-state capacity at ~2^28 slots (2 GiB buffer limit;
 the elect5 campaign measured probing degrade as load crossed 0.48 near
 130M orbits — RESULTS.md "capacity findings").  This engine removes the
 device table from the correctness path entirely, the external-memory
@@ -29,7 +29,8 @@ regime TLC itself uses for its `states/` fingerprint set
   traces — is byte-identical to the oracle and every other engine (the
   parity suite asserts it, including under forced filter eviction).
 - **Level-synchronous BFS** keeps counts exact: new states join the next
-  level only (frontier blocks stream host→device as in streamed_engine).
+  level only (the frontier streams host→device one block at a time, so
+  no level has to fit on the device).
 
 Capacity: master keys 8 B/state + packed rows in host RAM (~10^9 states
 on this host), no device table in the correctness path — the designed
@@ -43,8 +44,9 @@ always genuinely new — a previously-seen state with a failing invariant
 would have stopped the run at ITS first occurrence — so after a forced
 flush the violator is the last appended state (asserted by key).
 
-Checkpoints are fully incremental: rows/links/constraints stream as in
-streamed_engine, and the master keys are checkpointed as their
+Checkpoints are fully incremental: rows/links/constraints are appended to
+``.rows`` / ``.links`` / ``.con`` streams (``ckpt.stream_rows_append``),
+and the master keys are checkpointed as their
 *discovery-order append log* (a width-2 int32 native store) — sorted
 back into the master on resume.  Snapshots land at block boundaries with
 an empty pending buffer, so resume never re-expands or double-counts.
@@ -1005,8 +1007,8 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
 
         # refbfs-exact truncation: first invariant violation (violator
         # kept) vs first dead row (its and later rows' candidates cut),
-        # ordered the way streamed_engine orders them (flat candidate
-        # position vs drow * A)
+        # ordered by flat candidate position (a dead row ``drow`` sits at
+        # ``drow * A``)
         with jax.named_scope("invariants"):
             inv_bad = cand_act & jnp.any(~inv_ok_rows, axis=-1) if n_inv \
                 else jnp.zeros((NK,), bool)
@@ -1422,7 +1424,6 @@ class DDDEngine:
                                           prefix="ddd_frontier_")
         # fresh run: any stream files at the checkpoint path belong to
         # some other run — remove before incremental appends trust them
-        # (same contract as streamed_engine.check)
         _SUFFIXES = (".rows", ".links", ".con", ".keys")
         if checkpoint and not (resume and os.path.abspath(resume)
                                == os.path.abspath(checkpoint)):

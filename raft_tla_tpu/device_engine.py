@@ -103,8 +103,8 @@ class Capacities:
 # apply.  For this EXACT table the size is a correctness requirement
 # (unlike the DDD engines' shrinkable lossy filter), so large --cap
 # runs pay ~45 ms/chunk per GiB of table; that copy, not the probe
-# gathers, is most of what the round-2 "paged engine at 2^28 slots
-# measured ~8k orbits/s" observation was.  The DDD engines are the
+# gathers, is what an exact table of 2^28 slots costs (round 2 measured
+# ~8k orbits/s there).  The DDD engines are the
 # designed escape (host-exact dedup, small filter).
 def _dedup_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
     """Batched insert-if-absent of fingerprint pairs into the hash set.
@@ -215,18 +215,17 @@ FAIL_WIDTH = 1      # a successor exceeded a tensor-encoding capacity
 FAIL_PROBE = 2      # linear probe exceeded _MAX_PROBE (table too full)
 FAIL_STORE = 4      # more distinct states than Capacities.n_states
 FAIL_LEVEL = 8      # BFS deeper than Capacities.levels
-FAIL_RING = 16      # paged engine: live BFS window outgrew the HBM ring
 FAIL_ROUTE = 32     # a routing budget overflowed: shard engine's
                     # all_to_all exchange halo, or the EP-routed step's
                     # route_rows compaction slots (ddd_engine)
-FAIL_INDEX = 64     # paged engine: discovery index near the int32 ceiling
+FAIL_INDEX = 64     # ddd engines: discovery index past their ceiling
+                    # (ddd_engine._IDX_CEIL)
 
 _FAIL_TEXT = {
     FAIL_WIDTH: "state-width overflow (encoding capacity exceeded)",
     FAIL_PROBE: "fingerprint-table probe overflow (table too full)",
     FAIL_STORE: "state-store capacity exceeded",
     FAIL_LEVEL: "BFS level capacity exceeded",
-    FAIL_RING: "live BFS window exceeded the HBM ring",
     FAIL_ROUTE: "routing budget exceeded (all_to_all halo or EP "
                 "route_rows too small)",
     FAIL_INDEX: "global state index reached the int32 ceiling "
@@ -249,8 +248,8 @@ def decode_fail(fail_bits: int) -> str:
 # and shard engines bound rows by Capacities.n_states (far below 2^31 at
 # any allocatable HBM size; the shard engine additionally asserts
 # ndev * n_states fits the int32 global-id space at construction), and the
-# paged engine fails loudly via FAIL_INDEX before its global discovery
-# index could wrap.
+# ddd engines keep discovery indices on the host as int64 (FAIL_INDEX is
+# their loud guard).
 
 def _acc64_zero():
     return jnp.zeros((2,), U32)

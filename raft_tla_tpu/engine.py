@@ -68,9 +68,9 @@ class EngineResult:
     violation: Optional[Violation]
     levels: list           # new-state count per level (levels[0] = 1)
     wall_s: float
-    # False only for deadline-bounded partial runs (PagedEngine.check
-    # deadline_s — the bench's time-boxed north-star workload); every
-    # exhaustive verdict above requires complete=True.
+    # False only for runs stopped before the search's end (the ddd
+    # engines' deadline_s / SIGINT stop); every exhaustive verdict above
+    # requires complete=True.
     complete: bool = True
     # the pass ledger's record of this check() (obs/passlog.py: one entry a
     # level, the head, the tail, the workers' seams), shared with the

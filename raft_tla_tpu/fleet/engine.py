@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from raft_tla_tpu.config import CheckConfig
 from raft_tla_tpu.engine import DEADLOCK, Violation
-from raft_tla_tpu.parallel.shard_engine import _AXIS, make_mesh
+from raft_tla_tpu.parallel.mesh import _AXIS, make_mesh
 from raft_tla_tpu.simulate import resolve_sim_model
 
 I32 = jnp.int32

@@ -2,9 +2,9 @@
 engine family emits.
 
 Before this module each engine family grew its own ad-hoc ``on_progress``
-dict (device/paged's ``_progress_stats``, streamed's method of the same
-name, the shard engines' ``n_devices`` variant, and the ddd engines'
-``progress()`` closures with their incremental-rate anchors).  Campaign
+dict (the device engine's ``_progress_stats``, the shard engines'
+``n_devices`` variant, and the ddd engines' ``progress()`` closures with
+their incremental-rate anchors).  Campaign
 state then lived in hand-rolled ``runs/*.stats`` streams plus an
 undocumented ``.telemetry`` column format, and a resumed run's cumulative
 ``states_per_sec`` silently inflated (prior-process states / this-process

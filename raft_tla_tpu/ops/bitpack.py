@@ -4,9 +4,8 @@ The flat ``int32[W]`` state vector (ops/state.py) spends a full 32-bit word
 on every field element, though no field needs more than 29 bits and most
 need 2-6: the 3-server/2-value flagship layout is 60 words (240 B) carrying
 ~390 useful bits (~49 B).  HBM capacity and host↔device pageout bandwidth
-are the checker's scaling limits (the full 3s/2v run died when a BFS level
-pair outgrew the ring), so the paged engine stores rows *bit-packed* at
-~4-5x density and unpacks only the chunk being expanded.
+are the checker's scaling limits, so the ddd engines store and move rows
+*bit-packed* at ~4-5x density and unpack only the chunk being expanded.
 
 The packing is a static bitstream: field element w occupies bits
 ``[start[w], start[w] + bits[w])`` of the row, where ``bits[w]`` is derived
@@ -17,7 +16,7 @@ of shifts and ors that XLA fuses into the surrounding kernel — no gathers,
 no loops.
 
 Dual-backend (``xp`` = numpy | jax.numpy), like ops/state.py: the host
-store holds the same packed bytes the device ring holds, and the trace
+store holds the same packed bytes the device block holds, and the trace
 decoder unpacks with the identical code path.
 """
 

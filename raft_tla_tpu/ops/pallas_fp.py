@@ -6,7 +6,7 @@ one [rows, lanes] elementwise product, a lane reduction, and a handful of
 shifts.  XLA already fuses the jnp version into the surrounding step
 kernel, so this Pallas twin exists for the cases where the fingerprint
 runs *standalone* over large row blocks (host-store audits, re-hashing a
-paged store after a bounds change, the sharded engine's routing prefix)
+host store after a bounds change, the sharded engine's routing prefix)
 and as the reference pattern for hand-scheduled kernels in this codebase:
 explicit VMEM blocking over a 1-D grid, broadcast constants, lane-padded
 inputs.

@@ -1,16 +1,17 @@
-from raft_tla_tpu.parallel.shard_engine import (  # noqa: F401
-    ShardCapacities, ShardEngine, check, make_mesh, make_slice_mesh,
-    reshard_checkpoint)
+from raft_tla_tpu.parallel.mesh import (  # noqa: F401
+    make_mesh, make_slice_mesh)
 
-# The paged-shard engine (pulls utils.native: a g++ build on first use)
-# and the CP expansion load lazily — importing the package stays as
-# cheap as the repo's lazy-import layering everywhere else assumes.
+# The engines and the CP expansion load lazily — importing the package
+# stays as cheap as the repo's lazy-import layering everywhere else
+# assumes (ddd_shard_engine pulls utils.native: a g++ build on first use).
 _LAZY = {
+    "ShardCapacities": "shard_engine",
+    "ShardEngine": "shard_engine",
+    "check": "shard_engine",
+    "reshard_checkpoint": "shard_engine",
     "DDDShardCapacities": "ddd_shard_engine",
     "DDDShardEngine": "ddd_shard_engine",
     "reshard_ddd_checkpoint": "ddd_shard_engine",
-    "PagedShardCapacities": "paged_shard_engine",
-    "PagedShardEngine": "paged_shard_engine",
     "build_cp_expand": "cp_expand",
     "build_cp_step": "cp_expand",
     "cp_lane_count": "cp_expand",

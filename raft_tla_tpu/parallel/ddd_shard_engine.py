@@ -87,7 +87,7 @@ from raft_tla_tpu.ops import devdedup
 from raft_tla_tpu.ops import kernels
 from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym_mod
-from raft_tla_tpu.parallel.shard_engine import (
+from raft_tla_tpu.parallel.mesh import (
     _AXIS, _DCN, _mesh_axes, exchange, make_mesh)
 from raft_tla_tpu.utils import ckpt
 from raft_tla_tpu.utils import keyset

@@ -1,8 +1,8 @@
 """Shared checkpoint machinery for the engines (TLC ``-recover`` analog).
 
 One definition site for the soundness-critical parts so the engines cannot
-drift (a review round caught the device engine's digest missing
-``symmetry`` while the paged engine's had it):
+drift (a review round caught one engine's digest missing ``symmetry``
+while another's had it):
 
 - :func:`config_digest` — pins the full model identity (bounds, spec
   subset, invariants, **symmetry**, chunk, capacities) *and the initial
@@ -297,7 +297,7 @@ def stream_rows_in(path: str, writer, limit: int,
     The stream may legitimately hold MORE rows than ``limit``: snapshots
     write the (append-only, stable-prefix) streams before the metadata
     npz, so a crash between the two leaves longer streams next to an older
-    ``paged`` counter — the excess is simply ignored.  Fewer rows than
+    row counter — the excess is simply ignored.  Fewer rows than
     ``limit`` means a genuinely torn snapshot and is an error.
 
     ``expect_width`` pins the caller's current row layout: the config

@@ -1,12 +1,11 @@
 """Adaptive segment pacing — the shared chunks-per-dispatch controller.
 
-Every segmented engine (device, paged, streamed, ddd, shard, pagedshard)
-runs its search as repeated device dispatches of ``budget`` chunks and
-retunes the budget after each one.  The controller had been copied
-inline into all six loops; any fix (e.g. the executed-count ADVICE fix)
-had to be replicated six times.  This is the single implementation.
+Every segmented engine (device, ddd, shard, ddd-shard) runs its search as
+repeated device dispatches of ``budget`` chunks and retunes the budget
+after each one, through this one controller: a fix to the policy (e.g. the
+executed-count ADVICE fix) is made once.
 
-Policy (unchanged from the engines' inline copies):
+Policy:
 
 - aim each dispatch at ``target_s`` wall seconds (geometric scaling,
   bounded to [0.25x, 2x] per step, clamped into [lo, hi]);

@@ -55,7 +55,7 @@ import jax
 
 from raft_tla_tpu.config import Bounds, CheckConfig
 from raft_tla_tpu.fleet import FleetSimulator
-from raft_tla_tpu.parallel.shard_engine import make_mesh
+from raft_tla_tpu.parallel.mesh import make_mesh
 from raft_tla_tpu.simulate import Simulator
 
 _ints = [int(a) for a in sys.argv[1:] if a.isdigit()]

@@ -58,34 +58,6 @@ def test_checkpoint_file_is_atomic_npz(tmp_path):
     assert not (tmp_path / "search.ckpt.tmp").exists()
 
 
-def test_paged_checkpoint_resume_bit_exact(tmp_path):
-    from raft_tla_tpu.paged_engine import PagedCapacities, PagedEngine
-    ckpt = str(tmp_path / "paged.ckpt")
-    cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
-                                    max_log=0, max_msgs=2),
-                      spec="election", invariants=("NoTwoLeaders",),
-                      chunk=16)
-    caps = PagedCapacities(ring=2048, table=1 << 13, levels=64)
-    eng = PagedEngine(cfg, caps, seg_chunks=8)
-    eng.SEG_MAX = 8
-    straight = eng.check()
-    eng2 = PagedEngine(cfg, caps, seg_chunks=8)
-    eng2.SEG_MAX = 8
-    eng2.check(checkpoint=ckpt, checkpoint_every_s=0.0)
-    eng3 = PagedEngine(cfg, caps, seg_chunks=8)
-    eng3.SEG_MAX = 8
-    resumed = eng3.check(resume=ckpt)
-    assert resumed.n_states == straight.n_states == 3014
-    assert resumed.levels == straight.levels
-    assert resumed.coverage == straight.coverage
-    assert resumed.n_transitions == straight.n_transitions
-
-    other = PagedEngine(cfg, PagedCapacities(ring=4096, table=1 << 13,
-                                             levels=64))
-    with pytest.raises(ValueError, match="checkpoint"):
-        other.check(resume=ckpt)
-
-
 def test_stream_rows_width_mismatch_rejected(tmp_path):
     """A packed-row layout change must refuse to resume old streams: the
     config digest does not cover the bit-pack schema (review finding)."""
@@ -209,27 +181,19 @@ def test_stream_append_shrink_and_stale_protection(tmp_path):
     ckpt.stream_rows_in(p, got.append, 5, expect_width=3)
     assert np.array_equal(np.concatenate(got), data[:5])
 
-    # a FRESH StreamedEngine run pointed at a path holding another run's
+    # a FRESH DDDEngine run pointed at a path holding another run's
     # streams must rewrite them from scratch (not append-reuse)
-    from raft_tla_tpu.config import Bounds, CheckConfig
-    from raft_tla_tpu.streamed_engine import (StreamedCapacities,
-                                              StreamedEngine)
-    cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
-                                    max_log=0, max_msgs=2),
-                      spec="election", invariants=("NoTwoLeaders",),
-                      chunk=32)
-    caps = StreamedCapacities(block=256, ring=4096, table=1 << 14,
-                              levels=64)
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+    caps = DDDCapacities(block=256, table=1 << 14, flush=1 << 10,
+                         levels=64)
     ck = str(tmp_path / "fresh.ckpt")
+    eng = DDDEngine(CFG, caps)
     # plant a bogus stream at the checkpoint path
+    P = eng.schema.P
     ckpt.stream_rows_out(ck + ".rows", lambda s, n: np.full(
-        (n, StreamedEngine(cfg, caps).schema.P), -7, np.int32), 100,
-        StreamedEngine(cfg, caps).schema.P)
-    eng = StreamedEngine(cfg, caps, seg_chunks=8)
-    eng.SEG_MAX = 8
+        (n, P), -7, np.int32), 100, P)
     straight = eng.check(checkpoint=ck, checkpoint_every_s=0.0)
-    eng2 = StreamedEngine(cfg, caps, seg_chunks=8)
-    resumed = eng2.check(resume=ck)
+    resumed = DDDEngine(CFG, caps).check(resume=ck)
     assert resumed.n_states == straight.n_states == 3014
     assert resumed.levels == straight.levels
 

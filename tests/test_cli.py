@@ -256,20 +256,20 @@ def test_cli_init_next_stanzas(tmp_path):
     assert code == cli.EXIT_ERROR
 
 
-def test_cli_streamed_and_pagedshard_engines(tmp_path):
-    """The two round-2 engines run end-to-end from the CLI with the
-    standard report and exit code."""
+@pytest.mark.parametrize("argv, said", [
+    (("--engine", "paged"), "invalid choice: 'paged'"),
+    (("--engine", "streamed"), "invalid choice: 'streamed'"),
+    (("--engine", "pagedshard"), "invalid choice: 'pagedshard'"),
+    (("--ring", "4096"), "unrecognized arguments: --ring 4096"),
+])
+def test_cli_refuses_a_removed_engine_or_flag(argv, said, tmp_path, capsys):
+    """The three engines removed in PR 46 and their ``--ring`` are unknown
+    to argparse like any other word: exit 2, its own message, no alias."""
     cfg = write_cfg(tmp_path / "e.cfg")
-    code, out = run_cli(cfg, "--engine", "streamed", "--spec", "election",
-                        "--max-term", "2", "--max-log", "0",
-                        "--max-msgs", "2", "--chunk", "64",
-                        "--cap", "65536", "--ring", "8192")
-    assert code == 0 and "3014 distinct states" in out
-    code, out = run_cli(cfg, "--engine", "pagedshard", "--spec",
-                        "election", "--max-term", "2", "--max-log", "0",
-                        "--max-msgs", "2", "--chunk", "64",
-                        "--cap", "65536", "--devices", "8")
-    assert code == 0 and "3014 distinct states" in out
+    with pytest.raises(SystemExit) as e:
+        cli.main([cfg, *argv])
+    assert e.value.code == 2
+    assert said in capsys.readouterr().err
 
 
 def test_cli_ddd_engine(tmp_path):

@@ -20,7 +20,7 @@ from raft_tla_tpu.models import interp, spec as SP
 from raft_tla_tpu.ops import kernels
 from raft_tla_tpu.parallel.cp_expand import (
     build_cp_step, cp_lane_count, cp_lane_map)
-from raft_tla_tpu.parallel.shard_engine import make_mesh, _AXIS
+from raft_tla_tpu.parallel.mesh import make_mesh, _AXIS
 
 from test_state import random_pystate
 

@@ -512,6 +512,25 @@ def test_deadline_stops_cleanly():
     assert got.violation is None
 
 
+def test_deadline_stopped_pass_reports_live_coverage():
+    """The progress stream carries per-action coverage (TLC ``-coverage 1``
+    analog) and a deadline stop is lossless: every record that closes a
+    level (the host key set has merged all that streamed; inside a level
+    the flush worker may be a batch ahead of the count) and the stopped
+    pass's result credit each state found but Init to one action."""
+    eng = _handover_case("election")[0]     # a segment is one 8-row chunk
+    stats: list = []
+    part = eng.check(deadline_s=0.25, on_progress=stats.append)
+    assert not part.complete and 1 < part.n_states < 3014
+    closes = [rec for rec, nxt in zip(stats, stats[1:])
+              if nxt["level"] > rec["level"]]
+    assert closes and closes[-1]["n_states"] <= part.n_states
+    for rec in closes:
+        assert sum(rec["coverage"].values()) == rec["n_states"] - 1
+    assert "Timeout" in stats[-1]["coverage"]
+    assert sum(part.coverage.values()) == part.n_states - 1
+
+
 # -- RAFT_TLA_PREFETCH gate (double-buffered upload prefetch) ---------------
 
 

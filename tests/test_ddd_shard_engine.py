@@ -28,7 +28,7 @@ from raft_tla_tpu.models import interp, refbfs, spec as S
 from raft_tla_tpu.ops import msgbits as mb
 from raft_tla_tpu.parallel.ddd_shard_engine import (
     DDDShardCapacities, DDDShardEngine, reshard_ddd_checkpoint)
-from raft_tla_tpu.parallel.shard_engine import make_mesh, make_slice_mesh
+from raft_tla_tpu.parallel.mesh import make_mesh, make_slice_mesh
 
 CFG = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                 max_log=0, max_msgs=2),

@@ -67,14 +67,6 @@ def test_device_engine_deadlock_parity():
     _assert_deadlock(got, ref)
 
 
-def test_paged_engine_deadlock_parity():
-    from raft_tla_tpu.paged_engine import PagedCapacities, PagedEngine
-    ref = refbfs.check(CFG1)
-    got = PagedEngine(CFG1, PagedCapacities(
-        ring=1 << 14, table=1 << 13, levels=64)).check()
-    _assert_deadlock(got, ref)
-
-
 @pytest.mark.slow      # virtual-mesh test (see test_shard_engine)
 def test_shard_engine_deadlock():
     """Like violation traces, deadlock reporting in the sharded engine is

@@ -305,7 +305,7 @@ def test_mesh_4dev_parity(backend, monkeypatch):
     canonical (level, window, shard) drain order untouched."""
     from raft_tla_tpu.parallel.ddd_shard_engine import (
         DDDShardCapacities, DDDShardEngine)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
 
     caps = DDDShardCapacities(block=256, table=1 << 14, seg_rows=1 << 14,
                               flush=1 << 10, levels=64)
@@ -332,7 +332,7 @@ def test_mesh_4dev_violation_identity(monkeypatch):
     from raft_tla_tpu.ops import msgbits as mb
     from raft_tla_tpu.parallel.ddd_shard_engine import (
         DDDShardCapacities, DDDShardEngine)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
 
     bounds = Bounds(n_servers=3, n_values=1, max_term=3, max_log=0,
                     max_msgs=4, max_dup=1)

@@ -15,7 +15,7 @@ from raft_tla_tpu.fleet import FleetSimulator, Scenario, fault_matrix, \
 from raft_tla_tpu.fleet.scenario import FAULT_FAMILIES
 from raft_tla_tpu.models import interp, spec as S
 from raft_tla_tpu.ops import msgbits as mb
-from raft_tla_tpu.parallel.shard_engine import make_mesh
+from raft_tla_tpu.parallel.mesh import make_mesh
 
 B3 = Bounds(n_servers=3, n_values=1, max_term=3, max_log=0, max_msgs=4)
 CV = CheckConfig(bounds=B3, spec="election",

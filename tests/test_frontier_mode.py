@@ -184,7 +184,7 @@ def _mesh_caps(**kw):
 @pytest.mark.slow      # virtual-mesh test (see test_shard_engine)
 def test_mesh_frontier_parity_8dev():
     from raft_tla_tpu.parallel.ddd_shard_engine import DDDShardEngine
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
 
     ref = refbfs.check(ELECTION)
     got = DDDShardEngine(ELECTION, make_mesh(8), _mesh_caps()).check()
@@ -200,7 +200,7 @@ def test_mesh_frontier_checkpoint_resume_and_reshard(tmp_path):
     frontier snapshot 8 -> 2 (keys + level files move verbatim)."""
     from raft_tla_tpu.parallel.ddd_shard_engine import (
         DDDShardEngine, reshard_ddd_checkpoint)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
 
     ck = str(tmp_path / "m.ckpt")
     ck2 = str(tmp_path / "m2.ckpt")
@@ -215,7 +215,7 @@ def test_mesh_frontier_checkpoint_resume_and_reshard(tmp_path):
     caps2 = _mesh_caps(block=1024, seg_rows=1 << 16)
     reshard_ddd_checkpoint(FULL, _mesh_caps(), ck, ck2, ndev_src=8,
                            ndev_dst=2, caps_dst=caps2)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh as mm
+    from raft_tla_tpu.parallel.mesh import make_mesh as mm
     got2 = DDDShardEngine(FULL, mm(2), caps2).check(resume=ck2)
     assert got2.n_states == ref.n_states
     assert got2.diameter == ref.diameter
@@ -285,7 +285,7 @@ def test_frontier_keep_levels_deadlock_trace():
 def test_frontier_keep_levels_shard_trace():
     from raft_tla_tpu.parallel.ddd_shard_engine import (
         DDDShardCapacities, DDDShardEngine)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
     caps = DDDShardCapacities(block=256, table=1 << 14,
                               seg_rows=1 << 14, flush=1 << 12,
                               levels=64, retention="frontier",

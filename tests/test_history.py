@@ -221,11 +221,11 @@ def test_symmetry_composes_with_faithful_mode():
     assert (ef.n_states, ef.diameter) == (26723, 32)
 
 
-def test_device_and_paged_engines_faithful_parity():
-    """The flagship engines run faithful mode too: HBM store rows and the
-    paged engine's bit-packed rows both carry the history fields."""
+def test_device_engine_faithful_parity():
+    """The device engine runs faithful mode too: its HBM store rows carry
+    the history fields (``ddd``'s bit-packed rows:
+    tests/test_ddd_engine.py::test_faithful_mode_parity)."""
     from raft_tla_tpu.device_engine import Capacities, DeviceEngine
-    from raft_tla_tpu.paged_engine import PagedCapacities, PagedEngine
     cc = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                    max_log=1, max_msgs=2, history=True,
                                    max_elections=4),
@@ -237,10 +237,6 @@ def test_device_and_paged_engines_faithful_parity():
     dev = DeviceEngine(cc, Capacities(n_states=1 << 16, levels=64)).check()
     assert (dev.n_states, dev.diameter) == (ref.n_states, ref.diameter)
     assert dev.levels == ref.levels and dev.coverage == ref.coverage
-    pag = PagedEngine(cc, PagedCapacities(ring=1 << 16, table=1 << 18,
-                                          levels=64)).check()
-    assert (pag.n_states, pag.diameter) == (ref.n_states, ref.diameter)
-    assert pag.levels == ref.levels and pag.coverage == ref.coverage
 
 
 def test_bitpack_roundtrip_history_fields():

@@ -63,15 +63,6 @@ def _run_engine(name, events, on_progress=None):
     if name == "device":
         from raft_tla_tpu.device_engine import Capacities, DeviceEngine
         eng = DeviceEngine(CFG, Capacities(n_states=1 << 15, levels=64))
-    elif name == "paged":
-        from raft_tla_tpu.paged_engine import PagedCapacities, PagedEngine
-        eng = PagedEngine(CFG, PagedCapacities(ring=16384, table=1 << 15,
-                                               levels=64))
-    elif name == "streamed":
-        from raft_tla_tpu.streamed_engine import (StreamedCapacities,
-                                                  StreamedEngine)
-        eng = StreamedEngine(CFG, StreamedCapacities(
-            block=256, ring=4096, table=1 << 14, levels=64))
     elif name == "ddd":
         from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
         eng = DDDEngine(CFG, DDDCapacities(block=256, table=1 << 14,
@@ -81,12 +72,6 @@ def _run_engine(name, events, on_progress=None):
                                            make_mesh)
         eng = ShardEngine(CFG, make_mesh(8),
                           ShardCapacities(n_states=1 << 12, levels=64))
-    elif name == "pagedshard":
-        from raft_tla_tpu.parallel.paged_shard_engine import (
-            PagedShardCapacities, PagedShardEngine)
-        from raft_tla_tpu.parallel.shard_engine import make_mesh
-        eng = PagedShardEngine(CFG, make_mesh(8), PagedShardCapacities(
-            ring=4096, table=1 << 12, levels=64))
     else:
         from raft_tla_tpu.parallel.ddd_shard_engine import (
             DDDShardCapacities, DDDShardEngine)
@@ -96,7 +81,7 @@ def _run_engine(name, events, on_progress=None):
 
 
 @pytest.mark.smoke
-@pytest.mark.parametrize("engine", ["device", "paged", "streamed", "ddd"])
+@pytest.mark.parametrize("engine", ["device", "ddd"])
 def test_event_conformance_single_device(engine, tmp_path):
     path = str(tmp_path / f"{engine}.events")
     lines = []
@@ -104,7 +89,7 @@ def test_event_conformance_single_device(engine, tmp_path):
     evs = _read_log(path)
     n = _assert_conformant(evs, engine)
     assert n == res.n_states == N_TOY
-    if engine in ("streamed", "ddd"):  # boundary-exact level accounting
+    if engine == "ddd":  # boundary-exact level accounting
         assert [e["level"] for e in evs if e["event"] == "level_end"]
     # on_progress receives the same records the log's segments carry
     segs = [e for e in evs if e["event"] == "segment"]
@@ -116,7 +101,7 @@ def test_event_conformance_single_device(engine, tmp_path):
 
 @pytest.mark.smoke
 @pytest.mark.slow
-@pytest.mark.parametrize("engine", ["shard", "pagedshard", "ddd-shard"])
+@pytest.mark.parametrize("engine", ["shard", "ddd-shard"])
 def test_event_conformance_sharded(engine, tmp_path):
     path = str(tmp_path / "shard.events")
     res = _run_engine(engine, path)

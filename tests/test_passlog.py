@@ -44,7 +44,7 @@ def _build(kind):
                                             flush=1 << 10, levels=64))
     from raft_tla_tpu.parallel.ddd_shard_engine import (DDDShardCapacities,
                                                         DDDShardEngine)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
     return DDDShardEngine(
         CFG, make_mesh(4),
         DDDShardCapacities(block=256, table=1 << 14, seg_rows=1 << 14,

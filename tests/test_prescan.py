@@ -37,7 +37,7 @@ from raft_tla_tpu.ops import kernels
 from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym
 
-from test_symmetry import _SCAN_CASES
+from symmetry_cases import _SCAN_CASES
 
 N = 64                                   # lanes of the synthetic chunk
 # rung -> (raw-distinct valid states, invalid lanes, lanes the taken scan
@@ -287,7 +287,7 @@ def test_pass_span_says_which_step_ran(monkeypatch, tmp_path, engine, mode,
     if engine == "ddd-shard":
         from raft_tla_tpu.parallel.ddd_shard_engine import (
             DDDShardCapacities, DDDShardEngine)
-        from raft_tla_tpu.parallel.shard_engine import make_mesh
+        from raft_tla_tpu.parallel.mesh import make_mesh
         eng = DDDShardEngine(cfg, make_mesh(2),
                              DDDShardCapacities(seg_rows=1 << 14, **caps))
     else:

@@ -118,7 +118,7 @@ def test_slice_mesh_2x4_parity():
     """2-D (dcn, ici) mesh with the hierarchical two-stage exchange
     explores the identical state graph: same counts, levels, transitions,
     verdicts as the oracle and (by test_ndev_invariance) the 1-D mesh."""
-    from raft_tla_tpu.parallel.shard_engine import make_slice_mesh
+    from raft_tla_tpu.parallel.mesh import make_slice_mesh
 
     cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                     max_log=0, max_msgs=2),
@@ -138,7 +138,7 @@ def test_slice_mesh_checkpoint_portable_from_1d(tmp_path):
     """FP ownership is by FLAT device id, so a 1-D 8-mesh checkpoint
     resumes on a 2x4 slice mesh (same total size) and finishes with
     identical counts."""
-    from raft_tla_tpu.parallel.shard_engine import make_slice_mesh
+    from raft_tla_tpu.parallel.mesh import make_slice_mesh
 
     cfg = CheckConfig(bounds=Bounds(n_servers=2, n_values=1, max_term=2,
                                     max_log=0, max_msgs=2),

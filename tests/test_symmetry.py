@@ -2,8 +2,9 @@
 
 Correctness anchors: the orbit key is permutation-invariant; the
 symmetry-reduced oracle count equals the brute-force orbit count of the
-full space; the device engine under symmetry reproduces the reduced oracle
-exactly; violations still surface with replayable traces.
+full space; the scan-compiled orbit pass keys every state as the unrolled
+loop does.  The engines under SYMMETRY are held to the reduced oracle in
+``test_symmetry_engines.py``.
 """
 
 import itertools
@@ -12,18 +13,12 @@ import numpy as np
 import pytest
 
 from raft_tla_tpu.config import Bounds, CheckConfig
-from raft_tla_tpu.device_engine import Capacities, DeviceEngine
 from raft_tla_tpu.models import interp, refbfs, spec as S
 from raft_tla_tpu.ops import msgbits as mb
 from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym
-
-B2 = Bounds(n_servers=2, n_values=1, max_term=2, max_log=0, max_msgs=2)
-B3 = Bounds(n_servers=3, n_values=1, max_term=2, max_log=0, max_msgs=1)
-
-
-def bag(*ms):
-    return tuple(sorted((m, 1) for m in ms))
+from symmetry_cases import (
+    _ELECT5, _FULL5, _B3S, _SCAN_CASES, B2, B3, _random_states)
 
 
 def permute_py_state(s, p, bounds):
@@ -96,249 +91,23 @@ def test_oracle_orbit_count_matches_brute_force():
     assert len(full) == 3014
 
 
-def test_device_engine_symmetry_parity():
-    cfg = CheckConfig(bounds=B3, spec="election",
-                      invariants=("NoTwoLeaders",), symmetry=("Server",),
-                      chunk=256)
-    ref = refbfs.check(cfg)
-    got = DeviceEngine(cfg, Capacities(n_states=1 << 16, levels=64)).check()
-    assert got.n_states == ref.n_states
-    assert got.diameter == ref.diameter
-    assert got.levels == ref.levels
-    assert got.n_transitions == ref.n_transitions
-    assert got.coverage == ref.coverage
-    assert got.violation is None
-    # sanity: it actually reduced (full space is 142538 with 2 values /
-    # this config's unreduced count is strictly larger)
-    unred = refbfs.check(CheckConfig(bounds=B3, spec="election",
-                                     invariants=("NoTwoLeaders",)))
-    assert ref.n_states < unred.n_states
-
-
-def test_symmetry_violation_trace_replayable():
-    bounds = Bounds(n_servers=3, n_values=1, max_term=3, max_log=0,
-                    max_msgs=4, max_dup=1)
-    cfg = CheckConfig(bounds=bounds, spec="election",
-                      invariants=("NaiveNoTwoLeaders",),
-                      symmetry=("Server",), chunk=256)
-    start = interp.init_state(bounds)._replace(
-        role=(S.LEADER, S.FOLLOWER, S.CANDIDATE),
-        term=(2, 3, 3), votedFor=(1, 3, 0),
-        vGrant=(0b011, 0, 0b100),
-        msgs=bag(mb.rv_response(3, 1, 1, 2)))
-    ref = refbfs.check(cfg, init_override=start)
-    got = DeviceEngine(cfg, Capacities(n_states=1 << 15, levels=64)
-                       ).check(init_override=start)
-    assert ref.violation is not None and got.violation is not None
-    assert got.violation.state == ref.violation.state
-    trace = got.violation.trace
-    for (_l, prev), (_label, cur) in zip(trace, trace[1:]):
-        succs = [t for _i, t in interp.successors(prev, bounds,
-                                                  spec="election")]
-        assert cur in succs
-
-
 def test_too_many_servers_is_loud():
     with pytest.raises(ValueError, match="symmetry"):
         sym.permutations(Bounds(n_servers=7, n_values=1, max_term=2,
                                 max_log=0, max_msgs=1))
 
 
-def test_host_engine_symmetry_parity():
-    """Regression: the host-dedup engine must apply the same orbit keys
-    (it once silently skipped the reduction while printing the banner)."""
-    from raft_tla_tpu import engine
-    cfg = CheckConfig(bounds=B2, spec="election", invariants=(),
-                      symmetry=("Server",), chunk=64)
-    ref = refbfs.check(cfg)
-    got = engine.check(cfg)
-    assert got.n_states == ref.n_states == 1514
-    assert got.levels == ref.levels
-
-
-def test_value_symmetry_orbit_counts():
+@pytest.mark.parametrize("axes, orbits", [
+    ((), 74897), (("Server",), 37472), (("Value",), 50515),
+    (("Server", "Value"), 25281)])
+def test_value_symmetry_orbit_counts(axes, orbits):
     """Value permutations (TLC Permutations(Value)) quotient further:
     values enter only through ClientRequest and flow inertly, so
     Server x Value orbits < Server orbits < raw states, same diameter."""
     bp = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2)
-
-    def run(axes):
-        return refbfs.check(CheckConfig(bounds=bp, spec="full",
-                                        invariants=(), symmetry=axes))
-    base, s_only, v_only, sv = (run(()), run(("Server",)), run(("Value",)),
-                                run(("Server", "Value")))
-    assert base.n_states == 74897
-    assert (s_only.n_states, v_only.n_states, sv.n_states) == \
-        (37472, 50515, 25281)
-    assert base.diameter == s_only.diameter == v_only.diameter == sv.diameter
-
-
-def test_value_symmetry_engine_parity():
-    from raft_tla_tpu import engine
-    bp = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2)
-    cfg = CheckConfig(bounds=bp, spec="full", invariants=("NoTwoLeaders",),
-                      symmetry=("Server", "Value"), chunk=512)
-    ref = refbfs.check(cfg)
-    got = engine.check(cfg)
-    assert (got.n_states, got.diameter) == (ref.n_states, ref.diameter)
-    assert got.coverage == ref.coverage and got.violation is None
-
-
-def test_value_symmetry_faithful_mode():
-    """Rank-table remaps + bitwise allLogs permutation: faithful spaces
-    quotient under Server x Value too, engines in exact agreement."""
-    from raft_tla_tpu import engine
-    bh = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2,
-                history=True, max_elections=4)
-    cf = CheckConfig(bounds=bh, spec="full",
-                     invariants=("NoTwoLeaders", "ElectionSafetyHist"),
-                     symmetry=("Server", "Value"), chunk=512)
-    ref = refbfs.check(cf)
-    got = engine.check(cf)
-    assert (ref.n_states, ref.diameter) == (28121, 32)  # of 84572 states
-    assert (got.n_states, got.diameter) == (28121, 32)
-    assert ref.violation is None and got.violation is None
-
-
-_B3S = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2)
-_BH2 = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2,
-              history=True, max_elections=4)
-# the benchmark's 5-server bounds (benchmark/configs/elect5.json, full5.json)
-_ELECT5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=0, max_msgs=2,
-                 max_dup=1)
-_FULL5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=1, max_msgs=2,
-                max_dup=1)
-def _scan_case_states(bounds, spec, depth, lane_cap, cap, first=False):
-    """A bag of reachable states: BFS prefix via the interpreter, keeping
-    ``lane_cap`` successors a level — every k-th one (late ones carry the
-    deeper histories), or with ``first`` the first ones (the low action
-    ids: timeouts, vote requests and their replies, where servers still
-    look alike) with the constraint ignored."""
-    frontier = [interp.init_state(bounds)]
-    seen = list(frontier)
-    for _ in range(depth):
-        nxt = []
-        for s in frontier:
-            # a state past the constraint is counted, not expanded
-            if first or interp.constraint_ok(s, bounds):
-                nxt += [t for _i, t in interp.successors(s, bounds,
-                                                         spec=spec)]
-        stride = 1 if first else max(1, len(nxt) // lane_cap)
-        frontier = nxt[::stride][:lane_cap]
-        seen += frontier
-    return seen[:cap]
-
-
-def _random_states(bounds, n, seed):
-    from test_state import random_pystate
-    rng = np.random.default_rng(seed)
-    return [random_pystate(rng, bounds) for _ in range(n)]
-
-
-def _all_distinct_state():
-    """No two servers interchangeable: every one of the 6 permutations
-    gives another orbit member, so the min really ranges over the group."""
-    return interp.init_state(_B3S)._replace(
-        role=(0, 1, 2), term=(1, 2, 2), votedFor=(0, 2, 3))
-
-
-def _distinct5():
-    """Five servers no two of which are interchangeable, empty bag."""
-    return interp.init_state(_FULL5)._replace(
-        role=(0, 1, 2, 0, 1), term=(1, 2, 2, 3, 1), votedFor=(0, 2, 3, 0, 5))
-
-
-def _bag_states():
-    """Bags the scan has to rank as ``canonicalize`` sorts them: three
-    occupied slots whose (dst, src) order a permutation changes; two
-    slots equal in ``hi`` that differ in ``lo`` alone (the same
-    AppendEntriesRequest but for its entry); a multiplicity of 2 beside a
-    1; one message; none."""
-    rv, ae = mb.rv_request, mb.ae_request
-    bags = [
-        bag(rv(2, 0, 0, 0, 4), rv(2, 0, 0, 3, 1), rv(2, 0, 0, 2, 2)),
-        bag(ae(2, 0, 0, 1, 1, 1, 0, 1, 3), ae(2, 0, 0, 1, 2, 2, 0, 1, 3),
-            rv(1, 0, 0, 4, 0)),
-        tuple(sorted([(rv(2, 0, 0, 0, 1), 2), (rv(1, 0, 0, 4, 2), 1)])),
-        bag(mb.rv_response(2, 1, 3, 0)),
-        (),
-    ]
-    ae_hi = [hi for (hi, _lo), _c in bags[1] if mb.mtype(hi) == 3]
-    assert len(ae_hi) == 2 and len(set(ae_hi)) == 1     # equal hi words
-    return [_distinct5()._replace(msgs=b) for b in bags]
-
-
-def _stale_slot_vecs():
-    """Packed rows no ``to_vec`` writes: an EMPTY slot (``msgCount`` 0)
-    that still holds content words, as a kernel that counts a message
-    down to 0 may leave it — in front of, between and behind the
-    occupied slots.  ``canonicalize`` zeroes it before it sorts; the
-    scan must drop it from its ranking.  Row 0 is the clean state."""
-    lay = st.Layout.of(_FULL5)
-    rv = mb.rv_request
-    clean = interp.to_vec(_distinct5()._replace(
-        msgs=bag(rv(2, 0, 0, 0, 4), rv(2, 0, 0, 3, 1))), _FULL5)
-    (h0, l0), (h1, l1) = sorted([rv(2, 0, 0, 0, 4), rv(2, 0, 0, 3, 1)])
-    stale_hi, stale_lo = rv(2, 0, 0, 2, 2)[0], 0x155
-    vecs = [clean]
-    for slots in ([(stale_hi, stale_lo, 0), (h0, l0, 1), (h1, l1, 1)],
-                  [(h0, l0, 1), (stale_hi, stale_lo, 0), (h1, l1, 1)],
-                  [(h0, l0, 1), (h1, l1, 1), (stale_hi, stale_lo, 0)]):
-        t = st.unpack(clean, lay, np)
-        t["msgHi"], t["msgLo"], t["msgCount"] = (
-            np.asarray(w, np.int32) for w in zip(*slots))
-        vecs.append(st.pack(t, np))
-    return np.stack(vecs)
-
-
-# name -> (bounds, axes, VIEW or None, states (or packed rows), at least
-# this many)
-_SCAN_CASES = {
-    "3s-server": (_B3S, ("Server",), None,
-                  lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
-    "3s-value": (_B3S, ("Value",), None,
-                 lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
-    "3s-server-value": (
-        _B3S, ("Server", "Value"), None,
-        lambda: _scan_case_states(_B3S, "full", 4, 40, 200), 100),
-    "2s-faithful-server-value": (
-        _BH2, ("Server", "Value"), None,
-        lambda: _scan_case_states(_BH2, "full", 4, 40, 200), 100),
-    "2s-faithful-value": (
-        _BH2, ("Value",), None,
-        lambda: _scan_case_states(_BH2, "full", 6, 60, 300), 100),
-    "elect5-server": (
-        _ELECT5, ("Server",), None,
-        lambda: _scan_case_states(_ELECT5, "election", 7, 60, 300), 300),
-    "full5-server": (
-        _FULL5, ("Server",), None,
-        lambda: _scan_case_states(_FULL5, "full", 7, 60, 300), 300),
-    # the poles of the orbit: every permutation ties / none does
-    "5s-all-identical": (
-        _ELECT5, ("Server",), None,
-        lambda: [interp.init_state(_ELECT5)] * 4, 4),
-    "3s-all-distinct": (
-        _B3S, ("Server",), None, lambda: [_all_distinct_state()], 1),
-    "3s-first-lanes-server-value": (
-        _B3S, ("Server", "Value"), None,
-        lambda: _scan_case_states(_B3S, "full", 3, 60, 150, first=True), 100),
-    "2s-faithful-first-lanes-server-value": (
-        _BH2, ("Server", "Value"), None,
-        lambda: _scan_case_states(_BH2, "full", 4, 60, 150, first=True), 100),
-    # the engines hand the scan the VIEWED struct; random bounded states,
-    # because votes on a server that is no candidate (what the view
-    # folds) are rare in a BFS prefix
-    "3s-view-server": (_B3S, ("Server",), "deadvotes",
-                       lambda: _random_states(_B3S, 120, seed=28), 120),
-    # the bag, which the scan ranks and the loop sorts (PR 29)
-    "full5-bags": (_FULL5, ("Server",), None, _bag_states, 5),
-    "full5-stale-slots": (_FULL5, ("Server",), None, _stale_slot_vecs, 4),
-    "full5-random": (_FULL5, ("Server",), None,
-                     lambda: _random_states(_FULL5, 60, seed=29), 60),
-    "3s-random-server-value": (
-        _B3S, ("Server", "Value"), None,
-        lambda: _random_states(_B3S, 60, seed=30), 60),
-}
+    got = refbfs.check(CheckConfig(bounds=bp, spec="full", invariants=(),
+                                   symmetry=axes))
+    assert (got.n_states, got.diameter) == (orbits, 32)
 
 
 @pytest.mark.parametrize("case", list(_SCAN_CASES))
@@ -452,70 +221,6 @@ def test_key_table_and_ranked_bag_equal_the_permuted_packed_row(
             want = fpr.fingerprint(st.pack(image, np), consts, np)
             assert (int(got[0][k]), int(got[1][k])) \
                 == (int(want[0]), int(want[1])), (perms[i], k)
-
-
-# F = 4n + 2nL + 5n^2 of the benchmark's configurations and of six servers
-_LIMB_SHAPES = {"flagship3": (3, 2), "elect5": (5, 1), "full5": (5, 2),
-                "six-servers": (6, 2)}
-
-
-@pytest.mark.parametrize("constants", ["all-ones", "top-bit", "zero",
-                                       "random"])
-@pytest.mark.parametrize("name", list(_LIMB_SHAPES))
-def test_limb_sums_equal_linear_sums_at_the_extremes(name, constants):
-    """The device's form of the linear sums, in NumPy alone: the table of
-    permuted constants as four balanced base-256 digits (``int8``), one
-    int32 matrix product with the features, the digits shifted home and
-    added in uint32 — the same word, on every bit, as ``_linear_sums``'
-    multiply-reduce in uint32.  At the extremes: every feature at the cap
-    ``config.Bounds`` allows (63) and at the most an ``int8`` holds
-    (127), constants whose digits all carry (0xFFFFFFFF), whose top digit
-    is the one negative one (0x80000000), zero and random."""
-    n, L = _LIMB_SHAPES[name]
-    F = 4 * n + 2 * n * L + 5 * n * n
-    rng = np.random.default_rng(F)
-    table = {"all-ones": np.full((3, 2, F), 0xFFFFFFFF, np.uint32),
-             "top-bit": np.full((3, 2, F), 0x80000000, np.uint32),
-             "zero": np.zeros((3, 2, F), np.uint32),
-             "random": rng.integers(0, 2**32, (3, 2, F), dtype=np.uint32),
-             }[constants]
-    limbs = sym._key_limbs(table)
-    assert limbs.dtype == np.int8 and limbs.shape == (3, 2, 4, F)
-    # the digits are the constant (mod 2^32)
-    back = sum(limbs[:, :, l].astype(np.int64) << (8 * l) for l in range(4))
-    assert ((back % 2**32).astype(np.uint32) == table).all()
-    lanes = 64
-    for cap in (63, 127):
-        sym._check_limb_range(F, cap)
-        for phi in (np.full((F, lanes), cap, np.int8),
-                    rng.integers(0, cap + 1, (F, lanes)).astype(np.int8)):
-            got = sym._limb_sums(limbs, phi, np)
-            assert got.dtype == np.uint32 and got.shape == (3, 2, lanes)
-            for p in range(3):
-                want = sym._linear_sums(phi, table[p], np)
-                assert (got[p, 0] == want[0]).all(), (name, constants, cap)
-                assert (got[p, 1] == want[1]).all(), (name, constants, cap)
-
-
-def test_limb_range_check_refuses_what_would_not_be_exact():
-    """``build_orbit_fp`` checks once, at build time, that the product is
-    exact: a feature past 127 does not fit the ``int8`` operand, and F
-    features at the cap times a digit of 128 must stay inside ``int32``.
-    The schemas in the tree are far inside both (full5: 165 features
-    capped at 3)."""
-    for bounds in (_B3S, _ELECT5, _FULL5):
-        cap = sym._feature_cap(bounds, sym._linear_fields(("Server",)))
-        assert cap == max(bounds.term_cap, bounds.log_cap + 1,
-                          bounds.n_values)
-        n, L = bounds.n_servers, bounds.log_cap
-        sym._check_limb_range(4 * n + 2 * n * L + 5 * n * n, cap)
-    sym._check_limb_range(165, 127)
-    with pytest.raises(ValueError, match="int8"):
-        sym._check_limb_range(165, 128)
-    most = (2**31 - 1) // (63 * 128)              # 266,305 features
-    sym._check_limb_range(most, 63)
-    with pytest.raises(ValueError, match="int32"):
-        sym._check_limb_range(most + 1, 63)
 
 
 def _lowered_scan(bounds, lanes, axes=("Server",)):

@@ -218,7 +218,7 @@ def test_tlc_export_carries_view():
 def test_mesh_engine_under_view():
     from raft_tla_tpu.parallel.ddd_shard_engine import (
         DDDShardCapacities, DDDShardEngine)
-    from raft_tla_tpu.parallel.shard_engine import make_mesh
+    from raft_tla_tpu.parallel.mesh import make_mesh
 
     ref = refbfs.check(CFG)
     caps = DDDShardCapacities(block=1 << 12, table=1 << 12,
