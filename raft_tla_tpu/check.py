@@ -705,10 +705,14 @@ def main(argv=None) -> int:
         if dev_line:
             print(dev_line)
         print(f"Invariants: {', '.join(config.invariants) or '(none)'}")
+        if config.symmetry:
+            print(f"Symmetry: {' x '.join(config.symmetry)} permutations, "
+                  f"|G| = {model.group_order(config)} (counting orbits)")
         if args.emit_tlc:
             try:
                 tla, cfgp = model.emit_tla(args.emit_tlc, b,
-                                           config.invariants)
+                                           config.invariants,
+                                           symmetry=config.symmetry)
             except (OSError, ValueError) as e:
                 print(f"Error: {e}", file=sys.stderr)
                 return EXIT_ERROR

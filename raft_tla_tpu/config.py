@@ -142,7 +142,8 @@ class CheckConfig:
     bounds: Bounds = dataclasses.field(default_factory=Bounds)
     spec: str = "full"                     # full | election | replication
     invariants: tuple = ("NoTwoLeaders",)  # registry names
-    symmetry: tuple = ()                   # () or ("Server",): TLC SYMMETRY
+    symmetry: tuple = ()                   # TLC SYMMETRY: Raft's axes ("Server",
+    #   "Value") or the sorts a frontend schema declares ("Acceptor", ...)
     chunk: int = 1024                      # frontier states expanded per jit call
     check_deadlock: bool = False           # TLC -deadlock analog (off: Restart is always enabled anyway)
     view: str | None = None                # TLC VIEW analog: a registered

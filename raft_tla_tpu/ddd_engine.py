@@ -1230,6 +1230,9 @@ class DDDEngine:
         # ladder: it compacts the live lanes before its scan)
         self._prescan = not self.caps.route_rows and \
             kernels._prescan_enabled(config.bounds, config.symmetry)
+        # |G| of the run's SYMMETRY (1 with none), for ``run_start`` and
+        # the ``segment`` spans: scope time over ``images`` is time an image
+        self._group = self.model.group_order(config)
         self._segment = jax.jit(
             _build_segment(config, self.caps, self.A, self.lay.width,
                            self.schema,
@@ -1611,7 +1614,7 @@ class DDDEngine:
                                     self.SEG_CLAMP_S)
         budget = pacer.budget
         last_ckpt = time.monotonic()
-        tel.run_start(n_states=n_states)
+        tel.run_start(n_states=n_states, group=self._group)
 
         def progress():
             if not tel.active:
@@ -1809,7 +1812,8 @@ class DDDEngine:
                                 thread="segments", level=len(level_ends),
                                 block=(b_start - lvl_lo) // Fcap,
                                 budget=seg_budget, steps=n_steps,
-                                lanes=n_steps * N,
+                                lanes=n_steps * N, group=self._group,
+                                images=self._group * n_steps * N,
                                 streamed_rows=ns, n_valid=nv,
                                 route_peak=seg_route,
                                 stream_peak=seg_peak,

@@ -186,7 +186,7 @@ def build_schema_expand(schema, defs, table, bounds, const_tables=None):
 
 
 def build_schema_step(schema, defs, table, bounds, predicates=(),
-                      const_tables=None):
+                      const_tables=None, sorts=()):
     """Generic fused step for a schema-declared spec.
 
     ``table`` is the action-instance list (objects with ``.family`` and
@@ -199,11 +199,23 @@ def build_schema_step(schema, defs, table, bounds, predicates=(),
     declares no bag-slot permutation) and ``con_ok`` is all-true; both
     are points where a future schema hook can slot in.
     ``const_tables`` are the schema's constant tables as the run binds
-    them (``Schema.bind_consts``), read by ``ConstTab`` nodes.
+    them (``Schema.bind_consts``), read by ``ConstTab`` nodes.  ``sorts``
+    names the symmetric sorts of the schema the run reduces by (the cfg's
+    SYMMETRY): with none the key of a lane is its plain fingerprint, and
+    the program is what it was before a schema could declare a sort; with
+    some it is the least fingerprint over the lane's images
+    (``ops/symmetry.build_schema_orbit_fp``), under the ``orbit_scan``
+    scope where ``plain_fp`` stood.
     """
     lay = schema.layout(bounds)
-    consts = jnp.asarray(fpr.lane_constants(lay.width))
+    host_consts = fpr.lane_constants(lay.width)
+    consts = jnp.asarray(host_consts)
     expand = build_schema_expand(schema, defs, table, bounds, const_tables)
+    orbit_fp = None
+    if sorts:
+        from raft_tla_tpu.ops import symmetry as sym
+        orbit_fp = sym.build_schema_orbit_fp(schema, bounds, tuple(sorts),
+                                             host_consts)
 
     def step(vecs):
         # the step's stage scopes (kernels.STAGE_SCOPES), as build_step
@@ -214,8 +226,12 @@ def build_schema_step(schema, defs, table, bounds, predicates=(),
             succs, valid, ovf = jax.vmap(expand)(structs)
         with jax.named_scope("pack"):
             svecs = jax.vmap(jax.vmap(lambda t: lay.pack(t, jnp)))(succs)
-        with jax.named_scope("plain_fp"):
-            fp_hi, fp_lo = fpr.fingerprint(svecs, consts, jnp)
+        if orbit_fp is None:
+            with jax.named_scope("plain_fp"):
+                fp_hi, fp_lo = fpr.fingerprint(svecs, consts, jnp)
+        else:
+            fp_hi, fp_lo = K.orbit_keys(bounds, tuple(sorts), orbit_fp,
+                                        consts, succs, svecs, valid)
         with jax.named_scope("invariants"):
             if predicates:
                 inv_ok = jnp.stack(

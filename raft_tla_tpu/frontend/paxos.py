@@ -51,7 +51,8 @@ import numpy as np
 
 from raft_tla_tpu.config import Bounds
 from raft_tla_tpu.frontend import expr as E
-from raft_tla_tpu.frontend.schema import Const, Field, Schema, envelope
+from raft_tla_tpu.frontend.schema import (Const, Field, Over, Schema, Sort,
+                                          envelope)
 
 
 def n_pairs(bounds) -> int:
@@ -60,16 +61,28 @@ def n_pairs(bounds) -> int:
     return 1 + bounds.term_cap * bounds.n_values
 
 
+# The model's SYMMETRY (MCPaxos: Permutations(Acceptor) \cup
+# Permutations(Value)): the spec tells no two acceptors and no two values
+# apart, so both are symmetric sorts.  An acceptor only ever indexes an
+# axis; a value indexes one ("2a", "2b"), is half of the "1b" pair index
+# (k = 0 fixed, else 1 + mbal * |Value| + value) and is what maxVal holds
+# (0 = None fixed).  Ballots are numbers, ordered: no sort.
+_ACC, _VAL, _VAL1 = Over("Acceptor"), Over("Value"), Over("Value", fixed=1)
+
 SCHEMA = Schema("paxos", (
-    Field("maxBal", ("n",), 0, "term_cap"),       # 0 = -1, else ballot + 1
-    Field("maxVBal", ("n",), 0, "term_cap"),
-    Field("maxVal", ("n",), 0, "V"),              # 0 = None, else value + 1
+    Field("maxBal", ("n",), 0, "term_cap", axes=(_ACC,)),   # 0 = -1, else
+    Field("maxVBal", ("n",), 0, "term_cap", axes=(_ACC,)),  # ballot + 1
+    Field("maxVal", ("n",), 0, "V", axes=(_ACC,),   # 0 = None, else value + 1
+          content=_VAL1),
     Field("msg1a", ("term_cap",), 0, 1),
-    Field("msg1b", ("n", "term_cap", n_pairs), 0, 1),
-    Field("msg2a", ("term_cap", "V"), 0, 1),
-    Field("msg2b", ("n", "term_cap", "V"), 0, 1),
+    Field("msg1b", ("n", "term_cap", n_pairs), 0, 1,
+          axes=(_ACC, None, _VAL1)),
+    Field("msg2a", ("term_cap", "V"), 0, 1, axes=(None, _VAL)),
+    Field("msg2b", ("n", "term_cap", "V"), 0, 1, axes=(_ACC, None, _VAL)),
 ), consts=(
-    Const("Quorum", ("*", "n"), 0, 1),
+    Const("Quorum", ("*", "n"), 0, 1, axes=(None, _ACC)),
+), sorts=(
+    Sort("Acceptor", "n"), Sort("Value", "V"),
 ))
 
 PHASE1A, PHASE1B, PHASE2A, PHASE2B = "Phase1a", "Phase1b", "Phase2a", \
@@ -361,7 +374,7 @@ _TLA_TEMPLATE = """---------------------------- MODULE MCPaxos -----------------
 \\* run of the exact model the TPU checker explored: Paxos.tla of
 \\* tlaplus/Examples with Ballot == 0..MaxBallot, and the consistency of
 \\* Voting.tla's chosen under Paxos.tla's votes mapping as an invariant.
-EXTENDS Integers
+EXTENDS Integers%(extends)s
 
 CONSTANTS Acceptor, Value, Quorum, None, MaxBallot
 
@@ -438,15 +451,20 @@ ChosenAt(b, v) == \\E Q \\in Quorum : \\A a \\in Q : VotedFor(a, b, v)
 chosen == {v \\in Value : \\E b \\in Ballot : ChosenAt(b, v)}
 
 Consistency == \\A v1, v2 \\in chosen : v1 = v2
-=======================================================================
+%(symmetry)s=======================================================================
 """
 
 
-def emit_tla(out_dir: str, bounds: Bounds, invariants=()) -> tuple:
+def emit_tla(out_dir: str, bounds: Bounds, invariants=(),
+             symmetry=()) -> tuple:
     """Write ``MCPaxos.tla`` / ``MCPaxos.cfg`` — the stock-TLC twin of this
     bounded model, ``Quorum`` as the run binds it (``bounds.constants``).  Only
     registered (named) invariants can be emitted; a whole-line expression
-    has no TLA+ operator name to reference."""
+    has no TLA+ operator name to reference.  ``symmetry``: the sorts the run
+    reduced by; the twin then defines ``Sym<Sorts>`` (``SymAcceptorValue``)
+    as the union of their ``Permutations`` (TLC module) and its cfg carries
+    ``SYMMETRY SymAcceptorValue``, so that TLC counts the same orbits (and
+    this checker reads the twin's cfg back: ``registry``)."""
     names = []
     for nm in invariants:
         if nm not in INVARIANTS:
@@ -459,8 +477,18 @@ def emit_tla(out_dir: str, bounds: Bounds, invariants=()) -> tuple:
     os.makedirs(out_dir, exist_ok=True)
     tla = os.path.join(out_dir, "MCPaxos.tla")
     cfgp = os.path.join(out_dir, "MCPaxos.cfg")
+    unknown = sorted(set(symmetry) - set(SCHEMA.sort_names))
+    if unknown:
+        raise ValueError(f"cannot emit SYMMETRY {unknown[0]}: paxos "
+                         f"declares {', '.join(SCHEMA.sort_names)}")
+    sorts = [nm for nm in SCHEMA.sort_names if nm in symmetry]
+    sym_name = "Sym" + "".join(sorts)
+    sym_def = "" if not sorts else (
+        f"\n{sym_name} == " + " \\cup ".join(
+            f"Permutations({nm})" for nm in sorts) + "\n")
     with open(tla, "w", encoding="utf-8") as f:
-        f.write(_TLA_TEMPLATE)
+        f.write(_TLA_TEMPLATE % {"extends": ", TLC" if symmetry else "",
+                                 "symmetry": sym_def})
     n = bounds.n_servers
     sets = ", ".join(
         "{" + ", ".join(_acc(a) for a in range(n) if row[a]) + "}"
@@ -474,6 +502,8 @@ def emit_tla(out_dir: str, bounds: Bounds, invariants=()) -> tuple:
              f"  MaxBallot = {bounds.max_term}"]
     for nm in names:
         lines.append(f"INVARIANT {nm}")
+    if symmetry:
+        lines.append(f"SYMMETRY {sym_name}")
     with open(cfgp, "w", encoding="utf-8") as f:
         f.write("\n".join(lines) + "\n")
     return tla, cfgp

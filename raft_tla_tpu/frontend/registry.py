@@ -27,7 +27,8 @@ modules — zero behavior change), with ``ir-full`` / ``ir-election`` /
 ``frontend/raft_ir``-compiled kernels instead of the hand-written ones
 (pinned bit-identical by tests).  ``twophase`` resolves to the bundled
 two-phase-commit spec and ``paxos`` to single-decree Paxos, both compiled
-entirely from frontend declarations.
+entirely from frontend declarations; a schema that declares symmetric sorts
+(``paxos``: ``Acceptor``, ``Value``) takes the cfg's SYMMETRY stanza.
 
 Everything heavy imports inside methods: this module sits under
 ``frontend/__init__`` which ``models/spec.py``'s re-export pulls in, so
@@ -37,6 +38,7 @@ module level must stay light to avoid import cycles.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import numpy as np
 
@@ -94,6 +96,13 @@ class RaftModel:
     def init_fingerprint(self, config, init_py, init_vec):
         from raft_tla_tpu.ops import symmetry as sym_mod
         return sym_mod.init_fingerprint(config, init_py, init_vec)
+
+    def group_order(self, config: CheckConfig) -> int:
+        """|G| of the SYMMETRY axes the run reduces by (1 with none)."""
+        from raft_tla_tpu.ops import symmetry as sym_mod
+        b, axes = config.bounds, config.symmetry
+        return (len(sym_mod.permutations(b)) if "Server" in axes else 1) \
+            * (len(sym_mod.value_permutations(b)) if "Value" in axes else 1)
 
     def constraint_ok(self, py, bounds) -> bool:
         from raft_tla_tpu.models import interp
@@ -179,12 +188,31 @@ class SchemaModel:
     def from_vec(self, vec, bounds):
         return self._mod().from_vec(vec, bounds)
 
+    @property
+    def sorts(self) -> tuple:
+        """The symmetric sorts this model can reduce by (the names a cfg's
+        SYMMETRY stanza may list): what its schema declares."""
+        return self._mod().SCHEMA.sort_names
+
     def init_fingerprint(self, config, init_py, init_vec):
-        # symmetry/view are rejected at config time, so this always takes
-        # the generic lane-constants branch — the same fingerprint the
+        # views are rejected at config time; under SYMMETRY the key of
+        # Init is its orbit's (the plain loop, on the host), else the
+        # generic lane-constants branch — either way the fingerprint the
         # compiled schema step computes on device.
         from raft_tla_tpu.ops import symmetry as sym_mod
-        return sym_mod.init_fingerprint(config, init_py, init_vec)
+        if not config.symmetry:
+            return sym_mod.init_fingerprint(config, init_py, init_vec)
+        lay = self.layout(config.bounds)
+        hi, lo = sym_mod.schema_orbit_fingerprint(
+            lay.unpack(np.asarray(init_vec, np.int32), np), lay,
+            sym_mod._host_consts(lay.width), tuple(config.symmetry), np)
+        return int(hi), int(lo)
+
+    def group_order(self, config: CheckConfig) -> int:
+        """|G| of the sorts the run reduces by (1 with none)."""
+        from raft_tla_tpu.ops import symmetry as sym_mod
+        return len(sym_mod.schema_group(
+            self._mod().SCHEMA, config.bounds, tuple(config.symmetry)))
 
     def constraint_ok(self, py, bounds) -> bool:
         return True      # the state space is finite with no constraint
@@ -195,12 +223,16 @@ class SchemaModel:
     def render_trace(self, violation, bounds):
         return self._mod().render_trace(violation, bounds)
 
-    def emit_tla(self, out_dir, bounds, invariants=()):
-        return self._mod().emit_tla(out_dir, bounds, invariants)
+    def emit_tla(self, out_dir, bounds, invariants=(), symmetry=()):
+        if not symmetry:
+            return self._mod().emit_tla(out_dir, bounds, invariants)
+        return self._mod().emit_tla(out_dir, bounds, invariants,
+                                    symmetry=tuple(symmetry))
 
     def _refuse_raft_options(self, cfg, opts) -> None:
-        """SYMMETRY, VIEW and faithful mode are Raft's: refused by name."""
-        if cfg.symmetry or opts.symmetry:
+        """VIEW and faithful mode are Raft's: refused by name.  So is
+        SYMMETRY for a schema that declares no symmetric sort."""
+        if (cfg.symmetry or opts.symmetry) and not self.sorts:
             raise ValueError("symmetry reduction is not supported for "
                              f"{self.name}")
         if cfg.view or opts.view:
@@ -208,6 +240,39 @@ class SchemaModel:
         if opts.faithful:
             raise ValueError("faithful mode (history variables) is "
                              "Raft-specific")
+
+    def _symmetry(self, cfg, opts, bounds, where: str) -> tuple:
+        """The sorts a run reduces by, in the schema's order: those the
+        cfg's SYMMETRY stanza names (the repository's convention: the
+        sort's own name, ``SYMMETRY Acceptor Value``), every declared one
+        under ``--symmetry``.  Refused by name: a sort the schema does not
+        declare, and a bound constant table with an axis over a named sort
+        that some permutation of it does not map onto itself."""
+        schema = self._mod().SCHEMA
+        # the emitted twin's own name for a union of Permutations:
+        # Sym + the sorts' names (SymAcceptorValue), as Raft's SymServer
+        unions = {"Sym" + "".join(c): c
+                  for k in range(1, len(self.sorts) + 1)
+                  for c in itertools.combinations(self.sorts, k)}
+        named = set(self.sorts) if opts.symmetry else set()
+        for nm in cfg.symmetry:
+            named |= set(unions.get(nm, (nm,)))
+        unknown = sorted(named - set(self.sorts))
+        if unknown:
+            raise ValueError(
+                f"{where}: SYMMETRY {unknown[0]} not supported: "
+                f"{self.name} declares the symmetric sorts "
+                f"{', '.join(self.sorts)} (name them so, or as the "
+                f"emitted twin does: {', '.join(sorted(unions))})")
+        sorts = tuple(s for s in self.sorts if s in named)
+        variant = schema.variant_const(bounds, sorts) if sorts else None
+        if variant:
+            raise ValueError(
+                f"{where}: SYMMETRY {variant[1]} is unsound here: the "
+                f"constant {variant[0]} is not invariant under the "
+                f"permutations of {variant[1]} (some permutation maps it "
+                "onto another table; TLC would not check this)")
+        return sorts
 
 
 class TwoPhaseModel(SchemaModel):
@@ -378,7 +443,8 @@ class PaxosModel(SchemaModel):
         preds = tuple(self._predicate(nm, b) for nm in config.invariants)
         return actions.build_schema_step(
             px.SCHEMA, px.ACTIONS, px.action_table(b), b,
-            predicates=preds, const_tables=self._consts(b))
+            predicates=preds, const_tables=self._consts(b),
+            sorts=tuple(config.symmetry))
 
     def py_invariant(self, name):
         px = self._mod()
@@ -451,8 +517,8 @@ class PaxosModel(SchemaModel):
             self._predicate(nm, bounds)
         config = CheckConfig(
             bounds=bounds, spec="paxos", invariants=invariants,
-            symmetry=(), chunk=opts.chunk, check_deadlock=opts.deadlock,
-            view=None)
+            symmetry=self._symmetry(cfg, opts, bounds, where),
+            chunk=opts.chunk, check_deadlock=opts.deadlock, view=None)
         return config, ()
 
 

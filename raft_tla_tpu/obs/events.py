@@ -25,14 +25,14 @@ wall).  This module replaces all of that with:
   ``level_end`` derived automatically from level transitions and
   ``violation`` derived from the final :class:`~raft_tla_tpu.engine.EngineResult`.
 
-Event grammar (``SCHEMA_VERSION`` = 14; earlier-version lines remain
+Event grammar (``SCHEMA_VERSION`` = 15; earlier-version lines remain
 valid) —
 every line is one JSON object with base fields ``v`` (schema version),
 ``event`` (type) and ``ts`` (unix epoch seconds):
 
 ``run_start``      engine, universe, spec, invariants, resumed
                    [+ bounds, symmetry, view, chunk, caps, n_states,
-                      n_devices, git_sha, fiducials, pid]
+                      n_devices, git_sha, fiducials, pid, group]
 ``segment``        the ProgressRecord fields (below)
 ``level_end``      level, n_states           (as observed at a boundary)
 ``checkpoint``     path [+ n_states]
@@ -190,13 +190,21 @@ of its ``d2h`` spans' ``bytes``; the mesh engine's ``d2h`` span also
 carries ``path``, ``"head"`` or ``"whole"``): keys inside the record and
 span ``args``, which the schema does not enumerate, so no version moved.
 
+Version 15 adds the order of the symmetry group a run reduces by:
+``run_start.group`` (|G|: 240 for Paxos under Acceptor x Value at five
+acceptors, 1 with no SYMMETRY; the ddd engine, Raft's axes and a frontend
+schema's sorts alike).  Its ``segment`` spans carry ``group`` and
+``images`` (``group`` x the lanes of the segment's steps) among their
+``args``, so that a reader turns the ``orbit_scan`` scope's device time
+into time an image with no shape of its own.
+
 A run log with no ``run_end`` means the process died — crash attribution
 for free.  The schema is strict: unknown fields fail validation and the
-v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12/v13/v14-only
+v2/v7/v8/v10-only event types (resp. v3/v4/v5/v6/v8/v9/v11/v12/v13/v14/v15-only
 fields) are invalid on a ``"v" < 2`` / ``"v" < 7`` / ``"v" < 8`` /
 ``"v" < 10`` (resp. ``"v" < 3`` / ``"v" < 4`` / ``"v" < 5`` /
 ``"v" < 6`` / ``"v" < 8`` / ``"v" < 9`` / ``"v" < 11`` / ``"v" < 12`` /
-``"v" < 13`` / ``"v" < 14``) line, so any addition requires
+``"v" < 13`` / ``"v" < 14`` / ``"v" < 15``) line, so any addition requires
 a version bump (versioning policy in README.md).
 """
 
@@ -209,7 +217,7 @@ import queue
 import threading
 import time
 
-SCHEMA_VERSION = 14
+SCHEMA_VERSION = 15
 _VERSIONS = tuple(range(1, SCHEMA_VERSION + 1))  # validate_event accepts
 
 # Environment knobs (set by check.py --events/--phase-timers; inherited by
@@ -340,17 +348,21 @@ _V13_FIELDS = {"segment": frozenset({"probe_tiles"})}
 # record of the run) — invalid on a "v" < 14 line.
 _V14_FIELDS = {"run_end": frozenset({"level_log"})}
 
+# Fields that only exist from schema version 15 on (the order of the
+# symmetry group a ddd run reduces by) — invalid on a "v" < 15 line.
+_V15_FIELDS = {"run_start": frozenset({"group"})}
+
 # schema version -> the fields that exist only from it on, by event
 _FIELDS_SINCE = {3: _V3_FIELDS, 4: _V4_FIELDS, 5: _V5_FIELDS,
                  6: _V6_FIELDS, 8: _V8_FIELDS, 9: _V9_FIELDS,
                  11: _V11_FIELDS, 12: _V12_FIELDS, 13: _V13_FIELDS,
-                 14: _V14_FIELDS}
+                 14: _V14_FIELDS, 15: _V15_FIELDS}
 
 _OPTIONAL = {
     "run_start": {"bounds": dict, "symmetry": list, "view": str,
                   "chunk": int, "caps": str, "n_states": int,
                   "n_devices": int, "git_sha": str, "fiducials": dict,
-                  "pid": int, "anchor": dict, "host": dict},
+                  "pid": int, "anchor": dict, "host": dict, "group": int},
     "segment": {"coverage": dict, "route_peak": int, "n_devices": int,
                 "inv_evals": dict, "phase_s": dict, "device_rates": list,
                 "bin": str, "inflight": int, "flush_backlog": int,
@@ -776,7 +788,8 @@ class RunTelemetry:
     # -- lifecycle events ---------------------------------------------------
 
     def run_start(self, n_states: int | None = None,
-                  fiducials: dict | None = None) -> None:
+                  fiducials: dict | None = None,
+                  group: int | None = None) -> None:
         if n_states is not None:
             self.tracker.anchor(n_states)
         if self.log is None:
@@ -812,6 +825,8 @@ class RunTelemetry:
             fields["git_sha"] = sha
         if fiducials:
             fields["fiducials"] = fiducials
+        if group is not None:
+            fields["group"] = int(group)
         fields["pid"] = os.getpid()
         # The v8 clock anchor and host context: always stamped (three
         # clock reads and a dict) so any log joins a merged trace

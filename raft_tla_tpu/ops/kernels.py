@@ -830,6 +830,43 @@ def _orbit_fp_prescan(orbit_fp, flat, raw_hi, raw_lo, N):
     return out(None)
 
 
+def orbit_keys(bounds, symmetry, orbit_fp, consts, ksuccs, svecs, valid):
+    """The orbit keys of ``[B, A]``-shaped successors, ``(fp_hi, fp_lo)``
+    of that shape: ``orbit_fp`` over every lane, or where the program gets
+    the prescan ladder (:func:`_prescan_enabled`) over the first occurrence
+    of each raw key.  One definition for every step that reduces by a
+    symmetry, Raft's (:func:`apply_stages`) and a schema-declared spec's
+    (``frontend/actions.build_schema_step``)."""
+    flat = jax.tree.map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), ksuccs)
+    N = valid.size
+    vmask = valid.reshape(-1)
+    if _prescan_enabled(bounds, symmetry):
+        # raw keys hash the ALREADY-PACKED UN-VIEWED rows —
+        # deliberate: zero extra pack cost, and raw grouping only
+        # needs to REFINE canonical equality (under a view,
+        # view-equal successors that differ in view-excluded fields
+        # just occupy separate slots — less compaction, never
+        # wrong).  In-chunk raw collisions are strictly inside the
+        # globally-accepted fp-collision class; invalid lanes
+        # collapse into one all-ones sentinel group
+        with jax.named_scope("prescan"):
+            rh, rl = fpr.fingerprint(svecs.reshape(N, -1), consts,
+                                     jnp)
+            rh = jnp.where(vmask, rh, ~jnp.uint32(0))
+            rl = jnp.where(vmask, rl, ~jnp.uint32(0))
+            fh, fl = _orbit_fp_prescan(orbit_fp, flat, rh, rl, N)
+    else:
+        with jax.named_scope("orbit_scan"):
+            fh, fl = orbit_fp(flat)
+    # invalid lanes: ZERO, not whichever garbage the sentinel
+    # group's rep produced — deterministic across step variants
+    # (the CP per-lane parity test compares every lane)
+    fh = jnp.where(vmask, fh, 0)
+    fl = jnp.where(vmask, fl, 0)
+    return fh.reshape(svecs.shape[:2]), fl.reshape(svecs.shape[:2])
+
+
 def apply_stages(bounds, stages, symmetry, succs, svecs, valid):
     """The per-candidate stage block on ``[B, A]``-shaped successors —
     view, orbit/plain fingerprints, invariants, StateConstraint.  One
@@ -843,35 +880,8 @@ def apply_stages(bounds, stages, symmetry, succs, svecs, valid):
             ksvecs = jax.vmap(jax.vmap(
                 lambda t: st.pack(t, jnp)))(ksuccs)
     if symmetry:
-        flat = jax.tree.map(
-            lambda a: a.reshape((-1,) + a.shape[2:]), ksuccs)
-        N = valid.size
-        vmask = valid.reshape(-1)
-        if _prescan_enabled(bounds, symmetry):
-            # raw keys hash the ALREADY-PACKED UN-VIEWED rows —
-            # deliberate: zero extra pack cost, and raw grouping only
-            # needs to REFINE canonical equality (under a view,
-            # view-equal successors that differ in view-excluded fields
-            # just occupy separate slots — less compaction, never
-            # wrong).  In-chunk raw collisions are strictly inside the
-            # globally-accepted fp-collision class; invalid lanes
-            # collapse into one all-ones sentinel group
-            with jax.named_scope("prescan"):
-                rh, rl = fpr.fingerprint(svecs.reshape(N, -1), consts,
-                                         jnp)
-                rh = jnp.where(vmask, rh, ~jnp.uint32(0))
-                rl = jnp.where(vmask, rl, ~jnp.uint32(0))
-                fh, fl = _orbit_fp_prescan(orbit_fp, flat, rh, rl, N)
-        else:
-            with jax.named_scope("orbit_scan"):
-                fh, fl = orbit_fp(flat)
-        # invalid lanes: ZERO, not whichever garbage the sentinel
-        # group's rep produced — deterministic across step variants
-        # (the CP per-lane parity test compares every lane)
-        fh = jnp.where(vmask, fh, 0)
-        fl = jnp.where(vmask, fl, 0)
-        fp_hi = fh.reshape(svecs.shape[:2])
-        fp_lo = fl.reshape(svecs.shape[:2])
+        fp_hi, fp_lo = orbit_keys(bounds, symmetry, orbit_fp, consts,
+                                  ksuccs, svecs, valid)
     else:
         with jax.named_scope("plain_fp"):
             fp_hi, fp_lo = fpr.fingerprint(ksvecs, consts, jnp)

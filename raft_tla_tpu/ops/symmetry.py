@@ -445,6 +445,28 @@ def _block_perms(n_perms: int, n_lanes: int) -> int:
     return max(d for d in range(1, fit + 1) if n_perms % d == 0)
 
 
+def _lex_min(a, b, xp=None):
+    """The lesser of two ``(hi, lo)`` keys, lane by lane (``a`` may be
+    ``None``: nothing yet)."""
+    if a is None:
+        return b
+    if xp is None:
+        import jax.numpy as xp
+    (ah, al), (bh, bl) = a, b
+    take = (bh < ah) | ((bh == ah) & (bl < al))
+    return xp.where(take, bh, ah), xp.where(take, bl, al)
+
+
+def _least_image(best, hi, lo):
+    """``best`` against the least of a block's images' keys
+    (``hi, lo: uint32[P_b, N]``, side by side)."""
+    import jax
+    import jax.numpy as jnp
+    top = jnp.uint32(0xFFFFFFFF)
+    return _lex_min(best, jax.lax.reduce((hi, lo), (top, top), _lex_min,
+                                         (0,)))
+
+
 def _relabel_hi(hi, src_row, dst_row, xp):
     """Message hi words with ``src`` / ``dst`` mapped through one server
     permutation, given as its rows of ``_server_luts``' ``src`` / ``dst``
@@ -672,11 +694,6 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
                 t = jax.vmap(lambda s: st.canonicalize(s, jnp))(t)
             return fpr.field_sums(t, consts, jnp, moved)
 
-        def lex_min(a, b):
-            (ah, al), (bh, bl) = a, b
-            take = (bh < ah) | ((bh == ah) & (bl < al))
-            return jnp.where(take, bh, ah), jnp.where(take, bl, al)
-
         def block(best, xs):
             # the linear sums of P_b permutations in one product; their
             # images side by side, [P_b, N]; the least of them
@@ -691,9 +708,7 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
                     s1, s2 = s1 + m1, s2 + m2
                 hi, lo = jax.vmap(image, in_axes=(0, 0, 0, None))(
                     s1, s2, sl, vl)
-                top = jnp.uint32(0xFFFFFFFF)
-                least = jax.lax.reduce((hi, lo), (top, top), lex_min, (0,))
-                return lex_min(best, least), None
+                return _least_image(best, hi, lo), None
 
             if value:
                 return jax.lax.scan(under, best, vluts)
@@ -713,6 +728,173 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     return orbit_fp
 
 
+# ---------------------------------------------------------------------------
+# Orbit keys of a spec declared as frontend data.  A schema names its
+# symmetric sorts and says, a field, which axes a sort indexes and whether
+# its contents are members of one (frontend/schema.Sort / Over): from that
+# alone, the plain loop that *is* the key (permute, pack, fingerprint,
+# least) and the device form of it.  Every field such a schema can declare
+# is a flag or a small int that a permutation moves or relabels, so the
+# whole key before its finaliser is ``features . table``: no bag to rank and
+# nothing to move, and the limbs, the block product and the least key below
+# are the functions Raft's scan runs.
+# ---------------------------------------------------------------------------
+
+def schema_group(schema, bounds: Bounds, sorts: tuple) -> tuple:
+    """The group the named sorts span, ``{sort: permutation}`` an element
+    (``p[j]`` = what member j becomes), the identity first; the sorts in
+    the schema's order, the last one's permutations innermost.  A name the
+    schema does not declare is refused."""
+    extra = sorted(set(sorts) - set(schema.sort_names))
+    if extra:
+        raise ValueError(
+            f"schema {schema.name!r} declares no symmetric sort "
+            f"{extra[0]!r} (declared: "
+            f"{', '.join(schema.sort_names) or 'none'})")
+    sizes = schema.layout(bounds).sort_sizes
+    per = []
+    for name in schema.sort_names:
+        if name not in sorts:
+            continue
+        k = sizes[name]
+        if k > MAX_SYM_SERVERS:
+            raise ValueError(
+                f"{name} symmetry supports at most {MAX_SYM_SERVERS} "
+                f"members (got {k}: {math.factorial(k)} permutations)")
+        per.append([(name, p) for p in itertools.permutations(range(k))])
+    return tuple(dict(g) for g in itertools.product(*per))
+
+
+def permute_schema_struct(struct: dict, lay, g: dict, xp) -> dict:
+    """The image of a schema-declared struct (leading batch dims pass
+    through) under one element of :func:`schema_group`: an axis a sort
+    indexes is reordered (the image's index ``img[i]`` holds the state's
+    ``i``), contents that are members of a sort are relabelled."""
+    out = {}
+    for f in lay.schema.fields:
+        a, shp = struct[f.name], lay.shapes[f.name]
+        for axis, over in f.overs():
+            if over.sort in g:
+                back = np.argsort(over.image(g[over.sort], shp[axis]))
+                a = xp.take(a, xp.asarray(back), axis=axis - len(shp))
+        if f.content is not None and f.content.sort in g:
+            lut = f.content.image(g[f.content.sort], lay.his[f.name] + 1)
+            a = xp.asarray(lut.astype(np.int32))[a]
+        out[f.name] = a
+    return out
+
+
+def schema_orbit_fingerprint(struct: dict, lay, consts, sorts: tuple, xp):
+    """Orbit-minimal (hi, lo) fingerprint of schema-declared struct(s): the
+    least key over the images under every element of the named sorts'
+    group.  The definition; :func:`build_schema_orbit_fp` is held to it
+    bit for bit."""
+    best = None
+    for g in schema_group(lay.schema, lay.bounds, sorts):
+        best = _lex_min(best, fpr.fingerprint(
+            lay.pack(permute_schema_struct(struct, lay, g, xp), xp),
+            consts, xp), xp)
+    return best
+
+
+def _schema_features(struct: dict, lay, xp):
+    """``struct[N, ...] -> int8[F, N]``: what no group element changes, in
+    :func:`_schema_key_table`'s order, lanes minor.  A field whose
+    contents a sort relabels gives one feature a position and a value (its
+    one-hot, 0 left out: it adds nothing to a sum); every other field the
+    word itself, which the fold leaves alone (declared within 0..127:
+    :func:`_schema_feature_cap`)."""
+    parts = []
+    for f in lay.schema.fields:
+        a = struct[f.name]
+        if f.content is not None:
+            a = a[..., None] == xp.arange(1, lay.his[f.name] + 1)
+        a = xp.reshape(a, (a.shape[0], -1))
+        parts.append(a.astype(xp.int8))     # 7-bit: the feature cap is 127
+    return xp.concatenate(parts, axis=1).T
+
+
+def _schema_feature_cap(lay) -> int:
+    """The largest entry :func:`_schema_features` can hold; a field the
+    linear key cannot carry (a negative value, which the fold would not
+    leave alone) is refused by name."""
+    cap = 1
+    for f in lay.schema.fields:
+        if f.lo < 0:
+            raise ValueError(
+                f"orbit key: field {f.name!r} of schema "
+                f"{lay.schema.name!r} may hold {f.lo} < 0")
+        if f.content is None:
+            cap = max(cap, lay.his[f.name])
+    return cap
+
+
+def _schema_key_table(lay, consts, group: tuple) -> np.ndarray:
+    """``uint32[|G|, 2, F]``: for each group element the two lanes'
+    constants of :func:`_schema_features`' entries, such that ``features .
+    table[g]`` (mod 2^32) is the sum before the finaliser of
+    ``fingerprint(pack(permute_schema_struct(s, g)))``: the constant of
+    the position a word moves to, times the value it is relabelled to
+    where the feature is a content's one-hot."""
+    fc = fpr.field_constants(lay.shapes, consts)
+    table = []
+    for g in group:
+        row = []
+        for f in lay.schema.fields:
+            cf, shp = fc[f.name], lay.shapes[f.name]
+            for axis, over in f.overs():
+                if over.sort in g:
+                    cf = np.take(cf, over.image(g[over.sort], shp[axis]),
+                                 axis=1 + axis)
+            if f.content is not None:
+                hi = lay.his[f.name]
+                to = f.content.image(g[f.content.sort], hi + 1) \
+                    if f.content.sort in g else np.arange(hi + 1)
+                cf = cf[..., None] * to[1:].astype(np.uint32)
+            row.append(cf.reshape(2, -1))
+        table.append(np.concatenate(row, axis=1))
+    return np.stack(table)
+
+
+def build_schema_orbit_fp(schema, bounds: Bounds, sorts: tuple, consts):
+    """Batched orbit-minimal fingerprints of a schema-declared spec:
+    ``struct[N, ...] -> (hi, lo)[N]``, bit-identical to
+    :func:`schema_orbit_fingerprint`.  :func:`build_orbit_fp`'s linear
+    part, and nothing else: ``int8`` features once a call
+    (:func:`_schema_features`), the host-built table of permuted constants
+    a group element (:func:`_schema_key_table`) split into ``int8`` limbs,
+    one ``dot_general`` a block of images (:func:`_block_perms`), the
+    limbs shifted home, the finaliser, the least ``(hi, lo)`` — a
+    ``lax.scan`` over the blocks, the program's size constant in |G|."""
+    import jax
+    import jax.numpy as jnp
+
+    lay = schema.layout(bounds)
+    group = schema_group(schema, bounds, sorts)
+    table = _schema_key_table(lay, np.asarray(consts), group)
+    _check_limb_range(table.shape[-1], _schema_feature_cap(lay))
+    limbs = jnp.asarray(_key_limbs(table))               # [G, 2, 4, F]
+
+    def orbit_fp(struct):
+        phi = _schema_features(struct, lay, jnp)
+        Gb = _block_perms(len(group), phi.shape[1])
+
+        def block(best, block_limbs):
+            sums = _limb_sums(block_limbs, phi, jnp)     # [G_b, 2, N]
+            hi, lo = fpr.finalise(sums[:, 0], sums[:, 1], jnp)
+            return _least_image(best, hi, lo), None
+
+        # +inf derived from the input, as build_orbit_fp's (shard_map)
+        top = jnp.zeros_like(phi[0]).astype(jnp.uint32) \
+            | jnp.uint32(0xFFFFFFFF)
+        (bh, bl), _ = jax.lax.scan(
+            block, (top, top),
+            limbs.reshape((len(group) // Gb, Gb) + limbs.shape[1:]))
+        return bh, bl
+
+    return orbit_fp
+
+
 def orbit_fingerprint(struct: dict, bounds: Bounds, consts, xp,
                       axes: tuple = ("Server",)):
     """Orbit-minimal (hi, lo) fingerprint of one canonical state struct,
@@ -720,20 +902,15 @@ def orbit_fingerprint(struct: dict, bounds: Bounds, consts, xp,
     sperms = permutations(bounds) if "Server" in axes \
         else (tuple(range(bounds.n_servers)),)
     vqs = range(len(value_permutations(bounds))) if "Value" in axes else (0,)
-    best_hi = best_lo = None
+    best = None
     for p in sperms:
         ps = permute_struct(struct, p, bounds, xp)
         for qi in vqs:
             t = permute_values(ps, qi, bounds, xp) if "Value" in axes else ps
             t = st.canonicalize(t, xp)
-            hi, lo = fpr.fingerprint(st.pack(t, xp), consts, xp)
-            if best_hi is None:
-                best_hi, best_lo = hi, lo
-            else:
-                take = (hi < best_hi) | ((hi == best_hi) & (lo < best_lo))
-                best_hi = xp.where(take, hi, best_hi)
-                best_lo = xp.where(take, lo, best_lo)
-    return best_hi, best_lo
+            best = _lex_min(best, fpr.fingerprint(st.pack(t, xp), consts,
+                                                  xp), xp)
+    return best
 
 
 @functools.lru_cache(maxsize=None)

@@ -136,7 +136,8 @@ def test_validate_rejects_unknowns_and_type_drift():
     assert validate_event({**ok, "v": 12}) == []            # v12 superset
     assert validate_event({**ok, "v": 13}) == []            # v13 superset
     assert validate_event({**ok, "v": 14}) == []            # v14 superset
-    assert validate_event({**ok, "v": 15})                  # future version
+    assert validate_event({**ok, "v": 15}) == []            # v15 superset
+    assert validate_event({**ok, "v": 16})                  # future version
     assert validate_event({"v": 1, "event": "level_end", "ts": 0.0,
                            "level": 3})                     # missing field
 
@@ -369,6 +370,21 @@ def test_validate_v14_run_end_level_log():
     assert len(errs) == 1 and "requires schema version >= 14" in errs[0]
     assert validate_event({**end, "level_log": []})        # type drift
     assert json.loads(json.dumps(end)) == end              # round trip
+
+
+def test_validate_v15_run_start_group():
+    """The order of the symmetry group a ddd run reduces by
+    (``run_start.group``) exists only from schema v15, field-gated like
+    ``run_end.level_log``; a v14 ``run_start`` still reads."""
+    v14 = {"v": 14, "event": "run_start", "ts": 0.0, "engine": "ddd",
+           "universe": {"servers": 5, "values": 2}, "spec": "paxos",
+           "invariants": ["Consistency"], "resumed": False}
+    start = {**v14, "v": 15, "group": 240}
+    assert validate_event(v14) == [] and validate_event(start) == []
+    errs = validate_event({**start, "v": 14})  # v15-only field, v14 line
+    assert len(errs) == 1 and "requires schema version >= 15" in errs[0]
+    assert validate_event({**start, "group": 240.0})       # type drift
+    assert json.loads(json.dumps(start)) == start          # round trip
 
 
 def test_git_sha_is_read_from_the_checkouts_files(tmp_path, monkeypatch):
