@@ -762,18 +762,47 @@ def _place_piece(fbuf, fcon, rows, con, at):
             jax.lax.dynamic_update_slice(fcon, con, (at,)))
 
 
-def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
-    """``_filter_insert_ordered`` with the streamed candidates as a mask
-    in lane order (``stream[c]`` is True iff candidate c streamed) in
-    place of the compaction order: one scatter of the streamed lanes
-    (3.9 ms at the mesh engine's 622,592 lanes, where the stage before it
-    is 2.9 and the lane-order stage was 51.8; PERF.md, PR 35)."""
-    BA = key_hi.shape[0]
-    tbl_hi, tbl_lo, n_stream, compact, _ = _filter_insert_ordered(
-        tbl_hi, tbl_lo, key_hi, key_lo, active)
-    lane = jnp.where(jnp.arange(BA, dtype=I32) < n_stream, compact, BA)
-    stream = jnp.zeros((BA,), bool).at[lane].set(True, mode="drop")
-    return tbl_hi, tbl_lo, stream
+def _write_slabs(bufs, cursor, n_stream, compact, nk, gather,
+                 watch=None, seen=()):
+    """The ``stream`` stage's writes, for a chunk of a one-chip segment and
+    for a lockstep step of a mesh shard alike: compact once, write
+    contiguously.  Slab j is the streamed candidates j*SLAB.. of the
+    filter's compaction order (``compact[:n_stream]``,
+    _filter_insert_ordered), gathered by ``gather(sel)`` — one column a
+    buffer, in the buffers' order — and laid down with one
+    ``dynamic_update_slice`` a buffer at ``cursor + j*SLAB``.  A slab's
+    entries past the streamed count are other candidates' rows above the
+    new cursor: the next step overwrites them and the harvest never reads
+    past the cursor.  The buffers' slack rows (``_slab_plan(nk)``, ``nk``
+    the most rows a step can stream) keep the last slab inside them, so
+    no write is ever clamped.  ``watch(seen, sel, live, at)``, where
+    given, folds what a caller wants to know of a slab's candidates
+    (``live``: which of its entries streamed) into ``seen`` as the loop
+    goes — the mesh step's first violating slot.  Returns ``(bufs,
+    n_slabs, seen)``."""
+    SLAB, SLACK = _slab_plan(nk)
+    compact_p = jnp.pad(compact, (0, SLACK))
+
+    def write_slab(j, carry):
+        bufs, seen = carry
+        sel = jax.lax.dynamic_slice(compact_p, (j * SLAB,), (SLAB,))
+        slab = gather(sel)
+        at = cursor + j * SLAB
+        if watch is not None:
+            live = j * SLAB + jnp.arange(SLAB, dtype=I32) < n_stream
+            seen = watch(seen, sel, live, at)
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                b, v, (at,) + (0,) * (b.ndim - 1))
+            for b, v in zip(bufs, slab)), seen
+
+    # the trip count is the observed count: one slab for nearly every
+    # step (an empty step still writes one, of garbage above the
+    # cursor), more only past SLAB streamed rows
+    n_slabs = jnp.maximum((n_stream + SLAB - 1) // SLAB, 1)
+    bufs, seen = jax.lax.fori_loop(0, n_slabs, write_slab,
+                                   (tuple(bufs), seen))
+    return bufs, n_slabs, seen
 
 
 def _filter_insert_ordered(tbl_hi, tbl_lo, key_hi, key_lo, active):
@@ -952,7 +981,6 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
     if OCAP < NK:
         raise ValueError(
             f"seg_rows={OCAP} must be >= per-chunk candidate rows = {NK}")
-    SLAB, SLACK = _slab_plan(NK)
     n_inv = len(config.invariants)
     BIG = jnp.int32(np.iinfo(np.int32).max)
 
@@ -1036,19 +1064,7 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
         with jax.named_scope("pack"):
             svecs = schema.pack(word_rows, jnp)
         with jax.named_scope("stream"):
-            # compact once, write contiguously: slab j is the streamed
-            # lanes j*SLAB.. of the filter's compaction order, gathered
-            # and laid down at cursor + j*SLAB.  A slab's entries past
-            # the streamed count are other lanes' rows above the new
-            # cursor: the next chunk overwrites them and the harvest
-            # never reads past stats.cursor.  The buffers' slack rows
-            # (_slab_plan) keep the last slab inside them, so no write
-            # is ever clamped.
-            compact_p = jnp.pad(compact, (0, SLACK))
-
-            def write_slab(j, bufs):
-                sel = jax.lax.dynamic_slice(compact_p, (j * SLAB,),
-                                            (SLAB,))
+            def gather(sel):
                 lane = src[sel] if routed else sel
                 # the packed rows word by word: P lane gathers keep the
                 # [N, P] rows and the buffer in their compact layouts; one
@@ -1060,22 +1076,13 @@ def _build_segment(config: CheckConfig, caps: DDDCapacities, A: int,
                 # how deep the campaign is); the harvest rebases to the
                 # global int64 discovery index by adding the block start
                 # on the host
-                slab = (kh[sel], kl[sel], rows, r0 + lane // A,
+                return (kh[sel], kl[sel], rows, r0 + lane // A,
                         lane % A, con_rows[sel])
-                at = cursor + j * SLAB
-                return tuple(
-                    jax.lax.dynamic_update_slice(
-                        b, v, (at,) + (0,) * (b.ndim - 1))
-                    for b, v in zip(bufs, slab))
 
-            # the trip count is the observed count: one slab for nearly
-            # every chunk (an empty chunk still writes one, of garbage
-            # above the cursor), more only past SLAB streamed rows
-            n_slabs = jnp.maximum((n_stream + SLAB - 1) // SLAB, 1)
             (okey_hi, okey_lo, orows, opar, olane,
-             ocon) = jax.lax.fori_loop(
-                0, n_slabs, write_slab,
-                (okey_hi, okey_lo, orows, opar, olane, ocon))
+             ocon), n_slabs, _ = _write_slabs(
+                (okey_hi, okey_lo, orows, opar, olane, ocon), cursor,
+                n_stream, compact, NK, gather)
             cursor = cursor + n_stream
             stream_peak = jnp.maximum(stream_peak, n_stream)
             stream_slabs = stream_slabs + n_slabs

@@ -167,7 +167,11 @@ Version 12 adds the ddd segment program's slab-write counters
 pass — the sizing signal for the slab size, as ``route_peak`` is for
 ``route_rows``) and ``stream_slabs`` (cumulative slab writes; equals the
 chunk steps unless a chunk streamed more than one slab holds).  The
-``segments`` track's ``segment`` spans carry the same two per segment.
+``segments`` track's ``segment`` spans carry the same two per segment, the
+``level`` span the level's; the mesh engine's ``level`` span carries them
+too (its step writes the same slabs, per shard): the most slabs any shard
+wrote, summed over the level's segments, and the most rows any shard
+streamed in one lockstep step.
 
 Version 13 adds the tile counter of the ddd filter probe (ddd_engine
 ``SegStats.probe_tiles``): segment ``probe_tiles`` (cumulative tiles of

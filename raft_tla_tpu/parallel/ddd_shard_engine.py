@@ -75,7 +75,8 @@ from raft_tla_tpu.device_engine import (
     _EMPTY, BUCKET, FAIL_INDEX, FAIL_LEVEL, FAIL_ROUTE, FAIL_WIDTH,
     aggregate_coverage, decode_fail)
 from raft_tla_tpu.ddd_engine import (
-    _filter_insert, _IDX_CEIL, frontier_backtrace,
+    _filter_insert_ordered, _IDX_CEIL, _slab_plan, _write_slabs,
+    frontier_backtrace,
     frontier_checkpoint_setup, load_ddd_snapshot,
     load_frontier_snapshot, save_ddd_snapshot, save_frontier_snapshot)
 from raft_tla_tpu.engine import DEADLOCK, EngineResult, Violation
@@ -105,8 +106,10 @@ class DDDShardCapacities:
     of one frontier window (a window is ``ndev * block`` global rows);
     ``table``: per-shard lossy filter slots (traffic only, never a
     ceiling); ``seg_rows``: per-shard output-buffer rows per segment (the
-    worst case a chunk can receive bounds it from below; a harvest fetches
-    the buffers' head, ``head_rows``, and all of them only past it);
+    worst case a chunk can receive bounds it from below; the buffers hold
+    these plus the slack of the stream's last slab (``_buf_rows``); a
+    harvest fetches the buffers' head, ``head_rows``, and all of them
+    only past it);
     ``flush``: per-shard pending candidates per host dedup pass;
     ``send``: per-destination exchange depth per chunk (None = the safe
     bound ``chunk * A``; smaller trades memory for a loud FAIL_ROUTE);
@@ -175,7 +178,9 @@ class MFilter(NamedTuple):
 
 
 class MBufs(NamedTuple):
-    """Per-shard candidate-stream output buffers (donated)."""
+    """Per-shard candidate-stream output buffers (donated), the engine's
+    ``_buf_rows`` rows each; rows at and past a segment's cursor are
+    unspecified."""
 
     okey_hi: jax.Array    # [dev] [OCAP]
     okey_lo: jax.Array    # [dev]
@@ -193,6 +198,9 @@ class MStats(NamedTuple):
     viol_pos: jax.Array   # [dev] [1] buffer slot of first violating
     viol_inv: jax.Array   # [dev] [1]   streamed candidate, -1 if none
     dead_g: jax.Array     # [dev] [1] global id of first dead row, -1
+    stream_slabs: jax.Array  # [dev] [1] slab writes; == steps unless a
+                             #   step streamed more than one slab here
+    stream_peak: jax.Array   # [dev] [1] most rows one step streamed here
     steps: jax.Array      # replicated: chunks executed (pacer signal)
     done: jax.Array       # replicated: window exhausted (reading it off
                           # stats keeps the host from syncing on the
@@ -214,19 +222,40 @@ class _MCarry(NamedTuple):
     viol_pos: jax.Array
     viol_inv: jax.Array
     dead_g: jax.Array
+    stream_slabs: jax.Array
+    stream_peak: jax.Array
     c: jax.Array          # replicated
     halt: jax.Array       # replicated: stop event or buffers full
 
 
 _SHARDED = ("tbl_hi", "tbl_lo", "okey_hi", "okey_lo", "orows", "opar",
             "olane", "ocon", "cursor", "n_valid", "fail", "viol_pos",
-            "viol_inv", "dead_g")
+            "viol_inv", "dead_g", "stream_slabs", "stream_peak")
 
 
 def _carry_specs(axes):
     ax = axes if len(axes) > 1 else axes[0]
     return _MCarry(**{f: P(ax) if f in _SHARDED else P()
                       for f in _MCarry._fields})
+
+
+def _exchange_plan(config: CheckConfig, caps: DDDShardCapacities, A: int,
+                   ndev: int, nici: int) -> tuple[int, int, int, int]:
+    """``(A_loc, Csend, Csend2, NR)``: the lanes a row expands to on one
+    shard (all of the action table, or its lane slice in CP mode), the
+    two exchange depths, and the rows one lockstep step can deliver to a
+    shard — what bounds ``seg_rows`` from below and sizes the stream's
+    slabs (``ddd_engine._slab_plan(NR)``)."""
+    if caps.cp:
+        from raft_tla_tpu.parallel import cp_expand as cpx
+        A_loc = cpx.cp_lane_count(config.bounds, config.spec, ndev)
+    else:
+        A_loc = A
+    Csend = caps.send if caps.send is not None else config.chunk * A_loc
+    nslice = ndev // nici
+    Csend2 = caps.send2 if caps.send2 is not None else nici * Csend
+    NR = nici * Csend if nslice == 1 else nslice * Csend2
+    return A_loc, Csend, Csend2, NR
 
 
 def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
@@ -245,7 +274,6 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                                  tuple(config.invariants),
                                  config.symmetry, ndev=ndev,
                                  view=config.view)
-        A_loc = cpx.cp_lane_count(config.bounds, config.spec, ndev)
         lane_map = jnp.asarray(cpx.cp_lane_map(config.bounds, config.spec,
                                                ndev))     # [ndev, A_loc]
     else:
@@ -254,13 +282,10 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
         step = kernels.build_step(config.bounds, config.spec,
                                   tuple(config.invariants),
                                   config.symmetry, view=config.view)
-        A_loc = A
+    A_loc, Csend, Csend2, NR = _exchange_plan(config, caps, A, ndev, nici)
     BA = B * A_loc
     OCAP = caps.seg_rows
-    Csend = caps.send if caps.send is not None else BA
     nslice = ndev // nici
-    Csend2 = caps.send2 if caps.send2 is not None else nici * Csend
-    NR = nici * Csend if nslice == 1 else nslice * Csend2
     if OCAP < NR:
         raise ValueError(
             f"seg_rows={OCAP} must be >= per-chunk receivable rows {NR} "
@@ -283,8 +308,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                 budget, n_chunks):
         def chunk_body(carry: _MCarry) -> _MCarry:
             (tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar, olane, ocon,
-             cursor, n_valid, fail, viol_pos, viol_inv, dead_g, c,
-             halt) = carry
+             cursor, n_valid, fail, viol_pos, viol_inv, dead_g,
+             stream_slabs, stream_peak, c, halt) = carry
             cur, nva, fa = cursor[0], n_valid[0], fail[0]
             vpos, vinv, dg = viol_pos[0], viol_inv[0], dead_g[0]
 
@@ -368,32 +393,48 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
 
             # ---- owner-side lossy filter; stream to my buffer ----
             with jax.named_scope("filter_insert"):
-                tbl_hi, tbl_lo, stream = _filter_insert(
-                    tbl_hi, tbl_lo, r_hi, r_lo, active)
+                tbl_hi, tbl_lo, n_stream, compact, _ = \
+                    _filter_insert_ordered(tbl_hi, tbl_lo, r_hi, r_lo,
+                                           active)
             with jax.named_scope("stream"):
-                pos = cur + jnp.cumsum(stream.astype(I32)) - 1
-                sl = jnp.where(stream, pos, OCAP)
-                okey_hi = okey_hi.at[sl].set(r_hi, mode="drop")
-                okey_lo = okey_lo.at[sl].set(r_lo, mode="drop")
-                orows = orows.at[sl].set(r_vec, mode="drop")
-                opar = opar.at[sl].set(r_par, mode="drop")
-                olane = olane.at[sl].set(r_lane, mode="drop")
-                ocon = ocon.at[sl].set(((r_flags >> 1) & 1) == 1,
-                                       mode="drop")
-                cur = cur + jnp.sum(stream.astype(I32))
+                # the one-chip stage (ddd_engine._write_slabs): slabs of
+                # the received lanes in the filter's compaction order —
+                # batch order, what a cumsum over a lane-order mask gives —
+                # laid down at my cursor.  The trip count is per shard; no
+                # collective runs inside the loop.
+                def gather(sel):
+                    # the packed rows word by word, as the one-chip stage
+                    rows = jnp.stack([r_vec[:, p][sel]
+                                      for p in range(schema.P)], axis=1)
+                    return (r_hi[sel], r_lo[sel], rows, r_par[sel],
+                            r_lane[sel], ((r_flags[sel] >> 1) & 1) == 1)
 
-            # ---- first violating streamed candidate (relaxed stop) ----
-            if n_inv:
-                bad = stream & ((r_flags >> 2) & ((1 << n_inv) - 1)
-                                != (1 << n_inv) - 1)
-                first = jnp.min(jnp.where(bad, pos, BIG))
-                hit = (first < BIG) & (vpos < 0)
-                fidx = jnp.argmin(jnp.where(bad, pos, BIG))
-                binv = jnp.argmax(
-                    ((r_flags[fidx] >> 2) & (1 << jnp.arange(n_inv))) == 0
-                ).astype(I32)
-                vpos = jnp.where(hit, first, vpos)
-                vinv = jnp.where(hit, binv, vinv)
+                def watch(seen, sel, live, at):
+                    # first violating streamed candidate (relaxed stop),
+                    # found in the slab from the flags it gathers anyway
+                    # (the slab's own gather of r_flags, one op once XLA
+                    # has merged the two): slabs come in stream order, so
+                    # the first hit is the least buffer slot
+                    vpos, vinv = seen
+                    fl = r_flags[sel]
+                    bad = live & ((fl >> 2) & ((1 << n_inv) - 1)
+                                  != (1 << n_inv) - 1)
+                    first = jnp.argmax(bad).astype(I32)
+                    hit = bad[first] & (vpos < 0)
+                    binv = jnp.argmax(
+                        ((fl[first] >> 2) & (1 << jnp.arange(n_inv))) == 0
+                    ).astype(I32)
+                    return (jnp.where(hit, at + first, vpos),
+                            jnp.where(hit, binv, vinv))
+
+                ((okey_hi, okey_lo, orows, opar, olane, ocon), n_slabs,
+                 (vpos, vinv)) = _write_slabs(
+                    (okey_hi, okey_lo, orows, opar, olane, ocon), cur,
+                    n_stream, compact, NR, gather,
+                    watch if n_inv else None, (vpos, vinv))
+                cur = cur + n_stream
+                slabs = stream_slabs[0] + n_slabs
+                peak = jnp.maximum(stream_peak[0], n_stream)
 
             # ---- lockstep continue/halt (replicated collectives) ----
             stop_ev = jax.lax.psum(
@@ -402,8 +443,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
             full = jax.lax.pmax((cur + NR > OCAP).astype(I32), axes) > 0
             return _MCarry(tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar,
                            olane, ocon, cur[None], nva[None], fa[None],
-                           vpos[None], vinv[None], dg[None], c + 1,
-                           stop_ev | full)
+                           vpos[None], vinv[None], dg[None], slabs[None],
+                           peak[None], c + 1, stop_ev | full)
 
         def cond(sc):
             s, carry = sc
@@ -418,6 +459,7 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
             fc.tbl_hi, fc.tbl_lo, *bufs,
             cursor=z1, n_valid=z1, fail=z1,
             viol_pos=z1 - 1, viol_inv=z1, dead_g=z1 - 1,
+            stream_slabs=z1, stream_peak=z1,
             c=fc.c, halt=jnp.bool_(False))
         steps, carry = jax.lax.while_loop(cond, body,
                                           (jnp.int32(0), carry))
@@ -426,6 +468,7 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                       carry.opar, carry.olane, carry.ocon),
                 MStats(carry.cursor, carry.n_valid, carry.fail,
                        carry.viol_pos, carry.viol_inv, carry.dead_g,
+                       carry.stream_slabs, carry.stream_peak,
                        steps, carry.c >= n_chunks))
 
     return segment
@@ -558,6 +601,12 @@ class DDDShardEngine:
                                                  config.symmetry)
         fn = _build_segment(config, self.caps, self.A, self.lay.width,
                             self.schema, self.ndev, nici, axes)
+        # rows of one shard's segment buffers: seg_rows plus the slack
+        # that keeps a step's last slab inside them (0 where a step
+        # receives whole slabs), fixed here with the program that writes
+        # the slabs
+        NR = _exchange_plan(config, self.caps, self.A, self.ndev, nici)[3]
+        self._buf_rows = self.caps.seg_rows + _slab_plan(NR)[1]
         self._segment = jax.jit(
             jax.shard_map(fn, mesh=self.mesh,
                           in_specs=(fc_specs, buf_specs, dp, dp, dp, dp,
@@ -612,7 +661,7 @@ class DDDShardEngine:
             n=jax.device_put(np.zeros((nd,), np.int32), sh))
 
     def _make_bufs(self) -> MBufs:
-        OCAP = self.caps.seg_rows
+        OCAP = self._buf_rows
         nd = self.ndev
         sh = NamedSharding(self.mesh, P(self._ax))
         z = lambda shape, dt, fill=0: jax.device_put(  # noqa: E731
@@ -931,7 +980,7 @@ class DDDShardEngine:
             prefetcher = prefetch.BlockPrefetcher(
                 pf_load, phases=tel.phases, tracer=tel.trace)
             _cleanup.callback(prefetcher.close)
-        OCAP = self.caps.seg_rows
+        OCAP = self._buf_rows       # a shard's rows of the whole buffers
         H = self._head_rows
         # one row of the six output arrays (the ddd engine's d2h ``bytes``)
         row_bytes = self.schema.P * 4 + 17
@@ -970,10 +1019,15 @@ class DDDShardEngine:
                 dev_dedup_hits=dd_hits if self._dd_apply else None)
 
         lvl_segs = lvl_steps = lvl_rows = 0  # the open level's work
+        # the stream stage's slab writes (MStats): the most any shard
+        # wrote, summed over the level's segments, and the most rows any
+        # shard streamed in one step
+        lvl_slabs = lvl_peak = 0
 
         def end_level():
             level_sp.set(segments=lvl_segs, steps=lvl_steps,
                          streamed_rows=lvl_rows,
+                         stream_slabs=lvl_slabs, stream_peak=lvl_peak,
                          new_states=n_states - lvl_hi).close()
 
         while not stopped:
@@ -985,7 +1039,7 @@ class DDDShardEngine:
             level_sp = tr.open("level", level=len(level_ends),
                                rows=lvl_hi - lvl_lo,
                                blocks=-(-(lvl_hi - w0) // W))
-            lvl_segs = lvl_steps = lvl_rows = 0
+            lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
             if prefetcher is not None and w0 < lvl_hi:
                 # level start: all window addresses are known — warm the
                 # first window immediately
@@ -1077,6 +1131,9 @@ class DDDShardEngine:
                                 else np.asarray(jax.device_get(ncur))
                         lvl_segs += 1
                         lvl_steps += int(st_h.steps)
+                        lvl_slabs += int(np.max(st_h.stream_slabs))
+                        lvl_peak = max(lvl_peak,
+                                       int(np.max(st_h.stream_peak)))
                         bufs_h = None
                         # ``stride``: rows a shard of the fetched arrays
                         src, stride, path = (head, H, "head") \

@@ -295,3 +295,105 @@ def test_manifest_gains_the_two_harvest_readers_for_the_mesh_cell_alone():
             "host_exposed_s"} <= set(names)
     for name in ("ramp_d2h_ms", "harvest_head_pct"):
         assert os.path.isfile(os.path.join(mf.BENCH, "metrics", name + ".py"))
+
+
+# ------------------------- PR 48: the two readers of the mesh step's stream
+
+def _mesh_capture(stream_op=True, exchange_op=True):
+    """A capture as ``stagered.load_xplane`` gives it: two chips, three
+    lockstep steps in one segment module, the second chip half as fast."""
+    seg = "jit(segment)/shard_map/while/body/"
+    ops = [["while.1", 0, 3000, "jit(segment)/shard_map/while"],
+           ["fusion.2", 10, 200, seg + "filter_insert/sort"]]
+    if exchange_op:
+        ops.append(["all-to-all.3", 300, 400, seg + "exchange/all_to_all"])
+    if stream_op:
+        # the slab loop's ops nest under the scope's own ``while``
+        ops += [["while.4", 1000, 900, seg + "stream/while"],
+                ["fusion.5", 1010, 300, seg + "stream/while/body/gather"],
+                ["fusion.6", 1400, 450,
+                 seg + "stream/while/body/dynamic_update_slice"]]
+    ops.append(["fusion.7", 2000, 50, seg + "streams/not_the_scope"])
+    plane = {"XLA Ops": ops, "XLA Modules": [["jit_segment(1)", 0, 3000]]}
+    slow = {"XLA Ops": [[n, 2 * s, 2 * d, path] for n, s, d, path in ops],
+            "XLA Modules": [["jit_segment(1)", 0, 6000]]}
+    return {"devices": {"/device:TPU:0": plane, "/device:TPU:1": slow}}
+
+
+def _stream_evidence(trace, steps=3):
+    from benchmark.harness import meshred, stagered
+    return {"meshred": meshred.scope_times(trace, 0, 9000),
+            "stagered": {"stages": stagered.stage_times(trace, 0, 9000)},
+            "work": {"steps": steps}, "passes": []}
+
+
+def test_stage_mesh_stream_ms_is_the_stream_scope_of_a_mesh_capture():
+    """By hand: under ``stream`` chip 0 spends the loop's own 150 ns (900
+    less its body's 750) + 300 + 450 = 900 ns, chip 1 twice that; the mean
+    is 1,350 ns over three steps."""
+    read = mf.metric_reader("stage_mesh_stream_ms")
+    ev = _stream_evidence(_mesh_capture())
+    assert ev["stagered"]["stages"]["stage_ns"]["stream"] == 1350
+    assert read(ev) == pytest.approx(1350 / 1e6 / 3)
+    # the one-chip cells' reader gives the same stage from the same capture
+    assert mf.metric_reader("stage_stream_ms")(ev) == read(ev)
+    # a one-chip capture names no exchange: this reader is the mesh's alone
+    one = _stream_evidence(_mesh_capture(exchange_op=False))
+    assert mf.metric_reader("stage_stream_ms")(one) is not None
+    assert read(one) is None
+    # a mesh capture that names no stream op: nothing to read, never 0.0
+    assert read(_stream_evidence(_mesh_capture(stream_op=False))) is None
+    # an untraced run, and a traced one whose capture no plane ran in
+    assert read({"passes": []}) is None
+    assert read({"meshred": None, "passes": []}) is None
+
+
+def _levels_log(tmp_path, levels):
+    log = tmp_path / "run.events"
+    log.write_text("\n".join(
+        _span("level", "MainThread", 10.0 + k, level=k + 1, **args)
+        for k, args in enumerate(levels)) + "\n")
+    p = passes.Pass(index=2, t_call=0.0, t_a=10.0, t_b=20.0, traced=True,
+                    events=str(log), t_trace_end=12.0)
+    return {"passes": [p]}
+
+
+def test_mesh_slabs_per_step_is_the_level_spans_slabs_over_steps(tmp_path):
+    """Over the whole traced pass's ``level`` spans: (1 + 6 + 9) slabs over
+    (1 + 6 + 6) lockstep steps."""
+    read = mf.metric_reader("mesh_slabs_per_step")
+    ev = _levels_log(tmp_path, [
+        dict(steps=1, stream_slabs=1, stream_peak=2, streamed_rows=2),
+        dict(steps=6, stream_slabs=6, stream_peak=9000, streamed_rows=30000),
+        dict(steps=6, stream_slabs=9, stream_peak=40000,
+             streamed_rows=150000)])
+    assert read(ev) == pytest.approx(16 / 13)
+    # the parent's mesh spans count steps and no slabs: nothing to read
+    assert read(_levels_log(tmp_path, [
+        dict(steps=1, streamed_rows=2), dict(steps=6, streamed_rows=30000)
+    ])) is None
+    assert read(_levels_log(tmp_path, [])) is None      # no level span
+    assert read({"passes": []}) is None                 # an untraced run
+
+
+def test_manifest_gains_the_two_stream_readers_for_the_mesh_cell_alone():
+    manifest = mf.load()
+    assert mf.problems(manifest) == []
+    # the 69 entries PR 47 left, then these two
+    assert manifest["per_layer"][69:71] == [
+        {"name": "stage_mesh_stream_ms", "unit": "ms/step",
+         "better": "lower", "source": "device_trace",
+         "layer": "d2h export and host key set", "moves": "orbits_per_s",
+         "workloads": [MESH]},
+        {"name": "mesh_slabs_per_step", "unit": "slabs/step",
+         "better": "lower", "source": "program_span",
+         "layer": "d2h export and host key set", "moves": "orbits_per_s",
+         "workloads": [MESH]}]
+    names = mf.metric_names(manifest, MESH, "per_layer")
+    assert {"stage_mesh_stream_ms", "mesh_slabs_per_step",
+            "stage_exchange_ms"} <= set(names)
+    # the one-chip cells' lists are as they were
+    assert MESH not in next(m for m in manifest["per_layer"]
+                            if m["name"] == "stage_stream_ms")["workloads"]
+    for name in ("stage_mesh_stream_ms", "mesh_slabs_per_step"):
+        assert os.path.isfile(os.path.join(mf.BENCH, "metrics", name + ".py"))

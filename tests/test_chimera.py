@@ -6,7 +6,7 @@ duplicate-index updates in operand order identically in both ops; a
 compiler drift could have fused a fabricated (hiA, loB) "chimera" key
 aliasing a never-streamed candidate — silent state loss, the one
 failure an exhaustive checker must never have.  Round 5 removed the
-reliance (``_filter_insert`` dedups (bucket, slot) within each batch so
+reliance (``_filter_insert_ordered`` dedups (bucket, slot) within each batch so
 the scatter indices are duplicate-free); these tests construct the
 adversarial colliding-keys case directly and would fail loudly if the
 dedup regressed AND the backend's duplicate-update order ever drifted
@@ -19,7 +19,7 @@ import numpy as np
 
 from raft_tla_tpu import Bounds, CheckConfig
 from raft_tla_tpu.ddd_engine import _EMPTY, DDDCapacities, DDDEngine, \
-    _filter_insert
+    _filter_insert_ordered
 from raft_tla_tpu.models import refbfs
 
 import pytest
@@ -27,6 +27,16 @@ import pytest
 pytestmark = pytest.mark.smoke
 
 U32 = jnp.uint32
+
+
+def _filter_insert(tbl_hi, tbl_lo, key_hi, key_lo, active):
+    """The stage with its streamed candidates as a mask in lane order,
+    built here from the compaction order it returns."""
+    tbl_hi, tbl_lo, n_stream, compact, _ = _filter_insert_ordered(
+        tbl_hi, tbl_lo, key_hi, key_lo, active)
+    stream = np.zeros(key_hi.shape[0], bool)
+    stream[np.asarray(compact)[:int(n_stream)]] = True
+    return tbl_hi, tbl_lo, stream
 
 
 def _table_pairs(tbl_hi, tbl_lo):

@@ -15,6 +15,7 @@ import time
 import numpy as np
 import pytest
 
+from frontier_cases import frontier_block
 from raft_tla_tpu.config import Bounds, CheckConfig
 from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
 from raft_tla_tpu.models import interp, refbfs
@@ -779,28 +780,6 @@ def _flavour_caps(flavour, cfg=CFG, **kw):
         else caps
 
 
-def _frontier_block(cfg, depth, n_rows):
-    """The first ``n_rows`` states of BFS level ``depth`` (plain Python,
-    models/interp) as one padded frontier block: packed rows + flags."""
-    seen = {interp.init_state(cfg.bounds)}
-    level = list(seen)
-    for _ in range(depth):
-        nxt = []
-        for s in level:
-            if not interp.constraint_ok(s, cfg.bounds):
-                continue             # kept, never expanded (refbfs)
-            for _a, t in interp.successors(s, cfg.bounds, spec=cfg.spec):
-                if t not in seen:
-                    seen.add(t)
-                    nxt.append(t)
-        level = nxt
-    level = level[:n_rows]
-    assert len(level) == n_rows
-    vecs = np.stack([interp.to_vec(s, cfg.bounds) for s in level])
-    con = np.array([interp.constraint_ok(s, cfg.bounds) for s in level])
-    return vecs, con
-
-
 def _run_segment(eng, vecs, con, budget=1 << 10):
     """One dispatch of the engine's compiled segment over ``vecs`` as one
     block behind an empty filter: host copies of (bufs, stats)."""
@@ -828,7 +807,7 @@ def whole_slab_runs():
     """What ``S >= N`` gives (the shipped _S_OUT dwarfs a toy chunk): the
     toy universe's check() and one deep block through the segment, per
     step flavour."""
-    vecs, con = _frontier_block(CFG, 9, 200)
+    vecs, con = frontier_block(CFG, 9, 200)
     out = {}
     for flavour in _FLAVOURS:
         caps = _flavour_caps(flavour)
@@ -901,7 +880,7 @@ class _PlannedStep:
             self.counts[t] = n
         rng = np.random.default_rng(seed)
         self.rank = rng.permutation(self.N).astype(np.int32)
-        tmpl, _con = _frontier_block(cfg, 6, 40)
+        tmpl, _con = frontier_block(cfg, 6, 40)
         self.tmpl = tmpl.astype(np.int32)
         assert self.tmpl.shape[1] == st.Layout.of(cfg.bounds).width
 

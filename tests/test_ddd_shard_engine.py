@@ -23,6 +23,8 @@ import pytest
 # what tier-1 runs of this engine's d2h.
 slow = pytest.mark.slow
 
+import raft_tla_tpu.ddd_engine as ddd_mod
+from frontier_cases import frontier_block
 from raft_tla_tpu.config import Bounds, CheckConfig
 from raft_tla_tpu.models import interp, refbfs, spec as S
 from raft_tla_tpu.ops import msgbits as mb
@@ -427,6 +429,9 @@ def test_sigint_window_boundary_stop_and_resume(tmp_path):
 # is held to the one-chip ``ddd`` engine on the same spec, the 1-device mesh
 # down to the bytes of its checkpoint.
 
+SLAB = 24       # the "slabs" case's _S_OUT: divides no step's receivable rows
+
+
 def _harvest_caps(case, ndev):
     kw = dict(block=1024 // ndev, table=1 << 14, seg_rows=1 << 14,
               flush=1 << 10, levels=64)
@@ -435,6 +440,12 @@ def _harvest_caps(case, ndev):
         # 5 on (``send`` lowers the 4-device mesh's floor on seg_rows)
         kw.update(seg_rows=1024) if ndev == 1 else \
             kw.update(seg_rows=512, send=64)
+    elif case == "slabs":
+        # buffers that hold little more than one step can deliver (352 rows
+        # on one device, 4 x 64 on four): ``full`` halts a segment as soon
+        # as 48 / 44 rows streamed, so a window takes several
+        kw.update(seg_rows=400) if ndev == 1 else \
+            kw.update(seg_rows=300, send=64)
     elif case == "devdedup":
         kw.update(table=1 << 7)      # the lossy filter leaks: the set drops
     elif case == "frontier":
@@ -481,6 +492,13 @@ def harvest_runs(tmp_path_factory):
             mp.setenv("RAFT_TLA_TRACE", "1")
             mp.setenv("RAFT_TLA_DEVDEDUP",
                       "hash" if case == "devdedup" and ndev else "off")
+            if case == "slabs":
+                # slabs of 24 rows under steps that can receive 352 (one
+                # device) or 256 (four, ``send`` 64): a step streams
+                # several, the buffers carry 8 slack rows, and with the
+                # "whole" case's small buffers a window takes several
+                # segments (the ``full`` halt)
+                mp.setattr(ddd_mod, "_S_OUT", SLAB)
             if ndev:
                 eng = DDDShardEngine(CFG, make_mesh(ndev),
                                      _harvest_caps(case, ndev))
@@ -496,6 +514,8 @@ def harvest_runs(tmp_path_factory):
             "result": res, "engine": eng, "digest": _snapshot_digest(ck),
             "d2h": [e for e in evs if e["event"] == "span"
                     and e["name"] == "d2h"],
+            "levels": [e["args"] for e in evs if e["event"] == "span"
+                       and e["name"] == "level"],
             "dd_hits": max((e.get("dev_dedup_hits") or 0 for e in evs
                             if e["event"] == "segment"), default=0)}
         return made[case, ndev]
@@ -504,11 +524,14 @@ def harvest_runs(tmp_path_factory):
 
 
 @pytest.mark.parametrize("ndev", [1, 4])
-@pytest.mark.parametrize("case", ["head", "whole", "devdedup", "frontier"])
+@pytest.mark.parametrize("case", ["head", "whole", "devdedup", "frontier",
+                                  "slabs"])
 def test_harvest_equals_single_chip(case, ndev, harvest_runs):
     """(i) every harvest takes the head, (ii) some cursors outgrow it and
     those harvests take the whole buffers, (iii) the device-dedup gate on:
-    the head is sliced after the compaction, (iv) frontier retention."""
+    the head is sliced after the compaction, (iv) frontier retention,
+    (v) PR 48: steps that stream several slabs into buffers with slack
+    rows, in windows of several segments."""
     ref = harvest_runs("frontier" if case == "frontier" else "head", 0)
     got = harvest_runs(case, ndev)
     assert got["result"].n_states == ref["result"].n_states == 3014
@@ -521,10 +544,27 @@ def test_harvest_equals_single_chip(case, ndev, harvest_runs):
         assert got["digest"] == ref["digest"]
     # the case took the paths it is there for, and said what crossed
     eng = got["engine"]
-    H, ocap = eng._head_rows, eng.caps.seg_rows
+    H, ocap = eng._head_rows, eng._buf_rows
     row_bytes = eng.schema.P * 4 + 17
     paths = {sp["args"]["path"] for sp in got["d2h"]}
-    assert paths == ({"head", "whole"} if case == "whole" else {"head"})
+    assert paths == ({"head", "whole"} if case in ("whole", "slabs")
+                     else {"head"})
+    # the level spans' slab counts: one slab a lockstep step unless a
+    # shard streamed more than a slab holds in one
+    lv = got["levels"]
+    assert all(a["stream_slabs"] >= a["steps"] for a in lv)
+    assert max(a["stream_peak"] for a in lv) \
+        <= max(a["streamed_rows"] for a in lv)
+    if case == "slabs":
+        assert ocap == eng.caps.seg_rows + 8
+        assert max(a["stream_peak"] for a in lv) > SLAB
+        assert sum(a["stream_slabs"] for a in lv) \
+            > sum(a["steps"] for a in lv)
+        # more harvests with rows than windows: ``full`` cut some window
+        assert len(got["d2h"]) > sum(a["blocks"] for a in lv)
+    else:
+        assert ocap == eng.caps.seg_rows
+        assert [a["stream_slabs"] for a in lv] == [a["steps"] for a in lv]
     for sp in got["d2h"]:
         rows = H if sp["args"]["path"] == "head" else ocap
         assert sp["args"]["bytes"] == ndev * rows * row_bytes
@@ -538,8 +578,274 @@ def test_harvest_path_leaves_the_streams_alone(harvest_runs):
     head give the same checkpoint, byte for byte."""
     base = harvest_runs("head", 4)["digest"]
     assert base["npz"]["n_states"] == 3014
-    for case in ("whole", "devdedup"):
+    for case in ("whole", "devdedup", "slabs"):
         assert harvest_runs(case, 4)["digest"] == base, case
+
+
+# --------------------------- PR 48: the stream stage writes slabs at the cursor
+#
+# The mesh step lays its streamed candidates down as the one-chip step does
+# (``ddd_engine._write_slabs``): gathered in the filter's compaction order, one
+# ``dynamic_update_slice`` a buffer at the shard's cursor.  What may not move
+# is ``[0, cursor)`` of the six buffers and every old field of ``MStats``.
+
+def test_last_slab_of_a_full_step_lands_in_the_slack_rows():
+    """``_write_slabs`` alone against NumPy: a step that streams every row
+    it can receive, from the highest cursor ``full`` lets a step start at,
+    writes its last slab into the buffers' slack rows and nothing is
+    clamped; rows under the cursor are left as they were."""
+    import jax
+    import jax.numpy as jnp
+
+    nk, seg_rows, slab = 100, 160, 24            # 100 = 4 slabs + 4 rows
+    rng = np.random.default_rng(48)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ddd_mod, "_S_OUT", slab)
+        assert ddd_mod._slab_plan(nk) == (slab, 20)
+        cols = (rng.integers(0, 1 << 32, nk, dtype=np.uint32),
+                rng.integers(0, 1 << 31, (nk, 3)).astype(np.int32),
+                rng.random(nk) < 0.5)
+        for n_stream, cursor in ((nk, seg_rows - nk), (nk, 0), (25, 7),
+                                 (24, 7), (0, 5)):
+            compact = rng.permutation(nk).astype(np.int32)
+            old = (np.full(seg_rows + 20, 7, np.uint32),
+                   np.full((seg_rows + 20, 3), 7, np.int32),
+                   np.zeros(seg_rows + 20, bool))
+
+            @jax.jit
+            def run(bufs, cursor, n_stream, compact):
+                return ddd_mod._write_slabs(
+                    bufs, cursor, n_stream, compact, nk,
+                    lambda sel: tuple(jnp.asarray(c)[sel] for c in cols))
+
+            bufs, n_slabs, seen = run(old, jnp.int32(cursor),
+                                      jnp.int32(n_stream),
+                                      jnp.asarray(compact))
+            assert int(n_slabs) == max(-(-n_stream // slab), 1)
+            assert seen == ()
+            for got, was, col in zip(bufs, old, cols):
+                got = np.asarray(got)
+                np.testing.assert_array_equal(got[:cursor], was[:cursor])
+                np.testing.assert_array_equal(
+                    got[cursor:cursor + n_stream],
+                    col[compact[:n_stream]])
+
+
+def _mesh_segment(eng, vecs, con):
+    """One dispatch of a mesh engine's segment over ``vecs`` as one window
+    (dealt block by block, as ``_upload_window`` deals it) behind an empty
+    filter: host copies of (bufs, stats)."""
+    import jax
+    import jax.numpy as jnp
+
+    nd, blk = eng.ndev, eng.caps.block
+    rows = np.zeros((nd * blk, eng.schema.P), np.int32)
+    rows[:len(vecs)] = eng.schema.pack(vecs, np)
+    flags = np.zeros((nd * blk,), bool)
+    flags[:len(vecs)] = con
+    nrows = np.clip(len(vecs) - np.arange(nd) * blk, 0, blk).astype(np.int32)
+    sh = eng._in_shardings
+    _fc, bufs, stats = eng._segment(
+        eng._init_filter(), eng._make_bufs(),
+        jax.device_put(rows, sh[0]), jax.device_put(flags, sh[1]),
+        jax.device_put(np.arange(nd * blk, dtype=np.int32), sh[2]),
+        jax.device_put(nrows, sh[3]), jnp.int32(1 << 10),
+        jnp.int32(-(-int(nrows.max()) // eng.config.chunk)))
+    return jax.device_get(bufs), jax.device_get(stats)
+
+
+_MESH_OLD_STATS = ("cursor", "n_valid", "fail", "viol_pos", "viol_inv",
+                   "dead_g", "steps", "done")
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_segment_streams_equal_whatever_the_slab(ndev, monkeypatch):
+    """One segment over a deep frontier block, slab by slab: every shard's
+    ``[0, cursor)`` of the six buffers and the old ``MStats`` fields are
+    those of the whole-slab program, which on one device are the one-chip
+    engine's ``SegBufs`` / ``SegStats`` byte for byte; the two new counters
+    equal a NumPy replay of the rows each step streamed."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+
+    vecs, con = frontier_block(CFG, 9, 200)
+    caps = DDDShardCapacities(block=256 // ndev, table=1 << 14,
+                              seg_rows=1 << 13, flush=1 << 10, levels=64)
+    want_bufs, want = _mesh_segment(
+        DDDShardEngine(CFG, make_mesh(ndev), caps), vecs, con)
+    monkeypatch.setattr(ddd_mod, "_S_OUT", 7)     # 352 = 50 slabs + 2 rows
+    eng = DDDShardEngine(CFG, make_mesh(ndev), caps)
+    nr = 32 * 11 * ndev
+    assert eng._buf_rows == caps.seg_rows + (-nr % 7) > caps.seg_rows
+    bufs, stats = _mesh_segment(eng, vecs, con)
+    for f in _MESH_OLD_STATS:
+        np.testing.assert_array_equal(getattr(stats, f), getattr(want, f), f)
+    assert int(stats.steps) == -(-min(len(vecs), caps.block) // CFG.chunk)
+    assert np.asarray(stats.cursor).sum() > 0
+    for s in range(ndev):
+        n = int(stats.cursor[s])
+        for f in bufs._fields:
+            got = getattr(bufs, f)[s * eng._buf_rows:][:n]
+            ref = getattr(want_bufs, f)[s * caps.seg_rows:][:n]
+            assert got.dtype == ref.dtype and got.tobytes() == ref.tobytes()
+        # rows a step streamed to this shard, read off the window-relative
+        # parents it carries (shard q expands rows [q*block, ...) in steps
+        # of ``chunk``)
+        par = bufs.opar[s * eng._buf_rows:][:n]
+        per_step = np.bincount((par % caps.block) // CFG.chunk,
+                               minlength=int(stats.steps))
+        assert int(stats.stream_peak[s]) == per_step.max(initial=0)
+        assert int(stats.stream_slabs[s]) \
+            == int(np.maximum(-(-per_step // 7), 1).sum())
+        assert int(want.stream_peak[s]) == per_step.max(initial=0)
+        assert int(want.stream_slabs[s]) == int(stats.steps)
+    assert int(np.max(stats.stream_slabs)) > int(stats.steps)
+    if ndev > 1:
+        return
+    # the 1-device mesh against the one-chip engine's own segment
+    one = DDDEngine(CFG, DDDCapacities(block=256, table=1 << 14,
+                                       seg_rows=1 << 13, flush=1 << 10,
+                                       levels=64))
+    rows = np.zeros((256, one.schema.P), np.int32)
+    rows[:len(vecs)] = one.schema.pack(vecs, np)
+    flags = np.zeros((256,), bool)
+    flags[:len(vecs)] = con
+    _fc, obufs, ostats = jax.device_get(one._segment(
+        one._init_filter(), one._make_bufs(), jnp.asarray(rows),
+        jnp.asarray(flags), jnp.int32(1 << 10), jnp.int32(len(vecs))))
+    n = int(ostats.cursor)
+    assert n == int(stats.cursor[0])
+    for f in ("n_valid", "fail", "steps", "stream_peak", "stream_slabs"):
+        assert int(np.asarray(getattr(ostats, f))) \
+            == int(np.asarray(getattr(stats, f)).reshape(-1)[0]), f
+    for f in bufs._fields:
+        got, ref = getattr(bufs, f)[:n], getattr(obufs, f)[:n]
+        assert got.dtype == ref.dtype and got.tobytes() == ref.tobytes(), f
+
+
+@pytest.mark.parametrize("ndev", [4, 8])
+def test_violation_in_a_later_slab(ndev, monkeypatch):
+    """The first violating streamed candidate is found in the slab that
+    holds it: with slabs of three rows it lies in the second slab or later
+    of its step (one step a segment, so ``viol_pos`` is the offset into
+    that step's stream), ``viol_pos`` is the same buffer slot as with one
+    slab a step, and the same trace replays."""
+    import jax
+
+    def run(slab):
+        if slab:
+            monkeypatch.setattr(ddd_mod, "_S_OUT", slab)
+        eng = DDDShardEngine(VIOL_CFG, make_mesh(ndev), VIOL_CAPS,
+                             seg_chunks=1)
+        eng.SEG_MIN = eng.SEG_MAX = 1           # a segment is one step
+        hits, seg = [], eng._segment
+
+        def recording(*a):
+            out = seg(*a)
+            st = jax.device_get(out[2])
+            if (np.asarray(st.viol_pos) >= 0).any():
+                hits.append((np.asarray(st.viol_pos).tolist(),
+                             np.asarray(st.viol_inv).tolist(),
+                             np.asarray(st.cursor).tolist(),
+                             np.asarray(st.stream_slabs).tolist()))
+            return out
+
+        eng._segment = recording
+        return eng.check(init_override=VIOL_START), hits, eng
+
+    whole, whole_hits, _ = run(None)
+    got, hits, eng = run(3)
+    # a step can deliver ndev x 64 x 20 rows: 5,120 / 10,240, not whole slabs
+    assert eng._buf_rows == VIOL_CAPS.seg_rows + (-(ndev * 1280) % 3) \
+        > VIOL_CAPS.seg_rows
+    assert_replayable_violation(got)
+    assert got.violation.trace == whole.violation.trace
+    assert (got.n_states, got.levels, got.n_transitions) \
+        == (whole.n_states, whole.levels, whole.n_transitions)
+    assert len(hits) == 1
+    vpos, vinv, cursors, slabs = hits[0]
+    assert (vpos, vinv, cursors) == whole_hits[0][:3]
+    shard = int(np.argmax(np.asarray(vpos) >= 0))
+    assert 3 <= vpos[shard] < cursors[shard]
+    assert slabs[shard] == -(-cursors[shard] // 3) >= 2
+    assert whole_hits[0][3] == [1] * ndev
+
+
+def test_cp_mode_streams_slabs(tmp_path, monkeypatch):
+    """CP mode (every shard expands the same rows over its lane slice) runs
+    the same ``stream`` stage: oracle-exact totals with slabs of five rows
+    under steps that receive up to 4 x 32 x ``cp_lane_count``, and the same
+    run as with one slab a step."""
+    import json
+
+    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
+    caps = DDDShardCapacities(block=256, table=1 << 12, seg_rows=1 << 12,
+                              flush=1 << 10, levels=64, cp=True)
+
+    def run(name):
+        log = str(tmp_path / name)
+        got = DDDShardEngine(CFG, make_mesh(4), caps).check(events=log)
+        with open(log) as f:
+            evs = [json.loads(line) for line in f]
+        return got, [e["args"] for e in evs
+                     if e["event"] == "span" and e["name"] == "level"]
+
+    ref = refbfs.check(CFG)
+    whole, whole_lv = run("whole.events")
+    monkeypatch.setattr(ddd_mod, "_S_OUT", 5)
+    got, lv = run("slabs.events")
+    assert_totals(got, ref)
+    assert got.coverage == whole.coverage
+    assert [a["stream_slabs"] for a in whole_lv] \
+        == [a["steps"] for a in whole_lv]
+    assert sum(a["stream_slabs"] for a in lv) > sum(a["steps"] for a in lv)
+    for key in ("steps", "streamed_rows", "new_states", "stream_peak"):
+        assert [a[key] for a in lv] == [a[key] for a in whole_lv], key
+
+
+def _scatters(jaxpr, stack=""):
+    """``(scope path, updates shape)`` of every scatter equation of a jaxpr,
+    sub-jaxprs included (an inner equation's name stack is relative to the
+    equation that holds it)."""
+    import jax
+
+    out = []
+    for eqn in jaxpr.eqns:
+        path = stack + "/" + str(eqn.source_info.name_stack)
+        if eqn.primitive.name.startswith("scatter"):
+            out.append((path, eqn.invars[2].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_scatters(sub, path))
+    return out
+
+
+def test_mesh_segment_scatters_nothing_in_stream(monkeypatch):
+    """The traced mesh segment: no scatter under the ``stream`` scope (the
+    parent had six over every received lane), and none under
+    ``filter_insert`` wider than the insert budget (the parent turned
+    ``compact`` back into a lane-order mask with one N-wide scatter)."""
+    import jax
+
+    monkeypatch.setattr(ddd_mod, "_S_INS", 16)
+    eng = DDDShardEngine(CFG, make_mesh(4), CAPS)
+    sh = eng._in_shardings
+    nd, blk = eng.ndev, eng.caps.block
+    S = jax.ShapeDtypeStruct
+    i32 = S((), np.int32)
+    closed = jax.make_jaxpr(eng._segment)(
+        jax.eval_shape(eng._init_filter), jax.eval_shape(eng._make_bufs),
+        S((nd * blk, eng.schema.P), np.int32, sharding=sh[0]),
+        S((nd * blk,), np.bool_, sharding=sh[1]),
+        S((nd * blk,), np.int32, sharding=sh[2]),
+        S((nd,), np.int32, sharding=sh[3]), i32, i32)
+    found = _scatters(closed.jaxpr)
+    scoped = [(p.split("/"), shape) for p, shape in found]
+    assert any("exchange" in p for p, _ in scoped)      # the walk sees scopes
+    assert not [p for p, _ in scoped if "stream" in p]
+    in_filter = [shape for p, shape in scoped if "filter_insert" in p]
+    assert in_filter and all(shape[0] <= 16 for shape in in_filter)
 
 
 def test_ledger_d2h_bytes_is_the_heads(harvest_runs):

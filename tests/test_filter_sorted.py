@@ -19,8 +19,7 @@ import numpy as np
 import pytest
 
 import raft_tla_tpu.ddd_engine as ddd_mod
-from raft_tla_tpu.ddd_engine import _EMPTY, _filter_insert, \
-    _filter_insert_ordered
+from raft_tla_tpu.ddd_engine import _EMPTY, _filter_insert_ordered
 
 pytestmark = pytest.mark.smoke
 
@@ -28,6 +27,19 @@ I32 = jnp.int32
 U32 = jnp.uint32
 T = 64                    # the tile under test (the shipped one is 2^14)
 S_INS = 96                # the insert budget under test: some cases pass it
+
+
+def _lane_mask(tbl_hi, tbl_lo, key_hi, key_lo, active):
+    """The streamed candidates as a mask in lane order, built from the
+    compaction order (the wrapper ``ddd_engine._filter_insert`` did this for
+    the mesh step until PR 48 gave that step the compaction order
+    itself)."""
+    BA = key_hi.shape[0]
+    tbl_hi, tbl_lo, n_stream, compact, _ = _filter_insert_ordered(
+        tbl_hi, tbl_lo, key_hi, key_lo, active)
+    lane = jnp.where(jnp.arange(BA, dtype=I32) < n_stream, compact, BA)
+    return tbl_hi, tbl_lo, \
+        jnp.zeros((BA,), bool).at[lane].set(True, mode="drop")
 
 
 def _reference(tbl_hi, tbl_lo, key_hi, key_lo, active, s_ins):
@@ -131,7 +143,7 @@ def test_sorted_space_filter_equals_the_lane_order_filter(case, monkeypatch):
     rng = np.random.default_rng(sorted(CASES).index(case))
     # fresh callables: a trace made under another tile is never reused
     new = jax.jit(lambda *a: _filter_insert_ordered(*a))
-    mask = jax.jit(lambda *a: _filter_insert(*a))
+    mask = jax.jit(lambda *a: _lane_mask(*a))
     ref = jax.jit(functools.partial(_reference, s_ins=S_INS))
 
     # a table a third full to start from: both sides get the same one

@@ -77,6 +77,7 @@ def two_passes(request, tmp_path_factory):
     evs = [json.loads(line) for line in open(log)]
     return {"kind": request.param, "plain": plain, "traced": traced,
             "snapshot_hits": fresh, "n_new": n_new, "events": evs,
+            "log": log,
             "levels": sorted((e for e in evs if e["event"] == "span"
                               and e["name"] == "level"),
                              key=lambda e: e["t0"])}
@@ -160,6 +161,32 @@ def test_traced_pass_leaves_the_same_record_from_the_same_sites(two_passes):
     end = two_passes["events"][-1]
     assert end["event"] == "run_end" and validate_event(end) == []
     assert end["level_log"] == passlog.rounded(rec)
+
+
+def test_level_spans_carry_the_slab_counts_on_both_engines(two_passes):
+    """``stream_slabs`` / ``stream_peak`` on every ``level`` span (ISSUE 48
+    gave the mesh step the one-chip step's slab writes and with them the
+    counts: the most any shard wrote, summed over the level's segments, and
+    the most rows any shard streamed in one step).  At a toy's size a step
+    streams less than a slab: one slab a step.  The report's ``L<k>`` rows
+    print them; the ledger's entries keep to their own fields."""
+    from raft_tla_tpu.obs import collect
+
+    spans = two_passes["levels"]
+    assert spans
+    for sp in spans:
+        a = sp["args"]
+        assert a["stream_slabs"] == a["steps"]
+        assert 0 <= a["stream_peak"] <= a["streamed_rows"]
+        assert (a["stream_peak"] > 0) == (a["streamed_rows"] > 0)
+    assert max(sp["args"]["stream_peak"] for sp in spans) > 0
+    assert not {"stream_slabs", "stream_peak"} \
+        & set(two_passes["traced"].level_log["levels"][0])
+    rep = collect.report(collect.collect([two_passes["log"]]))
+    rows = rep["processes"][0]["levels"]
+    assert [(r["stream_slabs"], r["stream_peak"]) for r in rows] \
+        == [(sp["args"]["stream_slabs"], sp["args"]["stream_peak"])
+            for sp in spans]
 
 
 def test_upload_bytes_and_pieces_are_the_sums_of_the_upload_spans(two_passes):
