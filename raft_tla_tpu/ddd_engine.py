@@ -762,6 +762,15 @@ def _place_piece(fbuf, fcon, rows, con, at):
             jax.lax.dynamic_update_slice(fcon, con, (at,)))
 
 
+def _slab_trips(n, nk: int):
+    """Slabs that ``_write_slabs`` writes for ``n`` observed rows of a
+    step that can hold ``nk``: one for nearly every step (an empty step
+    still writes one, of garbage above the cursor), more only past a
+    slab's rows."""
+    SLAB = _slab_plan(nk)[0]
+    return jnp.maximum((n + SLAB - 1) // SLAB, 1)
+
+
 def _write_slabs(bufs, cursor, n_stream, compact, nk, gather,
                  watch=None, seen=()):
     """The ``stream`` stage's writes, for a chunk of a one-chip segment and
@@ -796,10 +805,8 @@ def _write_slabs(bufs, cursor, n_stream, compact, nk, gather,
                 b, v, (at,) + (0,) * (b.ndim - 1))
             for b, v in zip(bufs, slab)), seen
 
-    # the trip count is the observed count: one slab for nearly every
-    # step (an empty step still writes one, of garbage above the
-    # cursor), more only past SLAB streamed rows
-    n_slabs = jnp.maximum((n_stream + SLAB - 1) // SLAB, 1)
+    # the trip count is the observed count
+    n_slabs = _slab_trips(n_stream, nk)
     bufs, seen = jax.lax.fori_loop(0, n_slabs, write_slab,
                                    (tuple(bufs), seen))
     return bufs, n_slabs, seen

@@ -171,7 +171,13 @@ chunk steps unless a chunk streamed more than one slab holds).  The
 ``level`` span the level's; the mesh engine's ``level`` span carries them
 too (its step writes the same slabs, per shard): the most slabs any shard
 wrote, summed over the level's segments, and the most rows any shard
-streamed in one lockstep step.
+streamed in one lockstep step.  Beside them the mesh ``level`` span carries
+what its exchange packed (PR 49; parallel/mesh.exchange gathers its send
+blocks slab by slab): ``route_peak``, the most live lanes any shard packed
+in one exchange, and ``exchange_slabs``, the trips of that gather loop —
+the most of any shard a segment, both stages of a 2-D mesh counted, summed
+over the level's segments.  Span ``args`` are not enumerated by the schema,
+so no version moved.
 
 Version 13 adds the tile counter of the ddd filter probe (ddd_engine
 ``SegStats.probe_tiles``): segment ``probe_tiles`` (cumulative tiles of

@@ -75,7 +75,8 @@ from raft_tla_tpu.device_engine import (
     _EMPTY, BUCKET, FAIL_INDEX, FAIL_LEVEL, FAIL_ROUTE, FAIL_WIDTH,
     aggregate_coverage, decode_fail)
 from raft_tla_tpu.ddd_engine import (
-    _filter_insert_ordered, _IDX_CEIL, _slab_plan, _write_slabs,
+    _filter_insert_ordered, _IDX_CEIL, _slab_plan, _slab_trips,
+    _write_slabs,
     frontier_backtrace,
     frontier_checkpoint_setup, load_ddd_snapshot,
     load_frontier_snapshot, save_ddd_snapshot, save_frontier_snapshot)
@@ -112,7 +113,9 @@ class DDDShardCapacities:
     only past it);
     ``flush``: per-shard pending candidates per host dedup pass;
     ``send``: per-destination exchange depth per chunk (None = the safe
-    bound ``chunk * A``; smaller trades memory for a loud FAIL_ROUTE);
+    bound ``chunk * A``; smaller trades memory for a loud FAIL_ROUTE: a
+    block is ``send`` rows copied and sent whatever it holds, and a slab of
+    gathers per 2^14 live lanes of the step beside it, ``mesh.exchange``);
     ``send2``: stage-B depth on 2-D meshes (None = ``nici * send``)."""
 
     block: int = 1 << 18
@@ -201,6 +204,10 @@ class MStats(NamedTuple):
     stream_slabs: jax.Array  # [dev] [1] slab writes; == steps unless a
                              #   step streamed more than one slab here
     stream_peak: jax.Array   # [dev] [1] most rows one step streamed here
+    route_peak: jax.Array    # [dev] [1] most live lanes one exchange
+                             #   packed here
+    exchange_slabs: jax.Array  # [dev] [1] trips of the exchanges' gather
+                               #   loops (both stages on a 2-D mesh)
     steps: jax.Array      # replicated: chunks executed (pacer signal)
     done: jax.Array       # replicated: window exhausted (reading it off
                           # stats keeps the host from syncing on the
@@ -224,13 +231,16 @@ class _MCarry(NamedTuple):
     dead_g: jax.Array
     stream_slabs: jax.Array
     stream_peak: jax.Array
+    route_peak: jax.Array
+    exchange_slabs: jax.Array
     c: jax.Array          # replicated
     halt: jax.Array       # replicated: stop event or buffers full
 
 
 _SHARDED = ("tbl_hi", "tbl_lo", "okey_hi", "okey_lo", "orows", "opar",
             "olane", "ocon", "cursor", "n_valid", "fail", "viol_pos",
-            "viol_inv", "dead_g", "stream_slabs", "stream_peak")
+            "viol_inv", "dead_g", "stream_slabs", "stream_peak", "route_peak",
+            "exchange_slabs")
 
 
 def _carry_specs(axes):
@@ -309,7 +319,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
         def chunk_body(carry: _MCarry) -> _MCarry:
             (tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar, olane, ocon,
              cursor, n_valid, fail, viol_pos, viol_inv, dead_g,
-             stream_slabs, stream_peak, c, halt) = carry
+             stream_slabs, stream_peak, route_peak, exchange_slabs, c,
+             halt) = carry
             cur, nva, fa = cursor[0], n_valid[0], fail[0]
             vpos, vinv, dg = viol_pos[0], viol_inv[0], dead_g[0]
 
@@ -378,6 +389,11 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                          (flo, _EMPTY, U32), (par_g, -1, I32),
                          (lane_a, -1, I32), (flags, 0, I32)))
             fa = fa | ovf.astype(I32) * FAIL_ROUTE
+            # what the exchange packed: its live lanes and its gather's
+            # trips (mesh.exchange's return keeps its shape: counted here)
+            n_live = jnp.sum((dest_a < nici).astype(I32))
+            rpeak = jnp.maximum(route_peak[0], n_live)
+            xslabs = exchange_slabs[0] + _slab_trips(n_live, BA)
             active = (r_flags & 1) == 1
             if nslice > 1:
                 dest_b = jnp.where(active, owner(r_hi) // nici, nslice)
@@ -389,6 +405,9 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                              (r_lo, _EMPTY, U32), (r_par, -1, I32),
                              (r_lane, -1, I32), (r_flags, 0, I32)))
                 fa = fa | ovf2.astype(I32) * FAIL_ROUTE
+                n_live = jnp.sum((dest_b < nslice).astype(I32))
+                rpeak = jnp.maximum(rpeak, n_live)
+                xslabs = xslabs + _slab_trips(n_live, nici * Csend)
                 active = (r_flags & 1) == 1
 
             # ---- owner-side lossy filter; stream to my buffer ----
@@ -444,7 +463,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
             return _MCarry(tbl_hi, tbl_lo, okey_hi, okey_lo, orows, opar,
                            olane, ocon, cur[None], nva[None], fa[None],
                            vpos[None], vinv[None], dg[None], slabs[None],
-                           peak[None], c + 1, stop_ev | full)
+                           peak[None], rpeak[None], xslabs[None], c + 1,
+                           stop_ev | full)
 
         def cond(sc):
             s, carry = sc
@@ -459,7 +479,8 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
             fc.tbl_hi, fc.tbl_lo, *bufs,
             cursor=z1, n_valid=z1, fail=z1,
             viol_pos=z1 - 1, viol_inv=z1, dead_g=z1 - 1,
-            stream_slabs=z1, stream_peak=z1,
+            stream_slabs=z1, stream_peak=z1, route_peak=z1,
+            exchange_slabs=z1,
             c=fc.c, halt=jnp.bool_(False))
         steps, carry = jax.lax.while_loop(cond, body,
                                           (jnp.int32(0), carry))
@@ -469,6 +490,7 @@ def _build_segment(config: CheckConfig, caps: DDDShardCapacities, A: int,
                 MStats(carry.cursor, carry.n_valid, carry.fail,
                        carry.viol_pos, carry.viol_inv, carry.dead_g,
                        carry.stream_slabs, carry.stream_peak,
+                       carry.route_peak, carry.exchange_slabs,
                        steps, carry.c >= n_chunks))
 
     return segment
@@ -1021,13 +1043,14 @@ class DDDShardEngine:
         lvl_segs = lvl_steps = lvl_rows = 0  # the open level's work
         # the stream stage's slab writes (MStats): the most any shard
         # wrote, summed over the level's segments, and the most rows any
-        # shard streamed in one step
-        lvl_slabs = lvl_peak = 0
+        # shard streamed in one step; and the exchange's two likewise
+        lvl_slabs = lvl_peak = lvl_xslabs = lvl_route = 0
 
         def end_level():
             level_sp.set(segments=lvl_segs, steps=lvl_steps,
                          streamed_rows=lvl_rows,
                          stream_slabs=lvl_slabs, stream_peak=lvl_peak,
+                         exchange_slabs=lvl_xslabs, route_peak=lvl_route,
                          new_states=n_states - lvl_hi).close()
 
         while not stopped:
@@ -1040,6 +1063,7 @@ class DDDShardEngine:
                                rows=lvl_hi - lvl_lo,
                                blocks=-(-(lvl_hi - w0) // W))
             lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
+            lvl_xslabs = lvl_route = 0
             if prefetcher is not None and w0 < lvl_hi:
                 # level start: all window addresses are known — warm the
                 # first window immediately
@@ -1134,6 +1158,9 @@ class DDDShardEngine:
                         lvl_slabs += int(np.max(st_h.stream_slabs))
                         lvl_peak = max(lvl_peak,
                                        int(np.max(st_h.stream_peak)))
+                        lvl_xslabs += int(np.max(st_h.exchange_slabs))
+                        lvl_route = max(lvl_route,
+                                        int(np.max(st_h.route_peak)))
                         bufs_h = None
                         # ``stride``: rows a shard of the fetched arrays
                         src, stride, path = (head, H, "head") \

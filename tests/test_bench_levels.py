@@ -397,3 +397,49 @@ def test_manifest_gains_the_two_stream_readers_for_the_mesh_cell_alone():
                             if m["name"] == "stage_stream_ms")["workloads"]
     for name in ("stage_mesh_stream_ms", "mesh_slabs_per_step"):
         assert os.path.isfile(os.path.join(mf.BENCH, "metrics", name + ".py"))
+
+
+# ---------------------- PR 49: the mesh exchange's gather loop, counted by
+# the program (``exchange_slabs`` on the mesh engine's ``level`` spans)
+
+def test_exchange_slabs_per_step_is_the_level_spans_trips_over_steps(
+        tmp_path):
+    """Over the whole traced pass's ``level`` spans: (1 + 6 + 15) trips of
+    the exchange's gather loop over (1 + 6 + 6) lockstep steps."""
+    read = mf.metric_reader("exchange_slabs_per_step")
+    ev = _levels_log(tmp_path, [
+        dict(steps=1, exchange_slabs=1, route_peak=2, stream_slabs=1),
+        dict(steps=6, exchange_slabs=6, route_peak=11000, stream_slabs=6),
+        dict(steps=6, exchange_slabs=15, route_peak=40000, stream_slabs=6)])
+    assert read(ev) == pytest.approx(22 / 13)
+    # a level of a program that counts no trips adds neither sum
+    assert read(_levels_log(tmp_path, [
+        dict(steps=4, exchange_slabs=6), dict(steps=9, stream_slabs=9)
+    ])) == pytest.approx(6 / 4)
+    # the parent's mesh spans and a one-chip program's count steps and
+    # slabs of the stream, no trips of an exchange: nothing to read
+    assert read(_levels_log(tmp_path, [
+        dict(steps=1, stream_slabs=1), dict(steps=6, stream_slabs=6)
+    ])) is None
+    assert read(_levels_log(tmp_path, [])) is None      # no level span
+    assert read({"passes": []}) is None                 # an untraced run
+
+
+def test_manifest_gains_the_exchange_reader_for_the_mesh_cell_alone():
+    manifest = mf.load()
+    assert mf.problems(manifest) == []
+    # the 71 entries PR 48 left, then this one, beside the stage it counts
+    assert manifest["per_layer"][71:] == [
+        {"name": "exchange_slabs_per_step", "unit": "slabs/step",
+         "better": "lower", "source": "program_span",
+         "layer": "mesh exchange", "moves": "orbits_per_s",
+         "workloads": [MESH]}]
+    assert next(m for m in manifest["per_layer"]
+                if m["name"] == "stage_exchange_ms")["layer"] \
+        == "mesh exchange"
+    for w in manifest["workloads"]:
+        listed = "exchange_slabs_per_step" in mf.metric_names(
+            manifest, w["name"], "per_layer")
+        assert listed == (w["name"] == MESH)
+    assert os.path.isfile(os.path.join(
+        mf.BENCH, "metrics", "exchange_slabs_per_step.py"))

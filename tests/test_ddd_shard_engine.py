@@ -822,10 +822,11 @@ def _scatters(jaxpr, stack=""):
 
 
 def test_mesh_segment_scatters_nothing_in_stream(monkeypatch):
-    """The traced mesh segment: no scatter under the ``stream`` scope (the
-    parent had six over every received lane), and none under
-    ``filter_insert`` wider than the insert budget (the parent turned
-    ``compact`` back into a lane-order mask with one N-wide scatter)."""
+    """The traced mesh segment: no scatter under the ``stream`` scope (PR
+    47's program had six over every received lane), none under ``exchange``
+    (PR 48's had six over every lane of the chunk: the send blocks are
+    gathered slabs now), and none under ``filter_insert`` wider than the
+    insert budget (where the walk is seen to read scopes)."""
     import jax
 
     monkeypatch.setattr(ddd_mod, "_S_INS", 16)
@@ -842,10 +843,121 @@ def test_mesh_segment_scatters_nothing_in_stream(monkeypatch):
         S((nd,), np.int32, sharding=sh[3]), i32, i32)
     found = _scatters(closed.jaxpr)
     scoped = [(p.split("/"), shape) for p, shape in found]
-    assert any("exchange" in p for p, _ in scoped)      # the walk sees scopes
-    assert not [p for p, _ in scoped if "stream" in p]
+    assert not [p for p, _ in scoped if "stream" in p or "exchange" in p]
     in_filter = [shape for p, shape in scoped if "filter_insert" in p]
     assert in_filter and all(shape[0] <= 16 for shape in in_filter)
+
+
+def test_level_spans_count_what_the_exchange_packed(tmp_path, monkeypatch):
+    """PR 49: a 4-device run's ``level`` spans carry ``route_peak`` (the
+    most live lanes one shard's exchange packed in a step) and
+    ``exchange_slabs`` (the trips of its gather loop, the most of any shard
+    a segment, summed), and both are what a plain count of each window's
+    destinations gives: a lane is addressed to an owner exactly when the
+    interpreter finds its action enabled on a row the constraint admits."""
+    import json
+
+    import jax
+
+    from raft_tla_tpu.ops import state as st
+
+    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
+    monkeypatch.setattr(ddd_mod, "_S_OUT", 5)     # 352 lanes: 70 slabs + 2
+    log = str(tmp_path / "run.events")
+    eng = DDDShardEngine(CFG, make_mesh(4), _harvest_caps("head", 4))
+    B, blk, seg = CFG.chunk, eng.caps.block, eng._segment
+    enabled = {}                                  # packed row -> live lanes
+
+    def live_lanes(row):
+        if row.tobytes() not in enabled:
+            s = interp.from_struct(
+                st.unpack(eng.schema.unpack(row[None], np)[0], eng.lay, np),
+                CFG.bounds)
+            enabled[row.tobytes()] = sum(
+                1 for _ in interp.successors(s, CFG.bounds, spec=CFG.spec))
+        return enabled[row.tobytes()]
+
+    counted = []                # a segment: (route_peak, exchange_slabs)
+
+    def recording(fc, bufs, fbuf, fcon, fpar, nrows, budget, n_chunks):
+        c0 = int(fc.c)                            # read before the donation
+        rows, con, nr = (np.asarray(jax.device_get(a))
+                         for a in (fbuf, fcon, nrows))
+        out = seg(fc, bufs, fbuf, fcon, fpar, nrows, budget, n_chunks)
+        stats = jax.device_get(out[2])
+        peak, trips = [], []
+        for q in range(4):
+            live = [sum(live_lanes(rows[q * blk + r])
+                        for r in range(k * B, min((k + 1) * B, int(nr[q])))
+                        if con[q * blk + r])
+                    for k in range(c0, c0 + int(stats.steps))]
+            peak.append(max(live, default=0))
+            trips.append(sum(max(-(-n // 5), 1) for n in live))
+        assert np.asarray(stats.route_peak).tolist() == peak
+        assert np.asarray(stats.exchange_slabs).tolist() == trips
+        counted.append((max(peak), max(trips)))
+        return out
+
+    eng._segment = recording
+    res = eng.check(events=log)
+    assert (res.n_states, res.n_transitions) == (3014, 5274)
+    with open(log) as f:
+        levels = [e["args"] for e in map(json.loads, f)
+                  if e["event"] == "span" and e["name"] == "level"]
+    assert sum(a["segments"] for a in levels) == len(counted)
+    for a in levels:
+        mine, counted = counted[:a["segments"]], counted[a["segments"]:]
+        assert a["route_peak"] == max((p for p, _ in mine), default=0)
+        assert a["exchange_slabs"] == sum(t for _, t in mine)
+    # the run packed more than a slab in a step, and less in others
+    assert max(a["route_peak"] for a in levels) > 5
+    assert sum(a["exchange_slabs"] for a in levels) \
+        > sum(a["steps"] for a in levels) > 0
+
+
+# What the parent of PR 49 (2e397bc: the exchange's six scatters) wrote for
+# the same 4-device runs — ``_snapshot_digest`` there: the level table and
+# every stream's (bytes, sha256).  ``.keys`` is the discovery order itself.
+_PARENT_KEYS = (
+    24128, "ffa92ec19d39cbdf503617cb9d7728a489743f88adf213a0c4f4b0d6ac2bd0e7")
+_PARENT_STREAMS = {
+    "frontier": {
+        ".keys": _PARENT_KEYS,
+        ".conL18": (112, "73a098f974bf66e7968a8fc0f40adc94"
+                         "ea6b729a89ca0cc963e81585cfbac9d9"),
+        ".conL19": (16, "9d34149fbd1fe777eb238799054c8cbf"
+                        "bce372255f219f8740838def9bfd02db"),
+        ".rowsL18": (592, "9c64fbf88e8a36ac6bb7f4f5228d45c0"
+                          "d01326fc5d15a01ae58b605dc778bb07"),
+        ".rowsL19": (16, "931c13477d08fd5ad0741bd095218457"
+                         "ea8aaf60f175328f6532bcb49c1a49cd")},
+    "head": {
+        ".keys": _PARENT_KEYS,
+        ".con": (12072, "752a33784be92d0fd61e9eac2800bfc1"
+                        "744230f299433669e6b1f562cb4b4229"),
+        ".links": (36184, "1ddcc382cb67448d1af4145fe2d38c28"
+                          "bbf2cf13873fcfaa95624f4fa61c88a2"),
+        ".rows": (72352, "932851b8f04e8e4e5bc6953289729bbd"
+                         "d475b7a7f9f6988203bb898098abd87c")}}
+_PARENT_LEVELS = [1, 2, 7, 20, 44, 88, 140, 156, 220, 384, 306, 294, 472, 340,
+                  194, 210, 112, 24]
+
+
+@pytest.mark.parametrize("case", ["frontier", "head"])
+def test_four_device_streams_are_the_parents(case, harvest_runs):
+    """The exchange delivers what it delivered, so a 4-device run discovers
+    what it discovered in the order it did: the level counts and every
+    checkpoint stream — frontier retention's level files and the full
+    retention's rows, links and constraint flags — are byte for byte what
+    the parent's tree wrote (the cases of one tree are held to each other
+    above; this holds them across the change of the exchange)."""
+    got = harvest_runs(case, 4)
+    assert list(got["result"].levels) == _PARENT_LEVELS
+    digest = dict(got["digest"])
+    npz = digest.pop("npz")
+    assert (npz["n_states"], npz["n_trans"]) == (3014, 5274)
+    assert npz["level_ends"] == np.cumsum(_PARENT_LEVELS).tolist()
+    assert {k: tuple(v) for k, v in digest.items()} == _PARENT_STREAMS[case]
 
 
 def test_ledger_d2h_bytes_is_the_heads(harvest_runs):
