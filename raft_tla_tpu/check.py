@@ -879,6 +879,13 @@ def main(argv=None) -> int:
     print(f"{result.n_states} distinct states found, diameter "
           f"{result.diameter}, {result.n_transitions} transitions, "
           f"{wall:.2f}s ({result.n_states / max(wall, 1e-9):,.0f} states/s).")
+    ledger = getattr(result, "level_log", None) or {}
+    if "elections_peak" in ledger:
+        # faithful mode on a ddd engine: the pass ledger's two counts
+        words = {lv["row_words"] for lv in ledger["levels"]}
+        print(f"History: packed rows of {'/'.join(map(str, sorted(words)))} "
+              f"words; elections peak {ledger['elections_peak']} of "
+              f"{config.bounds.max_elections} slots.")
     if args.coverage:
         for fam, cnt in sorted(result.coverage.items()):
             print(f"  {fam}: {cnt} new states")

@@ -1203,6 +1203,11 @@ class DDDEngine:
         self._digest_caps = _DigestCaps(block=self.caps.block,
                                         levels=self.caps.levels)
         self.schema = self.model.bit_schema(self.bounds)
+        # faithful mode: where the ``elections`` slots' eTerm words lie in
+        # the flat row, for the pass ledger's ``elections_peak``
+        self._eterm0 = self.lay.offset("eTerm") \
+            if getattr(self.lay, "history", False) else None
+        self._epeak = 0
         # RAFT_TLA_HOSTDEDUP gate: partitioned master keys + background
         # flush worker.  Resolved once at construction (like the
         # prescan gate) and deliberately NOT part of
@@ -1303,6 +1308,12 @@ class DDDEngine:
         n_new = int(new_idx.size)
         if n_new:
             rows = np.concatenate(pend["rows"])[new_idx]
+            if self._eterm0 is not None:
+                # occupied slots sort first (ops/state.canonicalize): the
+                # peak moves when a row fills the slot after it
+                while self._epeak < self.lay.E and self.schema.unpack_word(
+                        rows, self._eterm0 + self._epeak, np).any():
+                    self._epeak += 1
             lane = np.concatenate(pend["lane"])[new_idx]
             con = np.concatenate(pend["con"])[new_idx]
             host.append(rows)
@@ -1403,6 +1414,7 @@ class DDDEngine:
         tr = tel.trace
         pass_sp = tr.open("pass", engine="ddd", resumed=resume is not None,
                           prescan=self._prescan)
+        self._epeak = 0     # (a resumed pass counts what it admits itself)
         _cleanup.callback(pass_sp.close)     # raise paths; idempotent
         bounds, model = self.bounds, self.model
         init_py = init_override if init_override is not None \
@@ -1680,6 +1692,7 @@ class DDDEngine:
             # end_level(), here or after the loop (close is idempotent)
             level_sp = tr.open("level", level=len(level_ends),
                                rows=lvl_hi - lvl_lo,
+                               row_words=self.schema.P,
                                blocks=-(-(lvl_hi - b0) // Fcap))
             lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
             lvl_valid = lvl_route = lvl_tiles = 0
@@ -1825,6 +1838,7 @@ class DDDEngine:
                                 time.monotonic() - t_disp,
                                 thread="segments", level=len(level_ends),
                                 block=(b_start - lvl_lo) // Fcap,
+                                row_words=self.schema.P,
                                 budget=seg_budget, steps=n_steps,
                                 lanes=n_steps * N, group=self._group,
                                 images=self._group * n_steps * N,
@@ -2097,6 +2111,8 @@ class DDDEngine:
             host.close()
             constore.close()
             keystore.close()
+        if self._eterm0 is not None:
+            pass_sp.set(elections_peak=self._epeak)
         pass_sp.set(levels=len(levels_arr), n_states=n_states,
                     stopped_by="violation" if violation is not None
                     else stopped_by).close()

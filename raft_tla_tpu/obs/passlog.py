@@ -25,9 +25,10 @@ call -> the first level opens (a resumed pass: the resume), ``tail_s`` the
 last level's close -> the ``pass`` span closes.  One entry a level, in the
 names the ``level`` span uses::
 
-    {"level", "t0", "gap_s", "wall_s", "rows", "blocks", "segments", "steps",
-     "streamed_rows", "new_states", "upload_s", "uploads", "upload_bytes",
-     "upload_pieces", "d2h_bytes", "expand_s", "wait_s", "d2h_s", "dedup_s",
+    {"level", "t0", "gap_s", "wall_s", "rows", "row_words", "blocks",
+     "segments", "steps", "streamed_rows", "new_states", "upload_s",
+     "uploads", "upload_bytes", "upload_pieces", "d2h_bytes", "expand_s",
+     "wait_s", "d2h_s", "dedup_s",
      "close_s", "cpu_s", "gc_s", "majflt", "nivcsw"}
 
 ``wall_s`` is the ``level`` span's own ``dur``; ``gap_s`` is the previous
@@ -94,8 +95,8 @@ SEAM_FIELDS = tuple(dict.fromkeys(SEAMS.values()))
 # every span name the ledger reads: the seams, the two explicit handles and
 # the prefetcher's stage (a worker's: it lands in ``threads``)
 NAMES = frozenset(SEAMS) | {"pass", "level", "prefetch"}
-_COUNTS = ("level", "rows", "blocks", "segments", "steps", "streamed_rows",
-           "new_states")
+_COUNTS = ("level", "rows", "row_words", "blocks", "segments", "steps",
+           "streamed_rows", "new_states")
 # beside the seams, what a stall's line says of its level
 _SUSPECTS = ("uploads", "upload_bytes", "upload_pieces", "d2h_bytes", "cpu_s",
              "gc_s", "majflt", "nivcsw")
@@ -300,6 +301,8 @@ class PassLog:
         rec["tail_s"] = t_end - self._t_end
         rec["stopped_by"] = args.get("stopped_by")
         rec["n_states"] = args.get("n_states")
+        if "elections_peak" in args:     # faithful mode alone
+            rec["elections_peak"] = args["elections_peak"]
         with self._lock:                 # a worker's late seam is dropped
             self._done = True
         for st in self._ledger.add(rec):

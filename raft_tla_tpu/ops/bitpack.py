@@ -192,12 +192,18 @@ class BitSchema:
     def unpack(self, packed, xp):
         """``int32[..., P] -> int32[..., W]``."""
         u = packed.astype(xp.uint32)
-        cols = []
-        for w in range(self.W):
-            b, s = int(self.bits[w]), int(self.start[w])
-            o, sh = s // 32, s % 32
-            v = u[..., o] >> xp.uint32(sh) if sh else u[..., o]
-            if sh + b > 32:
-                v = v | (u[..., o + 1] << xp.uint32(32 - sh))
-            cols.append(v & xp.uint32((1 << b) - 1))
-        return xp.stack(cols, axis=-1).astype(xp.int32)
+        return xp.stack([self._word(u, w, xp) for w in range(self.W)],
+                        axis=-1).astype(xp.int32)
+
+    def unpack_word(self, packed, w: int, xp):
+        """``int32[..., P] -> int32[...]``: element ``w`` of the flat
+        vector alone."""
+        return self._word(packed.astype(xp.uint32), w, xp).astype(xp.int32)
+
+    def _word(self, u, w: int, xp):
+        b, s = int(self.bits[w]), int(self.start[w])
+        o, sh = s // 32, s % 32
+        v = u[..., o] >> xp.uint32(sh) if sh else u[..., o]
+        if sh + b > 32:
+            v = v | (u[..., o + 1] << xp.uint32(32 - sh))
+        return v & xp.uint32((1 << b) - 1)

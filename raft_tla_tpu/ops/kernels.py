@@ -50,7 +50,9 @@ I32 = jnp.int32
 def _log_rank(bounds, s, i):
     """Rank of ``log[i]`` in the bounded log universe (faithful mode)."""
     uni = loguniv.LogUniverse.of(bounds)
-    return uni.log_id(s["logTerm"][i], s["logVal"][i], s["logLen"][i], jnp)
+    with jax.named_scope(HISTORY_SCOPE):
+        return uni.log_id(s["logTerm"][i], s["logVal"][i], s["logLen"][i],
+                          jnp)
 
 
 def _popcount(x):
@@ -163,7 +165,8 @@ def k_restart(bounds, s, i):
     out["matchIndex"] = _set_row(s["matchIndex"], i, 0)
     out["commitIndex"] = _set1(s["commitIndex"], i, 0)
     if "vLog" in s:   # voterLog[i] := empty map (raft.tla:171)
-        out["vLog"] = _set_row(s["vLog"], i, 0)
+        with jax.named_scope(HISTORY_SCOPE):
+            out["vLog"] = _set_row(s["vLog"], i, 0)
     return out, jnp.bool_(True), jnp.bool_(False)
 
 
@@ -177,7 +180,8 @@ def k_timeout(bounds, s, i):
     out["vResp"] = _set1(s["vResp"], i, 0)
     out["vGrant"] = _set1(s["vGrant"], i, 0)
     if "vLog" in s:   # voterLog[i] := empty map (raft.tla:186)
-        out["vLog"] = _set_row(s["vLog"], i, 0)
+        with jax.named_scope(HISTORY_SCOPE):
+            out["vLog"] = _set_row(s["vLog"], i, 0)
     return out, valid, jnp.bool_(False)
 
 
@@ -226,21 +230,31 @@ def k_become_leader(bounds, s, i):
     out["matchIndex"] = _set_row(s["matchIndex"], i, 0)
     ovf = jnp.bool_(False)
     if "eTerm" in s:
-        lid = _log_rank(bounds, s, i)
-        vrow = s["vLog"][i]
-        occ = s["eTerm"] > 0
-        match = (occ & (s["eTerm"] == s["term"][i]) & (s["eLeader"] == i)
-                 & (s["eLog"] == lid) & (s["eVotes"] == s["vGrant"][i])
-                 & jnp.all(s["eVLog"] == vrow[None, :], axis=1))
-        ins, _exists, ovf = _slot_insert(match, ~occ)
-        out["eTerm"] = jnp.where(ins, s["term"][i], s["eTerm"]).astype(I32)
-        out["eLeader"] = jnp.where(ins, i, s["eLeader"]).astype(I32)
-        out["eLog"] = jnp.where(ins, lid, s["eLog"]).astype(I32)
-        out["eVotes"] = jnp.where(ins, s["vGrant"][i],
-                                  s["eVotes"]).astype(I32)
-        out["eVLog"] = jnp.where(ins[:, None], vrow[None, :],
-                                 s["eVLog"]).astype(I32)
+        with jax.named_scope(HISTORY_SCOPE):
+            out, ovf = _elections_insert(bounds, s, out, i)
     return out, valid, valid & ovf
+
+
+def _elections_insert(bounds, s, out, i):
+    """``elections' = elections \\cup {[eterm, eleader, elog, evotes,
+    evoterLog]}`` of ``BecomeLeader(i)`` (raft.tla:237-242), all from the
+    unprimed state: ``out`` with the record in its first free slot, and
+    whether there was none."""
+    lid = _log_rank(bounds, s, i)
+    vrow = s["vLog"][i]
+    occ = s["eTerm"] > 0
+    match = (occ & (s["eTerm"] == s["term"][i]) & (s["eLeader"] == i)
+             & (s["eLog"] == lid) & (s["eVotes"] == s["vGrant"][i])
+             & jnp.all(s["eVLog"] == vrow[None, :], axis=1))
+    ins, _exists, ovf = _slot_insert(match, ~occ)
+    out = dict(out)
+    out["eTerm"] = jnp.where(ins, s["term"][i], s["eTerm"]).astype(I32)
+    out["eLeader"] = jnp.where(ins, i, s["eLeader"]).astype(I32)
+    out["eLog"] = jnp.where(ins, lid, s["eLog"]).astype(I32)
+    out["eVotes"] = jnp.where(ins, s["vGrant"][i], s["eVotes"]).astype(I32)
+    out["eVLog"] = jnp.where(ins[:, None], vrow[None, :],
+                             s["eVLog"]).astype(I32)
+    return out, ovf
 
 
 def k_client_request(bounds, s, i, v):
@@ -331,9 +345,11 @@ def k_receive(bounds, s, slot):
         _set1(s["vGrant"], i, s["vGrant"][i] | (1 << j)), s["vGrant"])
     if "vLog" in s:
         # voterLog[i] @@ (j :> m.mlog): existing entry wins (raft.tla:316-317)
-        cur = s["vLog"][i, j]
-        newv = jnp.where((mb.fa(hi) > 0) & (cur == 0), mb.fg(lo) + 1, cur)
-        s_rvresp["vLog"] = _set2(s["vLog"], i, j, newv)
+        with jax.named_scope(HISTORY_SCOPE):
+            cur = s["vLog"][i, j]
+            newv = jnp.where((mb.fa(hi) > 0) & (cur == 0), mb.fg(lo) + 1,
+                             cur)
+            s_rvresp["vLog"] = _set2(s["vLog"], i, j, newv)
     s_rvresp = bag_remove(s_rvresp, hi, lo)
 
     # HandleAppendEntriesRequest (raft.tla:327-389)
@@ -536,10 +552,14 @@ def finish_expand(bounds, s, succs, valids, ovfs):
     of an expansion's postlude (dense and CP twins both end here)."""
     all_succs = jax.tree.map(
         lambda *xs: jnp.concatenate(xs, axis=0), *succs)
+    # st.canonicalize in two halves: the history's under its own scope
+    all_succs = jax.vmap(lambda t: st.canonicalize_bag(t, jnp))(all_succs)
     if "allLogs" in s:
-        all_succs["allLogs"] = _alllogs_update(
-            bounds, s, all_succs["allLogs"].shape[0])
-    all_succs = jax.vmap(lambda t: st.canonicalize(t, jnp))(all_succs)
+        with jax.named_scope(HISTORY_SCOPE):
+            all_succs["allLogs"] = _alllogs_update(
+                bounds, s, all_succs["allLogs"].shape[0])
+            all_succs.update(jax.vmap(
+                lambda t: st.canonicalize_elections(t, jnp))(all_succs))
     return all_succs, jnp.concatenate(valids), jnp.concatenate(ovfs)
 
 
@@ -592,6 +612,15 @@ def _alllogs_update(bounds, s, n_lanes):
 STAGE_SCOPES = ("unpack", "expand", "pack", "prescan", "orbit_scan",
                 "plain_fp", "invariants", "constraint", "filter_insert",
                 "stream")
+# Two scopes that open INSIDE a stage and are no stage themselves (an op's
+# stage stays the innermost name of STAGE_SCOPES on its path, so the stage
+# totals keep their meaning): ``history`` inside ``expand``, what faithful
+# mode adds to a step (the allLogs union, the voterLog writes, the elections
+# insert and sort, the mlog ranks; never opened in parity mode), and
+# ``orbit_moved`` inside ``orbit_scan``, the fields the scan still moves and
+# canonicalises an image at a time (ops/symmetry.build_orbit_fp: logVal
+# under Value symmetry, the faithful-mode history).
+HISTORY_SCOPE, ORBIT_MOVED_SCOPE = NESTED_SCOPES = ("history", "orbit_moved")
 
 
 def _step_stages(bounds: Bounds, spec: str, invariants: tuple,

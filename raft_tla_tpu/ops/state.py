@@ -119,6 +119,15 @@ class Layout:
     def width(self) -> int:
         return sum(int(np.prod(s)) for s in self.shapes.values())
 
+    def offset(self, field: str) -> int:
+        """Position of ``field``'s first word in the flat vector."""
+        off = 0
+        for f, shape in self.shapes.items():
+            if f == field:
+                return off
+            off += int(np.prod(shape))
+        raise KeyError(field)
+
 
 def init_struct(bounds: Bounds, xp):
     """The unique initial state (``Init``, ``raft.tla:155-160``).
@@ -242,7 +251,17 @@ def _np_swap(a, i: int, j: int, le):
 
 
 def canonicalize(struct, xp):
-    """Sort message slots into canonical order: occupied first, then (hi, lo).
+    """Sort message slots into canonical order: occupied first, then (hi, lo)
+    (:func:`canonicalize_bag`) and, in faithful mode, the ``elections`` slots
+    likewise (:func:`canonicalize_elections`)."""
+    out = canonicalize_bag(struct, xp)
+    if "eTerm" in struct:
+        out.update(canonicalize_elections(struct, xp))
+    return out
+
+
+def canonicalize_bag(struct, xp):
+    """The struct with its message slots in canonical order.
 
     The bag is an unordered function (``raft.tla:32``); slot order is an
     encoding artifact and must not influence the fingerprint.  Distinct
@@ -264,22 +283,25 @@ def canonicalize(struct, xp):
     out = dict(struct)
     out["msgHi"], out["msgLo"], out["msgCount"] = _network_sort(
         [occ_key, hi, lo], [hi, lo, ct], M, xp)
-    if "eTerm" in struct:
-        # elections is a set (raft.tla:39); slot order is an encoding
-        # artifact, canonicalized exactly like the message bag.  eTerm > 0
-        # marks occupancy (election terms start at 1, raft.tla:143).
-        eocc_key = (~(struct["eTerm"] > 0)).astype(xp.int32)
-        E = int(struct["eTerm"].shape[-1])
-        evl_cols = [struct["eVLog"][..., c]
-                    for c in range(struct["eVLog"].shape[-1])]
-        keys = [eocc_key, struct["eTerm"], struct["eLeader"],
-                struct["eLog"], struct["eVotes"]] + evl_cols
-        sorted_vals = _network_sort(
-            keys, [struct["eTerm"], struct["eLeader"], struct["eLog"],
-                   struct["eVotes"]] + evl_cols, E, xp)
-        out["eTerm"], out["eLeader"], out["eLog"], out["eVotes"] = \
-            sorted_vals[:4]
-        out["eVLog"] = xp.stack(sorted_vals[4:], axis=-1)
+    return out
+
+
+def canonicalize_elections(struct, xp) -> dict:
+    """The five ``elections`` fields of a faithful-mode struct in canonical
+    slot order.  elections is a set (raft.tla:39); slot order is an encoding
+    artifact, canonicalized exactly like the message bag.  eTerm > 0 marks
+    occupancy (election terms start at 1, raft.tla:143)."""
+    eocc_key = (~(struct["eTerm"] > 0)).astype(xp.int32)
+    E = int(struct["eTerm"].shape[-1])
+    evl_cols = [struct["eVLog"][..., c]
+                for c in range(struct["eVLog"].shape[-1])]
+    keys = [eocc_key, struct["eTerm"], struct["eLeader"],
+            struct["eLog"], struct["eVotes"]] + evl_cols
+    sorted_vals = _network_sort(
+        keys, [struct["eTerm"], struct["eLeader"], struct["eLog"],
+               struct["eVotes"]] + evl_cols, E, xp)
+    out = dict(zip(("eTerm", "eLeader", "eLog", "eVotes"), sorted_vals[:4]))
+    out["eVLog"] = xp.stack(sorted_vals[4:], axis=-1)
     return out
 
 

@@ -644,6 +644,8 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     import jax
     import jax.numpy as jnp
 
+    from raft_tla_tpu.ops.kernels import ORBIT_MOVED_SCOPE
+
     server, value = "Server" in axes, "Value" in axes
     lay = st.Layout.of(bounds)
     n = lay.n
@@ -685,14 +687,18 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
 
         def moved_sums(sl, vl):
             # what the fields that have to move add to one image's sums
-            t = struct
-            if server:
-                t = {**t, **_permute_struct_batch(t, moved, sl, jnp)}
-            if value:
-                t = {**t, **_permute_values_batch(t, moved, vl, jnp)}
-            if faithful:     # the elections sort (its bag sort is dead)
-                t = jax.vmap(lambda s: st.canonicalize(s, jnp))(t)
-            return fpr.field_sums(t, consts, jnp, moved)
+            # (a scope inside the caller's ``orbit_scan``:
+            # kernels.NESTED_SCOPES)
+            with jax.named_scope(ORBIT_MOVED_SCOPE):
+                t = struct
+                if server:
+                    t = {**t, **_permute_struct_batch(t, moved, sl, jnp)}
+                if value:
+                    t = {**t, **_permute_values_batch(t, moved, vl, jnp)}
+                if faithful:   # the elections sort alone: the bag is ranked
+                    t = {**t, **jax.vmap(
+                        lambda s: st.canonicalize_elections(s, jnp))(t)}
+                return fpr.field_sums(t, consts, jnp, moved)
 
         def block(best, xs):
             # the linear sums of P_b permutations in one product; their

@@ -1061,6 +1061,7 @@ class DDDShardEngine:
             # end_level(), here or after the loop (close is idempotent)
             level_sp = tr.open("level", level=len(level_ends),
                                rows=lvl_hi - lvl_lo,
+                               row_words=self.schema.P,
                                blocks=-(-(lvl_hi - w0) // W))
             lvl_segs = lvl_steps = lvl_rows = lvl_slabs = lvl_peak = 0
             lvl_xslabs = lvl_route = 0

@@ -1,6 +1,6 @@
 """Generate the ready-to-run TLC twin bundles (VERDICT r3 next #8).
 
-Three headline configurations, one directory each under runs/tlc_twin/,
+The headline configurations, one directory each under runs/tlc_twin/,
 so the BASELINE "invariant parity against TLC" gate closes in minutes on
 any JVM host: copy the (unmodified) reference ``raft.tla`` next to the
 bundle's MCraft.* pair and run the one-liner in RUN.txt.  The reference
@@ -56,6 +56,19 @@ BUNDLES = {
         invariants=FAITHFUL_INVS, parity_view=False, symmetry=(),
         spec="full",
         expect="84,572 distinct states, diameter 32, all 7 hold"),
+    # the benchmark's faithful3 (runs/MC3s2v_faithful.cfg): the reference
+    # raft.cfg universe with every variable of vars in the state identity,
+    # SYMMETRY Server, the seven invariants; no VIEW stanza
+    "faithful3_3s2v": dict(
+        bounds=Bounds(n_servers=3, n_values=2, max_term=2, max_log=1,
+                      max_msgs=2, max_dup=1, history=True),
+        invariants=FAITHFUL_INVS, parity_view=False, symmetry=("Server",),
+        spec="full",
+        expect="per level from Init, cumulative distinct states under "
+               "SYMMETRY Server: 1, 3, 8, 24, 79, 243, 678, 1591, 3452, "
+               "7595, ... 1,011,645 at depth 17, 1,605,204 at depth 18 "
+               "(benchmark/configs/faithful3.json level_pins; the whole "
+               "space has not been run), all 7 hold"),
     # round-5 temporal surface: leads-to under WF(Next) on the faithful
     # 2-server full space (TLC checks liveness on real states, so the
     # twin omits the VIEW; the checker's verdict is the refutation)
@@ -96,6 +109,10 @@ CLI = {
     "full2s_faithful": "python -m raft_tla_tpu.check runs/tlc_twin/"
                        "full2s_faithful/MCraft.cfg --faithful "
                        "--engine ddd",
+    "faithful3_3s2v": "python -m raft_tla_tpu.check runs/"
+                      "MC3s2v_faithful.cfg --faithful --engine ddd "
+                      "--symmetry --max-term 2 --max-log 1 --max-msgs 2 "
+                      "--chunk 4096 --cap 2097152",
     "full2s_temporal": "python -m raft_tla_tpu.check <cfg with PROPERTY "
                        "SomeCandidate ~> SomeLeader> --spec full "
                        "--engine ref --wf Next   (exit 13 + lasso)",

@@ -31,8 +31,8 @@ CFG = CheckConfig(
     spec="election", invariants=("NoTwoLeaders",), chunk=32)
 N_TOY = 3014
 SEAMS = ("upload_s", "expand_s", "wait_s", "d2h_s", "dedup_s", "close_s")
-FIELDS = {"level", "t0", "gap_s", "wall_s", "rows", "blocks", "segments",
-          "steps", "streamed_rows", "new_states", "upload_s", "uploads",
+FIELDS = {"level", "t0", "gap_s", "wall_s", "rows", "row_words", "blocks",
+          "segments", "steps", "streamed_rows", "new_states", "upload_s", "uploads",
           "upload_bytes", "upload_pieces", "d2h_bytes", "expand_s", "wait_s",
           "d2h_s", "dedup_s", "close_s", "cpu_s", "gc_s", "majflt", "nivcsw"}
 
