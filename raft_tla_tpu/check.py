@@ -885,7 +885,10 @@ def main(argv=None) -> int:
         words = {lv["row_words"] for lv in ledger["levels"]}
         print(f"History: packed rows of {'/'.join(map(str, sorted(words)))} "
               f"words; elections peak {ledger['elections_peak']} of "
-              f"{config.bounds.max_elections} slots.")
+              f"{config.bounds.max_elections} slots"
+              + (f"; the orbit scan moves {ledger['scan_moved_fields']} "
+                 "field(s) an image." if "scan_moved_fields" in ledger
+                 else "."))
     if args.coverage:
         for fam, cnt in sorted(result.coverage.items()):
             print(f"  {fam}: {cnt} new states")

@@ -1252,6 +1252,9 @@ class DDDEngine:
         # |G| of the run's SYMMETRY (1 with none), for ``run_start`` and
         # the ``segment`` spans: scope time over ``images`` is time an image
         self._group = self.model.group_order(config)
+        # ... and how many fields its scan still moves an image at a time
+        # (None with no SYMMETRY), for the ``pass`` span
+        self._scan_moved = self.model.scan_moved_fields(config)
         self._segment = jax.jit(
             _build_segment(config, self.caps, self.A, self.lay.width,
                            self.schema,
@@ -2113,6 +2116,8 @@ class DDDEngine:
             keystore.close()
         if self._eterm0 is not None:
             pass_sp.set(elections_peak=self._epeak)
+        if self._scan_moved is not None:
+            pass_sp.set(scan_moved_fields=self._scan_moved)
         pass_sp.set(levels=len(levels_arr), n_states=n_states,
                     stopped_by="violation" if violation is not None
                     else stopped_by).close()

@@ -104,6 +104,16 @@ class RaftModel:
         return (len(sym_mod.permutations(b)) if "Server" in axes else 1) \
             * (len(sym_mod.value_permutations(b)) if "Value" in axes else 1)
 
+    def scan_moved_fields(self, config: CheckConfig) -> int | None:
+        """How many fields the orbit scan still permutes and canonicalises
+        an image at a time (``ops/symmetry.scan_forms``); ``None`` with no
+        SYMMETRY: no scan."""
+        from raft_tla_tpu.ops import symmetry as sym_mod
+        if not config.symmetry:
+            return None
+        return len(sym_mod.scan_forms(config.bounds,
+                                      tuple(config.symmetry))["moved"])
+
     def constraint_ok(self, py, bounds) -> bool:
         from raft_tla_tpu.models import interp
         return bool(interp.constraint_ok(py, bounds))
@@ -213,6 +223,11 @@ class SchemaModel:
         from raft_tla_tpu.ops import symmetry as sym_mod
         return len(sym_mod.schema_group(
             self._mod().SCHEMA, config.bounds, tuple(config.symmetry)))
+
+    def scan_moved_fields(self, config: CheckConfig) -> int | None:
+        """0 under SYMMETRY — a schema's whole key is ``features . table``
+        (``ops/symmetry.build_schema_orbit_fp``) — and ``None`` with none."""
+        return 0 if config.symmetry else None
 
     def constraint_ok(self, py, bounds) -> bool:
         return True      # the state space is finite with no constraint

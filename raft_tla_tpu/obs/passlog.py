@@ -301,8 +301,10 @@ class PassLog:
         rec["tail_s"] = t_end - self._t_end
         rec["stopped_by"] = args.get("stopped_by")
         rec["n_states"] = args.get("n_states")
-        if "elections_peak" in args:     # faithful mode alone
-            rec["elections_peak"] = args["elections_peak"]
+        for key in ("elections_peak",      # faithful mode alone
+                    "scan_moved_fields"):  # under SYMMETRY alone
+            if key in args:
+                rec[key] = args[key]
         with self._lock:                 # a worker's late seam is dropped
             self._done = True
         for st in self._ledger.add(rec):

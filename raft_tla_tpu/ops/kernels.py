@@ -617,9 +617,13 @@ STAGE_SCOPES = ("unpack", "expand", "pack", "prescan", "orbit_scan",
 # totals keep their meaning): ``history`` inside ``expand``, what faithful
 # mode adds to a step (the allLogs union, the voterLog writes, the elections
 # insert and sort, the mlog ranks; never opened in parity mode), and
-# ``orbit_moved`` inside ``orbit_scan``, the fields the scan still moves and
-# canonicalises an image at a time (ops/symmetry.build_orbit_fp: logVal
-# under Value symmetry, the faithful-mode history).
+# ``orbit_moved`` inside ``orbit_scan``, what the scan's linear key and
+# ranked bag do not cover (ops/symmetry.build_orbit_fp): what the
+# faithful-mode history adds to an image's key (``allLogs``' sums once a
+# step, the ``elections`` records' keys and sums an image; since PR 51
+# nothing of it is moved under Server symmetry alone) and the fields a
+# value permutation still moves and canonicalises an image at a time
+# (``logVal``, the history's log ranks).
 HISTORY_SCOPE, ORBIT_MOVED_SCOPE = NESTED_SCOPES = ("history", "orbit_moved")
 
 

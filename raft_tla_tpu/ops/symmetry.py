@@ -20,6 +20,10 @@ the *unmoved* state against that permutation's row of a host-built table
 of permuted constants, and the message bag is ranked where the loop sorts
 it — the same bits, and since PR 42 the table is ``int8`` limbs, so the
 linear part of a block of images' keys is one matrix product on the MXU.
+Since PR 51 that holds for the faithful-mode history under Server
+symmetry too (``voterLog`` on the table, ``allLogs`` once a call, the
+``elections`` records as packed keys); only a value permutation still
+moves data (``logVal``, and the history's log ranks).
 
 Permuting one state under ``p`` (new index of old server j is ``p[j]``):
 
@@ -270,27 +274,59 @@ def _value_luts(bounds: Bounds, faithful: bool) -> dict:
 # ---------------------------------------------------------------------------
 
 _BAG = ("msgHi", "msgLo", "msgCount")
+_ELECTIONS = ("eTerm", "eLeader", "eLog", "eVotes", "eVLog")
 # fields a server permutation only moves -> how many of their leading axes
-# are server-indexed; a feature is the word itself
+# are server-indexed; a feature is the word itself (``vLog``: log ranks
+# hold no server id, so ``voterLog`` is ``nextIndex``' case)
 _MOVED_AXES = {"role": 1, "term": 1, "commitIndex": 1, "logLen": 1,
-               "logTerm": 1, "logVal": 1, "nextIndex": 2, "matchIndex": 2}
+               "logTerm": 1, "logVal": 1, "nextIndex": 2, "matchIndex": 2,
+               "vLog": 2}
 # fields whose words also name servers: votedFor by id (one feature a
 # value, its one-hot), the vote masks by bit (one feature a bit)
 _VOTE_MASKS = ("vResp", "vGrant")
 _RELABELLED = ("votedFor",) + _VOTE_MASKS
 
 
-def _linear_fields(axes: tuple) -> tuple:
+def _linear_fields(axes: tuple, history: bool = False) -> tuple:
     """The fields whose share of an image's key is ``features . table``:
-    every per-server parity field, less ``logVal`` where a value
-    permutation relabels its contents (it keeps the data-moving path,
-    with the faithful-mode history; the bag is ranked, :func:`_bag_sums`)."""
-    return tuple(f for f in st.STATE_FIELDS
+    every per-server parity field and, in faithful mode, ``vLog`` — less
+    what a value permutation relabels the contents of, ``logVal`` and the
+    ``vLog`` ranks (they keep the data-moving path; the bag and the
+    ``elections`` records are ranked, :func:`_bag_sums`,
+    :func:`_election_sums`)."""
+    return tuple(f for f in st.STATE_FIELDS + ("vLog",) * history
                  if (f in _MOVED_AXES or f in _RELABELLED)
-                 and not (f == "logVal" and "Value" in axes))
+                 and not (f in ("logVal", "vLog") and "Value" in axes))
 
 
-def _key_features(struct: dict, fields: tuple, xp):
+def scan_forms(bounds: Bounds, axes: tuple) -> dict:
+    """Which form each field's share of an image's key takes in
+    :func:`build_orbit_fp`, from the SYMMETRY axes and the layout alone:
+    ``table`` (features times permuted constants), ``ranked`` (relabelled
+    and put in order where it lies: the bag's slots counted into place,
+    the ``elections`` records as packed keys), ``once`` (a fixed point of
+    the group: summed once a call) or ``moved`` (permuted and
+    canonicalised an image at a time).  Every field of the layout is in
+    exactly one."""
+    lay = st.Layout.of(bounds)
+    table = _linear_fields(axes, lay.history)
+    still = lay.history and "Value" not in axes
+    forms = {"table": table,
+             "ranked": _BAG + (_ELECTIONS if still else ()),
+             "once": ("allLogs",) if still else ()}
+    taken = {f for fs in forms.values() for f in fs}
+    forms["moved"] = tuple(f for f in lay.fields if f not in taken)
+    return forms
+
+
+# A feature is a signed byte.  A word that can pass 127 (``vLog`` where
+# the log universe has more ranks) is two base-128 digits, two features
+# with the constants ``c`` and ``c << 7``: the fold is the identity under
+# 2^16, so the word's share of the sum is still linear in its digits.
+_DIGIT_BITS = 7
+
+
+def _key_features(struct: dict, fields: tuple, xp, wide: tuple = ()):
     """``struct[N, ...] -> int8[F, N]``: the permutation-independent
     features of ``fields``, in :func:`_key_table`'s order, lanes minor (a
     ``[N, small]`` array is kept padded to 128 lanes on the TPU).  Every
@@ -298,7 +334,9 @@ def _key_features(struct: dict, fields: tuple, xp):
     at 63 by ``config.Bounds`` (:func:`_feature_cap`), the rest are bits —
     so the fold (``x ^ (x >> 16)``) is the identity on all of them and a
     signed byte holds each: the matrix the MXU multiplies by a block of
-    permutations' limbs (:func:`_limb_sums`), read once a block."""
+    permutations' limbs (:func:`_limb_sums`), read once a block.  A field
+    of ``wide`` (:func:`_wide_fields`) gives its low digits, then its high
+    ones."""
     n = struct["role"].shape[1]
     N = struct["role"].shape[0]
     parts = []
@@ -309,7 +347,10 @@ def _key_features(struct: dict, fields: tuple, xp):
         elif f in _VOTE_MASKS:
             a = (a[:, :, None] >> xp.arange(n)) & 1       # [N, j, bit]
         a = xp.reshape(a, (N, -1))
-        parts.append(a.astype(xp.int8))     # 6-bit: Bounds caps them at 63
+        if f in wide:
+            a = xp.concatenate([a & ((1 << _DIGIT_BITS) - 1),
+                                a >> _DIGIT_BITS], axis=1)
+        parts.append(a.astype(xp.int8))     # 7-bit: _feature_cap
     return xp.concatenate(parts, axis=1).T
 
 
@@ -326,9 +367,12 @@ def _key_table(bounds: Bounds, consts, fields: tuple,
     - ``votedFor`` = id: ``c[p[j]] * (p[id - 1] + 1)`` — the relabelled
       id, which the fold leaves alone;
     - vote masks, bit ``b``: ``c[p[j]] << p[b]`` — distinct bits, so the
-      relabelled mask is their sum.
+      relabelled mask is their sum;
+    - a wide field (:func:`_wide_fields`): ``c`` for its low digits, then
+      ``c << 7`` for its high ones.
     """
     fc = fpr.field_constants(st.Layout.of(bounds).shapes, consts)
+    wide = _wide_fields(bounds, fields)
     table = []
     for p in perms:
         p = np.asarray(p)
@@ -341,7 +385,11 @@ def _key_table(bounds: Bounds, consts, fields: tuple,
                 cf = cf[:, :, None] * (p + 1).astype(np.uint32)
             elif f in _VOTE_MASKS:
                 cf = cf[:, :, None] << p.astype(np.uint32)
-            row.append(cf.reshape(2, -1))
+            cf = cf.reshape(2, -1)
+            if f in wide:
+                cf = np.concatenate([cf, cf << np.uint32(_DIGIT_BITS)],
+                                    axis=1)
+            row.append(cf)
         table.append(np.concatenate(row, axis=1))
     return np.stack(table)
 
@@ -377,14 +425,31 @@ _BLOCK_PERMS = 8
 _PRODUCT_BYTES = 96 << 20
 
 
-def _feature_cap(bounds: Bounds, fields: tuple) -> int:
-    """The largest entry :func:`_key_features` can hold for ``fields``
-    under ``bounds`` (capacities, one past each constraint)."""
+def _word_caps(bounds: Bounds, fields: tuple) -> dict:
+    """field -> the largest word it can hold under ``bounds``
+    (capacities, one past each constraint; ``vLog`` a log rank + 1)."""
     cap = {"role": 2, "term": bounds.term_cap, "logTerm": bounds.term_cap,
            "commitIndex": bounds.log_cap, "logLen": bounds.log_cap,
            "matchIndex": bounds.log_cap, "nextIndex": bounds.log_cap + 1,
            "logVal": bounds.n_values}
-    return max(cap.get(f, 1) for f in fields)    # one-hots and bits: 1
+    if "vLog" in fields:
+        from raft_tla_tpu.ops.loguniv import LogUniverse
+        cap["vLog"] = LogUniverse.of(bounds).size
+    return {f: cap.get(f, 1) for f in fields}    # one-hots and bits: 1
+
+
+def _wide_fields(bounds: Bounds, fields: tuple) -> tuple:
+    """The fields of ``fields`` whose words can pass a signed byte: each
+    is keyed as two base-128 digits (``_DIGIT_BITS``)."""
+    return tuple(f for f, cap in _word_caps(bounds, fields).items()
+                 if cap >= 1 << _DIGIT_BITS)
+
+
+def _feature_cap(bounds: Bounds, fields: tuple) -> int:
+    """The largest entry :func:`_key_features` can hold for ``fields``
+    under ``bounds``: a word, or a wide word's digit."""
+    return max(min(cap, (1 << _DIGIT_BITS) - 1)
+               for cap in _word_caps(bounds, fields).values())
 
 
 def _check_limb_range(n_features: int, feature_cap: int) -> None:
@@ -537,6 +602,157 @@ def _bag_sums(hi, lo, ct, cbag, xp):
     return s1, s2
 
 
+# The ``elections`` records of faithful mode, put in order as the bag is:
+# with no state moved.  A record's sort key is ``canonicalize_elections``'
+# own — empty last, then ``eTerm``, ``eLeader``, ``eLog``, ``eVotes``, the
+# ``eVLog`` columns — packed most significant first into non-negative
+# ``int32`` words, so one compare a word orders two records.  Every part
+# of it is a small bit field and the words hold all of a record: two
+# records with one key are one record, the keys in order are the sorted
+# image's records, and each word of a record is cut from its key.
+_KEY_WORD_BITS = 31
+
+
+def _election_key_plan(bounds: Bounds) -> tuple:
+    """``(parts, n_words)``: a record's key parts in sort order, each
+    ``(field, column or None, word, shift, width)``, packed greedily into
+    ``n_words`` words of ``_KEY_WORD_BITS`` bits.  ``eTerm`` is keyed as
+    ``(eTerm - 1) mod 2^width``: the order of the terms, and an empty
+    slot's 0 the greatest (``width`` holds ``term_cap`` and one more);
+    the other widths hold the words' capacities (a server id, a rank of
+    the log universe, a vote mask, a rank + 1)."""
+    from raft_tla_tpu.ops.loguniv import LogUniverse
+    n, U = bounds.n_servers, LogUniverse.of(bounds).size
+    widths = [("eTerm", None, bounds.term_cap.bit_length()),
+              ("eLeader", None, max(1, (n - 1).bit_length())),
+              ("eLog", None, max(1, (U - 1).bit_length())),
+              ("eVotes", None, n)] \
+        + [("eVLog", j, U.bit_length()) for j in range(n)]
+    parts, word, free = [], 0, _KEY_WORD_BITS
+    for f, col, width in widths:
+        if width > free:
+            word, free = word + 1, _KEY_WORD_BITS
+        free -= width
+        parts.append((f, col, word, free, width))
+    return tuple(parts), word + 1
+
+
+def _key_places(plan: tuple) -> dict:
+    """``(field, column or None) -> (word, shift, width)`` of a plan."""
+    return {(f, col): place for f, col, *place in plan[0]}
+
+
+def _election_luts(bounds: Bounds, plan: tuple) -> dict:
+    """What relabels a record's key under each server permutation, with no
+    gather: ``e_lead [P]`` the permutation as one word, ``p[j]`` in the
+    key's ``eLeader`` width at bit ``j * width``; ``e_vote [P, n]`` where
+    vote bit ``j`` goes in its key word, ``p[j]`` past the field's shift;
+    ``e_vsh`` / ``e_vword [P, n]`` the shift and the key word of the
+    place ``eVLog`` column ``j`` goes to, column ``p[j]``'s."""
+    at = _key_places(plan)
+    p = np.asarray(permutations(bounds), np.int32)         # [P, n]
+    n = p.shape[1]
+    lead_w = at["eLeader", None][2]
+    col_word, col_sh, _ = (np.asarray(a, np.int32) for a in zip(
+        *(at["eVLog", j] for j in range(n))))
+    return {"e_lead": (p << (lead_w * np.arange(n, dtype=np.int32)))
+            .sum(axis=1, dtype=np.int32),
+            "e_vote": p + np.int32(at["eVotes", None][1]),
+            "e_vsh": col_sh[p], "e_vword": col_word[p]}
+
+
+def _election_records(struct: dict, plan: tuple, xp) -> list:
+    """The ``elections`` slots slot-major, once a call: for each slot the
+    ``[N]`` vectors (lanes minor) that no permutation changes —
+    ``occ``; ``fixed``, a key word a list entry with ``eTerm`` and ``eLog``
+    in place; ``stale``, ``eLeader`` and ``eVotes`` in place as they
+    stand (``permute_struct`` relabels neither in an empty slot); and
+    what a permutation relabels: ``lead_at``, the leader's bit in
+    ``e_lead``, ``votes``, and ``vlog``, a column a list entry."""
+    n_words, at = plan[1], _key_places(plan)
+    E, n = struct["eVLog"].shape[1:]
+    recs = []
+    for e in range(E):
+        term, lead, log, votes = (struct[f][:, e] for f in _ELECTIONS[:4])
+        fixed = [xp.zeros_like(term) for _ in range(n_words)]
+        stale = list(fixed)
+        w, sh, width = at["eTerm", None]
+        fixed[w] = fixed[w] | (((term - 1) & ((1 << width) - 1)) << sh)
+        w, sh, _ = at["eLog", None]
+        fixed[w] = fixed[w] | (log << sh)
+        w, sh, lead_w = at["eLeader", None]
+        stale[w] = stale[w] | (lead << sh)
+        w, sh, _ = at["eVotes", None]
+        stale[w] = stale[w] | (votes << sh)
+        recs.append({"occ": term > 0, "fixed": fixed, "stale": stale,
+                     "lead_at": lead * lead_w, "votes": votes,
+                     "vlog": [struct["eVLog"][:, e, j] for j in range(n)]})
+    return recs
+
+
+def _election_sums(recs: list, luts: dict, plan: tuple, celect: dict, xp):
+    """What the ``elections`` records of one image add to the lanes' sums
+    before the finaliser, **relabelled and ordered where they lie, not
+    permuted and sorted as arrays**.  ``recs``:
+    :func:`_election_records`; ``luts``: one permutation's rows of
+    :func:`_election_luts`; ``celect``: the lane constants of the five
+    fields (``fingerprint.field_constants``).
+
+    A slot's key under the permutation is put together by shifts
+    (``eLeader`` and ``eVotes`` through the permutation where the slot is
+    occupied, the ``eVLog`` columns always: ``permute_struct``'s bits).
+    The keys then go through ``canonicalize_elections``' own comparator
+    network, a compare and two selects a key word: a key is all of its
+    record, so whatever the network does on a tie the keys come out as
+    the sorted image's records, position by position, and each word of a
+    record is cut from its key and takes the constants of its position.
+    The fold is the identity on every such word (under 2^11).  An empty
+    slot is keyed like any other: all-zero it adds 0 wherever it lands,
+    and with stale words it adds what the loop's sort would make it
+    add."""
+    parts, n_words = plan
+    at = _key_places(plan)
+    (w_lead, sh_lead, lead_w), (w_votes, _, _) = \
+        at["eLeader", None], at["eVotes", None]
+    n = len(recs[0]["vlog"])
+    w_vlog = sorted({at["eVLog", j][0] for j in range(n)})
+    keys = []
+    for r in recs:
+        relab = [0] * n_words
+        relab[w_lead] = ((luts["e_lead"] >> r["lead_at"])
+                         & ((1 << lead_w) - 1)) << sh_lead
+        for j in range(n):
+            relab[w_votes] = relab[w_votes] \
+                + (((r["votes"] >> j) & 1) << luts["e_vote"][j])
+        key = list(r["fixed"])
+        for w in sorted({w_lead, w_votes}):
+            key[w] = key[w] + xp.where(r["occ"], relab[w], r["stale"][w])
+        for j in range(n):
+            col = r["vlog"][j] << luts["e_vsh"][j]
+            for w in w_vlog:
+                key[w] = key[w] + (col if len(w_vlog) == 1 else xp.where(
+                    luts["e_vword"][j] == w, col, 0))
+        keys.append(key)
+    for i, j in st._oddeven_pairs(len(keys)):
+        a, b = keys[i], keys[j]
+        gt = a[-1] > b[-1]
+        for x, y in zip(a[-2::-1], b[-2::-1]):
+            gt = (x > y) | ((x == y) & gt)
+        keys[i] = [xp.where(gt, y, x) for x, y in zip(a, b)]
+        keys[j] = [xp.where(gt, x, y) for x, y in zip(a, b)]
+    s1 = s2 = xp.uint32(0)
+    with np.errstate(over="ignore"):
+        for r, key in enumerate(keys):
+            for f, col, w, sh, width in parts:
+                v = (key[w] >> sh) & ((1 << width) - 1)
+                if f == "eTerm":
+                    v = (v + 1) & ((1 << width) - 1)
+                c = celect[f][:, r] if col is None else celect[f][:, r, col]
+                v = v.astype(xp.uint32)
+                s1, s2 = s1 + v * c[0], s2 + v * c[1]
+    return s1, s2
+
+
 def _permute_struct_batch(struct: dict, fields: tuple, luts: dict, xp):
     """The images of ``fields`` under one server permutation, over a
     leading batch axis, the permutation given as its traced rows of
@@ -625,10 +841,25 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
       linear in them), then ranked, not sorted (:func:`_bag_sums`), the
       block's images side by side (``[P_b, N]``, one fusion with the
       finaliser and the block's least key);
-    - what neither covers — ``logVal`` under Value symmetry, the
-      faithful-mode history with its ``elections`` sort — is moved and
-      canonicalised as the loop does, for those fields alone, an image at
-      a time.
+    - the faithful-mode history under Server symmetry alone (PR 51), none
+      of it moved: ``vLog`` rides the key table (log ranks hold no server
+      id: ``nextIndex``' case, a rank past 127 as two base-128 digits);
+      ``allLogs`` is a fixed point of every server permutation and is
+      summed once a call; the ``elections`` records are relabelled into
+      packed keys and ordered as keys (:func:`_election_sums`), side by
+      side with the bag in the same images' body;
+    - what is still moved, permuted and canonicalised as the loop does,
+      for those fields alone, an image at a time (``moved_sums``):
+      ``logVal`` under Value symmetry (a value permutation relabels its
+      contents, which a table of permuted constants cannot: it would
+      take a one-hot a value), and all of the history wherever a value
+      permutation is in the group (every log rank maps through
+      :func:`_rank_maps`, ``allLogs`` bitwise).
+
+    Which field took which form is on the returned function
+    (``orbit_fp.forms``, :func:`scan_forms`), and the engines put the
+    count of the moved ones on their ``pass`` span
+    (``scan_moved_fields``).
 
     The round-1 unrolled graph at five servers (120 copies) crashed
     compiles at chunk 2048 and capped the elect5 run at ~3k orbits/s;
@@ -651,28 +882,36 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
     n = lay.n
     perms = permutations(bounds) if server else (tuple(range(n)),)
     P = len(perms)
-    Q = len(value_permutations(bounds)) if value else 1
-    linear = _linear_fields(axes)
-    moved = tuple(f for f in lay.fields
-                  if f not in linear and f not in _BAG)
+    forms = scan_forms(bounds, axes)
+    linear, moved = forms["table"], forms["moved"]
+    ranked = _ELECTIONS[0] in forms["ranked"]     # the records, not moved
+    wide = _wide_fields(bounds, linear)
     table = _key_table(bounds, consts, linear, perms)
     _check_limb_range(table.shape[-1], _feature_cap(bounds, linear))
     limbs = jnp.asarray(_key_limbs(table))               # [P, 2, 4, F]
-    sluts = {k: jnp.asarray(v) for k, v in _server_luts(bounds).items()} \
-        if server else None
+    sluts = _server_luts(bounds) if server else None
+    fc = fpr.field_constants(lay.shapes, consts)
+    cbag = np.stack([fc[f] for f in _BAG], axis=1)       # [2, 3, S]
+    if ranked:
+        plan = _election_key_plan(bounds)
+        sluts = {**(sluts or {}), **_election_luts(bounds, plan)}
+    sluts = sluts and {k: jnp.asarray(v) for k, v in sluts.items()}
     vluts = {k: jnp.asarray(v)
              for k, v in _value_luts(bounds, faithful).items()} \
         if value else None
-    fc = fpr.field_constants(lay.shapes, consts)
-    cbag = np.stack([fc[f] for f in _BAG], axis=1)       # [2, 3, S]
 
     def orbit_fp(struct):
         # what no group element changes, once a call: the features of
-        # the linear fields and the bag slot-major, a vector a slot
-        phi = _key_features(struct, linear, jnp)
+        # the linear fields, the bag slot-major, a vector a slot, and in
+        # faithful mode the election records likewise and allLogs' sums
+        phi = _key_features(struct, linear, jnp, wide)
         hi0, lo0, ct = ([struct[f][:, s] for s in range(lay.S)]
                         for f in _BAG)
         Pb = _block_perms(P, phi.shape[1])
+        if ranked:
+            with jax.named_scope(ORBIT_MOVED_SCOPE):
+                recs = _election_records(struct, plan, jnp)
+                once = fpr.field_sums(struct, consts, jnp, forms["once"])
 
         def image(s1, s2, sl, vl):
             # one image's key from its two sums over everything but the
@@ -683,12 +922,22 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
             if value:
                 lo = [_relabel_lo(w, vl, jnp) for w in lo]
             b1, b2 = _bag_sums(hi, lo, ct, cbag, jnp)
+            if ranked:
+                # what the history adds to the image's key (a scope
+                # inside the caller's ``orbit_scan``: NESTED_SCOPES),
+                # kept a fusion of its own: fused into the images' one
+                # it ran 0.54 ms a step slower on the v5e and read as
+                # ``orbit_scan`` alone (PERF.md section 6, PR 51)
+                with jax.named_scope(ORBIT_MOVED_SCOPE):
+                    e1, e2 = _election_sums(recs, sl, plan, fc, jnp)
+                    e1, e2 = jax.lax.optimization_barrier(
+                        (e1 + once[0], e2 + once[1]))
+                b1, b2 = b1 + e1, b2 + e2
             return fpr.finalise(s1 + b1, s2 + b2, jnp)
 
         def moved_sums(sl, vl):
             # what the fields that have to move add to one image's sums
-            # (a scope inside the caller's ``orbit_scan``:
-            # kernels.NESTED_SCOPES)
+            # (under the same scope)
             with jax.named_scope(ORBIT_MOVED_SCOPE):
                 t = struct
                 if server:
@@ -731,6 +980,7 @@ def build_orbit_fp(bounds: Bounds, axes: tuple, consts, faithful: bool):
         (bh, bl), _ = jax.lax.scan(block, (top, top), blocks)
         return bh, bl
 
+    orbit_fp.forms = forms
     return orbit_fp
 
 

@@ -14,6 +14,13 @@ B3 = Bounds(n_servers=3, n_values=1, max_term=2, max_log=0, max_msgs=1)
 _B3S = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2)
 _BH2 = Bounds(n_servers=2, n_values=2, max_term=2, max_log=1, max_msgs=2,
               history=True, max_elections=4)
+# faithful3's own bounds (benchmark/configs/faithful3.json), and bounds
+# whose log universe has 259 ranks: a ``vLog`` word past a signed byte and
+# an ``elections`` key of two words
+_BH3 = Bounds(n_servers=3, n_values=2, max_term=2, max_log=1, max_msgs=2,
+              history=True, max_elections=6)
+_BH3W = Bounds(n_servers=3, n_values=2, max_term=2, max_log=2, max_msgs=2,
+               history=True, max_elections=3)
 # the benchmark's 5-server bounds (benchmark/configs/elect5.json, full5.json)
 _ELECT5 = Bounds(n_servers=5, n_values=2, max_term=2, max_log=0, max_msgs=2,
                  max_dup=1)
@@ -108,6 +115,83 @@ def _stale_slot_vecs():
     return np.stack(vecs)
 
 
+def _elected_states(bounds, depth=3, cap=60):
+    """Reachable states that hold an ``elections`` record: the shortest
+    run of the election actions to a first ``BecomeLeader``, then a BFS
+    prefix of the full ``Next`` from there (the record rides along while
+    the servers' own words change)."""
+    seen = {interp.init_state(bounds)}
+    frontier = list(seen)
+    won = None
+    while won is None:
+        nxt = []
+        for s in frontier:
+            for _i, t in interp.successors(s, bounds, spec="election"):
+                if t.elections:
+                    won = won or t
+                if t not in seen and interp.constraint_ok(t, bounds):
+                    seen.add(t)
+                    nxt.append(t)
+        frontier = nxt
+    out, frontier = [won], [won]
+    for _ in range(depth):
+        nxt = [t for s in frontier if interp.constraint_ok(s, bounds)
+               for _i, t in interp.successors(s, bounds, spec="full")]
+        frontier = nxt[::max(1, len(nxt) // cap)][:cap]
+        out += frontier
+    assert all(t.elections for t in out)
+    return out
+
+
+def _tied_record_states(bounds, seed):
+    """Bounded random states whose ``elections`` hold 2..6 distinct
+    records, the first two equal in ``eTerm``, ``eLeader`` and ``eLog``
+    and apart in ``eVotes`` alone, the third apart from the first in one
+    ``evoterLog`` column alone: orders a late key word decides, and a
+    server permutation changes."""
+    out = []
+    for s in _random_states(bounds, 400, seed):
+        if len(s.elections) < 2:
+            continue
+        (term, lead, log, votes, vlog), *rest = s.elections
+        twin = (term, lead, log, votes ^ 1, vlog)
+        col = len(out) % bounds.n_servers
+        near = (term, lead, log, votes, vlog[:col]
+                + (None if vlog[col] == () else (),) + vlog[col + 1:])
+        recs = {s.elections[0], twin, near, *rest[1:]}
+        out.append(s._replace(elections=tuple(sorted(
+            recs, key=interp._election_key))[:bounds.max_elections]))
+    assert any(len(s.elections) == bounds.max_elections for s in out)
+    return out[:80]
+
+
+def _stale_election_vecs():
+    """Packed rows no ``to_vec`` writes: an EMPTY ``elections`` slot
+    (``eTerm`` 0) that still holds words, in front of, between and behind
+    the occupied ones.  No kernel leaves one (``elections`` only grows),
+    but ``canonicalize_elections`` zeroes nothing: the loop sorts such a
+    slot among the empty ones and its words join the key, so the scan
+    has to key it the same way.  Row 0 is the clean state."""
+    lay = st.Layout.of(_BH3)
+    clean = next(s for s in _tied_record_states(_BH3, seed=53)
+                 if len(s.elections) == 3)
+    vecs = [interp.to_vec(clean, _BH3)]
+    for at in (0, 1, 3):
+        t = st.unpack(vecs[0], lay, np)
+        stale = {"eTerm": 0, "eLeader": 2, "eLog": 5, "eVotes": 6}
+        for f in ("eTerm", "eLeader", "eLog", "eVotes", "eVLog"):
+            rows = list(t[f][:3])
+            rows.insert(at, np.asarray([7, 0, 9]) if f == "eVLog"
+                        else stale[f])
+            t[f] = np.concatenate([np.asarray(rows, np.int32),
+                                   t[f][4:]]).astype(np.int32)
+            if at == 3:    # and a second stale slot behind it, another order
+                t[f][5] = np.asarray([0, 8, 0]) if f == "eVLog" \
+                    else {**stale, "eLeader": 1}[f]
+        vecs.append(st.pack(t, np))
+    return np.stack(vecs)
+
+
 # name -> (bounds, axes, VIEW or None, states (or packed rows), at least
 # this many)
 _SCAN_CASES = {
@@ -130,6 +214,17 @@ _SCAN_CASES = {
     "full5-server": (
         _FULL5, ("Server",), None,
         lambda: _scan_case_states(_FULL5, "full", 7, 60, 300), 300),
+    # faithful mode under Server symmetry alone (PR 51): nothing is moved
+    "3s-faithful-server": (
+        _BH3, ("Server",), None,
+        lambda: _elected_states(_BH3) + _tied_record_states(_BH3, seed=51)
+        + _random_states(_BH3, 40, seed=52), 150),
+    "3s-faithful-wide-ranks-server": (
+        _BH3W, ("Server",), None,
+        lambda: _elected_states(_BH3W, depth=2, cap=30)
+        + _tied_record_states(_BH3W, seed=54)[:40], 60),
+    "3s-faithful-stale-election-slots": (
+        _BH3, ("Server",), None, _stale_election_vecs, 4),
     # the poles of the orbit: every permutation ties / none does
     "5s-all-identical": (
         _ELECT5, ("Server",), None,

@@ -437,7 +437,17 @@ def _lowered(cfg):
         jax.ShapeDtypeStruct((eng.caps.block,), np.bool_), i32, i32)
 
 
-def test_faithful_mode_opens_two_scopes_inside_two_stages():
+@pytest.fixture(scope="module")
+def toy_paths():
+    """The toy configuration's compiled segment module's op names: what a
+    device trace's events carry."""
+    import re
+    cfg = mf.read_json("testdata", "toy_hist3.json")
+    return cfg, set(re.findall(r'op_name="(jit\(segment\)/[^"]*)"',
+                               _lowered(cfg).compile().as_text()))
+
+
+def test_faithful_mode_opens_two_scopes_inside_two_stages(toy_paths):
     import re
     from raft_tla_tpu.ops import kernels
     # the stage list is the accepted reducer's, letter for letter; the two
@@ -446,10 +456,7 @@ def test_faithful_mode_opens_two_scopes_inside_two_stages():
     assert kernels.NESTED_SCOPES == histred.SCOPES \
         == (kernels.HISTORY_SCOPE, kernels.ORBIT_MOVED_SCOPE)
     assert not set(kernels.NESTED_SCOPES) & set(kernels.STAGE_SCOPES)
-    cfg = mf.read_json("testdata", "toy_hist3.json")
-    # the compiled module's op names: what a device trace's events carry
-    paths = set(re.findall(r'op_name="(jit\(segment\)/[^"]*)"',
-                           _lowered(cfg).compile().as_text()))
+    cfg, paths = toy_paths
     hist = [p for p in paths if histred.scope_of(p) == "history"]
     moved = [p for p in paths if histred.scope_of(p) == "orbit_moved"]
     assert hist and moved
@@ -469,6 +476,30 @@ def test_faithful_mode_opens_two_scopes_inside_two_stages():
     ptext = _lowered(parity).as_text(debug_info=True)
     assert "orbit_scan" in ptext and "expand" in ptext
     assert not re.search("|".join(kernels.NESTED_SCOPES), ptext)
+
+
+def test_the_scan_of_the_toy_moves_no_history(toy_paths):
+    """PR 51: under SYMMETRY Server the faithful scan moves no field
+    (``scan_forms``), and what the history adds to an image's key still
+    lowers under ``orbit_moved`` inside ``orbit_scan`` — once a step in
+    front of the scan (the slot-major record words, ``allLogs``' sums) and
+    in the images' vmapped body — with no loop of its own: the parent's
+    ``lax.map`` over the images put every op of the scope inside a second
+    ``while`` under the scan's."""
+    from raft_tla_tpu.ops import symmetry as sym
+    cfg, paths = toy_paths
+    forms = sym.scan_forms(fam.check_config(cfg).bounds,
+                           tuple(cfg["symmetry"]))
+    assert forms["moved"] == () and forms["once"] == ("allLogs",)
+    assert "vLog" in forms["table"] and "eVLog" in forms["ranked"]
+    moved = [p.split("/orbit_scan/", 1)[1] for p in paths
+             if histred.scope_of(p) == "orbit_moved"]
+    assert any(p.startswith("orbit_moved/") for p in moved)
+    assert any("vmap(orbit_moved)" in p for p in moved)
+    assert max(p.count("while") for p in moved) <= 1
+    assert not any(re_op in p for p in moved
+                   for re_op in ("gather", "scatter", "dynamic_update_slice",
+                                 "sort"))
 
 
 # ----------------------------------------- the whole run, at toy size (CPU)

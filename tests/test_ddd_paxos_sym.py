@@ -244,7 +244,18 @@ def test_view_faithful_mode_and_twophases_symmetry_stay_refused():
 
 # ------------------------------------------------- the acceptance run (CLI)
 
-def test_five_acceptors_through_the_command_line(tmp_path, capsys):
+def _returns_the_cli_env(monkeypatch, events):
+    """``check.main`` writes ``--events`` / ``--trace`` into ``os.environ``
+    for the engines it builds; in-process that outlives the test and turns
+    tracing on for every later test of the worker (``run_end.compiles`` then
+    differs between two arms that tests/test_serve_sched.py compares).
+    Setting them through ``monkeypatch`` first gives the originals back."""
+    monkeypatch.setenv("RAFT_TLA_EVENTS", str(events))
+    monkeypatch.setenv("RAFT_TLA_TRACE", "1")
+
+
+def test_five_acceptors_through_the_command_line(tmp_path, capsys,
+                                                 monkeypatch):
     """``check --spec paxos --engine ddd`` on the configuration's own cfg
     text, the normal path, at ballots 0..1: the ``Symmetry:`` line, 5,811
     orbits in 25 levels, complete."""
@@ -254,6 +265,7 @@ def test_five_acceptors_through_the_command_line(tmp_path, capsys):
     cfg = tmp_path / "MCPaxos.cfg"
     cfg.write_text(text)
     events = tmp_path / "run.events"
+    _returns_the_cli_env(monkeypatch, events)
     rc = cli.main([str(cfg), "--spec", "paxos", "--engine", "ddd",
                    "--max-term", "1", "--cpu", "--chunk", "256",
                    "--events", str(events), "--trace",
@@ -289,10 +301,12 @@ def test_five_acceptors_through_the_command_line(tmp_path, capsys):
                for a in segs)
 
 
-def test_without_symmetry_the_twin_and_the_spans_say_so(tmp_path, capsys):
+def test_without_symmetry_the_twin_and_the_spans_say_so(tmp_path, capsys,
+                                                        monkeypatch):
     cfg = tmp_path / "MCPaxos.cfg"
     cfg.write_text(cfg_text(3, symmetry=None))
     events = tmp_path / "run.events"
+    _returns_the_cli_env(monkeypatch, events)
     rc = cli.main([str(cfg), "--spec", "paxos", "--engine", "ddd",
                    "--max-term", "1", "--cpu", "--chunk", "64",
                    "--events", str(events), "--trace",

@@ -221,6 +221,39 @@ def test_symmetry_composes_with_faithful_mode():
     assert (ef.n_states, ef.diameter) == (26723, 32)
 
 
+def test_the_pass_says_how_many_fields_the_orbit_scan_moves(tmp_path,
+                                                            capsys):
+    """``scan_moved_fields`` on the ``pass`` span, in the pass ledger and in
+    the CLI's faithful-mode line: 0 in faithful mode under SYMMETRY Server
+    (the history rides the key table, a sum taken once and packed record
+    keys), 1 under a Value symmetry (``logVal``), nothing with no
+    SYMMETRY (no scan)."""
+    from raft_tla_tpu import check as cli
+    from raft_tla_tpu.ddd_engine import DDDCapacities, DDDEngine
+    from test_cli import write_cfg
+
+    tiny = ("--max-term", "2", "--max-log", "0", "--max-msgs", "1",
+            "--spec", "election", "--engine", "ddd", "--chunk", "32",
+            "--cpu")
+    cfg = write_cfg(tmp_path / "h.cfg", extra="SYMMETRY Server\n")
+    assert cli.main([cfg, "--faithful", *tiny]) == cli.EXIT_OK
+    said = capsys.readouterr().out
+    assert "elections peak 1 of 6 slots; the orbit scan moves 0 field(s) " \
+           "an image." in said
+    cfg = write_cfg(tmp_path / "p.cfg")
+    assert cli.main([cfg, "--faithful", *tiny]) == cli.EXIT_OK
+    assert "elections peak 1 of 6 slots." in capsys.readouterr().out
+
+    cc = CheckConfig(bounds=Bounds(n_servers=2, n_values=2, max_term=2,
+                                   max_log=1, max_msgs=1),
+                     spec="election", invariants=("NoTwoLeaders",),
+                     symmetry=("Server", "Value"), chunk=32)
+    rec = DDDEngine(cc, DDDCapacities(block=1 << 10, table=1 << 12,
+                                      seg_rows=1 << 9, levels=64)
+                    ).check().level_log
+    assert rec["scan_moved_fields"] == 1 and "elections_peak" not in rec
+
+
 def test_device_engine_faithful_parity():
     """The device engine runs faithful mode too: its HBM store rows carry
     the history fields (``ddd``'s bit-packed rows:

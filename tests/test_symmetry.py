@@ -18,7 +18,8 @@ from raft_tla_tpu.ops import msgbits as mb
 from raft_tla_tpu.ops import state as st
 from raft_tla_tpu.ops import symmetry as sym
 from symmetry_cases import (
-    _ELECT5, _FULL5, _B3S, _SCAN_CASES, B2, B3, _random_states)
+    _BH3, _BH3W, _ELECT5, _FULL5, _B3S, _SCAN_CASES, B2, B3, _random_states,
+    _tied_record_states)
 
 
 def permute_py_state(s, p, bounds):
@@ -155,6 +156,12 @@ def test_scan_orbit_fp_bit_identical_to_loop(case):
     if case == "full5-stale-slots":
         # a stale slot is no message: every row keys as the clean one
         assert len({(int(h), int(l)) for h, l in zip(hi_s, lo_s)}) == 1
+    if case == "3s-faithful-stale-election-slots":
+        # canonicalize_elections zeroes nothing: a stale record's words
+        # join the loop's key, and so the scan's (the case can see);
+        # where the slot stood does not
+        keys = [(int(h), int(l)) for h, l in zip(hi_s, lo_s)]
+        assert keys[1] == keys[2] and len(set(keys)) == 3
 
 
 @pytest.mark.parametrize("name, bounds, n_perms", [
@@ -223,6 +230,115 @@ def test_key_table_and_ranked_bag_equal_the_permuted_packed_row(
                 == (int(want[0]), int(want[1])), (perms[i], k)
 
 
+@pytest.mark.parametrize("name, bounds, n_words", [
+    ("faithful3", _BH3, 1), ("wide-ranks", _BH3W, 2)])
+def test_faithful_key_parts_equal_the_permuted_packed_row(
+        name, bounds, n_words):
+    """The same algebra with the history in it (PR 51), in NumPy alone:
+    for every server permutation ``p`` at three servers, ``features .
+    table[p]`` (``vLog`` among the features, as two base-128 digits where
+    the log universe has more than 127 ranks) plus the ranked bag, plus
+    the ``elections`` records relabelled and ordered as packed keys, plus
+    ``allLogs``' sum taken once, finalised, is
+    ``fingerprint(pack(canonicalize(permute_struct(s, p))))`` on both
+    lanes — no field of ``s`` moved.  On random bounded states and on
+    states whose records tie on their leading key parts."""
+    from raft_tla_tpu.ops import fingerprint as fpr
+
+    lay = st.Layout.of(bounds)
+    consts = fpr.lane_constants(lay.width)
+    states = _random_states(bounds, 30, seed=51) \
+        + _tied_record_states(bounds, seed=52)[:30]
+    vecs = np.stack([interp.to_vec(s, bounds) for s in states])
+    batch = st.unpack(vecs, lay, np)
+    assert ((batch["eTerm"] > 0).sum(axis=1) == lay.E).any()
+    assert (batch["vLog"] > 0).any() and (batch["allLogs"] != 0).any()
+    forms = sym.scan_forms(bounds, ("Server",))
+    assert forms["moved"] == () and forms["once"] == ("allLogs",)
+    assert sorted(f for fs in forms.values() for f in fs) \
+        == sorted(lay.fields)
+    fields = forms["table"]
+    assert fields == sym._linear_fields(("Server",), True) \
+        and fields[-1] == "vLog"
+    wide = sym._wide_fields(bounds, fields)
+    assert wide == (("vLog",) if n_words == 2 else ())
+    assert (batch["vLog"].max() > 127) == bool(wide)
+    phi = sym._key_features(batch, fields, np, wide)
+    assert phi.dtype == np.int8
+    assert 0 <= phi.min() and phi.max() <= sym._feature_cap(bounds, fields)
+    perms = sym.permutations(bounds)
+    table = sym._key_table(bounds, consts, fields, perms)
+    assert table.shape == (6, 2, phi.shape[0])
+    sym._check_limb_range(table.shape[-1], sym._feature_cap(bounds, fields))
+    limb_sums = sym._limb_sums(sym._key_limbs(table), phi, np)
+    plan = sym._election_key_plan(bounds)
+    assert plan[1] == n_words
+    luts = {**sym._server_luts(bounds), **sym._election_luts(bounds, plan)}
+    fc = fpr.field_constants(lay.shapes, consts)
+    cbag = np.stack([fc[f] for f in sym._BAG], axis=1)
+    slots = {f: [batch[f][:, s] for s in range(lay.S)] for f in sym._BAG}
+    recs = sym._election_records(batch, plan, np)
+    once = fpr.field_sums(batch, consts, np, forms["once"])
+    for i, p in enumerate(perms):
+        s1, s2 = sym._linear_sums(phi, table[i], np)
+        assert (limb_sums[i, 0] == s1).all() and (limb_sums[i, 1] == s2).all()
+        hi = [sym._relabel_hi(w, luts["src"][i], luts["dst"][i], np)
+              for w in slots["msgHi"]]
+        b1, b2 = sym._bag_sums(hi, slots["msgLo"], slots["msgCount"], cbag,
+                               np)
+        e1, e2 = sym._election_sums(
+            recs, {k: v[i] for k, v in luts.items()}, plan, fc, np)
+        got = fpr.finalise(s1 + b1 + e1 + once[0], s2 + b2 + e2 + once[1],
+                           np)
+        for k in range(len(vecs)):
+            image = st.canonicalize(sym.permute_struct(
+                st.unpack(vecs[k], lay, np), p, bounds, np), np)
+            want = fpr.fingerprint(st.pack(image, np), consts, np)
+            assert (int(got[0][k]), int(got[1][k])) \
+                == (int(want[0]), int(want[1])), (p, k)
+
+
+def test_scan_forms_say_what_each_symmetry_still_moves():
+    """``build_orbit_fp`` chooses each field's form from the SYMMETRY axes
+    and the layout alone and says so on the function it returns: nothing
+    is moved under Server symmetry, with or without the history;
+    ``logVal`` alone under Value symmetry in parity mode (``repl3``); and
+    with a value permutation over the history its log ranks relabel, so
+    the history keeps the data-moving path."""
+    from raft_tla_tpu.ops import fingerprint as fpr
+    import jax.numpy as jnp
+
+    hist = st.HISTORY_FIELDS
+    for bounds, axes, moved, once in (
+            (_B3S, ("Server",), (), ()),
+            (_B3S, ("Value",), ("logVal",), ()),
+            (_B3S, ("Server", "Value"), ("logVal",), ()),
+            (_BH3, ("Server",), (), ("allLogs",)),
+            (_BH3, ("Value",), ("logVal",) + hist, ()),
+            (_BH3, ("Server", "Value"), ("logVal",) + hist, ())):
+        lay = st.Layout.of(bounds)
+        fn = sym.build_orbit_fp(
+            bounds, axes, jnp.asarray(fpr.lane_constants(lay.width)),
+            lay.history)
+        assert fn.forms == sym.scan_forms(bounds, axes)
+        assert fn.forms["moved"] == moved and fn.forms["once"] == once
+        assert ("vLog" in fn.forms["table"]) \
+            == ("eTerm" in fn.forms["ranked"]) == (once != ())
+        assert sorted(f for fs in fn.forms.values() for f in fs) \
+            == sorted(lay.fields)
+
+
+def test_a_key_part_the_limbs_cannot_carry_is_refused_by_name():
+    """Features are signed bytes: ``_check_limb_range`` refuses a cap past
+    127, and the wide digits are what keeps ``vLog`` under it at any
+    universe ``Bounds`` admits (1,024 ranks: a high digit of 8)."""
+    assert sym._feature_cap(_BH3W, ("vLog", "role")) == 127
+    assert sym._word_caps(_BH3W, ("vLog",)) == {"vLog": 259}
+    assert sym._word_caps(_BH3, ("vLog", "term")) == {"vLog": 43, "term": 3}
+    with pytest.raises(ValueError, match="int8"):
+        sym._check_limb_range(10, 128)
+
+
 def _lowered_scan(bounds, lanes, axes=("Server",)):
     """``build_orbit_fp`` lowered for ``lanes`` states of ``bounds``: the
     StableHLO text and the struct's shapes."""
@@ -234,7 +350,7 @@ def _lowered_scan(bounds, lanes, axes=("Server",)):
     consts = jnp.asarray(fpr.lane_constants(lay.width))
     struct = {f: jax.ShapeDtypeStruct((lanes,) + tuple(shape), jnp.int32)
               for f, shape in lay.shapes.items()}
-    fn = sym.build_orbit_fp(bounds, axes, consts, False)
+    fn = sym.build_orbit_fp(bounds, axes, consts, lay.history)
     return jax.jit(fn).lower(struct).as_text(), struct
 
 
@@ -324,3 +440,29 @@ def test_scan_moves_no_state_data():
         valued, _ = _lowered_scan(bounds, lanes, ("Server", "Value"))
         assert re.search(
             rf"stablehlo\.gather.*tensor<{lanes}x{n}x{L}xi32>", valued)
+
+
+@pytest.mark.parametrize("bounds", [_BH3, _BH3W], ids=["faithful3", "wide"])
+def test_faithful_server_scan_moves_no_history(bounds):
+    """PR 51: in faithful mode under Server symmetry alone the scan holds
+    what the parity scan holds — one ``while`` over the blocks, one
+    ``dot_general``, no ``gather``, no ``scatter`` /
+    ``dynamic_update_slice`` (the ``elections`` sort network's
+    ``.at[..., i].set``), no ``sort`` — with ``vLog``'s ``n * n`` words
+    among the byte-wide features (twice where a rank passes 127: two
+    digits).  The same bounds under Server x Value keep the data-moving
+    path and show all of it (the test can see)."""
+    lanes = 24
+    lay = st.Layout.of(bounds)
+    n, L = lay.n, lay.L
+    text, _struct = _lowered_scan(bounds, lanes)
+    assert text.count("stablehlo.while") == 1
+    for op in ("gather", "dynamic_update_slice", "stablehlo.sort", "scatter"):
+        assert op not in text, op
+    wide = bool(sym._wide_fields(bounds, ("vLog",)))
+    F = 4 * n + 2 * n * L + 5 * n * n + (1 + wide) * n * n
+    assert _dot_generals(text) == [
+        (f"48x{F}xi8", f"{F}x{lanes}xi8", f"48x{lanes}xi32")]
+    valued, _ = _lowered_scan(bounds, lanes, ("Server", "Value"))
+    assert valued.count("stablehlo.while") > 1
+    assert "stablehlo.gather" in valued and "stablehlo.scatter" in valued
